@@ -52,8 +52,8 @@ bench-overlap:
 # on the CPU sim (docs/roofline.md round 8; BENCH_SEQ/BENCH_SP/
 # BENCH_HBM_GB and the dim knobs documented in bench.py).
 bench-longctx:
-	BENCH_LONGCTX=1 python bench.py
-	BENCH_LONGCTX=1 BENCH_SEQ=1048576 BENCH_SP=8 python bench.py
+	BENCH_LONGCTX=1 BENCH_PEAK_TFLOPS=197 BENCH_HBM_GBPS=819 python bench.py
+	BENCH_LONGCTX=1 BENCH_PEAK_TFLOPS=197 BENCH_HBM_GBPS=819 BENCH_SEQ=1048576 BENCH_SP=8 python bench.py
 
 # Quantization acceptance gates (observability/quant_stats.py
 # run_quant_bench): measures the ZeRO++ trio's error on real tensors —

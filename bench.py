@@ -61,7 +61,8 @@ import time
 # table and one formula (tools/device_step_bench.py imports them from
 # here — keep the re-export)
 from deepspeed_tpu.observability.roofline import (  # noqa: E402,F401
-    PEAK_TFLOPS, detect_peak_tflops)
+    PEAK_TFLOPS, detect_peak_tflops, on_tpu_or_named_cpu_smoke)
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 # the real shape (docs/roofline.md): llama3-8b geometry at the depth +
 # true vocab that exercise ZeRO-Infinity streaming on one 16GB chip
@@ -79,7 +80,7 @@ def read_tuned_defaults(path=None):
     try:
         with open(path) as f:
             return json.load(f)
-    except Exception:
+    except FileNotFoundError:
         return {}
 
 
@@ -236,7 +237,7 @@ def longctx_bench_report(env=None):
 
     dev = jax.devices()[0]
     peak = float(env.get("BENCH_PEAK_TFLOPS", 0)) or detect_peak_tflops(dev)
-    hbm = detect_hbm_gbps(dev)
+    hbm = float(env.get("BENCH_HBM_GBPS", 0)) or detect_hbm_gbps(dev)
     depth = plan.overlap_depth_hint
     table = attribution_markdown(
         regions, peak, hbm,
@@ -276,33 +277,31 @@ def overlap_report(model, step_ms, overlap_depth, streaming,
     """
     if not streaming or overlap_depth is None or not step_ms:
         return None, None
-    try:
-        import jax
+    import jax
 
-        from deepspeed_tpu.models.transformer import init_params
-        from deepspeed_tpu.observability.attribution import (
-            _DEFAULT_FETCH_GBPS, _per_layer_shapes, _tree_bytes,
-            overlap_split_ms)
+    from deepspeed_tpu.models.transformer import init_params
+    from deepspeed_tpu.observability.attribution import (
+        _DEFAULT_FETCH_GBPS, _per_layer_shapes, _tree_bytes,
+        overlap_split_ms)
 
-        cfg = model.config
-        params = jax.eval_shape(lambda k: init_params(cfg, k),
-                                jax.random.PRNGKey(0))
-        layer_bytes = _tree_bytes(_per_layer_shapes(params["layers"]))
-        fetch = (fetch_gbps if fetch_gbps is not None
-                 else float(os.environ.get("DSTPU_FETCH_GBPS",
-                                           _DEFAULT_FETCH_GBPS)))
-        transfer_ms = (layer_bytes * cfg.num_layers * 2  # fwd + bwd
-                       / (fetch * 1e9) * 1e3)
-        stages = 2 * max(int(cfg.num_layers), 1)
-        split = overlap_split_ms(transfer_ms, float(step_ms) / stages,
-                                 int(overlap_depth), stages)
-        return (round(split["hidden_frac"], 4),
-                round(split["exposed_ms"], 2))
-    except Exception:
-        return None, None
+    cfg = model.config
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    layer_bytes = _tree_bytes(_per_layer_shapes(params["layers"]))
+    fetch = (fetch_gbps if fetch_gbps is not None
+             else float(os.environ.get("DSTPU_FETCH_GBPS",
+                                       _DEFAULT_FETCH_GBPS)))
+    transfer_ms = (layer_bytes * cfg.num_layers * 2  # fwd + bwd
+                   / (fetch * 1e9) * 1e3)
+    stages = 2 * max(int(cfg.num_layers), 1)
+    split = overlap_split_ms(transfer_ms, float(step_ms) / stages,
+                             int(overlap_depth), stages)
+    return (round(split["hidden_frac"], 4),
+            round(split["exposed_ms"], 2))
 
 
 def main():
+    enable_compile_cache()
     if os.environ.get("BENCH_MODE") in ("serve", "serve_slo",
                                         "serve_fleet", "serve_quant",
                                         "serve_tier", "serve_procs",
@@ -454,7 +453,7 @@ def main():
     from deepspeed_tpu.models.zoo import get_model
 
     n_chips = len(jax.devices())
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = on_tpu_or_named_cpu_smoke()
 
     # shape + perf knobs resolve in one place (resolve_bench_defaults —
     # tier-1 tested): real shape 8L + 131,072 vocab by default, the
@@ -788,15 +787,14 @@ def main():
     contended = len(kept) < len(windows) or any(
         w[1] > load_max for w in windows)
     flops_per_token = model.flops_per_token()
-    peak = detect_peak_tflops(jax.devices()[0])
-    mfu = tok_per_sec_chip * flops_per_token / (peak * 1e12)
+    # the named CPU smoke has no chip peak: its MFU is not measured
+    mfu = (tok_per_sec_chip * flops_per_token
+           / (detect_peak_tflops(jax.devices()[0]) * 1e12)
+           if on_tpu else None)
 
-    baseline = {}
-    try:
-        with open(os.path.join(os.path.dirname(__file__), "BASELINE.json")) as f:
-            baseline = json.load(f).get("published", {}) or {}
-    except Exception:
-        pass
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "BASELINE.json")) as f:
+        baseline = json.load(f).get("published", {}) or {}
     base_key = ("llama3_8b_geom_tokens_per_sec_per_chip" if llama_headline
                 else "gpt2_125m_tokens_per_sec_per_chip")
     base_tps = baseline.get(base_key)
@@ -821,7 +819,7 @@ def main():
         "value": round(tok_per_sec_chip, 1),
         "unit": "tokens/s/chip",
         "vs_baseline": round(vs_baseline, 3),
-        "mfu": round(mfu, 4),
+        "mfu": round(mfu, 4) if mfu is not None else None,
         "engine_mfu": (round(engine_mfu, 4)
                        if engine_mfu is not None else None),
         "host_gap_ms": (round(host_gap_ms, 3)

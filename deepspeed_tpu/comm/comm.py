@@ -31,7 +31,6 @@ from jax import lax
 
 from deepspeed_tpu.utils.comms_logging import get_comms_logger
 from deepspeed_tpu.utils.logging import log_dist, logger
-from deepspeed_tpu.utils import jaxcompat
 
 __all__ = [
     "init_distributed", "is_initialized", "get_world_size", "get_rank",
@@ -347,7 +346,7 @@ def reduce_scatter(x, axis, *, scatter_dim: int = 0, op: str = "sum",
         out = lax.psum_scatter(x, axis, scatter_dimension=scatter_dim,
                                tiled=True)
         if op in ("avg", "mean"):
-            out = out / jaxcompat.axis_size(axis)
+            out = out / jax.lax.axis_size(axis)
         return out
 
 
@@ -395,7 +394,7 @@ def axis_index(axis):
 
 
 def axis_size(axis):
-    return jaxcompat.axis_size(axis)
+    return jax.lax.axis_size(axis)
 
 
 def configure(config=None) -> None:

@@ -25,6 +25,7 @@ from deepspeed_tpu.inference import model_runner
 from deepspeed_tpu.models.transformer import TransformerLM
 from deepspeed_tpu.parallel import topology as topo
 from deepspeed_tpu.runtime.sharding import spec_from_logical
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 # TP rule table for inference (reference AutoTP policy: qkv/mlp-in column,
@@ -49,6 +50,7 @@ class InferenceEngine:
                  dtype=jnp.bfloat16, max_batch: int = 8,
                  max_seq_len: Optional[int] = None, seed: int = 0,
                  quantize_weights: Optional[str] = None):
+        enable_compile_cache()
         self.model = model
         self.cfg = model.config
         if mesh is None:

@@ -21,7 +21,6 @@ import os
 import sys
 import time
 from typing import List
-from deepspeed_tpu.utils import jaxcompat
 
 
 # ---------------------------------------------------------------------------
@@ -79,7 +78,7 @@ def bench_collectives(axis: str = "dp", sizes_mb: List[float] = (1, 4, 16, 64),
                 return comm.all_to_all(x.reshape(world, -1), axis,
                                        split_dim=0, concat_dim=1)
 
-            fn = jax.jit(jaxcompat.shard_map(body, mesh=mesh, in_specs=P(axis),
+            fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(axis),
                                        out_specs=P(axis), check_vma=False))
             r = fn(x)
             jax.block_until_ready(r)  # compile + warm

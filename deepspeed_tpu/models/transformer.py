@@ -480,7 +480,6 @@ def _fpdt_sp_block(cfg: TransformerConfig, x, layer_params, positions,
     from deepspeed_tpu.parallel import topology as _topo
     from deepspeed_tpu.parallel.fpdt import fpdt_attention_block
     from deepspeed_tpu.runtime.sharding import effective_dtype
-    from deepspeed_tpu.utils import jaxcompat
 
     mesh = _topo._GLOBAL_MESH
     dt = effective_dtype(cfg.dtype)
@@ -508,9 +507,9 @@ def _fpdt_sp_block(cfg: TransformerConfig, x, layer_params, positions,
                                     cfg.norm_eps),
             post_fn=post, sp_axis="sp", sp_size=sp)
 
-    fn = jaxcompat.shard_map(body, mesh=mesh,
-                             in_specs=(x_spec, p_specs, pos_spec),
-                             out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(x_spec, p_specs, pos_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(x, layer_params, positions)
 
 

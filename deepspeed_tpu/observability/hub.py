@@ -17,7 +17,7 @@ shows the whole picture. Sinks attach via :meth:`configure` (config
 block or ``DSTPU_METRICS_JSONL`` / ``DSTPU_METRICS_PROM`` env vars).
 
 Compile/retrace visibility: jax.monitoring event listeners (registered
-once, best-effort — older jax may lack the API) count XLA compilations
+once) count XLA compilations
 and their wall time; ``StepTrace.compile_events`` > 0 on a mid-run step
 is the classic silent-retrace regression signature.
 """
@@ -59,13 +59,9 @@ def _register_compile_listeners() -> None:
     if _LISTENERS_REGISTERED:
         return
     _LISTENERS_REGISTERED = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        monitoring.register_event_duration_secs_listener(
-            _on_compile_duration)
-    except Exception as e:  # jax.monitoring API varies across versions
-        logger.debug(f"compile-event listener unavailable: {e}")
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
 
 
 def compile_stats() -> Dict[str, float]:

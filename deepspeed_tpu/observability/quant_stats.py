@@ -752,9 +752,8 @@ def kv_off_switch_structural(cfg=None, params=None) -> bool:
     on = fn.lower(params, kvq, *a).as_text()
 
     def has_int8(txt: str) -> bool:
-        # StableHLO spells int8 tensors "xi8>"/"tensor<i8>"; HLO text
-        # (older jax as_text) spells them "s8[" — accept either
-        return "s8[" in txt or "i8>" in txt
+        # StableHLO spells int8 tensors "xi8>"/"tensor<i8>"
+        return "i8>" in txt
 
     return (not has_int8(off)) and has_int8(on)
 

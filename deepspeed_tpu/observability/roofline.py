@@ -13,6 +13,13 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, Optional
 
+# Peaks are keyed by a substring of ``device.device_kind`` (a v5e chip
+# reports "TPU v5 lite"). Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v4", "TPU v5e",
+# "TPU v5p", "TPU v6e"). A device that is not in the tables is an error,
+# never a default: a number computed against the wrong chip is worse
+# than no number.
+
 # per-chip dense bf16 peak TFLOPS by TPU generation
 PEAK_TFLOPS = {
     "v4": 275.0,
@@ -33,28 +40,60 @@ HBM_GBPS = {
     "v6e": 1640.0,
 }
 
-_CPU_SIM_PEAK = 197.0  # arbitrary reference chip for cpu-sim MFU numbers
+class UnknownDeviceError(LookupError):
+    """The device is in no peak table and no override was given."""
+
+
+def _lookup(table: Dict[str, float], env_key: str, device, what: str
+            ) -> float:
+    if env_key in os.environ:
+        return float(os.environ[env_key])
+    kind = getattr(device, "device_kind", "")
+    for key, val in table.items():
+        if key in kind.lower():
+            return val
+    raise UnknownDeviceError(
+        f"no {what} for device_kind {kind!r}: add the chip to "
+        f"observability/roofline.py with its source, or pass {env_key}")
 
 
 def detect_peak_tflops(device) -> float:
-    """bf16 peak for ``device``; BENCH_PEAK_TFLOPS env overrides."""
-    if "BENCH_PEAK_TFLOPS" in os.environ:
-        return float(os.environ["BENCH_PEAK_TFLOPS"])
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in PEAK_TFLOPS.items():
-        if key in kind:
-            return val
-    return _CPU_SIM_PEAK
+    """bf16 peak for ``device``; BENCH_PEAK_TFLOPS env overrides.
+    Raises :class:`UnknownDeviceError` for a device not in the table."""
+    return _lookup(PEAK_TFLOPS, "BENCH_PEAK_TFLOPS", device,
+                   "bf16 peak TFLOP/s")
 
 
 def detect_hbm_gbps(device) -> float:
-    if "BENCH_HBM_GBPS" in os.environ:
-        return float(os.environ["BENCH_HBM_GBPS"])
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in HBM_GBPS.items():
-        if key in kind:
-            return val
-    return 819.0
+    """HBM bandwidth for ``device``; BENCH_HBM_GBPS env overrides.
+    Raises :class:`UnknownDeviceError` for a device not in the table."""
+    return _lookup(HBM_GBPS, "BENCH_HBM_GBPS", device, "HBM GB/s")
+
+
+CPU_SMOKE_ENV = "BENCH_CPU_SMOKE"
+
+
+class NoChipError(RuntimeError):
+    """A measurement path found no TPU and no CPU smoke was asked for."""
+
+
+def on_tpu_or_named_cpu_smoke() -> bool:
+    """True on a TPU backend. Anywhere else a benchmark may run only as
+    the CPU smoke its caller asked for by name (``BENCH_CPU_SMOKE=1``:
+    toy shapes, for control flow and counts, labelled cpu) — then this
+    returns False. Without that it raises: a time or a rate taken off
+    the chip is not a result, so nothing prints one by accident."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return True
+    if os.environ.get(CPU_SMOKE_ENV) == "1":
+        return False
+    raise NoChipError(
+        f"backend is {backend!r}, not a TPU: benchmarks report device "
+        f"numbers and refuse to run elsewhere. Set {CPU_SMOKE_ENV}=1 for "
+        "the toy-size CPU smoke (control flow and counts only)")
 
 
 def mfu(tokens_per_sec_per_chip: float, flops_per_token: float,

@@ -3,10 +3,11 @@
 Covers the role of the reference's fused attention kernels
 (csrc/transformer/*softmax*.cu, inference flash kernels
 inference/v2/kernels/ragged_ops/blocked_flash). The ``impl='auto'`` path
-picks the Pallas flash kernel on TPU (ops/pallas/flash_attention.py) and
-falls back to the XLA einsum implementation elsewhere — the op-builder
-``is_compatible`` pattern (op_builder/builder.py:116) reduced to a runtime
-platform probe.
+picks between the Pallas flash kernel (ops/pallas/flash_attention.py) and
+the XLA einsum implementation per shape bucket from the measured win/loss
+table, and from the backend and sequence length where the table has no
+row. A decision for the kernel runs the kernel or raises: there is no
+downgrade to the O(S^2) path behind the caller's back.
 """
 
 from __future__ import annotations
@@ -21,22 +22,12 @@ NEG_INF = -1e30
 
 
 @functools.lru_cache(None)
-def _flash_importable() -> bool:
-    try:
-        from deepspeed_tpu.ops.pallas import flash_attention  # noqa: F401
-
-        return True
-    except Exception:
-        return False
-
-
-@functools.lru_cache(None)
 def _flash_available() -> bool:
-    """Legacy heuristic availability: TPU backend + importable kernel.
-    (The win/loss table can still route to the kernel off-TPU — e.g. a
-    CPU-measured table entry in tests — interpreter mode is
-    numerics-equivalent, just slow.)"""
-    return jax.default_backend() == "tpu" and _flash_importable()
+    """Legacy heuristic availability: a TPU backend. (The win/loss table
+    can still route to the kernel off-TPU — e.g. a CPU-measured table
+    entry in tests — interpreter mode is numerics-equivalent, just
+    slow.)"""
+    return jax.default_backend() == "tpu"
 
 
 def repeat_kv_heads(q, k, v):
@@ -97,11 +88,8 @@ _SPARSE_CONFIG = None
 # seq-derived blocks)
 _KERNEL_CONFIG = None
 
-# trace-time dispatch outcomes: pallas/xla picks plus the
-# wanted-flash-but-unavailable fallbacks (the perf cliff the bare
-# telemetry counter used to hide; published as a hub ratio like
-# serve.paged_fallback_ratio)
-_DISPATCH_STATS = {"pallas": 0, "xla": 0, "flash_fallbacks": 0}
+# trace-time dispatch outcomes of impl='auto': pallas/xla picks
+_DISPATCH_STATS = {"pallas": 0, "xla": 0}
 
 
 def set_sparse_config(sparsity) -> None:
@@ -121,13 +109,6 @@ def set_kernel_config(kernels) -> None:
 def dispatch_stats() -> dict:
     """Copy of the trace-time dispatch counters (tests + bench)."""
     return dict(_DISPATCH_STATS)
-
-
-def flash_fallback_ratio() -> float:
-    """Fraction of flash-worthy dispatches that lost the kernel —
-    the train-path analog of ``serve.paged_fallback_ratio``."""
-    fb = _DISPATCH_STATS["flash_fallbacks"]
-    return fb / max(1, _DISPATCH_STATS["pallas"] + fb)
 
 
 def _reset_dispatch_stats() -> None:
@@ -182,9 +163,65 @@ def _export_dispatch(region: str, source: str, reason: str,
     if hub is None:
         return
     hub.gauge(f"kernel.{region}.pallas", 1.0 if source == "pallas" else 0.0)
-    hub.gauge("kernel.flash_fallback_ratio", flash_fallback_ratio())
     hub.record_event("kernel_dispatch", region=region, source=source,
                      reason=reason, bucket=bucket)
+
+
+def _flash_on_mesh(q, k, v, causal: bool, segment_ids, block_q: int,
+                   block_k: int) -> jax.Array:
+    """The flash kernel on whatever mesh is live.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map" — the TPU compiler refuses the program; interpret mode on
+    the CPU never notices), so on a multi-device mesh each shard runs the
+    kernel on its local slice: batch over the data axes that divide it,
+    heads over tp and sp (the Ulysses head-scatter layout) where they
+    divide the kv heads. Attention is independent per (batch, head), so
+    the region needs no collective; an axis that divides neither dim
+    sees replicated operands and computes redundantly. Axes an enclosing
+    region already made manual (the pipeline's pp, the ZeRO++ dp step)
+    are left to it."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.parallel import topology
+    from deepspeed_tpu.runtime import sharding as shard_lib
+
+    kernel = functools.partial(flash_attention, causal=causal,
+                               block_q=block_q, block_k=block_k)
+    mesh = topology._GLOBAL_MESH
+    manual = shard_lib._MANUAL_AXES
+    auto = {a: n for a, n in (mesh.shape.items() if mesh is not None else ())
+            if n > 1 and a not in manual}
+    if not auto:
+        return kernel(q, k, v, segment_ids=segment_ids)
+
+    def dividing(axes, dim):
+        took, prod = [], 1
+        for a in axes:
+            if a in auto and dim % (prod * auto[a]) == 0:
+                took.append(a)
+                prod *= auto[a]
+        return tuple(took) or None
+
+    batch = dividing(topology.BATCH_AXES, q.shape[0])
+    heads = dividing(("tp", "sp"), k.shape[2])
+    spec = P(batch, None, heads, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args, in_specs = args + (segment_ids,), in_specs + (P(batch, None),)
+
+    def local(q, k, v, seg=None):
+        return kernel(q, k, v, segment_ids=seg)
+
+    return jax.shard_map(
+        local,
+        # nested in a partial-manual region, shard_map takes the context
+        # mesh and may only manualize the axes still under GSPMD
+        mesh=jax.sharding.get_abstract_mesh() if manual else mesh,
+        in_specs=in_specs, out_specs=spec,
+        axis_names=frozenset(a for a in mesh.axis_names if a not in manual),
+        check_vma=False)(*args)
 
 
 def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
@@ -211,12 +248,8 @@ def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
         k, v = repeat_kv_heads(q, k, v)  # blocksparse kernel is MHA-only
         return blocksparse_attention(q, k, v, _SPARSE_CONFIG, causal=causal)
     if impl == "flash":
-        from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-
         bq, bk = _pick_blocks(seq, None)
-        return flash_attention(q, k, v, causal=causal,
-                               segment_ids=segment_ids,
-                               block_q=bq, block_k=bk)
+        return _flash_on_mesh(q, k, v, causal, segment_ids, bq, bk)
     if impl != "auto":
         return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
 
@@ -235,25 +268,9 @@ def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
             "flash_attention", bucket, "xla_attention",
             default_use=heuristic,
             table_path=getattr(kcfg, "table_path", None))
-    if decision.source == "pallas" and not _flash_importable():
-        # the flash kernel should have dispatched here but can't load —
-        # the O(S^2)-memory XLA path is a real perf downgrade on TPU
-        from deepspeed_tpu.utils import telemetry
-
-        telemetry.count("attention.flash_to_xla_fallback",
-                        "pallas flash kernel unavailable "
-                        f"(backend={jax.default_backend()})")
-        _DISPATCH_STATS["flash_fallbacks"] += 1
-        decision = registry.DispatchDecision(
-            op_name="xla_attention", source="xla",
-            reason=f"flash unavailable; was: {decision.reason}")
     _DISPATCH_STATS[decision.source] += 1
     _export_dispatch("attention", decision.source, decision.reason, bucket)
     if decision.source == "pallas":
-        from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-
         bq, bk = _pick_blocks(seq, decision.blocks)
-        return flash_attention(q, k, v, causal=causal,
-                               segment_ids=segment_ids,
-                               block_q=bq, block_k=bk)
+        return _flash_on_mesh(q, k, v, causal, segment_ids, bq, bk)
     return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)

@@ -53,7 +53,9 @@ def _source_hash(paths) -> str:
 
 
 def build_native_lib(verbose: bool = False) -> Optional[ctypes.CDLL]:
-    """Compile (cached) and load the native library; None if unavailable."""
+    """Compile (cached) and load the native library; None if unavailable
+    (host-offload callers then run their numpy path — every reason is
+    logged once, so the slower implementation is never silent)."""
     global _lib, _build_error
     with _lock:
         if _lib is not None:
@@ -63,11 +65,13 @@ def build_native_lib(verbose: bool = False) -> Optional[ctypes.CDLL]:
         cxx = shutil.which(os.environ.get("CXX", "g++"))
         if cxx is None:
             _build_error = "no C++ compiler found"
+            logger.warning(_build_error)
             return None
         srcs = [os.path.join(_csrc_dir(), s) for s in _SOURCES]
         missing = [s for s in srcs if not os.path.exists(s)]
         if missing:
             _build_error = f"missing sources: {missing}"
+            logger.warning(_build_error)
             return None
         tag = _source_hash(srcs)
         out_dir = os.path.join(_cache_dir(), tag)
@@ -95,6 +99,7 @@ def build_native_lib(verbose: bool = False) -> Optional[ctypes.CDLL]:
             _lib = ctypes.CDLL(so_path)
         except OSError as e:
             _build_error = f"dlopen failed: {e}"
+            logger.warning(_build_error)
             return None
         _declare(_lib)
         return _lib

@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from deepspeed_tpu.utils import jaxcompat
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -54,6 +53,12 @@ NEG_INF = -1e30
 # tiling; 8 lanes (the fp32 sublane tile) instead of 128 cuts their
 # HBM traffic 16x — they otherwise write/read 2x the attention output
 STAT_LANES = 8
+# segment ids reach the kernels pre-broadcast, q ids as a column
+# [B, S, STAT_LANES] and k ids as a row [B, SEG_SUBLANES, S]: Mosaic
+# wants a block's last two dims divisible by (8, 128) or equal to the
+# array's, which a (1, block) slice of a [B, S] operand is not, and the
+# column/row split also spares the kernel a lane→sublane relayout
+SEG_SUBLANES = 8
 
 
 def _interpret() -> bool:
@@ -129,7 +134,8 @@ def _kv_row(b, hq: int, hkv: int):
 
 def _mask(s, *, iq, ik, causal: bool, seg_q, seg_k,
           block_q: int, block_k: int):
-    """Apply causal and/or segment masks to a [BQ, BK] score block."""
+    """Apply causal and/or segment masks to a [BQ, BK] score block.
+    ``seg_q`` is a [BQ, 1] column, ``seg_k`` a [1, BK] row."""
     if causal:
         qpos = iq * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
@@ -137,8 +143,7 @@ def _mask(s, *, iq, ik, causal: bool, seg_q, seg_k,
             jnp.int32, (block_q, block_k), 1)
         s = jnp.where(kpos <= qpos, s, NEG_INF)
     if seg_q is not None:
-        same = seg_q[:, None] == seg_k[None, :]  # [BQ, BK]
-        s = jnp.where(same, s, NEG_INF)
+        s = jnp.where(seg_q == seg_k, s, NEG_INF)
     return s
 
 
@@ -176,8 +181,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # [BQ, BK]
     s = _mask(s, iq=iq, ik=ik, causal=causal,
-              seg_q=sq_ref[0] if has_segments else None,
-              seg_k=sk_ref[0] if has_segments else None,
+              seg_q=sq_ref[0][:, :1] if has_segments else None,
+              seg_k=sk_ref[0][:1, :] if has_segments else None,
               block_q=block_q, block_k=block_k)
 
     m_prev = m_sc[:, :1]  # [BQ, 1]
@@ -204,7 +209,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
 def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
                hq: int, hkv: int,
                block_q: int, block_k: int) -> Tuple[jax.Array, jax.Array]:
-    """q: [B*Hq, S, D]; k,v: [B*Hkv, S, D]; seg_*: [B, S] or None.
+    """q: [B*Hq, S, D]; k,v: [B*Hkv, S, D]; seg_q: [B, S, STAT_LANES],
+    seg_k: [B, SEG_SUBLANES, S], or both None.
 
     Returns (o [B*Hq, S, D], lse [B*Hq, S, STAT_LANES]).
     """
@@ -228,8 +234,10 @@ def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
     args = [q, k, v]
     if has_segments:
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, t: (b // hq, d_q(t)[0])),
-            pl.BlockSpec((1, block_k), lambda b, t: (b // hq, d_q(t)[1])),
+            pl.BlockSpec((1, block_q, STAT_LANES),
+                         lambda b, t: (b // hq, d_q(t)[0], 0)),
+            pl.BlockSpec((1, SEG_SUBLANES, block_k),
+                         lambda b, t: (b // hq, 0, d_q(t)[1])),
         ]
         args += [seg_q, seg_k]
     kernel = functools.partial(
@@ -253,7 +261,7 @@ def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
             jax.ShapeDtypeStruct((BHq, S, D), q.dtype),
             jax.ShapeDtypeStruct((BHq, S, STAT_LANES), jnp.float32),
         ],
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -300,8 +308,8 @@ def _bwd_dkdv_kernel(*refs, scale: float, causal: bool,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # [BQ, BK]
     s = _mask(s, iq=iq, ik=ik, causal=causal,
-              seg_q=sq_ref[0] if has_segments else None,
-              seg_k=sk_ref[0] if has_segments else None,
+              seg_q=sq_ref[0][:, :1] if has_segments else None,
+              seg_k=sk_ref[0][:1, :] if has_segments else None,
               block_q=block_q, block_k=block_k)
     p = jnp.exp(s - lse)  # [BQ, BK]
     # dv += p^T @ do
@@ -352,8 +360,8 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     s = _mask(s, iq=iq, ik=ik, causal=causal,
-              seg_q=sq_ref[0] if has_segments else None,
-              seg_k=sk_ref[0] if has_segments else None,
+              seg_q=sq_ref[0][:, :1] if has_segments else None,
+              seg_k=sk_ref[0][:1, :] if has_segments else None,
               block_q=block_q, block_k=block_k)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
@@ -405,10 +413,10 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
     dkdv_args = [q, k, v, do, lse, delta]
     if has_segments:
         dkdv_in_specs += [
-            pl.BlockSpec((1, block_q),
-                         lambda b, t, m: (b // hkv, d_kv(t)[0])),
-            pl.BlockSpec((1, block_k),
-                         lambda b, t, m: (b // hkv, d_kv(t)[1])),
+            pl.BlockSpec((1, block_q, STAT_LANES),
+                         lambda b, t, m: (b // hkv, d_kv(t)[0], 0)),
+            pl.BlockSpec((1, SEG_SUBLANES, block_k),
+                         lambda b, t, m: (b // hkv, 0, d_kv(t)[1])),
         ]
         dkdv_args += [seg_q, seg_k]
     dkdv = pl.pallas_call(
@@ -431,7 +439,7 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
             jax.ShapeDtypeStruct((BHkv, S, D), k.dtype),
             jax.ShapeDtypeStruct((BHkv, S, D), v.dtype),
         ],
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
     )(*dkdv_args)
@@ -459,8 +467,10 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
     dq_args = [q, k, v, do, lse, delta]
     if has_segments:
         dq_in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, t: (b // hq, d_q(t)[0])),
-            pl.BlockSpec((1, block_k), lambda b, t: (b // hq, d_q(t)[1])),
+            pl.BlockSpec((1, block_q, STAT_LANES),
+                         lambda b, t: (b // hq, d_q(t)[0], 0)),
+            pl.BlockSpec((1, SEG_SUBLANES, block_k),
+                         lambda b, t: (b // hq, 0, d_q(t)[1])),
         ]
         dq_args += [seg_q, seg_k]
     dq = pl.pallas_call(
@@ -473,7 +483,7 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
                                lambda b, t: (b, d_q(t)[0], 0)),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((BHq, S, D), q.dtype),
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(*dq_args)
@@ -558,6 +568,8 @@ def flash_attention(q, k, v, causal: bool = True,
         # distinct pad values so padded q rows match nothing at all
         seg_q = jnp.pad(seg, ((0, 0), (0, Sp - S)), constant_values=-2)
         seg_k = jnp.pad(seg, ((0, 0), (0, Sp - S)), constant_values=-1)
+        seg_q = jnp.broadcast_to(seg_q[:, :, None], (B, Sp, STAT_LANES))
+        seg_k = jnp.broadcast_to(seg_k[:, None, :], (B, SEG_SUBLANES, Sp))
 
     o = _flash(prep(q), prep(k), prep(v), seg_q, seg_k,
                causal, Nq, Nkv, bq, bk)

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from deepspeed_tpu.utils import jaxcompat
 
 
 def _interpret() -> bool:
@@ -198,7 +197,7 @@ def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
     )(*meta, lhs, rhs)
@@ -263,7 +262,7 @@ def _tgmm_call(lhs: jax.Array, dout: jax.Array, group_sizes: jax.Array,
             scratch_shapes=[pltpu.VMEM((block_k, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_groups, kdim, n), lhs.dtype),
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*meta, lhs, dout)

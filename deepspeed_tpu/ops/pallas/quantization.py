@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from deepspeed_tpu.utils import jaxcompat
 
 DEFAULT_BLOCK = 256
 
@@ -103,13 +102,16 @@ def quantize_blockwise(x: jax.Array, bits: int = 8,
         q, s = _quantize_ref(x2, bits, block)
         return (q.reshape(orig_shape),
                 s.reshape(*orig_shape[:-1], n // block))
+    tile = min(rows, 256)
     q, s = pl.pallas_call(
         functools.partial(_quant_kernel, bits=bits, block=block),
-        grid=(max(1, rows // 256),),
-        in_specs=[pl.BlockSpec((min(rows, 256), block), lambda i: (i, 0))],
+        # cdiv: a row count off the 256 grid keeps its tail rows (the
+        # last tile is partial)
+        grid=(pl.cdiv(rows, tile),),
+        in_specs=[pl.BlockSpec((tile, block), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((min(rows, 256), block), lambda i: (i, 0)),
-            pl.BlockSpec((min(rows, 256), 128), lambda i: (i, 0)),
+            pl.BlockSpec((tile, block), lambda i: (i, 0)),
+            pl.BlockSpec((tile, 128), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, block), jnp.int8),
@@ -220,7 +222,7 @@ def quantized_psum_scatter(x: jax.Array, axis: str, bits: int = 8,
     local reduce (reference all_to_all_quant_reduce,
     runtime/comm/coalesced_collectives.py:31). Inside shard_map; scatters
     dim 0. Returns the mean-reduced shard in x.dtype."""
-    n = jaxcompat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     shard = x.shape[0] // n
     q, s = quantize_blockwise(x, bits=bits, block=block)
     if bits == 4:
@@ -250,7 +252,7 @@ def quantized_all_reduce(x: jax.Array, axis: str, bits: int = 8,
     Pads dim 0 to a multiple of the axis size so arbitrary leading shapes
     reduce-scatter cleanly; padding is stripped after the gather.
     """
-    n = jaxcompat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     d0 = x.shape[0]
     pad = (-d0) % n
     xp = x if pad == 0 else jnp.concatenate(

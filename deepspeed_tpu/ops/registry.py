@@ -118,9 +118,9 @@ def _tpu_probe() -> Tuple[bool, str]:
     backend = jax.default_backend()
     if backend == "tpu":
         return True, ""
-    interp = True  # pallas interpreter mode works on cpu
-    return (interp, f"backend={backend}: runs in Pallas interpreter mode "
-                    "(slow; numerics-equivalent)")
+    # callable for CPU tests, but never a device kernel: say so
+    return True, (f"interpret-only: backend={backend} has no device "
+                  "kernel (Pallas interpreter, for tests)")
 
 
 _BUILTIN_LOADED = False

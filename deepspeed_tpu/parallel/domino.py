@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel import topology
 from deepspeed_tpu.utils.comms_logging import get_comms_logger
-from deepspeed_tpu.utils import jaxcompat
 
 BATCH_SPEC = P(("dp", "fsdp", "ep"))
 
@@ -129,7 +128,7 @@ def domino_transformer_layer(params, x, *, num_heads: int,
         log_name="domino_layer_allreduce")
     wspecs = {"wqkv": P(None, tp_axis), "wo": P(tp_axis, None),
               "w1": P(None, tp_axis), "w2": P(tp_axis, None)}
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_local_layer, num_heads=num_heads,
                           num_chunks=num_chunks, causal=causal,
                           tp_axis=tp_axis),

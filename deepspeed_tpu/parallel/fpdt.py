@@ -382,8 +382,7 @@ def fpdt_attention_block(y, ap, positions, *, num_heads: int,
     to host, and streams them through its local q chunks with
     shard-offset query positions. ``sp_size`` must be the static degree
     of ``sp_axis`` (the global valid length S·p is a nondiff argument of
-    the streaming kernel, so it cannot be derived from a traced
-    ``axis_size`` on older jax).
+    the streaming kernel).
     """
     if sp_axis is not None and hosted:
         raise ValueError("fpdt sp composition does not support the "

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from deepspeed_tpu.runtime.sharding import constrain_activation
-from deepspeed_tpu.utils import jaxcompat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -557,11 +556,11 @@ def moe_ffn_dropless(x: jax.Array, router_w: jax.Array,
         # nested inside a partial-manual region (the pipeline stage body
         # is manual over pp): shard_map must take the context abstract
         # mesh and may only manualize the axes still under GSPMD
-        sm_mesh = jaxcompat.get_abstract_mesh(fallback=mesh)
+        sm_mesh = jax.sharding.get_abstract_mesh()
     else:
         sm_mesh = mesh
     names = frozenset(a for a in mesh.axis_names if a not in manual)
-    out, stats_sh = jaxcompat.shard_map(
+    out, stats_sh = jax.shard_map(
         local_fn, mesh=sm_mesh,
         in_specs=(x_spec, P(), exp_specs),
         out_specs=(x_spec, stat_spec), axis_names=names, check_vma=False,

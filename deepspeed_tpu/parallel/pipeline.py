@@ -46,7 +46,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.parallel import topology as topo
-from deepspeed_tpu.utils import jaxcompat
 
 
 def pipeline_enabled(mesh: Optional[Mesh]) -> bool:
@@ -215,7 +214,7 @@ def pipelined_layers(layer_fn: Callable, stacked_params: Any, x: jax.Array,
     # as the ZeRO++ dp region, runtime/zeropp.py:116), so fsdp/tp/sp
     # sharding and quantized gathers compose with pipeline stages
     with manual_axes({"pp"}), ctx2:
-        out, aux = jaxcompat.shard_map(
+        out, aux = jax.shard_map(
             per_stage,
             mesh=mesh,
             in_specs=(param_specs, P()),

@@ -36,7 +36,6 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.comm import comm
 from deepspeed_tpu.parallel import topology
-from deepspeed_tpu.utils import jaxcompat
 
 BATCH = ("dp", "fsdp", "ep")
 
@@ -51,7 +50,7 @@ def _ring_attn_local(q, k, v, seg, *, axis: str, causal: bool,
     from deepspeed_tpu.parallel._blockwise import (
         block_attn_partial, finalize, init_accumulators, online_merge)
 
-    p_size = jaxcompat.axis_size(axis)
+    p_size = jax.lax.axis_size(axis)
     my_idx = lax.axis_index(axis)
     s_loc = q.shape[1]
     q_pos = my_idx * s_loc + jnp.arange(s_loc)
@@ -141,7 +140,7 @@ def ring_attention(q, k, v, causal: bool = True, axis: str = "sp",
     batch_axes = tuple(a for a in BATCH if a in mesh.shape)
     spec = P(batch_axes, axis, "tp" if "tp" in mesh.shape else None, None)
     seg_spec = P(batch_axes, None if seg.shape[1] == 0 else axis)
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         partial(_ring_attn_local, axis=axis, causal=causal, s_global=S),
         mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
         check_vma=False)

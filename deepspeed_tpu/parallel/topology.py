@@ -30,7 +30,7 @@ import math
 from typing import Dict, Optional, Sequence
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deepspeed_tpu.utils.logging import logger
@@ -86,8 +86,7 @@ def build_mesh(
 
     Devices are laid out so the innermost axes (tp, sp) map to adjacent
     devices. On real TPU slices ``jax.devices()`` order already follows the
-    torus; ``mesh_utils.create_device_mesh`` improves ICI contiguity when
-    available.
+    torus; ``mesh_utils.create_device_mesh`` improves ICI contiguity.
     """
     if devices is None:
         devices = jax.devices()
@@ -102,15 +101,10 @@ def build_mesh(
         topo = TopologyConfig(**topo)
     sizes = topo.sizes(len(devices))
     shape = tuple(sizes[a] for a in MESH_AXES)
-    try:
-        from jax.experimental import mesh_utils
-
-        device_array = mesh_utils.create_device_mesh(
-            shape, devices=list(devices), allow_split_physical_axes=True
-        )
-    except Exception as e:  # CPU-sim or odd shapes: fall back to row-major
-        logger.debug(f"mesh_utils.create_device_mesh failed ({e}); using reshape")
-        device_array = np.asarray(list(devices)).reshape(shape)
+    # topology-aware on a TPU slice (ICI-contiguous inner axes), row-major
+    # on the CPU simulator; a device set it cannot lay out is an error
+    device_array = mesh_utils.create_device_mesh(
+        shape, devices=list(devices), allow_split_physical_axes=True)
     mesh = Mesh(device_array, MESH_AXES)
     logger.info(
         "mesh: "

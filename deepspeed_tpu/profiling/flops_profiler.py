@@ -95,8 +95,6 @@ def profile_compiled(fn: Callable, *args, static_argnums=(),
     lowered = jitted.lower(*args, **kwargs)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     out = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

@@ -109,16 +109,10 @@ def resolve_policy(name: Optional[str] = None,
         names = canonical[1]
         if cpu_checkpointing or _GLOBAL_CONFIG.get("cpu_checkpointing"):
             # honor the host-offload request for named saves too
-            offload = getattr(jax.checkpoint_policies,
-                              "save_and_offload_only_these_names", None)
-            if offload is not None:
-                return offload(names_which_can_be_saved=[],
-                               names_which_can_be_offloaded=list(names),
-                               offload_src="device",
-                               offload_dst="pinned_host")
-            logger.warning(
-                "cpu_checkpointing requested but this JAX lacks "
-                "save_and_offload_only_these_names; named saves stay in HBM")
+            return jax.checkpoint_policies.save_and_offload_only_these_names(
+                names_which_can_be_saved=[],
+                names_which_can_be_offloaded=list(names),
+                offload_src="device", offload_dst="pinned_host")
         return jax.checkpoint_policies.save_only_these_names(*names)
     if cpu_checkpointing or _GLOBAL_CONFIG.get("cpu_checkpointing"):
         canonical = "offload_dots_host"

@@ -50,6 +50,7 @@ from deepspeed_tpu.runtime.optimizer import (
     init_mixed_precision)
 from deepspeed_tpu.runtime.prefetch import PrefetchingIterator
 from deepspeed_tpu.utils import memspace
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
     BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
@@ -83,6 +84,7 @@ def initialize(
     if config is None and args is not None:
         config = getattr(args, "deepspeed_config", None)
 
+    enable_compile_cache()
     comm.init_distributed(dist_init_required=dist_init_required)
     engine = Engine(
         model=model,
@@ -706,7 +708,7 @@ class Engine:
             out_sh = (param_sh, OneBitState(master=master_sh, m=master_sh,
                                             v=master_sh, error=err_sh,
                                             step=rep))
-            with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else _nullctx():
+            with jax.set_mesh(mesh):
                 self.params, self._onebit_state = jax.jit(
                     init_fn, out_shardings=out_sh)(self._rng)
             self.opt_state = None
@@ -737,7 +739,7 @@ class Engine:
             master_sh = jax.tree.map(lambda _: sh, param_sh)
             out_sh = (param_sh, ZeroppState(master=master_sh, m=master_sh,
                                             v=master_sh, step=rep))
-            with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else _nullctx():
+            with jax.set_mesh(mesh):
                 self.params, self._zeropp_state = jax.jit(
                     init_fn, out_shardings=out_sh)(self._rng)
             self.opt_state = None
@@ -760,7 +762,7 @@ class Engine:
                 lambda s: memspace.with_memory_kind(s, "pinned_host"),
                 opt_sh)
                 if host_init else opt_sh)
-            with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else _nullctx():
+            with jax.set_mesh(mesh):
                 p32 = jax.jit(init32, out_shardings=out_sh)(self._rng)
             if not host_init:
                 def _pin(a):
@@ -829,12 +831,12 @@ class Engine:
             def init_fn(rng):
                 p32 = self.model.init(rng)
                 p32 = _constrain_tree(p32, opt_sh)
-                mp = init_mixed_precision(p32, self.tx)
+                mp = init_mixed_precision(p32, self.tx, shardings=opt_sh)
                 params = jax.tree.map(lambda m: m.astype(cdt), mp.master)
                 params = _constrain_tree(params, param_sh)
                 return params, mp
 
-            with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else _nullctx():
+            with jax.set_mesh(mesh):
                 self.params, self.opt_state = jax.jit(init_fn)(self._rng)
         self._param_shardings = param_sh
         self._opt_shardings = opt_sh
@@ -1756,8 +1758,11 @@ class Engine:
             if tps_chip:
                 fpt = self._model_flops_per_token()
                 if fpt:
-                    peak = _rl.detect_peak_tflops(jax.devices()[0])
-                    mfu_val = _rl.mfu(tps_chip, fpt, peak)
+                    try:
+                        peak = _rl.detect_peak_tflops(jax.devices()[0])
+                        mfu_val = _rl.mfu(tps_chip, fpt, peak)
+                    except _rl.UnknownDeviceError:
+                        pass  # no chip peak known: MFU is not measured
 
             def _f(key):
                 v = metrics.get(key)
@@ -2161,10 +2166,3 @@ def _constrain_tree(tree, shardings):
     return jax.tree.map(
         lambda x, s: jax.lax.with_sharding_constraint(x, s), tree, shardings)
 
-
-class _nullctx:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False

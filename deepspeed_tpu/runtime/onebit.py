@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.utils.logging import log_dist
-from deepspeed_tpu.utils import jaxcompat
 
 ONEBIT_OPTIMIZERS = ("onebitadam", "zerooneadam", "onebitlamb")
 
@@ -171,7 +170,7 @@ def build_onebit_step(model, mesh, cfg, opt: Dict, param_shardings,
         err_specs = jax.tree.map(lambda _: P("dp"), state.error)
         batch_specs = jax.tree.map(lambda _: batch_spec, batches)
 
-        sm = jaxcompat.shard_map(
+        sm = jax.shard_map(
             partial(local_grads),
             mesh=mesh, axis_names={"dp"},
             in_specs=(rep, batch_specs, rep, err_specs, rep),

@@ -104,10 +104,20 @@ class MixedPrecisionState(NamedTuple):
     inner: Any  # optax state (same sharding as master)
 
 
-def init_mixed_precision(params_fp32, tx: optax.GradientTransformation
-                         ) -> MixedPrecisionState:
+def init_mixed_precision(params_fp32, tx: optax.GradientTransformation,
+                         shardings=None) -> MixedPrecisionState:
+    """fp32 masters + fresh inner state. ``shardings`` (a tree shaped
+    like the params) pins every param-shaped leaf of the inner state:
+    the moments are zeros with no data dependence on the sharded
+    masters, so left alone XLA materializes them whole on every device
+    (8 of ZeRO's 12 state bytes per parameter) and the first train step
+    compiles twice, once for that layout and once for the sharded one."""
     master = jax.tree.map(lambda p: p.astype(jnp.float32), params_fp32)
-    return MixedPrecisionState(master=master, inner=tx.init(master))
+    inner = tx.init(master)
+    if shardings is not None:
+        inner = optax.tree_utils.tree_map_params(
+            tx, jax.lax.with_sharding_constraint, inner, shardings)
+    return MixedPrecisionState(master=master, inner=inner)
 
 
 def apply_mixed_precision_update(

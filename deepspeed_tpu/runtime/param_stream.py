@@ -64,8 +64,7 @@ def pin_stage(anchor: Any, pinned: Any):
     that XLA's own latency-hiding pass does NOT keep these copies off
     the critical path in the default scan schedule on v5e-1; pinning
     the issue order into the program is the control that works on every
-    backend. No differentiation rule exists for the barrier on jax
-    0.4.x, so callers must keep it inside custom-VJP fwd/bwd bodies
+    backend. Callers keep it inside custom-VJP fwd/bwd bodies
     (streamed_layers_prefetch does), never in a differentiated trace.
     """
     return lax.optimization_barrier((anchor, pinned))

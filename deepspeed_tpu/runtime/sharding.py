@@ -44,7 +44,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deepspeed_tpu.config.config import Config
 from deepspeed_tpu.utils.logging import warning_once
-from deepspeed_tpu.utils import jaxcompat
 
 # Logical axis vocabulary used by the model zoo (models/layers.py).
 LOGICAL_AXES = (
@@ -613,7 +612,7 @@ def vocab_parallel_lookup(table, ids, axis: str = "tp"):
     # clamp like XLA's gather does, so out-of-range ids embed to the same
     # row with or without tp instead of silently zeroing under tp
     ids = jnp.clip(ids, 0, V - 1)
-    out = jaxcompat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(PartitionSpec(axis), PartitionSpec()),
         out_specs=PartitionSpec(),

@@ -34,7 +34,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.utils.logging import log_dist
-from deepspeed_tpu.utils import jaxcompat
 
 QUANT_BLOCK = 256
 
@@ -148,7 +147,7 @@ def build_zeropp_step(model, mesh, gas: int, base_lr: float,
                 # collective's internal padding never triggers.
                 full = quantized_all_reduce(flat, "dp", bits=qar_bits,
                                             block=QUANT_BLOCK)
-                rows = flat.shape[0] // jaxcompat.axis_size("dp")
+                rows = flat.shape[0] // jax.lax.axis_size("dp")
                 red = lax.dynamic_slice_in_dim(
                     full, lax.axis_index("dp") * rows, rows, axis=0)
             elif qg_enabled:
@@ -156,7 +155,7 @@ def build_zeropp_step(model, mesh, gas: int, base_lr: float,
                                              block=QUANT_BLOCK)
             else:  # qwZ-only config: exact (unquantized) grad reduce
                 red = lax.psum_scatter(flat, "dp", scatter_dimension=0,
-                                       tiled=True) / jaxcompat.axis_size("dp")
+                                       tiled=True) / jax.lax.axis_size("dp")
             g_shards.append(red.reshape(-1))
 
         sq = sum(jnp.sum(gs.astype(jnp.float32) ** 2) for gs in g_shards)
@@ -204,7 +203,7 @@ def build_zeropp_step(model, mesh, gas: int, base_lr: float,
     rep = P()
     shard_spec = P("dp")
 
-    mapped = jaxcompat.shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, shard_spec, shard_spec, shard_spec, rep, rep,
                   batch_spec),

@@ -97,7 +97,9 @@ def _resolve_dtypes(d: Dict[str, Any]) -> Dict[str, Any]:
 
 def build_replica(spec: Dict[str, Any]):
     """Model + params + ServingReplica from the spec — deterministic:
-    same spec seed => bit-identical params in every process."""
+    same spec seed => bit-identical params in every process. A worker
+    is one replica on one device: the first this process can see (the
+    supervisor leaves a tpu worker exactly one chip visible)."""
     import jax
     import jax.numpy as jnp  # noqa: F401  (dtype resolution)
 
@@ -111,7 +113,8 @@ def build_replica(spec: Dict[str, Any]):
     engine_kw = _resolve_dtypes(spec.get("engine") or {})
     return ServingReplica.create(
         model, int(spec["replica_id"]), role=spec.get("role", "unified"),
-        run_dir=spec.get("run_dir"), params=params, **engine_kw)
+        run_dir=spec.get("run_dir"), device=jax.devices()[0],
+        params=params, **engine_kw)
 
 
 def open_channel(spec: Dict[str, Any]):
@@ -122,7 +125,13 @@ def open_channel(spec: Dict[str, Any]):
 
     max_frame = int(spec.get("max_frame_mb", 64)) << 20
     kind = spec.get("channel", "socket")
-    ready = {"pid": os.getpid(), "channel": kind, "port": None}
+    import jax
+
+    # the worker's backend is up (build_replica ran): name what it got
+    devs = jax.devices()
+    ready = {"pid": os.getpid(), "channel": kind, "port": None,
+             "device": {"platform": devs[0].platform,
+                        "kind": devs[0].device_kind, "count": len(devs)}}
     if kind == "socket":
         srv = SocketServer(max_frame_bytes=max_frame)
         ready["port"] = srv.port
@@ -409,10 +418,10 @@ def main(argv: Optional[list] = None) -> int:
         return 2
     with open(argv[0]) as f:
         spec = json.load(f)
-    # the spec pins the platform before jax import — fleet workers are
-    # host processes; the accelerator belongs to the engine they host
-    os.environ.setdefault("JAX_PLATFORMS",
-                          spec.get("jax_platform", "cpu"))
+    # the spec decides the platform, before jax is imported: not an
+    # inherited variable and not a default — a worker that was told tpu
+    # and cannot have it fails instead of serving from the CPU
+    os.environ["JAX_PLATFORMS"] = spec["jax_platform"]
     return WorkerLoop(spec).run()
 
 

@@ -98,13 +98,27 @@ class ServingReplica:
 
     @classmethod
     def create(cls, model, replica_id: int, role: str = "unified",
-               run_dir: Optional[str] = None, **engine_kw
+               run_dir: Optional[str] = None, device=None, **engine_kw
                ) -> "ServingReplica":
         """Build the replica AND its engine, injecting the per-replica
         metric labels and (when a run dir is given) the fleet-layer
-        load-report publisher."""
+        load-report publisher. ``device`` is the one device this replica
+        owns (its engine runs on a one-device mesh over it); a replica
+        that spans devices passes ``mesh`` in ``engine_kw`` instead.
+        With neither, the engine's own default applies — a mesh over
+        every device of the process — which is right only for a process
+        that hosts a single replica."""
         from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
 
+        if device is not None:
+            if engine_kw.get("mesh") is not None:
+                raise ValueError("pass a replica its device or its mesh, "
+                                 "not both")
+            from deepspeed_tpu.parallel.topology import (TopologyConfig,
+                                                         build_mesh)
+
+            engine_kw["mesh"] = build_mesh(TopologyConfig(),
+                                           devices=[device])
         engine_kw.setdefault("metric_labels",
                              {"replica": f"r{int(replica_id)}"})
         engine = InferenceEngineV2(model, **engine_kw)

@@ -93,17 +93,33 @@ from deepspeed_tpu.serving.replica import ServingReplica, Submission
 
 def build_fleet(model, router_cfg=None, engine_kw=None,
                 run_dir: Optional[str] = None,
-                eos_token_id: Optional[int] = None) -> "FleetRouter":
+                eos_token_id: Optional[int] = None,
+                devices=None) -> "FleetRouter":
     """Construct replicas + router from a ``serving.router`` config
     block (config.RouterConfig or any object with its fields; None uses
     the defaults). ``engine_kw`` is forwarded to every replica's
     engine constructor — pass shared ``params`` so the fleet serves one
-    model, not N random inits."""
+    model, not N random inits.
+
+    Each in-process replica owns ONE device: replica ``i`` runs on
+    ``devices[i % len(devices)]`` (default: this process's local
+    devices), so four replicas on a four-chip host are four one-chip
+    engines, not four engines each spread over all four chips. A fleet
+    of multi-device replicas passes ``mesh`` in ``engine_kw`` instead
+    (every replica then runs on that mesh)."""
     from deepspeed_tpu.config.config import RouterConfig
     from deepspeed_tpu.serving.autoscale import AutoscaleSignal
 
     cfg = router_cfg if router_cfg is not None else RouterConfig()
     engine_kw = dict(engine_kw or {})
+    if engine_kw.get("mesh") is not None:
+        if devices is not None:
+            raise ValueError("pass build_fleet devices or a shared "
+                             "engine mesh, not both")
+    elif devices is None:
+        import jax
+
+        devices = jax.local_devices()
     n = int(cfg.replicas)
     n_prefill = int(cfg.prefill_replicas) if cfg.mode == "disagg" else 0
     replicas = []
@@ -111,7 +127,9 @@ def build_fleet(model, router_cfg=None, engine_kw=None,
         role = "unified" if cfg.mode == "unified" else (
             "prefill" if i < n_prefill else "decode")
         replicas.append(ServingReplica.create(
-            model, i, role=role, run_dir=run_dir, **engine_kw))
+            model, i, role=role, run_dir=run_dir,
+            device=devices[i % len(devices)] if devices else None,
+            **engine_kw))
     from deepspeed_tpu.observability.hub import get_hub
 
     autoscale = AutoscaleSignal(

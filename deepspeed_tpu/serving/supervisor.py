@@ -62,7 +62,7 @@ import subprocess
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -465,7 +465,6 @@ class ReplicaSupervisor:
                  connect_backoff_s: float = 0.05,
                  spawn_timeout_s: float = 60.0,
                  default_role: str = "unified",
-                 jax_platform: str = "cpu",
                  python: Optional[str] = None,
                  connect_policy=None,
                  restart_policy=None,
@@ -474,7 +473,22 @@ class ReplicaSupervisor:
                  min_healthy: int = 1,
                  clock_sync: bool = True,
                  clock_sync_rounds: int = 8,
-                 clock_resync_s: float = 5.0):
+                 clock_resync_s: float = 5.0,
+                 *, jax_platform: str,
+                 tpu_chips: Sequence[int] = ()):
+        """``jax_platform`` is the backend every worker must come up on
+        ("cpu" | "tpu"); the caller names it — there is no default to
+        fall back to. With "tpu", ``tpu_chips`` lists the host's chip
+        indices this fleet may use: each live worker owns exactly one
+        of them (the only chip its process can see), and this process
+        itself stays off JAX, since a parent that holds the chips
+        starves its children."""
+        if jax_platform not in ("cpu", "tpu"):
+            raise ValueError(
+                f"jax_platform must be cpu|tpu, got {jax_platform!r}")
+        if jax_platform == "tpu" and not tpu_chips:
+            raise ValueError("jax_platform='tpu' needs tpu_chips: the "
+                             "chip indices the workers may own")
         if channel not in ("socket", "file"):
             raise ValueError(
                 f"channel must be socket|file, got {channel!r}")
@@ -491,6 +505,8 @@ class ReplicaSupervisor:
         self.spawn_timeout_s = float(spawn_timeout_s)
         self.default_role = default_role
         self.jax_platform = jax_platform
+        self._free_chips: List[int] = [int(c) for c in tpu_chips]
+        self._chip_of: Dict[int, int] = {}  # live tpu worker -> chip
         self.python = python or sys.executable
         self.router = None  # attach after building FleetRouter
         self.replicas: Dict[int, RemoteReplica] = {}
@@ -569,6 +585,34 @@ class ReplicaSupervisor:
             jr.decision("SUPERVISOR", ts=now, action=action,
                         replica=replica_id, **fields)
 
+    def _chip_env(self, rid: int) -> Dict[str, str]:
+        """A tpu worker's view of the host: exactly one chip. libtpu
+        reads these at start-up; each worker is a one-chip "slice" of
+        its own with its own controller port, so workers neither see
+        nor wait for one another."""
+        if self.jax_platform != "tpu":
+            return {}
+        self._reap_chips()
+        if not self._free_chips:
+            raise RuntimeError(
+                f"no free chip for replica {rid}: every one of this "
+                "fleet's tpu_chips is owned by a live worker")
+        chip = self._chip_of[rid] = self._free_chips.pop(0)
+        port = 8476 + chip
+        return {"TPU_VISIBLE_CHIPS": str(chip),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+                "TPU_MESH_CONTROLLER_PORT": str(port)}
+
+    def _reap_chips(self) -> None:
+        """Return the chips of workers whose process has exited."""
+        for rid, chip in list(self._chip_of.items()):
+            proc = self._procs.get(rid)
+            if proc is None or proc.poll() is not None:
+                del self._chip_of[rid]
+                self._free_chips.append(chip)
+
     # -- spawn ---------------------------------------------------------
     def spawn(self, role: Optional[str] = None,
               replica_id: Optional[int] = None,
@@ -602,6 +646,7 @@ class ReplicaSupervisor:
         _atomic_write_json(spec_path, spec)
         env = dict(os.environ)
         env.update(env_extra or {})
+        env.update(self._chip_env(rid))
         log_path = os.path.join(self.run_dir, "logs",
                                 f"replica_{rid}.log")
         log = open(log_path, "ab")
@@ -879,6 +924,13 @@ class ReplicaSupervisor:
         from deepspeed_tpu.observability.journal import chain_tokens
         from deepspeed_tpu.serving.proc_worker import build_replica
 
+        if self.jax_platform == "tpu":
+            # building a replica here would take the chips from the
+            # workers this process supervises
+            raise RuntimeError(
+                "the supervisor of tpu workers stays off JAX: pass "
+                "canary_chains (decoded by a worker or an earlier "
+                "release) instead of computing them in this process")
         rep = build_replica({"replica_id": 9_999, "role": "unified",
                              "model": self.model, "engine": self.engine,
                              "seed": int(self.seed if seed is None

@@ -100,15 +100,13 @@ def program_costs(compiled) -> Dict[str, float]:
     Combines XLA's cost analysis (flops / bytes accessed /
     transcendentals — the roofline inputs) with this module's
     collective wire-byte accounting over the compiled HLO text. Any
-    piece that a given jax version can't produce is reported as 0.0
-    rather than raising, so callers can always roofline what they have.
+    piece that a backend can't produce is reported as 0.0 rather than
+    raising, so callers can always roofline what they have.
     """
     out = {"flops": 0.0, "bytes_accessed": 0.0, "transcendentals": 0.0,
            "collective_bytes": 0.0}
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax wraps in a list
-            cost = cost[0] if cost else {}
+        cost = compiled.cost_analysis() or {}
         out["flops"] = float(cost.get("flops", 0.0))
         out["bytes_accessed"] = float(cost.get("bytes accessed", 0.0))
         out["transcendentals"] = float(cost.get("transcendentals", 0.0))

@@ -28,22 +28,10 @@ import jax
 
 def _inside_trace() -> bool:
     """True while a jit/scan/grad trace is being staged."""
-    try:
-        from jax._src.core import trace_state_clean
-
-        return not trace_state_clean()
-    except Exception:  # private API moved: compare opaque trace state
-        try:
-            return (jax.core.get_opaque_trace_state()
-                    != _EAGER_TRACE_STATE)
-        except Exception:
-            return False
+    return jax.core.get_opaque_trace_state() != _EAGER_TRACE_STATE
 
 
-try:
-    _EAGER_TRACE_STATE = jax.core.get_opaque_trace_state()
-except Exception:  # pragma: no cover
-    _EAGER_TRACE_STATE = None
+_EAGER_TRACE_STATE = jax.core.get_opaque_trace_state()
 
 
 class OnDevice:

@@ -1,21 +1,16 @@
 """Backend-portable memory-space placement.
 
-The offload tier talks to XLA memory spaces through two jax APIs that
-drift across versions and backends:
+The offload tier talks to XLA memory spaces through two jax APIs:
 
-  * ``jax.memory.Space.Device`` / ``.Host`` — added in jax 0.5; older
-    jax spells the same transfer ``TransferToMemoryKind("pinned_host")``
-    (still importable from ``jax._src.sharding_impls``).
+  * ``jax.memory.Space.Device`` / ``.Host`` as ``device_put`` targets;
   * ``Sharding.with_memory_kind("pinned_host" | "device")`` — raises on
-    backends whose devices expose no such space. The CPU simulator is
-    the important case: its only addressable memory is ``unpinned_host``,
-    where host/device distinction is physically moot — every placement
-    lands in the same DRAM, so degrading to the array's existing
-    placement preserves the exact numerics the tests assert on.
+    backends whose devices expose no such space; there every placement
+    lands in the same memory, so degrading to the array's existing
+    placement preserves the exact numerics.
 
 Every memory-space placement in the tree goes through this module so
-the TPU fast path and the CPU test path share one degradation policy
-instead of per-call-site try/excepts.
+the TPU path and the CPU test path share one policy instead of
+per-call-site try/excepts.
 """
 
 from __future__ import annotations
@@ -49,12 +44,8 @@ def space(kind: str) -> Optional[Any]:
     assert kind in _PLACEABLE, kind
     if not memories_supported():
         return None
-    mem = getattr(jax, "memory", None)
-    if mem is not None:
-        return mem.Space.Device if kind == "device" else mem.Space.Host
-    from jax._src.sharding_impls import TransferToMemoryKind
-
-    return TransferToMemoryKind(kind)
+    return (jax.memory.Space.Device if kind == "device"
+            else jax.memory.Space.Host)
 
 
 def put(a: Any, kind: str) -> Any:

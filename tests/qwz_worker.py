@@ -26,10 +26,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # older jax: XLA_FLAGS above already set 8 devices
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np  # noqa: E402
 

@@ -17,6 +17,10 @@ BASE = {
 }
 
 
+# the CPU simulator reports no memory limit: the budget is passed in
+HBM = 16 * 2**30
+
+
 def batch_fn(global_batch):
     rng = np.random.default_rng(0)
     return {"input_ids": rng.integers(0, 64, (global_batch, 16)
@@ -26,7 +30,8 @@ def batch_fn(global_batch):
 def make_tuner(tmp_path, space):
     return Autotuner(model_factory=lambda: TransformerLM(TINY),
                      base_config=dict(BASE), batch_fn=batch_fn,
-                     tuning_space=space, results_dir=str(tmp_path))
+                     tuning_space=space, hbm_budget_bytes=HBM,
+                     results_dir=str(tmp_path))
 
 
 def test_candidates_enumeration(tmp_path):
@@ -83,6 +88,7 @@ def test_cli_fast_mode(capsys, devices):
     from deepspeed_tpu.autotuning.autotuner import main
 
     rc = main(["--model", "tiny", "--seq", "32", "--fast",
+               "--hbm-budget-gb", "16",
                "--micro-batch-sizes", "1", "--zero-stages", "1"])
     assert rc == 0
     best = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
@@ -130,7 +136,7 @@ def test_fast_tune_persists_winner(tmp_path, devices):
                   tuning_space={"micro_batch_sizes": [2],
                                 "zero_stages": [1],
                                 "prefetch_depths": [2]},
-                  results_dir=str(tmp_path),
+                  hbm_budget_bytes=HBM, results_dir=str(tmp_path),
                   persist_path=str(persist))
     best = t.tune(fast=True)
     assert best is not None

@@ -105,8 +105,10 @@ def test_longctx_bench_tier_resolves():
 def test_longctx_bench_report_emits_three_regions():
     from bench import longctx_bench_report
 
-    table, payload = longctx_bench_report(env={"BENCH_SEQ": "262144",
-                                               "BENCH_SP": "4"})
+    table, payload = longctx_bench_report(env={
+        "BENCH_SEQ": "262144", "BENCH_SP": "4",
+        # an analytic model of a chip: its peaks are named (v5e)
+        "BENCH_PEAK_TFLOPS": "197", "BENCH_HBM_GBPS": "819"})
     assert "| attn |" in table and "| sp_comm |" in table
     assert "| host_kv_stream |" in table
     assert payload["unit"] == "modeled exposed ms/step"

@@ -75,6 +75,11 @@ def test_stage3_params_sharded(devices):
     m = engine.opt_state.master["layers"]["attn"]["wq"]
     assert m.addressable_shards[0].data.shape[1] == m.shape[1] // 8
     assert m.dtype == jnp.float32
+    # and so are the Adam moments, from init on — not whole on every
+    # device until the first step reshards them
+    for leaf in jax.tree.leaves(engine.opt_state.inner):
+        if leaf.shape == m.shape:
+            assert leaf.addressable_shards[0].data.size == leaf.size // 8
 
 
 def test_stage1_params_replicated_opt_sharded(devices):

@@ -55,7 +55,8 @@ def skewed_fleet(tmp_path_factory):
     """One +/-250 ms two-worker fleet, driven to drained once; every
     test reads the same aftermath (the drill is the expensive part)."""
     run_dir = tmp_path_factory.mktemp("skewed_fleet")
-    sup = ReplicaSupervisor(str(run_dir), model=MODEL_SPEC,
+    sup = ReplicaSupervisor(str(run_dir), jax_platform="cpu",
+                            model=MODEL_SPEC,
                             engine=dict(ENGINE_SPEC), seed=0)
     skews = {}
     remotes = []

@@ -369,7 +369,8 @@ def _install(sup, rid, role="unified", lineage=None):
 def faked_supervisor(tmp_path, monkeypatch):
     """A ReplicaSupervisor whose spawn() installs fakes instead of
     forking — maintain()'s containment logic runs unmodified."""
-    sup = ReplicaSupervisor(str(tmp_path), model={"name": "tiny"},
+    sup = ReplicaSupervisor(str(tmp_path), jax_platform="cpu",
+                            model={"name": "tiny"},
                             max_restarts_per_window=2,
                             restart_window_s=60.0)
     spawned = []
@@ -440,7 +441,8 @@ class TestCrashLoopContainment:
 
 class TestMinHealthyFloor:
     def test_drain_refused_at_the_floor(self, tmp_path):
-        sup = ReplicaSupervisor(str(tmp_path), min_healthy=1)
+        sup = ReplicaSupervisor(str(tmp_path), jax_platform="cpu",
+                                min_healthy=1)
         _install(sup, 0)
         assert sup.drain(0) is False
         assert sup.actions[-1][1] == "drain_refused"
@@ -469,10 +471,12 @@ class TestConnectPolicyKnobs:
 
         monkeypatch.setattr(sup_mod, "_WARNED_LEGACY_CONNECT", False)
         with pytest.warns(DeprecationWarning, match="legacy"):
-            ReplicaSupervisor(str(tmp_path / "a"), connect_retries=10)
+            ReplicaSupervisor(str(tmp_path / "a"), jax_platform="cpu",
+                              connect_retries=10)
         with warnings.catch_warnings():
             warnings.simplefilter("error")  # second time stays silent
-            ReplicaSupervisor(str(tmp_path / "b"), connect_retries=10)
+            ReplicaSupervisor(str(tmp_path / "b"), jax_platform="cpu",
+                              connect_retries=10)
 
     def test_config_validates_new_knobs(self):
         from deepspeed_tpu.config.config import RouterConfig

@@ -73,8 +73,7 @@ def test_bench_diff_flags_lost_kernel_win(tmp_path):
 
     old = {"metric": "kernel_win_ratio_geomean", "unit": "x", "value": 1.8,
            "winning_kernels": ["flash_attention:s2048_d128_causal",
-                               "paged_attention:s2048_d128_causal"],
-           "flash_fallback_ratio": 0.0}
+                               "paged_attention:s2048_d128_causal"]}
     good = diff_reports(old, dict(old, value=1.9))
     assert good["ok"], good["violations"]
 
@@ -84,8 +83,3 @@ def test_bench_diff_flags_lost_kernel_win(tmp_path):
     v = next(v for v in lost["violations"]
              if v["metric"] == "winning_kernels")
     assert v["regressed"] == ["flash_attention:s2048_d128_causal"]
-
-    fell_back = diff_reports(old, dict(old, flash_fallback_ratio=0.5))
-    assert not fell_back["ok"]
-    assert any(v["metric"] == "flash_fallback_ratio"
-               for v in fell_back["violations"])

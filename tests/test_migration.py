@@ -371,7 +371,8 @@ class TestOpsConfig:
 
 
 def _proc_fleet(run_dir, n=2, seed=0):
-    sup = ReplicaSupervisor(str(run_dir), model=MODEL_SPEC,
+    sup = ReplicaSupervisor(str(run_dir), jax_platform="cpu",
+                            model=MODEL_SPEC,
                             engine=dict(ENGINE_SPEC), seed=seed,
                             min_healthy=1)
     remotes = [sup.spawn(role="unified") for _ in range(n)]

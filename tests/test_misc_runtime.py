@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.runtime.eigenvalue import Eigenvalue
-from deepspeed_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from deepspeed_tpu.runtime.progressive_layer_drop import ProgressiveLayerDrop
 from deepspeed_tpu.runtime.sparse_tensor import (SparseTensor,
                                                  sparse_allreduce)

@@ -191,9 +191,12 @@ def _data_iter(batch, seq=16, vocab=64):
 
 
 class TestStepTraceEmission:
-    def test_engine_emits_step_traces(self, devices, tmp_path):
+    def test_engine_emits_step_traces(self, devices, tmp_path,
+                                      monkeypatch):
         import os
 
+        # no peak is known for the CPU simulator: MFU needs one passed in
+        monkeypatch.setenv("BENCH_PEAK_TFLOPS", "1.0")
         jsonl = str(tmp_path / "steps.jsonl")
         engine = _tiny_engine(extra={"observability": {
             "jsonl_path": jsonl,
@@ -266,7 +269,9 @@ class TestStepTraceEmission:
         assert engine_mfu == pytest.approx(bench_mfu, rel=0.02), \
             (engine_mfu, bench_mfu)
 
-    def test_comm_deltas_and_roofline(self, devices):
+    def test_comm_deltas_and_roofline(self, devices, monkeypatch):
+        monkeypatch.setenv("BENCH_PEAK_TFLOPS", "1.0")
+        monkeypatch.setenv("BENCH_HBM_GBPS", "10.0")
         engine = _tiny_engine()
         it = _data_iter(engine.micro_batch_size * engine.dp_world_size)
         engine.train_batch(it)
@@ -361,8 +366,7 @@ class TestRoofline:
 
 
 # ---------------------------------------------------------------------------
-# serving latency snapshot (engine_v2 on a single-device mesh — the
-# multi-device kernel path needs jax.shard_map, absent in older jax)
+# serving latency snapshot (engine_v2 on a single-device mesh)
 # ---------------------------------------------------------------------------
 
 class TestServingSnapshot:

@@ -102,9 +102,12 @@ def test_markdown_gains_split_columns_only_when_asked():
 # ---------------------------------------------------------------------------
 
 
-def test_probe_analytic_schema(capsys):
+def test_probe_analytic_schema(capsys, monkeypatch):
     import latency_hiding_probe as probe
 
+    # the analytic split models a chip: name its peaks (v5e)
+    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "197")
+    monkeypatch.setenv("BENCH_HBM_GBPS", "819")
     rc = probe.main(["--analytic", "--layers", "1", "--micro", "1",
                      "--seq", "32", "--vocab", "128",
                      "--overlap-depth", "2"])

@@ -10,17 +10,6 @@ import deepspeed_tpu as dstpu
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.parallel import topology as topo
 from deepspeed_tpu.parallel.pipeline import pipelined_layers
-from deepspeed_tpu.utils.jaxcompat import supports_spmd_partition_id
-
-# pipelined_layers on a pp mesh lowers through a partial-auto shard_map
-# whose SPMD partitioning emits a partition-id HLO; jax 0.4.x's XLA:CPU
-# rejects that at execute time (probe: utils/jaxcompat.py) — the full
-# engine paths below (pp_training/pp_with_zero) lower differently and
-# still run everywhere
-needs_partition_id = pytest.mark.skipif(
-    not supports_spmd_partition_id(),
-    reason="backend rejects PartitionId under partial-auto SPMD "
-           "(jax-0.4.x XLA:CPU limitation)")
 
 TINY4 = TransformerConfig(
     vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
@@ -38,7 +27,6 @@ def data_iter(batch, seq=17, seed=0):
         i += 1
 
 
-@needs_partition_id
 def test_pipelined_layers_matches_scan(devices):
     """The pipeline transform is the identity rewrite of scan-over-layers."""
     mesh = topo.build_mesh({"dp": 1, "fsdp": 2, "pp": 4})
@@ -57,7 +45,6 @@ def test_pipelined_layers_matches_scan(devices):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-@needs_partition_id
 def test_pipelined_layers_grads_match(devices):
     mesh = topo.build_mesh({"dp": 1, "pp": 4, "fsdp": 2})
     topo.set_global_mesh(mesh)
@@ -116,7 +103,6 @@ def test_pp_with_zero_and_tp(devices):
     assert losses[-1] < losses[0]
 
 
-@needs_partition_id
 def test_windowed_waves_match_single_pass(devices):
     """Waves of `window` microbatches compute the same function."""
     mesh = topo.build_mesh({"dp": 1, "fsdp": 2, "pp": 4})
@@ -145,7 +131,6 @@ def test_windowed_waves_match_single_pass(devices):
     np.testing.assert_allclose(np.asarray(g2), np.asarray(g1), atol=3e-4)
 
 
-@needs_partition_id
 def test_window_bounds_memory_as_microbatches_grow(devices):
     """1F1B-depth memory: with a fixed window, doubling M (and the batch)
     must NOT double compiled temp memory — the backward replays one wave
@@ -182,7 +167,6 @@ def test_window_bounds_memory_as_microbatches_grow(devices):
     assert t32_nowin > t32, (t32_nowin, t32)
 
 
-@needs_partition_id
 def test_save_boundaries_schedule(devices):
     """VERDICT r2 #7: a schedule without the wave-recompute tax.
     save_boundaries runs one un-rematted pass whose residuals are the

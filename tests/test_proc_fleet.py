@@ -61,7 +61,8 @@ def reference_outputs(prompts, gen):
 
 def make_proc_fleet(run_dir, roles, engine=None, routing="least_loaded",
                     stale_after_s=5.0, affinity_blocks=2, autoscale=None):
-    sup = ReplicaSupervisor(str(run_dir), model=MODEL_SPEC,
+    sup = ReplicaSupervisor(str(run_dir), jax_platform="cpu",
+                            model=MODEL_SPEC,
                             engine=dict(engine or ENGINE_SPEC), seed=0)
     remotes = [sup.spawn(role=r) for r in roles]
     router = FleetRouter(remotes, stale_after_s=stale_after_s,
@@ -245,7 +246,8 @@ class TestFileChannelFleet:
     def test_file_channel_degraded_mode(self, tmp_path):
         """The socketless fallback serves the same workload over
         spool-dir frames (slower, same contract)."""
-        sup = ReplicaSupervisor(str(tmp_path), model=MODEL_SPEC,
+        sup = ReplicaSupervisor(str(tmp_path), jax_platform="cpu",
+                                model=MODEL_SPEC,
                                 engine=dict(ENGINE_SPEC), seed=0,
                                 channel="file")
         try:

@@ -343,7 +343,7 @@ class TestQuantModes:
             batch_fn=lambda gb: {},
             tuning_space={"micro_batch_sizes": [1], "zero_stages": [3],
                           "quant_modes": ["off", "qwz+qgz+hpz8"]},
-            results_dir=str(tmp_path))
+            hbm_budget_bytes=16 * 2**30, results_dir=str(tmp_path))
         cands = t.candidates()
         assert len(cands) == 2
         by_mode = {c["_quant_mode"]: c for c in cands}
@@ -521,11 +521,18 @@ class TestBenchDiff:
         assert bench_diff.main(["--root", str(tmp_path)]) == 1
         capsys.readouterr()
 
-    def test_single_round_is_a_noop(self, tmp_path, capsys):
-        with open(tmp_path / "BENCH_r01.json", "w") as f:
-            json.dump({"n": 1, "rc": 0, "parsed": _parsed()}, f)
+    @pytest.mark.parametrize("n_rounds,says", [
+        (0, "no BENCH_r*.json under"), (1, "only BENCH_r01.json found")])
+    def test_fewer_than_two_rounds_is_a_noop(self, tmp_path, capsys,
+                                             n_rounds, says):
+        """No records (the state of the repo's root) or a single one:
+        a clear note and exit 0, never a traceback."""
+        for n in range(1, n_rounds + 1):
+            with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
+                json.dump({"n": n, "rc": 0, "parsed": _parsed()}, f)
         assert bench_diff.main(["--root", str(tmp_path)]) == 0
-        assert "nothing to diff" in capsys.readouterr().out
+        out = capsys.readouterr().out
+        assert says in out and "nothing to diff" in out
 
 
 # ---------------------------------------------------------------------------

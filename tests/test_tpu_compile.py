@@ -1,0 +1,170 @@
+"""The main path's Pallas kernels, compiled for a TPU that is described
+and not attached.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+a block whose last two dims break the (8, 128) tiling rule, a slice that
+is not aligned, more VMEM than a kernel may hold. The TPU compiler ships
+in the installation and compiles for a ``v5e:2x2`` topology description
+without a chip (guide ``on-chip-measurement`` §2.3), so these compile
+each kernel at the real ``mistral-7b`` widths — about two seconds each,
+no chip time — and assert the Mosaic custom call is in the program.
+Nothing runs: a pass here is not a chip run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.ops.pallas import (flash_attention, grouped_matmul,
+                                      paged_attention, quantization)
+
+# mistral-7b attention geometry (models/zoo.py)
+HQ, HKV, D = 32, 8, 128
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip, as a sharding for abstract arguments."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    # a described-device executable can be written to the persistent
+    # cache but not read back without a chip: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _device_kernels(monkeypatch):
+    """``jax.default_backend()`` is still the CPU here, so each module's
+    ``_interpret()`` would pick the interpreter: steer it in the test."""
+    for mod in (flash_attention, paged_attention, grouped_matmul,
+                quantization):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _compile(chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _flash_loss(q, k, v, seg=None):
+    out = flash_attention.flash_attention(
+        q, k, v, causal=True, segment_ids=seg, block_q=512, block_k=512)
+    return jnp.sum(out.astype(jnp.float32))
+
+
+def test_flash_fwd_bwd(chip):
+    B, S = 2, 2048
+    _compile(chip, jax.grad(_flash_loss, argnums=(0, 1, 2)),
+             ((B, S, HQ, D), BF16), ((B, S, HKV, D), BF16),
+             ((B, S, HKV, D), BF16))
+
+
+@pytest.mark.parametrize("batch", [2, 8])
+def test_flash_segment_ids_fwd_bwd(chip, batch):
+    """The packed-sequence path: its [B, S] segment ids used to be
+    blocked as (1, block), which Mosaic rejects for every B."""
+    S = 2048
+    _compile(chip, jax.grad(_flash_loss, argnums=(0, 1, 2)),
+             ((batch, S, HQ, D), BF16), ((batch, S, HKV, D), BF16),
+             ((batch, S, HKV, D), BF16), ((batch, S), jnp.int32))
+
+
+def test_flash_padded_noncausal(chip):
+    """Non-causal with S off the block grid synthesises segment ids."""
+    def f(q, k, v):
+        return flash_attention.flash_attention(q, k, v, causal=False,
+                                               block_q=512, block_k=512)
+
+    _compile(chip, f, ((2, 1000, HQ, D), BF16), ((2, 1000, HKV, D), BF16),
+             ((2, 1000, HKV, D), BF16))
+
+
+def test_flash_on_a_four_chip_mesh(chip):
+    """GSPMD cannot partition a Mosaic kernel: the dispatcher has to wrap
+    the call in a shard_map, or ZeRO-3 over fsdp=4 does not compile."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops import attention as attn_ops
+    from deepspeed_tpu.parallel import topology
+    from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
+
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    mesh = build_mesh(TopologyConfig(dp=1, fsdp=2, tp=2), devices=devices)
+    topology.set_global_mesh(mesh)
+    sh = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+
+    def loss(q, k, v):
+        out = attn_ops.multi_head_attention(q, k, v, causal=True,
+                                            impl="flash")
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((4, 2048, n, D), BF16, sharding=sh)
+            for n in (HQ, HKV, HKV)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# paged KV pool of one layer: [num_blocks, block_size, 2, kv_heads, D]
+_POOL = ((512, 16, 2, HKV, D), BF16)
+
+
+@pytest.mark.parametrize("pages", [1, 4])
+def test_paged_decode(chip, pages):
+    seqs, max_pages = 16, 64
+
+    def f(q, kv, bt, ctx):
+        return paged_attention.paged_decode_attention(
+            q, kv, bt, ctx, pages_per_compute_block=pages)
+
+    _compile(chip, f, ((seqs, HQ, D), BF16), _POOL,
+             ((seqs, max_pages), jnp.int32), ((seqs,), jnp.int32))
+
+
+def test_paged_prefill_tq64(chip):
+    segs, tq, max_pages = 4, 64, 64
+    _compile(chip, paged_attention.paged_prefill_attention,
+             ((segs, tq, HQ, D), BF16), _POOL,
+             ((segs, max_pages), jnp.int32), ((segs,), jnp.int32),
+             ((segs,), jnp.int32))
+
+
+def test_grouped_matmul_fwd_bwd(chip):
+    """Mixtral-width expert matmul: M=8192 rows over E=8 experts."""
+    M, K, N, E = 8192, 4096, 14336, 8
+
+    def loss(lhs, rhs, sizes):
+        return jnp.sum(grouped_matmul.gmm(lhs, rhs, sizes)
+                       .astype(jnp.float32))
+
+    _compile(chip, jax.grad(loss, argnums=(0, 1)),
+             ((M, K), BF16), ((E, K, N), BF16), ((E,), jnp.int32))
+
+
+def test_kv_quantize_int8(chip):
+    """One step's new K/V rows, one fp32 scale per head vector."""
+    def f(x):
+        return quantization.kv_quantize(x, bits=8)
+
+    _compile(chip, f, ((256, 2, HKV, D), BF16))

@@ -11,7 +11,6 @@ module_inject/layers.py:678 (reference keeps the table sharded too).
 """
 
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +20,6 @@ from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.parallel import topology as topo
 from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
 from deepspeed_tpu.runtime.sharding import vocab_parallel_lookup
-from deepspeed_tpu.utils.jaxcompat import supports_spmd_partition_id
 
 TINY = TransformerConfig(
     vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
@@ -35,10 +33,6 @@ def _mesh(**sizes):
     return mesh
 
 
-@pytest.mark.skipif(
-    not supports_spmd_partition_id(),
-    reason="backend rejects PartitionId under partial-auto SPMD "
-           "(jax-0.4.x XLA:CPU limitation)")
 def test_lookup_matches_plain_gather(devices):
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(64, 16)).astype(np.float32))

@@ -336,9 +336,10 @@ def test_multihost_two_process_matches_single():
                 assert p.returncode == 0, se[-2000:]
         return json.loads(outs[0][0].strip().splitlines()[-1])["losses"]
 
-    import tempfile
-
-    env["ZF_CACHE"] = tempfile.mkdtemp(prefix="zf_cache_")
+    # workers keep their compile cache at the one fixed place
+    # (utils/compile_cache.py): a directory that moves never hits
+    env["ZF_CACHE"] = "1"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
     single = run_single()
     multi = None
     for attempt in range(MAX_ATTEMPTS):

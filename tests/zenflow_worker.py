@@ -37,19 +37,16 @@ os.environ["XLA_FLAGS"] = (
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", ndev)
-except AttributeError:
-    # older jax (<0.5) has no jax_num_cpu_devices option; the XLA_FLAGS
-    # --xla_force_host_platform_device_count set above provides the
-    # simulated devices there (same fallback as tests/conftest.py)
-    pass
+jax.config.update("jax_num_cpu_devices", ndev)
 if os.environ.get("ZF_CACHE"):
     # persistent compile cache: on single-core CI hosts the two
     # processes' first-run compiles drift by minutes while gloo's pair
     # timeout is ~30s; a warm cache collapses the drift (the test
-    # retries once after populating it)
-    jax.config.update("jax_compilation_cache_dir", os.environ["ZF_CACHE"])
+    # retries once after populating it). The directory follows the one
+    # rule (utils/compile_cache.py): the environment's, else the repo's.
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 if mode == "multi":

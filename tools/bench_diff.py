@@ -18,8 +18,7 @@ metric and **exits nonzero** when a metric crosses its threshold:
   ``ok`` flag must be true and ``value`` (gate violations) must not
   grow — the quant SNR gates re-checked at diff time;
 - kernel tier (``BENCH_KERNELS`` payloads): every kernel:bucket in the
-  old round's ``winning_kernels`` must still be winning, and
-  ``flash_fallback_ratio`` must not rise by more than 0.10.
+  old round's ``winning_kernels`` must still be winning.
 
 Rounds with a different metric/unit (the headline changed shape, e.g.
 zero3 train → device fwd+bwd) are *incomparable*: reported, but only a
@@ -85,7 +84,6 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # the failure the table-driven dispatch exists to catch), and the
     # share of flash-worthy dispatches that lost the kernel must not
     # creep up by more than 10 points
-    "flash_fallback_ratio": ("max_increase", 0.10),
     # observability plane (BENCH_MODE=obs_fleet): the per-request tracer
     # emit-point overhead gets a loose order-of-magnitude leash (tens of
     # µs measured on a shared host — only a blowup is signal), and the
@@ -242,13 +240,6 @@ def diff_reports(old: Dict[str, Any], new: Dict[str, Any],
                   not regressed)
             if regressed:
                 violations[-1]["regressed"] = regressed
-        ov = old.get("flash_fallback_ratio")
-        nv = new.get("flash_fallback_ratio")
-        if isinstance(ov, (int, float)) and isinstance(nv, (int, float)):
-            rule, limit = th["flash_fallback_ratio"]
-            rise = nv - ov
-            check("flash_fallback_ratio", rule, limit, ov, nv, rise,
-                  rise <= limit)
         # observability-plane sentinels (obs_fleet payloads): tracer
         # overhead trend and the worst clock-offset error
         ov = old.get("obs.trace_overhead_us")
@@ -407,9 +398,10 @@ def main(argv=None) -> int:
     else:
         rounds = load_rounds(args.root)
         if len(rounds) < 2:
+            found = (f"no BENCH_r*.json under {args.root}" if not rounds
+                     else f"only {os.path.basename(rounds[0][1])} found")
             print(json.dumps({"schema": SCHEMA, "ok": True,
-                              "note": f"{len(rounds)} round(s) found — "
-                                      "nothing to diff"}))
+                              "note": f"{found}: nothing to diff"}))
             return 0
         (_, old_path, old_doc), (_, new_path, new_doc) = rounds[-2:]
 

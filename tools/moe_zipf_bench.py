@@ -26,12 +26,14 @@ import numpy as np
 
 from deepspeed_tpu.parallel import topology as topo
 from deepspeed_tpu.parallel.moe import GateConfig, moe_ffn
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 B, S, H, F, E, K = 4, 2048, 4096, 14336, 8, 2
 DT = jnp.bfloat16
 
 
 def run():
+    enable_compile_cache()
     topo._GLOBAL_MESH = None
     rng = jax.random.PRNGKey(0)
     x = jax.random.normal(rng, (B, S, H), DT)

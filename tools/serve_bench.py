@@ -56,6 +56,14 @@ import os
 import sys
 import time
 
+from deepspeed_tpu.observability.roofline import on_tpu_or_named_cpu_smoke
+
+# the process-fleet drills (serve_procs, chaos_fleet, replay_fleet,
+# deploy_drill) certify routing, failover and replay with toy-size
+# workers on the CPU: they gate on counts and bit-identity and report
+# no device number. Their supervisor therefore names "cpu" outright.
+_DRILL_PLATFORM = "cpu"
+
 
 def run() -> dict:
     import jax
@@ -65,7 +73,7 @@ def run() -> dict:
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models.zoo import get_model
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = on_tpu_or_named_cpu_smoke()
     model_name = os.environ.get("SERVE_MODEL", "llama3-8b")
     layers = int(os.environ.get("SERVE_LAYERS", 3))
     n_seqs = int(os.environ.get("SERVE_SEQS", 24 if on_tpu else 4))
@@ -272,7 +280,7 @@ def run_slo() -> dict:
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models.zoo import get_model
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = on_tpu_or_named_cpu_smoke()
     model_name = os.environ.get("SLO_MODEL",
                                 "llama3-8b" if on_tpu else "tiny")
     layers = int(os.environ.get("SLO_LAYERS", 3 if on_tpu else 2))
@@ -556,7 +564,7 @@ def run_fleet() -> list:
 
     from deepspeed_tpu.models.zoo import get_model
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = on_tpu_or_named_cpu_smoke()
     model_name = os.environ.get("FLEET_MODEL",
                                 "llama3-8b" if on_tpu else "tiny")
     layers = int(os.environ.get("FLEET_LAYERS", 3 if on_tpu else 2))
@@ -666,7 +674,7 @@ def run_quant() -> dict:
     from deepspeed_tpu.models.zoo import get_model
     from deepspeed_tpu.serving import disagg
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = on_tpu_or_named_cpu_smoke()
     model_name = os.environ.get("QUANT_SERVE_MODEL", "llama3-8b")
     layers = int(os.environ.get("QUANT_SERVE_LAYERS", 3 if on_tpu else 2))
     vocab = int(os.environ.get("QUANT_SERVE_VOCAB",
@@ -876,7 +884,7 @@ def run_tier() -> dict:
                                                      TransformerDrafter)
     from deepspeed_tpu.models.zoo import get_model
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = on_tpu_or_named_cpu_smoke()
     block = 8
     prompt_len = int(os.environ.get("TIER_SERVE_PROMPT", 24))
     gen = int(os.environ.get("TIER_SERVE_GEN", 8))
@@ -1189,7 +1197,8 @@ def _drive_procs_arm(arm, base_dir, model_spec, engine_spec, prompts,
     engine = dict(engine_spec)
     if arm == "disagg":
         engine["handoff_wire"] = knobs["wire"]
-    sup = ReplicaSupervisor(run_dir, model=model_spec, engine=engine,
+    sup = ReplicaSupervisor(run_dir, jax_platform=_DRILL_PLATFORM,
+                            model=model_spec, engine=engine,
                             seed=knobs["seed"])
     n_rep = knobs["replicas"]
     chaos_victim = None
@@ -1583,8 +1592,8 @@ def _drive_chaos_arm(arm, base_dir, model_spec, engine_spec, prompts,
     run_dir = os.path.join(base_dir, arm)
     crashloop = arm == "crashloop"
     sup = ReplicaSupervisor(
-        run_dir, model=model_spec, engine=dict(engine_spec),
-        seed=knobs["seed"],
+        run_dir, jax_platform=_DRILL_PLATFORM, model=model_spec,
+        engine=dict(engine_spec), seed=knobs["seed"],
         max_restarts_per_window=2 if crashloop else 3,
         restart_window_s=60.0 if crashloop else 30.0,
         min_healthy=1)
@@ -2178,8 +2187,9 @@ def _record_replay_arm(base_dir, journal_path, model_spec, engine_spec,
         replay=recipe, fault=fault_spec)
 
     sup = ReplicaSupervisor(
-        os.path.join(base_dir, "record"), model=model_spec,
-        engine=dict(engine_spec), seed=knobs["seed"], min_healthy=1)
+        os.path.join(base_dir, "record"), jax_platform=_DRILL_PLATFORM,
+        model=model_spec, engine=dict(engine_spec), seed=knobs["seed"],
+        min_healthy=1)
     remotes = [sup.spawn(role="unified") for _ in range(n_rep)]
     router = FleetRouter(remotes, **router_kw)
     sup.router = router
@@ -2485,8 +2495,8 @@ def _drive_deploy_arm(arm, base_dir, model_spec, engine_spec, prompts,
             model=model_spec, engine=engine_spec, seed=knobs["seed"],
             drill=True))
     sup = ReplicaSupervisor(
-        run_dir, model=model_spec, engine=dict(engine_spec),
-        seed=knobs["seed"], min_healthy=1)
+        run_dir, jax_platform=_DRILL_PLATFORM, model=model_spec,
+        engine=dict(engine_spec), seed=knobs["seed"], min_healthy=1)
     remotes = [sup.spawn(role="unified")]
     if drill:
         # the rush-hour casualty: SIGKILLs itself on its second busy
