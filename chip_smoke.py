@@ -146,11 +146,11 @@ def _train_engine(sz: Sizes, seed: int, layers: int, micro: int, **mesh_kw):
 
 
 def _drop_state(engine) -> None:
-    """A training engine registers itself with process-wide hooks (stall
-    watchdog, checkpoint I/O, signal handlers) and so outlives its scope;
-    the next phase needs the chip's memory, so its device state is
-    dropped by hand."""
-    engine.synchronize()
+    """``Engine.close()`` drains the window and stops the engine's own
+    threads; process-wide hooks (flight recorder, signal handlers) still
+    hold the object, and the next phase needs the chip's memory, so its
+    device state is dropped by hand."""
+    engine.close()
     engine.params = engine.opt_state = None
     gc.collect()
 
@@ -272,6 +272,7 @@ def phase_serve(sz: Sizes, seed: int) -> dict:
     setup_s = time.perf_counter() - t0  # init + every compile + first run
     stats = first.log_summary()
     params = first.params
+    first.close()
     del first
     gc.collect()  # the first engine's KV pool goes before the second's
 
@@ -279,6 +280,7 @@ def phase_serve(sz: Sizes, seed: int) -> dict:
     second = engine(params)
     again, second_s = run(second)
     compiles = _compile_events() - compiles0
+    second.close()
 
     for uid in uids:
         check(len(streams.get(uid, ())) == sz.new_tokens,

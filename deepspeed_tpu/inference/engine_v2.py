@@ -37,6 +37,7 @@ from deepspeed_tpu.models.transformer import TransformerLM
 from deepspeed_tpu.observability.clocksync import wall_time
 from deepspeed_tpu.observability.journal import get_journal
 from deepspeed_tpu.parallel import topology as topo
+from deepspeed_tpu.utils.annotate import named, span
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -78,14 +79,21 @@ def _shared_step_fns(cfg, kernel_mesh):
     hit = _JIT_CACHE.get(key)
     if hit is not None and hit[0] is cfg:
         return hit[1]
+    # each program under a stable name: the device trace's module line
+    # says jit_dstpu_serve_gather, ... ("step" is the gather program)
     fns = {
-        "step": jax.jit(partial(model_runner.ragged_forward, cfg)),
-        "decode": jax.jit(partial(
-            model_runner.ragged_decode_forward, cfg, mesh=kernel_mesh)),
-        "prefill": jax.jit(partial(
-            model_runner.ragged_prefill_forward, cfg, mesh=kernel_mesh)),
-        "multi_decode": jax.jit(partial(
-            model_runner.ragged_multi_decode, cfg, mesh=kernel_mesh),
+        "step": jax.jit(named(
+            partial(model_runner.ragged_forward, cfg),
+            "dstpu_serve_gather")),
+        "decode": jax.jit(named(
+            partial(model_runner.ragged_decode_forward, cfg,
+                    mesh=kernel_mesh), "dstpu_serve_decode")),
+        "prefill": jax.jit(named(
+            partial(model_runner.ragged_prefill_forward, cfg,
+                    mesh=kernel_mesh), "dstpu_serve_prefill")),
+        "multi_decode": jax.jit(named(
+            partial(model_runner.ragged_multi_decode, cfg,
+                    mesh=kernel_mesh), "dstpu_serve_multi_decode"),
             static_argnames=("steps",)),
     }
     _JIT_CACHE[key] = (cfg, fns)
@@ -94,13 +102,31 @@ def _shared_step_fns(cfg, kernel_mesh):
 
 # device-side token picks are config-independent — one compiled copy
 # per process, not per engine
-_PICK_GREEDY = jax.jit(lambda lg, idx: jnp.argmax(
-    lg.reshape(-1, lg.shape[-1])[idx].astype(jnp.float32),
-    axis=-1).astype(jnp.int32))
-_TAKE_ROWS = jax.jit(lambda lg, idx: lg.reshape(-1, lg.shape[-1])[idx])
-_PICK_GREEDY_ALL = jax.jit(lambda lg: jnp.argmax(
-    lg.reshape(-1, lg.shape[-1]).astype(jnp.float32),
-    axis=-1).astype(jnp.int32))
+@jax.jit
+def dstpu_pick_greedy(lg, idx):
+    return jnp.argmax(lg.reshape(-1, lg.shape[-1])[idx].astype(jnp.float32),
+                      axis=-1).astype(jnp.int32)
+
+
+@jax.jit
+def dstpu_take_rows(lg, idx):
+    return lg.reshape(-1, lg.shape[-1])[idx]
+
+
+@jax.jit
+def dstpu_pick_greedy_all(lg):
+    return jnp.argmax(lg.reshape(-1, lg.shape[-1]).astype(jnp.float32),
+                      axis=-1).astype(jnp.int32)
+
+
+# the stats key that counts the tokens a step of each program emitted
+_TOKENS_OF = {"gather": "tokens_gather", "prefill": "tokens_prefill_kernel",
+              "decode": "tokens_decode",
+              "multi_decode": "tokens_multi_decode"}
+
+_PICK_GREEDY = dstpu_pick_greedy
+_TAKE_ROWS = dstpu_take_rows
+_PICK_GREEDY_ALL = dstpu_pick_greedy_all
 
 
 class InferenceEngineV2:
@@ -234,7 +260,23 @@ class InferenceEngineV2:
                       # plus the source-side captures
                       "migrated_out": 0, "migrated_in": 0,
                       "migrate_paged": 0, "migrate_recompute": 0,
-                      "migrate_resume_tokens": 0}
+                      "migrate_resume_tokens": 0,
+                      # where the work happens, as flat counters a
+                      # caller can difference over a window: tokens
+                      # emitted by steps of each program (they sum to
+                      # the tokens emitted; speculative rounds run the
+                      # gather program), (request, step) pairs that
+                      # advanced a prompt chunk, and the sums behind the
+                      # admission-wait and TTFT histograms
+                      "tokens_gather": 0, "tokens_prefill_kernel": 0,
+                      "tokens_decode": 0, "tokens_multi_decode": 0,
+                      "prefill_chunks": 0, "admission_wait_s": 0.0,
+                      "ttft_s": 0.0, "first_tokens": 0}
+        # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
+        # span, of the spans nested in it, and of the request tracer's
+        # PREFILL / DECODE_EMIT spans of that step
+        self._step_id = 0
+        self._closed = False
         # admission queue: put() never raises on a full KV pool — requests
         # wait FIFO here and admit as blocks free up; preemption victims
         # requeue at the FRONT with their generated tokens preserved
@@ -383,6 +425,11 @@ class InferenceEngineV2:
         only for a prompt that can NEVER fit (per-seq block cap / total
         pool), and RuntimeError when ``max_queue_depth`` is configured
         and the queue is full (opt-in fail-fast backpressure)."""
+        with span("put", uid=int(uids[0]) if len(uids) else -1,
+                  requests=len(uids)):
+            self._put(uids, tokens_list, max_new_tokens)
+
+    def _put(self, uids, tokens_list, max_new_tokens: int) -> None:
         now = time.perf_counter()
         jr = get_journal()
         # a router-fronted engine defers ADMIT/EMIT journaling to the
@@ -451,6 +498,7 @@ class InferenceEngineV2:
             if req.admit_time is not None:
                 self._admit_time[req.uid] = req.admit_time
             self._admission_hist.observe(now - req.enqueue_time)
+            self.stats["admission_wait_s"] += now - req.enqueue_time
             self.stats["admitted"] += 1
         self._hub.gauge("serve.queue_wait_depth", len(self._queue),
                         labels=self._metric_labels)
@@ -625,6 +673,7 @@ class InferenceEngineV2:
         elif req.admit_time is not None:
             self._admit_time[req.uid] = req.admit_time
         self._admission_hist.observe(now - req.enqueue_time)
+        self.stats["admission_wait_s"] += now - req.enqueue_time
         return "resumed"
 
     def page_out(self, uid: int) -> bool:
@@ -836,56 +885,164 @@ class InferenceEngineV2:
         keys, _ = cache.lookup(toks, max_tokens=max(0, len(toks) - 1))
         return len(keys)
 
+    def _open_step(self):
+        """The next engine step's host span: ``dstpu/serve_step`` with its
+        ``step_id``. The phases nest inside it (admit, schedule,
+        build_batch, dispatch, fetch, bookkeep, journal), and the request
+        tracer's spans of the step carry the same id, so a request's
+        timeline joins to its steps and through them to the device
+        programs dispatched under them."""
+        self._step_id += 1
+        return span("serve_step", step_id=self._step_id)
+
     def step(self, temperature: float = 0.0, seed: int = 0,
              eos_token_id: Optional[int] = None) -> Dict[int, int]:
         """Run one SplitFuse step. Returns {uid: new_token} for sequences
         that produced a token this step."""
-        t0 = time.perf_counter()
-        self._admit_from_queue()
-        scheduled = self.scheduler.schedule()
-        self._release_finished()
-        if not scheduled:
-            # all live sequences starved for KV (pool exhausted mid-decode):
-            # preempt the last-admitted sequence so the others can progress
-            # — without this the engine deadlocks and leaks the pool. The
-            # victim requeues at the queue front with its generated tokens
-            # kept for prefix recompute; it is never silently dropped.
-            live = [s for s in self.state.seqs.values() if not s.done]
-            if len(live) > 1 or (live and self._queue):
-                victim = live[-1]
-                # page to the host tier when one is attached (decode
-                # resumes without re-prefill); recompute-requeue is the
-                # fallback when paging doesn't apply
-                if self._page_out(victim):
-                    log_dist(
-                        f"KV pool exhausted: paged uid={victim.uid} to "
-                        f"the host tier ({len(victim.generated)} tokens "
-                        "generated) — warm resume on readmission",
-                        ranks=[0])
-                else:
-                    log_dist(
-                        f"KV pool exhausted: preempting uid={victim.uid} "
-                        f"({len(victim.generated)} tokens generated) — "
-                        "requeued for readmission", ranks=[0])
-                    self._requeue(victim)
-            elif live:
-                # a lone sequence the pool cannot grow for: requeueing
-                # would just readmit it into the same wall, so end it
-                # (the only remaining truncation path)
-                victim = live[0]
+        with self._open_step():
+            with span("admit"):
+                self._admit_from_queue()
+            return self._splitfuse_step(temperature, seed, eos_token_id)
+
+    def _preempt_starved(self) -> None:
+        """All live sequences starved for KV (pool exhausted mid-decode):
+        preempt the last-admitted sequence so the others can progress —
+        without this the engine deadlocks and leaks the pool. The victim
+        requeues at the queue front with its generated tokens kept for
+        prefix recompute; it is never silently dropped."""
+        live = [s for s in self.state.seqs.values() if not s.done]
+        if len(live) > 1 or (live and self._queue):
+            victim = live[-1]
+            # page to the host tier when one is attached (decode
+            # resumes without re-prefill); recompute-requeue is the
+            # fallback when paging doesn't apply
+            if self._page_out(victim):
                 log_dist(
-                    f"KV pool exhausted by lone uid={victim.uid}: "
-                    "truncated (pool smaller than one request)", ranks=[0])
-                victim.done = True
-                victim.truncated = True
-                self.stats["truncated"] += 1
-                self.tracer.on_finish(victim.uid, "truncated")
-                self._release_seq(victim.uid)
-            return {}
-        batch = build_ragged_batch(scheduled, self.max_tokens, self.max_seqs,
-                                   self.max_blocks_per_seq)
-        # steady-state decode (one token per sequence): tokens line up
-        # with slots, so the compact paged-kernel path applies
+                    f"KV pool exhausted: paged uid={victim.uid} to "
+                    f"the host tier ({len(victim.generated)} tokens "
+                    "generated) — warm resume on readmission",
+                    ranks=[0])
+            else:
+                log_dist(
+                    f"KV pool exhausted: preempting uid={victim.uid} "
+                    f"({len(victim.generated)} tokens generated) — "
+                    "requeued for readmission", ranks=[0])
+                self._requeue(victim)
+        elif live:
+            # a lone sequence the pool cannot grow for: requeueing
+            # would just readmit it into the same wall, so end it
+            # (the only remaining truncation path)
+            victim = live[0]
+            log_dist(
+                f"KV pool exhausted by lone uid={victim.uid}: "
+                "truncated (pool smaller than one request)", ranks=[0])
+            victim.done = True
+            victim.truncated = True
+            self.stats["truncated"] += 1
+            self.tracer.on_finish(victim.uid, "truncated")
+            self._release_seq(victim.uid)
+
+    def _splitfuse_step(self, temperature: float, seed: int,
+                        eos_token_id: Optional[int]) -> Dict[int, int]:
+        t0 = time.perf_counter()
+        with span("schedule"):
+            scheduled = self.scheduler.schedule()
+            self._release_finished()
+            if not scheduled:
+                self._preempt_starved()
+                return {}
+        with self.mesh:
+            with span("build_batch"):
+                fn, program, args, batch = self._build_step_call(scheduled)
+            with span("dispatch", program=program, seqs=len(scheduled),
+                      tokens=int(batch.num_tokens)):
+                logits, new_kv = fn(self.params, self.kv_cache.kv_state,
+                                    *args)
+        self.kv_cache.set_kv_state(new_kv)
+
+        # Sample ON DEVICE and fetch only token ids (greedy) or just the
+        # consumed rows (stochastic). Materializing the full [T, V]
+        # logits host-side is 131 MB/step at a 256-token budget x 128k
+        # vocab; the ids are 4 bytes/sequence.
+        with span("bookkeep"):
+            stride = logits.shape[1] if logits.ndim == 3 else 1
+            flat_idx = np.zeros(self.max_seqs, np.int32)
+            consumers = []
+            for slot, (seq, new_tokens, start_pos) in enumerate(scheduled):
+                n = len(new_tokens)
+                seq.seen_tokens = start_pos + n
+                # prompt blocks the step just completed become shareable
+                self.state.register_prefix_blocks(seq)
+                if start_pos < len(seq.input_tokens):
+                    self.stats["prefill_chunks"] += 1
+                if seq.seen_tokens < len(seq.input_tokens):
+                    continue  # mid-prefill: no logits consumed
+                if program == "prefill":
+                    flat_idx[slot] = slot * stride + (n - 1)
+                elif program == "decode":
+                    flat_idx[slot] = slot
+                else:
+                    flat_idx[slot] = batch.last_token_index[slot]
+                consumers.append((slot, seq))
+
+        emitted: Dict[int, int] = {}
+        if consumers:
+            with span("fetch"), self.mesh:
+                # the host blocks on the device here: the picked ids (or
+                # rows) are the step's only result it reads
+                idx_dev = jnp.asarray(flat_idx)
+                if temperature == 0.0:
+                    toks_np = np.asarray(self._pick_greedy(logits, idx_dev))
+                else:
+                    rows_np = np.asarray(self._take_rows(logits, idx_dev))
+        with span("bookkeep"):
+            for slot, seq in consumers:
+                if temperature == 0.0:
+                    tok = int(toks_np[slot])
+                else:
+                    tok = int(_sample_np(rows_np[slot], temperature,
+                                         seed + slot + seq.seen_tokens))
+                seq.generated.append(tok)
+                emitted[seq.uid] = tok
+                if eos_token_id is not None and tok == eos_token_id:
+                    seq.done = True
+                if seq.gen_budget_left <= 0:
+                    seq.done = True
+            self.stats[_TOKENS_OF[program]] += len(emitted)
+            now = time.perf_counter()
+            self._step_hist.observe(now - t0)
+            self._flight.record("serve_step", tokens=batch.num_tokens,
+                                emitted=len(emitted),
+                                wall_ms=round((now - t0) * 1000.0, 3))
+            if self.tracer.enabled:
+                # one PREFILL span per prompt chunk this step advanced;
+                # the span start backdates by the step wall so prefill
+                # lanes line up with the step that computed them
+                wall_ms = (now - t0) * 1e3
+                # same clock domain as every other span (skew-aware wall
+                # time): a stamp from the raw clock would rebase acausally
+                t_start = wall_time() - (now - t0)
+                for seq, new_tokens, start_pos in scheduled:
+                    if start_pos < len(seq.input_tokens):
+                        self.tracer.on_prefill(seq.uid, t_start, wall_ms,
+                                               tokens=len(new_tokens),
+                                               start_pos=start_pos,
+                                               step_id=self._step_id)
+            for uid in emitted:
+                self._note_emitted(uid, 1, now)
+            self._update_serve_gauges()
+            self._release_finished()
+        return emitted
+
+    def _build_step_call(self, scheduled):
+        """Pick the program for this step's mix and assemble its host
+        arrays: ``(jitted fn, program name, arguments after params and
+        KV, the ragged batch)``. ``decode`` when every sequence advances one token (tokens
+        line up with slots, so the compact paged-kernel path applies),
+        ``prefill`` when the Pallas prefill kernel takes the chunks,
+        else the flat ``gather`` program."""
+        batch = build_ragged_batch(scheduled, self.max_tokens,
+                                   self.max_seqs, self.max_blocks_per_seq)
         decode_only = (self._use_paged_kernel
                        and all(len(nt) == 1 for _, nt, _ in scheduled))
         seg_plan = None
@@ -917,99 +1074,27 @@ class InferenceEngineV2:
                 labels=self._metric_labels)
         elif decode_only:
             self.stats["decode_kernel_steps"] += 1
-        with self.mesh:
-            if seg_plan is not None:
-                n_segs = seg_plan[0].shape[0]
-                logits, new_kv = self._prefill_fn(
-                    self.params, self.kv_cache.kv_state, *seg_plan,
-                    jnp.asarray(batch.block_table[:n_segs]))
-            elif decode_only:
-                # compact per-slot arrays: token i belongs to slot i; pad
-                # out to max_seqs (token budget may be smaller than the
-                # slot budget)
-                n = batch.num_tokens
-                d_tok = np.zeros(self.max_seqs, np.int32)
-                d_pos = np.zeros(self.max_seqs, np.int32)
-                d_tok[:n] = batch.token_ids[:n]
-                d_pos[:n] = batch.token_pos[:n]
-                logits, new_kv = self._decode_fn(
-                    self.params, self.kv_cache.kv_state,
-                    jnp.asarray(d_tok), jnp.asarray(d_pos),
-                    jnp.asarray(batch.block_table),
-                    jnp.asarray(batch.ctx_lens))
-            else:
-                logits, new_kv = self._step_fn(
-                    self.params, self.kv_cache.kv_state,
-                    jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
-                    jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
-                    jnp.asarray(batch.num_tokens, jnp.int32))
-        self.kv_cache.set_kv_state(new_kv)
-
-        # Sample ON DEVICE and fetch only token ids (greedy) or just the
-        # consumed rows (stochastic). Materializing the full [T, V]
-        # logits host-side is 131 MB/step at a 256-token budget x 128k
-        # vocab; the ids are 4 bytes/sequence.
-        stride = logits.shape[1] if logits.ndim == 3 else 1
-        flat_idx = np.zeros(self.max_seqs, np.int32)
-        consumers = []
-        for slot, (seq, new_tokens, start_pos) in enumerate(scheduled):
-            n = len(new_tokens)
-            seq.seen_tokens = start_pos + n
-            # prompt blocks the step just completed become shareable
-            self.state.register_prefix_blocks(seq)
-            if seq.seen_tokens < len(seq.input_tokens):
-                continue  # mid-prefill: no logits consumed
-            if seg_plan is not None:
-                flat_idx[slot] = slot * stride + (n - 1)
-            elif decode_only:
-                flat_idx[slot] = slot
-            else:
-                flat_idx[slot] = batch.last_token_index[slot]
-            consumers.append((slot, seq))
-
-        emitted: Dict[int, int] = {}
-        if consumers:
-            idx_dev = jnp.asarray(flat_idx)
-            with self.mesh:
-                if temperature == 0.0:
-                    toks_np = np.asarray(self._pick_greedy(logits, idx_dev))
-                else:
-                    rows_np = np.asarray(self._take_rows(logits, idx_dev))
-            for slot, seq in consumers:
-                if temperature == 0.0:
-                    tok = int(toks_np[slot])
-                else:
-                    tok = int(_sample_np(rows_np[slot], temperature,
-                                         seed + slot + seq.seen_tokens))
-                seq.generated.append(tok)
-                emitted[seq.uid] = tok
-                if eos_token_id is not None and tok == eos_token_id:
-                    seq.done = True
-                if seq.gen_budget_left <= 0:
-                    seq.done = True
-        now = time.perf_counter()
-        self._step_hist.observe(now - t0)
-        self._flight.record("serve_step", tokens=batch.num_tokens,
-                            emitted=len(emitted),
-                            wall_ms=round((now - t0) * 1000.0, 3))
-        if self.tracer.enabled:
-            # one PREFILL span per prompt chunk this step advanced; the
-            # span start backdates by the step wall so prefill lanes
-            # line up with the step that computed them
-            wall_ms = (now - t0) * 1e3
-            # same clock domain as every other span (skew-aware wall
-            # time): a stamp from the raw clock would rebase acausally
-            t_start = wall_time() - (now - t0)
-            for seq, new_tokens, start_pos in scheduled:
-                if start_pos < len(seq.input_tokens):
-                    self.tracer.on_prefill(seq.uid, t_start, wall_ms,
-                                           tokens=len(new_tokens),
-                                           start_pos=start_pos)
-        for uid in emitted:
-            self._note_emitted(uid, 1, now)
-        self._update_serve_gauges()
-        self._release_finished()
-        return emitted
+        if seg_plan is not None:
+            n_segs = seg_plan[0].shape[0]
+            return self._prefill_fn, "prefill", (
+                *seg_plan, jnp.asarray(batch.block_table[:n_segs])), batch
+        if decode_only:
+            # compact per-slot arrays: token i belongs to slot i; pad
+            # out to max_seqs (token budget may be smaller than the
+            # slot budget)
+            n = batch.num_tokens
+            d_tok = np.zeros(self.max_seqs, np.int32)
+            d_pos = np.zeros(self.max_seqs, np.int32)
+            d_tok[:n] = batch.token_ids[:n]
+            d_pos[:n] = batch.token_pos[:n]
+            return self._decode_fn, "decode", (
+                jnp.asarray(d_tok), jnp.asarray(d_pos),
+                jnp.asarray(batch.block_table),
+                jnp.asarray(batch.ctx_lens)), batch
+        return self._step_fn, "gather", (
+            jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
+            jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
+            jnp.asarray(batch.num_tokens, jnp.int32)), batch
 
     def _plan_prefill_segments(self, scheduled):
         """Per-slot padded chunk layout for the Pallas prefill kernel, or
@@ -1065,13 +1150,16 @@ class InferenceEngineV2:
         round's rejected-draft compute, attached to its DECODE_EMIT
         span for the phase decomposition."""
         self.tracer.on_emit(uid, n_tokens,
-                            spec_overhead_ms=spec_overhead_ms)
+                            spec_overhead_ms=spec_overhead_ms,
+                            step_id=self._step_id)
         self._hub.counter_add("serve.tokens_emitted", n_tokens,
                               labels=self._metric_labels)
         admit = self._admit_time.pop(uid, None)
         last = self._last_emit_time.get(uid)
         if admit is not None:
             self._ttft_hist.observe(now - admit)
+            self.stats["ttft_s"] += now - admit
+            self.stats["first_tokens"] += 1
             n_tokens -= 1
             last = now
         if last is not None and n_tokens > 0:
@@ -1116,6 +1204,72 @@ class InferenceEngineV2:
         prefill pending, and KV capacity for the whole burst (the block
         tables are frozen for its duration). Returns None when a single
         SplitFuse step should run instead."""
+        with span("schedule"):
+            K = self._plan_decode_burst()
+        if K is None:
+            return None
+        live = [s for s in self.state.seqs.values() if not s.done]
+        t0 = time.perf_counter()
+        with self.mesh:
+            with span("build_batch"):
+                S = self.max_seqs
+                d_tok = np.zeros(S, np.int32)
+                d_pos = np.zeros(S, np.int32)
+                ctx = np.zeros(S, np.int32)
+                bt = np.zeros((S, self.max_blocks_per_seq), np.int32)
+                for i, s in enumerate(live):
+                    d_tok[i] = (s.generated[-1] if s.generated
+                                else int(s.input_tokens[-1]))
+                    d_pos[i] = s.seen_tokens
+                    ctx[i] = s.seen_tokens + 1
+                    bt[i, :len(s.kv_blocks)] = s.kv_blocks
+                args = (jnp.asarray(d_tok), jnp.asarray(d_pos),
+                        jnp.asarray(bt), jnp.asarray(ctx))
+            with span("dispatch", program="multi_decode", seqs=len(live),
+                      tokens=K * len(live)):
+                toks, new_kv = self._multi_decode_fn(
+                    self.params, self.kv_cache.kv_state, *args, steps=K)
+            with span("fetch"):
+                toks_np = np.asarray(toks)  # [K, S]: one fetch per K tokens
+        self.kv_cache.set_kv_state(new_kv)
+        with span("bookkeep"):
+            self.stats["decode_kernel_steps"] += K
+            self.stats["burst_steps"] = self.stats.get("burst_steps", 0) + 1
+            emitted: Dict[int, List[int]] = {}
+            for i, s in enumerate(live):
+                accepted = []
+                budget_left = s.gen_budget_left
+                for k in range(K):
+                    tok = int(toks_np[k, i])
+                    accepted.append(tok)
+                    if eos_token_id is not None and tok == eos_token_id:
+                        s.done = True
+                        break
+                    if len(accepted) >= budget_left:
+                        s.done = True
+                        break
+                s.generated.extend(accepted)
+                s.seen_tokens += len(accepted)
+                emitted[s.uid] = accepted
+            now = time.perf_counter()
+            self._step_hist.observe(now - t0)
+            # burst efficiency: accepted tokens vs the K*len(live) the
+            # device program computed (early-EOS/max-token exits waste
+            # the tail)
+            n_emitted = sum(len(v) for v in emitted.values())
+            self.stats["tokens_multi_decode"] += n_emitted
+            self._burst_tokens += n_emitted
+            self._burst_capacity += K * len(live)
+            for uid, toks in emitted.items():
+                if toks:
+                    self._note_emitted(uid, len(toks), now)
+            self._update_serve_gauges()
+            self._release_finished()
+        return emitted
+
+    def _plan_decode_burst(self) -> Optional[int]:
+        """The burst length K this round can run, with KV capacity for
+        the whole burst allocated, or None."""
         live = [s for s in self.state.seqs.values() if not s.done]
         if (self.decode_steps <= 1 or not live or len(live) > self.max_seqs
                 or any((not s.in_decode) or s.pending_prefill for s in live)):
@@ -1143,55 +1297,7 @@ class InferenceEngineV2:
         for s in live:
             ok = self.state.ensure_capacity(s, s.seen_tokens + K)
             assert ok, "capacity probe said yes but allocation failed"
-        t0 = time.perf_counter()
-        S = self.max_seqs
-        d_tok = np.zeros(S, np.int32)
-        d_pos = np.zeros(S, np.int32)
-        ctx = np.zeros(S, np.int32)
-        bt = np.zeros((S, self.max_blocks_per_seq), np.int32)
-        for i, s in enumerate(live):
-            d_tok[i] = (s.generated[-1] if s.generated
-                        else int(s.input_tokens[-1]))
-            d_pos[i] = s.seen_tokens
-            ctx[i] = s.seen_tokens + 1
-            bt[i, :len(s.kv_blocks)] = s.kv_blocks
-        with self.mesh:
-            toks, new_kv = self._multi_decode_fn(
-                self.params, self.kv_cache.kv_state, jnp.asarray(d_tok),
-                jnp.asarray(d_pos), jnp.asarray(bt), jnp.asarray(ctx),
-                steps=K)
-            toks_np = np.asarray(toks)  # [K, S] — one fetch per K tokens
-        self.kv_cache.set_kv_state(new_kv)
-        self.stats["decode_kernel_steps"] += K
-        self.stats["burst_steps"] = self.stats.get("burst_steps", 0) + 1
-        emitted: Dict[int, List[int]] = {}
-        for i, s in enumerate(live):
-            accepted = []
-            budget_left = s.gen_budget_left
-            for k in range(K):
-                tok = int(toks_np[k, i])
-                accepted.append(tok)
-                if eos_token_id is not None and tok == eos_token_id:
-                    s.done = True
-                    break
-                if len(accepted) >= budget_left:
-                    s.done = True
-                    break
-            s.generated.extend(accepted)
-            s.seen_tokens += len(accepted)
-            emitted[s.uid] = accepted
-        now = time.perf_counter()
-        self._step_hist.observe(now - t0)
-        # burst efficiency: accepted tokens vs the K*len(live) the device
-        # program computed (early-EOS/max-token exits waste the tail)
-        self._burst_tokens += sum(len(v) for v in emitted.values())
-        self._burst_capacity += K * len(live)
-        for uid, toks in emitted.items():
-            if toks:
-                self._note_emitted(uid, len(toks), now)
-        self._update_serve_gauges()
-        self._release_finished()
-        return emitted
+        return K
 
     def _spec_round_k(self, seq, occ: float) -> int:
         """Draft length for ``seq`` this spec round. Fixed ``spec_k``
@@ -1228,6 +1334,38 @@ class InferenceEngineV2:
         token is the model's own argmax chain — token-identical to
         non-speculative greedy. Returns None when a plain step should
         run instead (prefill pending, no drafts, or KV-starved)."""
+        if self._drafter is None:
+            return None
+        with span("schedule"):
+            sched = self._plan_spec_round()
+        if sched is None:
+            return None
+        t_start = time.perf_counter()
+        with self.mesh:
+            with span("build_batch"):
+                batch = build_ragged_batch(sched, self.max_tokens,
+                                           self.max_seqs,
+                                           self.max_blocks_per_seq)
+                args = (jnp.asarray(batch.token_ids),
+                        jnp.asarray(batch.token_seq),
+                        jnp.asarray(batch.token_pos),
+                        jnp.asarray(batch.block_table),
+                        jnp.asarray(batch.num_tokens, jnp.int32))
+            with span("dispatch", program="spec", seqs=len(sched),
+                      tokens=int(batch.num_tokens)):
+                logits, new_kv = self._step_fn(
+                    self.params, self.kv_cache.kv_state, *args)
+            with span("fetch"):
+                greedy = np.asarray(self._pick_greedy_all(logits))
+        self.kv_cache.set_kv_state(new_kv)
+        with span("bookkeep"):
+            return self._accept_spec_round(sched, batch, greedy, t_start,
+                                           eos_token_id)
+
+    def _plan_spec_round(self):
+        """Pass 1 of a speculative round: propose drafts and probe KV
+        capacity without side effects, then allocate. Returns the
+        round's ``[(seq, chunk, start_pos)]`` or None."""
         live = [s for s in self.state.seqs.values() if not s.done]
         if (self._drafter is None or not live or len(live) > self.max_seqs
                 or len(live) > self.max_tokens
@@ -1284,17 +1422,13 @@ class InferenceEngineV2:
             ok = self.state.ensure_capacity(s, s.seen_tokens + len(chunk))
             assert ok, "spec capacity probe said yes but allocation failed"
             sched.append((s, chunk, s.seen_tokens))
-        t_start = time.perf_counter()
-        batch = build_ragged_batch(sched, self.max_tokens, self.max_seqs,
-                                   self.max_blocks_per_seq)
-        with self.mesh:
-            logits, new_kv = self._step_fn(
-                self.params, self.kv_cache.kv_state,
-                jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
-                jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
-                jnp.asarray(batch.num_tokens, jnp.int32))
-            greedy = np.asarray(self._pick_greedy_all(logits))
-        self.kv_cache.set_kv_state(new_kv)
+        return sched
+
+    def _accept_spec_round(self, sched, batch, greedy, t_start: float,
+                           eos_token_id: Optional[int]
+                           ) -> Dict[int, List[int]]:
+        """Pass 2: accept each sequence's longest draft prefix that
+        matches the greedy chain, plus one bonus token."""
         emitted: Dict[int, List[int]] = {}
         wasted_rows: Dict[int, int] = {}
         cursor = 0
@@ -1367,6 +1501,8 @@ class InferenceEngineV2:
                             emitted=sum(len(v) for v in emitted.values()),
                             spec=True,
                             wall_ms=round(round_wall_ms, 3))
+        # a speculative round runs the gather program
+        self.stats["tokens_gather"] += sum(len(v) for v in emitted.values())
         for uid, toks in emitted.items():
             if toks:
                 # this request's share of the verify round spent on
@@ -1388,21 +1524,25 @@ class InferenceEngineV2:
         available), multi-token burst (steady greedy decode), or a plain
         SplitFuse step. Returns {uid: tokens emitted this round}. The
         open-loop SLO harness (tools/serve_bench.py) drives this."""
-        self._admit_from_queue()
-        out: Optional[Dict[int, List[int]]] = None
-        if temperature == 0.0:
-            out = self._try_spec_step(eos_token_id)
+        with self._open_step():
+            with span("admit"):
+                self._admit_from_queue()
+            out: Optional[Dict[int, List[int]]] = None
+            if temperature == 0.0:
+                out = self._try_spec_step(eos_token_id)
+                if out is None:
+                    out = self._try_decode_burst(eos_token_id)
             if out is None:
-                out = self._try_decode_burst(eos_token_id)
-        if out is None:
-            emitted = self.step(temperature, seed, eos_token_id)
-            out = {uid: [tok] for uid, tok in emitted.items()}
-        jr = get_journal()
-        if jr is not None and out and jr.claim_ingress(
-                self._journal_owner) == self._journal_owner:
-            for uid, toks in out.items():
-                if toks:
-                    jr.emit(uid, toks)
+                emitted = self._splitfuse_step(temperature, seed,
+                                               eos_token_id)
+                out = {uid: [tok] for uid, tok in emitted.items()}
+            with span("journal"):
+                jr = get_journal()
+                if jr is not None and out and jr.claim_ingress(
+                        self._journal_owner) == self._journal_owner:
+                    for uid, toks in out.items():
+                        if toks:
+                            jr.emit(uid, toks)
         return out
 
     def generate_all(self, temperature: float = 0.0, seed: int = 0,
@@ -1437,6 +1577,18 @@ class InferenceEngineV2:
         drop = set(uids)
         if any(r.uid in drop for r in self._queue):
             self._queue = deque(r for r in self._queue if r.uid not in drop)
+
+    def close(self) -> None:
+        """Stop what this engine's observability started: the request
+        tracer's crash-dump context leaves the flight recorder and the
+        hub's Prometheus page is written once more. Idempotent. Device
+        state (weights, KV pool) goes with the object; the process-wide
+        hub, flight recorder and crash handlers stay for other engines."""
+        if self._closed:
+            return
+        self._closed = True
+        self.tracer.detach_flight()
+        self._hub.write_prometheus()
 
     def log_summary(self) -> Dict[str, Any]:
         """Serve-path telemetry (the comms-logger log_summary analog):

@@ -67,6 +67,35 @@ def _kernel_pages() -> int:
         if kcfg is not None else 1
 
 
+@jax.named_scope("kv_write")
+def _kv_write(kv_layer, kv_sc, page, offset, k, v):
+    """Scatter this step's keys and values into their pages; a quantized
+    pool quantizes on append (one scale per head vector). Returns
+    (kv_layer', kv_sc')."""
+    if kv_sc is None:
+        kv_layer = kv_layer.at[page, offset, 0].set(k.astype(kv_layer.dtype))
+        kv_layer = kv_layer.at[page, offset, 1].set(v.astype(kv_layer.dtype))
+        return kv_layer, None
+    bits = _kv_bits(kv_layer)
+    qk, sk = kv_quantize(k, bits=bits)
+    qv, sv = kv_quantize(v, bits=bits)
+    kv_layer = kv_layer.at[page, offset, 0].set(kv_pack(qk, bits))
+    kv_layer = kv_layer.at[page, offset, 1].set(kv_pack(qv, bits))
+    kv_sc = kv_sc.at[page, offset, 0].set(sk)
+    kv_sc = kv_sc.at[page, offset, 1].set(sv)
+    return kv_layer, kv_sc
+
+
+def _kv_dense(kv_layer, kv_sc, dt):
+    """The layer pool as the Pallas kernels read it: a quantized pool
+    dequantizes its per-layer slice (transient, 1/L of the bf16 pool);
+    the persistent pool stays int8 / packed int4 / fp8."""
+    if kv_sc is None:
+        return kv_layer
+    return kv_dequantize(kv_unpack(kv_layer, _kv_bits(kv_layer)), kv_sc,
+                         dtype=dt)
+
+
 def _qkv(cfg: TransformerConfig, layer_params, y, positions):
     """Project y [..., H] to q/k/v with rope applied. Returns q [.., nh, hd],
     k/v [.., nkv, hd] (GQA heads NOT repeated — cache stays small)."""
@@ -85,6 +114,7 @@ def _qkv(cfg: TransformerConfig, layer_params, y, positions):
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _mlp(cfg: TransformerConfig, layer_params, x):
     if "moe" in layer_params:
         return _moe_mlp(cfg, layer_params, x)
@@ -127,7 +157,10 @@ def _moe_mlp(cfg, layer_params, x):
     return x + (out[0] if y.ndim == 2 else out)
 
 
+@jax.named_scope("head")
 def _unembed(cfg: TransformerConfig, params, x):
+    """Final norm + unembedding: hidden [..., H] -> fp32 logits."""
+    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = jnp.einsum("...h,vh->...v", x,
                             params["embed"]["tokens"].astype(x.dtype))
@@ -196,7 +229,6 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
         return _mlp(cfg, layer_params, x), kv_layer
 
     x, new_cache = lax.scan(layer_body, x, (params["layers"], cache))
-    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     return _unembed(cfg, params, x), new_cache
 
 
@@ -256,45 +288,38 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
             kv_sc = None
         else:
             layer_params, kv_layer, kv_sc = inputs
-        y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(cfg, layer_params, y, token_pos)  # q [T,nh,hd] k/v [T,nkv,hd]
-        if kv_sc is None:
-            kv_layer = kv_layer.at[page, offset, 0].set(
-                k.astype(kv_layer.dtype))
-            kv_layer = kv_layer.at[page, offset, 1].set(
-                v.astype(kv_layer.dtype))
-        else:
-            bits = _kv_bits(kv_layer)
-            qk, sk = kv_quantize(k, bits=bits)  # quantize-on-append
-            qv, sv = kv_quantize(v, bits=bits)  # per head vector
-            kv_layer = kv_layer.at[page, offset, 0].set(kv_pack(qk, bits))
-            kv_layer = kv_layer.at[page, offset, 1].set(kv_pack(qv, bits))
-            kv_sc = kv_sc.at[page, offset, 0].set(sk)
-            kv_sc = kv_sc.at[page, offset, 1].set(sv)
-        # gather each slot's pages into dense [S, Lmax, nkv, hd]
-        gathered = kv_layer[block_table]  # [S, Bm, bs, 2, nkv, hd(/2)]
-        if kv_sc is not None:
-            # dequant-on-read: only the gathered pages, never the pool
-            gathered = kv_dequantize(
-                kv_unpack(gathered, _kv_bits(kv_layer)),
-                kv_sc[block_table], dtype=dt)
-        gathered = gathered.reshape(Smax, max_ctx, 2, cfg.kv_heads,
-                                    cfg.head_dim)
-        k_seq = gathered[:, :, 0][token_seq]  # [T, Lmax, nkv, hd]
-        v_seq = gathered[:, :, 1][token_seq]
-        if rep > 1:
-            k_seq = jnp.repeat(k_seq, rep, axis=2)
-            v_seq = jnp.repeat(v_seq, rep, axis=2)
-        scores = jnp.einsum("tnd,tmnd->tnm", q, k_seq.astype(dt))
-        scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim)).astype(dt)
-        mask = key_pos[None, None, :] <= token_pos[:, None, None]
-        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-        attn = jnp.einsum("tnm,tmnd->tnd", probs, v_seq.astype(dt))
-        attn = jnp.einsum("tnd,ndh->th", attn,
-                          layer_params["attn"]["wo"].astype(dt))
-        if cfg.use_biases:
-            attn = attn + layer_params["attn"]["bo"].astype(dt)
+        with jax.named_scope("attn"):
+            y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
+            # q [T,nh,hd] k/v [T,nkv,hd]
+            q, k, v = _qkv(cfg, layer_params, y, token_pos)
+        kv_layer, kv_sc = _kv_write(kv_layer, kv_sc, page, offset, k, v)
+        with jax.named_scope("kv_gather"):
+            # gather each slot's pages into dense [S, Lmax, nkv, hd], then
+            # one row of the full context per *token*: [T, Lmax, nh, hd]
+            gathered = kv_layer[block_table]  # [S, Bm, bs, 2, nkv, hd(/2)]
+            if kv_sc is not None:
+                # dequant-on-read: only the gathered pages, never the pool
+                gathered = kv_dequantize(
+                    kv_unpack(gathered, _kv_bits(kv_layer)),
+                    kv_sc[block_table], dtype=dt)
+            gathered = gathered.reshape(Smax, max_ctx, 2, cfg.kv_heads,
+                                        cfg.head_dim)
+            k_seq = gathered[:, :, 0][token_seq]  # [T, Lmax, nkv, hd]
+            v_seq = gathered[:, :, 1][token_seq]
+            if rep > 1:
+                k_seq = jnp.repeat(k_seq, rep, axis=2)
+                v_seq = jnp.repeat(v_seq, rep, axis=2)
+        with jax.named_scope("attn"):
+            scores = jnp.einsum("tnd,tmnd->tnm", q, k_seq.astype(dt))
+            scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim)).astype(dt)
+            mask = key_pos[None, None, :] <= token_pos[:, None, None]
+            scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+            attn = jnp.einsum("tnm,tmnd->tnd", probs, v_seq.astype(dt))
+            attn = jnp.einsum("tnd,ndh->th", attn,
+                              layer_params["attn"]["wo"].astype(dt))
+            if cfg.use_biases:
+                attn = attn + layer_params["attn"]["bo"].astype(dt)
         kv_out = kv_layer if kv_sc is None else (kv_layer, kv_sc)
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             return _mlp(cfg, layer_params, x) + attn, kv_out
@@ -304,7 +329,6 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     xs = ((params["layers"], kv_data) if kv_scales is None
           else (params["layers"], kv_data, kv_scales))
     x, new_kv = lax.scan(layer_body, x, xs)
-    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     return _unembed(cfg, params, x), new_kv
 
 
@@ -406,33 +430,18 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
             kv_sc = None
         else:
             layer_params, kv_layer, kv_sc = inputs
-        y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(cfg, layer_params, y, pos)  # q [S,Tq,nh,hd]
-        if kv_sc is None:
-            kv_layer = kv_layer.at[page, offset, 0].set(
-                k.astype(kv_layer.dtype))
-            kv_layer = kv_layer.at[page, offset, 1].set(
-                v.astype(kv_layer.dtype))
-            kv_read = kv_layer
-        else:
-            bits = _kv_bits(kv_layer)
-            qk, sk = kv_quantize(k, bits=bits)
-            qv, sv = kv_quantize(v, bits=bits)
-            kv_layer = kv_layer.at[page, offset, 0].set(kv_pack(qk, bits))
-            kv_layer = kv_layer.at[page, offset, 1].set(kv_pack(qv, bits))
-            kv_sc = kv_sc.at[page, offset, 0].set(sk)
-            kv_sc = kv_sc.at[page, offset, 1].set(sv)
-            # the Pallas kernel reads a dense layer pool; dequantize the
-            # per-layer slice (transient, 1/L of the bf16 pool) — the
-            # persistent pool stays int8/packed-int4
-            kv_read = kv_dequantize(kv_unpack(kv_layer, bits), kv_sc,
-                                    dtype=dt)
-        attn = _paged_prefill(mesh, q.astype(dt), kv_read, block_table,
-                              seg_pos0, ctx_lens)
-        attn = jnp.einsum("stnd,ndh->sth", attn.astype(dt),
-                          layer_params["attn"]["wo"].astype(dt))
-        if cfg.use_biases:
-            attn = attn + layer_params["attn"]["bo"].astype(dt)
+        with jax.named_scope("attn"):
+            y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
+            q, k, v = _qkv(cfg, layer_params, y, pos)  # q [S,Tq,nh,hd]
+        kv_layer, kv_sc = _kv_write(kv_layer, kv_sc, page, offset, k, v)
+        with jax.named_scope("attn"):
+            attn = _paged_prefill(mesh, q.astype(dt),
+                                  _kv_dense(kv_layer, kv_sc, dt),
+                                  block_table, seg_pos0, ctx_lens)
+            attn = jnp.einsum("stnd,ndh->sth", attn.astype(dt),
+                              layer_params["attn"]["wo"].astype(dt))
+            if cfg.use_biases:
+                attn = attn + layer_params["attn"]["bo"].astype(dt)
         kv_out = kv_layer if kv_sc is None else (kv_layer, kv_sc)
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             return _mlp(cfg, layer_params, x) + attn, kv_out
@@ -442,7 +451,6 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
     xs = ((params["layers"], kv_data) if kv_scales is None
           else (params["layers"], kv_data, kv_scales))
     x, new_kv = lax.scan(layer_body, x, xs)
-    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     return _unembed(cfg, params, x), new_kv
 
 
@@ -492,30 +500,18 @@ def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
             kv_sc = None
         else:
             layer_params, kv_layer, kv_sc = inputs
-        y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
-        q, k, v = _qkv(cfg, layer_params, y, token_pos)  # q [S,nh,hd]
-        if kv_sc is None:
-            kv_layer = kv_layer.at[page, offset, 0].set(
-                k.astype(kv_layer.dtype))
-            kv_layer = kv_layer.at[page, offset, 1].set(
-                v.astype(kv_layer.dtype))
-            kv_read = kv_layer
-        else:
-            bits = _kv_bits(kv_layer)
-            qk, sk = kv_quantize(k, bits=bits)
-            qv, sv = kv_quantize(v, bits=bits)
-            kv_layer = kv_layer.at[page, offset, 0].set(kv_pack(qk, bits))
-            kv_layer = kv_layer.at[page, offset, 1].set(kv_pack(qv, bits))
-            kv_sc = kv_sc.at[page, offset, 0].set(sk)
-            kv_sc = kv_sc.at[page, offset, 1].set(sv)
-            kv_read = kv_dequantize(kv_unpack(kv_layer, bits), kv_sc,
-                                    dtype=dt)
-        attn = _paged_decode(mesh, q.astype(dt), kv_read, block_table,
-                             context_lens)
-        attn = jnp.einsum("snd,ndh->sh", attn.astype(dt),
-                          layer_params["attn"]["wo"].astype(dt))
-        if cfg.use_biases:
-            attn = attn + layer_params["attn"]["bo"].astype(dt)
+        with jax.named_scope("attn"):
+            y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
+            q, k, v = _qkv(cfg, layer_params, y, token_pos)  # q [S,nh,hd]
+        kv_layer, kv_sc = _kv_write(kv_layer, kv_sc, page, offset, k, v)
+        with jax.named_scope("attn"):
+            attn = _paged_decode(mesh, q.astype(dt),
+                                 _kv_dense(kv_layer, kv_sc, dt),
+                                 block_table, context_lens)
+            attn = jnp.einsum("snd,ndh->sh", attn.astype(dt),
+                              layer_params["attn"]["wo"].astype(dt))
+            if cfg.use_biases:
+                attn = attn + layer_params["attn"]["bo"].astype(dt)
         kv_out = kv_layer if kv_sc is None else (kv_layer, kv_sc)
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             return _mlp(cfg, layer_params, x) + attn, kv_out
@@ -525,7 +521,6 @@ def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     xs = ((params["layers"], kv_data) if kv_scales is None
           else (params["layers"], kv_data, kv_scales))
     x, new_kv = lax.scan(layer_body, x, xs)
-    x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     return _unembed(cfg, params, x), new_kv
 
 

@@ -618,38 +618,40 @@ def _layer(cfg: TransformerConfig, x, layer_params, positions,
         attn = constrain_activation(
             checkpoint_name(attn, "attn_out"), ("batch", "seq", "embed"))
         return _layer_mlp(cfg, x, attn, layer_params)
-    y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
-    q = jnp.einsum("bsh,hnd->bsnd", y, ap["wq"].astype(dt))
-    k = jnp.einsum("bsh,hnd->bsnd", y, ap["wk"].astype(dt))
-    v = jnp.einsum("bsh,hnd->bsnd", y, ap["wv"].astype(dt))
-    if cfg.use_biases:
-        q = q + ap["bq"].astype(dt)
-        k = k + ap["bk"].astype(dt)
-        v = v + ap["bv"].astype(dt)
-    q = checkpoint_name(q, "qkv_proj")
-    k = checkpoint_name(k, "qkv_proj")
-    v = checkpoint_name(v, "qkv_proj")
-    if cfg.pos_emb == "rope":
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    q = constrain_activation(q, ("batch", "seq", "heads", None))
-    k = constrain_activation(k, ("batch", "seq", "heads", None))
-    v = constrain_activation(v, ("batch", "seq", "heads", None))
-    if cfg.sequence_parallel or cfg.attn_chunks > 1:
-        # GQA: the SP all-to-all / chunked paths split on the head axis
-        # and need equal q/kv head counts; the plain path keeps KV at
-        # kv_heads — the flash kernel reads grouped KV natively.
-        from deepspeed_tpu.ops.attention import repeat_kv_heads
-        k, v = repeat_kv_heads(q, k, v)
-    attn = checkpoint_name(_attention(q, k, v, cfg), "attn_kernel_out")
-    attn = jnp.einsum("bsnd,ndh->bsh", attn, ap["wo"].astype(dt))
-    if cfg.use_biases:
-        attn = attn + ap["bo"].astype(dt)
-    attn = constrain_activation(
-        checkpoint_name(attn, "attn_out"), ("batch", "seq", "embed"))
+    with jax.named_scope("attn"):
+        y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
+        q = jnp.einsum("bsh,hnd->bsnd", y, ap["wq"].astype(dt))
+        k = jnp.einsum("bsh,hnd->bsnd", y, ap["wk"].astype(dt))
+        v = jnp.einsum("bsh,hnd->bsnd", y, ap["wv"].astype(dt))
+        if cfg.use_biases:
+            q = q + ap["bq"].astype(dt)
+            k = k + ap["bk"].astype(dt)
+            v = v + ap["bv"].astype(dt)
+        q = checkpoint_name(q, "qkv_proj")
+        k = checkpoint_name(k, "qkv_proj")
+        v = checkpoint_name(v, "qkv_proj")
+        if cfg.pos_emb == "rope":
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        q = constrain_activation(q, ("batch", "seq", "heads", None))
+        k = constrain_activation(k, ("batch", "seq", "heads", None))
+        v = constrain_activation(v, ("batch", "seq", "heads", None))
+        if cfg.sequence_parallel or cfg.attn_chunks > 1:
+            # GQA: the SP all-to-all / chunked paths split on the head axis
+            # and need equal q/kv head counts; the plain path keeps KV at
+            # kv_heads — the flash kernel reads grouped KV natively.
+            from deepspeed_tpu.ops.attention import repeat_kv_heads
+            k, v = repeat_kv_heads(q, k, v)
+        attn = checkpoint_name(_attention(q, k, v, cfg), "attn_kernel_out")
+        attn = jnp.einsum("bsnd,ndh->bsh", attn, ap["wo"].astype(dt))
+        if cfg.use_biases:
+            attn = attn + ap["bo"].astype(dt)
+        attn = constrain_activation(
+            checkpoint_name(attn, "attn_out"), ("batch", "seq", "embed"))
     return _layer_mlp(cfg, x, attn, layer_params)
 
 
+@jax.named_scope("mlp")
 def _layer_mlp(cfg: TransformerConfig, x, attn, layer_params):
     """Residual-add + MLP half of the block (shared by the standard and
     fpdt_host_kv attention paths)."""
@@ -746,10 +748,12 @@ def apply_hidden(cfg: TransformerConfig, params: Dict[str, Any],
             "path — apply_hidden would materialize the full-S buffer "
             "this mode removes")
 
-    x = vocab_parallel_lookup(params["embed"]["tokens"].astype(dt), tokens)
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["positions"].astype(dt)[positions]
-    x = constrain_activation(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        x = vocab_parallel_lookup(params["embed"]["tokens"].astype(dt),
+                                  tokens)
+        if cfg.pos_emb == "learned":
+            x = x + params["embed"]["positions"].astype(dt)[positions]
+        x = constrain_activation(x, ("batch", "seq", "embed"))
 
     layer_fn = partial(_layer, cfg)
 
@@ -864,7 +868,8 @@ def apply_hidden(cfg: TransformerConfig, params: Dict[str, Any],
 
     if not final_norm:
         return x
-    return _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+    with jax.named_scope("head_loss"):
+        return _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
 
 
 def apply_hidden_hosted(cfg: TransformerConfig, params: Dict[str, Any],
@@ -1000,6 +1005,12 @@ def apply(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
         x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     else:
         x = apply_hidden(cfg, params, tokens, positions)
+    return _unembed_logits(cfg, params, x)
+
+
+@jax.named_scope("head_loss")
+def _unembed_logits(cfg: TransformerConfig, params, x) -> jax.Array:
+    dt = cfg.dtype
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsh,vh->bsv", x, params["embed"]["tokens"].astype(dt))
     else:
@@ -1045,38 +1056,41 @@ def loss_fn(cfg: TransformerConfig, params, batch) -> Tuple[jax.Array, Dict]:
 
         hidden = apply_hidden(cfg, params, inputs, final_norm=False)
 
-        def fnorm_tile(h):
-            return _norm(h, params["final_norm"], cfg.norm, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            # the table also feeds the token lookup; its gather stays
-            # exact (quantizing it would noise embeddings, not just wire)
-            unembed = params["embed"]["tokens"].astype(cfg.dtype)
-            transpose = True
-        else:
-            from deepspeed_tpu.runtime.sharding import (
-                quantized_param_fetch, qwz_sequence_barrier)
+        with jax.named_scope("head_loss"):
+            def fnorm_tile(h):
+                return _norm(h, params["final_norm"], cfg.norm, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                # the table also feeds the token lookup; its gather stays
+                # exact (quantizing it would noise embeddings, not just wire)
+                unembed = params["embed"]["tokens"].astype(cfg.dtype)
+                transpose = True
+            else:
+                from deepspeed_tpu.runtime.sharding import (
+                    quantized_param_fetch, qwz_sequence_barrier)
 
-            unembed, hidden = qwz_sequence_barrier(
-                params["unembed"]["kernel"], hidden)
-            unembed = quantized_param_fetch(
-                unembed, ("embed", "vocab"), path="['unembed']['kernel']")
-            unembed = unembed.astype(cfg.dtype)
-            transpose = False
-        nll_sum, total = tiled_logits_loss(
-            hidden, unembed, labels, mask, cfg.tiled_logits,
-            transpose_unembed=transpose, tile_transform=fnorm_tile)
-        total = jnp.maximum(total, 1.0)
-        loss = nll_sum / total
-        return loss, {"loss": loss, "ntokens": total}
+                unembed, hidden = qwz_sequence_barrier(
+                    params["unembed"]["kernel"], hidden)
+                unembed = quantized_param_fetch(
+                    unembed, ("embed", "vocab"), path="['unembed']['kernel']")
+                unembed = unembed.astype(cfg.dtype)
+                transpose = False
+            nll_sum, total = tiled_logits_loss(
+                hidden, unembed, labels, mask, cfg.tiled_logits,
+                transpose_unembed=transpose, tile_transform=fnorm_tile)
+            total = jnp.maximum(total, 1.0)
+            loss = nll_sum / total
+            return loss, {"loss": loss, "ntokens": total}
 
     logits = apply(cfg, params, inputs)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    if mask is None:
-        mask = jnp.ones_like(nll)
-    total = jnp.maximum(mask.sum(), 1.0)
-    loss = (nll * mask).sum() / total
+    with jax.named_scope("head_loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None],
+                                   axis=-1)[..., 0]
+        nll = logz - gold
+        if mask is None:
+            mask = jnp.ones_like(nll)
+        total = jnp.maximum(mask.sum(), 1.0)
+        loss = (nll * mask).sum() / total
     return loss, {"loss": loss, "ntokens": total}
 
 

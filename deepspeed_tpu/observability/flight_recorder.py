@@ -81,6 +81,13 @@ class FlightRecorder:
         carry. Last registration per name wins."""
         self._dump_context[name] = provider
 
+    def remove_dump_context(self, name: str, provider=None) -> None:
+        """Drop a registered provider (an engine's ``close()``). With
+        ``provider`` given, only if it is still the one registered: a
+        later engine's registration under the same name stays."""
+        if provider is None or self._dump_context.get(name) == provider:
+            self._dump_context.pop(name, None)
+
     # -- configuration -------------------------------------------------
     def configure(self, capacity: Optional[int] = None,
                   rank: Optional[int] = None,

@@ -8,10 +8,13 @@ with different flags: the window is checked against the engine's step
 counter at the train_batch boundary, so a long run can be profiled by
 setting the env var before launch and letting the window pass.
 
-The per-step phases inside the capture are named by the
-``jax.profiler.StepTraceAnnotation`` wrapped around each traced step
-plus the ``utils/annotate.py`` scopes already present in the model code
-(attention/mlp/collective ranges show up under those names).
+What the capture shows by name (docs/observability.md, "Profiler spans
+and names"): each step is a ``dstpu/train_batch`` step span with the
+host phases nested in it (``utils/annotate.py``; the engine opens them
+whether or not a capture runs, a profiler session is what records
+them), the device programs are ``jit_dstpu_*`` modules, the Pallas
+kernels carry their ``name=``, and ``forward_backward`` / ``optimizer``
+/ ``attn`` / ``mlp`` are ``jax.named_scope`` regions of the HLO.
 """
 
 from __future__ import annotations
@@ -53,7 +56,7 @@ class TraceCapture:
                                                  "/tmp/dstpu_trace")
         self.active = False
         self.done = False
-        self._step_ann = None
+        self._stop_due = False
 
     @classmethod
     def from_env(cls) -> "TraceCapture":
@@ -82,28 +85,27 @@ class TraceCapture:
             except Exception as e:
                 logger.warning(f"profiler trace start failed: {e}")
                 self.done = True
-                return
-        if self.active:
-            import jax
-
-            # named step boundary inside the capture (xprof groups by it)
-            self._step_ann = jax.profiler.StepTraceAnnotation(
-                "train_batch", step_num=step)
-            self._step_ann.__enter__()
 
     def on_step_end(self, step: int) -> None:
-        if self._step_ann is not None:
-            self._step_ann.__exit__(None, None, None)
-            self._step_ann = None
+        """Call when a step's results have resolved. The session closes
+        in ``stop_if_due``, once the caller has left the step's own
+        ``dstpu/train_batch`` span: a span that ends after the session
+        is lost."""
         if self.active and step >= self.window[1]:
-            import jax
+            self._stop_due = True
 
-            try:
-                jax.profiler.stop_trace()
-                logger.warning(
-                    f"profiler trace stopped after step {step}; view with "
-                    f"`tensorboard --logdir {self.out_dir}` (profile tab)")
-            except Exception as e:
-                logger.warning(f"profiler trace stop failed: {e}")
-            self.active = False
-            self.done = True   # one capture per process
+    def stop_if_due(self) -> None:
+        if not (self.active and self._stop_due):
+            return
+        import jax
+
+        try:
+            jax.profiler.stop_trace()
+            logger.warning(
+                f"profiler trace stopped after step {self.window[1]}; "
+                f"view with `tensorboard --logdir {self.out_dir}` "
+                "(profile tab)")
+        except Exception as e:
+            logger.warning(f"profiler trace stop failed: {e}")
+        self.active = False
+        self.done = True   # one capture per process

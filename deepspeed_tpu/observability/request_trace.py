@@ -341,6 +341,13 @@ class RequestTracer:
         if add is not None:
             add("requests_in_flight", self._inflight_summary)
 
+    def detach_flight(self) -> None:
+        """Undo ``attach_flight`` (the engine's ``close()``)."""
+        remove = getattr(self._flight, "remove_dump_context", None)
+        if remove is not None:
+            remove("requests_in_flight", self._inflight_summary)
+        self._flight = None
+
     def _inflight_summary(self) -> List[Dict[str, Any]]:
         out = []
         for t in list(self._active.values()):
@@ -402,15 +409,20 @@ class RequestTracer:
         t.add("PREFIX_HIT", _wall(), tokens=int(tokens))
 
     def on_prefill(self, uid: int, start: float, dur_ms: float,
-                   tokens: int, start_pos: int) -> None:
+                   tokens: int, start_pos: int,
+                   step_id: Optional[int] = None) -> None:
+        """``step_id``: the engine step that computed the chunk, the id
+        of its ``dstpu/serve_step`` span on the profiler's clock."""
         t = self._active.get(uid) if self.enabled else None
         if t is None:
             return
+        ids = {} if step_id is None else {"step_id": int(step_id)}
         t.add("PREFILL", start, dur_ms=dur_ms, tokens=int(tokens),
-              start_pos=int(start_pos))
+              start_pos=int(start_pos), **ids)
 
     def on_emit(self, uid: int, n_tokens: int,
-                spec_overhead_ms: float = 0.0) -> None:
+                spec_overhead_ms: float = 0.0,
+                step_id: Optional[int] = None) -> None:
         t = self._active.get(uid) if self.enabled else None
         if t is None:
             return
@@ -420,6 +432,8 @@ class RequestTracer:
             t.first_token_ts = now
         t.generated_tokens += int(n_tokens)
         fields: Dict[str, Any] = {"n": int(n_tokens)}
+        if step_id is not None:
+            fields["step_id"] = int(step_id)
         if first:
             fields["first"] = True
         if spec_overhead_ms > 0.0:

@@ -316,6 +316,7 @@ def blocksparse_attention_pallas(q, k, v, sparsity: SparsityConfig,
                                causal=causal, block_q=block, block_k=block)
     o = pl.pallas_call(
         kernel,
+        name="blocksparse_fwd",
         grid=(B * N, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # layout [nq, nk]

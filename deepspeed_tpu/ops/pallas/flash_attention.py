@@ -245,6 +245,7 @@ def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
         block_q=block_q, block_k=block_k, nq=nq, nk=nk)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(BHq, _num_items(nq, nk, causal)),
         in_specs=in_specs,
         out_specs=[
@@ -423,6 +424,7 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           has_segments=has_segments,
                           block_q=block_q, block_k=block_k, nq=nq, nk=nk),
+        name="flash_bwd_dkdv",
         grid=(BHkv, _num_items(nq, nk, causal), g),
         in_specs=dkdv_in_specs,
         out_specs=[
@@ -477,6 +479,7 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           has_segments=has_segments,
                           block_q=block_q, block_k=block_k, nq=nq, nk=nk),
+        name="flash_bwd_dq",
         grid=(BHq, _num_items(nq, nk, causal)),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, D),

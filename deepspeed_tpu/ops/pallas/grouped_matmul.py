@@ -182,6 +182,7 @@ def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, block_m=block_m,
                           transpose_rhs=transpose_rhs),
+        name="grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
@@ -245,6 +246,7 @@ def _tgmm_call(lhs: jax.Array, dout: jax.Array, group_sizes: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_tgmm_kernel, block_m=block_m),
+        name="grouped_matmul_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,

@@ -238,6 +238,7 @@ def paged_prefill_attention(q: jax.Array, kv_layer: jax.Array,
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, bs=bs, nkv=nkv, g=g, tq=tq,
                           scale=float(scale), pages=P),
+        name="paged_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nkv, tq * g, hd), q.dtype),
         interpret=_interpret(),
@@ -312,6 +313,7 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, nkv=nkv, gp=gp,
                           scale=float(scale), pages=P),
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nkv, gp, hd), q.dtype),
         interpret=_interpret(),

@@ -105,6 +105,7 @@ def quantize_blockwise(x: jax.Array, bits: int = 8,
     tile = min(rows, 256)
     q, s = pl.pallas_call(
         functools.partial(_quant_kernel, bits=bits, block=block),
+        name="quantize_blockwise",
         # cdiv: a row count off the 256 grid keeps its tail rows (the
         # last tile is partial)
         grid=(pl.cdiv(rows, tile),),

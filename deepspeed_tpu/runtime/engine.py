@@ -50,6 +50,7 @@ from deepspeed_tpu.runtime.optimizer import (
     init_mixed_precision)
 from deepspeed_tpu.runtime.prefetch import PrefetchingIterator
 from deepspeed_tpu.utils import memspace
+from deepspeed_tpu.utils.annotate import named, span, step_span
 from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
@@ -537,6 +538,8 @@ class Engine:
         self._prefetcher = None       # PrefetchingIterator over data_iter
         self._prefetch_source = None  # the data_iter the prefetcher owns
         self._last_drain_t = None     # perf_counter at the previous drain
+        self._last_grad_norm = None   # get_global_grad_norm()
+        self._closed = False
         if self._dispatch_ahead > 0:
             log_dist(f"pipelined loop: dispatch-ahead depth "
                      f"{self._dispatch_ahead}, input prefetch depth "
@@ -970,22 +973,26 @@ class Engine:
                 # the group dim carries the batch axes; activation
                 # constraints inside the mapped trace must not re-pin
                 # them (sharding.vmapped_axes)
-                with shard_lib.vmapped_axes(topo.BATCH_AXES):
-                    (_, (losses_g, ntoks_g)), g_groups = jax.vmap(
-                        jax.value_and_grad(per_group, has_aux=True),
-                        in_axes=(None, 1))(params, grouped)
-                g_groups = jax.tree.map(
-                    lambda g: g.astype(jnp.float32), g_groups)
-                grads = qgz_reduce_tree(g_groups, grad_sh, self.mesh)
+                with jax.named_scope("forward_backward"):
+                    with shard_lib.vmapped_axes(topo.BATCH_AXES):
+                        (_, (losses_g, ntoks_g)), g_groups = jax.vmap(
+                            jax.value_and_grad(per_group, has_aux=True),
+                            in_axes=(None, 1))(params, grouped)
+                    g_groups = jax.tree.map(
+                        lambda g: g.astype(jnp.float32), g_groups)
+                    grads = qgz_reduce_tree(g_groups, grad_sh, self.mesh)
                 losses = jnp.mean(losses_g, axis=0)
                 ntoks = jnp.sum(ntoks_g, axis=0)
             else:
-                (_, (losses, ntoks)), grads = jax.value_and_grad(
-                    total_loss, has_aux=True)(params)
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                grads = _constrain_tree(grads, grad_sh)
-            params, opt_state, new_ls, new_step, metrics = apply_update(
-                params, opt_state, ls_state, step, grads, ntoks)
+                with jax.named_scope("forward_backward"):
+                    (_, (losses, ntoks)), grads = jax.value_and_grad(
+                        total_loss, has_aux=True)(params)
+                    grads = jax.tree.map(
+                        lambda g: g.astype(jnp.float32), grads)
+                    grads = _constrain_tree(grads, grad_sh)
+            with jax.named_scope("optimizer"):
+                params, opt_state, new_ls, new_step, metrics = apply_update(
+                    params, opt_state, ls_state, step, grads, ntoks)
             metrics["loss"] = jnp.mean(losses)
             return params, opt_state, new_ls, new_step, metrics
 
@@ -1049,14 +1056,19 @@ class Engine:
             return grads, jnp.mean(losses)
 
         donate = (0, 1, 2, 3)
-        self._jit_train_step = jax.jit(train_step, donate_argnums=donate)
-        self._jit_grad_step = jax.jit(grad_step)
+        # stable program names: the device trace's module line and the
+        # HLO say jit_dstpu_train_step, not jit_train_step or _unknown
+        self._jit_train_step = jax.jit(named(train_step, "dstpu_train_step"),
+                                       donate_argnums=donate)
+        self._jit_grad_step = jax.jit(named(grad_step, "dstpu_grad_step"))
         if self._onebit:
-            self._jit_onebit = jax.jit(self._onebit_step_fn,
-                                       donate_argnums=(0, 1))
+            self._jit_onebit = jax.jit(
+                named(self._onebit_step_fn, "dstpu_onebit_step"),
+                donate_argnums=(0, 1))
         if self._zeropp:
-            self._jit_zeropp = jax.jit(self._zeropp_step_fn,
-                                       donate_argnums=(0, 1))
+            self._jit_zeropp = jax.jit(
+                named(self._zeropp_step_fn, "dstpu_zeropp_step"),
+                donate_argnums=(0, 1))
         # offload resharding hops: host-updated (optimizer-sharded) tree →
         # param sharding = the "allgather updated partitions" collective,
         # compiled by XLA over ICI; and grad-acc → optimizer sharding.
@@ -1081,9 +1093,10 @@ class Engine:
                 t, host_sh)
         self._jit_to_opt_sharding = jax.jit(
             lambda t: t, out_shardings=opt_sh)
-        self._jit_fwd_bwd = jax.jit(fwd_bwd)
-        self._jit_apply = jax.jit(apply_update, donate_argnums=(0, 1, 2, 3, 4))
-        self._jit_eval = jax.jit(model_loss)
+        self._jit_fwd_bwd = jax.jit(named(fwd_bwd, "dstpu_fwd_bwd"))
+        self._jit_apply = jax.jit(named(apply_update, "dstpu_apply_update"),
+                                  donate_argnums=(0, 1, 2, 3, 4))
+        self._jit_eval = jax.jit(named(model_loss, "dstpu_eval"))
         self._jit_accumulate = jax.jit(
             lambda acc, g, c: jax.tree.map(lambda a, b: a + b * c, acc, g),
             donate_argnums=(0,))
@@ -1185,26 +1198,36 @@ class Engine:
                 raise ValueError("train_batch needs data_iter or training_data")
             data_iter = iter(self.training_dataloader)
         self._last_data_iter = data_iter  # data_cursor loader-state source
+        step_no = self.global_steps + 1
+        if self._trace_capture is not None:
+            self._trace_capture.on_step_begin(step_no)
+        # the step's host phases as spans on the profiler's clock
+        # (utils/annotate.py): recorded only while a profiler session runs
+        with step_span("train_batch", step_no):
+            loss = self._train_batch(data_iter, step_no)
+        if self._trace_capture is not None:
+            self._trace_capture.stop_if_due()
+        return loss
+
+    def _train_batch(self, data_iter, step_no: int) -> jax.Array:
         depth = self._effective_depth()
         sync = depth == 0
         host_t0 = time.perf_counter()
         if sync:
             self.timers(TRAIN_BATCH_TIMER).start()
             self.tput_timer.start()
-        batches = self._next_batches(data_iter)
-        step_no = self.global_steps + 1
+        with span("next_batches"):
+            batches = self._next_batches(data_iter)
         if self._chaos is not None:
             self._chaos.on_step(step_no)
         if self.flight is not None:
             self.flight.record("step_entry", step=step_no,
                                inflight=len(self._inflight))
-        if self._trace_capture is not None:
-            self._trace_capture.on_step_begin(step_no)
         if sync and self.watchdog is not None:
             # armed until the step's results are blocked on below: a
             # wedged collective fires a stack/memory report
             self.watchdog.arm(step_no)
-        with topo.use_mesh(self.mesh):
+        with span("dispatch"), topo.use_mesh(self.mesh):
             metrics = self._dispatch_train_step(batches)
         dispatch_t = time.perf_counter()
         if self.flight is not None:
@@ -1214,9 +1237,10 @@ class Engine:
         # dispatch-order bookkeeping; the host READS defer to the drain
         self.global_steps += 1
         self.global_samples += self.train_batch_size
-        for hook in self._post_step_hooks:
-            hook(self)
-        self._ckpt_io.maybe_commit()
+        with span("ckpt_commit"):
+            for hook in self._post_step_hooks:
+                hook(self)
+            self._ckpt_io.maybe_commit()
         self._inflight.append(_InflightStep(
             step=step_no, metrics=metrics,
             struct=jax.tree.map(
@@ -1245,9 +1269,15 @@ class Engine:
         run its deferred host reads and emit its trace row."""
         entry = self._inflight.popleft()
         metrics = entry.metrics
+        with span("drain_wait"):
+            # the one block on the step's results; every host read
+            # below finds them resolved
+            jax.block_until_ready(metrics)
+        self._last_grad_norm = metrics.get("grad_norm")
         if entry.sync:
             # blocking path: identical ordering to the classic loop
-            self._after_step_host(metrics, entry.step, entry.samples)
+            with span("after_step_host"):
+                self._after_step_host(metrics, entry.step, entry.samples)
             self.timers(TRAIN_BATCH_TIMER).stop(block=metrics["loss"])
             wall_ms = self._last_step_wall_ms()
             if self._trace_capture is not None:
@@ -1257,7 +1287,6 @@ class Engine:
                 self.watchdog.observe(wall_ms / 1000.0, entry.step)
             self._last_drain_t = time.perf_counter()
         else:
-            jax.block_until_ready(metrics["loss"])
             resolved_t = time.perf_counter()
             # drain-to-drain span ≈ this step's device time once the
             # pipeline is full; during fill it degrades to dispatch→done
@@ -1265,8 +1294,9 @@ class Engine:
                     else max(self._last_drain_t, entry.host_t0))
             wall_ms = (resolved_t - base) * 1000.0
             self._last_drain_t = resolved_t
-            self._after_step_host(metrics, entry.step, entry.samples,
-                                  wall_s=wall_ms / 1000.0)
+            with span("after_step_host"):
+                self._after_step_host(metrics, entry.step, entry.samples,
+                                      wall_s=wall_ms / 1000.0)
             self.timers(TRAIN_BATCH_TIMER).record_ms(wall_ms)
             if self._trace_capture is not None:
                 self._trace_capture.on_step_end(entry.step)
@@ -1282,10 +1312,11 @@ class Engine:
                                wall_ms=round(wall_ms, 3),
                                inflight=len(self._inflight))
         if self.hub is not None:
-            self._emit_step_trace(entry.step, metrics, entry.struct,
-                                  wall_ms, host_gap_ms=entry.host_ms,
-                                  samples=entry.samples,
-                                  inflight=len(self._inflight))
+            with span("step_trace"):
+                self._emit_step_trace(entry.step, metrics, entry.struct,
+                                      wall_ms, host_gap_ms=entry.host_ms,
+                                      samples=entry.samples,
+                                      inflight=len(self._inflight))
 
     def synchronize(self) -> "Engine":
         """Drain every dispatched-but-unresolved train step (pipeline
@@ -1298,6 +1329,24 @@ class Engine:
         while self._inflight:
             self._drain_one()
         return self
+
+    def close(self) -> None:
+        """Drain the in-flight window and stop what this engine started:
+        the input prefetcher's worker and the stall watchdog's thread; the
+        hub's Prometheus page is written once more. Idempotent. The
+        process-wide hub and flight recorder stay for other engines;
+        device state goes with the object."""
+        if self._closed:
+            return
+        self._closed = True
+        self.synchronize()
+        if self._prefetcher is not None:
+            self._prefetcher.close()
+            self._prefetcher = None
+        if self.watchdog is not None:
+            self.watchdog.stop()
+        if self.hub is not None:
+            self.hub.write_prometheus()
 
     def _emergency_checkpoint(self) -> None:
         """Preemption-notice path: drain, save, force-commit — bounded by
@@ -1981,7 +2030,11 @@ class Engine:
         self._states_offloaded = False
 
     def get_global_grad_norm(self):
-        return getattr(self, "_last_grad_norm", None)
+        """The global gradient norm of the last step whose results the
+        host has read: the last *drained* ``train_batch`` step (the step
+        program computes it; no device read is added), or the last host
+        optimizer step under offload. None before the first."""
+        return self._last_grad_norm
 
     # -- reference-parity engine API ------------------------------------
     def no_sync(self):

@@ -4,10 +4,9 @@ from deepspeed_tpu.utils.logging import logger, log_dist  # noqa: F401
 # so the host-side launcher processes (runner.py, launch.py pre-binding)
 # never pay the jax import for `from deepspeed_tpu.utils.logging import ...`
 _LAZY = {
-    "instrument_w_nvtx": "deepspeed_tpu.utils.annotate",
     "instrument_w_profiler": "deepspeed_tpu.utils.annotate",
-    "range_push": "deepspeed_tpu.utils.annotate",
-    "range_pop": "deepspeed_tpu.utils.annotate",
+    "span": "deepspeed_tpu.utils.annotate",
+    "step_span": "deepspeed_tpu.utils.annotate",
     "OnDevice": "deepspeed_tpu.utils.init_on_device",
     "on_device": "deepspeed_tpu.utils.init_on_device",
     "see_memory_usage": "deepspeed_tpu.utils.memory",

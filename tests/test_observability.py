@@ -433,6 +433,8 @@ class TestServingSnapshot:
         e._decode_hist = Histogram("decode")
         e._admit_time = {1: 100.0}
         e._last_emit_time = {}
+        e._step_id = 0                         # and counts its steps
+        e.stats = {"ttft_s": 0.0, "first_tokens": 0}
         e._note_emitted(1, 1, now=100.5)       # first token: TTFT 0.5s
         e._note_emitted(1, 1, now=100.7)       # decode gap 0.2s
         e._note_emitted(1, 2, now=101.1)       # burst: 2 tokens over 0.4s
@@ -442,6 +444,8 @@ class TestServingSnapshot:
         d = e._decode_hist.snapshot()
         assert d["count"] == 3
         assert d["max"] == pytest.approx(0.2, rel=0.02)
+        # the flat counters hold the same observation as the histogram
+        assert e.stats == {"ttft_s": pytest.approx(0.5), "first_tokens": 1}
 
 
 # ---------------------------------------------------------------------------

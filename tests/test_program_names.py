@@ -1,0 +1,247 @@
+"""Names on the device: every jitted program lowers to a ``jit_dstpu_*``
+module, every ``pallas_call`` carries its kernel name, and the train step
+and the serving programs hold their ``jax.named_scope`` regions. Metadata
+only: nothing here runs a program (docs/observability.md, "Profiler spans
+and names")."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as dstpu
+from deepspeed_tpu.inference import engine_v2
+from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.zoo import get_model
+from deepspeed_tpu.ops.pallas import (blocksparse_attention, flash_attention,
+                                      grouped_matmul, paged_attention,
+                                      quantization)
+
+F32, I32 = jnp.float32, jnp.int32
+
+
+def _sds(shape, dtype=F32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _module(lowered) -> str:
+    return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+
+# -- serving programs --------------------------------------------------------
+
+T, S, BM, NB, BS = 16, 4, 8, 32, 8      # tokens, slots, pages/seq, pool, page
+
+
+@pytest.fixture(scope="module")
+def serve_lowered():
+    """Each serving program lowered on abstract arguments of a tiny model."""
+    model = get_model("tiny")
+    cfg = model.config
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    kv = _sds((cfg.num_layers, NB, BS, 2, cfg.kv_heads, cfg.head_dim))
+    fns = engine_v2._shared_step_fns(cfg, None)
+    ids = lambda *shape: _sds(shape, I32)  # noqa: E731
+    return {
+        "gather": fns["step"].lower(params, kv, ids(T), ids(T), ids(T),
+                                    ids(S, BM), ids()),
+        "prefill": fns["prefill"].lower(params, kv, ids(2, 8), ids(2),
+                                        ids(2), ids(2, BM)),
+        "decode": fns["decode"].lower(params, kv, ids(S), ids(S),
+                                      ids(S, BM), ids(S)),
+        "multi_decode": fns["multi_decode"].lower(
+            params, kv, ids(S), ids(S), ids(S, BM), ids(S), steps=3),
+    }
+
+
+@pytest.mark.parametrize("program", ["gather", "prefill", "decode",
+                                     "multi_decode"])
+def test_serving_program_module_name(serve_lowered, program):
+    assert _module(serve_lowered[program]) == f"jit_dstpu_serve_{program}"
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("gather", ["kv_write", "kv_gather", "attn", "mlp", "head"]),
+    ("prefill", ["kv_write", "attn", "mlp", "head"]),
+    ("decode", ["kv_write", "attn", "mlp", "head"]),
+    ("multi_decode", ["kv_write", "attn", "mlp", "head"]),
+])
+def test_serving_program_scopes(serve_lowered, program, scopes):
+    text = serve_lowered[program].as_text(debug_info=True)
+    for scope in scopes:
+        assert re.search(rf'"[^"]*\b{scope}/[^"]*"', text), (program, scope)
+    if program != "gather":     # the [T, max_ctx, ...] gather is that path's
+        assert "kv_gather/" not in text
+
+
+@pytest.mark.parametrize("fn,args,name", [
+    (engine_v2._PICK_GREEDY, (_sds((T, 64)), _sds((S,), I32)),
+     "jit_dstpu_pick_greedy"),
+    (engine_v2._TAKE_ROWS, (_sds((T, 64)), _sds((S,), I32)),
+     "jit_dstpu_take_rows"),
+    (engine_v2._PICK_GREEDY_ALL, (_sds((T, 64)),),
+     "jit_dstpu_pick_greedy_all"),
+])
+def test_token_pick_module_name(fn, args, name):
+    assert _module(fn.lower(*args)) == name
+
+
+def test_step_programs_are_shared_by_config():
+    """The names did not cost the process-level sharing: a second engine
+    over the same config object reuses the first one's jitted callables."""
+    cfg = get_model("tiny").config
+    assert engine_v2._shared_step_fns(cfg, None) is \
+        engine_v2._shared_step_fns(cfg, None)
+
+
+# -- training programs -------------------------------------------------------
+
+TINY = TransformerConfig(
+    vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+    max_seq_len=32, pos_emb="learned", norm="layernorm",
+    activation="gelu", tie_embeddings=True, remat=True)
+BASE = {"train_micro_batch_size_per_chip": 2, "steps_per_print": 10**9,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-2}}}
+
+
+def _engine(**extra):
+    engine, *_ = dstpu.initialize(model=TransformerLM(TINY),
+                                  config=dict(BASE, **extra))
+    return engine
+
+
+def _batches(engine):
+    ids = np.zeros((1, engine.train_batch_size, 17), np.int32)
+    return engine.shard_batch({"input_ids": ids}, leading_dims=2)
+
+
+@pytest.fixture(scope="module")
+def train_engine(devices):
+    engine = _engine(zero_optimization={"stage": 3}, bf16={"enabled": True})
+    yield engine
+    engine.close()
+
+
+def _train_lowered(engine, which):
+    e, b = engine, _batches(engine)
+    one = jax.tree.map(lambda x: x[0], b)
+    scale = jnp.asarray(1.0, F32)
+    if which == "train_step":
+        return e._jit_train_step.lower(e.params, e.opt_state,
+                                       e.loss_scale_state, e.step_count, b)
+    if which == "grad_step":
+        return e._jit_grad_step.lower(e.params, b, scale)
+    if which == "fwd_bwd":
+        return e._jit_fwd_bwd.lower(e.params, one, scale)
+    if which == "eval":
+        return e._jit_eval.lower(e.params, one)
+    grads = jax.tree.map(lambda p: jnp.zeros(p.shape, F32), e.params)
+    return e._jit_apply.lower(e.params, e.opt_state, e.loss_scale_state,
+                              e.step_count, grads, jnp.asarray(0.0))
+
+
+@pytest.mark.parametrize("which", ["train_step", "grad_step", "fwd_bwd",
+                                   "apply_update", "eval"])
+def test_training_program_module_name(train_engine, which):
+    assert _module(_train_lowered(train_engine, which)) == f"jit_dstpu_{which}"
+
+
+def test_onebit_step_module_name(devices):
+    engine = _engine(optimizer={"type": "onebitadam",
+                                "params": {"lr": 1e-2, "freeze_step": 4}},
+                     zero_optimization={"stage": 1})
+    low = engine._jit_onebit.lower(engine.params, engine._onebit_state,
+                                   _batches(engine), jnp.asarray(0.0, F32))
+    assert _module(low) == "jit_dstpu_onebit_step"
+    engine.close()
+
+
+def test_zeropp_step_module_name(devices):
+    engine = _engine(zero_optimization={"stage": 1,
+                                        "zero_quantized_allreduce": True})
+    low = engine._jit_zeropp.lower(engine.params, engine._zeropp_state,
+                                   _batches(engine), jnp.asarray(0.0, F32))
+    assert _module(low) == "jit_dstpu_zeropp_step"
+    engine.close()
+
+
+def test_train_step_scopes(train_engine):
+    """``forward_backward`` holds the forward (``jvp``) and what JAX marks
+    as transposed; ``optimizer`` the update; the model's regions are named
+    inside the layer body."""
+    text = _train_lowered(train_engine, "train_step").as_text(debug_info=True)
+    for path in ["forward_backward/jvp(", "forward_backward/transpose(jvp(",
+                 "optimizer/", "embed", "head_loss", "attn/", "mlp/"]:
+        assert path in text, path
+    # the compiled HLO composes the whole path, through the scan and the
+    # rematerialised layer body, onto each op
+    ops = set(re.findall(r'op_name="([^"]*)"',
+                         _train_lowered(train_engine, "train_step")
+                         .compile().as_text()))
+    fwd = [o for o in ops if "/forward_backward/jvp(" in o and "/attn/" in o]
+    bwd = [o for o in ops if "/forward_backward/transpose(jvp(" in o]
+    assert fwd and bwd and any("/optimizer/" in o for o in ops)
+
+
+# -- Pallas kernels ----------------------------------------------------------
+
+def _kernel_names(fn, *args):
+    return set(re.findall(r"\bname=(\w+)", str(jax.make_jaxpr(fn)(*args))))
+
+
+def _flash(q, k, v):
+    return jnp.sum(flash_attention.flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128))
+
+
+def _gmm(lhs, rhs, sizes):
+    return jnp.sum(grouped_matmul.gmm(lhs, rhs, sizes))
+
+
+_QKV = (_sds((1, 256, 4, 64)), _sds((1, 256, 2, 64)), _sds((1, 256, 2, 64)))
+_POOL = _sds((16, 8, 2, 2, 64))
+
+KERNELS = [
+    ("flash_fwd", _flash, _QKV),
+    ("flash_bwd_dkdv", jax.grad(_flash, argnums=(0, 1, 2)), _QKV),
+    ("flash_bwd_dq", jax.grad(_flash, argnums=(0, 1, 2)), _QKV),
+    ("paged_decode", paged_attention.paged_decode_attention,
+     (_sds((4, 4, 64)), _POOL, _sds((4, 4), I32), _sds((4,), I32))),
+    ("paged_prefill", paged_attention.paged_prefill_attention,
+     (_sds((2, 8, 4, 64)), _POOL, _sds((2, 4), I32), _sds((2,), I32),
+      _sds((2,), I32))),
+    ("grouped_matmul", _gmm,
+     (_sds((256, 128)), _sds((2, 128, 128)), _sds((2,), I32))),
+    ("grouped_matmul_dw", jax.grad(_gmm, argnums=(0, 1)),
+     (_sds((256, 128)), _sds((2, 128, 128)), _sds((2,), I32))),
+    ("blocksparse_fwd",
+     lambda q, k, v: blocksparse_attention.blocksparse_attention_pallas(
+         q, k, v, blocksparse_attention.make_sparsity_config(
+             "fixed", block=16), causal=True),
+     (_sds((1, 64, 2, 16)),) * 3),
+    ("quantize_blockwise", quantization.quantize_blockwise,
+     (_sds((64, 256)),)),
+]
+
+
+@pytest.mark.parametrize("name,fn,args", KERNELS, ids=[k[0] for k in KERNELS])
+def test_pallas_kernel_carries_its_name(monkeypatch, name, fn, args):
+    # quantize_blockwise takes its reference path under the interpreter;
+    # tracing (nothing runs) is enough to see the call
+    monkeypatch.setattr(quantization, "_interpret", lambda: False)
+    assert name in _kernel_names(fn, *args)
+
+
+def test_every_pallas_call_site_passes_a_name():
+    import glob
+    import os
+
+    root = os.path.dirname(flash_attention.__file__)
+    for path in glob.glob(os.path.join(root, "*.py")):
+        src = open(path).read()
+        calls = len(re.findall(r"pl\.pallas_call\(", src))
+        named = len(re.findall(r"pl\.pallas_call\([^#]*?\bname=\"\w+\"", src,
+                               re.S))
+        assert calls == named, (os.path.basename(path), calls, named)

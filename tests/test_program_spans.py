@@ -1,0 +1,285 @@
+"""The program's host spans on the profiler's clock, and the counters where
+the work happens: a tiny engine of each kind under a ``jax.profiler``
+session on the CPU yields the ``dstpu/`` vocabulary of
+docs/observability.md ("Profiler spans and names"), nested as documented
+and joined by ``step_id`` to the request tracer; without a session the
+engines compute the same bits."""
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as dstpu
+from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.models.zoo import get_model
+from deepspeed_tpu.utils.annotate import SPAN_PREFIX
+
+TRAIN_CHILDREN = ["next_batches", "dispatch", "ckpt_commit", "drain_wait",
+                  "after_step_host", "step_trace"]
+SERVE_CHILDREN = {"admit", "schedule", "build_batch", "dispatch", "fetch",
+                  "bookkeep", "journal"}
+
+
+def capture(tmp_path, fn):
+    """Run ``fn`` under a profiler session; return its result and the
+    ``dstpu/`` spans as dicts (name, start, end, ids), in start order."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(SPAN_PREFIX):
+                    spans.append({
+                        "name": ev.name[len(SPAN_PREFIX):],
+                        "start": ev.start_ns, "end": ev.start_ns + ev.duration_ns,
+                        "ids": {k: v for k, v in ev.stats
+                                if not k.startswith("_")}})
+    return out, sorted(spans, key=lambda s: (s["start"], -s["end"]))
+
+
+def inside(parent, spans):
+    return [s for s in spans if s is not parent
+            and s["start"] >= parent["start"] and s["end"] <= parent["end"]]
+
+
+# -- training ---------------------------------------------------------------
+
+def _train_engine():
+    engine, *_ = dstpu.initialize(model=get_model("tiny"), config={
+        "train_micro_batch_size_per_chip": 2, "steps_per_print": 10**9,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 3}, "bf16": {"enabled": True},
+        "seed": 3})
+    return engine
+
+
+def _train_steps(engine, n=3):
+    rng = np.random.default_rng(0)
+    data = iter([{"input_ids": rng.integers(
+        0, 100, (engine.train_batch_size, 33)).astype(np.int32)}
+        for _ in range(n)])
+    return [float(engine.train_batch(data)) for _ in range(n)]
+
+
+def test_train_spans_nest_in_their_step(devices, tmp_path):
+    engine = _train_engine()
+    _train_steps(engine, 1)                       # compile outside
+    losses, spans = capture(tmp_path, lambda: _train_steps(engine, 3))
+    steps = [s for s in spans if s["name"] == "train_batch"]
+    assert [s["ids"]["step_num"] for s in steps] == [2, 3, 4]
+    for step in steps:
+        kids = inside(step, spans)
+        assert [k["name"] for k in kids] == TRAIN_CHILDREN
+        # siblings, in order, not overlapping
+        assert all(a["end"] <= b["start"] for a, b in zip(kids, kids[1:]))
+    assert len(spans) == 3 * (1 + len(TRAIN_CHILDREN))
+    # the satellite: the drained step's norm, no None on this path
+    assert float(engine.get_global_grad_norm()) > 0
+    engine.close()
+    engine.close()                                # idempotent
+    assert engine.watchdog is None or engine.watchdog._stop
+
+
+def test_train_losses_do_not_depend_on_a_profiler_session(devices, tmp_path):
+    a, b = _train_engine(), _train_engine()
+    plain = _train_steps(a, 3)
+    traced, spans = capture(tmp_path, lambda: _train_steps(b, 3))
+    assert plain == traced and spans
+    for x, y in zip(jax.tree.leaves(a.params), jax.tree.leaves(b.params)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    a.close(), b.close()
+
+
+def test_trace_capture_keeps_the_last_step_span(devices, tmp_path,
+                                                monkeypatch):
+    """``DSTPU_TRACE_STEPS``: the session closes after the step's own
+    span has, so the window's last ``dstpu/train_batch`` is in the file."""
+    monkeypatch.setenv("DSTPU_TRACE_STEPS", "2:3")
+    monkeypatch.setenv("DSTPU_TRACE_DIR", str(tmp_path))
+    engine = _train_engine()
+    _train_steps(engine, 4)
+    assert engine._trace_capture.done and not engine._trace_capture.active
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    nums = sorted(dict(ev.stats)["step_num"]
+                  for plane in jax.profiler.ProfileData.from_file(path).planes
+                  for line in plane.lines for ev in line.events
+                  if ev.name == SPAN_PREFIX + "train_batch")
+    assert nums == [2, 3]
+    engine.close()
+
+
+# -- serving -----------------------------------------------------------------
+
+def _serve_engine(**kw):
+    kw = dict(dict(kv_blocks=64, kv_block_size=8, max_tokens_per_step=32,
+                   max_seqs_per_step=4, max_blocks_per_seq=16,
+                   dtype=jnp.float32, request_trace={"sample_rate": 1.0}),
+              **kw)
+    return InferenceEngineV2(get_model("tiny"), **kw)
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(0, 100, n).astype(np.int32)
+
+
+def _mixed_run(engine):
+    """Gather, kernel-prefill, burst and single-decode steps, by hand:
+
+    A. three prompts of 20, 5, 5 tokens, 10 new tokens each. One step
+       takes all three chunks; padded to 4 slots x 32 it is over twice
+       the step's 32-token budget, so the gather program runs it: 3
+       tokens. A burst of 8 follows (24 tokens); with one token left to
+       each a burst does not pay, and one decode step emits 3.
+    B. one prompt of 40, 2 new tokens: chunks of 32 (no token) and 8 (1
+       token) through the prefill kernel, then one decode step (1).
+    """
+    out = {}
+    engine.put([1, 2, 3], [_prompt(20, 1), _prompt(5, 2), _prompt(5, 3)],
+               max_new_tokens=10)
+    out.update(engine.generate_all())
+    engine.put([4], [_prompt(40, 4)], max_new_tokens=2)
+    out.update(engine.generate_all())
+    return out
+
+
+EXPECTED = {"tokens_gather": 3, "tokens_multi_decode": 24,
+            "tokens_decode": 3 + 1, "tokens_prefill_kernel": 1,
+            "prefill_chunks": 3 + 2, "first_tokens": 4, "admitted": 4}
+
+
+def test_counters_against_a_hand_counted_schedule(devices):
+    # a label of its own: the hub's histograms are the process's, and the
+    # sums below are compared with this engine's observations alone
+    engine = _serve_engine(metric_labels={"engine": "hand-count"})
+    out = _mixed_run(engine)
+    assert {u: len(t) for u, t in out.items()} == {1: 10, 2: 10, 3: 10, 4: 2}
+    got = {k: engine.stats[k] for k in EXPECTED}
+    assert got == EXPECTED
+    tokens = sum(engine.stats[k] for k in EXPECTED if k.startswith("tokens_"))
+    assert tokens == sum(len(t) for t in out.values()) == 32
+    # the time sums hold the histograms' own observations
+    snap = engine.snapshot()
+    assert engine.stats["ttft_s"] == pytest.approx(snap["ttft"]["sum"],
+                                                   rel=1e-4)
+    assert snap["ttft"]["count"] == 4
+    assert engine.stats["admission_wait_s"] == pytest.approx(
+        snap["admission_wait"]["sum"], abs=1e-5)
+    assert 0 < engine.stats["admission_wait_s"] < engine.stats["ttft_s"]
+    engine.close()
+    engine.close()
+
+
+def test_admission_wait_counts_the_queue(devices):
+    """Five requests into four slots: the fifth is admitted when a slot
+    frees, and its wait is most of the counter."""
+    engine = _serve_engine()
+    engine.put(list(range(5)), [_prompt(6, i) for i in range(5)],
+               max_new_tokens=4)
+    assert engine.stats["admitted"] == 4
+    early = engine.stats["admission_wait_s"]
+    engine.generate_all()
+    assert engine.stats["admitted"] == 5 == engine.stats["first_tokens"]
+    waited = engine.stats["admission_wait_s"] - early
+    assert waited > 10 * early and waited < engine.stats["ttft_s"]
+    engine.close()
+
+
+def test_serve_spans_and_request_trace_share_step_ids(devices, tmp_path):
+    engine = _serve_engine(prefix_cache=False)    # both runs prefill alike
+    _mixed_run(engine)                            # compile outside
+    first = engine._step_id
+    engine.flush([1, 2, 3, 4])
+    out, spans = capture(tmp_path, lambda: _mixed_run(engine))
+    steps = [s for s in spans if s["name"] == "serve_step"]
+    ids = [s["ids"]["step_id"] for s in steps]
+    assert ids == list(range(first + 1, engine._step_id + 1))   # one a step
+    programs = []
+    for step in steps:
+        kids = inside(step, spans)
+        names = [k["name"] for k in kids]
+        assert set(names) <= SERVE_CHILDREN
+        assert names[0] == "admit" and names[-1] == "journal"
+        assert names.count("dispatch") == 1
+        d = kids[names.index("dispatch")]
+        programs.append(d["ids"]["program"])
+        assert d["ids"]["seqs"] >= 1 and d["ids"]["tokens"] >= 1
+        assert names.index("build_batch") < names.index("dispatch")
+    assert programs == ["gather", "multi_decode", "decode",
+                        "prefill", "prefill", "decode"]
+    puts = [s for s in spans if s["name"] == "put"]
+    assert [(p["ids"]["uid"], p["ids"]["requests"]) for p in puts] == \
+        [(1, 3), (4, 1)]
+    # every span is a put, a serve_step, or lies inside one
+    tops = puts + steps
+    assert all(any(s is t or (s["start"] >= t["start"]
+                              and s["end"] <= t["end"]) for t in tops)
+               for s in spans)
+    # the request tracer's spans name the step that made them
+    traces = {t.uid: t for t in engine.request_traces()}
+    by_step = dict(zip(programs, ids))
+    four = [(s.kind, s.fields.get("step_id")) for s in traces[4].spans
+            if s.kind in ("PREFILL", "DECODE_EMIT")]
+    assert four == [("PREFILL", ids[3]), ("PREFILL", ids[4]),
+                    ("DECODE_EMIT", ids[4]), ("DECODE_EMIT", ids[5])]
+    one = [s.fields["step_id"] for s in traces[1].spans
+           if s.kind == "DECODE_EMIT"]
+    assert one == [by_step["gather"], by_step["multi_decode"], ids[2]]
+    engine.close()
+
+
+def test_speculative_round_spans(devices, tmp_path):
+    engine = _serve_engine(spec_decode=True, spec_k=3)
+    prompt = np.tile(np.arange(6, dtype=np.int32), 4)   # lookup finds drafts
+    engine.put([1], [prompt], max_new_tokens=12)
+    plain = _serve_engine()
+    plain.put([1], [prompt], max_new_tokens=12)
+    want = plain.generate_all()
+    got, spans = capture(tmp_path, engine.generate_all)
+    assert got == want
+    spec = [s for s in spans if s["name"] == "dispatch"
+            and s["ids"]["program"] == "spec"]
+    assert len(spec) == engine.stats["spec_steps"]
+    # a speculative round runs the gather program
+    s = engine.stats
+    assert (s["tokens_gather"] + s["tokens_prefill_kernel"]
+            + s["tokens_decode"] + s["tokens_multi_decode"]) == 12
+    engine.close(), plain.close()
+
+
+def test_serve_tokens_do_not_depend_on_a_profiler_session(devices, tmp_path):
+    a, b = _serve_engine(), _serve_engine()
+    plain = _mixed_run(a)
+    traced, spans = capture(tmp_path, lambda: _mixed_run(b))
+    assert plain == traced and spans
+    assert {k: a.stats[k] for k in EXPECTED} == \
+        {k: b.stats[k] for k in EXPECTED}
+    a.close(), b.close()
+
+
+def test_close_detaches_the_request_tracer_from_the_flight_recorder(devices):
+    from deepspeed_tpu.observability.flight_recorder import \
+        get_flight_recorder
+
+    flight = get_flight_recorder()
+    first, second = _serve_engine(), _serve_engine()
+    assert flight._dump_context["requests_in_flight"] == \
+        second.tracer._inflight_summary
+    first.close()           # a later engine's registration stays
+    assert flight._dump_context["requests_in_flight"] == \
+        second.tracer._inflight_summary
+    second.close()
+    assert "requests_in_flight" not in flight._dump_context

@@ -11,8 +11,8 @@ from deepspeed_tpu.models.zoo import get_model
 from deepspeed_tpu.runtime import sharding
 from deepspeed_tpu.utils import (OnDevice, get_z3_leaf_modules,
                                  instrument_w_profiler, on_device,
-                                 range_pop, range_push, see_memory_usage,
-                                 set_z3_leaf_modules, unset_z3_leaf_modules)
+                                 see_memory_usage, set_z3_leaf_modules,
+                                 span, step_span, unset_z3_leaf_modules)
 
 
 class TestOnDevice:
@@ -126,5 +126,5 @@ class TestMemoryAndAnnotate:
             return x * 2
 
         assert float(f(jnp.float32(3))) == 6.0
-        ann = range_push("test-range")
-        range_pop(ann)
+        with step_span("test-step", 3), span("test-range", uid=7):
+            pass
