@@ -80,21 +80,23 @@ def _shared_step_fns(cfg, kernel_mesh):
     if hit is not None and hit[0] is cfg:
         return hit[1]
     # each program under a stable name: the device trace's module line
-    # says jit_dstpu_serve_gather, ... ("step" is the gather program)
+    # says jit_dstpu_serve_gather, ... ("step" is the gather program).
+    # Every program donates the KV pool (argument 1): the pool is one
+    # buffer for the life of the engine, updated in place, and the handle
+    # a caller passed in is dead once the call returns.
+    def program(fn, name, **kw):
+        return jax.jit(named(fn, name), donate_argnums=(1,), **kw)
+
     fns = {
-        "step": jax.jit(named(
-            partial(model_runner.ragged_forward, cfg),
-            "dstpu_serve_gather")),
-        "decode": jax.jit(named(
-            partial(model_runner.ragged_decode_forward, cfg,
-                    mesh=kernel_mesh), "dstpu_serve_decode")),
-        "prefill": jax.jit(named(
-            partial(model_runner.ragged_prefill_forward, cfg,
-                    mesh=kernel_mesh), "dstpu_serve_prefill")),
-        "multi_decode": jax.jit(named(
-            partial(model_runner.ragged_multi_decode, cfg,
-                    mesh=kernel_mesh), "dstpu_serve_multi_decode"),
-            static_argnames=("steps",)),
+        "step": program(partial(model_runner.ragged_forward, cfg),
+                        "dstpu_serve_gather"),
+        "decode": program(partial(model_runner.ragged_decode_forward, cfg,
+                                  mesh=kernel_mesh), "dstpu_serve_decode"),
+        "prefill": program(partial(model_runner.ragged_prefill_forward, cfg,
+                                   mesh=kernel_mesh), "dstpu_serve_prefill"),
+        "multi_decode": program(
+            partial(model_runner.ragged_multi_decode, cfg, mesh=kernel_mesh),
+            "dstpu_serve_multi_decode", static_argnames=("steps",)),
     }
     _JIT_CACHE[key] = (cfg, fns)
     return fns
@@ -958,6 +960,7 @@ class InferenceEngineV2:
                       tokens=int(batch.num_tokens)):
                 logits, new_kv = fn(self.params, self.kv_cache.kv_state,
                                     *args)
+        # the program consumed (donated) the handle it was given
         self.kv_cache.set_kv_state(new_kv)
 
         # Sample ON DEVICE and fetch only token ids (greedy) or just the
@@ -1229,9 +1232,10 @@ class InferenceEngineV2:
                       tokens=K * len(live)):
                 toks, new_kv = self._multi_decode_fn(
                     self.params, self.kv_cache.kv_state, *args, steps=K)
+            # at once: the handle the cache still holds is consumed
+            self.kv_cache.set_kv_state(new_kv)
             with span("fetch"):
                 toks_np = np.asarray(toks)  # [K, S]: one fetch per K tokens
-        self.kv_cache.set_kv_state(new_kv)
         with span("bookkeep"):
             self.stats["decode_kernel_steps"] += K
             self.stats["burst_steps"] = self.stats.get("burst_steps", 0) + 1
@@ -1355,9 +1359,9 @@ class InferenceEngineV2:
                       tokens=int(batch.num_tokens)):
                 logits, new_kv = self._step_fn(
                     self.params, self.kv_cache.kv_state, *args)
+            self.kv_cache.set_kv_state(new_kv)
             with span("fetch"):
                 greedy = np.asarray(self._pick_greedy_all(logits))
-        self.kv_cache.set_kv_state(new_kv)
         with span("bookkeep"):
             return self._accept_spec_round(sched, batch, greedy, t_start,
                                            eos_token_id)

@@ -18,7 +18,6 @@ jitted once per shape bucket (the CUDA-graph analog, engine.py:497).
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Any, Dict, Tuple
 
 import jax
@@ -68,32 +67,43 @@ def _kernel_pages() -> int:
 
 
 @jax.named_scope("kv_write")
-def _kv_write(kv_layer, kv_sc, page, offset, k, v):
-    """Scatter this step's keys and values into their pages; a quantized
-    pool quantizes on append (one scale per head vector). Returns
-    (kv_layer', kv_sc')."""
+def _kv_write(kv, kv_sc, layer, page, offset, k, v):
+    """Scatter this step's keys and values into their rows
+    ``[layer, page, offset]`` of the carried pool (K and V as one scatter
+    of the stacked pair); a quantized pool quantizes on append (one scale
+    per head vector). The pool is a loop carry that nothing else reads,
+    so XLA updates it in place. Returns (kv', kv_sc')."""
+    new = jnp.stack([k, v], axis=-3)              # [..., 2, nkv, hd]
     if kv_sc is None:
-        kv_layer = kv_layer.at[page, offset, 0].set(k.astype(kv_layer.dtype))
-        kv_layer = kv_layer.at[page, offset, 1].set(v.astype(kv_layer.dtype))
-        return kv_layer, None
-    bits = _kv_bits(kv_layer)
-    qk, sk = kv_quantize(k, bits=bits)
-    qv, sv = kv_quantize(v, bits=bits)
-    kv_layer = kv_layer.at[page, offset, 0].set(kv_pack(qk, bits))
-    kv_layer = kv_layer.at[page, offset, 1].set(kv_pack(qv, bits))
-    kv_sc = kv_sc.at[page, offset, 0].set(sk)
-    kv_sc = kv_sc.at[page, offset, 1].set(sv)
-    return kv_layer, kv_sc
+        return kv.at[layer, page, offset].set(new.astype(kv.dtype)), None
+    bits = _kv_bits(kv)
+    q, sc = kv_quantize(new, bits=bits)
+    kv = kv.at[layer, page, offset].set(kv_pack(q, bits))
+    return kv, kv_sc.at[layer, page, offset].set(sc)
 
 
-def _kv_dense(kv_layer, kv_sc, dt):
-    """The layer pool as the Pallas kernels read it: a quantized pool
-    dequantizes its per-layer slice (transient, 1/L of the bf16 pool);
-    the persistent pool stays int8 / packed int4 / fp8."""
+def _kv_dense(kv, kv_sc, layer, dt):
+    """The (pool, layer) pair the Pallas kernels take. A bf16 pool goes
+    in whole (the kernel's index map picks the layer); a quantized pool
+    dequantizes its per-layer slice (transient, 1/L of the bf16 pool; the
+    persistent pool stays int8 / packed int4 / fp8), which the kernel
+    reads as a one-layer pool at layer 0."""
     if kv_sc is None:
-        return kv_layer
-    return kv_dequantize(kv_unpack(kv_layer, _kv_bits(kv_layer)), kv_sc,
-                         dtype=dt)
+        return kv, layer
+    dense = kv_dequantize(kv_unpack(kv[layer], _kv_bits(kv)), kv_sc[layer],
+                          dtype=dt)
+    return dense[None], 0
+
+
+def _scan_layers(layer_body, x, params, kv_data, kv_scales):
+    """Run ``layer_body((x, kv, kv_sc), (layer_params, l))`` over the
+    layers with the pool as the scan's *carry*: one buffer, scattered
+    into at ``[l, ...]`` and read at ``l``, never sliced per layer into
+    ``xs`` nor restacked from ``ys``. Returns (x, kv_state')."""
+    (x, kv_data, kv_scales), _ = lax.scan(
+        layer_body, (x, kv_data, kv_scales),
+        (params["layers"], jnp.arange(kv_data.shape[0], dtype=jnp.int32)))
+    return x, (kv_data if kv_scales is None else (kv_data, kv_scales))
 
 
 def _qkv(cfg: TransformerConfig, layer_params, y, positions):
@@ -282,26 +292,24 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     max_ctx = Bm * bs
     key_pos = jnp.arange(max_ctx)  # [Lmax]
 
-    def layer_body(x, inputs):
-        if kv_scales is None:
-            layer_params, kv_layer = inputs  # [num_blocks, bs, 2, nkv, hd]
-            kv_sc = None
-        else:
-            layer_params, kv_layer, kv_sc = inputs
+    def layer_body(carry, inputs):
+        x, kv, kv_sc = carry             # kv [L, num_blocks, bs, 2, nkv, hd]
+        layer_params, l = inputs
         with jax.named_scope("attn"):
             y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
             # q [T,nh,hd] k/v [T,nkv,hd]
             q, k, v = _qkv(cfg, layer_params, y, token_pos)
-        kv_layer, kv_sc = _kv_write(kv_layer, kv_sc, page, offset, k, v)
+        kv, kv_sc = _kv_write(kv, kv_sc, l, page, offset, k, v)
         with jax.named_scope("kv_gather"):
-            # gather each slot's pages into dense [S, Lmax, nkv, hd], then
-            # one row of the full context per *token*: [T, Lmax, nh, hd]
-            gathered = kv_layer[block_table]  # [S, Bm, bs, 2, nkv, hd(/2)]
+            # gather each slot's pages (and no others) into dense
+            # [S, Lmax, nkv, hd], then one row of the full context per
+            # *token*: [T, Lmax, nh, hd]
+            gathered = kv[l, block_table]  # [S, Bm, bs, 2, nkv, hd(/2)]
             if kv_sc is not None:
                 # dequant-on-read: only the gathered pages, never the pool
                 gathered = kv_dequantize(
-                    kv_unpack(gathered, _kv_bits(kv_layer)),
-                    kv_sc[block_table], dtype=dt)
+                    kv_unpack(gathered, _kv_bits(kv)),
+                    kv_sc[l, block_table], dtype=dt)
             gathered = gathered.reshape(Smax, max_ctx, 2, cfg.kv_heads,
                                         cfg.head_dim)
             k_seq = gathered[:, :, 0][token_seq]  # [T, Lmax, nkv, hd]
@@ -320,15 +328,13 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
                               layer_params["attn"]["wo"].astype(dt))
             if cfg.use_biases:
                 attn = attn + layer_params["attn"]["bo"].astype(dt)
-        kv_out = kv_layer if kv_sc is None else (kv_layer, kv_sc)
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
-            return _mlp(cfg, layer_params, x) + attn, kv_out
-        x = x + attn
-        return _mlp(cfg, layer_params, x), kv_out
+            x = _mlp(cfg, layer_params, x) + attn
+        else:
+            x = _mlp(cfg, layer_params, x + attn)
+        return (x, kv, kv_sc), None
 
-    xs = ((params["layers"], kv_data) if kv_scales is None
-          else (params["layers"], kv_data, kv_scales))
-    x, new_kv = lax.scan(layer_body, x, xs)
+    x, new_kv = _scan_layers(layer_body, x, params, kv_data, kv_scales)
     return _unembed(cfg, params, x), new_kv
 
 
@@ -339,53 +345,59 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
 
 
 
-def _tp_shard_map(kernel, mesh, q_spec, n_extra: int):
-    """Wrap a Pallas paged-attention kernel for a multi-device mesh.
+def _on_tp_mesh(kernel, mesh, q_spec, q, kv, layer, *meta):
+    """Run a Pallas paged-attention kernel, wrapped for a multi-device
+    mesh where there is one.
 
     Pallas calls can't run under plain GSPMD partitioning; shard_map
     makes the mesh manual so each shard runs the kernel on its local
     heads: q sharded on num_heads over tp, the KV pool sharded on
     kv_heads over tp (contiguous GQA grouping keeps q-head i's kv head
     on the same shard whenever tp divides kv_heads — the engine gates
-    on that), metadata replicated. Axes other than tp are unmentioned =
-    replicated (the default inference mesh absorbs spare chips into dp).
-    Reference: the TP-sharded ragged kernels of inference/v2
-    (kernels/ragged_ops + TP sharding).
+    on that), layer and metadata replicated. Axes other than tp are
+    unmentioned = replicated (the default inference mesh absorbs spare
+    chips into dp). Reference: the TP-sharded ragged kernels of
+    inference/v2 (kernels/ragged_ops + TP sharding).
+
+    ``kv`` and ``layer`` as :func:`_kv_dense` hands them out.
     """
+    pages = _kernel_pages()
+
+    def call(q, kv, layer, *meta):
+        return kernel(q, kv, *meta, layer=layer,
+                      pages_per_compute_block=pages)
+
+    layer = jnp.asarray(layer, jnp.int32)
+    if mesh is None:
+        return call(q, kv, layer, *meta)
     from jax.sharding import PartitionSpec as PS
 
-    kv_spec = PS(None, None, None, "tp", None)
-    in_specs = (q_spec, kv_spec) + (PS(),) * n_extra
-    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                         out_specs=q_spec, check_vma=False)
+    kv_spec = PS(None, None, None, None, "tp", None)
+    in_specs = (q_spec, kv_spec) + (PS(),) * (1 + len(meta))
+    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                         out_specs=q_spec, check_vma=False)(
+                             q, kv, layer, *meta)
 
 
-def _paged_decode(mesh, q, kv_layer, block_table, context_lens):
+def _paged_decode(mesh, q, kv, layer, block_table, context_lens):
+    from jax.sharding import PartitionSpec as PS
+
     from deepspeed_tpu.ops.pallas.paged_attention import \
         paged_decode_attention
 
-    kernel = partial(paged_decode_attention,
-                     pages_per_compute_block=_kernel_pages())
-    if mesh is None:
-        return kernel(q, kv_layer, block_table, context_lens)
+    return _on_tp_mesh(paged_decode_attention, mesh, PS(None, "tp", None),
+                       q, kv, layer, block_table, context_lens)
+
+
+def _paged_prefill(mesh, q, kv, layer, block_table, seg_pos0, ctx_lens):
     from jax.sharding import PartitionSpec as PS
 
-    fn = _tp_shard_map(kernel, mesh, PS(None, "tp", None), 2)
-    return fn(q, kv_layer, block_table, context_lens)
-
-
-def _paged_prefill(mesh, q, kv_layer, block_table, seg_pos0, ctx_lens):
     from deepspeed_tpu.ops.pallas.paged_attention import \
         paged_prefill_attention
 
-    kernel = partial(paged_prefill_attention,
-                     pages_per_compute_block=_kernel_pages())
-    if mesh is None:
-        return kernel(q, kv_layer, block_table, seg_pos0, ctx_lens)
-    from jax.sharding import PartitionSpec as PS
-
-    fn = _tp_shard_map(kernel, mesh, PS(None, None, "tp", None), 3)
-    return fn(q, kv_layer, block_table, seg_pos0, ctx_lens)
+    return _on_tp_mesh(paged_prefill_attention, mesh,
+                       PS(None, None, "tp", None), q, kv, layer,
+                       block_table, seg_pos0, ctx_lens)
 
 
 def ragged_prefill_forward(cfg: TransformerConfig, params,
@@ -424,33 +436,28 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
     page = jnp.where(real, page, scratch)
     offset = jnp.where(real, pos % bs, bs - 1)
 
-    def layer_body(x, inputs):
-        if kv_scales is None:
-            layer_params, kv_layer = inputs
-            kv_sc = None
-        else:
-            layer_params, kv_layer, kv_sc = inputs
+    def layer_body(carry, inputs):
+        x, kv, kv_sc = carry
+        layer_params, l = inputs
         with jax.named_scope("attn"):
             y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
             q, k, v = _qkv(cfg, layer_params, y, pos)  # q [S,Tq,nh,hd]
-        kv_layer, kv_sc = _kv_write(kv_layer, kv_sc, page, offset, k, v)
+        kv, kv_sc = _kv_write(kv, kv_sc, l, page, offset, k, v)
         with jax.named_scope("attn"):
             attn = _paged_prefill(mesh, q.astype(dt),
-                                  _kv_dense(kv_layer, kv_sc, dt),
+                                  *_kv_dense(kv, kv_sc, l, dt),
                                   block_table, seg_pos0, ctx_lens)
             attn = jnp.einsum("stnd,ndh->sth", attn.astype(dt),
                               layer_params["attn"]["wo"].astype(dt))
             if cfg.use_biases:
                 attn = attn + layer_params["attn"]["bo"].astype(dt)
-        kv_out = kv_layer if kv_sc is None else (kv_layer, kv_sc)
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
-            return _mlp(cfg, layer_params, x) + attn, kv_out
-        x = x + attn
-        return _mlp(cfg, layer_params, x), kv_out
+            x = _mlp(cfg, layer_params, x) + attn
+        else:
+            x = _mlp(cfg, layer_params, x + attn)
+        return (x, kv, kv_sc), None
 
-    xs = ((params["layers"], kv_data) if kv_scales is None
-          else (params["layers"], kv_data, kv_scales))
-    x, new_kv = lax.scan(layer_body, x, xs)
+    x, new_kv = _scan_layers(layer_body, x, params, kv_data, kv_scales)
     return _unembed(cfg, params, x), new_kv
 
 
@@ -494,33 +501,28 @@ def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     page = jnp.where(alive, page, scratch)
     offset = jnp.where(alive, token_pos % bs, bs - 1)
 
-    def layer_body(x, inputs):
-        if kv_scales is None:
-            layer_params, kv_layer = inputs
-            kv_sc = None
-        else:
-            layer_params, kv_layer, kv_sc = inputs
+    def layer_body(carry, inputs):
+        x, kv, kv_sc = carry
+        layer_params, l = inputs
         with jax.named_scope("attn"):
             y = _norm(x, layer_params["ln1"], cfg.norm, cfg.norm_eps)
             q, k, v = _qkv(cfg, layer_params, y, token_pos)  # q [S,nh,hd]
-        kv_layer, kv_sc = _kv_write(kv_layer, kv_sc, page, offset, k, v)
+        kv, kv_sc = _kv_write(kv, kv_sc, l, page, offset, k, v)
         with jax.named_scope("attn"):
             attn = _paged_decode(mesh, q.astype(dt),
-                                 _kv_dense(kv_layer, kv_sc, dt),
+                                 *_kv_dense(kv, kv_sc, l, dt),
                                  block_table, context_lens)
             attn = jnp.einsum("snd,ndh->sh", attn.astype(dt),
                               layer_params["attn"]["wo"].astype(dt))
             if cfg.use_biases:
                 attn = attn + layer_params["attn"]["bo"].astype(dt)
-        kv_out = kv_layer if kv_sc is None else (kv_layer, kv_sc)
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
-            return _mlp(cfg, layer_params, x) + attn, kv_out
-        x = x + attn
-        return _mlp(cfg, layer_params, x), kv_out
+            x = _mlp(cfg, layer_params, x) + attn
+        else:
+            x = _mlp(cfg, layer_params, x + attn)
+        return (x, kv, kv_sc), None
 
-    xs = ((params["layers"], kv_data) if kv_scales is None
-          else (params["layers"], kv_data, kv_scales))
-    x, new_kv = lax.scan(layer_body, x, xs)
+    x, new_kv = _scan_layers(layer_body, x, params, kv_data, kv_scales)
     return _unembed(cfg, params, x), new_kv
 
 

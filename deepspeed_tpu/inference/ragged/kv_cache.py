@@ -16,6 +16,7 @@ preallocating the cache up front.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import jax
@@ -63,6 +64,13 @@ class KVCacheConfig:
             return vecs * (self.payload_width + 4)
         itemsize = jnp.dtype(self.dtype).itemsize
         return vecs * self.head_dim * itemsize
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def dstpu_kv_write_blocks(pool, idx, rows):
+    """``pool[:, idx] = rows`` in the pool's own buffer (donated): a
+    restore touches the blocks it writes, not the whole pool."""
+    return pool.at[:, idx].set(rows.astype(pool.dtype))
 
 
 class BlockedKVCache:
@@ -122,7 +130,9 @@ class BlockedKVCache:
 
     def set_kv_state(self, state) -> None:
         """Store the pool returned by a compiled step (inverse of
-        :attr:`kv_state`)."""
+        :attr:`kv_state`). The step programs donate the pool they are
+        handed, so this is the only live handle afterwards: read
+        ``data`` / ``kv_state`` afresh, never keep one across a step."""
         if self.scales is None:
             self.data = state
         else:
@@ -149,13 +159,14 @@ class BlockedKVCache:
     def write_blocks(self, block_ids, payload, scales=None) -> None:
         """Host→device restore of pool contents at ``block_ids`` —
         the inverse of :meth:`read_blocks_host`, bit-exact when the
-        payload is pool-native."""
-        idx = jnp.asarray(np.asarray(block_ids, np.int64))
-        self.data = self.data.at[:, idx].set(
-            jnp.asarray(payload, self.data.dtype))
+        payload is pool-native. The pool is updated in place: the
+        handles read from ``data`` / ``scales`` before are dead after."""
+        idx = jnp.asarray(np.asarray(block_ids, np.int32))
+        self.data = dstpu_kv_write_blocks(self.data, idx,
+                                          jnp.asarray(payload))
         if self.scales is not None and scales is not None:
-            self.scales = self.scales.at[:, idx].set(
-                jnp.asarray(scales, jnp.float32))
+            self.scales = dstpu_kv_write_blocks(self.scales, idx,
+                                                jnp.asarray(scales))
 
     def free(self, blocks) -> None:
         if len(blocks):

@@ -13,10 +13,14 @@ pipeline). Online-softmax accumulation over pages in fp32 scratch; GQA
 handled by grouping query heads per kv head (static in-kernel loop, since
 Mosaic block shapes cannot tile the kv-head axis independently).
 
-Layout matches inference/ragged/kv_cache.py: one layer's pool is
-``kv[num_blocks, block_size, 2, kv_heads, head_dim]`` — the same array is
-fetched one page per grid step; the kernel reads K from plane 0 and V
-from plane 1 of the same block.
+Layout matches inference/ragged/kv_cache.py: the pool is
+``kv[L, num_blocks, block_size, 2, kv_heads, head_dim]`` and the kernels
+take it whole, with the layer as one more scalar-prefetch operand: the
+index map picks ``(layer, page)``, so a step program never slices a
+layer out of the pool (a 130 MB copy per layer at mistral-7b width).
+One page is fetched per grid step; the kernel reads K from plane 0 and V
+from plane 1 of the same block. A 5-D one-layer pool is still accepted
+(it is viewed as ``kv[None]``, layer 0).
 """
 
 from __future__ import annotations
@@ -68,14 +72,14 @@ def _visit(q_ref, kv_ref, m_ref, l_ref, acc_ref, visible, *, bs: int,
     """Fold one K/V page into the online-softmax state (decode)."""
     for n in range(nkv):  # static unroll over kv heads
         q = q_ref[0, n].astype(jnp.float32) * scale   # [gp, hd]
-        k = kv_ref[0, :, 0, n].astype(jnp.float32)    # [bs, hd]
-        v = kv_ref[0, :, 1, n].astype(jnp.float32)    # [bs, hd]
+        k = kv_ref[0, 0, :, 0, n].astype(jnp.float32)  # [bs, hd]
+        v = kv_ref[0, 0, :, 1, n].astype(jnp.float32)  # [bs, hd]
         _fold_page(q, k, v, visible, m_ref, l_ref, acc_ref,
                    slice(n * gp, (n + 1) * gp), gp)
 
 
-def _kernel(bt_ref, ctx_ref, q_ref, *refs, bs: int, nkv: int, gp: int,
-            scale: float, pages: int):
+def _kernel(bt_ref, ctx_ref, layer_ref, q_ref, *refs, bs: int, nkv: int,
+            gp: int, scale: float, pages: int):
     # refs = pages kv page blocks, then out_ref + 3 scratch refs. The
     # pages fold sequentially in ascending page order — the identical
     # op sequence for every pages_per_compute_block, so outputs stay
@@ -114,8 +118,9 @@ def _kernel(bt_ref, ctx_ref, q_ref, *refs, bs: int, nkv: int, gp: int,
             out_ref[0, n] = (acc_ref[rows, :] / l).astype(out_ref.dtype)
 
 
-def _prefill_kernel(pos0_ref, ctx_ref, bt_ref, q_ref, *refs, bs: int,
-                    nkv: int, g: int, tq: int, scale: float, pages: int):
+def _prefill_kernel(pos0_ref, ctx_ref, bt_ref, layer_ref, q_ref, *refs,
+                    bs: int, nkv: int, g: int, tq: int, scale: float,
+                    pages: int):
     kv_refs = refs[:pages]
     out_ref, m_ref, l_ref, acc_ref = refs[pages:]
     s = pl.program_id(0)
@@ -146,8 +151,8 @@ def _prefill_kernel(pos0_ref, ctx_ref, bt_ref, q_ref, *refs, bs: int,
                 # q layout is [S, nkv, tq*g, hd] (wrapper pre-transposes):
                 # only leading-dim integer indexing, which Mosaic supports
                 q = q_ref[0, n].astype(jnp.float32) * scale  # [rows, hd]
-                k = kv_ref[0, :, 0, n].astype(jnp.float32)   # [bs, hd]
-                v = kv_ref[0, :, 1, n].astype(jnp.float32)
+                k = kv_ref[0, 0, :, 0, n].astype(jnp.float32)  # [bs, hd]
+                v = kv_ref[0, 0, :, 1, n].astype(jnp.float32)
                 _fold_page(q, k, v, visible, m_ref, l_ref, acc_ref,
                            slice(n * rows, (n + 1) * rows), rows)
 
@@ -160,11 +165,34 @@ def _prefill_kernel(pos0_ref, ctx_ref, bt_ref, q_ref, *refs, bs: int,
             out_ref[0, n] = (acc_ref[rsl, :] / l).astype(out_ref.dtype)
 
 
-def paged_prefill_attention(q: jax.Array, kv_layer: jax.Array,
+def _page_id(bt, ctx, s, j, bs: int, nb: int):
+    """Pool page behind entry ``j`` of sequence ``s``'s block table.
+    Entries beyond the context clamp to the last live page: Mosaic skips
+    the DMA when consecutive grid steps request the same block."""
+    last = jax.lax.max(ctx[s] - 1, 0) // bs
+    return jax.lax.min(jax.lax.max(bt[s, jax.lax.min(j, last)], 0), nb - 1)
+
+
+def _pool_and_layer(kv: jax.Array, layer):
+    """The pool as the kernels index it, ``[L, nb, bs, 2, nkv, hd]``, and
+    the layer as the ``int32[1]`` scalar-prefetch operand. A 5-D pool is
+    one layer's: a free reshape to ``kv[None]``, layer 0."""
+    if kv.ndim == 5:
+        if layer is not None:
+            raise ValueError("a 5-D KV pool is one layer's: pass the "
+                             "[L, ...] pool with `layer`")
+        kv, layer = kv[None], 0
+    elif layer is None:
+        raise ValueError("the [L, num_blocks, ...] KV pool needs `layer`")
+    return kv, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def paged_prefill_attention(q: jax.Array, kv: jax.Array,
                             block_table: jax.Array, seg_pos0: jax.Array,
                             context_lens: jax.Array,
                             scale: float = None,
-                            pages_per_compute_block: int = 1) -> jax.Array:
+                            pages_per_compute_block: int = 1,
+                            layer=None) -> jax.Array:
     """Chunked-prefill attention over paged KV (SplitFuse chunk step).
 
     Each segment is one sequence's contiguous chunk of ``Tq`` new tokens
@@ -175,7 +203,9 @@ def paged_prefill_attention(q: jax.Array, kv_layer: jax.Array,
     q            [S, Tq, num_heads, head_dim] (padded rows have garbage;
                  their outputs are well-defined zeros only if the whole
                  segment is dead — callers slice real rows out)
-    kv_layer     [num_blocks, block_size, 2, kv_heads, head_dim]
+    kv           [L, num_blocks, block_size, 2, kv_heads, head_dim], read
+                 at ``layer`` (a traced int32 scalar); or one layer's
+                 5-D pool with ``layer`` left out
     block_table  [S, max_pages]
     seg_pos0     [S] absolute position of each segment's first query
     context_lens [S] keys visible to the segment's LAST query (pos0 +
@@ -189,7 +219,8 @@ def paged_prefill_attention(q: jax.Array, kv_layer: jax.Array,
     Returns [S, Tq, num_heads, head_dim] in q.dtype.
     """
     S, tq, nh, hd = q.shape
-    nb, bs, _, nkv, _ = kv_layer.shape
+    kv, layer = _pool_and_layer(kv, layer)
+    _, nb, bs, _, nkv, _ = kv.shape
     Bm = block_table.shape[1]
     if nh % nkv:
         raise ValueError(f"num_heads {nh} not a multiple of kv_heads {nkv}")
@@ -207,28 +238,21 @@ def paged_prefill_attention(q: jax.Array, kv_layer: jax.Array,
 
     P = max(1, min(int(pages_per_compute_block), Bm))
 
-    def page(s, j, pos0, ctx, bt, i=0):
-        # clamp beyond-context iterations to the last live page: Mosaic
-        # skips the DMA when consecutive grid steps request the same block
-        last = jax.lax.max(ctx[s] - 1, 0) // bs
-        j_eff = jax.lax.min(j * P + i, last)
-        return jax.lax.min(jax.lax.max(bt[s, j_eff], 0), nb - 1)
-
     def kv_spec(i):
         return pl.BlockSpec(
-            (1, bs, 2, nkv, hd),
-            lambda s, j, pos0, ctx, bt: (page(s, j, pos0, ctx, bt, i),
-                                         0, 0, 0, 0))
+            (1, 1, bs, 2, nkv, hd),
+            lambda s, j, pos0, ctx, bt, lyr: (
+                lyr[0], _page_id(bt, ctx, s, j * P + i, bs, nb), 0, 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(S, -(-Bm // P)),
         in_specs=[
             pl.BlockSpec((1, nkv, tq * g, hd),
-                         lambda s, j, pos0, ctx, bt: (s, 0, 0, 0)),
+                         lambda s, j, pos0, ctx, bt, lyr: (s, 0, 0, 0)),
         ] + [kv_spec(i) for i in range(P)],
         out_specs=pl.BlockSpec((1, nkv, tq * g, hd),
-                               lambda s, j, pos0, ctx, bt: (s, 0, 0, 0)),
+                               lambda s, j, pos0, ctx, bt, lyr: (s, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nkv * tq * g, 128), jnp.float32),
             pltpu.VMEM((nkv * tq * g, 128), jnp.float32),
@@ -243,20 +267,23 @@ def paged_prefill_attention(q: jax.Array, kv_layer: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, nkv, tq * g, hd), q.dtype),
         interpret=_interpret(),
     )(seg_pos0.astype(jnp.int32), context_lens.astype(jnp.int32),
-      block_table.astype(jnp.int32), qg, *([kv_layer] * P))
+      block_table.astype(jnp.int32), layer, qg, *([kv] * P))
     return (out.reshape(S, nkv, tq, g, hd)
             .transpose(0, 2, 1, 3, 4)
             .reshape(S, tq, nh, hd))
 
 
-def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
+def paged_decode_attention(q: jax.Array, kv: jax.Array,
                            block_table: jax.Array, context_lens: jax.Array,
                            scale: float = None,
-                           pages_per_compute_block: int = 1) -> jax.Array:
+                           pages_per_compute_block: int = 1,
+                           layer=None) -> jax.Array:
     """Decode attention over a paged KV pool.
 
     q            [S, num_heads, head_dim] — one query token per sequence
-    kv_layer     [num_blocks, block_size, 2, kv_heads, head_dim]
+    kv           [L, num_blocks, block_size, 2, kv_heads, head_dim], read
+                 at ``layer`` (a traced int32 scalar); or one layer's
+                 5-D pool with ``layer`` left out
     block_table  [S, max_pages] int32 page ids (entries past the context
                  may be stale/scratch; they are read but masked)
     context_lens [S] int32 — keys visible per sequence (including the
@@ -269,7 +296,8 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     Returns [S, num_heads, head_dim] in q.dtype.
     """
     S, nh, hd = q.shape
-    nb, bs, _, nkv, _ = kv_layer.shape
+    kv, layer = _pool_and_layer(kv, layer)
+    _, nb, bs, _, nkv, _ = kv.shape
     Bm = block_table.shape[1]
     if nh % nkv:
         raise ValueError(f"num_heads {nh} not a multiple of kv_heads {nkv}")
@@ -284,26 +312,21 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
 
     P = max(1, min(int(pages_per_compute_block), Bm))
 
-    def page(s, j, bt, ctx, i=0):
-        # clamp beyond-context iterations to the last live page: Mosaic
-        # skips the DMA when consecutive grid steps request the same block
-        last = jax.lax.max(ctx[s] - 1, 0) // bs
-        j_eff = jax.lax.min(j * P + i, last)
-        return jax.lax.min(jax.lax.max(bt[s, j_eff], 0), nb - 1)
-
     def kv_spec(i):
         return pl.BlockSpec(
-            (1, bs, 2, nkv, hd),
-            lambda s, j, bt, ctx: (page(s, j, bt, ctx, i), 0, 0, 0, 0))
+            (1, 1, bs, 2, nkv, hd),
+            lambda s, j, bt, ctx, lyr: (
+                lyr[0], _page_id(bt, ctx, s, j * P + i, bs, nb), 0, 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, -(-Bm // P)),
         in_specs=[
-            pl.BlockSpec((1, nkv, gp, hd), lambda s, j, bt, ctx: (s, 0, 0, 0)),
+            pl.BlockSpec((1, nkv, gp, hd),
+                         lambda s, j, bt, ctx, lyr: (s, 0, 0, 0)),
         ] + [kv_spec(i) for i in range(P)],
         out_specs=pl.BlockSpec((1, nkv, gp, hd),
-                               lambda s, j, bt, ctx: (s, 0, 0, 0)),
+                               lambda s, j, bt, ctx, lyr: (s, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nkv * gp, 128), jnp.float32),  # running max
             pltpu.VMEM((nkv * gp, 128), jnp.float32),  # running denom
@@ -318,5 +341,5 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, nkv, gp, hd), q.dtype),
         interpret=_interpret(),
     )(block_table.astype(jnp.int32), context_lens.astype(jnp.int32),
-      qg, *([kv_layer] * P))
+      layer, qg, *([kv] * P))
     return out[:, :, :g, :].reshape(S, nh, hd)

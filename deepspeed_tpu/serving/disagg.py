@@ -340,15 +340,12 @@ def install_prefix(engine, handoff: Optional[KVHandoff]
     import jax.numpy as jnp
 
     blocks = kvc.allocator.allocate(need)
-    bidx = jnp.asarray(blocks)
     sel = handoff.block_data[:, to_install]
     ssel = (None if handoff.scales is None
             else jnp.asarray(handoff.scales[:, to_install], jnp.float32))
     q, s = _pool_convert(kvc, jnp.asarray(sel), ssel,
                          handoff.wire_bits, handoff.packed)
-    kvc.data = kvc.data.at[:, bidx].set(q)
-    if s is not None:
-        kvc.scales = kvc.scales.at[:, bidx].set(s)
+    kvc.write_blocks(blocks, q, s)
     installed: List[str] = []
     for idx, blk in zip(to_install, blocks):
         if cache.register(handoff.keys[idx], int(blk)):
