@@ -41,8 +41,10 @@ def sample(traffic: Dict, seed: int, vocab: int, n: int):
 
 def drive(served: Served, traffic: Dict, seed: int, vocab: int,
           seconds: float, on_window_open=None, while_open=None) -> Dict:
-    """Returns the window ``[0, end]`` on the served clock (rebased when
-    the window opens)."""
+    """Returns the window ``[0, seconds]`` on the served clock (rebased
+    when the window opens), exactly: the ``serve_step`` that straddles the
+    close counts for the share of its duration inside
+    (``stats.tokens_prorated``). ``t_end`` is when that step ended."""
     reqs = stream(traffic, seed, vocab)
     for _ in range(traffic["clients"]):
         served.put(next(reqs))
@@ -69,4 +71,4 @@ def drive(served: Served, traffic: Dict, seed: int, vocab: int,
         pump()
         if while_open:
             while_open()
-    return {"t0": 0.0, "t1": served.now()}
+    return {"t0": 0.0, "t1": float(seconds), "t_end": served.now()}

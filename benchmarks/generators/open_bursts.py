@@ -156,6 +156,10 @@ def drive(served: Served, traffic: Dict, seed: int, vocab: int,
                 time.sleep(max(0.0, nxt - served.now()))
             continue
         served.step()
-    return {"t0": 0.0, "t1": served.now(), "scheduled": sched,
+    t1 = served.now()
+    return {"t0": 0.0, "t1": t1, "scheduled": sched,
             "generator_lag_s": lag, "sent": i,
+            # due in the window and never put: the engine sat in one step
+            # from before their arrival to the close
+            "unsent": [r for r in reqs[i:] if r.scheduled < t1],
             "tags": {r.rid: r.tag for r in reqs}}

@@ -41,8 +41,9 @@ class Served:
     """The engine through ``put`` and ``serve_step`` only, with the
     benchmark's clock and spans around each call and its own ledger:
     deliveries (time, request, tokens), seconds inside ``serve_step``, and
-    per device step the sequences it advanced and the contexts a decode
-    step read (from the public counters' deltas)."""
+    per ``serve_step`` its start, duration and the tokens it handed out,
+    the sequences it advanced and the contexts a decode step read (from
+    the public counters' deltas)."""
 
     def __init__(self, engine):
         self.engine = engine
@@ -75,6 +76,18 @@ class Served:
     def outstanding(self) -> int:
         return len(self.asked) - len(self.done_at)
 
+    def drain(self, limit_s: float) -> None:
+        """After a window has closed: let what it left in flight finish,
+        for at most ``limit_s``. The ledgers the metrics are reduced from
+        (deliveries, busy, steps) stay as the close left them; only what
+        each request got moves, so that a late request can be told from
+        one the engine never finished."""
+        kept = len(self.deliveries), len(self.busy), len(self.steps)
+        until = self.now() + limit_s
+        while self.outstanding and self.now() < until:
+            self.step()
+        del self.deliveries[kept[0]:], self.busy[kept[1]:], self.steps[kept[2]:]
+
     def put(self, req: Request) -> None:
         with trace.span("put"):
             self.engine.put([req.rid], [req.prompt], max_new_tokens=req.max_new)
@@ -96,6 +109,7 @@ class Served:
         burst = eng.stats.get("burst_steps", 0) - b0 > 0
         live = [rid for rid, toks in out.items() if toks]
         rec = {"t": t, "dt": t1 - t, "decode_kernel_steps": dk,
+               "tokens": sum(len(toks) for toks in out.values()),
                "device_steps": dk if burst else 1,
                "seqs": len(live) if burst
                else eng.scheduler.last_scheduled_seqs}

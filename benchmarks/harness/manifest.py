@@ -1,17 +1,35 @@
 """``BENCHMARK.json`` -> the files of one cell, by name.
 
 Everything that belongs to one configuration, one traffic mix, one
-generator, one runner or one per-layer metric sits in a file of its own,
-found by the name the manifest (or the file that names it) gives. A later
-PR adds files and entries and edits nothing here. A name that resolves to
-no file is an error that says which file was looked for.
+generator, one runner, one per-layer metric or one *architecture* sits in
+a file of its own, found by the name the manifest (or the file that names
+it) gives. A later PR adds files and entries and edits nothing here. A
+name that resolves to no file is an error that says which file was looked
+for.
+
+An architecture is two files, and a configuration names both:
+
+* ``"reference": "<name>"`` -> ``references/<name>.py``, the one place
+  that knows the architecture: ``Arch`` (``from_model(config)``,
+  ``leaf_table()``, and the attributes ``num_hidden_layers`` and
+  ``vocab_size``), ``forward_logits``, ``loss_and_grads``,
+  ``CHECK_LAYER_LEAVES`` / ``CHECK_TOP_LEAVES`` and
+  ``train_flops_per_token`` (``references/mistral.py`` is the pattern);
+* ``"published": "<name>"`` -> ``published/<name>.json``: the source URL
+  and the values of the model's own ``config.json`` (``config``), against
+  which the manifest test holds every configuration that names it.
+
+Two keys and not one, because one set of layer equations serves several
+published models. ``benchmarks/README.md`` says how to add an architecture.
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
+import sys
 from typing import Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -51,15 +69,48 @@ def load_json(kind_dir: str, name: str, bench_dir: str = BENCH_DIR) -> Dict:
         return json.load(f)
 
 
+_BY_PATH: Dict[str, object] = {}
+
+
 def load_module(kind_dir: str, name: str, bench_dir: str = BENCH_DIR):
     """Import ``<bench_dir>/<kind_dir>/<name>.py`` by path (a metric's name
-    may hold dots and dashes, so it is not a module name)."""
+    may hold dots and dashes, so it is not a module name). One module
+    object per file, and for a file of the ``benchmarks`` package with a
+    module's name the package's own (``benchmarks.references.mistral``): a
+    reference's ``Arch`` keys the caches of jitted programs, so it has to
+    be one class however it was reached."""
     path = _find(kind_dir, name, ".py", bench_dir)
-    spec = importlib.util.spec_from_file_location(
-        f"_bench_{kind_dir}_{name}".replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    if path not in _BY_PATH:
+        if name.isidentifier() and os.path.dirname(os.path.dirname(
+                path)) == BENCH_DIR:
+            mod = importlib.import_module(f"benchmarks.{kind_dir}.{name}")
+        else:
+            spec = importlib.util.spec_from_file_location(
+                f"_bench_{kind_dir}_{name}".replace(".", "_")
+                .replace("-", "_"), path)
+            mod = importlib.util.module_from_spec(spec)
+            sys.modules[spec.name] = mod    # a dataclass looks itself up
+            spec.loader.exec_module(mod)
+        _BY_PATH[path] = mod
+    return _BY_PATH[path]
+
+
+def reference_of(config: Dict, bench_dir: str = BENCH_DIR):
+    """The reference module a configuration names (``"reference"``)."""
+    return load_module("references", _named(config, "reference"), bench_dir)
+
+
+def published_of(config: Dict, bench_dir: str = BENCH_DIR) -> Dict:
+    """The published file a configuration names (``"published"``)."""
+    return load_json("published", _named(config, "published"), bench_dir)
+
+
+def _named(config: Dict, key: str) -> str:
+    if key not in config:
+        raise MissingPiece(f"configuration {config.get('name')!r} has no "
+                           f"{key!r} key: the name of its architecture's "
+                           f"file under benchmarks/ ({key} ...)")
+    return config[key]
 
 
 def cell(manifest: Dict, workload: str) -> Dict:

@@ -40,9 +40,66 @@ def token_gaps(deliveries: Iterable[Tuple[float, int, int]], t0: float,
     return gaps
 
 
-def tokens_in_window(deliveries: Iterable[Tuple[float, int, int]], t0: float,
-                     t1: float) -> int:
-    return sum(n for t, _, n in deliveries if t0 <= t < t1)
+def step_share_inside(start: float, duration: float, t0: float,
+                      t1: float) -> float:
+    """The share of the step ``[start, start + duration]`` that lies in
+    ``[t0, t1]``: 1 for a step inside, 0 for one outside, and for the step
+    that straddles a border the share of its duration on the inside."""
+    if duration <= 0.0:
+        return 1.0 if t0 <= start < t1 else 0.0
+    inside = min(start + duration, t1) - max(start, t0)
+    return min(1.0, max(0.0, inside / duration))
+
+
+def tokens_prorated(steps: Iterable[Tuple[float, float, int]], t0: float,
+                    t1: float) -> float:
+    """Tokens delivered in ``[t0, t1]`` as a *continuous* function of the
+    borders. ``steps`` are ``(start, duration, tokens)``: a step hands out
+    its tokens when it ends, and they are the work of its whole duration,
+    so the step that straddles the close counts for the share of its
+    duration inside. Counting whole deliveries instead makes the rate step
+    by one delivery (a decode burst hands out 192-256 tokens at once) as
+    the close moves across a step's end by a millisecond."""
+    return sum(n * step_share_inside(s, d, t0, t1) for s, d, n in steps if n)
+
+
+# the closes a closed loop's rate is the mean over: the window's last tenth.
+# A constant of the yardstick, not of a mix: every cell that reports the
+# rate reports the same quantity.
+RATE_OVER_LAST = 0.1
+
+
+def mean_rate_over_closes(steps: Sequence[Tuple[float, float, int]], t0: float,
+                          c_lo: float, c_hi: float) -> float:
+    """The mean, over every close ``c`` in ``[c_lo, c_hi]``, of the rate of
+    the window ``[t0, c]``: ``tokens_prorated(steps, t0, c) / (c - t0)``.
+
+    Every member is all the tokens over all the time of a window that
+    opens at ``t0``. One close alone reads the timeline at one instant,
+    where tokens come at 1,500 a second inside a decode burst and at 85
+    inside a gather step, so a run that is 0.1 s behind (a stall inside one
+    step) reads up to three times that lag; the mean over a few seconds of
+    closes reads the lag once. Exact: the prorated count is linear between
+    step borders, and the integral of (a + b x) / x is a ln x + b x.
+    ``steps`` do not overlap (one engine thread)."""
+    if not t0 < c_lo < c_hi:
+        raise ValueError(f"closes [{c_lo}, {c_hi}] of a window opening at {t0}")
+    borders = sorted({c_lo, c_hi} | {p for s, d, _ in steps for p in (s, s + d)
+                                     if c_lo < p < c_hi})
+    total = 0.0
+    for a, b in zip(borders, borders[1:]):
+        na, nb = tokens_prorated(steps, t0, a), tokens_prorated(steps, t0, b)
+        slope = (nb - na) / (b - a)
+        at_zero = na - slope * (a - t0)
+        total += at_zero * math.log((b - t0) / (a - t0)) + slope * (b - a)
+    return total / (c_hi - c_lo)
+
+
+def closed_loop_rate(steps: Sequence[Tuple[float, float, int]], t0: float,
+                     t1: float) -> float:
+    """``serve_tokens_per_s`` of the window ``[t0, t1]``: the mean over the
+    closes in its last ``RATE_OVER_LAST``. There is no other form of it."""
+    return mean_rate_over_closes(steps, t0, t1 - RATE_OVER_LAST * (t1 - t0), t1)
 
 
 def first_token_times(deliveries: Iterable[Tuple[float, int, int]]

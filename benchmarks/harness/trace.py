@@ -34,18 +34,18 @@ _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 
 
-_MOSAIC = re.compile(
-    r"custom-call\((.*?)\), custom_call_target=\"tpu_custom_call\"")
+_MOSAIC = re.compile(r"^%?([\w\-]+?)(?:\.\d+)? = .*custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"", re.S)
 
 
-def mosaic_operands(event_name: str) -> Optional[int]:
-    """How many operands a Pallas (Mosaic) kernel's device event takes, or
-    None for any other event. The program gives its kernels no name (an
-    event is named by its whole HLO instruction, ``%closed_call.9 = ...
-    custom-call(...)``; PERF.md, Open questions), so they are told apart
-    by what they are."""
-    m = _MOSAIC.search(event_name)
-    return m.group(1).count("%") if m else None
+def kernel_name(event_name: str) -> Optional[str]:
+    """The name the program gave a Pallas (Mosaic) kernel, or None for any
+    other event. A device event is named by its whole HLO instruction, and
+    a kernel's instruction by the ``name=`` of its ``pallas_call``:
+    ``%flash_fwd.17 = (...) custom-call(...), custom_call_target=
+    "tpu_custom_call"`` -> ``flash_fwd`` (PERF.md section 3)."""
+    m = _MOSAIC.match(event_name)
+    return m.group(1) if m else None
 
 
 def span(name: str):

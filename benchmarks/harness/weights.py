@@ -1,57 +1,38 @@
 """Weights from ``--seed``: one function, read by the program and by the
 plain reference alike, so neither depends on anything the other made.
 
-Every leaf is a normal draw from its own key (seed, leaf name, layer), so
-one layer can be made alone — the reference holds one layer in float32 at
-a time — and equals, bit for bit, that layer's slice of the stacked tree
-the program is given (a test pins it). Scales follow the usual fan-in rule
-so that activations stay of order one through the depth; norm gains are
-drawn around one so that a dropped gain would show.
+Which leaves an architecture has is data: its reference module's
+``Arch.leaf_table()`` gives a ``Leaf`` for each (path in the program's
+tree, published name, shape, scale, whether it is one per layer), and
+nothing here knows an architecture. Every leaf is a normal draw from its
+own key (seed, leaf path, layer), so one layer can be made alone — the
+reference holds one layer in float32 at a time — and equals, bit for bit,
+that layer's slice of the stacked tree the program is given (a test pins
+it). A scale of None is a norm gain, drawn around one so that a dropped
+gain would show.
 
-The stacked tree has the layout ``deepspeed_tpu.models.transformer``
-takes (``layers.attn.wq [L, H, n, D]`` ...): that layout is the interface
-between benchmark and program. ``reference_layer`` / ``reference_top``
-rename the same arrays to the published names the reference uses.
+The stacked tree has the layout the program takes (``layers.attn.wq [L,
+H, n, D]`` ...): that layout is the interface between benchmark and
+program. ``reference_layer_fn`` / ``reference_top`` rename the same arrays
+to the published names the reference uses.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 import zlib
-from typing import Dict
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from benchmarks.references.mistral import Arch
 
-# leaf name -> (path in the program's tree, published name or None)
-_LAYER_LEAVES = {
-    "attn.wq": "q_proj", "attn.wk": "k_proj", "attn.wv": "v_proj",
-    "attn.wo": "o_proj", "mlp.wg": "gate_proj", "mlp.wi": "up_proj",
-    "mlp.wo": "down_proj", "ln1.scale": "input_layernorm",
-    "ln2.scale": "post_attention_layernorm",
-}
-_TOP_LEAVES = {"embed.tokens": "embed_tokens", "final_norm.scale": "norm",
-               "unembed.kernel": "lm_head"}
-
-
-def _shapes(a: Arch) -> Dict[str, tuple]:
-    h, nq, nkv, d, f, v = (a.hidden_size, a.num_attention_heads,
-                           a.num_key_value_heads, a.head_dim,
-                           a.intermediate_size, a.vocab_size)
-    fan = 1.0 / math.sqrt(h)
-    return {
-        "attn.wq": ((h, nq, d), fan), "attn.wk": ((h, nkv, d), fan),
-        "attn.wv": ((h, nkv, d), fan),
-        "attn.wo": ((nq, d, h), 1.0 / math.sqrt(nq * d)),
-        "mlp.wg": ((h, f), fan), "mlp.wi": ((h, f), fan),
-        "mlp.wo": ((f, h), 1.0 / math.sqrt(f)),
-        "ln1.scale": ((h,), None), "ln2.scale": ((h,), None),
-        "embed.tokens": ((v, h), 0.02), "final_norm.scale": ((h,), None),
-        "unembed.kernel": ((h, v), 0.02),
-    }
+class Leaf(NamedTuple):
+    path: str                   # in the program's tree: "attn.wq"
+    published: str              # in the reference: "q_proj"
+    shape: Tuple[int, ...]      # of one layer's leaf, or of a top leaf
+    scale: Optional[float]      # of the normal draw; None: 1 + 0.1 n
+    per_layer: bool             # stacked on axis 0 under "layers"
 
 
 def base_key(seed: int):
@@ -63,8 +44,8 @@ def base_key(seed: int):
     return jax.random.PRNGKey(int(seed))
 
 
-def _leaf_key(key, name: str):
-    return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+def _leaf_key(key, path: str):
+    return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
 def _draw(key, shape, scale, dtype):
@@ -73,39 +54,41 @@ def _draw(key, shape, scale, dtype):
     return x.astype(dtype)
 
 
-def layer_leaf(a: Arch, key, name: str, layer, dtype):
-    shape, scale = _shapes(a)[name]
-    return _draw(jax.random.fold_in(_leaf_key(key, name), layer), shape,
-                 scale, dtype)
+def draw_leaf(leaf: Leaf, key, layer, dtype):
+    """One leaf from ``key = base_key(seed)``; ``layer`` (traced or not)
+    for a per-layer leaf, None for a top leaf."""
+    k = _leaf_key(key, leaf.path)
+    if leaf.per_layer:
+        k = jax.random.fold_in(k, layer)
+    return _draw(k, leaf.shape, leaf.scale, dtype)
 
 
-def _nest(flat: Dict[str, jax.Array]) -> Dict:
-    tree: Dict = {}
-    for name, x in flat.items():
-        outer, inner = name.split(".")
-        tree.setdefault(outer, {})[inner] = x
-    return tree
+def _put(tree: Dict, path: str, x) -> None:
+    *outer, inner = path.split(".")
+    for part in outer:
+        tree = tree.setdefault(part, {})
+    tree[inner] = x
 
 
-def program_params(a: Arch, key, dtype) -> Dict:
+def program_params(a, key, dtype) -> Dict:
     """The whole tree in the program's layout, layers stacked on axis 0,
     from ``key = base_key(seed)``. Pure and traceable: call it under
     ``jit`` with the key as an argument, so that the weights are made on
     the device, in the type they are used in, by a program that does not
     depend on the seed."""
     layers = jnp.arange(a.num_hidden_layers)
-    flat = {name: jax.vmap(lambda l, n=name: layer_leaf(a, key, n, l, dtype))(
-        layers) for name in _LAYER_LEAVES}
-    tree = {"layers": _nest(flat)}
-    for name in _TOP_LEAVES:
-        shape, scale = _shapes(a)[name]
-        outer, inner = name.split(".")
-        tree.setdefault(outer, {})[inner] = _draw(_leaf_key(key, name),
-                                                  shape, scale, dtype)
+    tree: Dict = {"layers": {}}
+    for leaf in a.leaf_table():
+        if leaf.per_layer:
+            _put(tree["layers"], leaf.path, jax.vmap(
+                lambda l, leaf=leaf: draw_leaf(leaf, key, l, dtype))(layers))
+    for leaf in a.leaf_table():
+        if not leaf.per_layer:
+            _put(tree, leaf.path, draw_leaf(leaf, key, None, dtype))
     return tree
 
 
-def reference_layer_fn(a: Arch, seed: int, dtype):
+def reference_layer_fn(a, seed: int, dtype):
     """``layer -> {published name: float32 array}``: the same draws, in
     the program's storage type first (so the values are the ones the
     program holds), then widened."""
@@ -115,44 +98,43 @@ def reference_layer_fn(a: Arch, seed: int, dtype):
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_program(a: Arch, dtype: str):
+def _layer_program(a, dtype: str):
     return jax.jit(lambda key, layer: {
-        pub: layer_leaf(a, key, name, layer, jnp.dtype(dtype))
-        .astype(jnp.float32) for name, pub in _LAYER_LEAVES.items()})
+        leaf.published: draw_leaf(leaf, key, layer, jnp.dtype(dtype))
+        .astype(jnp.float32) for leaf in a.leaf_table() if leaf.per_layer})
 
 
 @functools.lru_cache(maxsize=None)
-def _top_program(a: Arch, dtype: str):
-    def make(key):
-        out = {}
-        for name, pub in _TOP_LEAVES.items():
-            shape, scale = _shapes(a)[name]
-            out[pub] = _draw(_leaf_key(key, name), shape, scale,
-                             jnp.dtype(dtype)).astype(jnp.float32)
-        return out
-
-    return jax.jit(make)
+def _top_program(a, dtype: str):
+    return jax.jit(lambda key: {
+        leaf.published: draw_leaf(leaf, key, None, jnp.dtype(dtype))
+        .astype(jnp.float32) for leaf in a.leaf_table()
+        if not leaf.per_layer})
 
 
-def reference_top(a: Arch, seed: int, dtype) -> Dict:
+def reference_top(a, seed: int, dtype) -> Dict:
     return _top_program(a, jnp.dtype(dtype).name)(base_key(seed))
 
 
 @functools.lru_cache(maxsize=None)
-def _params_program(a: Arch, dtype: str):
+def _params_program(a, dtype: str):
     return jax.jit(lambda key: program_params(a, key, jnp.dtype(dtype)))
 
 
-def make_program_params(a: Arch, seed: int, dtype) -> Dict:
+def make_program_params(a, seed: int, dtype) -> Dict:
     """The program's tree, made on the device in one jitted call."""
     return _params_program(a, jnp.dtype(dtype).name)(base_key(seed))
 
 
-def program_leaf_name(published: str) -> str:
+def leaf_of(a, published: str) -> Leaf:
+    """The leaf behind a published name, with or without its layer:
+    ``"layers.3.q_proj"`` or ``"lm_head"``."""
+    name = published.split(".")[-1]
+    return next(leaf for leaf in a.leaf_table() if leaf.published == name)
+
+
+def program_leaf_name(a, published: str) -> str:
     """``"layers.3.q_proj"`` -> the path of the same leaf in the program's
     tree (``"layers.attn.wq"``) — for reading the program's gradient."""
-    inv = {v: k for k, v in {**_LAYER_LEAVES, **_TOP_LEAVES}.items()}
-    parts = published.split(".")
-    if parts[0] == "layers":
-        return "layers." + inv[parts[2]]
-    return inv[published]
+    leaf = leaf_of(a, published)
+    return ("layers." if leaf.per_layer else "") + leaf.path

@@ -6,24 +6,24 @@ of memory-efficient attention is five (scores again, dV, dP, dQ, dK). A
 kernel that recomputes more than that reads a lower share, never a higher.
 Bytes: every operand read once and every result written once.
 
-``classify`` tells the kernels' device events apart in a trace. The
-program gives its kernels no name of their own (an event is named by the
-whole HLO instruction, ``%closed_call.9 = ... custom-call(...)``; PERF.md,
-Open questions), so they are told by what they are: a ``tpu_custom_call``
-with three operands (q, k, v) is a forward, one with six (q, k, v, o, lse,
-delta) is one half of a backward (dK/dV or dQ).
+``classify`` tells the kernels' device events apart in a trace by the
+name the program gives each kernel (``flash_fwd``, and the two halves of a
+backward, ``flash_bwd_dkdv`` and ``flash_bwd_dq``), so that a program with
+other kernels beside them is read the same.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Tuple
 
-from benchmarks.harness.trace import mosaic_operands
+from benchmarks.harness.trace import kernel_name
+
+KERNELS = {"flash_fwd": "fwd", "flash_bwd_dkdv": "bwd", "flash_bwd_dq": "bwd"}
 
 
 def classify(event_name: str):
     """"fwd", "bwd" (one of its two kernels) or None."""
-    return {3: "fwd", 6: "bwd"}.get(mosaic_operands(event_name))
+    return KERNELS.get(kernel_name(event_name))
 
 
 def _pairs(seq: int, causal: bool) -> float:

@@ -11,13 +11,13 @@ from __future__ import annotations
 
 from typing import Iterable, Tuple
 
-from benchmarks.harness.trace import mosaic_operands
+from benchmarks.harness.trace import kernel_name
 
 
 def classify(event_name: str):
-    """"decode" for a Mosaic kernel event inside a decode program (the
-    decode programs hold no other kernel), else None."""
-    return "decode" if mosaic_operands(event_name) is not None else None
+    """"decode" for the ``paged_decode`` kernel's device events (by the
+    name the program gives the kernel), else None."""
+    return "decode" if kernel_name(event_name) == "paged_decode" else None
 
 
 def call(context_lens: Iterable[int], n_q: int, n_kv: int, d: int,

@@ -1,29 +1,27 @@
 """Device time of the decode programs (``decode`` and ``multi_decode``
-of ``_shared_step_fns``) per token step they produce. The device's module
-line names every program ``jit__unknown`` (PERF.md, Open questions), so the
-programs are told by when they ran: the device-busy time inside the
-benchmark's own ``serve_step`` spans of the traced window whose step the
-engine's counter shows as decode-only, over the decode steps it counted.
+of ``_shared_step_fns``) per token step they produce: the device-busy time
+inside the executions of ``jit_dstpu_serve_decode`` and
+``jit_dstpu_serve_multi_decode`` (told by the module line, as
+``kv_pool_copy_ms`` tells them) in the traced window, over the decode token
+steps the engine's counter shows for the steps of that window.
 """
 
+from benchmarks.harness import program_trace as P
 from benchmarks.harness import trace as T
+from benchmarks.layer_metrics.kv_pool_copy_ms import DECODE_PROGRAMS
 
 
 def read(ctx, result):
-    tr = result.get("trace")
-    if tr is None or not tr.device_ops:
+    pt = P.open_run(ctx, result)
+    if pt is None:
         return None
+    runs = [r for p in DECODE_PROGRAMS for r in pt.executions(p)]
     lo, hi = result["facts"]["traced_steps"]
-    steps = result["served"].steps[lo:hi]
-    spans = sorted((s for s in tr.host_spans if s[0] == "serve_step"),
-                   key=lambda s: s[1])
-    if len(spans) != len(steps):      # the profiler dropped host events
-        ctx.note({"decode_step_ms": f"{len(spans)} spans for {len(steps)} "
-                                    "steps: paired in order from the first"})
-    ops = tr.device_ops[min(tr.device_ops)]
-    busy = n = 0.0
-    for (_, start, dur), step in zip(spans, steps):
-        if step["decode_kernel_steps"]:
-            busy += T.busy_seconds(ops, start, start + dur)
-            n += step["decode_kernel_steps"]
-    return 1e3 * busy / n if n else None
+    token_steps = sum(s["decode_kernel_steps"]
+                      for s in result["served"].steps[lo:hi])
+    if not runs or not token_steps:
+        return None
+    ops = T.merge((s, s + d) for _, s, d in
+                  pt.trace.device_ops[min(pt.trace.device_ops)])
+    busy = sum(T.measure(T.clip(ops, start, end)) for start, end in runs)
+    return 1e3 * busy / token_steps

@@ -28,10 +28,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, Sequence
+import math
+from typing import Callable, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.harness.weights import Leaf
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +55,62 @@ class Arch:
     def from_model(cls, model: Dict) -> "Arch":
         names = [f.name for f in dataclasses.fields(cls)]
         return cls(**{k: model[k] for k in names})
+
+    def leaf_table(self) -> Tuple[Leaf, ...]:
+        """Every weight, as data for ``harness/weights.py``: its path in
+        the program's tree (the layout ``deepspeed_tpu.models.transformer``
+        takes, layer leaves stacked on axis 0), the published name the
+        equations below use, its shape and the scale of its normal draw
+        (the usual fan-in rule, so that activations stay of order one
+        through the depth; None: a norm gain, drawn around one so that a
+        dropped gain would show)."""
+        h, nq, nkv, d, f, v = (self.hidden_size, self.num_attention_heads,
+                               self.num_key_value_heads, self.head_dim,
+                               self.intermediate_size, self.vocab_size)
+        fan = 1.0 / math.sqrt(h)
+        return (
+            Leaf("attn.wq", "q_proj", (h, nq, d), fan, True),
+            Leaf("attn.wk", "k_proj", (h, nkv, d), fan, True),
+            Leaf("attn.wv", "v_proj", (h, nkv, d), fan, True),
+            Leaf("attn.wo", "o_proj", (nq, d, h), 1.0 / math.sqrt(nq * d),
+                 True),
+            Leaf("mlp.wg", "gate_proj", (h, f), fan, True),
+            Leaf("mlp.wi", "up_proj", (h, f), fan, True),
+            Leaf("mlp.wo", "down_proj", (f, h), 1.0 / math.sqrt(f), True),
+            Leaf("ln1.scale", "input_layernorm", (h,), None, True),
+            Leaf("ln2.scale", "post_attention_layernorm", (h,), None, True),
+            Leaf("embed.tokens", "embed_tokens", (v, h), 0.02, False),
+            Leaf("final_norm.scale", "norm", (h,), None, False),
+            Leaf("unembed.kernel", "lm_head", (h, v), 0.02, False),
+        )
+
+
+# the gradient leaves the training check samples (published names): one
+# seeded layer's seven matrices, and of the top the final norm and the head
+CHECK_LAYER_LEAVES = ("q_proj", "k_proj", "v_proj", "o_proj", "gate_proj",
+                      "up_proj", "down_proj")
+CHECK_TOP_LEAVES = ("norm", "lm_head")
+
+
+def matmul_params(a: Arch) -> int:
+    """Weights that multiply every token: the blocks and the head (the
+    embedding is a lookup)."""
+    h, d = a.hidden_size, a.head_dim
+    attn = h * d * (2 * a.num_attention_heads + 2 * a.num_key_value_heads)
+    mlp = 3 * h * a.intermediate_size
+    return a.num_hidden_layers * (attn + mlp) + h * a.vocab_size
+
+
+def train_flops_per_token(a: Arch, seq: int) -> float:
+    """Operations the forward and backward passes require per trained
+    token; recomputation is not counted (the numerator of model-FLOP/s
+    utilization, not of hardware utilization). Forward 2 FLOP per weight;
+    causal attention, averaged over the positions of a full sequence, 2
+    products of seq/2 keys by head_dim per query head: 2 * 2 * (seq / 2) *
+    head_dim * heads per layer. Backward twice the forward."""
+    fwd = 2.0 * matmul_params(a) + (a.num_hidden_layers * 2.0 * seq
+                                    * a.head_dim * a.num_attention_heads)
+    return 3.0 * fwd
 
 
 def _round(x, numerics: str):

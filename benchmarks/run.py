@@ -43,8 +43,9 @@ class Context:
             bool(args.trace)
         self.seconds_left = float(args.seconds)
         self.rehearse = args.rehearse
-        self.trace_dir = os.path.join(mf.ROOT, ".bench_out", "trace",
-                                      cell["name"])
+        # what a run leaves behind goes inside the checkout
+        self.out_dir = os.path.join(mf.ROOT, ".bench_out")
+        self.trace_dir = os.path.join(self.out_dir, "trace", cell["name"])
         self.verdict = Verdict()
         self.setup_s = None
 
@@ -123,6 +124,7 @@ def main(argv=None) -> int:
         dev["busy_s"], dev["window_s"] = tr.busy_s(), tr.window_s
         line["breakdown"] = tr.breakdown()
     sys.stdout.flush()
+    ctx.verdict.print(sys.stderr)       # the record of a run keeps stderr's end
     print(json.dumps(line), flush=True)
     return 0
 

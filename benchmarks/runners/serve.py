@@ -19,7 +19,6 @@ import numpy as np
 
 from benchmarks.generators.requests import Request, Served
 from benchmarks.harness import compare, compiles, manifest, stats, trace, weights
-from benchmarks.references import mistral
 
 
 def build_engine(cfg: Dict, arch, seed: int):
@@ -165,7 +164,7 @@ def engine_rows(served: Served, reqs: List[Request],
                                      for r in reqs}}
 
 
-def reference_rows(arch, cfg, seed, reqs, tokens, numerics="float32"):
+def reference_rows(ref, arch, cfg, seed, reqs, tokens, numerics="float32"):
     """Reference logits at every position that predicted a generated
     token: for request r, rows ``len(prompt) - 1 + j`` for j < generated."""
     import jax.numpy as jnp
@@ -183,7 +182,7 @@ def reference_rows(arch, cfg, seed, reqs, tokens, numerics="float32"):
         first = len(r.prompt) - 1
         rows.append(np.minimum(first + np.arange(n_rows), len(s) - 1))
         seqs.append(np.pad(s, (0, ceiling - len(s))))
-    out = mistral.forward_logits(
+    out = ref.forward_logits(
         arch, seqs, rows, weights.reference_layer_fn(arch, seed, jnp.bfloat16),
         weights.reference_top(arch, seed, jnp.bfloat16), numerics)
     return {r.rid: np.asarray(o)[:len(tokens[r.rid])]
@@ -196,19 +195,19 @@ def margin(ref_row: np.ndarray, token: int) -> float:
     return float((ref_row.max() - ref_row[token]) / (ref_row.std() + 1e-30))
 
 
-def serve_numbers(reqs, got_rows, got_tokens, ref) -> Dict:
+def serve_numbers(reqs, got_rows, got_tokens, want) -> Dict:
     """The three numbers: relative L2 of the rows at the last prompt
     position, of the rows at decode positions, and the mean margin of
     every generated token under the reference's logits."""
     pre_g, pre_w, dec_g, dec_w, margins, per_row = [], [], [], [], [], []
     for r in reqs:
         for j, tok in enumerate(got_tokens[r.rid]):
-            margins.append(margin(ref[r.rid][j], tok))
+            margins.append(margin(want[r.rid][j], tok))
         for j, row in got_rows[r.rid].items():
             (pre_g if j == 0 else dec_g).append(row)
-            (pre_w if j == 0 else dec_w).append(ref[r.rid][j])
+            (pre_w if j == 0 else dec_w).append(want[r.rid][j])
             per_row.append([r.rid, j, len(r.prompt) + j,
-                            round(compare.rel_l2(row, ref[r.rid][j]), 4)])
+                            round(compare.rel_l2(row, want[r.rid][j]), 4)])
     return {"logits_prefill": compare.rel_l2(np.stack(pre_g), np.stack(pre_w))
             if pre_g else float("nan"),
             "logits_decode": compare.rel_l2(np.stack(dec_g), np.stack(dec_w))
@@ -230,12 +229,12 @@ def sample_requests(cfg, traffic, seed, arch, gen) -> List[Request]:
     return reqs
 
 
-def check(ctx, served: Served, arch, gen) -> Dict:
+def check(ctx, served: Served, ref, arch, gen) -> Dict:
     cfg = ctx.config
     reqs = sample_requests(cfg, ctx.traffic, ctx.seed, arch, gen)
     got = engine_rows(served, reqs)
-    ref = reference_rows(arch, cfg, ctx.seed, reqs, got["tokens"])
-    numbers = serve_numbers(reqs, got["rows"], got["tokens"], ref)
+    want = reference_rows(ref, arch, cfg, ctx.seed, reqs, got["tokens"])
+    numbers = serve_numbers(reqs, got["rows"], got["tokens"], want)
     for k, limit in cfg["check"]["limits"].items():
         ctx.verdict.hold(f"serve.{k}", numbers[k], limit)
     ctx.verdict.require("serve.every_token_delivered", all(
@@ -243,20 +242,38 @@ def check(ctx, served: Served, arch, gen) -> Dict:
     return numbers
 
 
-def open_loop_outcome(served: Served, window: Dict):
-    """(attempted, failed) of an open-loop window: every request due in it
-    was asked for in it, and one that has not delivered all its tokens
-    when the window closes — not sent, queued, still running or cut short
-    — has failed. (Its wait so far stands among the TTFT samples:
-    ``stats.ttfts``.)"""
+def open_loop_outcome(served: Served, window: Dict, drain_s: float):
+    """(attempted, late, failed) of an open-loop window. Every request due
+    in it was asked for in it. One that has not delivered all its tokens
+    when the window closes is *late*: its wait so far stands among the
+    TTFT samples (``stats.ttfts``), and that is where lateness is judged.
+    The engine then gets ``drain_s`` more, outside every ledger, for what
+    the close left in flight or unsent; a request still short of its
+    tokens after that — dropped, cut short, stuck — has *failed*."""
     due = [rid for rid, at in window["scheduled"].items()
            if window["t0"] <= at < window["t1"]]
-    return len(due), sum(rid not in served.done_at for rid in due)
+    late = sum(rid not in served.done_at for rid in due)
+    for req in window.get("unsent", []):
+        served.put(req)
+    served.drain(drain_s)
+    return len(due), late, sum(rid not in served.done_at for rid in due)
+
+
+def where_the_close_fell(steps, t0: float, t1: float) -> Dict:
+    """So that a run says where its window closed: the tokens of the step
+    that straddles the close (0: it closed between steps) and the window's
+    seconds outside any ``serve_step`` (a run that stalls between steps
+    names itself; one that stalls inside a step shows in ``tokens``)."""
+    inside = sum(d * stats.step_share_inside(s, d, t0, t1) for s, d, _ in steps)
+    return {"straddling_step_tokens": sum(n for s, d, n in steps
+                                          if s < t1 < s + d),
+            "outside_serve_step_s": (t1 - t0) - inside}
 
 
 def run(ctx) -> Dict:
     cfg, traffic = ctx.config, ctx.traffic
-    arch = mistral.Arch.from_model(cfg)
+    ref = manifest.reference_of(cfg, ctx.bench_dir)
+    arch = ref.Arch.from_model(cfg)
     gen = manifest.load_module("generators", traffic["generator"],
                                ctx.bench_dir)
     parts = {}
@@ -266,7 +283,7 @@ def run(ctx) -> Dict:
     served = Served(engine)
     parts.update(warm_up(served, cfg, arch.vocab_size))
     t = time.perf_counter()
-    numbers = check(ctx, served, arch, gen)
+    numbers = check(ctx, served, ref, arch, gen)
     parts["check_s"] = time.perf_counter() - t
     t = time.perf_counter()
     if hasattr(gen, "prewarm"):
@@ -329,20 +346,24 @@ def run(ctx) -> Dict:
 
     t0, t1 = window["t0"], window["t1"]
     span = t1 - t0
-    tokens = stats.tokens_in_window(served.deliveries, t0, t1)
+    steps = [(s["t"], s["dt"], s["tokens"]) for s in served.steps]
+    tokens = stats.tokens_prorated(steps, t0, t1)
     done = served.completed()
     truncated = delta["engine"].get("truncated", 0)
     busy_s = sum(d for _, d in served.busy)
-    e2e = {"serve_tokens_per_s": tokens / span,
+    e2e = {"serve_tokens_per_s": stats.closed_loop_rate(steps, t0, t1),
            "serve_busy_ms_per_req": 1e3 * busy_s / max(1, len(done))}
-    gaps = stats.token_gaps(served.deliveries, t0, t1)
+    # the gaps a client sees keep their whole deliveries: up to the end of
+    # the last step, which a closed loop's window cuts (``t_end``)
+    gaps = stats.token_gaps(served.deliveries, t0, window.get("t_end", t1))
     if gaps:
         e2e["tpot_p90_ms"] = 1e3 * stats.percentile(gaps, 90)
     samples = {"token_gaps": len(gaps), "requests_completed": len(done)}
     if "scheduled" in window:
         tt = stats.ttfts(served.deliveries, window["scheduled"], t0, t1)
-        attempted, failed = open_loop_outcome(served, window)
-        samples.update(ttft=len(tt), unfinished=failed)
+        attempted, late, failed = open_loop_outcome(served, window,
+                                                    ctx.seconds)
+        samples.update(ttft=len(tt), unfinished_at_close=late)
         if tt:
             e2e["ttft_p50_ms"] = 1e3 * stats.percentile(tt, 50)
             e2e["ttft_p90_ms"] = 1e3 * stats.percentile(tt, 90)
@@ -350,6 +371,7 @@ def run(ctx) -> Dict:
         # closed loop: the requests in flight at the close are the load
         attempted, failed = len(done) + served.outstanding, truncated
     ctx.note({"window_s": span, "tokens": tokens, "samples": samples,
+              "close": where_the_close_fell(steps, t0, t1),
               "serve_step_share": busy_s / span,
               "end_to_end": e2e, "counters": delta})
     return {"attempted": attempted, "failed": failed,

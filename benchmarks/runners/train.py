@@ -20,8 +20,7 @@ from typing import Dict
 
 import numpy as np
 
-from benchmarks.harness import compare, compiles, flops, manifest, trace, weights
-from benchmarks.references import mistral
+from benchmarks.harness import compare, compiles, manifest, trace, weights
 
 
 def _sample_rows(n_rows: int, want: int, seed: int, salt: int):
@@ -29,34 +28,39 @@ def _sample_rows(n_rows: int, want: int, seed: int, salt: int):
     return np.sort(rng.choice(n_rows, size=min(want, n_rows), replace=False))
 
 
-def _leaf_plan(arch: mistral.Arch, seed: int, rows: int) -> Dict[str, np.ndarray]:
+def _leaf_plan(ref, arch, seed: int, rows: int) -> Dict[str, np.ndarray]:
     """Which gradient leaves are compared, and which rows of each (axis
-    0): one seeded layer's attention and MLP matrices, the final norm
-    (whole) and rows of the head and the embedding."""
+    0): of one seeded layer the leaves the reference lists
+    (``CHECK_LAYER_LEAVES``) and of the top its ``CHECK_TOP_LEAVES``; a
+    matrix gives a seeded sample of its rows, a vector (a gain) all of
+    itself."""
     layer = int(np.random.default_rng([int(seed), 7]).integers(
         arch.num_hidden_layers))
     plan = {}
-    for i, (name, n0) in enumerate([
-            ("q_proj", arch.hidden_size), ("k_proj", arch.hidden_size),
-            ("v_proj", arch.hidden_size), ("o_proj", arch.num_attention_heads),
-            ("gate_proj", arch.hidden_size), ("up_proj", arch.hidden_size),
-            ("down_proj", arch.intermediate_size)]):
-        plan[f"layers.{layer}.{name}"] = _sample_rows(n0, rows, seed, 100 + i)
-    plan["norm"] = np.arange(arch.hidden_size)
-    plan["lm_head"] = _sample_rows(arch.hidden_size, rows, seed, 200)
+    for names, prefix, salt in ((ref.CHECK_LAYER_LEAVES, f"layers.{layer}.", 100),
+                                (ref.CHECK_TOP_LEAVES, "", 200)):
+        matrices = 0
+        for name in names:
+            shape = weights.leaf_of(arch, name).shape
+            if len(shape) == 1:
+                plan[prefix + name] = np.arange(shape[0])
+            else:
+                plan[prefix + name] = _sample_rows(shape[0], rows, seed,
+                                                   salt + matrices)
+                matrices += 1
     return plan
 
 
-def reference_numbers(arch, cfg, batch, seed, numerics="float32") -> Dict:
+def reference_numbers(ref, arch, cfg, batch, seed, numerics="float32") -> Dict:
     import jax.numpy as jnp
 
-    plan = _leaf_plan(arch, seed, cfg["check"]["sample_rows"])
+    plan = _leaf_plan(ref, arch, seed, cfg["check"]["sample_rows"])
 
     def keep(name, g):
         rows = plan.get(name)
         return None if rows is None else np.asarray(g[jnp.asarray(rows)])
 
-    out = mistral.loss_and_grads(
+    out = ref.loss_and_grads(
         arch, batch, weights.reference_layer_fn(arch, seed, jnp.float32),
         weights.reference_top(arch, seed, jnp.float32), keep, numerics)
     out["plan"] = plan
@@ -69,7 +73,8 @@ def _tree_get(tree, dotted: str):
     return tree
 
 
-def engine_gradient_rows(engine, plan, b1: float = 0.9) -> Dict[str, np.ndarray]:
+def engine_gradient_rows(engine, arch, plan, b1: float = 0.9
+                         ) -> Dict[str, np.ndarray]:
     """The gradient of the step just taken, read from AdamW's first moment
     (mu_1 = (1 - b1) g_1 from a zero start; the job clips nothing)."""
     import jax
@@ -79,7 +84,7 @@ def engine_gradient_rows(engine, plan, b1: float = 0.9) -> Dict[str, np.ndarray]
     mu = optax.tree_utils.tree_get(engine.opt_state.inner, "mu")
     out = {"_norm": float(jax.jit(optax.global_norm)(mu)) / (1.0 - b1)}
     for name, rows in plan.items():
-        leaf = _tree_get(mu, weights.program_leaf_name(name))
+        leaf = _tree_get(mu, weights.program_leaf_name(arch, name))
         if name.startswith("layers."):
             leaf = leaf[int(name.split(".")[1])]
         out[name] = np.asarray(leaf[jnp.asarray(rows)]) / (1.0 - b1)
@@ -106,14 +111,13 @@ def build_engine(cfg: Dict, arch, seed: int, cell_chips: int):
     import jax.numpy as jnp
 
     import deepspeed_tpu as dstpu
-    from deepspeed_tpu.models.transformer import TransformerLM
     from deepspeed_tpu.models.zoo import get_model
     from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
 
     base = get_model(cfg["preset"], num_layers=arch.num_hidden_layers,
                      max_seq_len=cfg["seq_len"], **cfg.get("preset_overrides", {}))
 
-    class SeededLM(TransformerLM):
+    class SeededLM(type(base)):
         """The zoo model with its weights drawn by the benchmark."""
 
         def init(self, rng):
@@ -134,7 +138,8 @@ def run(ctx) -> Dict:
     import jax
 
     cfg, traffic, seed = ctx.config, ctx.traffic, ctx.seed
-    arch = mistral.Arch.from_model(cfg)
+    ref_mod = manifest.reference_of(cfg, ctx.bench_dir)
+    arch = ref_mod.Arch.from_model(cfg)
     seq, chips = cfg["seq_len"], ctx.cell["chips"]
     gen = manifest.load_module("generators", traffic["generator"], ctx.bench_dir)
     global_batch = cfg["job"]["train_micro_batch_size_per_chip"] * chips \
@@ -151,7 +156,7 @@ def run(ctx) -> Dict:
     batch0, distinct = gen.check_batch(traffic, seed, arch.vocab_size,
                                        global_batch, seq,
                                        cfg["check"]["sample_sequences"])
-    ref = reference_numbers(arch, cfg, distinct, seed)
+    ref = reference_numbers(ref_mod, arch, cfg, distinct, seed)
     parts["reference_s"] = time.perf_counter() - t
 
     t = time.perf_counter()
@@ -163,7 +168,7 @@ def run(ctx) -> Dict:
     t = time.perf_counter()
     loss0 = float(engine.train_batch(iter([{"input_ids": batch0}])))
     engine.synchronize()
-    rows = engine_gradient_rows(engine, ref["plan"])
+    rows = engine_gradient_rows(engine, arch, ref["plan"])
     gnorm = rows.pop("_norm")
     parts["first_step_s"] = time.perf_counter() - t
     numbers = compare_to_reference(ctx.verdict, ref, loss0, gnorm, rows,
@@ -216,5 +221,5 @@ def run(ctx) -> Dict:
         "facts": {"arch": arch, "seq": seq, "chips": chips,
                   "micro_per_chip": cfg["job"]["train_micro_batch_size_per_chip"],
                   "traced_steps": len(traced),
-                  "flops_per_token": flops.train_flops_per_token(arch, seq)},
+                  "flops_per_token": ref_mod.train_flops_per_token(arch, seq)},
     }

@@ -27,11 +27,11 @@ from benchmarks.harness import manifest as mf  # noqa: E402
 def _train(cfg, traffic, cell, seeds, control_seeds, control, emit, bench_dir):
     import jax
 
-    from benchmarks.references import mistral
     from benchmarks.harness import compare
     runner = mf.load_module("runners", "train", bench_dir)
     gen = mf.load_module("generators", traffic["generator"], bench_dir)
-    arch = mistral.Arch.from_model(cfg)
+    ref_mod = mf.reference_of(cfg, bench_dir)
+    arch = ref_mod.Arch.from_model(cfg)
     chips, seq = cell["chips"], cfg["seq_len"]
     gb = cfg["job"]["train_micro_batch_size_per_chip"] * chips
     for seed in sorted(set(seeds) | set(control_seeds)):
@@ -39,10 +39,11 @@ def _train(cfg, traffic, cell, seeds, control_seeds, control, emit, bench_dir):
             traffic, seed, arch.vocab_size, gb, seq,
             cfg["check"]["sample_sequences"])
         t = time.perf_counter()
-        ref = runner.reference_numbers(arch, cfg, distinct, seed)
+        ref = runner.reference_numbers(ref_mod, arch, cfg, distinct, seed)
         ref_s = time.perf_counter() - t
         if seed in control_seeds:
-            low = runner.reference_numbers(arch, cfg, distinct, seed, control)
+            low = runner.reference_numbers(ref_mod, arch, cfg, distinct, seed,
+                                           control)
             emit({"seed": seed, "side": "control", "numerics": control,
                   "loss": compare.rel_abs(low["loss"], ref["loss"]),
                   "grad_norm": compare.rel_abs(low["grad_norm"],
@@ -55,7 +56,7 @@ def _train(cfg, traffic, cell, seeds, control_seeds, control, emit, bench_dir):
             data = gen.batches(traffic, seed, arch.vocab_size, gb, seq)
             loss0 = float(engine.train_batch(iter([{"input_ids": batch0}])))
             engine.synchronize()
-            rows = runner.engine_gradient_rows(engine, ref["plan"])
+            rows = runner.engine_gradient_rows(engine, arch, ref["plan"])
             gnorm = rows.pop("_norm")
             v = compare.Verdict()
             num = runner.compare_to_reference(
@@ -75,10 +76,10 @@ def _serve(cfg, traffic, cell, seeds, control_seeds, control, emit, bench_dir):
 
     from benchmarks.generators.requests import Served
     from benchmarks.harness import weights
-    from benchmarks.references import mistral
     runner = mf.load_module("runners", "serve", bench_dir)
     gen = mf.load_module("generators", traffic["generator"], bench_dir)
-    arch = mistral.Arch.from_model(cfg)
+    ref_mod = mf.reference_of(cfg, bench_dir)
+    arch = ref_mod.Arch.from_model(cfg)
     n = cfg["check"]["sample_requests"]
     engine = served = None
     for seed in sorted(set(seeds) | set(control_seeds)):
@@ -97,14 +98,15 @@ def _serve(cfg, traffic, cell, seeds, control_seeds, control, emit, bench_dir):
             r.rid += seed * 10
         t = time.perf_counter()
         got = runner.engine_rows(served, reqs)
-        ref = runner.reference_rows(arch, cfg, seed, reqs, got["tokens"])
+        ref = runner.reference_rows(ref_mod, arch, cfg, seed, reqs,
+                                    got["tokens"])
         if seed in seeds:
             num = runner.serve_numbers(reqs, got["rows"], got["tokens"], ref)
             emit(dict(num, seed=seed, side="program",
                       check_s=time.perf_counter() - t))
         if seed in control_seeds:
-            low = runner.reference_rows(arch, cfg, seed, reqs, got["tokens"],
-                                        control)
+            low = runner.reference_rows(ref_mod, arch, cfg, seed, reqs,
+                                        got["tokens"], control)
             rows = {r.rid: {j: low[r.rid][j] for j in got["rows"][r.rid]}
                     for r in reqs}
             toks = {r.rid: [int(x) for x in low[r.rid].argmax(-1)]

@@ -30,6 +30,10 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=5)
     ap.add_argument("--manifest", default=os.path.join(mf.ROOT,
                                                        "BENCHMARK.json"))
+    ap.add_argument("--arrival-span", type=float, default=None,
+                    help="share of each window in which requests arrive "
+                         "(default: the traffic file's); 1.0 leaves a rate "
+                         "above the knee no time to catch up")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
     man, bench_dir, cell, cfg, traffic = mf.resolve(args.manifest,
@@ -37,12 +41,11 @@ def main() -> int:
     mf.program_logs_to_stderr()
     from benchmarks.generators.requests import Served
     from benchmarks.harness import cache, compiles, stats
-    from benchmarks.references import mistral
     cache.enable()
     compiles.install()
     runner = mf.load_module("runners", "serve", bench_dir)
     gen = mf.load_module("generators", traffic["generator"], bench_dir)
-    arch = mistral.Arch.from_model(cfg)
+    arch = mf.reference_of(cfg, bench_dir).Arch.from_model(cfg)
     engine, _ = runner.build_engine(cfg, arch, args.seed)
     served = Served(engine)
     runner.warm_up(served, cfg, arch.vocab_size)
@@ -50,19 +53,23 @@ def main() -> int:
     rows = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         t = dict(traffic, bursts_per_s=rate)
+        if args.arrival_span is not None:
+            t["arrival_span"] = args.arrival_span
         before = compiles.count()
         win = gen.drive(served, t, args.seed + i, arch.vocab_size,
                         args.seconds, salt=10 + i)
         backlog = served.outstanding
         tt = stats.ttfts(served.deliveries, win["scheduled"], 0.0, win["t1"])
         busy = sum(d for _, d in served.busy)
-        row = {"bursts_per_s": rate, "sent": win["sent"],
+        row = {"bursts_per_s": rate, "arrival_span": t.get("arrival_span", 1.0),
+               "planned": len(win["scheduled"]), "sent": win["sent"],
                "requests_per_s": win["sent"] / args.seconds,
                "completed": len(served.completed()), "backlog": backlog,
                "compiles": compiles.count() - before,
                "busy_share": busy / win["t1"],
                "ttft_p50_ms": 1e3 * stats.percentile(tt, 50) if tt else None,
                "ttft_p90_ms": 1e3 * stats.percentile(tt, 90) if tt else None,
+               "ttfts_ms": [round(1e3 * x, 1) for x in sorted(tt)],
                "first_tokens": sum(r in stats.first_token_times(
                    served.deliveries) for r in win["scheduled"]),
                "lag_p90_ms": 1e3 * stats.percentile(win["generator_lag_s"], 90)

@@ -170,10 +170,19 @@ def test_open_loop_times_from_the_scheduled_arrival():
     assert win["sent"] == len(win["scheduled"]) or win["t1"] >= 2.0
 
 
-@pytest.mark.parametrize("stall,late", [(0.0, False), (5.0, True)])
-def test_open_loop_requests_unfinished_at_the_close_have_failed(stall, late):
+@pytest.mark.parametrize("stall,drain_s,late,fails", [
+    (0.0, 1.0, False, False),      # in time
+    (2.0, 2.0, True, False),       # pushed past the close, finished after it
+    (9.0, 0.3, True, True),        # never finished: the engine is stuck
+])
+def test_open_loop_late_requests_are_late_and_unfinished_ones_fail(
+        stall, drain_s, late, fails):
     """A system that pushes requests past the window must not read as a
-    fast one: they are attempted, failed, and among the TTFT samples."""
+    fast one: they are attempted and among the TTFT samples at their wait
+    so far. They have *failed* only if the engine does not finish them in
+    the drain after the close — which leaves every ledger as the close
+    left it (one stall of the machine's inside a step made five requests
+    of serve-chat-steady late; PERF.md section 2)."""
     from benchmarks.runners import serve
 
     t = _tiny_bursts()
@@ -181,14 +190,39 @@ def test_open_loop_requests_unfinished_at_the_close_have_failed(stall, late):
     served = Served(eng)
     eng.t0 = served.t0
     win = open_bursts.drive(served, t, 5, 512, 1.5)
-    attempted, failed = serve.open_loop_outcome(served, win)
+    ledgers = [list(served.deliveries), list(served.busy), list(served.steps)]
+    attempted, n_late, failed = serve.open_loop_outcome(served, win, drain_s)
+    assert [served.deliveries, served.busy, served.steps] == ledgers
     tt = stats.ttfts(served.deliveries, win["scheduled"], win["t0"], win["t1"])
     assert attempted == len(win["scheduled"]) == len(tt) > 0
     if late:
-        assert failed == attempted and not served.deliveries
+        assert n_late == attempted and not served.deliveries
         assert min(tt) > 1.5 - max(win["scheduled"].values()) - 0.05
     else:
-        assert failed == 0 and max(tt) < 0.3
+        assert n_late == 0 and max(tt) < 0.3
+    assert failed == (attempted if fails else 0)
+    assert served.outstanding == (len(served.asked) if fails else 0)
+
+
+def test_a_request_never_sent_is_put_after_the_close_and_not_failed():
+    """An engine that sits in one step from before an arrival to the close
+    leaves that request unsent: due in the window, so attempted and late,
+    and served in the drain like the others."""
+    from benchmarks.runners import serve
+
+    class OneLongStep(_StalledEngine):
+        def serve_step(self):
+            if self.clock.perf_counter() - self.t0 < 2.0:
+                self.clock.sleep(2.0)           # holds the loop to the close
+            return super().serve_step()
+
+    t = _tiny_bursts()                          # two bursts in 2 s
+    served = Served(OneLongStep(stall=0.0))
+    win = open_bursts.drive(served, t, 5, 512, 2.0)
+    assert win["unsent"] and win["sent"] + len(win["unsent"]) == len(
+        win["scheduled"])
+    attempted, n_late, failed = serve.open_loop_outcome(served, win, 2.0)
+    assert (attempted, n_late, failed) == (len(win["scheduled"]), attempted, 0)
 
 
 def test_an_idle_open_loop_wakes_when_its_caller_asks():
@@ -223,3 +257,40 @@ def test_the_cells_mixes_give_every_seed_the_same_schedule(mix):
     assert not np.array_equal(ra[0].prompt, rb[0].prompt)
     lens = [len(r.prompt) for r in ra]
     assert lens != sorted(lens)              # a mixed order, not a ramp
+
+
+CHAT_REQUESTS_A_RUN = 19      # PERF.md section 4: serve-chat-steady at 40 s
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_bursts_of_one_with_nothing_hot_share_no_document(seed):
+    """``chat-steady-unshared``: ``open_bursts`` as it is with
+    ``burst_sizes`` [1] and ``hot_burst_share`` 0: every request brings a
+    document of its own, none is the hot one, and the timeline is one for
+    every seed."""
+    t = _traffic("chat-steady-unshared")
+    assert t["burst_sizes"] == [1] and t["hot_burst_share"] == 0.0
+    # four fifths of the knee over the window, offered in its first three
+    # quarters: at the knee while requests arrive (the file's ``rate_note``)
+    assert t["bursts_per_s"] * t["arrival_span"] == pytest.approx(
+        0.8 * t["knee_bursts_per_s"])
+    seconds = float(manifest.load_manifest()["run_seconds"])
+    got = open_bursts.plan(t, seed, 32000, seconds)
+    ref = open_bursts.plan(t, 12345, 32000, seconds)
+    assert len(got) == CHAT_REQUESTS_A_RUN == open_bursts.describe(t, seconds)["requests"]
+    assert [(r.scheduled, len(r.prompt), r.max_new) for r in got] \
+        == [(r.scheduled, len(r.prompt), r.max_new) for r in ref]
+    docs = [r.prompt[:640].tobytes() for r in got]
+    assert len(set(docs)) == len(docs)                       # nothing shared
+    hot = {d.tobytes() for d in open_bursts.hot_documents(t, seed, 32000)}
+    assert len(hot) == 1 and not hot & set(docs)
+    assert {r.tag for r in got} == {"fresh"} and len({r.group for r in got}) == len(got)
+    # no two prompts share even their first block of 16 tokens
+    assert len({r.prompt[:16].tobytes() for r in got}) == len(got)
+    assert open_bursts.offered_prefix_share(t, seconds) == 0.0
+    assert all(32 <= len(r.prompt) - 640 <= 192 and 32 <= r.max_new <= 64 for r in got)
+    times = [r.scheduled for r in got]
+    assert times == sorted(times) and times[0] == 0.0 and times[-1] < 0.75 * seconds
+    # the check's sample is of this mix and of documents the window never sends
+    sample = open_bursts.sample(t, seed, 32000, 3)
+    assert len(sample) == 3 and not {r.prompt[:640].tobytes() for r in sample} & set(docs)

@@ -83,20 +83,113 @@ def test_a_missing_piece_names_the_file_it_looked_for():
         mf.cell(mf.load_manifest(), "no-such-cell")
 
 
-def test_configs_keep_every_published_width_and_list_what_they_cut(man):
-    published = {"hidden_size": 4096, "intermediate_size": 14336,
-                 "num_attention_heads": 32, "num_key_value_heads": 8,
-                 "head_dim": 128, "vocab_size": 32000, "rope_theta": 10000.0,
-                 "rms_norm_eps": 1e-05, "num_hidden_layers": 32,
-                 "sliding_window": 4096, "max_position_embeddings": 32768}
+TINY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
+                    "BENCHMARK.tiny.json")
+
+
+def _configs_with_a_published_file(manifest_path):
+    """``(entry, configuration, published, bench_dir)`` of every
+    configuration that names a published file: all of the real manifest's,
+    and of the fixtures' the second architecture's."""
+    with open(manifest_path) as f:
+        man = json.load(f)
+    here = os.path.dirname(manifest_path)
+    bench_dir = os.path.normpath(os.path.join(here, man.get("bench_dir", "benchmarks")))
     for c in man["configs"]:
-        with open(os.path.join(mf.ROOT, c["file"])) as f:
+        with open(os.path.join(here, c["file"])) as f:
             cfg = json.load(f)
-        changed = sorted(k for k, v in published.items() if cfg[k] != v)
-        assert changed == sorted(c["reduced"]) == sorted(cfg["reduced"]), c["name"]
-        assert not any(k.endswith(("_dim", "_rank", "_size")) for k in changed)
-        assert cfg["source"] == c["source"]
+        if "published" in cfg or manifest_path != TINY:
+            yield c, cfg, mf.published_of(cfg, bench_dir), bench_dir
+
+
+def _violations(cfg, pub):
+    """What a configuration breaks of the model-configs guide, section 4,
+    against its architecture's published file: ``reduced`` is exactly what
+    differs; only the depth, the count of routed experts and the vocabulary
+    may differ, down to a whole period of the layer pattern, 8 experts and
+    an eighth of the vocabulary; every other published value is kept
+    letter for letter."""
+    want, out = pub["config"], []
+    depth, experts = pub.get("depth_key", "num_hidden_layers"), pub.get("experts_key")
+    if any(k not in cfg for k in want):
+        return ["missing " + k for k in want if k not in cfg]
+    changed = sorted(k for k, v in want.items()
+                     if cfg[k] != v or type(cfg[k]) is not type(v))
+    if changed != sorted(cfg["reduced"]):
+        out.append("reduced")
+    if not set(changed) <= {depth, experts, "vocab_size"}:
+        out.append("width")
+    if not pub.get("layer_period", 1) <= cfg[depth] <= want[depth]:
+        out.append("depth")
+    if not want["vocab_size"] / 8 <= cfg["vocab_size"] <= want["vocab_size"]:
+        out.append("vocab")
+    if experts and not 8 <= cfg[experts] <= want[experts]:
+        out.append("experts")
+    return out
+
+
+@pytest.mark.parametrize("manifest_path", [
+    os.path.join(mf.ROOT, "BENCHMARK.json"), TINY], ids=["committed", "fixtures"])
+def test_configs_keep_every_published_width_and_list_what_they_cut(manifest_path):
+    """Each configuration against its *own* architecture's published file
+    (``benchmarks/published/<name>.json``), and its reference resolves."""
+    seen = 0
+    for c, cfg, pub, bench_dir in _configs_with_a_published_file(manifest_path):
+        seen += 1
+        assert _violations(cfg, pub) == [], c["name"]
+        assert sorted(c.get("reduced", cfg["reduced"])) == sorted(cfg["reduced"])
+        assert cfg["source"] == pub["source"] == c.get("source", pub["source"])
         assert all(v is not None for v in cfg["check"]["limits"].values())
+        arch = mf.reference_of(cfg, bench_dir).Arch.from_model(cfg)
+        assert arch.num_hidden_layers == cfg[pub.get("depth_key", "num_hidden_layers")]
+        assert arch.vocab_size == cfg["vocab_size"]
+        table = arch.leaf_table()
+        assert len({leaf.path for leaf in table}) == len(table)
+        assert len({leaf.published for leaf in table}) == len(table)
+    assert seen >= 2
+
+
+def test_a_configuration_that_cuts_a_width_or_hides_a_cut_is_refused():
+    """The rule above, shown to fail: on copies of the second
+    architecture's configuration with a width cut, a cut not listed, too
+    little vocabulary, less than a period of layers and a value missing."""
+    fx = os.path.dirname(TINY)
+    with open(os.path.join(fx, "configs", "tiny-alt-serve-c1.json")) as f:
+        good = json.load(f)
+    pub = mf.published_of(good, fx)
+    assert _violations(good, pub) == []
+    assert _violations(dict(good, ffn_hidden_size=128, reduced=good["reduced"]
+                            + ["ffn_hidden_size"]), pub) == ["width"]
+    assert _violations(dict(good, reduced=["num_hidden_layers"]), pub) == ["reduced"]
+    assert _violations(dict(good, vocab_size=256), pub) == ["vocab"]
+    assert _violations(dict(good, num_hidden_layers=0), pub) == ["depth"]
+    assert _violations(dict(good, rope_theta=10000), pub) == ["reduced", "width"]
+    short = {k: v for k, v in good.items() if k != "head_dim"}
+    assert _violations(short, pub) == ["missing head_dim"]
+    experts = dict(pub, experts_key="num_experts",
+                   config=dict(pub["config"], num_experts=64))
+    assert _violations(dict(good, num_experts=4, reduced=good["reduced"]
+                            + ["num_experts"]), experts) == ["experts"]
+    assert _violations(dict(good, num_experts=8, reduced=good["reduced"]
+                            + ["num_experts"]), experts) == []
+
+
+RUNNERS_AND_HARNESS = [os.path.join("runners", "serve.py"), os.path.join("runners", "train.py")] \
+    + [os.path.join("harness", f) for f in sorted(os.listdir(os.path.join(mf.BENCH_DIR, "harness")))
+       if f.endswith(".py")]
+
+
+@pytest.mark.parametrize("rel", RUNNERS_AND_HARNESS)
+def test_no_runner_or_harness_file_imports_an_architecture(rel):
+    """What belongs to one architecture is found by name: the runners and
+    the harness import no reference module and hold no leaf of one."""
+    with open(os.path.join(mf.BENCH_DIR, rel)) as f:
+        src = f.read()
+    code = "\n".join(line.split("#")[0] for line in src.splitlines())
+    code = re.sub(r'"""(?:.|\n)*?"""', "", code)          # docstrings are prose
+    assert "benchmarks.references" not in code and "references import" not in code
+    for word in ("mistral", "q_proj", "gate_proj", "attn.wq", "lm_head"):
+        assert word not in code, (rel, word)
 
 
 def test_cells_report_setup_one_more_metric_and_a_layer_metric(man):

@@ -373,3 +373,61 @@ def test_traced_rehearsal_reports_the_counter_metrics_only(cell):
     assert last["correct"] is True and last["device"]["platform"] == "cpu"
     assert set(last["metrics"]) == COUNTED[cell]
     assert all(v["value"] >= 0 for v in last["metrics"].values())
+
+
+# -- decode programs told by the module line ---------------------------------
+
+def test_decode_step_reader_tells_decode_programs_by_module_name(monkeypatch):
+    """Device-busy time inside the executions of the two decode programs,
+    per decode token step of the traced steps: a gather program, a pick
+    program between them and the idle inside a burst are not counted."""
+    mods = [("jit_dstpu_serve_gather(1)", 0.0, 0.40),
+            ("jit_dstpu_serve_multi_decode(2)", 0.5, 0.16),
+            ("jit_dstpu_pick_greedy(5)", 0.70, 0.01),
+            ("jit_dstpu_serve_decode(3)", 0.8, 0.02),
+            ("jit_dstpu_serve_multi_decode(2)", 1.95, 0.16)]   # leaves the window
+    ops = [ev("fusion.1", 0.0, 0.40),
+           ev("paged_decode.6", 0.5, 0.07),
+           ev("fusion.2", 0.58, 0.08),                # 0.01 idle inside the burst
+           ev("fusion.3", 0.70, 0.01), ev("fusion.4", 0.8, 0.02)]
+    pt = program_trace(ops=ops, modules=mods, spans=[], t0=0.0, t1=2.0)
+    monkeypatch.setattr(P, "open_run", lambda ctx, result: pt)
+    served = type("S", (), {"steps": [
+        {"decode_kernel_steps": 0}, {"decode_kernel_steps": 8},
+        {"decode_kernel_steps": 1}, {"decode_kernel_steps": 8}]})()
+    result = {"facts": {"traced_steps": (0, 3)}, "served": served}
+    reader = mf.load_module("layer_metrics", "decode_step_ms")
+    assert reader.read(Ctx("serve"), result) == pytest.approx(
+        1e3 * (0.07 + 0.08 + 0.02) / 9)
+    result["facts"]["traced_steps"] = (0, 1)          # no decode step traced
+    assert reader.read(Ctx("serve"), result) is None
+
+
+def test_where_the_close_fell_names_the_straddling_step_and_a_stall():
+    from benchmarks.runners import serve
+
+    steps = [(0.00, 0.39, 32), (0.40, 0.12, 192), (0.53, 0.12, 192),
+             (1.66, 0.39, 32)]                   # 1.0 s lost before the last
+    note = serve.where_the_close_fell(steps, 0.0, 1.79)
+    assert note["straddling_step_tokens"] == 32
+    assert note["outside_serve_step_s"] == pytest.approx(1.79 - 0.39 - 0.24 - 0.13)
+    assert serve.where_the_close_fell(steps, 0.0, 1.0) == {
+        "straddling_step_tokens": 0,
+        "outside_serve_step_s": pytest.approx(1.0 - 0.39 - 0.24)}
+
+
+def test_window_steps_names_a_stalled_step_and_splits_the_window_by_kind():
+    """``benchmarks/tools/window_steps.py``: the diagnostics that do not
+    belong in every run's note."""
+    tool = mf.load_module("tools", "window_steps")
+    cycle = [(0.0, 0.39, 32), (0.39, 0.12, 192), (0.51, 0.12, 192)]
+    steps = [(s + 0.63 * k, d, n) for k in range(5) for s, d, n in cycle]
+    steps.append((3.15, 8.32, 32))          # a gather step that took 8.32 s
+    steps.append((11.47, 0.12, 192))
+    r = tool.report(steps, 0.0, 12.0)
+    assert r["slow_steps"] == [[3.15, 8.32, 32]]
+    assert r["steps_by_tokens"]["32"] == [6, pytest.approx(5 * 0.39 + 8.32)]
+    assert r["steps_by_tokens"]["192"][0] == 11
+    assert r["last_steps"][-1] == [11.47, 0.12, 192] and len(r["last_steps"]) == 5
+    assert r["outside_serve_step_s"] == pytest.approx(12.0 - 11.59)
+    assert r["serve_tokens_per_s"] != r["one_close_tokens_per_s"]

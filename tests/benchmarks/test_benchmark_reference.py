@@ -47,6 +47,65 @@ def test_one_layer_alone_equals_its_slice_of_the_stacked_tree(seed):
     assert jax.tree.map(lambda a: a.shape, want) == jax.tree.map(lambda a: a.shape, tree)
 
 
+# first three values and the last of six leaves, seed 5, at the committed
+# configurations' widths, as ``harness/weights.py`` drew them before the
+# table of leaves moved into the reference module (PR 27): the weights of
+# every cell stay bit for bit what they were
+PINNED = {
+    ("attn.wq", 0): [0.005016559734940529, 0.02217281050980091,
+                     0.009176946245133877, 0.009444541297852993],
+    ("mlp.wo", 1): [0.007860644720494747, -0.014517283998429775,
+                    -0.005434690974652767, -0.010122771374881268],
+    ("ln2.scale", 15): [1.0174803733825684, 1.0183157920837402,
+                        1.0977587699890137, 1.1563602685928345],
+    ("embed.tokens", None): [-0.005918500944972038, -0.00982873048633337,
+                             0.03801785781979561, 0.007151418831199408],
+    ("unembed.kernel", None): [-0.009967577643692493, 0.012947824783623219,
+                               0.022397883236408234, 0.009254544973373413],
+    ("final_norm.scale", None): [0.854008138179779, 1.0801080465316772,
+                                 1.020498514175415, 1.0363866090774536],
+}
+
+
+@pytest.mark.parametrize("path,layer", sorted(PINNED, key=str))
+def test_weights_of_the_committed_configurations_are_what_they_were(path, layer):
+    import json
+    import os
+
+    from benchmarks.harness import manifest as mf
+
+    with open(os.path.join(mf.BENCH_DIR, "configs", "mistral-7b-serve-c1.json")) as f:
+        cfg = json.load(f)
+    arch = mf.reference_of(cfg).Arch.from_model(cfg)
+    leaf = next(x for x in arch.leaf_table() if x.path == path)
+    got = np.asarray(weights.draw_leaf(leaf, weights.base_key(5), layer,
+                                       jnp.float32)).ravel()
+    assert [float(v) for v in got[:3]] + [float(got[-1])] == PINNED[path, layer]
+    if path == "attn.wq":        # and in the type they are served in
+        low = np.asarray(weights.draw_leaf(leaf, weights.base_key(5), 0, jnp.bfloat16)
+                         .astype(jnp.float32)).ravel()
+        assert [float(v) for v in low[:3]] == [0.0050048828125, 0.022216796875,
+                                               0.0091552734375]
+
+
+def test_the_three_committed_configurations_share_one_table_of_leaves():
+    import json
+    import os
+
+    from benchmarks.harness import manifest as mf
+
+    tables = set()
+    for c in mf.load_manifest()["configs"]:
+        with open(os.path.join(mf.ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        arch = mf.reference_of(cfg).Arch.from_model(cfg)
+        tables.add(arch.leaf_table())
+        assert [x.path for x in arch.leaf_table() if x.per_layer] == [
+            "attn.wq", "attn.wk", "attn.wv", "attn.wo", "mlp.wg", "mlp.wi",
+            "mlp.wo", "ln1.scale", "ln2.scale"]
+    assert len(tables) == 1          # depth is not in the table
+
+
 def test_weights_differ_by_seed_and_serve_dtype_rounds_the_same_draws():
     a = weights.reference_layer_fn(ARCH, 1, jnp.float32)(0)["q_proj"]
     b = weights.reference_layer_fn(ARCH, 2, jnp.float32)(0)["q_proj"]
@@ -90,7 +149,7 @@ def test_loss_and_sampled_gradients_match_the_program(tokens):
     for name in ("layers.1.q_proj", "layers.2.down_proj", "layers.0.input_layernorm",
                  "norm", "lm_head", "embed_tokens", "layers.1.k_proj",
                  "layers.0.gate_proj"):
-        path = weights.program_leaf_name(name).split(".")
+        path = weights.program_leaf_name(ARCH, name).split(".")
         leaf = want
         for p in path:
             leaf = leaf[p]

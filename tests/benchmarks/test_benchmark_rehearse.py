@@ -29,7 +29,12 @@ def _run(workload, trace, extra=(), manifest=TINY, seconds="2"):
 CELLS = {"tiny-train": {"train_tokens_per_s_chip"},
          "tiny-gen": {"serve_tokens_per_s", "tpot_p90_ms"},
          "tiny-burst": {"ttft_p50_ms"},
-         "tiny-train-fsdp4": {"train_tokens_per_s_chip"}}
+         "tiny-train-fsdp4": {"train_tokens_per_s_chip"},
+         # the second architecture (fixtures/references/tiny_alt.py): other
+         # leaves, another block, its own published file — through the same
+         # runners, judged against its own reference
+         "tiny-alt-train": {"train_tokens_per_s_chip"},
+         "tiny-alt-gen": {"serve_tokens_per_s", "tpot_p90_ms"}}
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
@@ -47,6 +52,37 @@ def test_cell_rehearses_end_to_end_on_the_cpu(cell):
     assert "busy_s" not in last["device"]
     compared = [json.loads(l)["compared"] for l in lines if '"compared"' in l]
     assert len(compared) >= 2 and all("limit" in c and "value" in c for c in compared)
+
+
+def test_the_second_architecture_is_judged_against_its_own_reference(tmp_path):
+    """Its own reference refuses what a broken block would produce: the
+    same cell with the parallel residual left out of the *program* (the
+    configuration's ``preset_overrides``) comes out not ``correct``, on the
+    logits."""
+    fx = os.path.join(HERE, "fixtures")
+    with open(os.path.join(fx, "configs", "tiny-alt-serve-c1.json")) as f:
+        cfg = json.load(f)
+    broken = dict(cfg, name="tiny-alt-broken", preset_overrides=dict(
+        cfg["preset_overrides"], parallel_block=False))
+    (tmp_path / "broken.json").write_text(json.dumps(broken))
+    with open(TINY) as f:
+        man = json.load(f)
+    man["bench_dir"] = fx
+    man["configs"] = [{"name": "tiny-alt-broken", "file": "broken.json"}]
+    man["workloads"] = [{"name": "tiny-alt-broken-gen", "config": "tiny-alt-broken",
+                         "traffic": "tiny-gen-closed", "chips": 1}]
+    for m in man["end_to_end"]:
+        if "tiny-alt-gen" in m.get("workloads", []):
+            m["workloads"] = ["tiny-alt-broken-gen"]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
+    out = _run("tiny-alt-broken-gen", 0, ["--rehearse"],
+               manifest=str(tmp_path / "BENCHMARK.json"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["correct"] is False
+    bad = [json.loads(l)["compared"] for l in out.stdout.splitlines()
+           if '"compared"' in l and '"ok": false' in l]
+    assert any(c["check"].startswith("serve.logits") for c in bad), bad
 
 
 @pytest.mark.parametrize("cell", ["tiny-train", "tiny-gen", "tiny-burst"])
@@ -97,13 +133,13 @@ sys.path.insert(0, sys.argv[1])
 from benchmarks.harness import compare, manifest as mf
 man, bench_dir, cell, cfg, traffic = mf.resolve(sys.argv[2], "tiny-train-fsdp4")
 mf.program_logs_to_stderr()
-from benchmarks.references import mistral
+ref_mod = mf.reference_of(cfg, bench_dir)
 runner = mf.load_module("runners", "train", bench_dir)
 gen = mf.load_module("generators", traffic["generator"], bench_dir)
-arch, seed, chips = mistral.Arch.from_model(cfg), 2**31 + 17, cell["chips"]
+arch, seed, chips = ref_mod.Arch.from_model(cfg), 2**31 + 17, cell["chips"]
 batch0, distinct = gen.check_batch(traffic, seed, arch.vocab_size, chips,
                                    cfg["seq_len"], cfg["check"]["sample_sequences"])
-ref = runner.reference_numbers(arch, cfg, distinct, seed)
+ref = runner.reference_numbers(ref_mod, arch, cfg, distinct, seed)
 other = batch0.copy()
 other[3] = np.random.default_rng(1).integers(0, arch.vocab_size, other[3].shape)
 fed = {"sound": batch0,
@@ -113,7 +149,7 @@ for name, batch in fed.items():
     _, engine = runner.build_engine(cfg, arch, seed, chips)
     loss0 = float(engine.train_batch(iter([{"input_ids": batch}])))
     engine.synchronize()
-    rows = runner.engine_gradient_rows(engine, ref["plan"])
+    rows = runner.engine_gradient_rows(engine, arch, ref["plan"])
     verdict = compare.Verdict()
     numbers = runner.compare_to_reference(verdict, ref, loss0, rows.pop("_norm"),
                                           rows, cfg["check"]["limits"])

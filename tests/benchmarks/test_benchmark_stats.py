@@ -37,9 +37,16 @@ def test_first_delivery_gives_no_gap():
     assert stats.token_gaps([(1.0, 1, 3)], 0.0, 2.0) == []
 
 
-def test_tokens_in_window_is_half_open():
-    log = [(0.0, 1, 2), (1.0, 1, 3), (2.0, 1, 5)]
-    assert stats.tokens_in_window(log, 0.0, 2.0) == 5
+def test_the_closed_loop_rate_is_the_mean_over_the_windows_last_tenth():
+    """One form for every cell that reports it: no mix chooses the share."""
+    line = [(0.63 * k + s, d, n) for k in range(70)
+            for s, d, n in [(0.0, 0.39, 32), (0.39, 0.12, 192), (0.51, 0.12, 192)]]
+    assert stats.RATE_OVER_LAST == 0.1
+    assert stats.closed_loop_rate(line, 0.0, 40.0) == pytest.approx(
+        stats.mean_rate_over_closes(line, 0.0, 36.0, 40.0))
+    assert stats.closed_loop_rate(line, 2.0, 42.0) == pytest.approx(
+        stats.mean_rate_over_closes(line, 2.0, 38.0, 42.0))
+    assert stats.closed_loop_rate([], 0.0, 40.0) == 0.0
 
 
 def test_ttft_counts_from_the_scheduled_arrival():
@@ -80,3 +87,76 @@ def test_spread_is_iqr_over_median_by_statistics_quantiles():
     q = statistics.quantiles(xs, n=4)
     assert stats.spread(xs) == pytest.approx((q[2] - q[0]) / 102.5)
     assert math.isclose(stats.spread([5.0] * 6), 0.0)
+
+
+# a closed loop's steps as (start, seconds, tokens): gather steps that hand
+# out 32 tokens in 0.39 s between decode bursts that hand out 192 in 0.12 s
+TIMELINE = [(0.00, 0.39, 32), (0.40, 0.12, 192), (0.53, 0.12, 192),
+            (0.66, 0.39, 32), (1.06, 0.12, 192)]
+
+
+def test_the_step_that_straddles_the_close_counts_for_its_share_inside():
+    whole = 32 + 192 + 192
+    assert stats.tokens_prorated(TIMELINE, 0.0, 0.655) == pytest.approx(whole)
+    # the close in the middle of the second burst: half of its tokens
+    assert stats.tokens_prorated(TIMELINE, 0.0, 0.59) == pytest.approx(
+        32 + 192 + 96)
+    # between two steps nothing is added; past the last, everything
+    assert stats.tokens_prorated(TIMELINE, 0.0, 0.652) == pytest.approx(whole)
+    assert stats.tokens_prorated(TIMELINE, 0.0, 9.0) == 32 + 192 * 3 + 32
+    assert stats.tokens_prorated(TIMELINE, 0.0, 0.0) == 0.0
+
+
+@pytest.mark.parametrize("end,tokens,seconds", [
+    (0.65, 192, 0.12),          # the end of a decode burst
+    (1.05, 32, 0.39),           # the end of a gather step
+    (0.52, 192, 0.12)])
+def test_moving_the_close_by_a_millisecond_moves_the_count_by_a_milliseconds_work(
+        end, tokens, seconds):
+    """Across a step's end the count moves by that step's tokens x 1 ms /
+    its duration, not by the step: the rate is continuous in the close.
+    Whole deliveries jump by the burst."""
+    before = stats.tokens_prorated(TIMELINE, 0.0, end - 0.001)
+    after = stats.tokens_prorated(TIMELINE, 0.0, end)
+    assert after - before == pytest.approx(tokens * 0.001 / seconds)
+    assert stats.tokens_prorated(TIMELINE, 0.0, end + 0.001) == pytest.approx(after)
+    whole = lambda t1: sum(n for s, d, n in TIMELINE if s + d < t1)  # noqa: E731
+    assert whole(end + 1e-9) - whole(end - 0.001) == tokens
+
+
+def test_prorating_holds_at_the_open_too_and_skips_empty_steps():
+    assert stats.tokens_prorated(TIMELINE, 0.195, 0.40) == pytest.approx(16)
+    assert stats.step_share_inside(0.4, 0.12, 0.0, 0.46) == pytest.approx(0.5)
+    assert stats.step_share_inside(2.0, 0.1, 0.0, 1.0) == 0.0
+    assert stats.step_share_inside(0.5, 0.0, 0.0, 1.0) == 1.0   # no duration
+    assert stats.tokens_prorated([(0.9, 0.2, 0)], 0.0, 1.0) == 0.0
+
+
+def test_the_mean_over_closes_of_a_steady_timeline_is_its_rate():
+    steady = [(0.1 * i, 0.1, 50) for i in range(400)]         # 500 tokens/s
+    assert stats.mean_rate_over_closes(steady, 0.0, 36.0, 40.0) == pytest.approx(500.0)
+    for c_lo in (0.0, 40.0):            # from the open itself; one close alone
+        with pytest.raises(ValueError):
+            stats.mean_rate_over_closes(steady, 0.0, c_lo, 40.0)
+
+
+def test_the_mean_over_closes_is_the_integral_of_the_prorated_rate():
+    closes = [0.9 + 0.0001 * i for i in range(2001)]           # 0.9 .. 1.1
+    by_hand = sum(stats.tokens_prorated(TIMELINE, 0.0, c) / c for c in closes) / len(closes)
+    assert stats.mean_rate_over_closes(TIMELINE, 0.0, 0.9, 1.1) == pytest.approx(by_hand, rel=1e-4)
+
+
+def test_a_lag_reads_once_in_the_mean_over_closes_and_threefold_at_one_close():
+    """Gather steps of 0.39 s (32 tokens) and bursts of 0.12 s (192): the
+    same timeline 0.05 s late. One close inside a burst loses the burst's
+    rate x the lag; the mean over 4 s of closes loses the mean rate x the lag."""
+    cycle = [(0.0, 0.39, 32), (0.39, 0.12, 192), (0.51, 0.12, 192)]      # 0.63 s, 416 tokens
+    line = [(s + 0.63 * k, d, n) for k in range(70) for s, d, n in cycle]
+    late = [(s + 0.05, d, n) for s, d, n in line]
+    mean_rate = 416 / 0.63
+    close = 63 * 0.63 + 0.45                                      # inside a burst
+    one = (stats.tokens_prorated(line, 0.0, close) - stats.tokens_prorated(late, 0.0, close))
+    assert one == pytest.approx(0.05 * 192 / 0.12)                # 80 tokens: 2.4 x the mean
+    many = (stats.mean_rate_over_closes(line, 0.0, close - 4.0, close)
+            - stats.mean_rate_over_closes(late, 0.0, close - 4.0, close)) * (close - 2.0)
+    assert many == pytest.approx(0.05 * mean_rate, rel=0.1)       # 33 tokens
