@@ -79,6 +79,11 @@ def _shared_step_fns(cfg, kernel_mesh):
     hit = _JIT_CACHE.get(key)
     if hit is not None and hit[0] is cfg:
         return hit[1]
+    # a model with recurrent layers has its own four programs under the
+    # same names and leading arguments (inference/hybrid_runner.py)
+    runner = model_runner
+    if getattr(cfg, "recurrent_layers", 0):
+        from deepspeed_tpu.inference import hybrid_runner as runner
     # each program under a stable name: the device trace's module line
     # says jit_dstpu_serve_gather, ... ("step" is the gather program).
     # Every program donates the KV pool (argument 1): the pool is one
@@ -88,14 +93,14 @@ def _shared_step_fns(cfg, kernel_mesh):
         return jax.jit(named(fn, name), donate_argnums=(1,), **kw)
 
     fns = {
-        "step": program(partial(model_runner.ragged_forward, cfg),
+        "step": program(partial(runner.ragged_forward, cfg),
                         "dstpu_serve_gather"),
-        "decode": program(partial(model_runner.ragged_decode_forward, cfg,
+        "decode": program(partial(runner.ragged_decode_forward, cfg,
                                   mesh=kernel_mesh), "dstpu_serve_decode"),
-        "prefill": program(partial(model_runner.ragged_prefill_forward, cfg,
+        "prefill": program(partial(runner.ragged_prefill_forward, cfg,
                                    mesh=kernel_mesh), "dstpu_serve_prefill"),
         "multi_decode": program(
-            partial(model_runner.ragged_multi_decode, cfg, mesh=kernel_mesh),
+            partial(runner.ragged_multi_decode, cfg, mesh=kernel_mesh),
             "dstpu_serve_multi_decode", static_argnames=("steps",)),
     }
     _JIT_CACHE[key] = (cfg, fns)
@@ -149,6 +154,7 @@ class InferenceEngineV2:
                  spec_adaptive_k: bool = False,
                  spec_accept_alpha: float = 0.25,
                  serving: Optional[Any] = None,
+                 state_slots: Optional[int] = None,
                  request_trace: Optional[Any] = None,
                  metric_labels: Optional[Dict[str, str]] = None):
         from deepspeed_tpu.inference.engine import InferenceEngine
@@ -177,13 +183,29 @@ class InferenceEngineV2:
                                    quantize_weights=quantize_weights)
         self.model, self.cfg = model, model.config
         self.mesh, self.params = self._v1.mesh, self._v1.params
+        # a model with recurrent layers (models/hybrid.py) keeps a second,
+        # slot-addressed kind of per-sequence state beside the KV blocks
+        # (ragged/state_pool.py). It has no snapshot yet, so whatever would
+        # copy or roll back a sequence's state is switched off (the prefix
+        # cache: a skipped prefix without the state at its end is a wrong
+        # answer) or refused by name (host tier, migration, hand-off,
+        # speculation): _refuse_without_snapshot
+        self._recurrent = bool(getattr(self.cfg, "recurrent_layers", 0))
+        # smallest chunk bucket of the prefill program (powers of two from
+        # here: _plan_prefill_segments)
+        self._min_segment = 8
+        if self._recurrent:
+            self._init_recurrent(kv_quant_bits, host_kv_tier,
+                                 spec_decode or drafter is not None)
+            prefix_cache = False
         # kept for reload_params: a hot-swap routes replacement weights
         # through the same v1 placement/quantization path as boot
         self._param_dtype = dtype
         self._quantize_weights = quantize_weights
 
         kv_cfg = KVCacheConfig(
-            num_layers=self.cfg.num_layers, kv_heads=self.cfg.kv_heads,
+            num_layers=getattr(self.cfg, "kv_layers", self.cfg.num_layers),
+            kv_heads=self.cfg.kv_heads,
             head_dim=self.cfg.head_dim, block_size=kv_block_size,
             num_blocks=kv_blocks, dtype=dtype, quant_bits=kv_quant_bits)
         self.kv_cache = BlockedKVCache(kv_cfg, mesh=self.mesh)
@@ -197,6 +219,18 @@ class InferenceEngineV2:
 
         self.kv_cache.allocator = BlockedAllocator(kv_blocks - 1)
         self._scratch_block = kv_blocks - 1
+        if self._recurrent:
+            from deepspeed_tpu.inference.ragged import (RecurrentStatePool,
+                                                        StatePoolConfig)
+
+            c = self.cfg
+            self.kv_cache.state_pool = RecurrentStatePool(StatePoolConfig(
+                layers=c.recurrent_layers,
+                slots=int(state_slots or max_seqs_per_step),
+                heads=c.linear_num_value_heads, key_dim=c.linear_key_head_dim,
+                value_dim=c.linear_value_head_dim,
+                conv_taps=c.linear_conv_kernel_dim,
+                conv_channels=c.conv_channels, dtype=dtype))
         # shared-prefix KV reuse: full blocks whose content-hash chain
         # matches a cached prefix are shared by reference and skip
         # prefill (ragged/prefix_cache.py; docs/serving.md)
@@ -274,6 +308,18 @@ class InferenceEngineV2:
                       "tokens_decode": 0, "tokens_multi_decode": 0,
                       "prefill_chunks": 0, "admission_wait_s": 0.0,
                       "ttft_s": 0.0, "first_tokens": 0}
+        if self._recurrent:
+            # the expert layers' routing, counted on the device by every
+            # step program and fetched with the step's tokens: tokens x
+            # expert layers, (token, expert) pairs routed to experts held
+            # here, held experts that got a row (summed over calls; the
+            # ``_decode`` pair counts the two decode programs alone); and
+            # the state pool's occupancy
+            pool = self.kv_cache.state_pool
+            self.stats.update(moe_token_layers=0, moe_local_pairs=0,
+                              moe_experts_hit=0, moe_local_pairs_decode=0,
+                              moe_experts_hit_decode=0, state_slots_in_use=0,
+                              state_slots=pool.total_slots)
         # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
         # span, of the spans nested in it, and of the request tracer's
         # PREFILL / DECODE_EMIT spans of that step
@@ -395,10 +441,12 @@ class InferenceEngineV2:
         over-admitting past the slots would silently degrade them to
         per-token steps for zero scheduling benefit."""
         blocks = self.kv_cache.blocks_needed(prompt_len + 1)
+        pool = self.kv_cache.state_pool
         if (blocks > self.max_blocks_per_seq
                 or len(self.state.seqs) >= self.max_seqs
                 or len(self.state.seqs)
-                >= self.state.max_tracked_sequences):
+                >= self.state.max_tracked_sequences
+                or (pool is not None and pool.free_slots == 0)):
             return False
         committed = 0
         for s in self.state.seqs.values():
@@ -684,6 +732,7 @@ class InferenceEngineV2:
         admission queue flagged ``paged`` and warm-resumes when capacity
         allows. False when paging doesn't apply — the sequence stays
         live."""
+        self._refuse_without_snapshot("host-tier parking (page_out)")
         seq = self.state.seqs.get(uid)
         if seq is None or seq.done:
             return False
@@ -703,6 +752,8 @@ class InferenceEngineV2:
         from host memory. Returns None when there is nothing warm to
         capture (unknown uid, mid-prefill, queued-but-never-admitted):
         the caller degrades to the legacy fold-and-resubmit path."""
+        self._refuse_without_snapshot("session migration "
+                                      "(migrate_out_session)")
         tier = getattr(self.kv_cache, "host_tier", None)
         seq = self.state.seqs.get(uid)
         if seq is None or seq.done:
@@ -769,6 +820,8 @@ class InferenceEngineV2:
           engine (per-seq cap): counted and closed, mirroring
           ``_requeue``'s cap-truncation contract.
         """
+        self._refuse_without_snapshot("session migration "
+                                      "(install_migrated_session)")
         uid = int(sess.uid)
         if uid in self.state.seqs or any(r.uid == uid for r in self._queue):
             return "duplicate"
@@ -870,6 +923,80 @@ class InferenceEngineV2:
             dtype=self._param_dtype,
             quantize_weights=self._quantize_weights)
         self.params = self._v1.params
+        if self._recurrent:
+            self._keep_serving_params()
+
+    def _init_recurrent(self, kv_quant_bits, host_kv_tier, speculate) -> None:
+        """Construction-time part of serving a model with recurrent layers:
+        refuse what needs a state snapshot, say once what was switched off,
+        and keep of the stacked tree only what the programs read."""
+        if kv_quant_bits is not None:
+            raise ValueError(
+                "a quantized KV pool is not wired into the hybrid step "
+                "programs (inference/hybrid_runner.py): serve with "
+                "kv_quant_bits=None")
+        if host_kv_tier:
+            self._refuse_without_snapshot("the host KV tier (host_kv_tier)")
+        if speculate:
+            self._refuse_without_snapshot("speculative decoding "
+                                          "(spec_decode / drafter)")
+        log_dist(
+            "InferenceEngineV2: the model has recurrent layers, whose "
+            "per-sequence state has no snapshot yet: prefix cache OFF (no "
+            "hit is taken); host-tier parking, session migration, the "
+            "disagg hand-off and speculative decoding are refused "
+            "(StateSnapshotUnsupported)", ranks=[0])
+        # the chunked recurrence pads every row of a segment batch to a
+        # whole chunk, so a smaller bucket would compile one more prefill
+        # program for the same work
+        from deepspeed_tpu.ops.pallas.gated_delta import CHUNK
+
+        self._min_segment = CHUNK
+        self._keep_serving_params()
+
+    def _keep_serving_params(self) -> None:
+        from deepspeed_tpu.models.hybrid import serving_params
+
+        self.params = serving_params(self.cfg, self.params)
+        self._v1.params = self.params     # drop the stacked tree's last ref
+
+    def _refuse_without_snapshot(self, what: str) -> None:
+        """Raise the named error for an operation that would copy or roll
+        back a sequence's recurrent state (no-op for other models)."""
+        if self._recurrent:
+            from deepspeed_tpu.inference.ragged import \
+                StateSnapshotUnsupported
+
+            raise StateSnapshotUnsupported(
+                f"{what} needs a snapshot of each sequence's recurrent "
+                "state (ragged/state_pool.py), which does not exist yet: "
+                "not available for a model with recurrent layers")
+
+    def _state_slots_arg(self, seqs) -> Tuple:
+        """The step programs' trailing argument for a model with recurrent
+        layers: each batch slot's state-pool slot (scratch where empty)."""
+        pool = self.kv_cache.state_pool
+        if pool is None:
+            return ()
+        slots = np.full(self.max_seqs, pool.scratch_slot, np.int32)
+        for i, s in enumerate(seqs):
+            slots[i] = s.state_slot
+        return (jnp.asarray(slots),)
+
+    def _fetch_counters(self, decode: bool) -> None:
+        """Add what the step program counted to ``stats`` (inside the
+        ``fetch`` span, after the step's tokens: the program is done)."""
+        pool = self.kv_cache.state_pool
+        if pool is None:
+            return
+        tokens, pairs, hit = (int(v) for v in np.asarray(pool.counters))
+        self.stats["moe_token_layers"] += tokens
+        self.stats["moe_local_pairs"] += pairs
+        self.stats["moe_experts_hit"] += hit
+        if decode:
+            self.stats["moe_local_pairs_decode"] += pairs
+            self.stats["moe_experts_hit_decode"] += hit
+        self.stats["state_slots_in_use"] = pool.slots_in_use
 
     def holds_prefix_blocks(self, tokens) -> int:
         """How many full prefix blocks of ``tokens`` this engine can
@@ -998,6 +1125,10 @@ class InferenceEngineV2:
                     toks_np = np.asarray(self._pick_greedy(logits, idx_dev))
                 else:
                     rows_np = np.asarray(self._take_rows(logits, idx_dev))
+                self._fetch_counters(program == "decode")
+        elif self._recurrent:
+            with span("fetch"):       # no token to read: the counters alone
+                self._fetch_counters(program == "decode")
         with span("bookkeep"):
             for slot, seq in consumers:
                 if temperature == 0.0:
@@ -1046,6 +1177,7 @@ class InferenceEngineV2:
         else the flat ``gather`` program."""
         batch = build_ragged_batch(scheduled, self.max_tokens,
                                    self.max_seqs, self.max_blocks_per_seq)
+        slots_arg = self._state_slots_arg([seq for seq, _, _ in scheduled])
         decode_only = (self._use_paged_kernel
                        and all(len(nt) == 1 for _, nt, _ in scheduled))
         seg_plan = None
@@ -1080,7 +1212,8 @@ class InferenceEngineV2:
         if seg_plan is not None:
             n_segs = seg_plan[0].shape[0]
             return self._prefill_fn, "prefill", (
-                *seg_plan, jnp.asarray(batch.block_table[:n_segs])), batch
+                *seg_plan, jnp.asarray(batch.block_table[:n_segs]),
+                *slots_arg), batch
         if decode_only:
             # compact per-slot arrays: token i belongs to slot i; pad
             # out to max_seqs (token budget may be smaller than the
@@ -1093,11 +1226,11 @@ class InferenceEngineV2:
             return self._decode_fn, "decode", (
                 jnp.asarray(d_tok), jnp.asarray(d_pos),
                 jnp.asarray(batch.block_table),
-                jnp.asarray(batch.ctx_lens)), batch
+                jnp.asarray(batch.ctx_lens), *slots_arg), batch
         return self._step_fn, "gather", (
             jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
             jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
-            jnp.asarray(batch.num_tokens, jnp.int32)), batch
+            jnp.asarray(batch.num_tokens, jnp.int32), *slots_arg), batch
 
     def _plan_prefill_segments(self, scheduled):
         """Per-slot padded chunk layout for the Pallas prefill kernel, or
@@ -1105,7 +1238,7 @@ class InferenceEngineV2:
         (then the gather path runs). Tq is bucketed to powers of two so
         jit compiles a handful of programs."""
         longest = max(len(nt) for _, nt, _ in scheduled)
-        tq = 8
+        tq = self._min_segment
         while tq < longest:
             tq *= 2
         # kernel scratch is (Tq*num_heads) rows of (2*128 + head_dim) fp32
@@ -1227,7 +1360,8 @@ class InferenceEngineV2:
                     ctx[i] = s.seen_tokens + 1
                     bt[i, :len(s.kv_blocks)] = s.kv_blocks
                 args = (jnp.asarray(d_tok), jnp.asarray(d_pos),
-                        jnp.asarray(bt), jnp.asarray(ctx))
+                        jnp.asarray(bt), jnp.asarray(ctx),
+                        *self._state_slots_arg(live))
             with span("dispatch", program="multi_decode", seqs=len(live),
                       tokens=K * len(live)):
                 toks, new_kv = self._multi_decode_fn(
@@ -1236,6 +1370,7 @@ class InferenceEngineV2:
             self.kv_cache.set_kv_state(new_kv)
             with span("fetch"):
                 toks_np = np.asarray(toks)  # [K, S]: one fetch per K tokens
+                self._fetch_counters(True)
         with span("bookkeep"):
             self.stats["decode_kernel_steps"] += K
             self.stats["burst_steps"] = self.stats.get("burst_steps", 0) + 1

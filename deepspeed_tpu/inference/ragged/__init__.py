@@ -7,6 +7,8 @@ from deepspeed_tpu.inference.ragged.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.ragged.sequence import (
     SequenceDescriptor, StateManager)
 from deepspeed_tpu.inference.ragged.ragged_batch import RaggedBatch
+from deepspeed_tpu.inference.ragged.state_pool import (
+    RecurrentStatePool, StatePoolConfig, StateSnapshotUnsupported)
 
 __all__ = [
     "BlockedAllocator",
@@ -18,4 +20,7 @@ __all__ = [
     "SequenceDescriptor",
     "StateManager",
     "RaggedBatch",
+    "RecurrentStatePool",
+    "StatePoolConfig",
+    "StateSnapshotUnsupported",
 ]

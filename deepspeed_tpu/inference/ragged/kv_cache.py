@@ -88,6 +88,10 @@ class BlockedKVCache:
         self.allocator = BlockedAllocator(config.num_blocks)
         self.prefix_cache = None  # Optional[PrefixCache], attached by owner
         self.host_tier = None     # Optional[HostKVTier], attached by owner
+        # Optional[RecurrentStatePool] (ragged/state_pool.py), attached by
+        # the owner for a model with recurrent layers: slot-addressed state
+        # beside the blocks, handed to the step programs in one pytree
+        self.state_pool = None
         shape = (config.num_layers, config.num_blocks, config.block_size,
                  2, config.kv_heads, config.payload_width)
         quantized = config.quant_bits is not None
@@ -123,7 +127,13 @@ class BlockedKVCache:
         """Device pool as the pytree the ragged forwards consume: the bare
         bf16 array when unquantized (today's program, verbatim), or a
         (payload, fp32 scales) pair when ``quant_bits`` is set (int8
-        payload, or packed-nibble uint8 for 4-bit storage)."""
+        payload, or packed-nibble uint8 for 4-bit storage). With a
+        recurrent-state pool attached: the dict of both pools
+        (``inference/hybrid_runner.py``)."""
+        if self.state_pool is not None:
+            sp = self.state_pool
+            return {"kv": self.data, "state": sp.state, "conv": sp.conv,
+                    "counters": sp.counters}
         if self.scales is None:
             return self.data
         return (self.data, self.scales)
@@ -133,7 +143,11 @@ class BlockedKVCache:
         :attr:`kv_state`). The step programs donate the pool they are
         handed, so this is the only live handle afterwards: read
         ``data`` / ``kv_state`` afresh, never keep one across a step."""
-        if self.scales is None:
+        if self.state_pool is not None:
+            sp = self.state_pool
+            self.data, sp.state, sp.conv, sp.counters = (
+                state["kv"], state["state"], state["conv"], state["counters"])
+        elif self.scales is None:
             self.data = state
         else:
             self.data, self.scales = state

@@ -42,6 +42,9 @@ class SequenceDescriptor:
     # this sequence's KV from the host tier — blocks paged in instead
     # of prefilled (the scheduler reports resumed decode separately)
     resumed_from_tier: int = 0
+    # slot of the recurrent-state pool (ragged/state_pool.py) for a model
+    # with recurrent layers; -1: the model has none
+    state_slot: int = -1
 
     @property
     def total_tokens(self) -> int:
@@ -84,6 +87,10 @@ class StateManager:
         seq = SequenceDescriptor(uid=uid,
                                  input_tokens=np.asarray(tokens, np.int32),
                                  max_new_tokens=max_new_tokens)
+        pool = getattr(self.kv_cache, "state_pool", None)
+        if pool is not None:
+            # a zeroed slot for the sequence's whole life
+            seq.state_slot = pool.allocate()
         self.seqs[uid] = seq
         return seq
 
@@ -221,6 +228,9 @@ class StateManager:
         if len(seq.kv_blocks) > n_shared:
             self.kv_cache.free(seq.kv_blocks[n_shared:])
         seq.kv_blocks = np.empty(0, dtype=np.int64)
+        if seq.state_slot >= 0:
+            self.kv_cache.state_pool.free(seq.state_slot)
+            seq.state_slot = -1
 
     def live_uids(self) -> List[int]:
         return list(self.seqs)

@@ -1,9 +1,19 @@
 """Model zoo presets.
 
-Named configurations for the model families the reference ships policies
-for (module_inject/containers/*, inference/v2/model_implementations/*):
-GPT-2 sizes, Llama-2/3, Mistral, Qwen2, Phi-3 — all instances of the
-generic TransformerLM; Mixtral/Qwen-MoE live in models/moe_transformer.py.
+Named configurations, by the class that runs them:
+
+* ``TransformerLM`` (models/transformer.py), one dense block: GPT-2 sizes,
+  Llama-2/3, Mistral, Qwen2, Phi-3, OPT, Falcon — the families the reference
+  ships policies for (module_inject/containers/*,
+  inference/v2/model_implementations/*);
+* ``MoETransformerLM`` (models/moe_transformer.py), the same attention with
+  a plain softmax top-k expert layer: Mixtral, Qwen2-MoE;
+* ``HybridLM`` (models/hybrid.py), periods of gated-DeltaNet layers and one
+  gated-attention layer, each with a shared-expert MoE that may hold a share
+  of the routed experts: Qwen3-Next.
+
+``get_model`` finds the class by the configuration's type
+(``_model_classes``).
 """
 
 from __future__ import annotations
@@ -102,6 +112,48 @@ def _register_moe():
 _register_moe()
 
 
+def _register_hybrid():
+    from deepspeed_tpu.models.hybrid import HybridConfig
+
+    CONFIGS.update({
+        # Qwen3-Next-80B-A3B (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct
+        # config.json, model_type qwen3_next) at the published values
+        "qwen3-next-80b-a3b": HybridConfig(
+            vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=16,
+            num_kv_heads=2, attn_head_dim=256, max_seq_len=262144,
+            pos_emb="rope", norm="rmsnorm", activation="swiglu",
+            tie_embeddings=False, rope_theta=1e7, norm_eps=1e-6,
+            partial_rotary_factor=0.25, full_attention_interval=4,
+            linear_num_key_heads=16, linear_num_value_heads=32,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_conv_kernel_dim=4, num_experts=512, top_k=10,
+            moe_ffn_size=512, shared_ffn_size=512),
+        "tiny-hybrid": HybridConfig(
+            vocab_size=256, hidden_size=64, num_layers=8, num_heads=4,
+            num_kv_heads=2, attn_head_dim=32, max_seq_len=256,
+            pos_emb="rope", norm="rmsnorm", activation="swiglu",
+            tie_embeddings=False, rope_theta=1e4, norm_eps=1e-6,
+            linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=32, linear_value_head_dim=32,
+            num_experts=16, top_k=4, moe_ffn_size=32, shared_ffn_size=32,
+            experts_held=4, remat=False),
+    })
+
+
+_register_hybrid()
+
+
+def _model_classes():
+    """Configuration type -> model class, most derived first."""
+    from deepspeed_tpu.models.hybrid import HybridConfig, HybridLM
+    from deepspeed_tpu.models.moe_transformer import (
+        MoETransformerConfig, MoETransformerLM)
+
+    return ((HybridConfig, HybridLM),
+            (MoETransformerConfig, MoETransformerLM),
+            (TransformerConfig, TransformerLM))
+
+
 def get_model(name: str, **overrides) -> TransformerLM:
     """Instantiate a preset, optionally overriding config fields
     (e.g. max_seq_len, remat_policy, sequence_parallel)."""
@@ -117,9 +169,5 @@ def get_model(name: str, **overrides) -> TransformerLM:
                                     "overlap_depth")
                   if f not in overrides}
     cfg = dataclasses.replace(cfg, **env_fields, **overrides)
-    from deepspeed_tpu.models.moe_transformer import (
-        MoETransformerConfig, MoETransformerLM)
-
-    if isinstance(cfg, MoETransformerConfig):
-        return MoETransformerLM(cfg)
-    return TransformerLM(cfg)
+    return next(model for kind, model in _model_classes()
+                if isinstance(cfg, kind))(cfg)

@@ -94,12 +94,16 @@ def make_group_metadata(group_sizes: jax.Array, m: int, block_m: int
     last = jnp.maximum(total - 1, 0)
     # padding items: aim at any m-tiles left uncovered by an under-sum
     # (one each, empty row range → zero-filled output); once tiles are
-    # exhausted, repeat the last real item (a benign re-visit)
+    # exhausted, stay on the tile visited last — the last uncovered one,
+    # or the last real item's where none was uncovered (a benign
+    # re-visit). Going *back* to the last real item's tile after the
+    # uncovered ones would open it anew and zero what it holds.
     first_uncovered = (ends[-1] + block_m - 1) // block_m
     pad_tile = first_uncovered + (w - total)
     use_pad_tile = jnp.logical_and(~valid, pad_tile < m_tiles)
+    rest = jnp.where(first_uncovered < m_tiles, m_tiles - 1, tile[last])
     tile = jnp.where(valid, tile,
-                     jnp.where(use_pad_tile, pad_tile, tile[last]))
+                     jnp.where(use_pad_tile, pad_tile, rest))
     tile = jnp.clip(tile, 0, max(m_tiles - 1, 0)).astype(jnp.int32)
     group = jnp.where(valid, gid, gid[last]).astype(jnp.int32)
     row_start = jnp.where(valid, starts[gid], 0).astype(jnp.int32)
@@ -125,9 +129,13 @@ def _pick_block(dim: int, want: int) -> int:
     return max(b, 1)
 
 
-def _gmm_kernel(tile_ids, group_ids, row_start, row_end,
-                lhs_ref, rhs_ref, out_ref, acc_ref, *, block_m: int,
-                transpose_rhs: bool):
+def _gmm_kernel(tile_ids, group_ids, row_start, row_end, *refs,
+                block_m: int, transpose_rhs: bool, layered: bool):
+    # a layered call carries the layer as a fifth scalar-prefetch operand
+    # (read by the index maps only) and a leading layer dim on the rhs block
+    lhs_ref, rhs_ref, out_ref, acc_ref = refs[1:] if layered else refs
+    if layered:
+        rhs_ref = rhs_ref.at[0]
     t = pl.program_id(1)
     k = pl.program_id(2)
     tile = tile_ids[t]
@@ -155,46 +163,50 @@ def _gmm_kernel(tile_ids, group_ids, row_start, row_end,
 
 def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
               block_m: int, block_n: int, block_k: int,
-              transpose_rhs: bool = False) -> jax.Array:
+              transpose_rhs: bool = False, layer=None) -> jax.Array:
     """out[m] = lhs[m] @ rhs[g(m)] (or @ rhs[g(m)].T when transpose_rhs,
     rhs then being [E, N, K] — saves materializing the swap in the
-    backward)."""
+    backward). With ``layer`` (a traced int32 scalar) ``rhs`` is a stack
+    ``[L, E, K, N]`` read at that layer by the index map: a step program
+    that loops over layers never slices one layer's experts out of the
+    stack (268 MB a projection at 128 experts of 2048 x 512)."""
     m, kdim = lhs.shape
+    layered = layer is not None
     if transpose_rhs:
-        num_groups, n, _ = rhs.shape
+        num_groups, n, _ = rhs.shape[-3:]
     else:
-        num_groups, _, n = rhs.shape
+        num_groups, _, n = rhs.shape[-3:]
     block_m = _pick_block(m, block_m)
     block_n = _pick_block(n, block_n)
     block_k = _pick_block(kdim, block_k)
     meta = make_group_metadata(group_sizes, m, block_m)
+    if layered:
+        meta = meta + (jnp.asarray(layer, jnp.int32).reshape(1),)
     t_total = _num_work_items(m, num_groups, block_m)
     grid = (n // block_n, t_total, kdim // block_k)
 
-    if transpose_rhs:
-        rhs_spec = pl.BlockSpec((1, block_n, block_k),
-                                lambda n, t, k, tiles, gids, rs, re:
-                                (gids[t], n, k))
-    else:
-        rhs_spec = pl.BlockSpec((1, block_k, block_n),
-                                lambda n, t, k, tiles, gids, rs, re:
-                                (gids[t], k, n))
+    def rhs_index(n, t, k, tiles, gids, rs, re, *lyr):
+        at = (gids[t], n, k) if transpose_rhs else (gids[t], k, n)
+        return (lyr[0][0],) + at if layered else at
+
+    rhs_block = (1, block_n, block_k) if transpose_rhs \
+        else (1, block_k, block_n)
+    rhs_spec = pl.BlockSpec(((1,) + rhs_block) if layered else rhs_block,
+                            rhs_index)
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, block_m=block_m,
-                          transpose_rhs=transpose_rhs),
+                          transpose_rhs=transpose_rhs, layered=layered),
         name="grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(meta),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_k),
-                             lambda n, t, k, tiles, gids, rs, re:
-                             (tiles[t], k)),
+                             lambda n, t, k, tiles, *_: (tiles[t], k)),
                 rhs_spec,
             ],
             out_specs=pl.BlockSpec((block_m, block_n),
-                                   lambda n, t, k, tiles, gids, rs, re:
-                                   (tiles[t], n)),
+                                   lambda n, t, k, tiles, *_: (tiles[t], n)),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
@@ -203,6 +215,15 @@ def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
         interpret=_interpret(),
     )(*meta, lhs, rhs)
     return out
+
+
+def gmm_layer(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, layer,
+              block_m: int = 512, block_n: int = 1024, block_k: int = 512
+              ) -> jax.Array:
+    """:func:`gmm` against one layer of a stack ``rhs [L, E, K, N]``, the
+    layer a traced scalar (forward only: the serving path's experts)."""
+    return _gmm_call(lhs, rhs, group_sizes, block_m, block_n, block_k,
+                     layer=layer)
 
 
 # ---------------------------------------------------------------------------

@@ -220,16 +220,23 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, expert_params: Dict[str, jax.Arra
 
 def _expert_ffn(sorted_x: jax.Array, group_sizes: jax.Array,
                 expert_params: Dict[str, jax.Array], activation: str,
-                dt) -> jax.Array:
-    """Grouped-GEMM expert FFN over rows sorted by (local) expert."""
+                dt, layer=None) -> jax.Array:
+    """Grouped-GEMM expert FFN over rows sorted by (local) expert. With
+    ``layer`` the expert leaves are stacks ``[L, E, ...]`` read at that
+    (traced) layer inside the kernel."""
     import functools
 
     from deepspeed_tpu.ops import attention as attn_ops
-    from deepspeed_tpu.ops.pallas.grouped_matmul import gmm as gmm_raw
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 
     # engine-installed tile geometry (config.kernels.gmm_block_{m,n,k});
     # gmm snaps each to the largest legal divisor per operand shape
-    gmm = functools.partial(gmm_raw, **attn_ops.kernel_gmm_tiles())
+    tiles = attn_ops.kernel_gmm_tiles()
+    if layer is None:
+        gmm = functools.partial(gm.gmm, **tiles)
+    else:
+        def gmm(lhs, rhs, sizes):
+            return gm.gmm_layer(lhs, rhs, sizes, layer, **tiles)
     wi, wo = expert_params["wi"].astype(dt), expert_params["wo"].astype(dt)
     if activation == "swiglu":
         wg = expert_params["wg"].astype(dt)
@@ -238,6 +245,89 @@ def _expert_ffn(sorted_x: jax.Array, group_sizes: jax.Array,
     else:
         hidden = jax.nn.gelu(gmm(sorted_x, wi, group_sizes))
     return gmm(hidden, wo, group_sizes)                     # [M, H-or-H_tp]
+
+
+def route_top_k(y: jax.Array, router_w: jax.Array, top_k: int):
+    """Softmax routing in float32 over *all* the router's outputs: the
+    ``top_k`` largest probabilities, renormalised to sum to one. y [T, H],
+    router_w [H, E]. Returns (weights [T, k] float32, experts [T, k])."""
+    logits = jnp.einsum("th,he->te", y.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return top / jnp.sum(top, axis=-1, keepdims=True), idx.astype(jnp.int32)
+
+
+@jax.named_scope("moe")
+def moe_ffn_share(y: jax.Array, router_w: jax.Array,
+                  expert_params: Dict[str, jax.Array], cfg: GateConfig, *,
+                  offset: int = 0, shared: Optional[Dict] = None,
+                  valid: Optional[jax.Array] = None, layer=None
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """An expert layer that holds a *share* of the experts: one chip's part
+    of a layer whose ``cfg.num_experts`` experts are divided over the chips
+    of an expert-parallel deployment (model-configs guide, section 4).
+
+    The router runs over all ``cfg.num_experts`` outputs and the weights are
+    renormalised over the chosen ``top_k``; the experts held here are
+    ``offset .. offset + E_held`` (``expert_params`` leaves ``[E_held, ...]``,
+    or ``[L, E_held, ...]`` with ``layer``). The result is what *these*
+    experts add for the tokens routed to them, plus — given ``shared``
+    (``wg``, ``wi``, ``wo`` and the gate vector ``gate``) — the shared
+    expert behind its sigmoid gate, which every chip computes alike. A token
+    none of whose experts live here gets the shared expert alone. Nothing
+    stands in for the absent chips: on one chip there is no exchange; on a
+    mesh with an ``ep`` axis the same layer is :func:`moe_ffn_dropless`,
+    whose shards each hold ``E / ep`` and exchange rows.
+
+    y [T, H] (already normed); ``valid [T]`` marks real tokens (padding is
+    routed nowhere). Returns (out [T, H] in y's type, counts): ``pairs``,
+    the (token, expert) pairs routed here, and ``experts_hit``, the held
+    experts that got a row — int32 scalars for the serving counters.
+
+    Rows sort by local expert; pairs routed elsewhere sort last and lie
+    beyond the groups' sum, where the grouped product yields zeros.
+    """
+    T, H = y.shape
+    held = expert_params["wi"].shape[-3]
+    k = cfg.top_k
+    w, idx = route_top_k(y, router_w, k)
+    with jax.named_scope("moe_route"):
+        here = (idx >= offset) & (idx < offset + held)
+        if valid is not None:
+            here = here & valid[:, None]
+        m0 = T * k
+        m = ((m0 + 127) // 128) * 128
+        local = jnp.where(here, idx - offset, held).reshape(-1)
+        flat_w = jnp.where(here, w, 0.0).reshape(-1)
+        token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        if m > m0:
+            local = jnp.concatenate(
+                [local, jnp.full((m - m0,), held, local.dtype)])
+            flat_w = jnp.concatenate([flat_w, jnp.zeros((m - m0,), w.dtype)])
+            token = jnp.concatenate([token, jnp.zeros((m - m0,), token.dtype)])
+        order = jnp.argsort(local, stable=True)
+        row_token = token[order]
+        group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
+            jnp.int32)
+    with jax.named_scope("moe_experts"):
+        out = _expert_ffn(y[row_token], group_sizes, expert_params, "swiglu",
+                          y.dtype, layer=layer)
+        contrib = out.astype(jnp.float32) * flat_w[order][:, None]
+        total = jnp.zeros((T, H), jnp.float32).at[row_token].add(contrib)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            dt = y.dtype
+            hid = jax.nn.silu(y @ shared["wg"].astype(dt)) \
+                * (y @ shared["wi"].astype(dt))
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "th,h->t", y.astype(jnp.float32),
+                shared["gate"].astype(jnp.float32)))
+            total = total + gate[:, None] * (
+                hid @ shared["wo"].astype(dt)).astype(jnp.float32)
+    counts = {"pairs": jnp.sum(here).astype(jnp.int32),
+              "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32)}
+    return total.astype(y.dtype), counts
 
 
 def _ep_capacity(m0: int, ep: int, cfg: GateConfig, train: bool) -> int:

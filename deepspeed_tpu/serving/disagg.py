@@ -233,6 +233,15 @@ def _pool_convert(kvc, payload, ssel, wire_bits, packed: bool):
     return kv_dequantize(payload, ssel, dtype=kvc.data.dtype), None
 
 
+def _refuse_for_recurrent(engine) -> None:
+    """A prefix's KV blocks without the recurrent state at the prefix's end
+    are a wrong answer on the target: a model with recurrent layers has no
+    hand-off until its state pool has snapshots (ragged/state_pool.py)."""
+    refuse = getattr(engine, "_refuse_without_snapshot", None)
+    if refuse is not None:
+        refuse("the disagg prefill->decode hand-off")
+
+
 def serialize_prefix(engine, tokens,
                      max_blocks: Optional[int] = None,
                      wire: Optional[str] = None
@@ -250,6 +259,7 @@ def serialize_prefix(engine, tokens,
     The chain is ref'd for the duration of the device→host copy so KV
     pressure on the source replica cannot evict-and-recycle a block
     mid-serialization."""
+    _refuse_for_recurrent(engine)
     cache = getattr(engine.kv_cache, "prefix_cache", None)
     if cache is None:
         return None
@@ -297,6 +307,7 @@ def install_prefix(engine, handoff: Optional[KVHandoff]
 
     Must run on the thread that owns ``engine`` (the replica pump): it
     mutates the pool array and the cache registry."""
+    _refuse_for_recurrent(engine)
     cache = getattr(engine.kv_cache, "prefix_cache", None)
     if cache is None or handoff is None or not handoff.keys:
         return (0, 0)

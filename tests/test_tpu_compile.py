@@ -19,8 +19,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deepspeed_tpu.ops.pallas import (flash_attention, grouped_matmul,
-                                      paged_attention, quantization)
+from deepspeed_tpu.ops.pallas import (flash_attention, gated_delta,
+                                      grouped_matmul, paged_attention,
+                                      quantization)
 
 # mistral-7b attention geometry (models/zoo.py)
 HQ, HKV, D = 32, 8, 128
@@ -54,7 +55,7 @@ def _device_kernels(monkeypatch):
     """``jax.default_backend()`` is still the CPU here, so each module's
     ``_interpret()`` would pick the interpreter: steer it in the test."""
     for mod in (flash_attention, paged_attention, grouped_matmul,
-                quantization):
+                quantization, gated_delta):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -202,3 +203,41 @@ def test_kv_quantize_int8(chip):
         return quantization.kv_quantize(x, bits=8)
 
     _compile(chip, f, ((256, 2, HKV, D), BF16))
+
+
+# the hybrid configuration's kernels at its published widths (Qwen3-Next:
+# 32 state heads of 128 x 128; 128 held experts of 2048 x 512 read by layer
+# from the stack; attention heads of 256 in 2 KV groups of 8)
+
+
+def test_gdn_decode_in_place_on_the_state_pool(chip):
+    B, n, d = 32, 32, 128
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in (
+        ((6, 49, n, d, d), f32), ((), jnp.int32), ((B,), jnp.int32),
+        ((B, n, d), f32), ((B, n, d), f32), ((B, n, d), f32), ((B, n), f32),
+        ((B, n), f32))]
+    exe = jax.jit(gated_delta.gdn_decode, donate_argnums=(0,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    mem = exe.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**20           # no copy of the pool
+    assert mem.alias_size_in_bytes >= 6 * 49 * n * d * d * 4
+
+
+def test_grouped_matmul_reads_a_layer_of_the_expert_stack(chip):
+    def f(lhs, rhs, sizes, layer):
+        return grouped_matmul.gmm_layer(lhs, rhs, sizes, layer)
+
+    text = _compile(chip, f, ((384, 2048), BF16), ((8, 128, 2048, 512), BF16),
+                    ((128,), jnp.int32), ((), jnp.int32))
+    # the stack goes into the kernel whole: no slice of it is made
+    assert "bf16[128,2048,512]" not in text.replace("bf16[8,128,2048,512]", "")
+
+
+def test_paged_decode_at_head_size_256_group_8(chip):
+    def f(q, kv, bt, ctx):
+        return paged_attention.paged_decode_attention(q, kv, bt, ctx, layer=1)
+
+    _compile(chip, f, ((32, 16, 256), BF16), ((2, 2080, 16, 2, 2, 256), BF16),
+             ((32, 64), jnp.int32), ((32,), jnp.int32))
