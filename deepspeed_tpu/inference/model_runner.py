@@ -124,6 +124,16 @@ def _qkv(cfg: TransformerConfig, layer_params, y, positions):
     return q, k, v
 
 
+def _attention_probs(cfg: TransformerConfig, scores, mask):
+    """Scaled, masked softmax over the last (context) axis: the scale in
+    the scores' own dtype, mask and softmax in float32, the result back in
+    that dtype. Whatever the leading axes are; ``mask`` broadcasts."""
+    dt = scores.dtype
+    scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim)).astype(dt)
+    scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+    return jax.nn.softmax(scores, axis=-1).astype(dt)
+
+
 @jax.named_scope("mlp")
 def _mlp(cfg: TransformerConfig, layer_params, x):
     if "moe" in layer_params:
@@ -208,7 +218,6 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
         x = x + params["embed"]["positions"].astype(dt)[positions]
 
     key_pos = jnp.arange(max_len)  # absolute position of each cache row
-    rep = cfg.num_heads // cfg.kv_heads
 
     def layer_body(x, inputs):
         layer_params, kv_layer = inputs  # kv_layer [B, max_len, 2, nkv, hd]
@@ -218,17 +227,16 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
         kv_new = jnp.stack([k, v], axis=2).astype(kv_layer.dtype)  # [B,S,2,nkv,hd]
         kv_layer = lax.dynamic_update_slice(
             kv_layer, kv_new, (0, start_pos, 0, 0, 0))
-        k_all = kv_layer[:, :, 0]  # [B, max_len, nkv, hd]
-        v_all = kv_layer[:, :, 1]
-        if rep > 1:
-            k_all = jnp.repeat(k_all, rep, axis=2)
-            v_all = jnp.repeat(v_all, rep, axis=2)
-        scores = jnp.einsum("bsnd,bmnd->bnsm", q, k_all.astype(dt))
-        scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim)).astype(dt)
-        mask = key_pos[None, None, None, :] <= positions[:, None, :, None]
-        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-        attn = jnp.einsum("bnsm,bmnd->bsnd", probs, v_all.astype(dt))
+        # grouped-query attention per KV head (k), its query heads as a
+        # group axis (g), against the cache as it lies: g is 1 for a
+        # multi-head model, k is 1 for multi-query
+        qg = q.reshape(B, S, cfg.kv_heads, -1, cfg.head_dim)
+        scores = jnp.einsum("bskgd,bmkd->bkgsm", qg,
+                            kv_layer[:, :, 0].astype(dt))
+        mask = key_pos[None, :] <= positions[0, :, None]  # [S, max_len]
+        probs = _attention_probs(cfg, scores, mask)
+        attn = jnp.einsum("bkgsm,bmkd->bskgd", probs,
+                          kv_layer[:, :, 1].astype(dt)).reshape(q.shape)
         attn = jnp.einsum("bsnd,ndh->bsh", attn,
                           layer_params["attn"]["wo"].astype(dt))
         if cfg.use_biases:
@@ -271,7 +279,6 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     Smax, Bm = block_table.shape
     bs = kv_data.shape[2]
     dt = effective_dtype(cfg.dtype)
-    rep = cfg.num_heads // cfg.kv_heads
     is_real = jnp.arange(T) < num_tokens  # [T]
 
     x = vocab_parallel_lookup(
@@ -302,8 +309,12 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
         kv, kv_sc = _kv_write(kv, kv_sc, l, page, offset, k, v)
         with jax.named_scope("kv_gather"):
             # gather each slot's pages (and no others) into dense
-            # [S, Lmax, nkv, hd], then one row of the full context per
-            # *token*: [T, Lmax, nh, hd]
+            # [S, Lmax, 2, nkv, hd], lay the *sequences'* keys and values
+            # out head-major, [S, nkv, Lmax, hd] each (an eighth of what
+            # the tokens' are), then one row of the full context per
+            # *token*, [T, nkv, Lmax, hd]: the layout the contractions
+            # below read, so the take is the only pass that writes a
+            # token's context
             gathered = kv[l, block_table]  # [S, Bm, bs, 2, nkv, hd(/2)]
             if kv_sc is not None:
                 # dequant-on-read: only the gathered pages, never the pool
@@ -312,18 +323,19 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
                     kv_sc[l, block_table], dtype=dt)
             gathered = gathered.reshape(Smax, max_ctx, 2, cfg.kv_heads,
                                         cfg.head_dim)
-            k_seq = gathered[:, :, 0][token_seq]  # [T, Lmax, nkv, hd]
-            v_seq = gathered[:, :, 1][token_seq]
-            if rep > 1:
-                k_seq = jnp.repeat(k_seq, rep, axis=2)
-                v_seq = jnp.repeat(v_seq, rep, axis=2)
+            k_seq = gathered[:, :, 0].transpose(0, 2, 1, 3)[token_seq]
+            v_seq = gathered[:, :, 1].transpose(0, 2, 1, 3)[token_seq]
         with jax.named_scope("attn"):
-            scores = jnp.einsum("tnd,tmnd->tnm", q, k_seq.astype(dt))
-            scores = scores / jnp.sqrt(jnp.float32(cfg.head_dim)).astype(dt)
-            mask = key_pos[None, None, :] <= token_pos[:, None, None]
-            scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-            attn = jnp.einsum("tnm,tmnd->tnd", probs, v_seq.astype(dt))
+            # grouped-query attention per KV head (k), its query heads as
+            # a group axis (g): g is 1 for a multi-head model, k is 1 for
+            # multi-query
+            qg = q.reshape(T, cfg.kv_heads, -1, cfg.head_dim)
+            scores = jnp.einsum("tkgd,tkmd->tkgm", qg, k_seq.astype(dt))
+            mask = key_pos[None, None, None, :] \
+                <= token_pos[:, None, None, None]
+            probs = _attention_probs(cfg, scores, mask)
+            attn = jnp.einsum("tkgm,tkmd->tkgd", probs,
+                              v_seq.astype(dt)).reshape(q.shape)
             attn = jnp.einsum("tnd,ndh->th", attn,
                               layer_params["attn"]["wo"].astype(dt))
             if cfg.use_biases:
