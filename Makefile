@@ -14,7 +14,7 @@ NPROC := $(shell nproc)
 XDIST ?= $(shell if [ $(NPROC) -gt 2 ] && python -c "import xdist" 2>/dev/null; then echo "-n $$(( $(NPROC) - 1 )) --dist loadfile"; fi)
 PYTEST ?= python -m pytest
 
-.PHONY: test smoke slow bench bench-real bench-proxy bench-hostgap bench-overlap bench-longctx bench-quant bench-kernels bench-diff quant-sweep fleet-demo chaos serve-slo serve-fleet serve-quant serve-tier serve-procs chaos-fleet obs-fleet replay-fleet deploy-drill
+.PHONY: test smoke slow bench bench-real bench-proxy bench-hostgap bench-overlap bench-longctx bench-quant bench-diff quant-sweep fleet-demo chaos serve-slo serve-fleet serve-quant serve-tier serve-procs chaos-fleet obs-fleet replay-fleet deploy-drill
 
 smoke:
 	$(PYTEST) tests/ -q -m "not slow" $(XDIST)
@@ -64,15 +64,6 @@ bench-longctx:
 # (docs/quantized_comm.md "Measuring the trade").
 bench-quant:
 	BENCH_QUANT=1 python bench.py
-
-# Per-kernel win/loss tier: each Pallas kernel vs its XLA fallback per
-# shape bucket (block-geometry sweep), one JSON line with the table,
-# measured rows recorded into docs/autotuned/kernel_table.json on TPU
-# (scratch table elsewhere); exits nonzero on a numerics or dispatch
-# gate violation (tools/kernel_bench.py; KERNEL_BENCH_FULL=1 for the
-# real-shape sweep).
-bench-kernels:
-	BENCH_KERNELS=1 python bench.py
 
 # Fail-loud regression sentinel over the BENCH_r*.json trajectory:
 # newest vs previous round per headline metric (throughput, mfu,

@@ -407,28 +407,6 @@ def main():
         print(json.dumps(payload))
         return
 
-    if int(os.environ.get("BENCH_KERNELS", "0")):
-        # kernel tier win/loss (make bench-kernels): each Pallas kernel
-        # vs its XLA fallback per shape bucket, block-geometry sweep,
-        # measured rows recorded into the dispatch table
-        # (docs/autotuned/kernel_table.json on TPU; scratch elsewhere).
-        # Gates: kernel-vs-XLA numerics per bucket, and the recorded
-        # table must provably steer multi_head_attention — losing
-        # buckets route to XLA bit-identically. Fail-loud like
-        # BENCH_QUANT. KERNEL_BENCH_* env knobs (tools/kernel_bench.py).
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.abspath(__file__)), "tools"))
-        from kernel_bench import run_kernel_bench
-
-        table, payload, ok = run_kernel_bench()
-        print(table)
-        print(json.dumps(payload))
-        if not ok:
-            raise SystemExit(1)
-        return
-
     if int(os.environ.get("BENCH_QUANT", "0")):
         # quantization acceptance gates (make bench-quant): per-region
         # SNR / max-rel-error on real params+grads, the bit-exact

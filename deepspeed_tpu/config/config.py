@@ -1064,23 +1064,16 @@ class CompileConfig(ConfigModel):
 @register_config_model
 @dataclass
 class KernelsConfig(ConfigModel):
-    """Pallas kernel geometry + dispatch policy (docs/kernels.md).
+    """Pallas kernel geometry (docs/kernels.md).
 
     Block sizes were hardcoded in the kernels; they are config knobs
     and autotuner axes now (kernel-geometry axis family — candidates
     are shape-legal divisors only, ``autotuning/autotuner.py``). 0
     means "auto": the kernel's seq-derived default for flash, the
     measured v5e tiles for the grouped matmul, one page per compute
-    block for paged attention.
-
-    ``dispatch`` picks how ``ops/attention.py`` chooses flash vs XLA:
-    "auto" consults the per-(kernel, shape-bucket) win/loss table
-    (``ops/kernel_table.py``; measured by ``make bench-kernels``) with
-    the legacy seq-length heuristic covering unmeasured buckets;
-    "heuristic" ignores the table (pre-round-14 behavior).
-    ``table_path`` overrides the table location (None → the
-    ``DSTPU_KERNEL_TABLE`` env var, then
-    ``docs/autotuned/kernel_table.json``)."""
+    block for paged attention. Which attention kernel runs is not
+    set here: ``ops/attention.py`` decides from the backend and the
+    sequence length, or ``attn_impl`` names one."""
 
     flash_block_q: int = 0  # 0 = auto (1024 at seq>=8k else min(512, S))
     flash_block_k: int = 0
@@ -1089,8 +1082,6 @@ class KernelsConfig(ConfigModel):
     gmm_block_n: int = 1024
     gmm_block_k: int = 512
     blocksparse_block: int = 0  # 0 = follow sparse_attention.block
-    dispatch: str = "auto"  # auto (win/loss table) | heuristic
-    table_path: Optional[str] = None
 
     def validate(self) -> None:
         for name in ("flash_block_q", "flash_block_k", "gmm_block_m",
@@ -1104,10 +1095,6 @@ class KernelsConfig(ConfigModel):
             raise ValueError(
                 f"kernels.pages_per_compute_block must be >= 1, got "
                 f"{self.pages_per_compute_block}")
-        if self.dispatch not in ("auto", "heuristic"):
-            raise ValueError(
-                f"kernels.dispatch must be auto|heuristic, got "
-                f"{self.dispatch!r}")
 
 
 @register_config_model

@@ -4,9 +4,8 @@ Covers the role of the reference's fused attention kernels
 (csrc/transformer/*softmax*.cu, inference flash kernels
 inference/v2/kernels/ragged_ops/blocked_flash). The ``impl='auto'`` path
 picks between the Pallas flash kernel (ops/pallas/flash_attention.py) and
-the XLA einsum implementation per shape bucket from the measured win/loss
-table, and from the backend and sequence length where the table has no
-row. A decision for the kernel runs the kernel or raises: there is no
+the XLA einsum implementation from the backend and the sequence length.
+A decision for the kernel runs the kernel or raises: there is no
 downgrade to the O(S^2) path behind the caller's back.
 """
 
@@ -23,10 +22,9 @@ NEG_INF = -1e30
 
 @functools.lru_cache(None)
 def _flash_available() -> bool:
-    """Legacy heuristic availability: a TPU backend. (The win/loss table
-    can still route to the kernel off-TPU — e.g. a CPU-measured table
-    entry in tests — interpreter mode is numerics-equivalent, just
-    slow.)"""
+    """A TPU backend: elsewhere the kernel exists only in interpreter
+    mode (numerics-equivalent, just slow), which ``impl='flash'`` asks
+    for by name."""
     return jax.default_backend() == "tpu"
 
 
@@ -69,13 +67,11 @@ def xla_attention(q, k, v, causal: bool = True,
     return jnp.einsum("bnqk,bknd->bqnd", probs, v)
 
 
-# Legacy crossover heuristic — covers buckets the win/loss table hasn't
-# measured yet. Below this sequence length XLA's fused attention beats
-# the Pallas kernel on-chip; above it flash wins AND avoids the [S,S]
-# fp32 score transient. Measured on v5e (B=32,N=12,D=64, fwd+bwd, block
-# 512): seq 1024 → flash 1.5x over XLA; block 128 (old default) was
-# 0.6x — block size dominates. Measured buckets override this entirely
-# (ops/kernel_table.py; `make bench-kernels` re-measures).
+# The crossover of impl='auto'. Below this sequence length XLA's fused
+# attention beats the Pallas kernel on-chip; above it flash wins AND
+# avoids the [S,S] fp32 score transient. Measured on v5e (B=32,N=12,D=64,
+# fwd+bwd, block 512): seq 1024 → flash 1.5x over XLA; block 128 (old
+# default) was 0.6x — block size dominates.
 FLASH_MIN_SEQ = 1024
 
 
@@ -83,9 +79,8 @@ FLASH_MIN_SEQ = 1024
 # set_sparse_config at engine init); used when impl == "blocksparse"
 _SPARSE_CONFIG = None
 
-# engine-configured kernel geometry + dispatch policy (config.kernels →
-# set_kernel_config at engine init); None = defaults (table dispatch,
-# seq-derived blocks)
+# engine-configured kernel geometry (config.kernels → set_kernel_config
+# at engine init); None = defaults (seq-derived blocks)
 _KERNEL_CONFIG = None
 
 # trace-time dispatch outcomes of impl='auto': pallas/xla picks
@@ -101,7 +96,7 @@ def set_sparse_config(sparsity) -> None:
 
 def set_kernel_config(kernels) -> None:
     """Install the ds_config ``kernels`` block (engine init): block
-    geometry overrides and the table-vs-heuristic dispatch switch."""
+    geometry overrides."""
     global _KERNEL_CONFIG
     _KERNEL_CONFIG = kernels
 
@@ -135,22 +130,18 @@ def _auto_block(seq: int) -> int:
     return 1024 if seq >= 8192 else min(512, seq)
 
 
-def _pick_blocks(seq: int, measured: Optional[dict]) -> tuple:
-    """Flash block geometry: measured winning blocks (table) > config
-    knobs (kernels.flash_block_q/_k, 0 = auto) > seq-derived default."""
+def _pick_blocks(seq: int) -> tuple:
+    """Flash block geometry: kernels.flash_block_q/_k where set (0 =
+    auto), else the seq-derived default."""
     bq = bk = _auto_block(seq)
     kcfg = _KERNEL_CONFIG
     if kcfg is not None:
         bq = getattr(kcfg, "flash_block_q", 0) or bq
         bk = getattr(kcfg, "flash_block_k", 0) or bk
-    if measured:
-        bq = int(measured.get("block_q", bq))
-        bk = int(measured.get("block_k", bk))
     return bq, bk
 
 
-def _export_dispatch(region: str, source: str, reason: str,
-                     bucket: str) -> None:
+def _export_dispatch(region: str, source: str, reason: str) -> None:
     """Publish the chosen source per region to the observability hub.
     Runs at trace time (once per compiled program, not per step); never
     instantiates a hub of its own."""
@@ -164,7 +155,7 @@ def _export_dispatch(region: str, source: str, reason: str,
         return
     hub.gauge(f"kernel.{region}.pallas", 1.0 if source == "pallas" else 0.0)
     hub.record_event("kernel_dispatch", region=region, source=source,
-                     reason=reason, bucket=bucket)
+                     reason=reason)
 
 
 def _flash_on_mesh(q, k, v, causal: bool, segment_ids, block_q: int,
@@ -228,10 +219,10 @@ def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
                          segment_ids: Optional[jax.Array] = None) -> jax.Array:
     """Dispatching entry point used by the model zoo.
 
-    ``impl='auto'`` is cost-driven: the registry consults the measured
-    per-(kernel, shape-bucket) win/loss table (compat probing as the
-    outer guard); unmeasured buckets fall back to the FLASH_MIN_SEQ
-    heuristic. Explicit ``impl='flash'``/``'xla'`` bypass the table.
+    ``impl='auto'`` runs the flash kernel on a TPU from FLASH_MIN_SEQ
+    up and the XLA einsum elsewhere; ``impl='flash'``/``'xla'`` name
+    one. Flash blocks are ``kernels.flash_block_q/_k`` where set, else
+    ``_auto_block(seq)``.
     """
     seq = q.shape[1]
     if impl == "blocksparse":
@@ -247,30 +238,16 @@ def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
 
         k, v = repeat_kv_heads(q, k, v)  # blocksparse kernel is MHA-only
         return blocksparse_attention(q, k, v, _SPARSE_CONFIG, causal=causal)
+    if impl == "auto":
+        on_tpu = _flash_available()
+        impl = "flash" if on_tpu and seq >= FLASH_MIN_SEQ else "xla"
+        source = "pallas" if impl == "flash" else "xla"
+        _DISPATCH_STATS[source] += 1
+        _export_dispatch("attention", source,
+                         "no TPU backend" if not on_tpu else
+                         f"seq {'>=' if impl == 'flash' else '<'} "
+                         "FLASH_MIN_SEQ")
     if impl == "flash":
-        bq, bk = _pick_blocks(seq, None)
-        return _flash_on_mesh(q, k, v, causal, segment_ids, bq, bk)
-    if impl != "auto":
-        return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
-
-    from deepspeed_tpu.ops import kernel_table, registry
-
-    kcfg = _KERNEL_CONFIG
-    bucket = kernel_table.attention_bucket(seq, q.shape[-1], causal)
-    heuristic = _flash_available() and seq >= FLASH_MIN_SEQ
-    if kcfg is not None and getattr(kcfg, "dispatch", "auto") == "heuristic":
-        decision = registry.DispatchDecision(
-            op_name=("flash_attention" if heuristic else "xla_attention"),
-            source=("pallas" if heuristic else "xla"),
-            reason="kernels.dispatch=heuristic")
-    else:
-        decision = registry.dispatch_op(
-            "flash_attention", bucket, "xla_attention",
-            default_use=heuristic,
-            table_path=getattr(kcfg, "table_path", None))
-    _DISPATCH_STATS[decision.source] += 1
-    _export_dispatch("attention", decision.source, decision.reason, bucket)
-    if decision.source == "pallas":
-        bq, bk = _pick_blocks(seq, decision.blocks)
+        bq, bk = _pick_blocks(seq)
         return _flash_on_mesh(q, k, v, causal, segment_ids, bq, bk)
     return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)

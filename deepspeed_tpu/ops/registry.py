@@ -4,17 +4,10 @@ Reference: op_builder/ (builder.py:116 ``OpBuilder`` ABC with
 ``is_compatible()``/``load()``; 26 named builders,
 ``get_accelerator().create_op_builder(name)``). CUDA needs a JIT C++
 build step; Pallas/XLA ops are jitted by XLA itself, so the registry's
-job reduces to (a) a stable name → op table for tooling (`dstpu-report`
-prints the compat column like ds_report), and (b) graceful-degradation
-probes so callers can pick fallbacks (e.g. flash attention → XLA
-attention when no TPU is present).
-
-Since round 14 the registry also owns cost-driven dispatch
-(:func:`dispatch_op`): the compat probe stays the outer guard, then
-the measured per-(kernel, shape-bucket) win/loss table
-(ops/kernel_table.py, written by ``make bench-kernels``) decides — a
-kernel runs on a bucket only if its measured win ratio is >= 1.0
-there; unmeasured buckets defer to the caller's legacy heuristic.
+job reduces to a stable name → op table for tooling (`dstpu-report`
+prints the compat column like ds_report) with a probe per op that says
+whether a device kernel exists here. Nothing dispatches through it:
+``ops/attention.py`` decides which attention runs.
 """
 
 from __future__ import annotations
@@ -63,53 +56,6 @@ def get_op(name: str) -> Callable:
 def all_ops() -> Dict[str, OpSpec]:
     _ensure_builtin()
     return dict(_REGISTRY)
-
-
-@dataclasses.dataclass(frozen=True)
-class DispatchDecision:
-    """Outcome of a cost-driven dispatch: which registered op runs and
-    why. ``blocks`` carries the measured winning geometry (table entry)
-    so the caller can run the kernel exactly as benched."""
-
-    op_name: str
-    source: str  # "pallas" | "xla"
-    reason: str
-    ratio: Optional[float] = None
-    blocks: Optional[Dict[str, int]] = None
-
-
-def dispatch_op(name: str, bucket: str, fallback: str,
-                default_use: bool = False,
-                table_path: Optional[str] = None) -> DispatchDecision:
-    """Pick ``name`` or ``fallback`` for a shape bucket.
-
-    Guard order: (1) compat probe — an incompatible kernel never runs,
-    whatever the table says; (2) win/loss table — measured entries are
-    authoritative (win ratio >= 1.0 runs the kernel, < 1.0 routes the
-    bucket to the fallback); (3) ``default_use`` — the caller's legacy
-    heuristic for unmeasured buckets.
-    """
-    _ensure_builtin()
-    if name not in _REGISTRY:
-        raise KeyError(f"unknown op {name!r}; known: {sorted(_REGISTRY)}")
-    ok, note = _REGISTRY[name].is_compatible()
-    if not ok:
-        return DispatchDecision(fallback, "xla",
-                                f"compat probe failed: {note}")
-    from deepspeed_tpu.ops import kernel_table
-
-    d = kernel_table.decide(name, bucket, path=table_path)
-    if d.measured:
-        if d.win:
-            return DispatchDecision(name, "pallas", d.reason,
-                                    d.ratio, d.blocks)
-        return DispatchDecision(fallback, "xla", d.reason,
-                                d.ratio, d.blocks)
-    if default_use:
-        return DispatchDecision(name, "pallas",
-                                f"{d.reason}; heuristic prefers kernel")
-    return DispatchDecision(fallback, "xla",
-                            f"{d.reason}; heuristic prefers fallback")
 
 
 def _tpu_probe() -> Tuple[bool, str]:

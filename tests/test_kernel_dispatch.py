@@ -1,22 +1,23 @@
-"""Cost-driven kernel dispatch: the win/loss table, the registry
-decision layer, and the attention entry point consulting both.
+"""Which attention kernel ``impl='auto'`` runs, and with what blocks.
 
 Load-bearing guarantees (docs/kernels.md):
-- dispatch provably consults the measured table: flipping a bucket's
-  entry to losing routes that bucket to XLA **bit-identically**, and a
-  winning entry routes to the flash kernel with the measured blocks;
-- table entries are backend-scoped — the committed TPU-measured
-  ``docs/autotuned/kernel_table.json`` never changes what a CPU run
-  dispatches (unmeasured on this backend → legacy heuristic);
-- compat probing stays the outer guard, the table rules measured
-  buckets, the FLASH_MIN_SEQ heuristic covers only unmeasured ones;
-- the chosen source is exported as ``kernel.*`` hub metrics, and the
-  wanted-flash-but-unavailable case is a warn-once telemetry ratio like
-  ``serve.paged_fallback_ratio``.
+- one rule decides, from what the program can observe: the flash kernel
+  on a TPU backend from ``FLASH_MIN_SEQ`` up, the XLA einsum elsewhere,
+  the latter **bit-identically** to ``xla_attention``;
+- the blocks are ``kernels.flash_block_q/_k`` where set, else
+  ``_auto_block(seq)``: a value a user sets is the value that runs;
+- nothing reads a file, an environment variable or a table, and no
+  module of the package imports the tooling above it;
+- a decision for the kernel runs the kernel or raises;
+- the chosen source is exported as ``kernel.*`` hub metrics.
 """
 
-import json
+import ast
+import builtins
+import os
+import re
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -25,35 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops import attention as attn_ops
-from deepspeed_tpu.ops import kernel_table, registry
 
-
-def _write_table(path, kernel, bucket, ratio, blocks=None, backend=None):
-    entry = {"kernel_ms": 1.0, "xla_ms": ratio, "ratio": ratio,
-             "backend": backend or jax.default_backend()}
-    if blocks:
-        entry["blocks"] = blocks
-    doc = {"_meta": {"schema": kernel_table.SCHEMA},
-           "entries": {kernel: {bucket: entry}}}
-    path.write_text(json.dumps(doc))
-    kernel_table.invalidate_cache()
-    return str(path)
-
-
-@pytest.fixture
-def table_env(tmp_path, monkeypatch):
-    """Point the dispatcher at a scratch table; restore + uncache on exit."""
-    path = tmp_path / "kernel_table.json"
-
-    def install(kernel, bucket, ratio, blocks=None, backend=None):
-        monkeypatch.setenv("DSTPU_KERNEL_TABLE",
-                           _write_table(path, kernel, bucket, ratio,
-                                        blocks=blocks, backend=backend))
-        return path
-
-    yield install
-    monkeypatch.delenv("DSTPU_KERNEL_TABLE", raising=False)
-    kernel_table.invalidate_cache()
+PACKAGE = Path(attn_ops.__file__).resolve().parents[1]
 
 
 @pytest.fixture
@@ -63,135 +37,169 @@ def qkv():
     return (mk(1, 256, 4, 32), mk(1, 256, 2, 32), mk(1, 256, 2, 32))
 
 
-# -- kernel_table unit layer ---------------------------------------------
+@pytest.fixture
+def on_backend(monkeypatch):
+    """Let ``jax.default_backend()`` name another backend for a trace."""
+
+    def name(backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        attn_ops._flash_available.cache_clear()
+
+    attn_ops._reset_dispatch_stats()
+    yield name
+    monkeypatch.undo()
+    attn_ops._flash_available.cache_clear()
 
 
-class TestKernelTable:
-    def test_bucketing_rounds_up_pow2(self):
-        assert kernel_table.bucket_pow2(1) == 128
-        assert kernel_table.bucket_pow2(128) == 128
-        assert kernel_table.bucket_pow2(129) == 256
-        assert kernel_table.attention_bucket(2048, 128, True) == \
-            "s2048_d128_causal"
-        assert kernel_table.attention_bucket(1000, 64, False) == \
-            "s1024_d64_full"
-        assert kernel_table.gmm_bucket(300, 128, 256, 4) == \
-            "m512_k128_n256_g4"
+@pytest.fixture
+def flash_calls(monkeypatch):
+    """The blocks ``_flash_on_mesh`` is called with, in place of the kernel."""
+    calls = []
 
-    def test_decide_win_loss_unmeasured(self, table_env):
-        table_env("flash_attention", "s256_d32_causal", 2.0,
-                  blocks={"block_q": 128, "block_k": 128})
-        d = kernel_table.decide("flash_attention", "s256_d32_causal")
-        assert d.measured and d.win and d.ratio == 2.0
-        assert d.blocks == {"block_q": 128, "block_k": 128}
+    def record(q, k, v, causal, segment_ids, block_q, block_k):
+        calls.append((block_q, block_k))
+        return q
 
-        table_env("flash_attention", "s256_d32_causal", 0.5)
-        d = kernel_table.decide("flash_attention", "s256_d32_causal")
-        assert d.measured and not d.win
-
-        d = kernel_table.decide("flash_attention", "s512_d32_causal")
-        assert not d.measured and "unmeasured" in d.reason
-
-    def test_backend_scoped_entries(self, table_env):
-        # a tpu-measured win must NOT drive a cpu run (and vice versa)
-        table_env("flash_attention", "s256_d32_causal", 3.0,
-                  backend="tpu" if jax.default_backend() != "tpu"
-                  else "cpu")
-        d = kernel_table.decide("flash_attention", "s256_d32_causal")
-        assert not d.measured
-        assert "measured on" in d.reason
-
-    def test_committed_table_is_tpu_scoped(self):
-        # the artifact the repo ships must be inert off-TPU: every entry
-        # carries an explicit non-local backend tag (tier-1 runs on CPU)
-        from pathlib import Path
-
-        doc = json.loads(Path(kernel_table.DEFAULT_TABLE).read_text())
-        assert doc["_meta"]["schema"] == kernel_table.SCHEMA
-        entries = [e for buckets in doc["entries"].values()
-                   for e in buckets.values()]
-        assert entries
-        assert all(e["backend"] == "tpu" for e in entries)
-        assert all(e["ratio"] == pytest.approx(
-            e["xla_ms"] / e["kernel_ms"], rel=0.01) for e in entries)
-        # the real-shape train bucket must be present and winning — the
-        # train path runs flash on the 8L/131k-vocab shape via this row
-        real = doc["entries"]["flash_attention"]["s2048_d128_causal"]
-        assert real["ratio"] >= 1.0
-
-    def test_record_roundtrip(self, tmp_path, monkeypatch):
-        path = tmp_path / "t.json"
-        monkeypatch.setenv("DSTPU_KERNEL_TABLE", str(path))
-        kernel_table.invalidate_cache()
-        kernel_table.record("grouped_matmul", "m256_k128_n256_g4",
-                            kernel_ms=2.0, xla_ms=5.0,
-                            blocks={"block_m": 128})
-        d = kernel_table.decide("grouped_matmul", "m256_k128_n256_g4")
-        assert d.measured and d.win and d.ratio == 2.5
-        monkeypatch.delenv("DSTPU_KERNEL_TABLE")
-        kernel_table.invalidate_cache()
-
-    def test_malformed_table_never_raises(self, tmp_path, monkeypatch):
-        path = tmp_path / "bad.json"
-        path.write_text("{not json")
-        monkeypatch.setenv("DSTPU_KERNEL_TABLE", str(path))
-        kernel_table.invalidate_cache()
-        d = kernel_table.decide("flash_attention", "s256_d32_causal")
-        assert not d.measured
-        monkeypatch.delenv("DSTPU_KERNEL_TABLE")
-        kernel_table.invalidate_cache()
+    monkeypatch.setattr(attn_ops, "_flash_on_mesh", record)
+    return calls
 
 
-# -- registry decision layer ---------------------------------------------
+def _trace_auto(seq, head_dim):
+    """Trace ``impl='auto'`` at a shape; nothing is computed."""
+    q = jax.ShapeDtypeStruct((1, seq, 4, head_dim), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, seq, 2, head_dim), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: attn_ops.multi_head_attention(
+        q, k, v, causal=True), q, kv, kv)
 
 
-class TestRegistryDispatch:
-    def test_measured_win_routes_to_kernel(self, table_env):
-        table_env("flash_attention", "s256_d32_causal", 1.8,
-                  blocks={"block_q": 128, "block_k": 128})
-        d = registry.dispatch_op("flash_attention", "s256_d32_causal",
-                                 "xla_attention", default_use=False)
-        assert d.source == "pallas" and d.op_name == "flash_attention"
-        assert d.blocks == {"block_q": 128, "block_k": 128}
-
-    def test_measured_loss_overrides_heuristic(self, table_env):
-        table_env("flash_attention", "s256_d32_causal", 0.6)
-        d = registry.dispatch_op("flash_attention", "s256_d32_causal",
-                                 "xla_attention", default_use=True)
-        assert d.source == "xla" and d.op_name == "xla_attention"
-
-    def test_unmeasured_falls_back_to_heuristic(self, table_env):
-        table_env("flash_attention", "s256_d32_causal", 2.0)
-        for default_use, source in ((True, "pallas"), (False, "xla")):
-            d = registry.dispatch_op("flash_attention", "s999_d32_causal",
-                                     "xla_attention",
-                                     default_use=default_use)
-            assert d.source == source and "heuristic" in d.reason
-
-    def test_unknown_op_raises(self):
-        with pytest.raises(KeyError):
-            registry.dispatch_op("not_an_op", "b", "xla_attention")
+# The first four TPU rows are the rows of the win/loss table this rule
+# replaced (v5e, fwd+bwd, kernel vs XLA): a loss at 512, wins with these
+# blocks at 1024, 2048 and 8192. The table is gone; what it measured is
+# held here as what the rule must answer.
+@pytest.mark.parametrize("backend,seq,head_dim,blocks", [
+    ("tpu", 512, 64, None),
+    ("tpu", 1024, 64, (512, 512)),
+    ("tpu", 2048, 128, (512, 512)),      # the training cells' shape
+    ("tpu", 8192, 128, (1024, 1024)),
+    ("tpu", 2048, 256, (512, 512)),      # the hybrid models' head
+    ("tpu", 4096, 128, (512, 512)),
+    ("tpu", 1023, 64, None),
+    ("cpu", 2048, 128, None),
+])
+def test_auto_route_and_blocks(on_backend, flash_calls, backend, seq,
+                               head_dim, blocks):
+    on_backend(backend)
+    _trace_auto(seq, head_dim)
+    assert flash_calls == ([blocks] if blocks else [])
+    assert attn_ops.dispatch_stats() == {
+        "pallas": int(blocks is not None), "xla": int(blocks is None)}
 
 
-# -- the acceptance-criteria test: dispatch provably consults the table --
+@pytest.mark.parametrize("seq", [1024, 2048, 8192])
+def test_set_flash_blocks_run_as_set(on_backend, flash_calls, seq):
+    from deepspeed_tpu.config.config import KernelsConfig
+
+    on_backend("tpu")
+    attn_ops.set_kernel_config(KernelsConfig(flash_block_q=256,
+                                             flash_block_k=256))
+    try:
+        _trace_auto(seq, 128)
+    finally:
+        attn_ops.set_kernel_config(None)
+    assert flash_calls == [(256, 256)]
+
+
+def test_auto_attention_opens_no_file(monkeypatch, qkv):
+    """The decision comes from the arguments and the backend: no file of
+    the checkout, nor any outside the installation, is opened or
+    stat'ed while ``impl='auto'`` traces and runs."""
+    from deepspeed_tpu.observability import hub  # noqa: F401 (lazy in ops)
+
+    installation = (str(PACKAGE), sys.prefix, sys.base_prefix)
+    touched = []
+
+    def guarded(real):
+        def call(path, *a, **kw):
+            if isinstance(path, (str, bytes, os.PathLike)):
+                full = os.path.abspath(os.fsdecode(path))
+                if not full.startswith(installation):
+                    touched.append(full)
+                    raise PermissionError(full)
+            return real(path, *a, **kw)
+        return call
+
+    q, k, v = qkv
+    with monkeypatch.context() as m:
+        m.setattr(builtins, "open", guarded(builtins.open))
+        m.setattr(Path, "open", guarded(Path.open))
+        m.setattr(os, "stat", guarded(os.stat))
+        out = attn_ops.multi_head_attention(q[:, :192], k[:, :192],
+                                            v[:, :192], causal=True)
+        out = np.asarray(out, np.float32)
+    assert touched == []
+    assert np.isfinite(out).all()
+
+
+def test_package_imports_nothing_above_itself():
+    """``deepspeed_tpu/`` is the lower layer: the benchmarks and the
+    tools import it, never the other way round."""
+    root = PACKAGE.parent
+    above = {"tools", "bench", "benchmarks", "chip_smoke"} | {
+        p.stem for p in (root / "tools").glob("*.py")}
+    found = []
+    for path in sorted(PACKAGE.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and not node.level:
+                names = [node.module or ""]
+            else:
+                continue
+            found += [(str(path.relative_to(PACKAGE)), n) for n in names
+                      if n.split(".")[0] in above]
+    assert found == []
+
+
+# every environment variable of its own that the package names; a new one
+# is an edit here
+DSTPU_ENV = """
+    DSTPU_AIO_BACKEND DSTPU_CACHE_DIR DSTPU_CHAOS DSTPU_CLOCK_SKEW_S
+    DSTPU_DCN_GBPS DSTPU_DISPATCH_AHEAD DSTPU_ELASTIC_RESTART_COUNT
+    DSTPU_ELASTIC_WORLD DSTPU_FETCH_GBPS DSTPU_FLIGHT_DIR
+    DSTPU_FPDT_BISECT DSTPU_GRADS_TO_HOST DSTPU_ICI_GBPS DSTPU_LOG_LEVEL
+    DSTPU_METRICS_JSONL DSTPU_METRICS_PROM DSTPU_NVME_CONFIG
+    DSTPU_OVERLAP_DEPTH DSTPU_PREFETCH DSTPU_PREFETCH_DEPTH
+    DSTPU_QUANT_CHAOS DSTPU_QUANT_STATS DSTPU_REQUEST_TRACE
+    DSTPU_REQ_TRACE_RING DSTPU_REQ_TRACE_SAMPLE DSTPU_REQ_TRACE_SLO_MS
+    DSTPU_ROOFLINE DSTPU_RUN_DIR DSTPU_SERIALIZE_FETCH DSTPU_TRACE_DIR
+    DSTPU_TRACE_STEPS DSTPU_WATCHDOG DSTPU_WATCHDOG_FACTOR
+    DSTPU_WATCHDOG_MIN_S DSTPU_WORLD_INFO
+""".split()
+
+
+def test_dstpu_env_census():
+    named = set()
+    for path in PACKAGE.rglob("*.py"):
+        named.update(re.findall(r"\bDSTPU_[A-Z0-9_]+", path.read_text()))
+    assert len(DSTPU_ENV) == 35
+    assert named == set(DSTPU_ENV)
 
 
 class TestAttentionDispatch:
-    def test_losing_entry_routes_to_xla_bit_identically(self, table_env,
+    def test_under_the_threshold_is_xla_bit_identically(self, on_backend,
                                                         qkv):
         q, k, v = qkv
-        table_env("flash_attention", "s256_d32_causal", 0.4)
-        attn_ops._reset_dispatch_stats()
+        on_backend("tpu")  # the one backend on which a length decides
         out = attn_ops.multi_head_attention(q, k, v, causal=True)
         want = attn_ops.xla_attention(q, k, v, causal=True)
         assert bool(jnp.array_equal(out, want))
         stats = attn_ops.dispatch_stats()
         assert stats["xla"] == 1 and stats["pallas"] == 0
 
-    def test_winning_entry_routes_to_flash(self, table_env, qkv):
+    def test_at_the_threshold_the_kernel_runs(self, monkeypatch, qkv):
+        monkeypatch.setattr(attn_ops, "_flash_available", lambda: True)
+        monkeypatch.setattr(attn_ops, "FLASH_MIN_SEQ", 256)
         q, k, v = qkv
-        table_env("flash_attention", "s256_d32_causal", 2.2,
-                  blocks={"block_q": 128, "block_k": 128})
         attn_ops._reset_dispatch_stats()
         out = attn_ops.multi_head_attention(q, k, v, causal=True)
         stats = attn_ops.dispatch_stats()
@@ -201,54 +209,30 @@ class TestAttentionDispatch:
                                    np.asarray(want, np.float32),
                                    rtol=2e-2, atol=2e-2)
 
-    def test_flip_win_to_loss_flips_route(self, table_env, qkv):
-        # the same bucket, measured twice: win → kernel, loss → XLA.
-        # This is the contract `make bench-kernels` regression-gates.
-        q, k, v = qkv
-        for ratio, source in ((1.5, "pallas"), (0.9, "xla")):
-            table_env("flash_attention", "s256_d32_causal", ratio,
-                      blocks={"block_q": 128, "block_k": 128})
-            attn_ops._reset_dispatch_stats()
-            attn_ops.multi_head_attention(q, k, v, causal=True)
-            assert attn_ops.dispatch_stats()[source] == 1
-
-    def test_heuristic_mode_ignores_table(self, table_env, qkv):
-        from deepspeed_tpu.config.config import KernelsConfig
-
-        q, k, v = qkv
-        table_env("flash_attention", "s256_d32_causal", 9.0)
-        attn_ops.set_kernel_config(KernelsConfig(dispatch="heuristic"))
-        try:
-            attn_ops._reset_dispatch_stats()
-            out = attn_ops.multi_head_attention(q, k, v, causal=True)
-            # seq 256 < FLASH_MIN_SEQ (and CPU): heuristic says XLA even
-            # though the table claims a 9x win
-            if jax.default_backend() != "tpu":
-                assert attn_ops.dispatch_stats()["xla"] == 1
-                want = attn_ops.xla_attention(q, k, v, causal=True)
-                assert bool(jnp.array_equal(out, want))
-        finally:
-            attn_ops.set_kernel_config(None)
-
-    def test_dispatch_exports_hub_metrics(self, table_env, qkv):
+    def test_dispatch_exports_hub_metrics(self, monkeypatch, qkv):
         from deepspeed_tpu.observability.hub import get_hub, reset_hub
 
         q, k, v = qkv
-        table_env("flash_attention", "s256_d32_causal", 0.4)
         reset_hub()
         hub = get_hub()
-        attn_ops._reset_dispatch_stats()
+        events = []
+        monkeypatch.setattr(hub, "record_event",
+                            lambda kind, **f: events.append((kind, f)))
         attn_ops.multi_head_attention(q, k, v, causal=True)
         snap = hub.snapshot()
         assert snap["gauges"]["kernel.attention.pallas"] == 0.0
+        assert events == [("kernel_dispatch", {
+            "region": "attention", "source": "xla",
+            "reason": "no TPU backend"})]
         reset_hub()
 
-    def test_unavailable_kernel_raises_not_downgrades(self, table_env,
-                                                      qkv, monkeypatch):
-        """A bucket routed to the kernel runs the kernel or fails: no
+    def test_unavailable_kernel_raises_not_downgrades(self, qkv,
+                                                      monkeypatch):
+        """A length routed to the kernel runs the kernel or fails: no
         silent downgrade to the O(S^2) XLA path."""
         q, k, v = qkv
-        table_env("flash_attention", "s256_d32_causal", 2.0)
+        monkeypatch.setattr(attn_ops, "_flash_available", lambda: True)
+        monkeypatch.setattr(attn_ops, "FLASH_MIN_SEQ", 256)
         monkeypatch.setitem(
             sys.modules, "deepspeed_tpu.ops.pallas.flash_attention", None)
         with pytest.raises(ImportError):
@@ -317,7 +301,7 @@ class TestKernelsConfig:
 
     @pytest.mark.parametrize("bad", [
         {"flash_block_q": 100}, {"gmm_block_m": 3},
-        {"pages_per_compute_block": 0}, {"dispatch": "nope"},
+        {"pages_per_compute_block": 0}, {"flash_block_k": 3},
     ])
     def test_rejects_bad_geometry(self, bad):
         from deepspeed_tpu.config.config import KernelsConfig
@@ -328,28 +312,29 @@ class TestKernelsConfig:
     def test_config_block_builds_from_dict(self):
         from deepspeed_tpu.config.config import Config
 
+        # "dispatch" went with the table it switched off: a config that
+        # still carries it loads, the key warned about and ignored
         cfg = Config.from_dict({"kernels": {
             "flash_block_q": 256, "flash_block_k": 512,
             "pages_per_compute_block": 4, "dispatch": "heuristic"}})
+        assert not hasattr(cfg.kernels, "dispatch")
         assert cfg.kernels.flash_block_q == 256
         assert cfg.kernels.pages_per_compute_block == 4
 
-    def test_block_precedence_measured_over_config(self):
+    def test_block_precedence_config_over_auto(self):
         from deepspeed_tpu.config.config import KernelsConfig
 
-        attn_ops.set_kernel_config(KernelsConfig(flash_block_q=256,
-                                                 flash_block_k=256))
+        attn_ops.set_kernel_config(KernelsConfig(flash_block_q=256))
         try:
-            # config knobs beat the seq-derived auto...
-            assert attn_ops._pick_blocks(2048, None) == (256, 256)
-            # ...but measured table blocks beat the config knobs
-            assert attn_ops._pick_blocks(
-                2048, {"block_q": 512, "block_k": 1024}) == (512, 1024)
+            # a set block beats the seq-derived one; an unset one (0) is
+            # the seq-derived one; nothing else has a say
+            assert attn_ops._pick_blocks(2048) == (256, 512)
+            assert attn_ops._pick_blocks(8192) == (256, 1024)
         finally:
             attn_ops.set_kernel_config(None)
         # no config installed: seq-derived default
-        assert attn_ops._pick_blocks(256, None) == (256, 256)
-        assert attn_ops._pick_blocks(8192, None) == (1024, 1024)
+        assert attn_ops._pick_blocks(256) == (256, 256)
+        assert attn_ops._pick_blocks(8192) == (1024, 1024)
 
     def test_gmm_tiles_helper(self):
         from deepspeed_tpu.config.config import KernelsConfig

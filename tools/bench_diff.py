@@ -16,9 +16,7 @@ metric and **exits nonzero** when a metric crosses its threshold:
 - ``host_gap_ms``: max ratio 1.5 (noisy on a shared host — loose);
 - quantization gates (``BENCH_QUANT`` payloads): the new round's
   ``ok`` flag must be true and ``value`` (gate violations) must not
-  grow — the quant SNR gates re-checked at diff time;
-- kernel tier (``BENCH_KERNELS`` payloads): every kernel:bucket in the
-  old round's ``winning_kernels`` must still be winning.
+  grow — the quant SNR gates re-checked at diff time.
 
 Rounds with a different metric/unit (the headline changed shape, e.g.
 zero3 train → device fwd+bwd) are *incomparable*: reported, but only a
@@ -79,11 +77,6 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # chaos.zero_drops / chaos.bit_identical certificates must stay
     # true — those are checked unconditionally below, not ratio'd
     "chaos.ttft_p999_ratio": ("max_ratio", 1.5),
-    # kernel tier (BENCH_KERNELS payloads): a kernel that won its bucket
-    # last round must still win (a silent all-XLA regression is exactly
-    # the failure the table-driven dispatch exists to catch), and the
-    # share of flash-worthy dispatches that lost the kernel must not
-    # creep up by more than 10 points
     # observability plane (BENCH_MODE=obs_fleet): the per-request tracer
     # emit-point overhead gets a loose order-of-magnitude leash (tens of
     # µs measured on a shared host — only a blowup is signal), and the
@@ -229,17 +222,6 @@ def diff_reports(old: Dict[str, Any], new: Dict[str, Any],
                 rule, limit = th[key]
                 ratio = nv / ov
                 check(key, rule, limit, ov, nv, ratio, ratio <= limit)
-        # kernel tier sentinels (BENCH_KERNELS payloads): no previously
-        # winning kernel may regress to losing, and the flash fallback
-        # ratio may not silently creep toward all-XLA
-        o_win, n_win = old.get("winning_kernels"), new.get("winning_kernels")
-        if isinstance(o_win, list) and isinstance(n_win, list):
-            regressed = sorted(set(o_win) - set(n_win))
-            check("winning_kernels", "no_regression", 0,
-                  len(o_win), len(n_win), float(len(regressed)),
-                  not regressed)
-            if regressed:
-                violations[-1]["regressed"] = regressed
         # observability-plane sentinels (obs_fleet payloads): tracer
         # overhead trend and the worst clock-offset error
         ov = old.get("obs.trace_overhead_us")
