@@ -429,7 +429,8 @@ def phase_kernels(sz: Sizes, seed: int) -> dict:
             q, k, v, causal=True, block_q=blk4, block_k=blk4))(q4, k4, v4),
         jax.jit(ref)(q4, k4, v4)))
 
-    # -- paged attention: decode (1 and 4 pages a step), chunked prefill ---
+    # -- paged attention: decode (the kernel's own block, then 1 and 4 pages
+    # a block), chunked prefill ------------------------------------------
     seqs, pages = 16, sz.kernel_ctx_pages
     pool = rand((seqs * pages + 1, KV_BLOCK, 2, nkv, hd))
     table = (jax.random.permutation(next(keys), seqs * pages)
@@ -439,13 +440,14 @@ def phase_kernels(sz: Sizes, seed: int) -> dict:
     qd = rand((seqs, nh, hd))
     want = _paged_reference(qd[:, None], pool, table,
                             (ctx_lens - 1)[:, None])[:, 0]
-    for ppb in (1, 4):
+    for ppb, name in ((0, "paged_decode_own_block"),
+                      (1, "paged_decode_pages1"), (4, "paged_decode_pages4")):
         def decode(q, kv, bt, ctx, ppb=ppb):
             return paged_attention.paged_decode_attention(
                 q, kv, bt, ctx, pages_per_compute_block=ppb)
 
         ran_on_device(decode, qd, pool, table, ctx_lens)
-        record(f"paged_decode_pages{ppb}", _rel_err(
+        record(name, _rel_err(
             jax.jit(decode)(qd, pool, table, ctx_lens), want))
 
     for tq in (64, 256):

@@ -1070,14 +1070,17 @@ class KernelsConfig(ConfigModel):
     and autotuner axes now (kernel-geometry axis family — candidates
     are shape-legal divisors only, ``autotuning/autotuner.py``). 0
     means "auto": the kernel's seq-derived default for flash, the
-    measured v5e tiles for the grouped matmul, one page per compute
-    block for paged attention. Which attention kernel runs is not
+    measured v5e tiles for the grouped matmul, the decode kernel's own
+    block for paged attention (``ops/pallas/paged_attention.py``
+    sizes it from the pool's shapes). Which attention kernel runs is not
     set here: ``ops/attention.py`` decides from the backend and the
     sequence length, or ``attn_impl`` names one."""
 
     flash_block_q: int = 0  # 0 = auto (1024 at seq>=8k else min(512, S))
     flash_block_k: int = 0
-    pages_per_compute_block: int = 1  # KV pages folded per paged-attn grid step
+    # 0 = the paged decode kernel chooses its block from the pool's shapes
+    # (the prefill kernel folds one page a step); > 0 sets it, for tests
+    pages_per_compute_block: int = 0
     gmm_block_m: int = 512
     gmm_block_n: int = 1024
     gmm_block_k: int = 512
@@ -1091,10 +1094,10 @@ class KernelsConfig(ConfigModel):
                 raise ValueError(
                     f"kernels.{name} must be 0 (auto) or a power of "
                     f"two, got {v}")
-        if self.pages_per_compute_block < 1:
+        if self.pages_per_compute_block < 0:
             raise ValueError(
-                f"kernels.pages_per_compute_block must be >= 1, got "
-                f"{self.pages_per_compute_block}")
+                f"kernels.pages_per_compute_block must be 0 (the kernel "
+                f"chooses) or positive, got {self.pages_per_compute_block}")
 
 
 @register_config_model

@@ -56,14 +56,14 @@ def _kv_bits(kv_layer):
 
 
 def _kernel_pages() -> int:
-    """``kernels.pages_per_compute_block`` from the installed kernel
-    config (ops.attention.set_kernel_config), resolved at trace time —
-    same contract as the DSTPU_* env-at-construction knobs."""
+    """``kernels.pages_per_compute_block`` where a kernel config is
+    installed (ops.attention.set_kernel_config: a training engine does,
+    a serving engine does not), resolved at trace time; 0, and nothing
+    installed, leave the block to the kernel."""
     from deepspeed_tpu.ops import attention as attn_ops
 
-    kcfg = attn_ops._KERNEL_CONFIG
-    return int(getattr(kcfg, "pages_per_compute_block", 1) or 1) \
-        if kcfg is not None else 1
+    return int(getattr(attn_ops._KERNEL_CONFIG, "pages_per_compute_block",
+                       0) or 0)
 
 
 @jax.named_scope("kv_write")

@@ -5,27 +5,41 @@ Reference: the ragged inference ops in
 reading K/V directly from paged cache blocks via a block table, so decode
 never materializes a per-token contiguous context.
 
-TPU re-design: one Pallas kernel per sequence walks that sequence's pages
-(innermost grid dim) with the block table as a scalar-prefetch operand —
-the page id feeds the BlockSpec index_map, so the next page's DMA is
-issued ahead of the body (the TPU analog of the reference's async-copy
-pipeline). Online-softmax accumulation over pages in fp32 scratch; GQA
-handled by grouping query heads per kv head (static in-kernel loop, since
-Mosaic block shapes cannot tile the kv-head axis independently).
+TPU re-design, decode: one grid step per sequence; the pool stays in
+HBM and the kernel walks the sequence's own pages in a loop whose trip
+count is ``ceil(context / block_tokens)``, fetching a block of pages a
+step by ``make_async_copy`` from the page ids in the scalar-prefetched
+block table, double-buffered: block n+1, or the next sequence's first
+block, is in flight while block n is multiplied (the TPU analog of the
+reference's async-copy pipeline). Nothing past the context is visited.
+A block's K (and V) of a sublane tile of KV heads is *one* MXU operand
+``[tokens * heads, head_dim]``, the (token, head) rows exactly as they
+lie in the pool, multiplied with those heads' query rows in one
+product; a mask keeps each query row to its own head's columns, so the
+score tile is lane-full and no row is moved between the fetch and the
+MXU. Operands enter the MXU in the pool's dtype; scores, running max,
+denominator and accumulator are float32 (online softmax across
+blocks). The block's size is the kernel's own choice from the shapes
+(``_decode_block_pages``). Pools Mosaic's DMA cannot slice
+(``_pages_sliceable``) and the prefill kernel keep the older walk: the
+grid's innermost dim steps over block-table entries, the page id feeds
+the BlockSpec index_map, and pages fold one at a time, one KV head at a
+time (static in-kernel loop).
 
 Layout matches inference/ragged/kv_cache.py: the pool is
 ``kv[L, num_blocks, block_size, 2, kv_heads, head_dim]`` and the kernels
 take it whole, with the layer as one more scalar-prefetch operand: the
-index map picks ``(layer, page)``, so a step program never slices a
+fetch picks ``(layer, page)``, so a step program never slices a
 layer out of the pool (a 130 MB copy per layer at mistral-7b width).
-One page is fetched per grid step; the kernel reads K from plane 0 and V
-from plane 1 of the same block. A 5-D one-layer pool is still accepted
-(it is viewed as ``kv[None]``, layer 0).
+The kernel reads K from plane 0 and V from plane 1 of the same page. A
+5-D one-layer pool is still accepted (it is viewed as ``kv[None]``,
+layer 0).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -78,12 +92,13 @@ def _visit(q_ref, kv_ref, m_ref, l_ref, acc_ref, visible, *, bs: int,
                    slice(n * gp, (n + 1) * gp), gp)
 
 
-def _kernel(bt_ref, ctx_ref, layer_ref, q_ref, *refs, bs: int, nkv: int,
-            gp: int, scale: float, pages: int):
-    # refs = pages kv page blocks, then out_ref + 3 scratch refs. The
-    # pages fold sequentially in ascending page order — the identical
-    # op sequence for every pages_per_compute_block, so outputs stay
-    # bit-identical across the autotuner's geometry candidates.
+def _grid_walk_kernel(bt_ref, ctx_ref, layer_ref, q_ref, *refs, bs: int,
+                      nkv: int, gp: int, scale: float, pages: int):
+    """Decode over a pool whose pages a DMA cannot address
+    (:func:`_pages_sliceable`): the grid walks every entry of the block
+    table, ``pages`` of them a step, each page its own pipelined block
+    and folded on its own, one KV head at a time."""
+    # refs = pages kv page blocks, then out_ref + 3 scratch refs
     kv_refs = refs[:pages]
     out_ref, m_ref, l_ref, acc_ref = refs[pages:]
     s = pl.program_id(0)
@@ -116,6 +131,110 @@ def _kernel(bt_ref, ctx_ref, layer_ref, q_ref, *refs, bs: int, nkv: int,
             l = l_ref[rows, :1]
             l = jax.lax.select(l == 0.0, jnp.ones_like(l), l)  # dead slots
             out_ref[0, n] = (acc_ref[rows, :] / l).astype(out_ref.dtype)
+
+
+def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
+                   buf, sem, slot_ref, *, bs: int, nb: int, c: int, g: int,
+                   nchunks: int, pages: int, scale: float):
+    """One sequence a grid step: walk its own pages, ``pages`` of them a
+    block, block n+1 (or the next sequence's first) in flight while block
+    n is multiplied. ``buf`` is [2, pages, bs, 2, nkv, hd]; a chunk of
+    ``c`` KV heads of a block is one [pages*bs*c, hd] operand, its rows
+    (token, head) pairs in the pool's own order, so nothing is moved
+    between the fetch and the MXU: the product also multiplies each query
+    row with the other heads' keys, and the head mask drops those."""
+    s = pl.program_id(0)
+    S = pl.num_programs(0)
+    T = pages * bs                      # tokens a block
+    N = T * c                           # (token, head) columns a chunk
+    rp = q_ref.shape[2]
+    layer = layer_ref[0]
+
+    def copies(seq, blk, slot, fn):
+        """Apply ``fn`` to the copy of every page of block ``blk`` of
+        sequence ``seq`` that holds context: entries of the block table
+        past the context are never read, let alone fetched. (A loop and
+        not an unrolled run of guarded copies: the kernel is traced for
+        every burst length, and a step's set-up is made of that.)"""
+        seq_c = jax.lax.min(seq, S - 1)
+        ctx = jnp.where(seq < S, ctx_ref[seq_c], 0)
+        live = jnp.clip((ctx - blk * T + bs - 1) // bs, 0, pages)
+
+        def page_copy(i, carry):
+            page = bt_ref[seq_c, blk * pages + i]
+            page = jax.lax.min(jax.lax.max(page, 0), nb - 1)
+            fn(pltpu.make_async_copy(kv_hbm.at[layer, page],
+                                     buf.at[slot, i], sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, live, page_copy, 0)
+
+    def start(seq, blk, slot):
+        copies(seq, blk, slot, lambda dma: dma.start())
+
+    @pl.when(s == 0)
+    def _first():
+        # rows a fetch never lands on still meet a zero weight in the
+        # value product: they have to be finite
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        start(s, 0, 0)
+
+    ctx = ctx_ref[s]
+    nblk = (ctx + T - 1) // T
+    slot0 = slot_ref[0]
+    slot_ref[0] = (slot0 + nblk) % 2    # where the next sequence starts
+
+    @pl.when(nblk == 0)
+    def _dead():
+        start(s + 1, 0, slot0)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (rp, N), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rp, N), 0)
+    tok = col // c                      # c is a power of two
+    own = (col % c) == (row // g)       # the column's head is the row's
+    dt = jnp.promote_types(q_ref.dtype, buf.dtype)   # what the MXU takes
+
+    def block(blk, carry):
+        slot = (slot0 + blk) % 2
+
+        # the next block, or the next sequence's first, flies meanwhile
+        last = blk + 1 == nblk
+        start(jnp.where(last, s + 1, s), jnp.where(last, 0, blk + 1),
+              1 - slot)
+        copies(s, blk, slot, lambda dma: dma.wait())
+        visible = jnp.logical_and(own, tok < ctx - blk * T)
+        out = []
+        for n, (m_prev, l_prev, acc) in enumerate(carry):
+            heads = slice(n * c, (n + 1) * c)
+            k = buf[slot, :, :, 0, heads, :].reshape(N, -1)
+            v = buf[slot, :, :, 1, heads, :].reshape(N, -1)
+            sc = jax.lax.dot_general(
+                q_ref[0, n].astype(dt), k.astype(dt),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(visible, sc, NEG_INF)
+            # every live row sees a key in every block it walks, so its
+            # running max is finite and exp(NEG_INF - m) is an exact 0
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(dt), v.astype(dt), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            out.append((m_new, l_new, acc))
+        return tuple(out)
+
+    hd = out_ref.shape[-1]
+    init = tuple((jnp.full((rp, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((rp, 1), jnp.float32),
+                  jnp.zeros((rp, hd), jnp.float32))
+                 for _ in range(nchunks))
+    final = jax.lax.fori_loop(0, nblk, block, init)
+    for n, (_, l, acc) in enumerate(final):
+        l = jnp.where(l == 0.0, 1.0, l)   # dead slots, padded rows
+        out_ref[0, n] = (acc / l).astype(out_ref.dtype)
 
 
 def _prefill_kernel(pos0_ref, ctx_ref, bt_ref, layer_ref, q_ref, *refs,
@@ -213,8 +332,8 @@ def paged_prefill_attention(q: jax.Array, kv: jax.Array,
 
     ``pages_per_compute_block`` (kernels config / autotuner axis) folds
     that many KV pages per grid step — fewer grid steps, more DMA in
-    flight per step. Outputs are bit-identical for every legal value
-    (pages fold in the same sequential order).
+    flight per step; 0 or ``None`` is 1. Outputs are bit-identical for
+    every legal value (pages fold in the same sequential order).
 
     Returns [S, Tq, num_heads, head_dim] in q.dtype.
     """
@@ -236,7 +355,7 @@ def paged_prefill_attention(q: jax.Array, kv: jax.Array,
           .transpose(0, 2, 1, 3, 4)
           .reshape(S, nkv, tq * g, hd))
 
-    P = max(1, min(int(pages_per_compute_block), Bm))
+    P = max(1, min(int(pages_per_compute_block or 1), Bm))
 
     def kv_spec(i):
         return pl.BlockSpec(
@@ -273,10 +392,46 @@ def paged_prefill_attention(q: jax.Array, kv: jax.Array,
             .reshape(S, tq, nh, hd))
 
 
+# The decode kernel's block, measured on a v5e at the serving cells'
+# shapes, the multi-head preset's and a tp=2 shard's (PERF.md section 6,
+# PR 31). A block costs about a microsecond whatever it holds, so it
+# should hold bytes: 1 MiB a fetch. But a context pays for the whole of
+# its last block's products, so no more than 256 tokens (contexts of a
+# few hundred tokens read 10-20% slower at 512). Two buffers live in
+# VMEM beside the score tile.
+_BLOCK_BYTES = 1024 * 1024
+_BLOCK_TOKENS = 256
+_VMEM_BUFFER_BYTES = 4 * 1024 * 1024
+
+
+def _decode_block_pages(bs: int, nkv: int, hd: int, itemsize: int,
+                        max_pages: int, c: int) -> int:
+    """Pages the decode kernel folds a block, from the shapes alone:
+    ``_BLOCK_BYTES`` of them but no more than ``_BLOCK_TOKENS`` tokens,
+    at least enough (token, head) columns to fill the score tile's 128
+    lanes, never more than a VMEM buffer holds or the block table has."""
+    page_bytes = bs * 2 * nkv * hd * itemsize
+    pages = min(_BLOCK_BYTES // page_bytes, _BLOCK_TOKENS // bs)
+    pages = max(pages, -(-128 // (bs * c)))
+    pages = min(pages, _VMEM_BUFFER_BYTES // page_bytes)
+    return max(1, min(pages, max_pages))
+
+
+def _pages_sliceable(nkv: int, hd: int, itemsize: int) -> bool:
+    """Whether a DMA can take one page ``[bs, 2, nkv, hd]`` out of the
+    pool: Mosaic slices a memory reference only where its last two
+    dims fill whole tiles, so ``hd`` has to be a multiple of the 128
+    lanes and ``nkv`` a multiple of 8 sublanes, or a power of two no
+    smaller than the values a 32-bit sublane packs (2 for bf16)."""
+    packing = max(1, 4 // itemsize)
+    whole = nkv % 8 == 0 or (nkv & (nkv - 1) == 0 and nkv >= packing)
+    return hd % 128 == 0 and whole
+
+
 def paged_decode_attention(q: jax.Array, kv: jax.Array,
                            block_table: jax.Array, context_lens: jax.Array,
                            scale: float = None,
-                           pages_per_compute_block: int = 1,
+                           pages_per_compute_block: int = None,
                            layer=None) -> jax.Array:
     """Decode attention over a paged KV pool.
 
@@ -285,13 +440,21 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array,
                  at ``layer`` (a traced int32 scalar); or one layer's
                  5-D pool with ``layer`` left out
     block_table  [S, max_pages] int32 page ids (entries past the context
-                 may be stale/scratch; they are read but masked)
+                 may be stale, scratch or out of range: never fetched)
     context_lens [S] int32 — keys visible per sequence (including the
                  token written this step); 0 marks a dead slot (output 0)
 
-    ``pages_per_compute_block`` folds that many KV pages per grid step
-    (kernels config / autotuner axis); bit-identical for every legal
-    value — the pages fold in the same sequential order.
+    The pool stays in HBM; the kernel fetches each sequence's own pages,
+    a block of them at a time, and stops at the sequence's last page.
+    ``pages_per_compute_block`` 0 or ``None`` (the default) leaves the
+    block to the kernel (:func:`_decode_block_pages`); a positive value
+    sets it, for tests and the chip smoke. Outputs agree across blocks
+    to float32 rounding, not bit for bit: a block is one product.
+
+    A pool whose pages a DMA cannot address (:func:`_pages_sliceable`:
+    ``head_dim`` 64, one bf16 KV head, twelve) keeps the walk over the
+    whole block table, one pipelined page and one KV head at a time;
+    there 0 or ``None`` is one page a grid step.
 
     Returns [S, num_heads, head_dim] in q.dtype.
     """
@@ -302,44 +465,77 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array,
     if nh % nkv:
         raise ValueError(f"num_heads {nh} not a multiple of kv_heads {nkv}")
     g = nh // nkv
-    gp = max(8, -(-g // 8) * 8)  # pad head group to the fp32 sublane tile
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
+    # (the interpreter has no tiles: every pool walks its own pages there)
+    own_walk = _interpret() or _pages_sliceable(nkv, hd, kv.dtype.itemsize)
 
-    qg = q.reshape(S, nkv, g, hd)
-    if gp != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    # KV heads multiplied together in one product: a whole sublane tile
+    # of them where the pool has one, so the (token, head) rows of a
+    # block go to the MXU as they lie
+    c = math.gcd(nkv, 8) if own_walk else 1
+    nchunks = nkv // c
+    rows = c * g
+    rp = -(-rows // 8) * 8              # pad to the fp32 sublane tile
+    qg = q.reshape(S, nchunks, rows, hd)
+    if rp != rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
 
-    P = max(1, min(int(pages_per_compute_block), Bm))
+    if pages_per_compute_block:
+        P = max(1, min(int(pages_per_compute_block), Bm))
+    elif own_walk:
+        P = _decode_block_pages(bs, nkv, hd, kv.dtype.itemsize, Bm, c)
+    else:
+        P = 1
 
-    def kv_spec(i):
-        return pl.BlockSpec(
-            (1, 1, bs, 2, nkv, hd),
-            lambda s, j, bt, ctx, lyr: (
-                lyr[0], _page_id(bt, ctx, s, j * P + i, bs, nb), 0, 0, 0, 0))
+    def rows_of(s, *_):                 # a sequence's query / output rows
+        return (s, 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, -(-Bm // P)),
-        in_specs=[
-            pl.BlockSpec((1, nkv, gp, hd),
-                         lambda s, j, bt, ctx, lyr: (s, 0, 0, 0)),
-        ] + [kv_spec(i) for i in range(P)],
-        out_specs=pl.BlockSpec((1, nkv, gp, hd),
-                               lambda s, j, bt, ctx, lyr: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nkv * gp, 128), jnp.float32),  # running max
-            pltpu.VMEM((nkv * gp, 128), jnp.float32),  # running denom
-            pltpu.VMEM((nkv * gp, hd), jnp.float32),   # weighted-value acc
-        ],
-    )
+    if own_walk:
+        kernel = functools.partial(_decode_kernel, bs=bs, nb=nb, c=c, g=g,
+                                   nchunks=nchunks, pages=P,
+                                   scale=float(scale))
+        grid = (S,)
+        kv_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        scratch = [
+            pltpu.VMEM((2, P, bs, 2, nkv, hd), kv.dtype),  # two blocks
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),    # buffer of the next block
+        ]
+    else:
+        def kv_spec(i):
+            return pl.BlockSpec(
+                (1, 1, bs, 2, nkv, hd),
+                lambda s, j, bt, ctx, lyr: (
+                    lyr[0], _page_id(bt, ctx, s, j * P + i, bs, nb),
+                    0, 0, 0, 0))
+
+        kernel = functools.partial(_grid_walk_kernel, bs=bs, nkv=nkv, gp=rp,
+                                   scale=float(scale), pages=P)
+        grid = (S, -(-Bm // P))
+        kv_specs = [kv_spec(i) for i in range(P)]
+        scratch = [
+            pltpu.VMEM((nkv * rp, 128), jnp.float32),  # running max
+            pltpu.VMEM((nkv * rp, 128), jnp.float32),  # running denom
+            pltpu.VMEM((nkv * rp, hd), jnp.float32),   # weighted-value acc
+        ]
+
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, nkv=nkv, gp=gp,
-                          scale=float(scale), pages=P),
+        kernel,
         name="paged_decode",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nkv, gp, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[pl.BlockSpec((1, nchunks, rp, hd), rows_of)] + kv_specs,
+            out_specs=pl.BlockSpec((1, nchunks, rp, hd), rows_of),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((S, nchunks, rp, hd), q.dtype),
+        # a step starts the next sequence's first fetch, or carries the
+        # running state on: in order, on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid)),
         interpret=_interpret(),
-    )(block_table.astype(jnp.int32), context_lens.astype(jnp.int32),
-      layer, qg, *([kv] * P))
-    return out[:, :, :g, :].reshape(S, nh, hd)
+    )(block_table.astype(jnp.int32),
+      jnp.minimum(context_lens.astype(jnp.int32), Bm * bs), layer, qg,
+      *([kv] * len(kv_specs)))
+    return out[:, :, :rows, :].reshape(S, nh, hd)

@@ -299,9 +299,33 @@ class TestKernelsConfig:
 
         KernelsConfig().validate()
 
+    def test_zero_pages_is_the_kernels_choice(self, monkeypatch):
+        """0 is the default and validates; the decode kernel then sizes
+        its block from the pool's shapes, as it does with nothing set."""
+        from deepspeed_tpu.config.config import KernelsConfig
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+        assert KernelsConfig().pages_per_compute_block == 0
+        KernelsConfig(pages_per_compute_block=0).validate()
+        rng = np.random.default_rng(0)
+        kv = jnp.asarray(rng.standard_normal((9, 16, 2, 2, 128)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((2, 4, 128)), jnp.float32)
+        table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+        ctx = jnp.asarray([64, 37], jnp.int32)
+        seen, rule = [], pa._decode_block_pages
+        monkeypatch.setattr(pa, "_decode_block_pages",
+                            lambda *a: seen.append(a) or rule(*a))
+        zero = pa.paged_decode_attention(q, kv, table, ctx,
+                                         pages_per_compute_block=0)
+        unset = pa.paged_decode_attention(q, kv, table, ctx)
+        pa.paged_decode_attention(q, kv, table, ctx,
+                                  pages_per_compute_block=2)
+        assert len(seen) == 2          # asked for 0 and None, not for 2
+        np.testing.assert_array_equal(np.asarray(zero), np.asarray(unset))
+
     @pytest.mark.parametrize("bad", [
         {"flash_block_q": 100}, {"gmm_block_m": 3},
-        {"pages_per_compute_block": 0}, {"flash_block_k": 3},
+        {"pages_per_compute_block": -1}, {"flash_block_k": 3},
     ])
     def test_rejects_bad_geometry(self, bad):
         from deepspeed_tpu.config.config import KernelsConfig

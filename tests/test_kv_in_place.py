@@ -141,7 +141,7 @@ def _pool_case(seed=0):
     return rng, kv, table
 
 
-@pytest.mark.parametrize("pages", [1, 2, 4])
+@pytest.mark.parametrize("pages", [0, 1, 2, 4])
 def test_decode_kernel_on_the_pool_equals_the_layer_slice(pages):
     rng, kv, table = _pool_case()
     q = jnp.asarray(rng.standard_normal((S, NH, HD)), jnp.float32)
@@ -183,7 +183,8 @@ def test_a_pool_needs_its_layer_and_a_slice_takes_none():
 @pytest.mark.parametrize("kernel", ["decode", "prefill"])
 def test_kernel_on_the_pool_under_tp2_shard_map(devices, kernel):
     """q sharded on heads, the pool on KV heads (its fifth axis now), the
-    layer replicated: each head's result is what one device computes."""
+    layer replicated: each head's result is what one device computes (to
+    the last float32 bit or two for the decode kernel)."""
     from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
 
     mesh = build_mesh(TopologyConfig(dp=4, tp=2))
@@ -204,7 +205,13 @@ def test_kernel_on_the_pool_under_tp2_shard_map(devices, kernel):
                 q, kv, layer)
         want = jax.jit(lambda q, kv, l: fn(None, q, kv, l, *meta))(
             q, kv, layer)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        if kernel == "decode":
+            # a shard multiplies its one KV head alone, one device both
+            # together in one product: float32 sums grouped otherwise
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=0, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- numerics: the pool after N steps ----------------------------------------

@@ -38,26 +38,130 @@ def _build_case(rng, S, nh, nkv, hd, bs, Bm, ctx_lens):
     return q, kv, table
 
 
-@pytest.mark.parametrize("nh,nkv", [(8, 8), (8, 2), (16, 1)])
-def test_matches_dense_reference(nh, nkv):
-    rng = np.random.default_rng(0)
-    S, hd, bs, Bm = 3, 64, 16, 4
-    ctx = np.array([1, 17, 64], np.int32)  # partial page, cross-page, full
-    q, kv, table = _build_case(rng, S, nh, nkv, hd, bs, Bm, ctx)
+def _want(q, kv, table, ctx, bs):
+    """Dense float32 reference over a 5-D pool, one sequence at a time;
+    a dead slot's rows are zero."""
+    out = np.zeros(q.shape, np.float32)
+    for s in range(q.shape[0]):
+        if ctx[s] == 0:
+            continue
+        rows = [kv[table[s, t // bs], t % bs] for t in range(ctx[s])]
+        out[s] = _dense_reference(q[s], np.stack([r[0] for r in rows]),
+                                  np.stack([r[1] for r in rows]))
+    return out
 
+
+# nh, nkv, hd, bs, Bm, fold (None: the kernel's own choice). Between them:
+# groups 1 (padded to the sublane tile), 4, 8 and 71; head sizes 64, 128,
+# 256; 1, 2, 8 and 32 KV heads (32: four products a block); blocks of 16
+# and 32 tokens
+SHAPES = [
+    (8, 8, 128, 16, 8, None),      # multi-head: group 1
+    (32, 8, 128, 16, 8, 2),        # group 4, eight heads one product
+    (16, 2, 256, 16, 8, None),     # group 8, head 256
+    (71, 1, 128, 16, 6, 3),        # multi-query: 71 rows padded to 72
+    (8, 2, 64, 16, 8, None),       # head 64
+    (16, 2, 128, 32, 4, 1),        # 32-token pages, a block of one page
+    (32, 32, 128, 16, 4, None),    # 32 KV heads: four chunks of eight
+]
+
+
+def _ragged(bs, Bm, fold):
+    """Contexts that end at 1 token, mid-page, on a page border, on a
+    block border, at the ceiling; a dead slot between live ones."""
+    block = (fold or 2) * bs
+    return np.array([1, bs + 5, 0, 2 * bs, min(block, Bm * bs), Bm * bs],
+                    np.int32)
+
+
+@pytest.mark.parametrize("nh,nkv,hd,bs,Bm,fold", SHAPES)
+def test_matches_dense_reference(nh, nkv, hd, bs, Bm, fold):
+    rng = np.random.default_rng(0)
+    ctx = _ragged(bs, Bm, fold)
+    q, kv, table = _build_case(rng, len(ctx), nh, nkv, hd, bs, Bm, ctx)
     out = np.asarray(paged_decode_attention(
         jnp.asarray(q), jnp.asarray(kv), jnp.asarray(table),
-        jnp.asarray(ctx)))
+        jnp.asarray(ctx), pages_per_compute_block=fold))
+    np.testing.assert_allclose(out, _want(q, kv, table, ctx, bs),
+                               rtol=2e-5, atol=2e-5)
+    assert np.all(out[2] == 0.0)
 
-    for s in range(S):
-        rows = []
-        for t in range(ctx[s]):
-            page, off = table[s, t // bs], t % bs
-            rows.append(kv[page, off])
-        keys = np.stack([r[0] for r in rows])
-        values = np.stack([r[1] for r in rows])
-        want = _dense_reference(q[s], keys, values)
-        np.testing.assert_allclose(out[s], want, rtol=2e-5, atol=2e-5)
+
+@pytest.mark.parametrize("nh,nkv,hd,bs,Bm,fold", SHAPES[:4])
+def test_nothing_past_the_context_is_seen(nh, nkv, hd, bs, Bm, fold):
+    """Large finite garbage in the rows of the last page past the context
+    and in pages no sequence owns, out-of-range ids in block-table entries
+    past the context: the output does not move by a bit."""
+    rng = np.random.default_rng(5)
+    ctx = _ragged(bs, Bm, fold)
+    q, kv, table = _build_case(rng, len(ctx), nh, nkv, hd, bs, Bm, ctx)
+    clean = np.asarray(paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(table),
+        jnp.asarray(ctx), pages_per_compute_block=fold))
+    dirty_kv, dirty_table = kv.copy(), table.copy()
+    for s, n in enumerate(ctx):
+        live = -(-n // bs)
+        if n % bs:
+            dirty_kv[table[s, live - 1], n % bs:] = 1e20
+        dirty_table[s, live:] = rng.choice([-7, 10 ** 6, 2 ** 31 - 1],
+                                           Bm - live)
+    dirty_kv[0] = -1e20                      # the decoy page
+    dirty = np.asarray(paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(dirty_kv), jnp.asarray(dirty_table),
+        jnp.asarray(ctx), pages_per_compute_block=fold))
+    np.testing.assert_array_equal(dirty, clean)
+
+
+def test_pool_at_a_traced_layer_equals_the_one_layer_pool():
+    rng = np.random.default_rng(6)
+    nh, nkv, hd, bs, Bm = 16, 2, 128, 16, 6
+    ctx = _ragged(bs, Bm, None)
+    q, kv0, table = _build_case(rng, len(ctx), nh, nkv, hd, bs, Bm, ctx)
+    pool = jnp.asarray(np.stack([kv0 + 1.0, kv0, kv0 - 1.0]))   # [3, ...]
+    on_pool = jax.jit(lambda l: paged_decode_attention(
+        jnp.asarray(q), pool, jnp.asarray(table), jnp.asarray(ctx), layer=l))
+    for l in range(3):
+        want = paged_decode_attention(jnp.asarray(q), pool[l],
+                                      jnp.asarray(table), jnp.asarray(ctx))
+        np.testing.assert_array_equal(
+            np.asarray(on_pool(jnp.asarray(l, jnp.int32))), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(on_pool(jnp.asarray(1, jnp.int32))),
+                               _want(q, kv0, table, ctx, bs),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_a_pool_a_dma_cannot_slice_keeps_the_grid_walk(monkeypatch):
+    """Head size 64, one bf16 KV head, twelve heads: Mosaic cannot slice
+    such a page out of the pool, and the kernel walks the block table
+    through pipelined blocks instead. The interpreter would take the
+    new walk for any pool, so the choice is steered here."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    assert pa._pages_sliceable(8, 128, 2) and pa._pages_sliceable(2, 256, 2)
+    assert pa._pages_sliceable(4, 128, 2) and pa._pages_sliceable(32, 128, 2)
+    assert pa._pages_sliceable(1, 128, 4) and pa._pages_sliceable(24, 128, 2)
+    assert not pa._pages_sliceable(8, 64, 2)      # half a lane tile
+    assert not pa._pages_sliceable(1, 128, 2)     # half a packed sublane
+    assert not pa._pages_sliceable(12, 128, 2)    # a tile and a half
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    rng = np.random.default_rng(7)
+    nh, nkv, hd, bs, Bm = 12, 12, 64, 16, 4
+    ctx = _ragged(bs, Bm, None)
+    q, kv, table = _build_case(rng, len(ctx), nh, nkv, hd, bs, Bm, ctx)
+    calls = []
+    real = pa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        calls.append(kernel.func.__name__)
+        return real(kernel, **{**kw, "interpret": True})
+
+    monkeypatch.setattr(pa.pl, "pallas_call", spy)
+    out = np.asarray(pa.paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(table),
+        jnp.asarray(ctx)))
+    assert calls == ["_grid_walk_kernel"]
+    np.testing.assert_allclose(out, _want(q, kv, table, ctx, bs),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_dead_slot_outputs_zero():
@@ -70,6 +174,21 @@ def test_dead_slot_outputs_zero():
         jnp.asarray(ctx)))
     assert np.all(out[1] == 0.0)
     assert np.all(np.isfinite(out))
+
+
+def test_block_rule_follows_bytes_lanes_and_vmem():
+    """The block is the kernel's choice from the shapes: about 1 MiB a
+    fetch, no more than 256 tokens, lane-full, inside a VMEM buffer."""
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        _decode_block_pages as pages
+
+    assert pages(16, 8, 128, 2, 64, 8) == 16      # mistral: 1 MiB, 256 tokens
+    assert pages(16, 2, 256, 2, 64, 2) == 16      # a quarter the bytes: tokens cap
+    assert pages(16, 32, 128, 2, 64, 8) == 4      # 256 KiB pages: bytes cap
+    assert pages(16, 8, 128, 2, 4, 8) == 4        # a short block table
+    assert pages(128, 8, 128, 2, 8, 8) == 2       # a page of 128 tokens
+    assert pages(16, 1, 128, 4, 64, 1) == 16      # one head: 8 pages fill the lanes
+    assert pages(128, 64, 256, 2, 8, 8) == 1      # an 8 MiB page: one, whatever
 
 
 class TestPrefill:
@@ -132,14 +251,21 @@ class TestPrefill:
                                     jnp.ones(1, jnp.int32))
 
 
-def test_bf16_and_jit_stability():
+@pytest.mark.parametrize("nh,nkv,hd", [(12, 4, 64), (32, 8, 128),
+                                       (16, 2, 256)])
+def test_bf16_and_jit_stability(nh, nkv, hd):
     rng = np.random.default_rng(2)
-    S, nh, nkv, hd, bs, Bm = 4, 12, 4, 64, 16, 8
+    S, bs, Bm = 4, 16, 8
     ctx = np.array([3, 40, 128, 77], np.int32)
     q, kv, table = _build_case(rng, S, nh, nkv, hd, bs, Bm, ctx)
-    out = paged_decode_attention(
-        jnp.asarray(q, jnp.bfloat16), jnp.asarray(kv, jnp.bfloat16),
-        jnp.asarray(table), jnp.asarray(ctx))
+    qb, kvb = jnp.asarray(q, jnp.bfloat16), jnp.asarray(kv, jnp.bfloat16)
+    out = jax.jit(paged_decode_attention)(qb, kvb, jnp.asarray(table),
+                                          jnp.asarray(ctx))
     assert out.dtype == jnp.bfloat16
     assert out.shape == (S, nh, hd)
-    assert np.all(np.isfinite(np.asarray(out, np.float32)))
+    # bf16 operands, float32 scores and accumulator, p rounded to bf16
+    # for the value product: a few bf16 ulps of the values' magnitude
+    want = _want(np.asarray(qb, np.float32), np.asarray(kvb, np.float32),
+                 table, ctx, bs)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                               rtol=0, atol=0.03)

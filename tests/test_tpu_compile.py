@@ -131,16 +131,74 @@ def test_flash_on_a_four_chip_mesh(chip):
 _POOL = ((512, 16, 2, HKV, D), BF16)
 
 
-@pytest.mark.parametrize("pages", [1, 4])
-def test_paged_decode(chip, pages):
-    seqs, max_pages = 16, 64
+# S, heads, KV heads, head size, layers of the pool, blocks in it
+_DECODE_SHAPES = {
+    "mistral-7b-serve-c1": (32, HQ, HKV, D, 16, 2080),
+    "qwen3-next-serve-c1": (32, 16, 2, 256, 2, 2080),
+    "multi-head-256KiB-pages": (32, 32, 32, D, 2, 512),
+    "tp2-shard-of-mistral": (32, HQ // 2, HKV // 2, D, 16, 2080),
+}
 
-    def f(q, kv, bt, ctx):
+
+@pytest.mark.parametrize("pages", [0, 1, 4])
+@pytest.mark.parametrize("shape", sorted(_DECODE_SHAPES))
+def test_paged_decode(chip, shape, pages):
+    """The decode kernel at the cells' shapes (32 sequences, 64-entry
+    block tables, 16-token pages), at the multi-head preset's 256 KiB
+    pages (where the VMEM bound sizes the block) and at what a tp=2
+    shard sees, on the whole pool at a traced layer; with the kernel's
+    own block (0) and with explicit folds."""
+    seqs, nh, nkv, hd, layers, blocks = _DECODE_SHAPES[shape]
+
+    def f(q, kv, bt, ctx, layer):
         return paged_attention.paged_decode_attention(
-            q, kv, bt, ctx, pages_per_compute_block=pages)
+            q, kv, bt, ctx, pages_per_compute_block=pages, layer=layer)
 
-    _compile(chip, f, ((seqs, HQ, D), BF16), _POOL,
-             ((seqs, max_pages), jnp.int32), ((seqs,), jnp.int32))
+    text = _compile(chip, f, ((seqs, nh, hd), BF16),
+                    ((layers, blocks, 16, 2, nkv, hd), BF16),
+                    ((seqs, 64), jnp.int32), ((seqs,), jnp.int32),
+                    ((), jnp.int32))
+    # the pool goes in whole: no layer of it is sliced out or laid out anew
+    assert f"bf16[{blocks},16,2,{nkv},{hd}]" not in text
+
+
+@pytest.mark.parametrize("nkv,hd", [(1, 64), (12, 64), (HKV, 64)])
+def test_paged_decode_on_a_pool_no_dma_can_slice(chip, nkv, hd):
+    """Multi-query with one bf16 KV head, twelve heads of 64: a page's
+    last two dims do not fill whole tiles, and the kernel keeps the
+    pipelined walk over the block table."""
+    assert not paged_attention._pages_sliceable(nkv, hd, 2)
+    nh = 71 if nkv == 1 else nkv
+    _compile(chip, paged_attention.paged_decode_attention,
+             ((16, nh, hd), BF16), ((512, 16, 2, nkv, hd), BF16),
+             ((16, 64), jnp.int32), ((16,), jnp.int32))
+
+
+def test_paged_decode_under_tp2_shard_map(chip):
+    """``model_runner._paged_decode`` on a tp=2 mesh: each shard runs
+    the kernel on its 4 KV heads of the whole pool."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.inference.model_runner import _paged_decode
+    from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
+
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    mesh = build_mesh(TopologyConfig(dp=2, tp=2), devices=devices)
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    args = (arg((32, HQ, D), BF16, None, "tp", None),
+            arg((2, 2080, 16, 2, HKV, D), BF16,
+                None, None, None, None, "tp", None),
+            arg((), jnp.int32), arg((32, 64), jnp.int32),
+            arg((32,), jnp.int32))
+    text = jax.jit(lambda q, kv, l, bt, ctx: _paged_decode(
+        mesh, q, kv, l, bt, ctx)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_paged_prefill_tq64(chip):
@@ -197,6 +255,10 @@ def test_decode_programs_keep_the_pool_in_place(chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // kv.shape[0]
+    # and since the decode kernel reads the pool from HBM by its own
+    # fetches (PR 31) they are what they were: 0.55 MB and 0.81 MB here,
+    # 0.52 and 0.78 with the pipelined pages before it
+    assert mem.temp_size_in_bytes < 2**20
 
 
 def test_gather_program_reads_each_kv_head_once(chip):
