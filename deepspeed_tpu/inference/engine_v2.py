@@ -31,6 +31,7 @@ from deepspeed_tpu.inference import model_runner
 from deepspeed_tpu.inference.ragged import (
     BlockedKVCache, KVCacheConfig, PrefixCache, RaggedBatch, StateManager)
 from deepspeed_tpu.inference.ragged.ragged_batch import build_ragged_batch
+from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
 from deepspeed_tpu.inference.scheduler import SplitFuseScheduler
 from deepspeed_tpu.inference.spec_decode import PromptLookupDrafter
 from deepspeed_tpu.models.transformer import TransformerLM
@@ -121,6 +122,12 @@ def dstpu_take_rows(lg, idx):
 
 
 @jax.jit
+def dstpu_merge_rows(into, rows, at):
+    """``into[at[j]] = rows[j]`` (an ``at`` past the end drops the row)."""
+    return into.at[at].set(rows, mode="drop")
+
+
+@jax.jit
 def dstpu_pick_greedy_all(lg):
     return jnp.argmax(lg.reshape(-1, lg.shape[-1]).astype(jnp.float32),
                       axis=-1).astype(jnp.int32)
@@ -203,11 +210,22 @@ class InferenceEngineV2:
         self._param_dtype = dtype
         self._quantize_weights = quantize_weights
 
+        # a model whose attention chooses the pages it reads
+        # (ops/block_sparse.py): one page is one block of the rule, its
+        # compressed keys live beside the pool (ragged/kv_cache.py), and no
+        # step of it runs the gather program (_split_by_program)
+        sparse = getattr(self.cfg, "sparse", None)
+        self._sparse = sparse is not None
+        if self._sparse and kv_block_size != sparse.block:
+            raise ValueError(
+                f"kv_block_size={kv_block_size}: a model with block-sparse "
+                f"attention needs pages of its block size, {sparse.block}")
         kv_cfg = KVCacheConfig(
             num_layers=getattr(self.cfg, "kv_layers", self.cfg.num_layers),
             kv_heads=self.cfg.kv_heads,
             head_dim=self.cfg.head_dim, block_size=kv_block_size,
-            num_blocks=kv_blocks, dtype=dtype, quant_bits=kv_quant_bits)
+            num_blocks=kv_blocks, dtype=dtype, quant_bits=kv_quant_bits,
+            compressed_per_block=sparse.per_block if self._sparse else 0)
         self.kv_cache = BlockedKVCache(kv_cfg, mesh=self.mesh)
         # disagg handoff wire codec mode ("auto"/"raw"/"int8"/"int4");
         # consumed by serving/disagg.py serialize_prefix
@@ -229,7 +247,7 @@ class InferenceEngineV2:
                 slots=int(state_slots or max_seqs_per_step),
                 heads=c.linear_num_value_heads, key_dim=c.linear_key_head_dim,
                 value_dim=c.linear_value_head_dim,
-                conv_taps=c.linear_conv_kernel_dim,
+                conv_taps=c.conv_taps,
                 conv_channels=c.conv_channels, dtype=dtype))
         # shared-prefix KV reuse: full blocks whose content-hash chain
         # matches a cached prefix are shared by reference and skip
@@ -314,12 +332,17 @@ class InferenceEngineV2:
             # expert layers, (token, expert) pairs routed to experts held
             # here, held experts that got a row (summed over calls; the
             # ``_decode`` pair counts the two decode programs alone); and
-            # the state pool's occupancy
+            # the state pool's occupancy; the sparse rule's choices likewise
+            # ((query, KV head) pairs' chosen and visible blocks over the
+            # queries past dense_len, and the queries below it: the
+            # programs' vector, state_pool.COUNTERS) and the compressed
+            # keys' occupancy (window slots of the held pages)
             pool = self.kv_cache.state_pool
-            self.stats.update(moe_token_layers=0, moe_local_pairs=0,
-                              moe_experts_hit=0, moe_local_pairs_decode=0,
+            self.stats.update(dict.fromkeys(COUNTERS, 0),
+                              moe_local_pairs_decode=0,
                               moe_experts_hit_decode=0, state_slots_in_use=0,
-                              state_slots=pool.total_slots)
+                              state_slots=pool.total_slots,
+                              compressed_keys_in_use=0)
         # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
         # span, of the spans nested in it, and of the request tracer's
         # PREFILL / DECODE_EMIT spans of that step
@@ -951,7 +974,8 @@ class InferenceEngineV2:
         # program for the same work
         from deepspeed_tpu.ops.pallas.gated_delta import CHUNK
 
-        self._min_segment = CHUNK
+        sparse = self.cfg.sparse        # its chunks also hold whole blocks
+        self._min_segment = max(CHUNK, sparse.block if sparse else 0)
         self._keep_serving_params()
 
     def _keep_serving_params(self) -> None:
@@ -989,14 +1013,16 @@ class InferenceEngineV2:
         pool = self.kv_cache.state_pool
         if pool is None:
             return
-        tokens, pairs, hit = (int(v) for v in np.asarray(pool.counters))
-        self.stats["moe_token_layers"] += tokens
-        self.stats["moe_local_pairs"] += pairs
-        self.stats["moe_experts_hit"] += hit
+        counted = dict(zip(COUNTERS, (int(v) for v in
+                                      np.asarray(pool.counters))))
+        for name, n in counted.items():
+            self.stats[name] += n
         if decode:
-            self.stats["moe_local_pairs_decode"] += pairs
-            self.stats["moe_experts_hit_decode"] += hit
+            self.stats["moe_local_pairs_decode"] += counted["moe_local_pairs"]
+            self.stats["moe_experts_hit_decode"] += counted["moe_experts_hit"]
         self.stats["state_slots_in_use"] = pool.slots_in_use
+        self.stats["compressed_keys_in_use"] = \
+            self.kv_cache.compressed_keys_in_use
 
     def holds_prefix_blocks(self, tokens) -> int:
         """How many full prefix blocks of ``tokens`` this engine can
@@ -1073,64 +1099,91 @@ class InferenceEngineV2:
 
     def _splitfuse_step(self, temperature: float, seed: int,
                         eos_token_id: Optional[int]) -> Dict[int, int]:
-        t0 = time.perf_counter()
         with span("schedule"):
             scheduled = self.scheduler.schedule()
             self._release_finished()
             if not scheduled:
                 self._preempt_starved()
                 return {}
-        with self.mesh:
-            with span("build_batch"):
-                fn, program, args, batch = self._build_step_call(scheduled)
-            with span("dispatch", program=program, seqs=len(scheduled),
-                      tokens=int(batch.num_tokens)):
-                logits, new_kv = fn(self.params, self.kv_cache.kv_state,
-                                    *args)
-        # the program consumed (donated) the handle it was given
-        self.kv_cache.set_kv_state(new_kv)
+        t0 = time.perf_counter()
+        # one program a part (one part, all of the step, but for a model
+        # that runs no gather program); the pools pass from one to the next
+        runs = []
+        for part in self._split_by_program(scheduled):
+            mine = [scheduled[i] for i in part]
+            with self.mesh:
+                with span("build_batch"):
+                    fn, program, args, batch = self._build_step_call(mine)
+                with span("dispatch", program=program, seqs=len(mine),
+                          tokens=int(batch.num_tokens)):
+                    logits, new_kv = fn(self.params, self.kv_cache.kv_state,
+                                        *args)
+            # the program consumed (donated) the handle it was given
+            self.kv_cache.set_kv_state(new_kv)
+            if runs and self._recurrent:    # before the next call overwrites
+                self._fetch_counters(runs[-1][1] == "decode")
+            runs.append((part, program, logits, batch))
 
         # Sample ON DEVICE and fetch only token ids (greedy) or just the
         # consumed rows (stochastic). Materializing the full [T, V]
         # logits host-side is 131 MB/step at a 256-token budget x 128k
         # vocab; the ids are 4 bytes/sequence.
         with span("bookkeep"):
-            stride = logits.shape[1] if logits.ndim == 3 else 1
-            flat_idx = np.zeros(self.max_seqs, np.int32)
-            consumers = []
-            for slot, (seq, new_tokens, start_pos) in enumerate(scheduled):
-                n = len(new_tokens)
-                seq.seen_tokens = start_pos + n
-                # prompt blocks the step just completed become shareable
-                self.state.register_prefix_blocks(seq)
-                if start_pos < len(seq.input_tokens):
-                    self.stats["prefill_chunks"] += 1
-                if seq.seen_tokens < len(seq.input_tokens):
-                    continue  # mid-prefill: no logits consumed
-                if program == "prefill":
-                    flat_idx[slot] = slot * stride + (n - 1)
-                elif program == "decode":
-                    flat_idx[slot] = slot
-                else:
-                    flat_idx[slot] = batch.last_token_index[slot]
-                consumers.append((slot, seq))
+            consumers, picks = [], []
+            for part, program, logits, batch in runs:
+                stride = logits.shape[1] if logits.ndim == 3 else 1
+                flat_idx = np.zeros(self.max_seqs, np.int32)
+                for slot, i in enumerate(part):
+                    seq, new_tokens, start_pos = scheduled[i]
+                    n = len(new_tokens)
+                    seq.seen_tokens = start_pos + n
+                    # prompt blocks the step just completed become shareable
+                    self.state.register_prefix_blocks(seq)
+                    if start_pos < len(seq.input_tokens):
+                        self.stats["prefill_chunks"] += 1
+                    if seq.seen_tokens < len(seq.input_tokens):
+                        continue  # mid-prefill: no logits consumed
+                    if program == "prefill":
+                        flat_idx[slot] = slot * stride + (n - 1)
+                    elif program == "decode":
+                        flat_idx[slot] = slot
+                    else:
+                        flat_idx[slot] = batch.last_token_index[slot]
+                    consumers.append((i, seq, program))
+                picks.append(flat_idx)
 
         emitted: Dict[int, int] = {}
+        last_program = runs[-1][1]
         if consumers:
             with span("fetch"), self.mesh:
                 # the host blocks on the device here: the picked ids (or
-                # rows) are the step's only result it reads
-                idx_dev = jnp.asarray(flat_idx)
+                # rows) are the step's only result it reads. Several
+                # programs: each one's sampled rows, put in the order of
+                # the schedule, so that the pick is one call whose row i
+                # belongs to the i-th scheduled sequence as ever
+                if len(runs) == 1:
+                    logits, idx_dev = runs[0][2], jnp.asarray(picks[0])
+                else:
+                    logits = None
+                    for (part, _, lg, _), idx in zip(runs, picks):
+                        rows = self._take_rows(lg, jnp.asarray(idx))
+                        at = np.full(self.max_seqs, self.max_seqs, np.int32)
+                        at[:len(part)] = part
+                        logits = dstpu_merge_rows(
+                            rows if logits is None else logits, rows,
+                            jnp.asarray(at))
+                    idx_dev = jnp.asarray(np.arange(self.max_seqs,
+                                                    dtype=np.int32))
                 if temperature == 0.0:
                     toks_np = np.asarray(self._pick_greedy(logits, idx_dev))
                 else:
                     rows_np = np.asarray(self._take_rows(logits, idx_dev))
-                self._fetch_counters(program == "decode")
+                self._fetch_counters(last_program == "decode")
         elif self._recurrent:
             with span("fetch"):       # no token to read: the counters alone
-                self._fetch_counters(program == "decode")
+                self._fetch_counters(last_program == "decode")
         with span("bookkeep"):
-            for slot, seq in consumers:
+            for slot, seq, program in consumers:
                 if temperature == 0.0:
                     tok = int(toks_np[slot])
                 else:
@@ -1138,14 +1191,15 @@ class InferenceEngineV2:
                                          seed + slot + seq.seen_tokens))
                 seq.generated.append(tok)
                 emitted[seq.uid] = tok
+                self.stats[_TOKENS_OF[program]] += 1
                 if eos_token_id is not None and tok == eos_token_id:
                     seq.done = True
                 if seq.gen_budget_left <= 0:
                     seq.done = True
-            self.stats[_TOKENS_OF[program]] += len(emitted)
             now = time.perf_counter()
             self._step_hist.observe(now - t0)
-            self._flight.record("serve_step", tokens=batch.num_tokens,
+            tokens = sum(int(r[3].num_tokens) for r in runs)
+            self._flight.record("serve_step", tokens=tokens,
                                 emitted=len(emitted),
                                 wall_ms=round((now - t0) * 1000.0, 3))
             if self.tracer.enabled:
@@ -1167,6 +1221,19 @@ class InferenceEngineV2:
             self._update_serve_gauges()
             self._release_finished()
         return emitted
+
+    def _split_by_program(self, scheduled):
+        """The step's work as the lists (of indices into ``scheduled``) one
+        program each takes: all of it, but for a model with block-sparse
+        attention (long contexts: the gather program, a context a token, is
+        not built for it) the sequences that advance one token go through
+        the decode program and every chunk through the prefill program, one
+        sequence a call."""
+        if not self._sparse:
+            return [list(range(len(scheduled)))]
+        single = [i for i, s in enumerate(scheduled) if len(s[1]) == 1]
+        chunks = [[i] for i, s in enumerate(scheduled) if len(s[1]) > 1]
+        return ([single] if single else []) + chunks
 
     def _build_step_call(self, scheduled):
         """Pick the program for this step's mix and assemble its host
@@ -1241,6 +1308,12 @@ class InferenceEngineV2:
         tq = self._min_segment
         while tq < longest:
             tq *= 2
+        if self._sparse:        # one sequence's chunk, no Pallas kernel
+            (_, nt, sp), = scheduled
+            toks = np.zeros((1, tq), np.int32)
+            toks[0, :len(nt)] = nt
+            return (jnp.asarray(toks), jnp.asarray([sp], np.int32),
+                    jnp.asarray([len(nt)], np.int32))
         # kernel scratch is (Tq*num_heads) rows of (2*128 + head_dim) fp32
         # VMEM; keep it well under the ~16MB/core budget or the Mosaic
         # compile fails at serve time (gather path has no such limit)

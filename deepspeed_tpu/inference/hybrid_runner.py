@@ -1,6 +1,6 @@
 """The four serving step programs for a hybrid model (``models/hybrid.py``):
-periods of recurrent layers and one full-attention layer, every layer with
-its expert block.
+recurrent layers and full-attention layers in the order the configuration
+lists, every layer with its expert block or dense feed-forward.
 
 Same programs, same names and the same leading arguments as
 ``model_runner``'s (``ragged_forward`` = ``jit_dstpu_serve_gather``,
@@ -8,117 +8,268 @@ Same programs, same names and the same leading arguments as
 ``ragged_multi_decode``), so the engine, the scheduler and the trace readers
 do not tell the models apart. What differs:
 
-* argument 1, donated, is the dict of **both** pools — ``kv`` (the paged
-  pool, one layer a *period*), ``state`` and ``conv`` (the recurrent-state
-  pool, ``inference/ragged/state_pool.py``) — plus ``counters``; all are
+* argument 1, donated, is the dict of the pools — ``kv`` (the paged pool,
+  one layer a full layer), ``state`` and ``conv`` (the recurrent-state
+  pool, ``inference/ragged/state_pool.py``) and, for a model with the
+  block-sparse rule, ``ck`` (the compressed keys, page-addressed beside
+  ``kv``: ``inference/ragged/kv_cache.py``) — plus ``counters``; all are
   carried through the layer loops and updated in place (the kernels read the
   pools whole, by layer and page or slot);
 * one more trailing argument, ``state_slots [S]``: the state-pool slot of the
   sequence in each batch slot (the scratch slot for an empty one);
-* the layer loop is a scan over periods with a scan over the period's
-  recurrent layers inside it: one recurrent and one full layer body a
-  program, whatever the depth;
-* a token step of a recurrent layer is the ``gdn_decode`` kernel; many tokens
-  a sequence (gather, prefill) go through the chunked form on a
-  sequence-by-token layout.
+* the layer loop is a scan over the repeats of the layer pattern with a scan
+  over each run of one kind inside it (``HybridConfig.stack_plan``): one
+  layer body a run, whatever the depth;
+* a token step of a recurrent layer is the ``gdn_decode`` or
+  ``lightning_decode`` kernel; many tokens a sequence (gather, prefill) go
+  through the chunked form on a sequence-by-token layout;
+* with the block-sparse rule a full layer's token step selects pages
+  (``sparse_select``) and runs the paged decode kernel over the chosen ones
+  (``sparse_attn``); a chunk attends over its own sequence's pages under the
+  block mask; the gather program is not built.
 
 ``counters`` comes back as this call's ``[moe_token_layers, moe_local_pairs,
-moe_experts_hit]`` (summed over the steps of a burst).
+moe_experts_hit, sparse_blocks_selected, sparse_blocks_visible,
+sparse_dense_tokens]`` (summed over the steps of a burst).
 """
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
-from deepspeed_tpu.inference.model_runner import (_kv_dense, _kv_write,
-                                                  _paged_decode,
-                                                  _paged_prefill, _unembed)
+from deepspeed_tpu.inference.model_runner import (_kv_write, _paged_decode,
+                                                  _paged_prefill)
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.hybrid import HybridConfig
-from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, gdn_decode
-from deepspeed_tpu.runtime.sharding import (effective_dtype,
-                                            vocab_parallel_lookup)
+from deepspeed_tpu.ops import block_sparse
+from deepspeed_tpu.ops.pallas.gated_delta import (gdn_chunk, gdn_decode,
+                                                  lightning_chunk,
+                                                  lightning_decode)
+from deepspeed_tpu.runtime.sharding import effective_dtype
 
 
 def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
-    """The layer loop. ``x`` has any leading shape; ``rec_fn(y, gp, l_rec,
-    state, conv) -> (out, state, conv)`` and ``full_fn(y, ap, l_kv, kv) ->
-    (out, kv)`` are the two mixers on normed input; ``valid`` marks the real
-    tokens (flat, for the experts' counters). Returns (x, pools')."""
-    per = cfg.full_attention_interval
-    P = cfg.periods
+    """The layer loop. ``x`` has any leading shape; ``rec_fn(y, mp, l_rec,
+    pools) -> (out, pools)`` and ``full_fn(y, ap, l_kv, pools) -> (out,
+    pools)`` are the two mixers on normed input; ``valid`` marks the real
+    tokens (flat, for the experts' counters). The layers run as
+    ``cfg.stack_plan`` lays them out: a scan over the repeats of the pattern
+    and, inside, each run of one kind scanned (a lone layer called), so a
+    program holds one layer body a run whatever the depth. ``pools`` is the
+    carry, ``pools["counters"]`` what this call counted. Returns (x, pools')."""
+    reps, runs = cfg.stack_plan
+    per = cfg.num_layers // reps
+    in_period = {True: sum(n for full, n in runs if full)}
+    in_period[False] = per - in_period[True]
     lead, H = x.shape[:-1], x.shape[-1]
     experts = params["experts"]
+    mixers = {True: params["attn"], False: params[cfg.recurrent_kind]}
 
-    def by_period(tree, n):
-        return jax.tree.map(lambda a: a.reshape((P, n) + a.shape[1:]), tree)
+    def at(tree, i):
+        # a layer's leaves read where they lie in the stack: a run's slice
+        # handed to the scan as its xs would be copied first (0.75 GiB a
+        # feed-forward matrix for six layers of 4096 x 16384)
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
 
-    def ffn(x, lp, l, counts):
-        out, c = hybrid.expert_block(cfg, lp, experts, x.reshape(-1, H), l,
-                                     valid)
-        return out.reshape(lead + (H,)), counts + jnp.stack(
-            [jnp.sum(valid).astype(jnp.int32), c["pairs"], c["experts_hit"]])
+    def body(full):
+        mixer, scope = (full_fn, "attn") if full else (rec_fn,
+                                                       cfg.recurrent_kind)
 
-    def rec_layer(carry, inputs):
-        x, state, conv, counts = carry
-        lp, gp, l, l_rec = inputs
-        with jax.named_scope("gdn"):
-            y = hybrid._rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-            out, state, conv = rec_fn(y, gp, l_rec, state, conv)
-        x, counts = ffn(x + out, lp, l, counts)
-        return (x, state, conv, counts), None
+        def layer(carry, where):
+            x, pools = carry
+            l, l_mix = where
+            lp, mp = at(params["layers"], l), at(mixers[full], l_mix)
+            with jax.named_scope(scope):
+                y = hybrid._rms(x, lp["ln1"]["scale"], cfg.norm_eps)
+                out, pools = mixer(y, mp, l_mix, pools)
+            x, c = hybrid.expert_block(cfg, lp, experts, hybrid.residual(
+                cfg, x, out).reshape(-1, H), l, valid)
+            if c is not None:
+                pools = dict(pools, counters=pools["counters"].at[:3].add(
+                    jnp.stack([jnp.sum(valid).astype(jnp.int32), c["pairs"],
+                               c["experts_hit"]])))
+            return (x.reshape(lead + (H,)), pools), None
 
-    def period(carry, inputs):
-        x, kv, state, conv, counts = carry
-        lps, gps, ap, p = inputs
-        ls = p * per + jnp.arange(per - 1, dtype=jnp.int32)
-        l_recs = p * (per - 1) + jnp.arange(per - 1, dtype=jnp.int32)
-        (x, state, conv, counts), _ = lax.scan(
-            rec_layer, (x, state, conv, counts),
-            (jax.tree.map(lambda a: a[:per - 1], lps), gps, ls, l_recs))
-        lp = jax.tree.map(lambda a: a[per - 1], lps)
-        with jax.named_scope("attn"):
-            y = hybrid._rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-            out, kv = full_fn(y, ap, p, kv)
-        x, counts = ffn(x + out, lp, p * per + per - 1, counts)
-        return (x, kv, state, conv, counts), None
+        return layer
 
-    carry = (x, pools["kv"], pools["state"], pools["conv"],
-             jnp.zeros((3,), jnp.int32))
-    (x, kv, state, conv, counts), _ = lax.scan(
-        period, carry,
-        (by_period(params["layers"], per), by_period(params["gdn"], per - 1),
-         params["attn"], jnp.arange(P, dtype=jnp.int32)))
-    return x, {"kv": kv, "state": state, "conv": conv, "counters": counts}
+    def period(carry, r):
+        first = {True: 0, False: 0, None: 0}
+        for full, n in runs:
+            steps = jnp.arange(n, dtype=jnp.int32)
+            where = (r * per + first[None] + steps,
+                     r * in_period[full] + first[full] + steps)
+            if n == 1:
+                carry, _ = body(full)(carry, (where[0][0], where[1][0]))
+            else:
+                carry, _ = lax.scan(body(full), carry, where)
+            first[None] += n
+            first[full] += n
+        return carry, None
+
+    pools = dict(pools, counters=jnp.zeros_like(pools["counters"]))
+    (x, pools), _ = lax.scan(period, (x, pools),
+                             jnp.arange(reps, dtype=jnp.int32))
+    return x, pools
 
 
-def _segment_recurrence(cfg, gp, l_rec, state, conv, slots, mixed, beta, g,
-                        real, nreal):
-    """Convolution and chunked recurrence on a sequence-by-token layout:
-    mixed [S, Tq, C]; beta, g [S, Tq, nv]; real [S, Tq]; nreal [S]. Reads and
-    writes each row's slot of both pools. Returns (o [S, Tq, nv, dv], state,
-    conv)."""
+def _rec_project(cfg, mp, y, pos):
+    """The recurrent mixer's projections of y [..., H] at positions pos
+    [...]: ``(what the recurrence reads, z)``."""
+    if cfg.recurrent_kind == "lightning":
+        q, k, v, z = hybrid.lightning_project(cfg, mp, y, pos)
+        return (q, k, v), z
+    mixed, z, beta, g = hybrid.gdn_project(cfg, mp, y)
+    return (mixed, beta, g), z
+
+
+def _rec_output(cfg, mp, o, z):
+    if cfg.recurrent_kind == "lightning":
+        return hybrid.lightning_output(cfg, mp, o, z)
+    return hybrid.gdn_output(cfg, mp, o, z)
+
+
+def _segment_recurrence(cfg, mp, l_rec, pools, slots, proj, real, nreal):
+    """The chunked recurrence (and the delta rule's convolution) on a
+    sequence-by-token layout: ``proj`` is :func:`_rec_project`'s first part
+    on [S, Tq, ...]; real [S, Tq]; nreal [S]. Reads and writes each row's
+    slot of the state pool (and of the convolution's). Returns (o [S, Tq, n,
+    dv] float32, pools')."""
+    state = pools["state"]
+    m = real[..., None]
+    if cfg.recurrent_kind == "lightning":
+        q, k, v = proj
+        g = jnp.where(m, cfg.lightning_decay()[l_rec], 0.0)
+        o, new = lightning_chunk(q, jnp.where(m[..., None], k, 0.0), v, g,
+                                 state[l_rec, slots])
+        return o, dict(pools, state=state.at[l_rec, slots].set(new))
+    mixed, beta, g = proj
+    conv = pools["conv"]
     K1 = cfg.linear_conv_kernel_dim - 1
-    out, window = hybrid.causal_conv(gp["conv"], conv[l_rec, slots], mixed)
+    out, window = hybrid.causal_conv(mp["conv"], conv[l_rec, slots], mixed)
     # the next tail: the last K - 1 real inputs (the old tail where a row
     # brought fewer)
     at = nreal[:, None] + jnp.arange(K1)[None, :]                  # [S, K1]
     tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
     conv = conv.at[l_rec, slots].set(tail.astype(conv.dtype))
     q, k, v = hybrid.gdn_heads(cfg, out)
-    m = real[..., None]
     o, new = gdn_chunk(q, k, v, jnp.where(m, g, 0.0), jnp.where(m, beta, 0.0),
                        state[l_rec, slots])
-    return o, state.at[l_rec, slots].set(new), conv
+    return o, dict(pools, state=state.at[l_rec, slots].set(new), conv=conv)
 
 
-def _embed(cfg, params, ids):
-    return vocab_parallel_lookup(
-        params["embed"]["tokens"].astype(effective_dtype(cfg.dtype)), ids)
+def _compress_new(sz, kv, ck, l_kv, block_table, pos0, n_new, windows: int):
+    """Write the compressed keys of every window whose *last* token is among
+    a row's ``n_new`` tokens from ``pos0`` on (at most ``windows`` of them),
+    from the keys in the pool (this step's are written already): window ``j``
+    goes to the page where it starts, ``ck[l_kv, page, slot]``. pos0, n_new
+    [S]; block_table [S, Bm]. Returns ck'."""
+    S, Bm = block_table.shape
+    bs = kv.shape[2]
+    j0 = jnp.maximum(0, -((sz.kernel - 1 - pos0) // sz.stride))     # ceil
+    j = j0[:, None] + jnp.arange(windows)[None, :]                   # [S, J]
+    start = sz.stride * j
+    whole = (start + sz.kernel <= (pos0 + n_new)[:, None]) & (n_new > 0)[:, None]
+    tok = jnp.minimum(start[..., None] + jnp.arange(sz.kernel), Bm * bs - 1)
+    page = jnp.take_along_axis(block_table, (tok // bs).reshape(S, -1),
+                               axis=1).reshape(tok.shape)
+    new = block_sparse.compress_windows(kv[l_kv, page, tok % bs, 0])
+    home = jnp.take_along_axis(block_table, jnp.minimum(start // bs, Bm - 1),
+                               axis=1)
+    return ck.at[l_kv, jnp.where(whole, home, ck.shape[1] - 1),
+                 jnp.where(whole, (start % bs) // sz.stride, 0)].set(
+                     new.astype(ck.dtype))
+
+
+def _sparse_counts(sz, t, real, count, visible):
+    """This call's ``[blocks selected, blocks visible, dense tokens]``: over
+    the real queries past ``dense_len`` the (query, KV head) pairs' chosen
+    and visible blocks, and the real queries below it."""
+    sparse = real & (t >= sz.dense_len)
+    return jnp.stack([
+        jnp.sum(jnp.where(sparse[..., None], count, 0)),
+        jnp.sum(jnp.where(sparse, visible, 0)) * count.shape[-1],
+        jnp.sum(real & ~sparse)]).astype(jnp.int32)
+
+
+def _sparse_decode(cfg, mesh, q, kv, ck, l_kv, block_table, context_lens):
+    """Decode attention over the pages each (sequence, KV head) *chose*: the
+    rule's list in place of the sequence's whole table, the paged decode
+    kernel as it is. A chosen list is ascending and ends in the sequence's
+    newest page, so all its pages but the last are full and ``count`` pages
+    stand for a context of ``(count - 1) * page + the newest page's tokens``.
+    Each (sequence, KV head) pair is a row of the kernel's batch with all the
+    query heads; a group's own row is kept. Positions below ``dense_len``
+    walk their own table. Returns (attention [S, nq, d], counts [3])."""
+    sz = cfg.sparse
+    S, Bm = block_table.shape
+    bs, nkv, d = kv.shape[2], cfg.kv_heads, cfg.head_dim
+    g = cfg.num_heads // nkv
+    alive = context_lens > 0
+    t = jnp.maximum(context_lens - 1, 0)
+    with jax.named_scope("sparse_select"):
+        cks = ck[l_kv, block_table].reshape(S, Bm * sz.per_block, nkv, d)
+        idx, count, visible = jax.vmap(
+            lambda qs, c, ts: block_sparse.select_blocks(
+                sz, qs[None], c, ts[None], 1.0 / math.sqrt(d)))(
+                    q.reshape(S, nkv, g, d), cks, t)
+        idx, count, visible = idx[:, 0], count[:, 0], visible[:, 0]
+        width = min(Bm, max(idx.shape[-1], -(-sz.dense_len // bs)))
+        chosen = jnp.take_along_axis(
+            block_table[:, None, :], jnp.minimum(idx, Bm - 1), axis=2)
+        chosen = jnp.pad(chosen, ((0, 0), (0, 0), (0, width - idx.shape[-1])))
+        sparse = (t >= sz.dense_len)[:, None]
+        table = jnp.where(sparse[..., None], chosen,
+                          block_table[:, None, :width])
+        ctx = jnp.where(sparse, (count - 1) * bs + (t % bs + 1)[:, None],
+                        context_lens[:, None])
+        ctx = jnp.where(alive[:, None], ctx, 0)
+    with jax.named_scope("sparse_attn"):
+        out = _paged_decode(mesh, jnp.repeat(q, nkv, axis=0), kv, l_kv,
+                            table.reshape(S * nkv, width), ctx.reshape(-1))
+        own = jnp.arange(nkv)
+        out = out.reshape(S, nkv, nkv, g, d)[:, own, own]
+    return out.reshape(S, nkv * g, d), _sparse_counts(sz, t, alive, count,
+                                                      visible)
+
+
+def _sparse_prefill(cfg, q, kv, ck, l_kv, block_table, pos, real, ctx_lens):
+    """Chunk attention under the block mask, one segment after another, each
+    reading its own sequence's pages and compressed keys (a sequence's keys
+    and values of one layer: 32 MiB at 32k tokens; nothing holds a context a
+    token). q [S, Tq, nq, d]; pos, real [S, Tq]. Returns (attention, counts
+    [3])."""
+    sz = cfg.sparse
+    S, Tq = pos.shape
+    Bm = block_table.shape[1]
+    nkv, d = cfg.kv_heads, cfg.head_dim
+    scale = 1.0 / math.sqrt(d)
+
+    def segment(args):
+        qs, table, ts, rs, n = args
+        # (held to the pool's own layout: the products below want the
+        # token second-minor, and without the constraint XLA lays the whole
+        # pool out anew for this gather, 2 GiB, and not its 32 MiB result)
+        pages = with_layout_constraint(
+            kv[l_kv, table], Layout(major_to_minor=(0, 1, 2, 3, 4)))
+        keys = pages[:, :, 0].reshape(-1, nkv, d)
+        values = pages[:, :, 1].reshape(-1, nkv, d)
+        cks = ck[l_kv, table].reshape(-1, nkv, d)
+        idx, count, visible = block_sparse.select_blocks(sz, qs, cks, ts, scale)
+        a = block_sparse.blocked_attention(
+            qs, keys, values, block_sparse.block_mask(sz, idx, ts, Bm), ts, n,
+            scale, sz.block)
+        return a, _sparse_counts(sz, ts, rs, count, visible)
+
+    a, counts = lax.map(segment, (
+        q.reshape(S, Tq, nkv, -1, d), block_table, pos, real, ctx_lens))
+    return a.reshape(q.shape), jnp.sum(counts, axis=0)
 
 
 def _scratch(pools, alive, state_slots):
@@ -131,13 +282,21 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
                    ) -> Tuple[jax.Array, Dict]:
     """One ragged step over flat tokens (``model_runner.ragged_forward``'s
     contract; sequences lie one after another in the flat order). Returns
-    (logits [T, V] float32, pools')."""
+    (logits [T, V] float32, pools'). Not built for a model with the sparse
+    rule: it lays out one whole context per *token*, and such a model's
+    contexts are long; the engine runs its chunks through the prefill
+    program and its single tokens through the decode program."""
+    if cfg.sparse is not None:
+        raise NotImplementedError(
+            "the gather program holds a context per token and is not built "
+            "for a model with block-sparse attention: its steps run the "
+            "prefill and decode programs")
     T = token_ids.shape[0]
     S, Bm = block_table.shape
     bs = pools["kv"].shape[2]
     dt = effective_dtype(cfg.dtype)
     real = jnp.arange(T) < num_tokens
-    x = _embed(cfg, params, token_ids)
+    x = hybrid.embed_tokens(cfg, params, token_ids)
 
     scratch = pools["kv"].shape[1] - 1
     page = jnp.where(real, block_table[token_seq, token_pos // bs], scratch)
@@ -155,16 +314,16 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
     col_of = jnp.arange(T) - start[token_seq]
     slots = _scratch(pools, nreal > 0, state_slots)
 
-    def rec_fn(y, gp, l_rec, state, conv):
-        mixed, z, beta, g = hybrid.gdn_project(cfg, gp, y)
-        o, state, conv = _segment_recurrence(
-            cfg, gp, l_rec, state, conv, slots, mixed[to_seg], beta[to_seg],
-            g[to_seg], seg_real, nreal)
-        return hybrid.gdn_output(cfg, gp, o[token_seq, col_of], z), state, conv
+    def rec_fn(y, mp, l_rec, pools):
+        proj, z = _rec_project(cfg, mp, y, token_pos)
+        o, pools = _segment_recurrence(
+            cfg, mp, l_rec, pools, slots, tuple(a[to_seg] for a in proj),
+            seg_real, nreal)
+        return _rec_output(cfg, mp, o[token_seq, col_of], z), pools
 
-    def full_fn(y, ap, l_kv, kv):
+    def full_fn(y, ap, l_kv, pools):
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, token_pos)
-        kv, _ = _kv_write(kv, None, l_kv, page, offset, k, v)
+        kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
         with jax.named_scope("kv_gather"):
             ctx = kv[l_kv, block_table].reshape(
                 S, Bm * bs, 2, cfg.kv_heads, cfg.head_dim)[token_seq]
@@ -174,17 +333,19 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
         seen = key_pos[None, None, None, :] <= token_pos[:, None, None, None]
         pr = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1).astype(dt)
         a = jnp.einsum("tkgm,tmkd->tkgd", pr, ctx[:, :, 1].astype(dt))
-        return hybrid.attn_output(ap, a.reshape(q.shape), gate), kv
+        return (hybrid.attn_output(ap, a.reshape(q.shape), gate),
+                dict(pools, kv=kv))
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn, real)
-    return _unembed(cfg, params, x), pools
+    return hybrid.head_logits(cfg, params, x), pools
 
 
 def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
                            seg_pos0, seg_nreal, block_table, state_slots, *,
                            mesh=None) -> Tuple[jax.Array, Dict]:
-    """Prefill chunks, one segment a sequence slot, attention through the
-    paged prefill kernel. Returns (logits [S, Tq, V] float32, pools')."""
+    """Prefill chunks, one segment a sequence slot; attention through the
+    paged prefill kernel or, with the sparse rule, over each segment's own
+    pages under its block mask. Returns (logits [S, Tq, V] float32, pools')."""
     S, Tq = seg_tokens.shape
     bs = pools["kv"].shape[2]
     dt = effective_dtype(cfg.dtype)
@@ -192,70 +353,101 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
     pos = seg_pos0[:, None] + qi
     real = qi < seg_nreal[:, None]
     ctx_lens = seg_pos0 + seg_nreal
-    x = _embed(cfg, params, seg_tokens)
+    x = hybrid.embed_tokens(cfg, params, seg_tokens)
 
     scratch = pools["kv"].shape[1] - 1
     page = jnp.where(real, jnp.take_along_axis(block_table, pos // bs, axis=1),
                      scratch)
     offset = jnp.where(real, pos % bs, bs - 1)
     slots = _scratch(pools, seg_nreal > 0, state_slots[:S])
+    sz = cfg.sparse
 
-    def rec_fn(y, gp, l_rec, state, conv):
-        mixed, z, beta, g = hybrid.gdn_project(cfg, gp, y)
-        o, state, conv = _segment_recurrence(
-            cfg, gp, l_rec, state, conv, slots, mixed, beta, g, real,
-            seg_nreal)
-        return hybrid.gdn_output(cfg, gp, o, z), state, conv
+    def rec_fn(y, mp, l_rec, pools):
+        proj, z = _rec_project(cfg, mp, y, pos)
+        o, pools = _segment_recurrence(cfg, mp, l_rec, pools, slots, proj,
+                                       real, seg_nreal)
+        return _rec_output(cfg, mp, o, z), pools
 
-    def full_fn(y, ap, l_kv, kv):
+    def full_fn(y, ap, l_kv, pools):
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, pos)
-        kv, _ = _kv_write(kv, None, l_kv, page, offset, k, v)
-        a = _paged_prefill(mesh, q.astype(dt), *_kv_dense(kv, None, l_kv, dt),
-                           block_table, seg_pos0, ctx_lens)
-        return hybrid.attn_output(ap, a.astype(dt), gate), kv
+        kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
+        pools = dict(pools, kv=kv)
+        if sz is None:
+            a = _paged_prefill(mesh, q.astype(dt), kv, l_kv, block_table,
+                               seg_pos0, ctx_lens)
+        else:
+            ck = _compress_new(sz, kv, pools["ck"], l_kv, block_table,
+                               seg_pos0, seg_nreal, Tq // sz.stride + 1)
+            a, counts = _sparse_prefill(cfg, q.astype(dt), kv, ck, l_kv,
+                                        block_table, pos, real, ctx_lens)
+            pools = dict(pools, ck=ck,
+                         counters=pools["counters"].at[3:].add(counts))
+        return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn,
                           real.reshape(-1))
-    return _unembed(cfg, params, x), pools
+    return hybrid.head_logits(cfg, params, x), pools
 
 
 def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
                           token_pos, block_table, context_lens, state_slots, *,
                           mesh=None) -> Tuple[jax.Array, Dict]:
     """One decode step: one new token for each live slot (``context_lens``
-    0 marks a dead one). The recurrent layers run the ``gdn_decode`` kernel on
-    each sequence's slot, the full layers the paged decode kernel. Returns
-    (logits [S, V] float32, pools')."""
+    0 marks a dead one). The recurrent layers run their decode kernel
+    (``gdn_decode`` or ``lightning_decode``) on each sequence's slot, the
+    full layers the paged decode kernel: over the sequence's pages or, with
+    the sparse rule, over the pages it chose. Returns (logits [S, V] float32,
+    pools')."""
     S = token_ids.shape[0]
     bs = pools["kv"].shape[2]
     dt = effective_dtype(cfg.dtype)
     alive = context_lens > 0
-    x = _embed(cfg, params, token_ids)
+    x = hybrid.embed_tokens(cfg, params, token_ids)
 
     scratch = pools["kv"].shape[1] - 1
     page = jnp.where(alive, block_table[jnp.arange(S), token_pos // bs],
                      scratch)
     offset = jnp.where(alive, token_pos % bs, bs - 1)
     slots = _scratch(pools, alive, state_slots)
+    sz = cfg.sparse
 
-    def rec_fn(y, gp, l_rec, state, conv):
-        mixed, z, beta, g = hybrid.gdn_project(cfg, gp, y)
-        out, window = hybrid.causal_conv(gp["conv"], conv[l_rec, slots],
+    def rec_fn(y, mp, l_rec, pools):
+        if cfg.recurrent_kind == "lightning":
+            q, k, v, z = hybrid.lightning_project(cfg, mp, y, token_pos)
+            decay = jnp.broadcast_to(jnp.exp(cfg.lightning_decay()[l_rec]),
+                                     q.shape[:2])
+            o, state = lightning_decode(pools["state"], l_rec, slots, q, k, v,
+                                        decay)
+            return (hybrid.lightning_output(cfg, mp, o, z),
+                    dict(pools, state=state))
+        conv = pools["conv"]
+        mixed, z, beta, g = hybrid.gdn_project(cfg, mp, y)
+        out, window = hybrid.causal_conv(mp["conv"], conv[l_rec, slots],
                                          mixed[:, None, :])
         conv = conv.at[l_rec, slots].set(window[:, 1:].astype(conv.dtype))
         q, k, v = hybrid.gdn_heads(cfg, out[:, 0])
-        o, state = gdn_decode(state, l_rec, slots, q, k, v, g, beta)
-        return hybrid.gdn_output(cfg, gp, o, z), state, conv
+        o, state = gdn_decode(pools["state"], l_rec, slots, q, k, v, g, beta)
+        return (hybrid.gdn_output(cfg, mp, o, z),
+                dict(pools, state=state, conv=conv))
 
-    def full_fn(y, ap, l_kv, kv):
+    def full_fn(y, ap, l_kv, pools):
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, token_pos)
-        kv, _ = _kv_write(kv, None, l_kv, page, offset, k, v)
-        a = _paged_decode(mesh, q.astype(dt), *_kv_dense(kv, None, l_kv, dt),
-                          block_table, context_lens)
-        return hybrid.attn_output(ap, a.astype(dt), gate), kv
+        kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
+        pools = dict(pools, kv=kv)
+        if sz is None:
+            a = _paged_decode(mesh, q.astype(dt), kv, l_kv, block_table,
+                              context_lens)
+        else:
+            ck = _compress_new(sz, kv, pools["ck"], l_kv, block_table,
+                               token_pos, alive.astype(jnp.int32), 1)
+            a, counts = _sparse_decode(cfg, mesh, q.astype(dt), kv, ck, l_kv,
+                                       block_table, context_lens)
+            pools = dict(pools, ck=ck,
+                         counters=pools["counters"].at[3:].add(counts))
+        return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn, alive)
-    return _unembed(cfg, params, x), pools
+    return hybrid.head_logits(cfg, params, x), pools
 
 
 def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
@@ -276,5 +468,5 @@ def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
 
     (pools, _, _, _, counts), toks = lax.scan(
         body, (pools, token_ids, token_pos, context_lens,
-               jnp.zeros((3,), jnp.int32)), length=steps)
+               jnp.zeros_like(pools["counters"])), length=steps)
     return toks, dict(pools, counters=counts)

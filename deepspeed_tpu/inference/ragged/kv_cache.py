@@ -11,6 +11,20 @@ compiled step via scatter (see inference/model_runner.py); the host only
 manages block ids (blocked_allocator.py). Static pool shape keeps every
 step the same compiled program — the XLA analog of the reference
 preallocating the cache up front.
+
+A model whose attention chooses the pages it reads (``ops/block_sparse.py``)
+keeps a third kind of state, the **compressed keys** its choice is scored
+against ("the cache for the indexer"): the mean of every window of keys, a
+few windows a page. They live here, page-addressed beside the keys they
+summarise,
+
+    compressed[L, num_blocks, windows_per_block, kv_heads, head_dim]
+
+(window ``j`` in the page where it starts), so the block table that
+addresses a sequence's pages addresses its summaries, and a page that is
+freed, preempted or recomputed takes them with it: there is no second
+allocator and nothing to leak. The step programs write them
+(``hybrid_runner._compress_new``) and carry the array with the pool.
 """
 
 from __future__ import annotations
@@ -40,6 +54,8 @@ class KVCacheConfig:
     # 128) with the same per-vector fp32 scale; "fp8" = e4m3 payload (the
     # quality midpoint between int8 and int4) with the same per-vector scale.
     quant_bits: Optional[object] = None
+    # compressed keys a page (0: none): block_size / the windows' stride
+    compressed_per_block: int = 0
 
     def __post_init__(self):
         if self.quant_bits not in (None, 4, 8, "fp8"):
@@ -63,7 +79,8 @@ class KVCacheConfig:
             # int8/fp8/packed-int4 payload + fp32 scale per head vector
             return vecs * (self.payload_width + 4)
         itemsize = jnp.dtype(self.dtype).itemsize
-        return vecs * self.head_dim * itemsize
+        summaries = self.num_layers * self.compressed_per_block * self.kv_heads
+        return (vecs + summaries) * self.head_dim * itemsize
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -92,6 +109,18 @@ class BlockedKVCache:
         # the owner for a model with recurrent layers: slot-addressed state
         # beside the blocks, handed to the step programs in one pytree
         self.state_pool = None
+        # the compressed keys of a model that chooses its pages (None: no
+        # such model); one dtype with the pool, handed out and taken back
+        # with it (``kv_state``)
+        self.compressed = None
+        if config.compressed_per_block:
+            if config.quant_bits is not None:
+                raise ValueError("compressed keys beside a quantized pool "
+                                 "are not wired")
+            self.compressed = jnp.zeros(
+                (config.num_layers, config.num_blocks,
+                 config.compressed_per_block, config.kv_heads,
+                 config.head_dim), config.dtype)
         shape = (config.num_layers, config.num_blocks, config.block_size,
                  2, config.kv_heads, config.payload_width)
         quantized = config.quant_bits is not None
@@ -132,8 +161,11 @@ class BlockedKVCache:
         (``inference/hybrid_runner.py``)."""
         if self.state_pool is not None:
             sp = self.state_pool
-            return {"kv": self.data, "state": sp.state, "conv": sp.conv,
-                    "counters": sp.counters}
+            state = {"kv": self.data, "state": sp.state, "conv": sp.conv,
+                     "counters": sp.counters}
+            if self.compressed is not None:
+                state["ck"] = self.compressed
+            return state
         if self.scales is None:
             return self.data
         return (self.data, self.scales)
@@ -147,6 +179,7 @@ class BlockedKVCache:
             sp = self.state_pool
             self.data, sp.state, sp.conv, sp.counters = (
                 state["kv"], state["state"], state["conv"], state["counters"])
+            self.compressed = state.get("ck")
         elif self.scales is None:
             self.data = state
         else:
@@ -189,6 +222,13 @@ class BlockedKVCache:
     @property
     def free_blocks(self) -> int:
         return self.allocator.free_blocks
+
+    @property
+    def compressed_keys_in_use(self) -> int:
+        """Window slots of the pages that sequences hold (a layer): they are
+        taken and given back with their pages."""
+        held = self.allocator.total_blocks - self.allocator.free_blocks
+        return held * self.config.compressed_per_block
 
     @property
     def available_blocks(self) -> int:

@@ -2,8 +2,9 @@
 
 A model with recurrent layers (``models/hybrid.py``) keeps, for every live
 sequence and every recurrent layer, a state that is *replaced* at each token
-and does not grow: the delta rule's matrix for each head (float32) and the
-last ``K - 1`` inputs of the causal convolution. It is not block-addressed:
+and does not grow: the recurrence's matrix for each head (float32; the delta
+rule's or lightning attention's) and the last ``K - 1`` inputs of the causal
+convolution (none for a mixer without one: ``conv_taps`` 1, an empty array). It is not block-addressed:
 a sequence owns one **slot** for its whole life,
 
     state[layers, slots + 1, heads, key_dim, value_dim]   float32
@@ -29,6 +30,12 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+
+
+# what a step program counts, in the order of its ``counters`` vector
+COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
+            "sparse_blocks_selected", "sparse_blocks_visible",
+            "sparse_dense_tokens")
 
 
 class StateSnapshotUnsupported(NotImplementedError):
@@ -69,7 +76,7 @@ class RecurrentStatePool:
         self.conv = jnp.zeros((c.layers, c.slots + 1, c.conv_taps - 1,
                                c.conv_channels), c.dtype)
         # what the last step program counted (hybrid_runner's ``counters``)
-        self.counters = jnp.zeros((3,), jnp.int32)
+        self.counters = jnp.zeros((len(COUNTERS),), jnp.int32)
         self._free: List[int] = list(range(c.slots - 1, -1, -1))
 
     @property

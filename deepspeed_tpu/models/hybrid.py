@@ -1,14 +1,19 @@
-"""Hybrid recurrent / attention LM with sparse experts (the ``qwen3_next``
-family: Qwen3-Next-80B-A3B).
+"""Hybrid recurrent / attention LM: one stack for the ``qwen3_next`` family
+(Qwen3-Next-80B-A3B) and the ``minicpm_sala`` family (MiniCPM-SALA).
 
-The stack is not one scanned block: in each *period* of
-``full_attention_interval`` layers all but the last are **recurrent** (gated
-DeltaNet, ``ops/pallas/gated_delta.py``) and the last is **full** (gated
-softmax attention with QK-norm and rotary on a part of each head); every
-layer ends in an expert block (``parallel/moe.py::moe_ffn_share``: softmax
-routing over all the experts, the top-k renormalised, the experts held here,
-and a shared expert behind a sigmoid gate). Norms, rotary and the embedding
-are ``models/transformer.py``'s.
+The stack is not one scanned block. Each layer is **recurrent** or **full**
+(:attr:`HybridConfig.layer_kinds`: Qwen3-Next's "every
+``full_attention_interval``-th layer is full", or a published list of mixers
+cut to the layers held here). A recurrent layer is gated DeltaNet or lightning
+attention (``recurrent_kind``; ``ops/pallas/gated_delta.py``); a full layer is
+gated softmax attention with QK-norm, rotary on a part of each head (none of
+it at ``partial_rotary_factor`` 0) and, with ``sparse_topk``, the block-sparse
+rule of ``ops/block_sparse.py``. Every layer ends in an expert block
+(``parallel/moe.py::moe_ffn_share``: softmax routing over all the experts, the
+top-k renormalised, the experts held here, and a shared expert behind a
+sigmoid gate) or, at ``num_experts`` 0, a dense SwiGLU. ``scale_emb``,
+``residual_scale`` and ``logit_divisor`` are the muP constants (1: none).
+Norms, rotary and the embedding are ``models/transformer.py``'s.
 
 The parameter tree stacks every per-layer leaf over *all* layers under
 ``"layers"`` (both mixers' leaves for every layer: the layout a checkpoint
@@ -16,10 +21,10 @@ loader or the benchmark's weight table hands over); :func:`serving_params`
 keeps, of each mixer, the layers that use it, and that is what the forward
 passes and the serving runner (``inference/hybrid_runner.py``) take:
 
-    layers  ln1, ln2, moe.{router, shared, shared_gate}   [L, ...]
-    experts wg, wi, wo                                     [L, E_held, ...]
-    gdn     the recurrent layers' mixer                    [L - L/period, ...]
-    attn    the full layers' mixer                         [L / period, ...]
+    layers  ln1, ln2, moe.{router, shared, shared_gate} | mlp   [L, ...]
+    experts wg, wi, wo (none with a dense feed-forward)    [L, E_held, ...]
+    gdn | lightning  the recurrent layers' mixer           [recurrent, ...]
+    attn    the full layers' mixer                         [full, ...]
 
 ``experts_held`` / ``expert_offset`` give the chip's share of the routed
 experts (None: all of them); the router always has ``num_experts`` outputs.
@@ -36,7 +41,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from deepspeed_tpu.models.transformer import (TransformerConfig, _norm, _rope)
-from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk
+from deepspeed_tpu.ops import block_sparse
+from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, lightning_chunk
 from deepspeed_tpu.parallel.moe import GateConfig, moe_ffn_share
 from deepspeed_tpu.runtime.sharding import (effective_dtype,
                                             vocab_parallel_lookup)
@@ -45,8 +51,8 @@ from deepspeed_tpu.runtime.sharding import (effective_dtype,
 @dataclasses.dataclass(frozen=True)
 class HybridConfig(TransformerConfig):
     """``TransformerConfig`` (hidden, heads, KV heads, vocabulary, rope,
-    norm) plus the recurrent mixer, the attention's extras and the experts.
-    ``num_layers`` is a whole number of periods."""
+    norm) plus the layer kinds, the recurrent mixer, the attention's extras
+    and the feed-forward."""
 
     attn_head_dim: int = 256
     partial_rotary_factor: float = 0.25
@@ -62,13 +68,42 @@ class HybridConfig(TransformerConfig):
     shared_ffn_size: int = 512
     experts_held: Optional[int] = None  # None: all of them
     expert_offset: int = 0
+    # the published model's mixers, one letter a layer ("m": full, "l":
+    # recurrent), of which this stack holds ``num_layers`` from
+    # ``first_layer`` on; None: every ``full_attention_interval``-th is full
+    layer_pattern: Optional[str] = None
+    first_layer: int = 0
+    recurrent_kind: str = "gdn"         # gdn | lightning
+    # muP: the embedding's scale, the scale of every residual branch, and
+    # what the final hidden state is divided by before the head
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    # the block-sparse rule of the full layers (ops/block_sparse.py);
+    # sparse_topk 0: plain causal attention
+    sparse_topk: int = 0
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
 
     def __post_init__(self):
         super().__post_init__()
-        if self.num_layers % self.full_attention_interval:
+        if self.layer_pattern is None:
+            if self.num_layers % self.full_attention_interval:
+                raise ValueError(
+                    f"num_layers={self.num_layers} is not a whole number of "
+                    f"periods of {self.full_attention_interval} layers")
+        elif (set(self.layer_pattern) - set("ml") or self.first_layer
+              + self.num_layers > len(self.layer_pattern)):
             raise ValueError(
-                f"num_layers={self.num_layers} is not a whole number of "
-                f"periods of {self.full_attention_interval} layers")
+                f"layers {self.first_layer}..{self.first_layer + self.num_layers}"
+                f" lie outside the pattern {self.layer_pattern!r} (m | l)")
+        if self.recurrent_kind not in ("gdn", "lightning"):
+            raise ValueError(f"recurrent_kind {self.recurrent_kind!r}")
+        self.sparse                     # the sizes check themselves
         if self.linear_num_value_heads % self.linear_num_key_heads:
             raise ValueError("linear value heads must be a multiple of the "
                              "key heads")
@@ -87,17 +122,42 @@ class HybridConfig(TransformerConfig):
             else self.experts_held
 
     @property
-    def periods(self) -> int:
-        return self.num_layers // self.full_attention_interval
+    def layer_kinds(self) -> Tuple[bool, ...]:
+        """For each layer held here, whether it is full."""
+        if self.layer_pattern is None:
+            per = self.full_attention_interval
+            return tuple((l + 1) % per == 0 for l in range(self.num_layers))
+        held = self.layer_pattern[self.first_layer:
+                                  self.first_layer + self.num_layers]
+        return tuple(c == "m" for c in held)
+
+    @property
+    def stack_plan(self) -> Tuple[int, Tuple[Tuple[bool, int], ...]]:
+        """``(repeats, runs)``: the layer kinds as ``repeats`` copies of the
+        shortest pattern that tiles them, the pattern as runs ``(full,
+        layers)`` of one kind. The serving runner scans the repeats and,
+        inside, each run: one layer body a run, whatever the depth."""
+        kinds, L = self.layer_kinds, self.num_layers
+        p = next(p for p in range(1, L + 1)
+                 if L % p == 0 and kinds == kinds[:p] * (L // p))
+        runs = []
+        for full in kinds[:p]:
+            if runs and runs[-1][0] == full:
+                runs[-1][1] += 1
+            else:
+                runs.append([full, 1])
+        return L // p, tuple((f, n) for f, n in runs)
 
     @property
     def kv_layers(self) -> int:
         """Layers that hold keys and values: the full ones."""
-        return self.periods
+        return sum(self.layer_kinds)
+
+    periods = kv_layers                 # Qwen3-Next: one full layer a period
 
     @property
     def recurrent_layers(self) -> int:
-        return self.num_layers - self.periods
+        return self.num_layers - self.kv_layers
 
     @property
     def conv_channels(self) -> int:
@@ -105,12 +165,39 @@ class HybridConfig(TransformerConfig):
                 + self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
+    def conv_taps(self) -> int:
+        """Taps of the recurrent mixer's convolution (lightning: none)."""
+        return self.linear_conv_kernel_dim if self.recurrent_kind == "gdn" else 1
+
+    @property
+    def sparse(self) -> Optional[block_sparse.SparseSizes]:
+        if not self.sparse_topk:
+            return None
+        return block_sparse.SparseSizes(
+            kernel=self.sparse_kernel_size, stride=self.sparse_kernel_stride,
+            block=self.sparse_block_size, init_blocks=self.sparse_init_blocks,
+            window=self.sparse_window_size, topk=self.sparse_topk,
+            dense_len=self.sparse_dense_len)
+
+    def lightning_decay(self) -> jax.Array:
+        """``log a`` [recurrent layers, heads] float32, a constant of the
+        head and of the layer's *published* index ``l``: ``-s_j (1 - l /
+        (layers - 1) + 1e-5)``, ``s_j = 2^(-8 (j + 1) / heads)`` (the
+        lightning-attention convention)."""
+        n = self.linear_num_value_heads
+        total = len(self.layer_pattern or "") or self.num_layers
+        slope = 2.0 ** (-8.0 * (jnp.arange(n, dtype=jnp.float32) + 1.0) / n)
+        ls = jnp.asarray([self.first_layer + l for l, full in
+                          enumerate(self.layer_kinds) if not full], jnp.float32)
+        return -slope[None, :] * (1.0 - ls[:, None] / max(total - 1, 1) + 1e-5)
+
+    @property
     def gate(self) -> GateConfig:
         return GateConfig(num_experts=self.num_experts, top_k=self.top_k,
                           drop_tokens=False)
 
     def is_full(self, layer: int) -> bool:
-        return (layer + 1) % self.full_attention_interval == 0
+        return self.layer_kinds[layer]
 
     def num_params(self) -> int:
         return sum(math.prod(s) for s in jax.tree.leaves(
@@ -120,6 +207,8 @@ class HybridConfig(TransformerConfig):
         """Forward and backward, 6 a weight a token touches (the experts:
         ``top_k`` and the shared one)."""
         h = self.hidden_size
+        if not self.num_experts:
+            return 6.0 * self.num_params()
         active = 3 * h * (self.top_k * self.moe_ffn_size + self.shared_ffn_size)
         held_all = 3 * h * self.moe_ffn_size * self.held
         return 6.0 * (self.num_params() - self.num_layers * (held_all - active))
@@ -132,6 +221,28 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     e, f, fs = cfg.held, cfg.moe_ffn_size, cfg.shared_ffn_size
+    if cfg.recurrent_kind == "gdn":
+        rec = {"wq": (L, h, nk, dk), "wk": (L, h, nk, dk),
+               "wv": (L, h, nv, dv), "wz": (L, h, nv, dv),
+               "wb": (L, h, nv), "wa": (L, h, nv),
+               "conv": (L, cfg.linear_conv_kernel_dim, cfg.conv_channels),
+               "A_log": (L, nv), "dt_bias": (L, nv), "norm": (L, dv),
+               "wo": (L, nv, dv, h)}
+    else:
+        rec = {"wq": (L, h, nv, dk), "wk": (L, h, nv, dk),
+               "wv": (L, h, nv, dv), "wz": (L, h, nv, dv),
+               "q_norm": (L, dk), "k_norm": (L, dk), "norm": (L, dv),
+               "wo": (L, nv, dv, h)}
+    if cfg.num_experts:
+        ffn = {"moe": {"router": (L, h, cfg.num_experts),
+                       "experts": {"wg": (L, e, h, f), "wi": (L, e, h, f),
+                                   "wo": (L, e, f, h)},
+                       "shared": {"wg": (L, h, fs), "wi": (L, h, fs),
+                                  "wo": (L, fs, h)},
+                       "shared_gate": (L, h)}}
+    else:
+        ffn = {"mlp": {"wg": (L, h, cfg.ffn_size), "wi": (L, h, cfg.ffn_size),
+                       "wo": (L, cfg.ffn_size, h)}}
     return {
         "embed": {"tokens": (v, h)},
         "final_norm": {"scale": (h,)},
@@ -141,23 +252,13 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
             "attn": {"wq": (L, h, nq, 2 * d), "wk": (L, h, nkv, d),
                      "wv": (L, h, nkv, d), "wo": (L, nq, d, h),
                      "q_norm": (L, d), "k_norm": (L, d)},
-            "gdn": {"wq": (L, h, nk, dk), "wk": (L, h, nk, dk),
-                    "wv": (L, h, nv, dv), "wz": (L, h, nv, dv),
-                    "wb": (L, h, nv), "wa": (L, h, nv),
-                    "conv": (L, cfg.linear_conv_kernel_dim, cfg.conv_channels),
-                    "A_log": (L, nv), "dt_bias": (L, nv), "norm": (L, dv),
-                    "wo": (L, nv, dv, h)},
-            "moe": {"router": (L, h, cfg.num_experts),
-                    "experts": {"wg": (L, e, h, f), "wi": (L, e, h, f),
-                                "wo": (L, e, f, h)},
-                    "shared": {"wg": (L, h, fs), "wi": (L, h, fs),
-                               "wo": (L, fs, h)},
-                    "shared_gate": (L, h)},
+            cfg.recurrent_kind: rec, **ffn,
         },
     }
 
 
 _GAINS = ("scale", "q_norm", "k_norm", "norm")
+_MIXERS = ("attn", "gdn", "lightning")
 
 
 def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
@@ -182,7 +283,7 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
             x = jax.random.normal(key, shape, cfg.param_dtype) * 0.5
         else:
             if name == "wo":   # contracts everything but the last axis
-                fan = math.prod(shape[2:-1]) if group in ("attn", "gdn") \
+                fan = math.prod(shape[2:-1]) if group in _MIXERS \
                     else shape[-2]
             else:              # [L, (E,) h, ...]: contracts h
                 fan = cfg.hidden_size
@@ -193,6 +294,27 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
 
 def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
     L = "layers"
+    rec = {"wq": (L, "embed", None, None), "wk": (L, "embed", None, None),
+           "wv": (L, "embed", None, None), "wz": (L, "embed", None, None),
+           "norm": (L, None), "wo": (L, None, None, "embed")}
+    if cfg.recurrent_kind == "gdn":
+        rec.update({"wb": (L, "embed", None), "wa": (L, "embed", None),
+                    "conv": (L, None, None), "A_log": (L, None),
+                    "dt_bias": (L, None)})
+    else:
+        rec.update({"q_norm": (L, None), "k_norm": (L, None)})
+    if cfg.num_experts:
+        ffn = {"moe": {"router": (L, "embed", None),
+                       "experts": {"wg": (L, "expert", "embed", None),
+                                   "wi": (L, "expert", "embed", None),
+                                   "wo": (L, "expert", None, "embed")},
+                       "shared": {"wg": (L, "embed", None),
+                                  "wi": (L, "embed", None),
+                                  "wo": (L, None, "embed")},
+                       "shared_gate": (L, "embed")}}
+    else:
+        ffn = {"mlp": {"wg": (L, "embed", "mlp"), "wi": (L, "embed", "mlp"),
+                       "wo": (L, "mlp", "embed")}}
     return {
         "embed": {"tokens": ("vocab", "embed")},
         "final_norm": {"scale": ("embed",)},
@@ -206,22 +328,7 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
                      "q_norm": (L, "head_dim"), "k_norm": (L, "head_dim")},
             # the recurrent mixer is replicated: its state pool is per
             # sequence, not per head shard
-            "gdn": {"wq": (L, "embed", None, None),
-                    "wk": (L, "embed", None, None),
-                    "wv": (L, "embed", None, None),
-                    "wz": (L, "embed", None, None),
-                    "wb": (L, "embed", None), "wa": (L, "embed", None),
-                    "conv": (L, None, None), "A_log": (L, None),
-                    "dt_bias": (L, None), "norm": (L, None),
-                    "wo": (L, None, None, "embed")},
-            "moe": {"router": (L, "embed", None),
-                    "experts": {"wg": (L, "expert", "embed", None),
-                                "wi": (L, "expert", "embed", None),
-                                "wo": (L, "expert", None, "embed")},
-                    "shared": {"wg": (L, "embed", None),
-                               "wi": (L, "embed", None),
-                               "wo": (L, None, "embed")},
-                    "shared_gate": (L, "embed")},
+            cfg.recurrent_kind: rec, **ffn,
         },
     }
 
@@ -237,13 +344,15 @@ def serving_params(cfg: HybridConfig, params: Dict[str, Any]) -> Dict[str, Any]:
     full = jnp.asarray([l for l in range(cfg.num_layers) if cfg.is_full(l)])
     rec = jnp.asarray([l for l in range(cfg.num_layers) if not cfg.is_full(l)])
     attn = jax.tree.map(lambda x: x[full], layers.pop("attn"))
-    gdn = jax.tree.map(lambda x: x[rec], layers.pop("gdn"))
-    moe = dict(layers["moe"])
-    experts = moe.pop("experts")
-    layers["moe"] = moe
+    mixer = jax.tree.map(lambda x: x[rec], layers.pop(cfg.recurrent_kind))
+    experts = {}
+    if cfg.num_experts:
+        moe = dict(layers["moe"])
+        experts = moe.pop("experts")
+        layers["moe"] = moe
     return {"embed": params["embed"], "final_norm": params["final_norm"],
             "unembed": params["unembed"], "layers": layers,
-            "experts": experts, "gdn": gdn, "attn": attn}
+            "experts": experts, cfg.recurrent_kind: mixer, "attn": attn}
 
 
 # ---------------------------------------------------------------------------
@@ -253,6 +362,12 @@ def serving_params(cfg: HybridConfig, params: Dict[str, Any]) -> Dict[str, Any]:
 
 def _rms(x, gain, eps):
     return _norm(x, {"scale": gain}, "rmsnorm", eps)
+
+
+def residual(cfg: "HybridConfig", x, out):
+    """``x + c * out``, ``c`` the muP scale of a residual branch."""
+    c = cfg.residual_scale
+    return x + out if c == 1.0 else x + jnp.asarray(c, out.dtype) * out
 
 
 def attn_project(cfg: HybridConfig, ap, y, positions):
@@ -269,6 +384,8 @@ def attn_project(cfg: HybridConfig, ap, y, positions):
     rot = int(d * cfg.partial_rotary_factor)
 
     def rope(x):
+        if not rot:
+            return x
         return jnp.concatenate(
             [_rope(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]], -1)
 
@@ -327,6 +444,30 @@ def gdn_output(cfg: HybridConfig, gp, o, z):
     return jnp.einsum("...nd,ndh->...h", o, gp["wo"].astype(dt))
 
 
+def lightning_project(cfg: HybridConfig, mp, y, positions):
+    """Lightning attention's projections of y [..., H]: float32 q (scaled),
+    k [..., n, d] normed and rotated over the whole head, v, and the gate's
+    input z [..., n, d]."""
+    dt = y.dtype
+    q = jnp.einsum("...h,hnd->...nd", y, mp["wq"].astype(dt))
+    k = jnp.einsum("...h,hnd->...nd", y, mp["wk"].astype(dt))
+    v = jnp.einsum("...h,hnd->...nd", y, mp["wv"].astype(dt))
+    z = jnp.einsum("...h,hnd->...nd", y, mp["wz"].astype(dt))
+    q = _rope(_rms(q, mp["q_norm"], cfg.norm_eps), positions, cfg.rope_theta)
+    k = _rope(_rms(k, mp["k_norm"], cfg.norm_eps), positions, cfg.rope_theta)
+    f32 = jnp.float32
+    return (q.astype(f32) / math.sqrt(cfg.linear_key_head_dim), k.astype(f32),
+            v.astype(f32), z)
+
+
+def lightning_output(cfg: HybridConfig, mp, o, z):
+    """``out_proj(sigmoid(z) * rmsnorm(o))``; o float32 [..., n, d]."""
+    dt = z.dtype
+    o = (_rms(o, mp["norm"], cfg.norm_eps).astype(dt)
+         * jax.nn.sigmoid(z.astype(jnp.float32)).astype(dt))
+    return jnp.einsum("...nd,ndh->...h", o, mp["wo"].astype(dt))
+
+
 @jax.named_scope("gdn_conv")
 def causal_conv(taps, tail, x):
     """Depthwise causal convolution over the token axis. taps [K, C]; x
@@ -341,15 +482,70 @@ def causal_conv(taps, tail, x):
 
 
 def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None):
-    """``x + experts(norm(x))`` on flat tokens x [T, H]; returns the
-    routing counts beside it."""
+    """``x + c * ffn(norm(x))`` on flat tokens x [T, H] (``c`` the residual
+    scale): the expert block, with its routing counts beside it, or the dense
+    SwiGLU (no counts)."""
     y = _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
+    if not cfg.num_experts:
+        with jax.named_scope("mlp"):
+            mp, dt = lp["mlp"], y.dtype
+            a = jax.nn.silu(y @ mp["wg"].astype(dt)) * (y @ mp["wi"].astype(dt))
+            return residual(cfg, x, a @ mp["wo"].astype(dt)), None
     moe = lp["moe"]
     out, counts = moe_ffn_share(
         y, moe["router"], experts, cfg.gate, offset=cfg.expert_offset,
         shared=dict(moe["shared"], gate=moe["shared_gate"]), valid=valid,
         layer=layer)
-    return x + out, counts
+    return residual(cfg, x, out), counts
+
+
+def embed_tokens(cfg: HybridConfig, params, ids):
+    dt = effective_dtype(cfg.dtype)
+    x = vocab_parallel_lookup(params["embed"]["tokens"].astype(dt), ids)
+    return x if cfg.scale_emb == 1.0 else x * jnp.asarray(cfg.scale_emb, dt)
+
+
+def head_logits(cfg: HybridConfig, params, x):
+    """Final norm, the muP divisor, the untied head; float32 logits."""
+    x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
+    if cfg.logit_divisor != 1.0:
+        x = x / jnp.asarray(cfg.logit_divisor, x.dtype)
+    return jnp.einsum("...h,hv->...v", x, params["unembed"]["kernel"].astype(
+        x.dtype)).astype(jnp.float32)
+
+
+def full_attention(cfg: HybridConfig, q, k, v, positions):
+    """Causal softmax attention of whole sequences, no cache: q [B, S, nq,
+    d]; k, v [B, S, nkv, d]. With the sparse rule, the masked-dense form:
+    compressed keys from ``k``, each query's blocks, a mask."""
+    B, S = q.shape[:2]
+    g_ = cfg.num_heads // cfg.kv_heads
+    dt = q.dtype
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    qh = q.reshape(B, S, cfg.kv_heads, g_, cfg.head_dim)
+    ok = jnp.broadcast_to(
+        (jnp.arange(S)[:, None] >= jnp.arange(S)[None, :])[None, None],
+        (B, cfg.kv_heads, S, S))
+    sz = cfg.sparse
+    if sz is not None:
+        pad = (-S) % sz.block
+        nblocks = (S + pad) // sz.block
+        W = nblocks * sz.per_block
+        kp = jnp.pad(k, ((0, 0), (0, pad + sz.kernel), (0, 0), (0, 0)))
+        at = (jnp.arange(W) * sz.stride)[:, None] + jnp.arange(sz.kernel)
+        ck = block_sparse.compress_windows(kp[:, at]).astype(dt)  # [B,W,k,d]
+
+        def one(qs, cks, ts):
+            idx, _, _ = block_sparse.select_blocks(sz, qs, cks, ts, scale)
+            return block_sparse.block_mask(sz, idx, ts, nblocks)
+
+        mask = jax.vmap(one)(qh, ck, positions)          # [B, S, nkv, blocks]
+        mask = jnp.repeat(mask, sz.block, axis=-1)[..., :S]
+        ok = ok & jnp.moveaxis(mask, 1, 2)
+    s = jnp.einsum("bskgd,btkd->bkgst", qh, k).astype(jnp.float32) * scale
+    s = jnp.where(ok[:, :, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("bkgst,btkd->bskgd", pr, v).reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -363,15 +559,14 @@ def apply(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
     empty state, the recurrence in its chunked form."""
     p = serving_params(cfg, params)
     B, S = tokens.shape
-    dt = effective_dtype(cfg.dtype)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    x = vocab_parallel_lookup(p["embed"]["tokens"].astype(dt), tokens)
-    per = cfg.full_attention_interval
+    x = embed_tokens(cfg, p, tokens)
+    dt = x.dtype
     nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                   cfg.linear_value_head_dim)
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    g_ = cfg.num_heads // cfg.kv_heads
+    state0 = jnp.zeros((B, nv, dk, dv), jnp.float32)
+    decay = cfg.lightning_decay() if cfg.recurrent_kind == "lightning" else None
 
     def layer_of(l):
         return jax.tree.map(lambda a: a[l], p["layers"])
@@ -381,32 +576,35 @@ def apply(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
                               x.reshape(B * S, -1), l)
         return out.reshape(B, S, -1)
 
+    l_kv = l_rec = 0
     for l in range(cfg.num_layers):
         lp = layer_of(l)
         y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
         if cfg.is_full(l):
-            ap = jax.tree.map(lambda a: a[l // per], p["attn"])
+            ap = jax.tree.map(lambda a: a[l_kv], p["attn"])
             q, k, v, gate = attn_project(cfg, ap, y, positions)
-            qh = q.reshape(B, S, cfg.kv_heads, g_, cfg.head_dim)
-            s = jnp.einsum("bskgd,btkd->bkgst", qh, k).astype(jnp.float32)
-            s = jnp.where(causal, s / math.sqrt(cfg.head_dim), -1e30)
-            pr = jax.nn.softmax(s, axis=-1).astype(dt)
-            a = jnp.einsum("bkgst,btkd->bskgd", pr, v).reshape(q.shape)
-            x = x + attn_output(ap, a, gate)
-        else:
-            gp = jax.tree.map(lambda a: a[l - l // per], p["gdn"])
+            a = full_attention(cfg, q, k, v, positions)
+            x = residual(cfg, x, attn_output(ap, a, gate))
+            l_kv += 1
+        elif cfg.recurrent_kind == "gdn":
+            gp = jax.tree.map(lambda a: a[l_rec], p["gdn"])
             mixed, z, beta, g = gdn_project(cfg, gp, y)
             tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1,
                               cfg.conv_channels), dt)
             conv, _ = causal_conv(gp["conv"], tail, mixed)
             qf, kf, vf = gdn_heads(cfg, conv)
-            o, _ = gdn_chunk(qf, kf, vf, g, beta,
-                             jnp.zeros((B, nv, dk, dv), jnp.float32))
-            x = x + gdn_output(cfg, gp, o, z)
+            o, _ = gdn_chunk(qf, kf, vf, g, beta, state0)
+            x = residual(cfg, x, gdn_output(cfg, gp, o, z))
+            l_rec += 1
+        else:
+            mp = jax.tree.map(lambda a: a[l_rec], p["lightning"])
+            qf, kf, vf, z = lightning_project(cfg, mp, y, positions)
+            g = jnp.broadcast_to(decay[l_rec], (B, S, nv))
+            o, _ = lightning_chunk(qf, kf, vf, g, state0)
+            x = residual(cfg, x, lightning_output(cfg, mp, o, z))
+            l_rec += 1
         x = ffn(x, l)
-    x = _rms(x, p["final_norm"]["scale"], cfg.norm_eps)
-    return jnp.einsum("bsh,hv->bsv", x,
-                      p["unembed"]["kernel"].astype(dt)).astype(jnp.float32)
+    return head_logits(cfg, p, x)
 
 
 def loss_fn(cfg: HybridConfig, params, batch) -> Tuple[jax.Array, Dict]:

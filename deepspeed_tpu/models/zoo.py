@@ -8,9 +8,11 @@ Named configurations, by the class that runs them:
   inference/v2/model_implementations/*);
 * ``MoETransformerLM`` (models/moe_transformer.py), the same attention with
   a plain softmax top-k expert layer: Mixtral, Qwen2-MoE;
-* ``HybridLM`` (models/hybrid.py), periods of gated-DeltaNet layers and one
-  gated-attention layer, each with a shared-expert MoE that may hold a share
-  of the routed experts: Qwen3-Next.
+* ``HybridLM`` (models/hybrid.py), recurrent layers among full-attention
+  ones in a listed order: Qwen3-Next (periods of gated-DeltaNet layers and
+  one gated-attention layer, each with a shared-expert MoE that may hold a
+  share of the routed experts) and MiniCPM-SALA (lightning attention among
+  block-sparse attention, a dense feed-forward, muP scalings).
 
 ``get_model`` finds the class by the configuration's type
 (``_model_classes``).
@@ -137,6 +139,40 @@ def _register_hybrid():
             linear_key_head_dim=32, linear_value_head_dim=32,
             num_experts=16, top_k=4, moe_ffn_size=32, shared_ffn_size=32,
             experts_held=4, remat=False),
+        # MiniCPM-SALA (huggingface.co/openbmb/MiniCPM-SALA config.json,
+        # model_type minicpm_sala) at the published values: 8 block-sparse
+        # softmax layers ("m", no rotary) among 24 lightning-attention
+        # layers ("l"), a dense SwiGLU under each, muP scalings. The seven
+        # sparse sizes are the MiniCPM4 family's sparse_config.
+        "minicpm-sala": HybridConfig(
+            vocab_size=73448, hidden_size=4096, num_layers=32, num_heads=32,
+            num_kv_heads=2, attn_head_dim=128, ffn_size=16384,
+            max_seq_len=524288, pos_emb="rope", norm="rmsnorm",
+            activation="swiglu", tie_embeddings=False, rope_theta=1e4,
+            norm_eps=1e-6, partial_rotary_factor=0.0,
+            layer_pattern="mllllllllmllllllmmllllmllllllmmm",
+            recurrent_kind="lightning", linear_num_key_heads=32,
+            linear_num_value_heads=32, linear_key_head_dim=128,
+            linear_value_head_dim=128, num_experts=0, scale_emb=12.0,
+            residual_scale=1.4 / 32 ** 0.5, logit_divisor=4096 / 256,
+            sparse_topk=64, sparse_kernel_size=32, sparse_kernel_stride=16,
+            sparse_block_size=64, sparse_init_blocks=1,
+            sparse_window_size=2048, sparse_dense_len=8192),
+        # the same stack at a toy size, the sparse sizes shrunk so that a
+        # prompt of a hundred tokens crosses dense_len
+        "tiny-sala": HybridConfig(
+            vocab_size=256, hidden_size=64, num_layers=4, num_heads=4,
+            num_kv_heads=2, attn_head_dim=32, ffn_size=128, max_seq_len=256,
+            pos_emb="rope", norm="rmsnorm", activation="swiglu",
+            tie_embeddings=False, rope_theta=1e4, norm_eps=1e-6,
+            partial_rotary_factor=0.0, layer_pattern="lmllmlml",
+            first_layer=1, recurrent_kind="lightning",
+            linear_num_key_heads=4, linear_num_value_heads=4,
+            linear_key_head_dim=32, linear_value_head_dim=32, num_experts=0,
+            scale_emb=12.0, residual_scale=1.4 / 8 ** 0.5, logit_divisor=4.0,
+            sparse_topk=5, sparse_kernel_size=8, sparse_kernel_stride=4,
+            sparse_block_size=16, sparse_init_blocks=1, sparse_window_size=32,
+            sparse_dense_len=64, remat=False),
     })
 
 

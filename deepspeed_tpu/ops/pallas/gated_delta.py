@@ -17,6 +17,12 @@ Two forms of the same recurrence:
   rounding; every pairwise decay is formed as ``exp(G_i - G_j)`` with
   ``i >= j``, so nothing overflows however fast a head forgets.
 
+Lightning attention (Qin et al. 2024, arXiv:2401.04658), the plain decayed
+outer-product memory ``S <- a S + k_t v_t^T; o_t = S^T q_t`` with a decay that
+is a constant of the head, lives on the same pool in the same two forms:
+:func:`lightning_decode` (a Pallas kernel named ``lightning_decode``) and
+:func:`lightning_chunk`.
+
 The pool is ``state[layers, slots, heads, key_dim, value_dim]`` float32
 (``inference/ragged/state_pool.py``); the kernel takes it whole with the
 layer and the slots as scalar-prefetch operands, so a step program never
@@ -213,5 +219,124 @@ def gdn_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         return S, o
 
     state, o = jax.lax.scan(step, state, (Wv, Wk, qk, qg, kg, last))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)   # [B, N, C, n, dv]
+    return o.reshape(B, N * C, n, dv)[:, :T], state
+
+
+def _lightning_kernel(layer_ref, slot_ref, q_ref, k_ref, v_ref, dec_ref,
+                      s_ref, o_ref, s_out_ref, *, heads: int):
+    del layer_ref, slot_ref                  # used by the index maps
+    dk = s_ref.shape[-2]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    for h in range(heads):                   # static: one head's tile at a time
+        row = slice(h, h + 1)
+        S = (s_ref[0, 0, h] * dec_ref[0, row, :]
+             + _column(k_ref[0, row, :], eye) * v_ref[0, row, :])
+        o_ref[0, row, :] = jnp.sum(S * _column(q_ref[0, row, :], eye),
+                                   axis=0, keepdims=True)
+        s_out_ref[0, 0, h] = S
+
+
+def lightning_decode(state: jax.Array, layer, slots: jax.Array, q: jax.Array,
+                     k: jax.Array, v: jax.Array, decay: jax.Array):
+    """One token of ``S <- a S + k v^T; o = S^T q`` for each row of the
+    batch, in place on the pool (``gdn_decode``'s contract and layout).
+
+    state  [L, slots, n, d, d] float32 — donated by the caller's program
+    layer  int32 scalar (traced);  slots [B] int32 (dead rows: scratch)
+    q, k, v [B, n, d] float32 (q already scaled)
+    decay  [B, n] float32: the head's ``a`` (1 for a row that is to keep
+           its state: a dead row writes ``k v^T`` into the scratch slot)
+
+    Returns (o [B, n, d] float32, state').
+    """
+    _, _, n, dk, dv = state.shape
+    B = q.shape[0]
+    if dk != dv:
+        raise ValueError(f"lightning_decode takes square heads, got {dk} x {dv}")
+    hb = min(HEADS_PER_BLOCK, n)
+    while n % hb:
+        hb -= 1
+    dec = jnp.broadcast_to(decay[:, :, None], (B, n, dv))
+
+    def vec_spec():
+        return pl.BlockSpec((1, hb, dk), lambda b, h, lyr, sl: (b, h, 0))
+
+    def state_spec():
+        return pl.BlockSpec((1, 1, hb, dk, dv),
+                            lambda b, h, lyr, sl: (lyr[0], sl[b], h, 0, 0))
+
+    o, state = pl.pallas_call(
+        functools.partial(_lightning_kernel, heads=hb),
+        name="lightning_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, n // hb),
+            in_specs=[vec_spec(), vec_spec(), vec_spec(), vec_spec(),
+                      state_spec()],
+            out_specs=[vec_spec(), state_spec()],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, n, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operands count the two scalar-prefetch arrays: the pool is the 7th
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+      q, k, v, dec, state)
+    return o, state
+
+
+@jax.named_scope("lightning_chunk")
+def lightning_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                    state: jax.Array, *, chunk: int = CHUNK):
+    """``S <- e^(g_t) S + k_t v_t^T; o_t = S^T q_t`` over ``T`` tokens of each
+    of ``B`` sequences, in chunks.
+
+    q, k [B, T, n, dk]; v [B, T, n, dv]; g [B, T, n] (the log-decay, <= 0; a
+    padding token carries ``g = 0`` and ``k = 0``: it decays nothing and
+    writes nothing); state [B, n, dk, dv] — all float32. Returns (o [B, T, n,
+    dv], state').
+
+    Within a chunk, with ``G`` the running sum of ``g`` and ``S_0`` the state
+    before it: ``o = (e^G q) S_0 + ((q k^T) * D) v`` with ``D_ij = e^(G_i -
+    G_j)`` on and below the diagonal, formed from the difference (a quotient
+    of powers underflows for a head that forgets in a token); ``S_C = e^(G_C)
+    S_0 + (e^(G_C - G) k)^T v``.
+    """
+    B, T, n, dk = q.shape
+    dv = v.shape[-1]
+    C = chunk
+    pad = (-T) % C
+    if pad:
+        q, k, v, g = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+                      for a in (q, k, v, g))
+    N = (T + pad) // C
+
+    def split(a):                  # [B, T, n, ...] -> [N, B, n, C, ...]
+        a = a.reshape((B, N, C) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
+
+    q, k, v, g = map(split, (q, k, v, g))
+    G = jnp.cumsum(g, axis=-1)                                  # [N, B, n, C]
+    i = jnp.arange(C)
+    D = jnp.exp(jnp.where(i[:, None] >= i[None, :],
+                          G[..., :, None] - G[..., None, :], -jnp.inf))
+    qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=HIGHEST) * D
+    intra = jnp.einsum("...ij,...jv->...iv", qk, v, precision=HIGHEST)
+    qg = q * jnp.exp(G)[..., None]
+    kg = k * jnp.exp(G[..., -1:] - G)[..., None]
+    last = jnp.exp(G[..., -1])                                  # [N, B, n]
+
+    def step(S, c):
+        intra_c, qg_c, kg_c, v_c, last_c = c
+        o = intra_c + jnp.einsum("bnck,bnkv->bncv", qg_c, S, precision=HIGHEST)
+        S = (last_c[..., None, None] * S
+             + jnp.einsum("bnck,bncv->bnkv", kg_c, v_c, precision=HIGHEST))
+        return S, o
+
+    state, o = jax.lax.scan(step, state, (intra, qg, kg, v, last))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)   # [B, N, C, n, dv]
     return o.reshape(B, N * C, n, dv)[:, :T], state

@@ -343,3 +343,82 @@ def test_paged_decode_at_head_size_256_group_8(chip):
 
     _compile(chip, f, ((32, 16, 256), BF16), ((2, 2080, 16, 2, 2, 256), BF16),
              ((32, 64), jnp.int32), ((32,), jnp.int32))
+
+
+# the third architecture at its published widths and the cell's shapes
+# (MiniCPM-SALA, layers 9-16: 32 lightning heads of 128 x 128; 2 KV heads of
+# 128 in 64-token pages; 16,384 pages, 512 a sequence, 32 sequences)
+
+
+def test_lightning_decode_in_place_on_the_state_pool(chip):
+    B, n, d = 32, 32, 128
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in (
+        ((6, 41, n, d, d), f32), ((), jnp.int32), ((B,), jnp.int32),
+        ((B, n, d), f32), ((B, n, d), f32), ((B, n, d), f32), ((B, n), f32))]
+    exe = jax.jit(gated_delta.lightning_decode, donate_argnums=(0,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    mem = exe.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**20           # no copy of the pool
+    assert mem.alias_size_in_bytes >= 6 * 41 * n * d * d * 4
+
+
+def _sala_c1(chip):
+    """``minicpm-sala-serve-c1`` as abstract arguments on the described chip.
+    Returns (step programs, serving params, pools, ids, bytes of the pools)."""
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.zoo import get_model
+
+    blocks, bs, pages, slots = 16384, 64, 512, 41
+    model = get_model("minicpm-sala", num_layers=8, first_layer=9,
+                      max_seq_len=pages * bs, param_dtype=BF16, remat=False)
+    cfg = model.config
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                       jax.eval_shape(model.init, jax.random.PRNGKey(0))))
+    pools = {"kv": sds((2, blocks, bs, 2, 2, 128), BF16),
+             "ck": sds((2, blocks, 4, 2, 128), BF16),
+             "state": sds((6, slots, 32, 128, 128), jnp.float32),
+             "conv": sds((6, slots, 0, 3 * 32 * 128), BF16),
+             "counters": sds((6,), jnp.int32)}
+    held = sum(2 * x.size if x.dtype == BF16 else 4 * x.size
+               for x in pools.values())
+    return (engine_v2._shared_step_fns(cfg, None), params, pools,
+            lambda *shape: sds(shape, jnp.int32), held)
+
+
+@pytest.mark.parametrize("program", ["decode", "multi_decode", "prefill"])
+def test_sala_programs_keep_the_three_pools_in_place(chip, program):
+    """The paged pool (2 GiB, its pages ``[64, 2, 2, 128]`` bf16 tiled
+    without padding), the compressed keys and the state pool come back in
+    the buffers they came in, and no program holds a temporary near 2 GiB:
+    the token step 0.07 GiB, the 8-step burst 0.9 (it lays the mixers'
+    projections out anew once a burst), a 2,048-token chunk of one sequence
+    0.7 (scores of 1,024 keys at a time; the sequence's own 32 MiB of pages
+    a layer, held to the pool's layout so that the pool is not laid out anew
+    for the gather)."""
+    fns, params, pools, ids, held = _sala_c1(chip)
+    S, pages = 32, 512
+    if program == "prefill":
+        lowered = fns["prefill"].lower(params, pools, ids(1, 2048), ids(1),
+                                       ids(1), ids(1, pages), ids(S))
+    else:
+        steps = {"steps": 8} if program == "multi_decode" else {}
+        lowered = fns[program].lower(params, pools, ids(S), ids(S),
+                                     ids(S, pages), ids(S), ids(S), **steps)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if program != "prefill":
+        assert "lightning_decode" in text and "paged_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 64
+    assert mem.temp_size_in_bytes < 2**30
+    # 5.25 GiB of weights + 2.54 GiB of pools
+    assert 7.7 < mem.argument_size_in_bytes / 2**30 < 7.9
