@@ -1070,7 +1070,8 @@ class KernelsConfig(ConfigModel):
     and autotuner axes now (kernel-geometry axis family — candidates
     are shape-legal divisors only, ``autotuning/autotuner.py``). 0
     means "auto": the kernel's seq-derived default for flash, the
-    measured v5e tiles for the grouped matmul, the decode kernel's own
+    grouped product's own tiles (an upper bound on them when set), the
+    decode kernel's own
     block for paged attention (``ops/pallas/paged_attention.py``
     sizes it from the pool's shapes). Which attention kernel runs is not
     set here: ``ops/attention.py`` decides from the backend and the
@@ -1081,9 +1082,11 @@ class KernelsConfig(ConfigModel):
     # 0 = the paged decode kernel chooses its block from the pool's shapes
     # (the prefill kernel folds one page a step); > 0 sets it, for tests
     pages_per_compute_block: int = 0
-    gmm_block_m: int = 512
-    gmm_block_n: int = 1024
-    gmm_block_k: int = 512
+    # 0 = the grouped product chooses its tiles from the shapes
+    # (grouped_matmul.choose_tiles); > 0 is an upper bound on that choice
+    gmm_block_m: int = 0
+    gmm_block_n: int = 0
+    gmm_block_k: int = 0
     blocksparse_block: int = 0  # 0 = follow sparse_attention.block
 
     def validate(self) -> None:

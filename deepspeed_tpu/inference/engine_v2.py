@@ -330,8 +330,9 @@ class InferenceEngineV2:
             # the expert layers' routing, counted on the device by every
             # step program and fetched with the step's tokens: tokens x
             # expert layers, (token, expert) pairs routed to experts held
-            # here, held experts that got a row (summed over calls; the
-            # ``_decode`` pair counts the two decode programs alone); and
+            # here, held experts that got a row, work items of a grouped
+            # product that did a product (summed over calls; the
+            # ``_decode`` three count the two decode programs alone); and
             # the state pool's occupancy; the sparse rule's choices likewise
             # ((query, KV head) pairs' chosen and visible blocks over the
             # queries past dense_len, and the queries below it: the
@@ -340,7 +341,8 @@ class InferenceEngineV2:
             pool = self.kv_cache.state_pool
             self.stats.update(dict.fromkeys(COUNTERS, 0),
                               moe_local_pairs_decode=0,
-                              moe_experts_hit_decode=0, state_slots_in_use=0,
+                              moe_experts_hit_decode=0,
+                              moe_work_items_decode=0, state_slots_in_use=0,
                               state_slots=pool.total_slots,
                               compressed_keys_in_use=0)
         # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
@@ -1020,6 +1022,7 @@ class InferenceEngineV2:
         if decode:
             self.stats["moe_local_pairs_decode"] += counted["moe_local_pairs"]
             self.stats["moe_experts_hit_decode"] += counted["moe_experts_hit"]
+            self.stats["moe_work_items_decode"] += counted["moe_work_items"]
         self.stats["state_slots_in_use"] = pool.slots_in_use
         self.stats["compressed_keys_in_use"] = \
             self.kv_cache.compressed_keys_in_use

@@ -29,8 +29,9 @@ do not tell the models apart. What differs:
   block mask; the gather program is not built.
 
 ``counters`` comes back as this call's ``[moe_token_layers, moe_local_pairs,
-moe_experts_hit, sparse_blocks_selected, sparse_blocks_visible,
-sparse_dense_tokens]`` (summed over the steps of a burst).
+moe_experts_hit, moe_work_items, sparse_blocks_selected,
+sparse_blocks_visible, sparse_dense_tokens]`` (``state_pool.COUNTERS``;
+summed over the steps of a burst).
 """
 
 from __future__ import annotations
@@ -45,6 +46,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.inference.model_runner import (_kv_write, _paged_decode,
                                                   _paged_prefill)
+from deepspeed_tpu.inference.ragged.state_pool import MOE_COUNTERS
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.hybrid import HybridConfig
 from deepspeed_tpu.ops import block_sparse
@@ -52,6 +54,9 @@ from deepspeed_tpu.ops.pallas.gated_delta import (gdn_chunk, gdn_decode,
                                                   lightning_chunk,
                                                   lightning_decode)
 from deepspeed_tpu.runtime.sharding import effective_dtype
+
+
+_MOE = len(MOE_COUNTERS)    # where the expert blocks' counters end
 
 
 def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
@@ -92,9 +97,9 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
             x, c = hybrid.expert_block(cfg, lp, experts, hybrid.residual(
                 cfg, x, out).reshape(-1, H), l, valid)
             if c is not None:
-                pools = dict(pools, counters=pools["counters"].at[:3].add(
+                pools = dict(pools, counters=pools["counters"].at[:_MOE].add(
                     jnp.stack([jnp.sum(valid).astype(jnp.int32), c["pairs"],
-                               c["experts_hit"]])))
+                               c["experts_hit"], c["work_items"]])))
             return (x.reshape(lead + (H,)), pools), None
 
         return layer
@@ -381,7 +386,7 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
             a, counts = _sparse_prefill(cfg, q.astype(dt), kv, ck, l_kv,
                                         block_table, pos, real, ctx_lens)
             pools = dict(pools, ck=ck,
-                         counters=pools["counters"].at[3:].add(counts))
+                         counters=pools["counters"].at[_MOE:].add(counts))
         return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn,
@@ -443,7 +448,7 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
             a, counts = _sparse_decode(cfg, mesh, q.astype(dt), kv, ck, l_kv,
                                        block_table, context_lens)
             pools = dict(pools, ck=ck,
-                         counters=pools["counters"].at[3:].add(counts))
+                         counters=pools["counters"].at[_MOE:].add(counts))
         return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn, alive)

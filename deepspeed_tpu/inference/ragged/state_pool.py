@@ -32,10 +32,12 @@ import jax
 import jax.numpy as jnp
 
 
-# what a step program counts, in the order of its ``counters`` vector
-COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
-            "sparse_blocks_selected", "sparse_blocks_visible",
-            "sparse_dense_tokens")
+# what a step program counts, in the order of its ``counters`` vector: the
+# expert blocks' part, then the block-selecting attention's
+MOE_COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
+                "moe_work_items")
+COUNTERS = MOE_COUNTERS + ("sparse_blocks_selected", "sparse_blocks_visible",
+                           "sparse_dense_tokens")
 
 
 class StateSnapshotUnsupported(NotImplementedError):

@@ -112,15 +112,14 @@ def _reset_dispatch_stats() -> None:
 
 
 def kernel_gmm_tiles() -> dict:
-    """Grouped-matmul tile overrides from the installed ``kernels``
-    config block (kernels.gmm_block_{m,n,k}); empty dict when no engine
-    has installed a config → ``gmm`` keeps its own defaults."""
-    kcfg = _KERNEL_CONFIG
-    if kcfg is None:
-        return {}
-    return {"block_m": int(getattr(kcfg, "gmm_block_m", 512)),
-            "block_n": int(getattr(kcfg, "gmm_block_n", 1024)),
-            "block_k": int(getattr(kcfg, "gmm_block_k", 512))}
+    """Upper bounds on the grouped product's tiles from the installed
+    ``kernels`` config block: those of ``kernels.gmm_block_{m,n,k}`` that
+    are set (positive). The kernel chooses its tiles from the shapes
+    (``grouped_matmul.choose_tiles``); with nothing installed or set,
+    nothing bounds it."""
+    limits = {b: int(getattr(_KERNEL_CONFIG, f"gmm_{b}", 0))
+              for b in ("block_m", "block_n", "block_k")}
+    return {b: v for b, v in limits.items() if v > 0}
 
 
 def _auto_block(seq: int) -> int:

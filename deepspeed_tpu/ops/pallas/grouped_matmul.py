@@ -14,16 +14,26 @@ of how imbalanced the groups are — versus the capacity-padded einsum
 dispatch whose cost is fixed at E*capacity slots and which *drops*
 tokens when a group overflows.
 
-Mechanics: group boundaries rarely align with the 128-row MXU tile, so
+Mechanics: group boundaries rarely align with the row tile, so
 the grid iterates over *work items* — (m-tile, group) pairs that
 intersect — with the per-item tile id, group id, and row range
 scalar-prefetched. A tile crossed by a boundary is visited once per
 group; rows outside the item's group are masked from the product and
 the partial products accumulate in a VMEM scratch across the
-consecutive visits. The number of work items is static:
-M/block_m + E - 1 in the worst case (every interior group boundary adds
-one extra visit); unused slots repeat the last real item with an empty
-row range so they contribute nothing.
+consecutive visits. The number of slots is static: M/block_m + E - 1,
+the worst case (every interior group boundary adds one extra visit). A
+group with no row has no item, and the slots beyond the real items
+repeat the last real item with an empty row range: such a slot does no
+product, touches no accumulator and fetches no new block (its block
+indices are the step's before it), so a call costs what its rows and
+the groups *that got a row* cost, not what the groups held would.
+
+The tiles are the kernel's choice from the static shapes
+(:func:`choose_tiles`): a few rows a group (a serving step: 3 at 384
+rows over 128 experts) is memory-bound and wants a short row tile and a
+group's whole matrix in as few steps as VMEM allows; thousands of rows a
+group (training) is compute-bound and wants the large tiles measured
+below.
 
 The backward is two more grouped products: dlhs = gmm(dout, rhs^T) and
 drhs[e] = lhs_e^T @ dout_e (``tgmm`` below, same metadata, accumulator
@@ -115,6 +125,14 @@ def _num_work_items(m: int, num_groups: int, block_m: int) -> int:
     return m // block_m + num_groups - 1
 
 
+def work_items(metadata) -> jax.Array:
+    """The slots of a work list that hold a row and so do a product — the
+    (m-tile, group) pairs that share one — as an int32 scalar: what a call
+    costs beside its bytes, for the serving counters."""
+    _, _, row_start, row_end = metadata
+    return jnp.sum(row_end > row_start).astype(jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # gmm: out[m] = lhs[m] @ rhs[group(m)]
 # ---------------------------------------------------------------------------
@@ -129,6 +147,48 @@ def _pick_block(dim: int, want: int) -> int:
     return max(b, 1)
 
 
+# a right-hand block of a memory-bound call: double-buffered, two of them
+# stay well inside the 16 MiB of VMEM a v5e kernel gets by default
+_RHS_BLOCK_BYTES = 2 << 20
+
+
+def row_tile(m: int, num_groups: int, dtype) -> int:
+    """The row tile for ``m`` rows over ``num_groups`` groups: the rows a
+    group gets on average, as a power of two between the type's sublane
+    packing (16 rows of bf16) and 512. A tile much taller than a group
+    multiplies masked rows, one much shorter visits a group's matrix more
+    often. It follows from the rows and the groups alone, so the products
+    of one expert block share it, and with it one work list."""
+    rows = -(-m // max(num_groups, 1))
+    return min(max(pl.next_power_of_2(rows), 32 // jnp.dtype(dtype).itemsize),
+               512)
+
+
+def choose_tiles(m: int, kdim: int, n: int, num_groups: int, dtype,
+                 block_m: int = 0, block_n: int = 0, block_k: int = 0
+                 ) -> Tuple[int, int, int]:
+    """(block_m, block_n, block_k) for ``[m, kdim] x [groups, kdim, n]``,
+    from the static shapes alone; a positive argument is an upper bound
+    on that tile (``kernels.gmm_block_*``).
+
+    The row tile is :func:`row_tile`. At 512 rows and more a group the
+    product is compute-bound and takes the tiles measured at the Mixtral
+    geometry (``gmm``); below, it streams weights, and the right-hand
+    block is the whole contraction by as much of ``n`` as
+    ``_RHS_BLOCK_BYTES`` holds, so a group's matrix is fetched once, in
+    as few steps as fit."""
+    itemsize = jnp.dtype(dtype).itemsize
+    bm = row_tile(m, num_groups, dtype)
+    if bm == 512:
+        bn, bk = 1024, 512
+    else:
+        bk = _pick_block(kdim, max(128, _RHS_BLOCK_BYTES // (128 * itemsize)))
+        bn = max(128, _RHS_BLOCK_BYTES // (bk * itemsize))
+    bm, bn, bk = (min(b, cap) if cap > 0 else b
+                  for b, cap in ((bm, block_m), (bn, block_n), (bk, block_k)))
+    return _pick_block(m, bm), _pick_block(n, bn), _pick_block(kdim, bk)
+
+
 def _gmm_kernel(tile_ids, group_ids, row_start, row_end, *refs,
                 block_m: int, transpose_rhs: bool, layered: bool):
     # a layered call carries the layer as a fifth scalar-prefetch operand
@@ -139,37 +199,48 @@ def _gmm_kernel(tile_ids, group_ids, row_start, row_end, *refs,
     t = pl.program_id(1)
     k = pl.program_id(2)
     tile = tile_ids[t]
-    prev_tile = tile_ids[jnp.maximum(t - 1, 0)]
-    first = jnp.logical_and(
-        k == 0, jnp.logical_or(t == 0, tile != prev_tile))
+    start, end = row_start[t], row_end[t]
+    opened = jnp.logical_and(k == 0, jnp.logical_or(
+        t == 0, tile != tile_ids[jnp.maximum(t - 1, 0)]))
 
-    @pl.when(first)
+    @pl.when(opened)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rows = tile * block_m + jax.lax.broadcasted_iota(
-        jnp.int32, (block_m, 1), 0)
-    mask = jnp.logical_and(rows >= row_start[t], rows < row_end[t])
-    if transpose_rhs:  # rhs block [bn, bk], contract both k dims
-        prod = jax.lax.dot_general(
-            lhs_ref[...], rhs_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    else:
-        prod = jnp.dot(lhs_ref[...], rhs_ref[0],
-                       preferred_element_type=jnp.float32)
-    acc_ref[...] += jnp.where(mask, prod, 0.0)
-    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+    @pl.when(end > start)
+    def _product():
+        rows = tile * block_m + jax.lax.broadcasted_iota(
+            jnp.int32, (block_m, 1), 0)
+        mask = jnp.logical_and(rows >= start, rows < end)
+        if transpose_rhs:  # rhs block [bn, bk], contract both k dims
+            prod = jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            prod = jnp.dot(lhs_ref[...], rhs_ref[0],
+                           preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.where(mask, prod, 0.0)
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    # a slot with no row does no product; one that opens a tile (an
+    # uncovered one) hands it back zero-filled
+    @pl.when(jnp.logical_and(opened, end <= start))
+    def _fill():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
               block_m: int, block_n: int, block_k: int,
-              transpose_rhs: bool = False, layer=None) -> jax.Array:
+              transpose_rhs: bool = False, layer=None, metadata=None
+              ) -> jax.Array:
     """out[m] = lhs[m] @ rhs[g(m)] (or @ rhs[g(m)].T when transpose_rhs,
     rhs then being [E, N, K] — saves materializing the swap in the
     backward). With ``layer`` (a traced int32 scalar) ``rhs`` is a stack
     ``[L, E, K, N]`` read at that layer by the index map: a step program
     that loops over layers never slices one layer's experts out of the
-    stack (268 MB a projection at 128 experts of 2048 x 512)."""
+    stack (268 MB a projection at 128 experts of 2048 x 512).
+    ``metadata`` is :func:`make_group_metadata` of these sizes at this
+    row tile, for calls that share one (built here when not given)."""
     m, kdim = lhs.shape
     layered = layer is not None
     if transpose_rhs:
@@ -179,13 +250,26 @@ def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     block_m = _pick_block(m, block_m)
     block_n = _pick_block(n, block_n)
     block_k = _pick_block(kdim, block_k)
-    meta = make_group_metadata(group_sizes, m, block_m)
+    t_total = _num_work_items(m, num_groups, block_m)
+    meta = metadata or make_group_metadata(group_sizes, m, block_m)
+    assert meta[0].shape == (t_total,), (meta[0].shape, t_total)
     if layered:
         meta = meta + (jnp.asarray(layer, jnp.int32).reshape(1),)
-    t_total = _num_work_items(m, num_groups, block_m)
-    grid = (n // block_n, t_total, kdim // block_k)
+    k_steps = kdim // block_k
+    grid = (n // block_n, t_total, k_steps)
+
+    def k_at(t, k, rs, re):
+        # an empty slot stays on the block the step before it ended on:
+        # an unchanged block index is not fetched again
+        if k_steps == 1:
+            return k
+        return jnp.where(re[t] > rs[t], k, k_steps - 1)
+
+    def lhs_index(n, t, k, tiles, gids, rs, re, *_):
+        return tiles[t], k_at(t, k, rs, re)
 
     def rhs_index(n, t, k, tiles, gids, rs, re, *lyr):
+        k = k_at(t, k, rs, re)
         at = (gids[t], n, k) if transpose_rhs else (gids[t], k, n)
         return (lyr[0][0],) + at if layered else at
 
@@ -201,8 +285,7 @@ def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
             num_scalar_prefetch=len(meta),
             grid=grid,
             in_specs=[
-                pl.BlockSpec((block_m, block_k),
-                             lambda n, t, k, tiles, *_: (tiles[t], k)),
+                pl.BlockSpec((block_m, block_k), lhs_index),
                 rhs_spec,
             ],
             out_specs=pl.BlockSpec((block_m, block_n),
@@ -217,13 +300,24 @@ def _gmm_call(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     return out
 
 
+def _tiles(lhs, rhs, block_m: int, block_n: int, block_k: int):
+    """The tiles of ``lhs @ rhs[g]``: each as given, or :func:`choose_tiles`'
+    where given as 0."""
+    auto = choose_tiles(lhs.shape[0], rhs.shape[-2], rhs.shape[-1],
+                        rhs.shape[-3], lhs.dtype)
+    return tuple(b or a for b, a in zip((block_m, block_n, block_k), auto))
+
+
 def gmm_layer(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, layer,
-              block_m: int = 512, block_n: int = 1024, block_k: int = 512
-              ) -> jax.Array:
+              block_m: int = 0, block_n: int = 0, block_k: int = 0,
+              metadata=None) -> jax.Array:
     """:func:`gmm` against one layer of a stack ``rhs [L, E, K, N]``, the
-    layer a traced scalar (forward only: the serving path's experts)."""
-    return _gmm_call(lhs, rhs, group_sizes, block_m, block_n, block_k,
-                     layer=layer)
+    layer a traced scalar (forward only: the serving path's experts).
+    The products of one expert block have one row tile and may share
+    their ``metadata`` (:func:`make_group_metadata` at that tile)."""
+    return _gmm_call(lhs, rhs, group_sizes,
+                     *_tiles(lhs, rhs, block_m, block_n, block_k), layer=layer,
+                     metadata=metadata)
 
 
 # ---------------------------------------------------------------------------
@@ -301,22 +395,28 @@ def _tgmm_call(lhs: jax.Array, dout: jax.Array, group_sizes: jax.Array,
 # public entry (differentiable)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
-        block_m: int = 512, block_n: int = 1024, block_k: int = 512
-        ) -> jax.Array:
+        block_m: int = 0, block_n: int = 0, block_k: int = 0) -> jax.Array:
     """Grouped matmul: row m of ``lhs`` times ``rhs[group(m)]``.
 
     lhs [M, K] sorted by group, rhs [E, K, N], group_sizes [E] int32 with
     sum == M. Returns [M, N] in lhs.dtype (fp32 MXU accumulation).
-    Block sizes are upper bounds — clamped to divisors of each dim.
-    Large blocks keep the kernel compute-bound: rhs[g] is re-read once
-    per m-tile of its group and lhs once per n-tile, so HBM traffic
-    scales with 1/block. Measured on v5e at Mixtral-8x7B geometry
-    (M=32k, K=4096, N=14336): (512, 1024, 512) → 98 TF/s, ~50% of peak;
-    the full no-drop MoE layer runs 2.7x faster than the capacity-einsum
-    dispatch.
+    A block size given as 0 is the kernel's choice from the shapes
+    (:func:`choose_tiles`); a positive one is that tile, snapped to a
+    divisor of its dim. The backward runs the forward's tiles.
+    Large blocks keep a call with many rows a group compute-bound: rhs[g]
+    is re-read once per m-tile of its group and lhs once per n-tile, so
+    HBM traffic scales with 1/block. Measured on v5e at Mixtral-8x7B
+    geometry (M=32k, K=4096, N=14336): (512, 1024, 512) → 98 TF/s, ~50%
+    of peak (149 TF/s on the rig of PERF.md, PR 34); the full no-drop
+    MoE layer runs 2.7x faster than the capacity-einsum dispatch.
     """
+    return _gmm(lhs, rhs, group_sizes,
+                *_tiles(lhs, rhs, block_m, block_n, block_k))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gmm(lhs, rhs, group_sizes, block_m, block_n, block_k):
     return _gmm_call(lhs, rhs, group_sizes, block_m, block_n, block_k)
 
 
@@ -336,4 +436,4 @@ def _gmm_bwd(block_m, block_n, block_k, res, dout):
     return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype), dgs
 
 
-gmm.defvjp(_gmm_fwd, _gmm_bwd)
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)

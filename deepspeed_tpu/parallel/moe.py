@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.ops.attention import kernel_gmm_tiles
+from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 from deepspeed_tpu.runtime.sharding import constrain_activation
 
 
@@ -220,23 +222,21 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, expert_params: Dict[str, jax.Arra
 
 def _expert_ffn(sorted_x: jax.Array, group_sizes: jax.Array,
                 expert_params: Dict[str, jax.Array], activation: str,
-                dt, layer=None) -> jax.Array:
+                dt, layer=None, tile_limits=None, metadata=None
+                ) -> jax.Array:
     """Grouped-GEMM expert FFN over rows sorted by (local) expert. With
     ``layer`` the expert leaves are stacks ``[L, E, ...]`` read at that
-    (traced) layer inside the kernel."""
-    import functools
-
-    from deepspeed_tpu.ops import attention as attn_ops
-    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
-
-    # engine-installed tile geometry (config.kernels.gmm_block_{m,n,k});
-    # gmm snaps each to the largest legal divisor per operand shape
-    tiles = attn_ops.kernel_gmm_tiles()
-    if layer is None:
-        gmm = functools.partial(gm.gmm, **tiles)
-    else:
-        def gmm(lhs, rhs, sizes):
-            return gm.gmm_layer(lhs, rhs, sizes, layer, **tiles)
+    (traced) layer inside the kernel, and the three products share the
+    work list ``metadata`` (their row tile is one: it follows from the
+    rows and the experts alone). The kernel chooses each product's tiles
+    from its shapes; ``tile_limits`` (``block_m`` / ``block_n`` /
+    ``block_k``) are upper bounds on that choice."""
+    def gmm(lhs, rhs, sizes):
+        tiles = gm.choose_tiles(lhs.shape[0], *rhs.shape[-2:], rhs.shape[-3],
+                                lhs.dtype, **(tile_limits or {}))
+        if layer is None:
+            return gm.gmm(lhs, rhs, sizes, *tiles)
+        return gm.gmm_layer(lhs, rhs, sizes, layer, *tiles, metadata=metadata)
     wi, wo = expert_params["wi"].astype(dt), expert_params["wo"].astype(dt)
     if activation == "swiglu":
         wg = expert_params["wg"].astype(dt)
@@ -282,8 +282,10 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
 
     y [T, H] (already normed); ``valid [T]`` marks real tokens (padding is
     routed nowhere). Returns (out [T, H] in y's type, counts): ``pairs``,
-    the (token, expert) pairs routed here, and ``experts_hit``, the held
-    experts that got a row — int32 scalars for the serving counters.
+    the (token, expert) pairs routed here, ``experts_hit``, the held
+    experts that got a row, and ``work_items``, the (row tile, expert)
+    pairs each of the three grouped products multiplies — int32 scalars
+    for the serving counters.
 
     Rows sort by local expert; pairs routed elsewhere sort last and lie
     beyond the groups' sum, where the grouped product yields zeros.
@@ -310,9 +312,12 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
         row_token = token[order]
         group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
             jnp.int32)
+        # one work list for the three products: they share the row tile
+        work = gm.make_group_metadata(group_sizes, m,
+                                      gm.row_tile(m, held, y.dtype))
     with jax.named_scope("moe_experts"):
         out = _expert_ffn(y[row_token], group_sizes, expert_params, "swiglu",
-                          y.dtype, layer=layer)
+                          y.dtype, layer=layer, metadata=work)
         contrib = out.astype(jnp.float32) * flat_w[order][:, None]
         total = jnp.zeros((T, H), jnp.float32).at[row_token].add(contrib)
     if shared is not None:
@@ -326,7 +331,8 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
             total = total + gate[:, None] * (
                 hid @ shared["wo"].astype(dt)).astype(jnp.float32)
     counts = {"pairs": jnp.sum(here).astype(jnp.int32),
-              "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32)}
+              "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32),
+              "work_items": gm.work_items(work)}
     return total.astype(y.dtype), counts
 
 
@@ -468,7 +474,8 @@ def _dropless_shard_core(x: jax.Array, router_w: jax.Array,
         sorted_x = recv_x[order]
         group_sizes = jnp.bincount(local_e, length=e_loc).astype(jnp.int32)
         expert_out = _expert_ffn(sorted_x, group_sizes, expert_params,
-                                 activation, dt)            # [m_rows, H]
+                                 activation, dt,            # [m_rows, H]
+                                 tile_limits=kernel_gmm_tiles())
         unsorted = jnp.zeros((m_rows, H), dt).at[order].set(expert_out)
         # all-to-all #2 (combine): results return to their source shard
         back = lax.all_to_all(unsorted, ep_axis, 0, 0, tiled=True)
@@ -494,7 +501,8 @@ def _dropless_shard_core(x: jax.Array, router_w: jax.Array,
         group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
         sorted_x = flat_x[row_token]                        # [M, H] gather
         expert_out = _expert_ffn(sorted_x, group_sizes, expert_params,
-                                 activation, dt)
+                                 activation, dt,
+                                 tile_limits=kernel_gmm_tiles())
         contrib = expert_out.astype(jnp.float32) * flat_w[:, None]
 
     # combine accumulates in fp32 (bf16 scatter-add would stack rounding

@@ -13,7 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.ops.pallas.grouped_matmul import gmm, make_group_metadata
+from deepspeed_tpu.ops.pallas.grouped_matmul import (choose_tiles, gmm,
+                                                     gmm_layer,
+                                                     make_group_metadata,
+                                                     row_tile, work_items)
 from deepspeed_tpu.parallel.moe import (GateConfig, moe_ffn,
                                         moe_ffn_dropless)
 
@@ -90,6 +93,108 @@ def test_metadata_covers_rows_exactly_once():
         lo, hi = t * bm, (t + 1) * bm
         covered[max(lo, s):min(hi, e)] += 1
     assert (covered == 1).all()
+
+
+# -- work for the rows and the groups that were routed ----------------------
+
+BF16 = jnp.bfloat16
+
+# rows, groups, groups that get a row, real rows (the rest lie beyond the
+# groups' sum, as the pairs routed to experts held elsewhere do)
+ROUTED = {
+    "decode": (384, 128, 57, 80),
+    "lone-sequence": (128, 128, 9, 10),
+    "gather": (2560, 128, 126, 640),
+    "none-routed": (384, 128, 0, 0),
+    "one-group-takes-all": (384, 128, 1, 384),
+    "training-tile": (4096, 8, 8, 4096),
+}
+
+
+def _routed_case(name, kdim=256, n=128):
+    m, groups, hit, real = ROUTED[name]
+    rng = np.random.default_rng(sum(map(ord, name)))
+    sizes = np.zeros(groups, np.int32)
+    chosen = rng.choice(groups, hit, replace=False)
+    sizes[chosen] = 1
+    if hit:
+        np.add.at(sizes, rng.choice(chosen, real - hit), 1)
+    lhs = jnp.asarray(rng.standard_normal((m, kdim)), BF16)
+    rhs = jnp.asarray(rng.standard_normal((2, groups, kdim, n)), BF16)
+    return lhs, rhs, sizes
+
+
+def _per_row_reference(lhs, rhs, sizes):
+    """Row r times its own group's matrix in float32, zero beyond the sum."""
+    lhs, rhs = np.asarray(lhs, np.float32), np.asarray(rhs, np.float32)
+    want, at = np.zeros((lhs.shape[0], rhs.shape[-1]), np.float32), 0
+    for e, size in enumerate(sizes):
+        want[at:at + size] = lhs[at:at + size] @ rhs[e]
+        at += size
+    return want
+
+
+@pytest.mark.parametrize("layered", [False, True], ids=["gmm", "gmm_layer"])
+@pytest.mark.parametrize("name", list(ROUTED))
+def test_forward_at_the_serving_shapes(name, layered):
+    """The kernel's own tiles (16 rows at the decode shapes, 32 at the
+    gather shape, 512 at the training one), most groups without a row, most
+    rows beyond the groups' sum: every routed row is its group's product,
+    every other row zero; the same against one (traced) layer of a stack."""
+    lhs, rhs, sizes = _routed_case(name)
+    if layered:
+        got = jax.jit(gmm_layer)(lhs, rhs, jnp.asarray(sizes), jnp.int32(1))
+    else:
+        got = gmm(lhs, rhs[1], jnp.asarray(sizes))
+    assert got.dtype == BF16
+    want = _per_row_reference(lhs, rhs[1], sizes)
+    # float32 accumulation, one rounding to bf16 at the end
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=2 ** -7, atol=2 ** -5)
+    real = int(sizes.sum())
+    assert not np.asarray(got[real:], np.float32).any()
+
+
+@pytest.mark.parametrize("name", list(ROUTED))
+def test_work_items_are_the_slots_that_hold_a_row(name):
+    """What the serving counter counts, and that three products may share
+    one work list: ``gmm_layer`` given it returns what it returns alone."""
+    m, groups, hit, _ = ROUTED[name]
+    lhs, rhs, sizes = _routed_case(name)
+    block_m = row_tile(m, groups, BF16)
+    work = make_group_metadata(jnp.asarray(sizes), m, block_m)
+    items = int(work_items(work))
+    # a group's rows lie in one tile unless a tile border cuts them
+    assert hit <= items <= hit + m // block_m - (hit > 0)
+    covered = np.zeros(m, np.int32)
+    for t, s, e in zip(*(np.asarray(a) for a in (work[0], work[2], work[3]))):
+        covered[max(t * block_m, s):min((t + 1) * block_m, e)] += 1
+    assert (covered[:sizes.sum()] == 1).all() and not covered[sizes.sum():].any()
+    alone = gmm_layer(lhs, rhs, jnp.asarray(sizes), jnp.int32(0))
+    shared = gmm_layer(lhs, rhs, jnp.asarray(sizes), jnp.int32(0),
+                       metadata=work)
+    assert bool(jnp.array_equal(alone, shared))
+
+
+@pytest.mark.parametrize("m,groups,kdim,n,dtype,limits,tiles", [
+    (384, 128, 2048, 512, BF16, {}, (16, 512, 2048)),      # a decode step
+    (384, 128, 512, 2048, BF16, {}, (16, 2048, 512)),
+    (128, 128, 2048, 512, BF16, {}, (16, 512, 2048)),      # a lone sequence
+    (2560, 128, 2048, 512, BF16, {}, (32, 512, 2048)),     # a gather step
+    (2560, 128, 512, 2048, BF16, {}, (32, 2048, 512)),
+    (384, 128, 2048, 512, jnp.float32, {}, (8, 256, 2048)),
+    (32768, 8, 4096, 14336, BF16, {}, (512, 1024, 512)),   # Mixtral, training
+    (32768, 8, 14336, 4096, BF16, {}, (512, 1024, 512)),
+    (4096, 8, 4096, 14336, BF16, {}, (512, 1024, 512)),
+    (1024, 8, 4096, 14336, BF16, {}, (128, 256, 4096)),    # 128 rows a group
+    # kernels.gmm_block_*: upper bounds on the choice
+    (32768, 8, 4096, 14336, BF16, {"block_m": 256, "block_n": 512},
+     (256, 512, 512)),
+    (384, 128, 2048, 512, BF16, {"block_m": 256, "block_k": 512},
+     (16, 512, 512)),
+])
+def test_tile_rule(m, groups, kdim, n, dtype, limits, tiles):
+    assert choose_tiles(m, kdim, n, groups, dtype, **limits) == tiles
 
 
 @pytest.mark.parametrize("activation", ["swiglu", "gelu"])

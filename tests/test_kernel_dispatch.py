@@ -361,14 +361,23 @@ class TestKernelsConfig:
         assert attn_ops._pick_blocks(8192) == (1024, 1024)
 
     def test_gmm_tiles_helper(self):
+        """``kernels.gmm_block_*``: 0 leaves the tile to the kernel, a set
+        one is an upper bound on the kernel's choice from the shapes."""
         from deepspeed_tpu.config.config import KernelsConfig
+        from deepspeed_tpu.ops.pallas.grouped_matmul import choose_tiles
 
+        bf16 = jnp.bfloat16
         assert attn_ops.kernel_gmm_tiles() == {}
-        attn_ops.set_kernel_config(KernelsConfig(gmm_block_m=256))
         try:
-            tiles = attn_ops.kernel_gmm_tiles()
-            assert tiles == {"block_m": 256, "block_n": 1024,
-                             "block_k": 512}
+            attn_ops.set_kernel_config(KernelsConfig())
+            assert attn_ops.kernel_gmm_tiles() == {}
+            attn_ops.set_kernel_config(KernelsConfig(gmm_block_m=256))
+            limits = attn_ops.kernel_gmm_tiles()
+            assert limits == {"block_m": 256}
+            assert choose_tiles(32768, 4096, 14336, 8, bf16, **limits) == (
+                256, 1024, 512)
+            assert choose_tiles(384, 2048, 512, 128, bf16, **limits) == (
+                16, 512, 2048)
         finally:
             attn_ops.set_kernel_config(None)
 

@@ -13,6 +13,8 @@ import pytest
 
 import deepspeed_tpu as dstpu
 from deepspeed_tpu.inference import engine_v2
+from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.models.zoo import get_model
 from deepspeed_tpu.ops.pallas import (blocksparse_attention, flash_attention,
@@ -94,6 +96,54 @@ def test_step_programs_are_shared_by_config():
     cfg = get_model("tiny").config
     assert engine_v2._shared_step_fns(cfg, None) is \
         engine_v2._shared_step_fns(cfg, None)
+
+
+# -- the grouped product's tiles are chosen when a program is traced ---------
+
+def _grouped_matmul_calls(jaxpr, under_cond=False):
+    """(calls of the ``grouped_matmul`` kernel, those under a ``cond``) in a
+    jaxpr and every jaxpr nested in it."""
+    calls = conds = 0
+    for eqn in jaxpr.eqns:
+        inside = under_cond or eqn.primitive.name == "cond"
+        if eqn.primitive.name == "pallas_call" and re.search(
+                r"\bname=grouped_matmul\b", str(eqn)):
+            calls, conds = calls + 1, conds + inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            c, k = _grouped_matmul_calls(sub, inside)
+            calls, conds = calls + c, conds + k
+    return calls, conds
+
+
+@pytest.mark.parametrize("program", ["gather", "decode", "multi_decode"])
+def test_hybrid_programs_hold_one_grouped_product_a_call_site(program):
+    """Three products an expert block, one block a run of one kind of layer
+    (``stack_plan``): that many kernels in the program and no conditional
+    around any. The tile is a function of the static shapes, so a program
+    holds no variant of the kernel to choose from while it runs."""
+    model = get_model("tiny-hybrid")
+    cfg = model.config
+    params = jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    slots, taps = 5, cfg.linear_conv_kernel_dim - 1
+    pools = {
+        "kv": _sds((cfg.kv_layers, NB, BS, 2, cfg.kv_heads, cfg.head_dim)),
+        "state": _sds((cfg.recurrent_layers, slots,
+                       cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                       cfg.linear_value_head_dim)),
+        "conv": _sds((cfg.recurrent_layers, slots, taps, cfg.conv_channels)),
+        "counters": _sds((len(COUNTERS),), I32)}
+    fns = engine_v2._shared_step_fns(cfg, None)
+    ids = lambda *shape: _sds(shape, I32)  # noqa: E731
+    if program == "gather":
+        traced = fns["step"].trace(params, pools, ids(T), ids(T), ids(T),
+                                   ids(S, BM), ids(), ids(S))
+    else:
+        steps = {"steps": 3} if program == "multi_decode" else {}
+        traced = fns[program].trace(params, pools, ids(S), ids(S), ids(S, BM),
+                                    ids(S), ids(S), **steps)
+    calls, under_cond = _grouped_matmul_calls(traced.jaxpr.jaxpr)
+    assert calls == 3 * len(cfg.stack_plan[1]) and under_cond == 0
 
 
 # -- training programs -------------------------------------------------------

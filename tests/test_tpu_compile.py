@@ -368,6 +368,7 @@ def _sala_c1(chip):
     """``minicpm-sala-serve-c1`` as abstract arguments on the described chip.
     Returns (step programs, serving params, pools, ids, bytes of the pools)."""
     from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
     from deepspeed_tpu.models import hybrid
     from deepspeed_tpu.models.zoo import get_model
 
@@ -387,7 +388,7 @@ def _sala_c1(chip):
              "ck": sds((2, blocks, 4, 2, 128), BF16),
              "state": sds((6, slots, 32, 128, 128), jnp.float32),
              "conv": sds((6, slots, 0, 3 * 32 * 128), BF16),
-             "counters": sds((6,), jnp.int32)}
+             "counters": sds((len(COUNTERS),), jnp.int32)}
     held = sum(2 * x.size if x.dtype == BF16 else 4 * x.size
                for x in pools.values())
     return (engine_v2._shared_step_fns(cfg, None), params, pools,
