@@ -80,10 +80,11 @@ def _shared_step_fns(cfg, kernel_mesh):
     hit = _JIT_CACHE.get(key)
     if hit is not None and hit[0] is cfg:
         return hit[1]
-    # a model with recurrent layers has its own four programs under the
-    # same names and leading arguments (inference/hybrid_runner.py)
+    # a hybrid stack (models/hybrid.py: recurrent layers, block-sparse or
+    # latent attention) has its own four programs under the same names and
+    # leading arguments (inference/hybrid_runner.py)
     runner = model_runner
-    if getattr(cfg, "recurrent_layers", 0):
+    if hasattr(cfg, "stack_plan"):
         from deepspeed_tpu.inference import hybrid_runner as runner
     # each program under a stable name: the device trace's module line
     # says jit_dstpu_serve_gather, ... ("step" is the gather program).
@@ -162,6 +163,7 @@ class InferenceEngineV2:
                  spec_accept_alpha: float = 0.25,
                  serving: Optional[Any] = None,
                  state_slots: Optional[int] = None,
+                 donate_params: bool = False,
                  request_trace: Optional[Any] = None,
                  metric_labels: Optional[Dict[str, str]] = None):
         from deepspeed_tpu.inference.engine import InferenceEngine
@@ -198,13 +200,32 @@ class InferenceEngineV2:
         # answer) or refused by name (host tier, migration, hand-off,
         # speculation): _refuse_without_snapshot
         self._recurrent = bool(getattr(self.cfg, "recurrent_layers", 0))
+        # any stack of models/hybrid.py: its programs take the pools as one
+        # dict with their counters, and its parameters as the serving tree
+        self._hybrid = hasattr(self.cfg, "stack_plan")
+        # ``donate_params``: the caller gives the stacked tree up, and the
+        # engine deletes each stacked leaf it cuts (hybrid.serving_params):
+        # the arrays handed in, here and to every later reload_params, are
+        # gone afterwards
+        self._donate_params = bool(donate_params)
+        # multi-head latent attention: the paged pool is a latent pool
+        # (ragged/kv_cache.py, kind "latent": one vector a token), no step
+        # runs the gather program, and what would read a K/V page by its
+        # heads refuses by name (_refuse_for_latent_pool)
+        self._latent = bool(getattr(self.cfg, "latent_dim", 0))
         # smallest chunk bucket of the prefill program (powers of two from
         # here: _plan_prefill_segments)
         self._min_segment = 8
+        if self._latent and (spec_decode or drafter is not None):
+            self._refuse_for_latent_pool(
+                "speculative decoding (spec_decode / drafter), which "
+                "verifies its drafts through the gather program,")
         if self._recurrent:
             self._init_recurrent(kv_quant_bits, host_kv_tier,
                                  spec_decode or drafter is not None)
             prefix_cache = False
+        elif self._hybrid:
+            self._keep_serving_params()
         # kept for reload_params: a hot-swap routes replacement weights
         # through the same v1 placement/quantization path as boot
         self._param_dtype = dtype
@@ -216,6 +237,7 @@ class InferenceEngineV2:
         # step of it runs the gather program (_split_by_program)
         sparse = getattr(self.cfg, "sparse", None)
         self._sparse = sparse is not None
+        self._no_gather = self._sparse or self._latent
         if self._sparse and kv_block_size != sparse.block:
             raise ValueError(
                 f"kv_block_size={kv_block_size}: a model with block-sparse "
@@ -225,8 +247,13 @@ class InferenceEngineV2:
             kv_heads=self.cfg.kv_heads,
             head_dim=self.cfg.head_dim, block_size=kv_block_size,
             num_blocks=kv_blocks, dtype=dtype, quant_bits=kv_quant_bits,
-            compressed_per_block=sparse.per_block if self._sparse else 0)
+            compressed_per_block=sparse.per_block if self._sparse else 0,
+            kind="latent" if self._latent else "kv",
+            latent_dim=getattr(self.cfg, "latent_dim", 0))
         self.kv_cache = BlockedKVCache(kv_cfg, mesh=self.mesh)
+        if self._hybrid and not self._recurrent:
+            # no state pool carries the step programs' counters
+            self.kv_cache.counters = jnp.zeros((len(COUNTERS),), jnp.int32)
         # disagg handoff wire codec mode ("auto"/"raw"/"int8"/"int4");
         # consumed by serving/disagg.py serialize_prefix
         self._handoff_wire = handoff_wire
@@ -326,7 +353,7 @@ class InferenceEngineV2:
                       "tokens_decode": 0, "tokens_multi_decode": 0,
                       "prefill_chunks": 0, "admission_wait_s": 0.0,
                       "ttft_s": 0.0, "first_tokens": 0}
-        if self._recurrent:
+        if self._hybrid:
             # the expert layers' routing, counted on the device by every
             # step program and fetched with the step's tokens: tokens x
             # expert layers, (token, expert) pairs routed to experts held
@@ -337,13 +364,15 @@ class InferenceEngineV2:
             # ((query, KV head) pairs' chosen and visible blocks over the
             # queries past dense_len, and the queries below it: the
             # programs' vector, state_pool.COUNTERS) and the compressed
-            # keys' occupancy (window slots of the held pages)
+            # keys' occupancy (window slots of the held pages); latent
+            # attention's decode kernel likewise (context tokens asked for,
+            # pages fetched)
             pool = self.kv_cache.state_pool
             self.stats.update(dict.fromkeys(COUNTERS, 0),
                               moe_local_pairs_decode=0,
                               moe_experts_hit_decode=0,
                               moe_work_items_decode=0, state_slots_in_use=0,
-                              state_slots=pool.total_slots,
+                              state_slots=pool.total_slots if pool else 0,
                               compressed_keys_in_use=0)
         # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
         # span, of the spans nested in it, and of the request tracer's
@@ -777,6 +806,7 @@ class InferenceEngineV2:
         from host memory. Returns None when there is nothing warm to
         capture (unknown uid, mid-prefill, queued-but-never-admitted):
         the caller degrades to the legacy fold-and-resubmit path."""
+        self._refuse_for_latent_pool("the session-migration wire")
         self._refuse_without_snapshot("session migration "
                                       "(migrate_out_session)")
         tier = getattr(self.kv_cache, "host_tier", None)
@@ -845,6 +875,7 @@ class InferenceEngineV2:
           engine (per-seq cap): counted and closed, mirroring
           ``_requeue``'s cap-truncation contract.
         """
+        self._refuse_for_latent_pool("the session-migration wire")
         self._refuse_without_snapshot("session migration "
                                       "(install_migrated_session)")
         uid = int(sess.uid)
@@ -937,7 +968,10 @@ class InferenceEngineV2:
         capture, so the swap costs zero recompilation and the next step
         serves the new weights — live KV blocks stay valid only if the
         caller quiesced the engine first (supervisor.rolling_swap drains
-        and migrates sessions out before calling this)."""
+        and migrates sessions out before calling this). An engine built
+        with ``donate_params`` consumes the replacement tree as it consumed
+        the first: the stacked leaves it cuts are deleted, and the caller
+        must not read ``params`` afterwards."""
         from deepspeed_tpu.inference.engine import InferenceEngine
 
         if params is None:
@@ -948,7 +982,7 @@ class InferenceEngineV2:
             dtype=self._param_dtype,
             quantize_weights=self._quantize_weights)
         self.params = self._v1.params
-        if self._recurrent:
+        if self._hybrid:
             self._keep_serving_params()
 
     def _init_recurrent(self, kv_quant_bits, host_kv_tier, speculate) -> None:
@@ -983,7 +1017,8 @@ class InferenceEngineV2:
     def _keep_serving_params(self) -> None:
         from deepspeed_tpu.models.hybrid import serving_params
 
-        self.params = serving_params(self.cfg, self.params)
+        self.params = serving_params(self.cfg, self.params,
+                                     donate=self._donate_params)
         self._v1.params = self.params     # drop the stacked tree's last ref
 
     def _refuse_without_snapshot(self, what: str) -> None:
@@ -997,6 +1032,16 @@ class InferenceEngineV2:
                 f"{what} needs a snapshot of each sequence's recurrent "
                 "state (ragged/state_pool.py), which does not exist yet: "
                 "not available for a model with recurrent layers")
+
+    def _refuse_for_latent_pool(self, what: str) -> None:
+        """Raise the named error for an operation that would read or write
+        a page by its K/V heads (no-op for other models)."""
+        if self._latent:
+            from deepspeed_tpu.inference.ragged import LatentPoolUnsupported
+
+            raise LatentPoolUnsupported(
+                f"{what} is not built for a latent pool (one vector a "
+                "token, no K/V pair, no head axis: ragged/kv_cache.py)")
 
     def _state_slots_arg(self, seqs) -> Tuple:
         """The step programs' trailing argument for a model with recurrent
@@ -1013,17 +1058,17 @@ class InferenceEngineV2:
         """Add what the step program counted to ``stats`` (inside the
         ``fetch`` span, after the step's tokens: the program is done)."""
         pool = self.kv_cache.state_pool
-        if pool is None:
+        if not self._hybrid:
             return
-        counted = dict(zip(COUNTERS, (int(v) for v in
-                                      np.asarray(pool.counters))))
+        counters = self.kv_cache.counters if pool is None else pool.counters
+        counted = dict(zip(COUNTERS, (int(v) for v in np.asarray(counters))))
         for name, n in counted.items():
             self.stats[name] += n
         if decode:
             self.stats["moe_local_pairs_decode"] += counted["moe_local_pairs"]
             self.stats["moe_experts_hit_decode"] += counted["moe_experts_hit"]
             self.stats["moe_work_items_decode"] += counted["moe_work_items"]
-        self.stats["state_slots_in_use"] = pool.slots_in_use
+        self.stats["state_slots_in_use"] = pool.slots_in_use if pool else 0
         self.stats["compressed_keys_in_use"] = \
             self.kv_cache.compressed_keys_in_use
 
@@ -1123,7 +1168,7 @@ class InferenceEngineV2:
                                         *args)
             # the program consumed (donated) the handle it was given
             self.kv_cache.set_kv_state(new_kv)
-            if runs and self._recurrent:    # before the next call overwrites
+            if runs and self._hybrid:       # before the next call overwrites
                 self._fetch_counters(runs[-1][1] == "decode")
             runs.append((part, program, logits, batch))
 
@@ -1182,7 +1227,7 @@ class InferenceEngineV2:
                 else:
                     rows_np = np.asarray(self._take_rows(logits, idx_dev))
                 self._fetch_counters(last_program == "decode")
-        elif self._recurrent:
+        elif self._hybrid:
             with span("fetch"):       # no token to read: the counters alone
                 self._fetch_counters(last_program == "decode")
         with span("bookkeep"):
@@ -1227,12 +1272,12 @@ class InferenceEngineV2:
 
     def _split_by_program(self, scheduled):
         """The step's work as the lists (of indices into ``scheduled``) one
-        program each takes: all of it, but for a model with block-sparse
-        attention (long contexts: the gather program, a context a token, is
-        not built for it) the sequences that advance one token go through
-        the decode program and every chunk through the prefill program, one
-        sequence a call."""
-        if not self._sparse:
+        program each takes: all of it, but for a model with block-sparse or
+        latent attention (long contexts: the gather program, a context a
+        token, is not built for it) the sequences that advance one token go
+        through the decode program and every chunk through the prefill
+        program, one sequence a call."""
+        if not self._no_gather:
             return [list(range(len(scheduled)))]
         single = [i for i, s in enumerate(scheduled) if len(s[1]) == 1]
         chunks = [[i] for i, s in enumerate(scheduled) if len(s[1]) > 1]
@@ -1311,7 +1356,7 @@ class InferenceEngineV2:
         tq = self._min_segment
         while tq < longest:
             tq *= 2
-        if self._sparse:        # one sequence's chunk, no Pallas kernel
+        if self._no_gather:     # one sequence's chunk, no Pallas kernel
             (_, nt, sp), = scheduled
             toks = np.zeros((1, tq), np.int32)
             toks[0, :len(nt)] = nt

@@ -26,12 +26,21 @@ do not tell the models apart. What differs:
 * with the block-sparse rule a full layer's token step selects pages
   (``sparse_select``) and runs the paged decode kernel over the chosen ones
   (``sparse_attn``); a chunk attends over its own sequence's pages under the
-  block mask; the gather program is not built.
+  block mask; the gather program is not built;
+* with latent attention (``attention_kind`` "mla") the paged pool is a latent
+  pool (one vector a token) and there is no recurrent layer, state pool or
+  ``state_slots``: a token step writes the token's latent and runs the
+  absorbed form through the ``mla_decode`` kernel over the sequence's own
+  pages (``mla_absorb`` / ``mla_attn``), a chunk the expanded form over its
+  sequence's cached latents, up-projected a block of context at a time
+  (``mla_chunk``); the prologue's dense layers run before the scan
+  (``dense_ffn``); the gather program is not built.
 
 ``counters`` comes back as this call's ``[moe_token_layers, moe_local_pairs,
 moe_experts_hit, moe_work_items, sparse_blocks_selected,
-sparse_blocks_visible, sparse_dense_tokens]`` (``state_pool.COUNTERS``;
-summed over the steps of a burst).
+sparse_blocks_visible, sparse_dense_tokens, mla_context_tokens,
+mla_pages_read]`` (``state_pool.COUNTERS``; summed over the steps of a
+burst).
 """
 
 from __future__ import annotations
@@ -46,7 +55,8 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.inference.model_runner import (_kv_write, _paged_decode,
                                                   _paged_prefill)
-from deepspeed_tpu.inference.ragged.state_pool import MOE_COUNTERS
+from deepspeed_tpu.inference.ragged.state_pool import (MOE_COUNTERS,
+                                                       SPARSE_COUNTERS)
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.hybrid import HybridConfig
 from deepspeed_tpu.ops import block_sparse
@@ -57,6 +67,7 @@ from deepspeed_tpu.runtime.sharding import effective_dtype
 
 
 _MOE = len(MOE_COUNTERS)    # where the expert blocks' counters end
+_SPARSE = _MOE + len(SPARSE_COUNTERS)   # and the sparse rule's; then latent
 
 
 def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
@@ -69,12 +80,15 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
     program holds one layer body a run whatever the depth. ``pools`` is the
     carry, ``pools["counters"]`` what this call counted. Returns (x, pools')."""
     reps, runs = cfg.stack_plan
-    per = cfg.num_layers // reps
+    K = cfg.first_k_dense if cfg.num_experts else 0
+    per = (cfg.num_layers - K) // reps
     in_period = {True: sum(n for full, n in runs if full)}
     in_period[False] = per - in_period[True]
     lead, H = x.shape[:-1], x.shape[-1]
     experts = params["experts"]
-    mixers = {True: params["attn"], False: params[cfg.recurrent_kind]}
+    full_kind = "mla" if cfg.attention_kind == "mla" else "attn"
+    mixers = {True: params[full_kind],
+              False: params.get(cfg.recurrent_kind)}
 
     def at(tree, i):
         # a layer's leaves read where they lie in the stack: a run's slice
@@ -83,9 +97,9 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
         return jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
 
-    def body(full):
-        mixer, scope = (full_fn, "attn") if full else (rec_fn,
-                                                       cfg.recurrent_kind)
+    def body(full, prologue=False):
+        mixer, scope = (full_fn, full_kind) if full else (rec_fn,
+                                                          cfg.recurrent_kind)
 
         def layer(carry, where):
             x, pools = carry
@@ -94,8 +108,10 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
             with jax.named_scope(scope):
                 y = hybrid._rms(x, lp["ln1"]["scale"], cfg.norm_eps)
                 out, pools = mixer(y, mp, l_mix, pools)
-            x, c = hybrid.expert_block(cfg, lp, experts, hybrid.residual(
-                cfg, x, out).reshape(-1, H), l, valid)
+            x, c = hybrid.expert_block(
+                cfg, lp, experts, hybrid.residual(cfg, x, out).reshape(-1, H),
+                l - K, valid,
+                dense=at(params["dense"], l) if prologue else None)
             if c is not None:
                 pools = dict(pools, counters=pools["counters"].at[:_MOE].add(
                     jnp.stack([jnp.sum(valid).astype(jnp.int32), c["pairs"],
@@ -104,12 +120,16 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
 
         return layer
 
+    # mixer-layer index of the first layer after the prologue, by kind
+    base = {True: sum(cfg.layer_kinds[:K])}
+    base[False] = K - base[True]
+
     def period(carry, r):
         first = {True: 0, False: 0, None: 0}
         for full, n in runs:
             steps = jnp.arange(n, dtype=jnp.int32)
-            where = (r * per + first[None] + steps,
-                     r * in_period[full] + first[full] + steps)
+            where = (K + r * per + first[None] + steps,
+                     base[full] + r * in_period[full] + first[full] + steps)
             if n == 1:
                 carry, _ = body(full)(carry, (where[0][0], where[1][0]))
             else:
@@ -118,10 +138,15 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
             first[full] += n
         return carry, None
 
-    pools = dict(pools, counters=jnp.zeros_like(pools["counters"]))
-    (x, pools), _ = lax.scan(period, (x, pools),
-                             jnp.arange(reps, dtype=jnp.int32))
-    return x, pools
+    carry = (x, dict(pools, counters=jnp.zeros_like(pools["counters"])))
+    seen = {True: 0, False: 0}
+    for l in range(K):      # the prologue: dense layers, outside the scan
+        full = cfg.layer_kinds[l]
+        carry, _ = body(full, prologue=True)(
+            carry, (jnp.int32(l), jnp.int32(seen[full])))
+        seen[full] += 1
+    carry, _ = lax.scan(period, carry, jnp.arange(reps, dtype=jnp.int32))
+    return carry
 
 
 def _rec_project(cfg, mp, y, pos):
@@ -278,12 +303,112 @@ def _sparse_prefill(cfg, q, kv, ck, l_kv, block_table, pos, real, ctx_lens):
 
 
 def _scratch(pools, alive, state_slots):
-    """Rows without a sequence read and write the pool's scratch slot."""
+    """Rows without a sequence read and write the pool's scratch slot (a
+    stack without recurrent layers has no state pool: None)."""
+    if "state" not in pools:
+        return None
     return jnp.where(alive, state_slots, pools["state"].shape[1] - 1)
 
 
+def _no_gather():
+    raise NotImplementedError(
+        "the gather program holds a context per token and is not built "
+        "for a model with block-sparse or latent attention: its steps run "
+        "the prefill and decode programs")
+
+
+def _mla_write(cfg, mp, y, pos, pools, l, page, offset):
+    """The mixer's projections and the tokens' latents written to their rows
+    of the latent pool (zeros up to the pool's lane-padded width). Returns
+    (q_n, q_r, pools')."""
+    with jax.named_scope("mla_project"):
+        q_n, q_r, latent = hybrid.mla_project(cfg, mp, y, pos)
+        kv = pools["kv"]
+        pad = kv.shape[-1] - latent.shape[-1]
+        latent = jnp.pad(latent, [(0, 0)] * (latent.ndim - 1) + [(0, pad)])
+        kv = kv.at[l, page, offset].set(latent.astype(kv.dtype))
+    return q_n, q_r, dict(pools, kv=kv)
+
+
+def _mla_decode(cfg, mp, q_n, q_r, pools, l, block_table, context_lens):
+    """A token step of latent attention in its absorbed form: each head's
+    query carried into the latent's coordinates, the ``mla_decode`` kernel
+    over the sequence's own pages (keys and values the same rows), the
+    result carried back to the head's values. Returns (o [S, n, v],
+    pools')."""
+    from deepspeed_tpu.ops.pallas.paged_attention import mla_decode_attention
+
+    kv = pools["kv"]
+    W = kv.shape[3]
+    with jax.named_scope("mla_absorb"):
+        q = jnp.concatenate([hybrid.mla_absorb_q(cfg, mp, q_n), q_r], -1)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, W - q.shape[-1])))
+    with jax.named_scope("mla_attn"):
+        o, fetched = mla_decode_attention(
+            q.astype(kv.dtype), kv, block_table, context_lens,
+            value_dim=cfg.kv_lora_rank, scale=cfg.mla_scale, layer=l)
+    with jax.named_scope("mla_absorb"):
+        o = hybrid.mla_absorb_o(cfg, mp, o.astype(q_n.dtype))
+    # asked of the kernel, and what the kernel counted as it fetched
+    counts = jnp.stack([jnp.sum(context_lens), jnp.sum(fetched)])
+    return o, dict(pools, counters=pools["counters"].at[_SPARSE:].add(
+        counts.astype(jnp.int32)))
+
+
+# context tokens whose keys and values the chunk path holds expanded at once
+_MLA_CHUNK_KEYS = 512
+
+
+@jax.named_scope("mla_chunk")
+def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens):
+    """Chunk attention in the expanded form, one segment after another:
+    each attends over its own sequence's cached latents (this chunk's are
+    written already), up-projected to keys and values ``_MLA_CHUNK_KEYS``
+    context tokens at a time under a running softmax, and no further than
+    the segment's context: nothing holds a sequence's expanded keys (0.75
+    GiB a layer at 24k tokens). q_n, q_r [S, Tq, n, .]; pos [S, Tq].
+    Returns o [S, Tq, n, v] in q's type."""
+    S, Tq = pos.shape
+    bs, Bm = kv.shape[2], block_table.shape[1]
+    n, dv, dt = cfg.num_heads, cfg.v_head_dim, q_n.dtype
+    Tk = min(_MLA_CHUNK_KEYS, Bm * bs)
+    scale = cfg.mla_scale
+
+    def segment(args):
+        qn, qr, table, ts, ctx = args
+        rows = with_layout_constraint(
+            kv[l, table], Layout(major_to_minor=(0, 1, 2))).reshape(
+                Bm * bs, -1)
+
+        def block(b, carry):
+            m, den, acc = carry
+            lat = lax.dynamic_slice_in_dim(rows, b * Tk, Tk).astype(dt)
+            k_n, v, k_r = hybrid.mla_expand(cfg, mp, lat)
+            sc = (jnp.einsum("qnd,knd->nqk", qn, k_n)
+                  + jnp.einsum("qnd,kd->nqk", qr, k_r)).astype(
+                      jnp.float32) * scale
+            seen = (b * Tk + jnp.arange(Tk))[None, :] <= ts[:, None]
+            sc = jnp.where(seen[None], sc, -1e30)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(seen[None], jnp.exp(sc - m_new[..., None]), 0.0)
+            den = alpha * den + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "nqk,knd->nqd", p.astype(dt), v).astype(jnp.float32)
+            return m_new, den, acc
+
+        init = (jnp.full((n, Tq), -1e30, jnp.float32),
+                jnp.zeros((n, Tq), jnp.float32),
+                jnp.zeros((n, Tq, dv), jnp.float32))
+        _, den, acc = lax.fori_loop(0, (ctx + Tk - 1) // Tk, block, init)
+        o = acc / jnp.where(den == 0.0, 1.0, den)[..., None]
+        return jnp.swapaxes(o, 0, 1).astype(dt)
+
+    return lax.map(segment, (q_n, q_r, block_table, pos, ctx_lens))
+
+
 def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
-                   token_pos, block_table, num_tokens, state_slots
+                   token_pos, block_table, num_tokens, state_slots=None
                    ) -> Tuple[jax.Array, Dict]:
     """One ragged step over flat tokens (``model_runner.ragged_forward``'s
     contract; sequences lie one after another in the flat order). Returns
@@ -291,11 +416,8 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
     rule: it lays out one whole context per *token*, and such a model's
     contexts are long; the engine runs its chunks through the prefill
     program and its single tokens through the decode program."""
-    if cfg.sparse is not None:
-        raise NotImplementedError(
-            "the gather program holds a context per token and is not built "
-            "for a model with block-sparse attention: its steps run the "
-            "prefill and decode programs")
+    if cfg.sparse is not None or cfg.attention_kind == "mla":
+        _no_gather()
     T = token_ids.shape[0]
     S, Bm = block_table.shape
     bs = pools["kv"].shape[2]
@@ -346,8 +468,8 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
 
 
 def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
-                           seg_pos0, seg_nreal, block_table, state_slots, *,
-                           mesh=None) -> Tuple[jax.Array, Dict]:
+                           seg_pos0, seg_nreal, block_table, state_slots=None,
+                           *, mesh=None) -> Tuple[jax.Array, Dict]:
     """Prefill chunks, one segment a sequence slot; attention through the
     paged prefill kernel or, with the sparse rule, over each segment's own
     pages under its block mask. Returns (logits [S, Tq, V] float32, pools')."""
@@ -364,7 +486,8 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
     page = jnp.where(real, jnp.take_along_axis(block_table, pos // bs, axis=1),
                      scratch)
     offset = jnp.where(real, pos % bs, bs - 1)
-    slots = _scratch(pools, seg_nreal > 0, state_slots[:S])
+    slots = _scratch(pools, seg_nreal > 0,
+                     None if state_slots is None else state_slots[:S])
     sz = cfg.sparse
 
     def rec_fn(y, mp, l_rec, pools):
@@ -374,6 +497,12 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
         return _rec_output(cfg, mp, o, z), pools
 
     def full_fn(y, ap, l_kv, pools):
+        if cfg.attention_kind == "mla":
+            q_n, q_r, pools = _mla_write(cfg, ap, y, pos, pools, l_kv, page,
+                                         offset)
+            o = _mla_chunk(cfg, ap, q_n, q_r, pools["kv"], l_kv, block_table,
+                           pos, ctx_lens)
+            return hybrid.mla_output(ap, o), pools
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, pos)
         kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
         pools = dict(pools, kv=kv)
@@ -386,7 +515,7 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
             a, counts = _sparse_prefill(cfg, q.astype(dt), kv, ck, l_kv,
                                         block_table, pos, real, ctx_lens)
             pools = dict(pools, ck=ck,
-                         counters=pools["counters"].at[_MOE:].add(counts))
+                         counters=pools["counters"].at[_MOE:_SPARSE].add(counts))
         return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn,
@@ -395,8 +524,9 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
 
 
 def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
-                          token_pos, block_table, context_lens, state_slots, *,
-                          mesh=None) -> Tuple[jax.Array, Dict]:
+                          token_pos, block_table, context_lens,
+                          state_slots=None, *, mesh=None
+                          ) -> Tuple[jax.Array, Dict]:
     """One decode step: one new token for each live slot (``context_lens``
     0 marks a dead one). The recurrent layers run their decode kernel
     (``gdn_decode`` or ``lightning_decode``) on each sequence's slot, the
@@ -436,6 +566,12 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
                 dict(pools, state=state, conv=conv))
 
     def full_fn(y, ap, l_kv, pools):
+        if cfg.attention_kind == "mla":
+            q_n, q_r, pools = _mla_write(cfg, ap, y, token_pos, pools, l_kv,
+                                         page, offset)
+            o, pools = _mla_decode(cfg, ap, q_n, q_r, pools, l_kv,
+                                   block_table, context_lens)
+            return hybrid.mla_output(ap, o), pools
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, token_pos)
         kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
         pools = dict(pools, kv=kv)
@@ -448,7 +584,7 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
             a, counts = _sparse_decode(cfg, mesh, q.astype(dt), kv, ck, l_kv,
                                        block_table, context_lens)
             pools = dict(pools, ck=ck,
-                         counters=pools["counters"].at[_MOE:].add(counts))
+                         counters=pools["counters"].at[_MOE:_SPARSE].add(counts))
         return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn, alive)
@@ -456,8 +592,9 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
 
 
 def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
-                        token_pos, block_table, context_lens, state_slots, *,
-                        steps: int, mesh=None) -> Tuple[jax.Array, Dict]:
+                        token_pos, block_table, context_lens,
+                        state_slots=None, *, steps: int, mesh=None
+                        ) -> Tuple[jax.Array, Dict]:
     """``steps`` greedy decode steps in one program, the argmax fed back on
     the device (``model_runner.ragged_multi_decode``'s contract); the
     counters sum over the steps. Returns (tokens [steps, S] int32, pools')."""

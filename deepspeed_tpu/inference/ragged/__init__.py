@@ -1,7 +1,8 @@
 """Ragged-batching state management (reference: inference/v2/ragged/)."""
 
 from deepspeed_tpu.inference.ragged.blocked_allocator import BlockedAllocator
-from deepspeed_tpu.inference.ragged.kv_cache import BlockedKVCache, KVCacheConfig
+from deepspeed_tpu.inference.ragged.kv_cache import (
+    BlockedKVCache, KVCacheConfig, LatentPoolUnsupported)
 from deepspeed_tpu.inference.ragged.kv_tier import HostKVTier, PagedSession
 from deepspeed_tpu.inference.ragged.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.ragged.sequence import (
@@ -15,6 +16,7 @@ __all__ = [
     "BlockedKVCache",
     "HostKVTier",
     "KVCacheConfig",
+    "LatentPoolUnsupported",
     "PagedSession",
     "PrefixCache",
     "SequenceDescriptor",

@@ -25,6 +25,21 @@ addresses a sequence's pages addresses its summaries, and a page that is
 freed, preempted or recomputed takes them with it: there is no second
 allocator and nothing to leak. The step programs write them
 (``hybrid_runner._compress_new``) and carry the array with the pool.
+
+A pool is of one of two **kinds** (``KVCacheConfig.kind``). ``"kv"`` is the
+layout above. ``"latent"`` is the pool of a model with multi-head latent
+attention, which keeps one compressed vector a token in place of keys and
+values a head:
+
+    kv[L, num_blocks, block_size, lanes(latent_dim)]
+
+no K/V pair and no head axis; a token's row is its ``latent_dim`` values
+(576: the compressed vector and the one rotary key) followed by zeros up to
+whole 128-lane tiles (640): the device's tiled layout pads the last axis so
+whatever the shape says, and the decode kernel's page fetch can slice a pool
+only at whole tiles. The block table, the allocator, the prefix chain and
+the host tier address pages and do not look inside one: a latent page is a
+page. The quantized rungs are not built for it and refuse by name.
 """
 
 from __future__ import annotations
@@ -38,6 +53,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.ragged.blocked_allocator import BlockedAllocator
+
+
+class LatentPoolUnsupported(NotImplementedError):
+    """The operation is not built for a latent pool (one vector a token, no
+    K/V pair, no head axis)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,8 +76,24 @@ class KVCacheConfig:
     quant_bits: Optional[object] = None
     # compressed keys a page (0: none): block_size / the windows' stride
     compressed_per_block: int = 0
+    # "kv": keys and values a head; "latent": one vector of ``latent_dim``
+    # values a token (multi-head latent attention)
+    kind: str = "kv"
+    latent_dim: int = 0
 
     def __post_init__(self):
+        if self.kind not in ("kv", "latent"):
+            raise ValueError(f"a KV pool is of kind 'kv' or 'latent', got "
+                             f"{self.kind!r}")
+        if self.kind == "latent":
+            if self.latent_dim <= 0:
+                raise ValueError("a latent pool needs latent_dim")
+            if self.quant_bits is not None or self.compressed_per_block:
+                raise LatentPoolUnsupported(
+                    f"a latent pool holds one bf16/float vector a token: "
+                    f"quant_bits={self.quant_bits!r} and compressed keys "
+                    f"are not built for it (quantized latent pages: "
+                    f"ROADMAP.md)")
         if self.quant_bits not in (None, 4, 8, "fp8"):
             raise ValueError(f"kv quant_bits must be None, 4, 8 or 'fp8', "
                              f"got {self.quant_bits}")
@@ -69,11 +105,22 @@ class KVCacheConfig:
     @property
     def payload_width(self) -> int:
         """Last-dim extent of the pool payload: head_dim values, packed
-        two-per-byte under int4."""
+        two-per-byte under int4; a latent pool's row, whole lane tiles."""
+        if self.kind == "latent":
+            return -(-self.latent_dim // 128) * 128
         return self.head_dim // 2 if self.quant_bits == 4 else self.head_dim
 
     @property
+    def pool_shape(self):
+        page = (self.payload_width,) if self.kind == "latent" else (
+            2, self.kv_heads, self.payload_width)
+        return (self.num_layers, self.num_blocks, self.block_size) + page
+
+    @property
     def bytes_per_block(self) -> int:
+        if self.kind == "latent":
+            return (self.num_layers * self.block_size * self.payload_width
+                    * jnp.dtype(self.dtype).itemsize)
         vecs = self.num_layers * self.block_size * 2 * self.kv_heads
         if self.quant_bits is not None:
             # int8/fp8/packed-int4 payload + fp32 scale per head vector
@@ -121,8 +168,10 @@ class BlockedKVCache:
                 (config.num_layers, config.num_blocks,
                  config.compressed_per_block, config.kv_heads,
                  config.head_dim), config.dtype)
-        shape = (config.num_layers, config.num_blocks, config.block_size,
-                 2, config.kv_heads, config.payload_width)
+        shape = config.pool_shape
+        # what the last step program of a hybrid stack counted, where no
+        # recurrent-state pool carries it (a stack without recurrent layers)
+        self.counters = None
         quantized = config.quant_bits is not None
         # int4 packs nibbles into uint8 (the runner infers the width from
         # the pool dtype at trace time: int8 → 8, uint8 → 4, e4m3 → fp8)
@@ -130,8 +179,8 @@ class BlockedKVCache:
                       else jnp.float8_e4m3fn if config.quant_bits == "fp8"
                       else jnp.int8 if quantized else config.dtype)
         self.scales = None
-        if mesh is not None and tp_axis in mesh.axis_names and (
-                mesh.shape[tp_axis] > 1):
+        if config.kind == "kv" and mesh is not None and (
+                tp_axis in mesh.axis_names and mesh.shape[tp_axis] > 1):
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             sharding = NamedSharding(
@@ -166,6 +215,8 @@ class BlockedKVCache:
             if self.compressed is not None:
                 state["ck"] = self.compressed
             return state
+        if self.counters is not None:
+            return {"kv": self.data, "counters": self.counters}
         if self.scales is None:
             return self.data
         return (self.data, self.scales)
@@ -180,6 +231,8 @@ class BlockedKVCache:
             self.data, sp.state, sp.conv, sp.counters = (
                 state["kv"], state["state"], state["conv"], state["counters"])
             self.compressed = state.get("ck")
+        elif self.counters is not None:
+            self.data, self.counters = state["kv"], state["counters"]
         elif self.scales is None:
             self.data = state
         else:
@@ -194,8 +247,9 @@ class BlockedKVCache:
     def read_blocks_host(self, block_ids):
         """Device→host copy of the pool contents at ``block_ids``:
         ``(payload [L, n, bs, 2, H, W], scales [L, n, bs, 2, H] | None)``
-        in the pool's native storage format — for a quantized pool this
-        IS the compact kv_pack wire format, so paging it out costs no
+        (a latent pool: ``payload [L, n, bs, W]``) in the pool's native
+        storage format — for a quantized pool this IS the compact kv_pack
+        wire format, so paging it out costs no
         conversion (the disagg serialize idiom applied to the tier)."""
         idx = np.asarray(block_ids, np.int64)
         payload = np.asarray(self.data[:, idx])

@@ -33,11 +33,16 @@ import jax.numpy as jnp
 
 
 # what a step program counts, in the order of its ``counters`` vector: the
-# expert blocks' part, then the block-selecting attention's
+# expert blocks' part, then the block-selecting attention's, then latent
+# attention's decode kernel's (the context tokens it was asked to read, and
+# the page copies it started, which the kernel counts itself; summed over
+# sequences, layers and steps)
 MOE_COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
                 "moe_work_items")
-COUNTERS = MOE_COUNTERS + ("sparse_blocks_selected", "sparse_blocks_visible",
-                           "sparse_dense_tokens")
+SPARSE_COUNTERS = ("sparse_blocks_selected", "sparse_blocks_visible",
+                   "sparse_dense_tokens")
+MLA_COUNTERS = ("mla_context_tokens", "mla_pages_read")
+COUNTERS = MOE_COUNTERS + SPARSE_COUNTERS + MLA_COUNTERS
 
 
 class StateSnapshotUnsupported(NotImplementedError):

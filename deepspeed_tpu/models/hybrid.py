@@ -1,5 +1,8 @@
 """Hybrid recurrent / attention LM: one stack for the ``qwen3_next`` family
-(Qwen3-Next-80B-A3B) and the ``minicpm_sala`` family (MiniCPM-SALA).
+(Qwen3-Next-80B-A3B), the ``minicpm_sala`` family (MiniCPM-SALA) and the
+``kimi_k2`` / ``deepseek_v3`` family (multi-head latent attention in every
+layer: ``attention_kind`` "mla", no recurrent layer; a prologue of
+``first_k_dense`` dense layers before the expert layers; sigmoid routing).
 
 The stack is not one scanned block. Each layer is **recurrent** or **full**
 (:attr:`HybridConfig.layer_kinds`: Qwen3-Next's "every
@@ -24,7 +27,11 @@ passes and the serving runner (``inference/hybrid_runner.py``) take:
     layers  ln1, ln2, moe.{router, shared, shared_gate} | mlp   [L, ...]
     experts wg, wi, wo (none with a dense feed-forward)    [L, E_held, ...]
     gdn | lightning  the recurrent layers' mixer           [recurrent, ...]
-    attn    the full layers' mixer                         [full, ...]
+    attn | mla       the full layers' mixer                [full, ...]
+    dense   wg, wi, wo: the prologue's feed-forward        [first_k_dense, ...]
+
+(with a prologue ``experts`` holds the expert layers alone, ``[L -
+first_k_dense, ...]``; the other per-layer leaves keep a slot a layer).
 
 ``experts_held`` / ``expert_offset`` give the chip's share of the routed
 experts (None: all of them); the router always has ``num_experts`` outputs.
@@ -40,7 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.models.transformer import (TransformerConfig, _norm, _rope)
+from deepspeed_tpu.models.transformer import (TransformerConfig, _norm, _rope,
+                                              yarn_inv_freq, yarn_mscale)
 from deepspeed_tpu.ops import block_sparse
 from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, lightning_chunk
 from deepspeed_tpu.parallel.moe import GateConfig, moe_ffn_share
@@ -88,9 +96,40 @@ class HybridConfig(TransformerConfig):
     sparse_init_blocks: int = 1
     sparse_window_size: int = 2048
     sparse_dense_len: int = 8192
+    # the full layers' mixer: "gated" (QK-norm, output gate, K and V a KV
+    # head) or "mla": multi-head latent attention, one ``kv_lora_rank +
+    # qk_rope_head_dim`` vector a token in the cache, queries through a
+    # ``q_lora_rank`` bottleneck, keys ``qk_nope + qk_rope`` wide, values
+    # ``v_head_dim``
+    attention_kind: str = "gated"
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # YaRN (factor 1: plain rotary): models/transformer.py::yarn_inv_freq,
+    # and ``yarn_mscale(factor, mscale_all_dim)**2`` on the softmax scale
+    rope_yarn_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    # the first layers' feed-forward is the dense SwiGLU of ``ffn_size``,
+    # the experts start after them
+    first_k_dense: int = 0
+    # the router's rule (parallel/moe.py::route) and whether the shared
+    # expert sits behind a sigmoid gate
+    router_scoring: str = "softmax"
+    routed_scale: float = 1.0
+    shared_gate: bool = True
 
     def __post_init__(self):
         super().__post_init__()
+        if self.attention_kind not in ("gated", "mla"):
+            raise ValueError(f"attention_kind {self.attention_kind!r}")
+        if not 0 <= self.first_k_dense <= self.num_layers:
+            raise ValueError(f"first_k_dense={self.first_k_dense} of "
+                             f"{self.num_layers} layers")
         if self.layer_pattern is None:
             if self.num_layers % self.full_attention_interval:
                 raise ValueError(
@@ -117,6 +156,28 @@ class HybridConfig(TransformerConfig):
         return self.attn_head_dim
 
     @property
+    def latent_dim(self) -> int:
+        """Values a token keeps in a latent cache (0: keys and values)."""
+        return (self.kv_lora_rank + self.qk_rope_head_dim
+                if self.attention_kind == "mla" else 0)
+
+    @property
+    def mla_scale(self) -> float:
+        """The softmax scale of latent attention: ``(nope + rope)^-1/2``
+        times YaRN's ``m**2``."""
+        m = yarn_mscale(self.rope_yarn_factor, self.rope_mscale_all_dim)
+        return m * m / math.sqrt(self.qk_nope_head_dim
+                                 + self.qk_rope_head_dim)
+
+    def mla_inv_freq(self):
+        """The rotary part's inverse frequencies (None: plain rotary)."""
+        if self.rope_yarn_factor <= 1.0:
+            return None
+        return yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta,
+                             self.rope_yarn_factor, self.rope_original_max,
+                             self.rope_beta_fast, self.rope_beta_slow)
+
+    @property
     def held(self) -> int:
         return self.num_experts if self.experts_held is None \
             else self.experts_held
@@ -133,11 +194,14 @@ class HybridConfig(TransformerConfig):
 
     @property
     def stack_plan(self) -> Tuple[int, Tuple[Tuple[bool, int], ...]]:
-        """``(repeats, runs)``: the layer kinds as ``repeats`` copies of the
-        shortest pattern that tiles them, the pattern as runs ``(full,
+        """``(repeats, runs)``: the layer kinds (after the prologue of
+        ``first_k_dense`` layers, which the runner calls one by one before
+        the scan) as ``repeats`` copies of the shortest pattern that tiles
+        them, the pattern as runs ``(full,
         layers)`` of one kind. The serving runner scans the repeats and,
         inside, each run: one layer body a run, whatever the depth."""
-        kinds, L = self.layer_kinds, self.num_layers
+        kinds = self.layer_kinds[self.first_k_dense:]   # after the prologue
+        L = len(kinds)
         p = next(p for p in range(1, L + 1)
                  if L % p == 0 and kinds == kinds[:p] * (L // p))
         runs = []
@@ -194,7 +258,12 @@ class HybridConfig(TransformerConfig):
     @property
     def gate(self) -> GateConfig:
         return GateConfig(num_experts=self.num_experts, top_k=self.top_k,
-                          drop_tokens=False)
+                          drop_tokens=False, scoring=self.router_scoring,
+                          routed_scale=self.routed_scale)
+
+    def is_dense(self, layer: int) -> bool:
+        """Whether the layer's feed-forward is the dense SwiGLU."""
+        return not self.num_experts or layer < self.first_k_dense
 
     def is_full(self, layer: int) -> bool:
         return self.layer_kinds[layer]
@@ -211,7 +280,11 @@ class HybridConfig(TransformerConfig):
             return 6.0 * self.num_params()
         active = 3 * h * (self.top_k * self.moe_ffn_size + self.shared_ffn_size)
         held_all = 3 * h * self.moe_ffn_size * self.held
-        return 6.0 * (self.num_params() - self.num_layers * (held_all - active))
+        # (the prologue's slots of the expert leaves are never read)
+        K = self.first_k_dense
+        return 6.0 * (self.num_params() - self.num_layers * held_all
+                      + (self.num_layers - K) * active
+                      - K * 3 * h * self.shared_ffn_size)
 
 
 def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
@@ -233,32 +306,49 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
                "wv": (L, h, nv, dv), "wz": (L, h, nv, dv),
                "q_norm": (L, dk), "k_norm": (L, dk), "norm": (L, dv),
                "wo": (L, nv, dv, h)}
+    top = {}
     if cfg.num_experts:
-        ffn = {"moe": {"router": (L, h, cfg.num_experts),
-                       "experts": {"wg": (L, e, h, f), "wi": (L, e, h, f),
-                                   "wo": (L, e, f, h)},
-                       "shared": {"wg": (L, h, fs), "wi": (L, h, fs),
-                                  "wo": (L, fs, h)},
-                       "shared_gate": (L, h)}}
+        moe = {"router": (L, h, cfg.num_experts),
+               "experts": {"wg": (L, e, h, f), "wi": (L, e, h, f),
+                           "wo": (L, e, f, h)},
+               "shared": {"wg": (L, h, fs), "wi": (L, h, fs),
+                          "wo": (L, fs, h)}}
+        if cfg.shared_gate:
+            moe["shared_gate"] = (L, h)
+        if cfg.router_scoring == "sigmoid":
+            moe["router_bias"] = (L, cfg.num_experts)
+        ffn = {"moe": moe}
+        if cfg.first_k_dense:
+            K, F = cfg.first_k_dense, cfg.ffn_size
+            top["dense"] = {"wg": (K, h, F), "wi": (K, h, F), "wo": (K, F, h)}
     else:
         ffn = {"mlp": {"wg": (L, h, cfg.ffn_size), "wi": (L, h, cfg.ffn_size),
                        "wo": (L, cfg.ffn_size, h)}}
+    if cfg.attention_kind == "mla":
+        c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        dn, dv, ql = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.q_lora_rank
+        mixers = {"mla": {"wqa": (L, h, ql), "q_norm": (L, ql),
+                          "wqb": (L, ql, nq, dn + r), "wkva": (L, h, c + r),
+                          "kv_norm": (L, c), "wkvb": (L, c, nq, dn + dv),
+                          "wo": (L, nq, dv, h)}}
+    else:
+        mixers = {"attn": {"wq": (L, h, nq, 2 * d), "wk": (L, h, nkv, d),
+                           "wv": (L, h, nkv, d), "wo": (L, nq, d, h),
+                           "q_norm": (L, d), "k_norm": (L, d)}}
+    if cfg.recurrent_layers:
+        mixers[cfg.recurrent_kind] = rec
     return {
         "embed": {"tokens": (v, h)},
         "final_norm": {"scale": (h,)},
         "unembed": {"kernel": (h, v)},
-        "layers": {
-            "ln1": {"scale": (L, h)}, "ln2": {"scale": (L, h)},
-            "attn": {"wq": (L, h, nq, 2 * d), "wk": (L, h, nkv, d),
-                     "wv": (L, h, nkv, d), "wo": (L, nq, d, h),
-                     "q_norm": (L, d), "k_norm": (L, d)},
-            cfg.recurrent_kind: rec, **ffn,
-        },
+        **top,
+        "layers": {"ln1": {"scale": (L, h)}, "ln2": {"scale": (L, h)},
+                   **mixers, **ffn},
     }
 
 
-_GAINS = ("scale", "q_norm", "k_norm", "norm")
-_MIXERS = ("attn", "gdn", "lightning")
+_GAINS = ("scale", "q_norm", "k_norm", "kv_norm", "norm")
+_MIXERS = ("attn", "mla", "gdn", "lightning")
 
 
 def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
@@ -285,6 +375,8 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
             if name == "wo":   # contracts everything but the last axis
                 fan = math.prod(shape[2:-1]) if group in _MIXERS \
                     else shape[-2]
+            elif name in ("wqb", "wkvb"):   # [L, rank, heads, d]
+                fan = shape[1]
             else:              # [L, (E,) h, ...]: contracts h
                 fan = cfg.hidden_size
             x = jax.random.normal(key, shape, cfg.param_dtype) / math.sqrt(fan)
@@ -303,56 +395,97 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
                     "dt_bias": (L, None)})
     else:
         rec.update({"q_norm": (L, None), "k_norm": (L, None)})
+    top = {}
     if cfg.num_experts:
-        ffn = {"moe": {"router": (L, "embed", None),
-                       "experts": {"wg": (L, "expert", "embed", None),
-                                   "wi": (L, "expert", "embed", None),
-                                   "wo": (L, "expert", None, "embed")},
-                       "shared": {"wg": (L, "embed", None),
-                                  "wi": (L, "embed", None),
-                                  "wo": (L, None, "embed")},
-                       "shared_gate": (L, "embed")}}
+        moe = {"router": (L, "embed", None),
+               "experts": {"wg": (L, "expert", "embed", None),
+                           "wi": (L, "expert", "embed", None),
+                           "wo": (L, "expert", None, "embed")},
+               "shared": {"wg": (L, "embed", None),
+                          "wi": (L, "embed", None),
+                          "wo": (L, None, "embed")}}
+        if cfg.shared_gate:
+            moe["shared_gate"] = (L, "embed")
+        if cfg.router_scoring == "sigmoid":
+            moe["router_bias"] = (L, None)
+        ffn = {"moe": moe}
+        if cfg.first_k_dense:
+            top["dense"] = {"wg": (L, "embed", "mlp"), "wi": (L, "embed", "mlp"),
+                            "wo": (L, "mlp", "embed")}
     else:
         ffn = {"mlp": {"wg": (L, "embed", "mlp"), "wi": (L, "embed", "mlp"),
                        "wo": (L, "mlp", "embed")}}
+    if cfg.attention_kind == "mla":
+        # replicated: a latent cache has no head axis to shard
+        mixers = {"mla": {"wqa": (L, "embed", None), "q_norm": (L, None),
+                          "wqb": (L, None, None, None),
+                          "wkva": (L, "embed", None), "kv_norm": (L, None),
+                          "wkvb": (L, None, None, None),
+                          "wo": (L, None, None, "embed")}}
+    else:
+        mixers = {"attn": {"wq": (L, "embed", "heads", "head_dim"),
+                           "wk": (L, "embed", "kv_heads", "head_dim"),
+                           "wv": (L, "embed", "kv_heads", "head_dim"),
+                           "wo": (L, "heads", "head_dim", "embed"),
+                           "q_norm": (L, "head_dim"),
+                           "k_norm": (L, "head_dim")}}
+    if cfg.recurrent_layers:
+        # the recurrent mixer is replicated: its state pool is per
+        # sequence, not per head shard
+        mixers[cfg.recurrent_kind] = rec
     return {
         "embed": {"tokens": ("vocab", "embed")},
         "final_norm": {"scale": ("embed",)},
         "unembed": {"kernel": ("embed", "vocab")},
-        "layers": {
-            "ln1": {"scale": (L, "embed")}, "ln2": {"scale": (L, "embed")},
-            "attn": {"wq": (L, "embed", "heads", "head_dim"),
-                     "wk": (L, "embed", "kv_heads", "head_dim"),
-                     "wv": (L, "embed", "kv_heads", "head_dim"),
-                     "wo": (L, "heads", "head_dim", "embed"),
-                     "q_norm": (L, "head_dim"), "k_norm": (L, "head_dim")},
-            # the recurrent mixer is replicated: its state pool is per
-            # sequence, not per head shard
-            cfg.recurrent_kind: rec, **ffn,
-        },
+        **top,
+        "layers": {"ln1": {"scale": (L, "embed")},
+                   "ln2": {"scale": (L, "embed")}, **mixers, **ffn},
     }
 
 
-def serving_params(cfg: HybridConfig, params: Dict[str, Any]) -> Dict[str, Any]:
+def serving_params(cfg: HybridConfig, params: Dict[str, Any],
+                   donate: bool = False) -> Dict[str, Any]:
     """The tree the forward passes take: of each mixer only the layers that
     use it (the other slices are dropped with the tree handed in), the
     routed experts apart from the other per-layer leaves (the grouped
-    product reads them by layer, inside the kernel). Idempotent."""
+    product reads them by layer, inside the kernel) and without the
+    prologue's slots. Idempotent. ``donate``: the caller gives the stacked
+    tree up, and each stacked leaf that is cut is deleted as soon as its cut
+    exists, so that at most one leaf is held twice (a chip that the cut
+    tree nearly fills cannot hold both trees)."""
     if "experts" in params:
         return params
+
+    def cut(tree, keep):
+        def one(x):
+            if len(keep) == x.shape[0]:
+                return x
+            out = x[keep[0]:keep[-1] + 1] if keep == list(
+                range(keep[0], keep[-1] + 1)) else x[jnp.asarray(keep)]
+            if donate:
+                jax.block_until_ready(out)
+                x.delete()
+            return out
+        return jax.tree.map(one, tree)
+
+    L = cfg.num_layers
     layers = dict(params["layers"])
-    full = jnp.asarray([l for l in range(cfg.num_layers) if cfg.is_full(l)])
-    rec = jnp.asarray([l for l in range(cfg.num_layers) if not cfg.is_full(l)])
-    attn = jax.tree.map(lambda x: x[full], layers.pop("attn"))
-    mixer = jax.tree.map(lambda x: x[rec], layers.pop(cfg.recurrent_kind))
+    full = [l for l in range(L) if cfg.is_full(l)]
+    out = {"embed": params["embed"], "final_norm": params["final_norm"],
+           "unembed": params["unembed"]}
+    mixer = "mla" if cfg.attention_kind == "mla" else "attn"
+    out[mixer] = cut(layers.pop(mixer), full)
+    if cfg.recurrent_layers:
+        out[cfg.recurrent_kind] = cut(layers.pop(cfg.recurrent_kind),
+                                      [l for l in range(L) if l not in full])
     experts = {}
     if cfg.num_experts:
         moe = dict(layers["moe"])
-        experts = moe.pop("experts")
+        experts = cut(moe.pop("experts"), list(range(cfg.first_k_dense, L)))
         layers["moe"] = moe
-    return {"embed": params["embed"], "final_norm": params["final_norm"],
-            "unembed": params["unembed"], "layers": layers,
-            "experts": experts, cfg.recurrent_kind: mixer, "attn": attn}
+    if "dense" in params:
+        out["dense"] = params["dense"]
+    return dict(out, layers=layers, experts=experts)
 
 
 # ---------------------------------------------------------------------------
@@ -390,6 +523,65 @@ def attn_project(cfg: HybridConfig, ap, y, positions):
             [_rope(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]], -1)
 
     return rope(q), rope(k), v, gate
+
+
+def mla_project(cfg: HybridConfig, mp, y, positions):
+    """Latent attention's projections of y [..., H] at positions [...]:
+    queries through their bottleneck, ``q_n [..., n, nope]`` and ``q_r
+    [..., n, rope]`` (rotated), and the token's latent ``[..., c + rope]``:
+    the normed compressed vector ``c_kv`` and the one rotated key ``k_r``
+    all heads share. That vector is what the cache holds."""
+    dt, c, dn = y.dtype, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    inv = cfg.mla_inv_freq()
+    cq = _rms(y @ mp["wqa"].astype(dt), mp["q_norm"], cfg.norm_eps)
+    q = jnp.einsum("...q,qnd->...nd", cq, mp["wqb"].astype(dt))
+    kva = y @ mp["wkva"].astype(dt)
+    c_kv = _rms(kva[..., :c], mp["kv_norm"], cfg.norm_eps)
+    q_r = _rope(q[..., dn:], positions, cfg.rope_theta, inv)
+    k_r = _rope(kva[..., None, c:], positions, cfg.rope_theta, inv)[..., 0, :]
+    return q[..., :dn], q_r, jnp.concatenate([c_kv, k_r], axis=-1)
+
+
+def mla_absorb_q(cfg: HybridConfig, mp, q_n):
+    """The absorbed query ``q_n W_kvb^K`` [..., n, c]: a head's query in the
+    latent's own coordinates, so that its score against a cached token is a
+    product with the latent itself."""
+    wk = mp["wkvb"][..., :cfg.qk_nope_head_dim].astype(q_n.dtype)
+    return jnp.einsum("...nd,cnd->...nc", q_n, wk)
+
+
+def mla_absorb_o(cfg: HybridConfig, mp, o):
+    """``o W_kvb^V``: attention's output over latents [..., n, c] to a
+    head's values [..., n, v]."""
+    wv = mp["wkvb"][..., cfg.qk_nope_head_dim:].astype(o.dtype)
+    return jnp.einsum("...nc,cnd->...nd", o, wv)
+
+
+def mla_expand(cfg: HybridConfig, mp, latent):
+    """The expanded form's keys and values of cached latents [..., c +
+    rope]: ``k_n [..., n, nope]``, ``v [..., n, v]`` and the shared rotary
+    key ``k_r [..., rope]``."""
+    c, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = jnp.einsum("...c,cnd->...nd", latent[..., :c],
+                    mp["wkvb"].astype(latent.dtype))
+    return kv[..., :dn], kv[..., dn:], latent[..., c:c + cfg.qk_rope_head_dim]
+
+
+def mla_output(mp, o):
+    """``concat(o) W_o``; o [..., n, v]."""
+    return jnp.einsum("...nd,ndh->...h", o, mp["wo"].astype(o.dtype))
+
+
+def mla_attention(cfg: HybridConfig, mp, q_n, q_r, latent):
+    """Causal latent attention of whole sequences in the expanded form, no
+    cache: q_n, q_r [B, S, n, .]; latent [B, S, c + rope]."""
+    S, dt = q_n.shape[1], q_n.dtype
+    k_n, v, k_r = mla_expand(cfg, mp, latent)
+    s = (jnp.einsum("bsnd,btnd->bnst", q_n, k_n)
+         + jnp.einsum("bsnd,btd->bnst", q_r, k_r)).astype(jnp.float32)
+    ok = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+    pr = jax.nn.softmax(jnp.where(ok, s * cfg.mla_scale, -1e30), axis=-1)
+    return jnp.einsum("bnst,btnd->bsnd", pr.astype(dt), v)
 
 
 @jax.named_scope("attn_gate")
@@ -481,21 +673,34 @@ def causal_conv(taps, tail, x):
     return out, window
 
 
-def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None):
+def _swiglu(mp, y):
+    dt = y.dtype
+    a = jax.nn.silu(y @ mp["wg"].astype(dt)) * (y @ mp["wi"].astype(dt))
+    return a @ mp["wo"].astype(dt)
+
+
+def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None,
+                 dense=None):
     """``x + c * ffn(norm(x))`` on flat tokens x [T, H] (``c`` the residual
     scale): the expert block, with its routing counts beside it, or the dense
-    SwiGLU (no counts)."""
+    SwiGLU (no counts): for every layer of a stack without experts, and with
+    ``dense`` (one prologue layer's ``wg``, ``wi``, ``wo``) for a layer of
+    the prologue. ``layer`` indexes ``experts`` (the expert layers alone)."""
     y = _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
+    if dense is not None:
+        with jax.named_scope("dense_ffn"):
+            return residual(cfg, x, _swiglu(dense, y)), None
     if not cfg.num_experts:
         with jax.named_scope("mlp"):
-            mp, dt = lp["mlp"], y.dtype
-            a = jax.nn.silu(y @ mp["wg"].astype(dt)) * (y @ mp["wi"].astype(dt))
-            return residual(cfg, x, a @ mp["wo"].astype(dt)), None
+            return residual(cfg, x, _swiglu(lp["mlp"], y)), None
     moe = lp["moe"]
+    shared = dict(moe["shared"])
+    if cfg.shared_gate:
+        shared["gate"] = moe["shared_gate"]
     out, counts = moe_ffn_share(
         y, moe["router"], experts, cfg.gate, offset=cfg.expert_offset,
-        shared=dict(moe["shared"], gate=moe["shared_gate"]), valid=valid,
-        layer=layer)
+        shared=shared, valid=valid, layer=layer,
+        router_bias=moe.get("router_bias"))
     return residual(cfg, x, out), counts
 
 
@@ -571,16 +776,24 @@ def apply(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
     def layer_of(l):
         return jax.tree.map(lambda a: a[l], p["layers"])
 
+    K = cfg.first_k_dense if cfg.num_experts else 0
+
     def ffn(x, l):
+        dense = jax.tree.map(lambda a: a[l], p["dense"]) if l < K else None
         out, _ = expert_block(cfg, layer_of(l), p["experts"],
-                              x.reshape(B * S, -1), l)
+                              x.reshape(B * S, -1), l - K, dense=dense)
         return out.reshape(B, S, -1)
 
     l_kv = l_rec = 0
     for l in range(cfg.num_layers):
         lp = layer_of(l)
         y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        if cfg.is_full(l):
+        if cfg.attention_kind == "mla":
+            mp = jax.tree.map(lambda a: a[l], p["mla"])
+            q_n, q_r, latent = mla_project(cfg, mp, y, positions)
+            a = mla_attention(cfg, mp, q_n, q_r, latent)
+            x = residual(cfg, x, mla_output(mp, a))
+        elif cfg.is_full(l):
             ap = jax.tree.map(lambda a: a[l_kv], p["attn"])
             q, k, v, gate = attn_project(cfg, ap, y, positions)
             a = full_attention(cfg, q, k, v, positions)

@@ -352,11 +352,40 @@ def _norm(x, p, kind: str, eps: float):
     return out.astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
-    """Rotary embedding on [..., seq, heads, head_dim]."""
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``m = 0.1 mscale ln(factor) + 1`` (1 at
+    ``factor`` <= 1). A model that scales its rotary frequencies this way
+    multiplies its softmax scale by ``m**2`` (``mscale`` its
+    ``mscale_all_dim``)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's inverse frequencies over ``dim`` rotary dimensions [dim / 2]
+    float32: pair ``i`` keeps ``theta^(-2i/dim)`` below the correction
+    dimension of ``beta_fast`` rotations over ``original_max`` positions, is
+    interpolated (divided by ``factor``) above that of ``beta_slow``, and
+    blends linearly between the two."""
+    def correction_dim(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    extra = theta ** (-2.0 * i / dim)
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def _rope(x, positions, theta: float, inv_freq=None):
+    """Rotary embedding on [..., seq, heads, head_dim] (rotate-half pairs);
+    ``inv_freq [head_dim / 2]`` replaces ``theta``'s frequencies (YaRN)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = inv_freq if inv_freq is not None else \
+        1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B?, S, half]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]

@@ -173,6 +173,37 @@ def _register_hybrid():
             sparse_topk=5, sparse_kernel_size=8, sparse_kernel_stride=4,
             sparse_block_size=16, sparse_init_blocks=1, sparse_window_size=32,
             sparse_dense_len=64, remat=False),
+        # Kimi-K2 (huggingface.co/moonshotai/Kimi-K2.7-Code config.json,
+        # model_type kimi_k2: DeepSeek-V3's layers) at the published values:
+        # multi-head latent attention in all 61 layers, one leading dense
+        # layer, then 384 routed experts (top 8 by sigmoid score with a
+        # bias that corrects the choice, weights scaled by 2.827) and an
+        # ungated shared expert, YaRN over 4096 original positions.
+        "kimi-k2": HybridConfig(
+            vocab_size=163840, hidden_size=7168, num_layers=61, num_heads=64,
+            num_kv_heads=64, attn_head_dim=192, ffn_size=18432,
+            max_seq_len=262144, pos_emb="rope", norm="rmsnorm",
+            activation="swiglu", tie_embeddings=False, rope_theta=50000.0,
+            norm_eps=1e-5, full_attention_interval=1, attention_kind="mla",
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, rope_yarn_factor=64.0,
+            rope_original_max=4096, rope_beta_fast=32.0, rope_beta_slow=1.0,
+            rope_mscale_all_dim=1.0, first_k_dense=1, num_experts=384,
+            top_k=8, moe_ffn_size=2048, shared_ffn_size=2048,
+            router_scoring="sigmoid", routed_scale=2.827, shared_gate=False),
+        # the same stack at a toy size (YaRN over 32 original positions, so
+        # that a prompt of a hundred tokens lies in the interpolated range)
+        "tiny-kimi": HybridConfig(
+            vocab_size=256, hidden_size=64, num_layers=3, num_heads=4,
+            num_kv_heads=4, attn_head_dim=48, ffn_size=128, max_seq_len=256,
+            pos_emb="rope", norm="rmsnorm", activation="swiglu",
+            tie_embeddings=False, rope_theta=1e4, norm_eps=1e-5,
+            full_attention_interval=1, attention_kind="mla", q_lora_rank=48,
+            kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=16,
+            v_head_dim=32, rope_yarn_factor=8.0, rope_original_max=32,
+            first_k_dense=1, num_experts=16, top_k=4, moe_ffn_size=32,
+            shared_ffn_size=32, experts_held=4, router_scoring="sigmoid",
+            routed_scale=2.5, shared_gate=False, remat=False),
     })
 
 

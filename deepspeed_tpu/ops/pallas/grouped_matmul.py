@@ -183,7 +183,11 @@ def choose_tiles(m: int, kdim: int, n: int, num_groups: int, dtype,
         bn, bk = 1024, 512
     else:
         bk = _pick_block(kdim, max(128, _RHS_BLOCK_BYTES // (128 * itemsize)))
-        bn = max(128, _RHS_BLOCK_BYTES // (bk * itemsize))
+        # (a power of two, so that the halving in _pick_block lands on a
+        # divisor of n that fills lanes: at a contraction of 7168 the bytes
+        # hold 146 columns, which would halve down to 4)
+        bn = max(128, pl.next_power_of_2(
+            _RHS_BLOCK_BYTES // (bk * itemsize) + 1) // 2)
     bm, bn, bk = (min(b, cap) if cap > 0 else b
                   for b, cap in ((bm, block_m), (bn, block_n), (bk, block_k)))
     return _pick_block(m, bm), _pick_block(n, bn), _pick_block(kdim, bk)

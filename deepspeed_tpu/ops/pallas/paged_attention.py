@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -133,21 +134,20 @@ def _grid_walk_kernel(bt_ref, ctx_ref, layer_ref, q_ref, *refs, bs: int,
             out_ref[0, n] = (acc_ref[rows, :] / l).astype(out_ref.dtype)
 
 
-def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
-                   buf, sem, slot_ref, *, bs: int, nb: int, c: int, g: int,
-                   nchunks: int, pages: int, scale: float):
-    """One sequence a grid step: walk its own pages, ``pages`` of them a
-    block, block n+1 (or the next sequence's first) in flight while block
-    n is multiplied. ``buf`` is [2, pages, bs, 2, nkv, hd]; a chunk of
-    ``c`` KV heads of a block is one [pages*bs*c, hd] operand, its rows
-    (token, head) pairs in the pool's own order, so nothing is moved
-    between the fetch and the MXU: the product also multiplies each query
-    row with the other heads' keys, and the head mask drops those."""
+def _walk_pages(bt_ref, ctx_ref, layer_ref, kv_hbm, buf, sem, slot_ref, *,
+                bs: int, nb: int, pages: int, multiply, init,
+                started_ref=None):
+    """The walk the decode kernels share, one sequence a grid step: fetch
+    the sequence's own pages from the pool in HBM (``kv_hbm[layer, page]``,
+    any page shape) into ``buf[slot, i]``, ``pages`` of them a block, block
+    n+1 (or the next sequence's first) in flight while block n is
+    multiplied. ``multiply(blk, slot, carry)`` is a block's arithmetic on
+    ``buf[slot]``, ``init`` its carry. Returns the carry after the
+    sequence's last block. ``started_ref`` (SMEM, a slot a grid step), where
+    given, counts the page copies this grid step starts."""
     s = pl.program_id(0)
     S = pl.num_programs(0)
     T = pages * bs                      # tokens a block
-    N = T * c                           # (token, head) columns a chunk
-    rp = q_ref.shape[2]
     layer = layer_ref[0]
 
     def copies(seq, blk, slot, fn):
@@ -168,9 +168,15 @@ def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
             return carry
 
         jax.lax.fori_loop(0, live, page_copy, 0)
+        return live
 
     def start(seq, blk, slot):
-        copies(seq, blk, slot, lambda dma: dma.start())
+        n = copies(seq, blk, slot, lambda dma: dma.start())
+        if started_ref is not None:
+            started_ref[s] += n
+
+    if started_ref is not None:
+        started_ref[s] = 0
 
     @pl.when(s == 0)
     def _first():
@@ -180,8 +186,7 @@ def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
         slot_ref[0] = 0
         start(s, 0, 0)
 
-    ctx = ctx_ref[s]
-    nblk = (ctx + T - 1) // T
+    nblk = (ctx_ref[s] + T - 1) // T
     slot0 = slot_ref[0]
     slot_ref[0] = (slot0 + nblk) % 2    # where the next sequence starts
 
@@ -189,20 +194,38 @@ def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
     def _dead():
         start(s + 1, 0, slot0)
 
+    def block(blk, carry):
+        slot = (slot0 + blk) % 2
+        # the next block, or the next sequence's first, flies meanwhile
+        last = blk + 1 == nblk
+        start(jnp.where(last, s + 1, s), jnp.where(last, 0, blk + 1),
+              1 - slot)
+        copies(s, blk, slot, lambda dma: dma.wait())
+        return multiply(blk, slot, carry)
+
+    return jax.lax.fori_loop(0, nblk, block, init)
+
+
+def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
+                   buf, sem, slot_ref, *, bs: int, nb: int, c: int, g: int,
+                   nchunks: int, pages: int, scale: float):
+    """One sequence a grid step over its own pages (:func:`_walk_pages`).
+    ``buf`` is [2, pages, bs, 2, nkv, hd]; a chunk of
+    ``c`` KV heads of a block is one [pages*bs*c, hd] operand, its rows
+    (token, head) pairs in the pool's own order, so nothing is moved
+    between the fetch and the MXU: the product also multiplies each query
+    row with the other heads' keys, and the head mask drops those."""
+    T = pages * bs                      # tokens a block
+    N = T * c                           # (token, head) columns a chunk
+    rp = q_ref.shape[2]
+    ctx = ctx_ref[pl.program_id(0)]
     col = jax.lax.broadcasted_iota(jnp.int32, (rp, N), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (rp, N), 0)
     tok = col // c                      # c is a power of two
     own = (col % c) == (row // g)       # the column's head is the row's
     dt = jnp.promote_types(q_ref.dtype, buf.dtype)   # what the MXU takes
 
-    def block(blk, carry):
-        slot = (slot0 + blk) % 2
-
-        # the next block, or the next sequence's first, flies meanwhile
-        last = blk + 1 == nblk
-        start(jnp.where(last, s + 1, s), jnp.where(last, 0, blk + 1),
-              1 - slot)
-        copies(s, blk, slot, lambda dma: dma.wait())
+    def multiply(blk, slot, carry):
         visible = jnp.logical_and(own, tok < ctx - blk * T)
         out = []
         for n, (m_prev, l_prev, acc) in enumerate(carry):
@@ -231,7 +254,9 @@ def _decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
                   jnp.zeros((rp, 1), jnp.float32),
                   jnp.zeros((rp, hd), jnp.float32))
                  for _ in range(nchunks))
-    final = jax.lax.fori_loop(0, nblk, block, init)
+    final = _walk_pages(bt_ref, ctx_ref, layer_ref, kv_hbm, buf, sem,
+                        slot_ref, bs=bs, nb=nb, pages=pages,
+                        multiply=multiply, init=init)
     for n, (_, l, acc) in enumerate(final):
         l = jnp.where(l == 0.0, 1.0, l)   # dead slots, padded rows
         out_ref[0, n] = (acc / l).astype(out_ref.dtype)
@@ -539,3 +564,121 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array,
       jnp.minimum(context_lens.astype(jnp.int32), Bm * bs), layer, qg,
       *([kv] * len(kv_specs)))
     return out[:, :, :rows, :].reshape(S, nh, hd)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) decode: the absorbed form over a latent pool
+# ---------------------------------------------------------------------------
+
+# tokens of context a block of the latent walk holds: 8 pages of 64, 640 KiB
+# a buffer at 640 bf16 lanes a token; the score tile is [heads, tokens]
+_MLA_BLOCK_TOKENS = 512
+
+
+def _mla_decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
+                       started_ref, buf, sem, slot_ref, acc_ref, *, bs: int,
+                       nb: int,
+                       pages: int, vd: int, scale: float):
+    """One sequence a grid step over its own latent pages
+    (:func:`_walk_pages`), every head at once: the heads' absorbed queries
+    ``[heads, W]`` against a block ``[pages * bs, W]``, the values the first
+    ``vd`` columns of the same rows already in VMEM. There is no KV head, so
+    no head mask."""
+    T = pages * bs
+    W = buf.shape[-1]
+    rp = q_ref.shape[1]
+    ctx = ctx_ref[pl.program_id(0)]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (rp, T), 1)
+    dt = jnp.promote_types(q_ref.dtype, buf.dtype)
+    q = q_ref[0].astype(dt)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def multiply(blk, slot, carry):
+        m_prev, l_prev = carry
+        k = buf[slot].reshape(T, W).astype(dt)
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(tok < ctx - blk * T, sc, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(dt), k[:, :vd], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new
+
+    _, l = _walk_pages(
+        bt_ref, ctx_ref, layer_ref, kv_hbm, buf, sem, slot_ref, bs=bs, nb=nb,
+        pages=pages, multiply=multiply, started_ref=started_ref,
+        init=(jnp.full((rp, 1), NEG_INF, jnp.float32),
+              jnp.zeros((rp, 1), jnp.float32)))
+    l = jnp.where(l == 0.0, 1.0, l)       # dead slots
+    out_ref[0] = (acc_ref[...] / l).astype(out_ref.dtype)
+
+
+def mla_decode_attention(q: jax.Array, kv: jax.Array, block_table: jax.Array,
+                         context_lens: jax.Array, *, value_dim: int,
+                         scale: float, layer,
+                         pages_per_compute_block: int = None
+                         ) -> Tuple[jax.Array, jax.Array]:
+    """Decode attention of multi-head latent attention in its absorbed form,
+    over a latent pool (``inference/ragged/kv_cache.py``, kind "latent").
+
+    q            [S, heads, W]: each head's absorbed query (its nope part
+                 through ``W_kvb^K``, then its rotated part), zero beyond
+                 the latent's width up to the pool's ``W``
+    kv           [L, num_blocks, block_size, W], read at ``layer``: a token's
+                 row is its latent (the compressed vector, the rotary key,
+                 zeros up to ``W``, whole 128-lane tiles)
+    block_table  [S, max_pages]; context_lens [S] (0: a dead slot, output 0)
+    value_dim    the leading columns of a row that are also its value (the
+                 compressed vector's width, a multiple of 128)
+
+    A score is ``q . row * scale``; the output ``[S, heads, value_dim]`` is
+    the softmax-weighted sum of the rows' leading ``value_dim`` columns: keys
+    and values are the same bytes of a page, fetched once. Softmax state in
+    float32, operands in the pool's type. Returns (output, pages ``[S]``
+    int32: the page copies the kernel started in each grid step, counted
+    where it starts them; their sum is what the call fetched).
+    """
+    S, nh, W = q.shape
+    _, nb, bs, Wp = kv.shape
+    if W != Wp or W % 128 or value_dim % 128 or value_dim > W:
+        raise ValueError(f"queries {q.shape} against a latent pool "
+                         f"{kv.shape} with values {value_dim} wide: the "
+                         f"widths must agree and fill whole 128-lane tiles")
+    Bm = block_table.shape[1]
+    P = pages_per_compute_block or max(1, _MLA_BLOCK_TOKENS // bs)
+    P = max(1, min(int(P), Bm))
+    rp = -(-nh // 8) * 8
+    if rp != nh:
+        q = jnp.pad(q, ((0, 0), (0, rp - nh), (0, 0)))
+    kernel = functools.partial(_mla_decode_kernel, bs=bs, nb=nb, pages=P,
+                               vd=value_dim, scale=float(scale))
+    out, started = pl.pallas_call(
+        kernel,
+        name="mla_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, rp, W), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, rp, value_dim),
+                                    lambda s, *_: (s, 0, 0)),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[
+                pltpu.VMEM((2, P, bs, W), kv.dtype),       # two blocks
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((rp, value_dim), jnp.float32),  # weighted rows
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, rp, value_dim), q.dtype),
+                   jax.ShapeDtypeStruct((S,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(block_table.astype(jnp.int32),
+      jnp.minimum(context_lens.astype(jnp.int32), Bm * bs),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, kv)
+    return out[:, :nh], started

@@ -38,6 +38,16 @@ class GateConfig:
     drop_tokens: bool = True
     aux_loss_weight: float = 0.01
     z_loss_weight: float = 0.0
+    # the router's rule (:func:`route`): "softmax" over all the outputs, the
+    # top_k renormalised; "sigmoid": each output's sigmoid is its score, a
+    # per-expert bias joins the *choice* only, the chosen scores are
+    # renormalised and multiplied by ``routed_scale``
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r} (softmax | sigmoid)")
 
 
 def compute_capacity(tokens_per_group: int, cfg: GateConfig,
@@ -258,23 +268,46 @@ def route_top_k(y: jax.Array, router_w: jax.Array, top_k: int):
     return top / jnp.sum(top, axis=-1, keepdims=True), idx.astype(jnp.int32)
 
 
+def route(y: jax.Array, router_w: jax.Array, cfg: GateConfig,
+          bias: Optional[jax.Array] = None):
+    """The router's rule as ``cfg`` states it, in float32 at ``HIGHEST``:
+    :func:`route_top_k`, or with ``scoring`` "sigmoid" the scores ``sigmoid(y
+    W_r)``, the ``top_k`` of ``score + bias`` chosen (``bias [E]``: the
+    correction that balances load takes part in the choice and never in a
+    weight), the chosen *scores* renormalised to sum to one and multiplied by
+    ``routed_scale``. Returns (weights [T, k] float32, experts [T, k])."""
+    if cfg.scoring == "softmax":
+        return route_top_k(y, router_w, cfg.top_k)
+    score = jax.nn.sigmoid(jnp.einsum(
+        "th,he->te", y.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    choose = score if bias is None else score + bias.astype(jnp.float32)
+    _, idx = lax.top_k(choose, cfg.top_k)
+    top = jnp.take_along_axis(score, idx, axis=-1)
+    w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scale, idx.astype(jnp.int32)
+
+
 @jax.named_scope("moe")
 def moe_ffn_share(y: jax.Array, router_w: jax.Array,
                   expert_params: Dict[str, jax.Array], cfg: GateConfig, *,
                   offset: int = 0, shared: Optional[Dict] = None,
-                  valid: Optional[jax.Array] = None, layer=None
+                  valid: Optional[jax.Array] = None, layer=None,
+                  router_bias: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """An expert layer that holds a *share* of the experts: one chip's part
     of a layer whose ``cfg.num_experts`` experts are divided over the chips
     of an expert-parallel deployment (model-configs guide, section 4).
 
-    The router runs over all ``cfg.num_experts`` outputs and the weights are
-    renormalised over the chosen ``top_k``; the experts held here are
+    The router runs over all ``cfg.num_experts`` outputs by ``cfg``'s rule
+    (:func:`route`; ``router_bias`` is the sigmoid rule's per-expert
+    correction); the experts held here are
     ``offset .. offset + E_held`` (``expert_params`` leaves ``[E_held, ...]``,
     or ``[L, E_held, ...]`` with ``layer``). The result is what *these*
     experts add for the tokens routed to them, plus — given ``shared``
-    (``wg``, ``wi``, ``wo`` and the gate vector ``gate``) — the shared
-    expert behind its sigmoid gate, which every chip computes alike. A token
+    (``wg``, ``wi``, ``wo`` and, for a model that has one, the gate vector
+    ``gate``) — the shared expert, behind its sigmoid gate or added as it is,
+    which every chip computes alike. A token
     none of whose experts live here gets the shared expert alone. Nothing
     stands in for the absent chips: on one chip there is no exchange; on a
     mesh with an ``ep`` axis the same layer is :func:`moe_ffn_dropless`,
@@ -293,7 +326,7 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
     T, H = y.shape
     held = expert_params["wi"].shape[-3]
     k = cfg.top_k
-    w, idx = route_top_k(y, router_w, k)
+    w, idx = route(y, router_w, cfg, router_bias)
     with jax.named_scope("moe_route"):
         here = (idx >= offset) & (idx < offset + held)
         if valid is not None:
@@ -313,8 +346,9 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
         group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
             jnp.int32)
         # one work list for the three products: they share the row tile
-        work = gm.make_group_metadata(group_sizes, m,
-                                      gm.row_tile(m, held, y.dtype))
+        # (the tile the products will use: the largest that divides m)
+        work = gm.make_group_metadata(
+            group_sizes, m, gm.choose_tiles(m, H, H, held, y.dtype)[0])
     with jax.named_scope("moe_experts"):
         out = _expert_ffn(y[row_token], group_sizes, expert_params, "swiglu",
                           y.dtype, layer=layer, metadata=work)
@@ -325,11 +359,12 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
             dt = y.dtype
             hid = jax.nn.silu(y @ shared["wg"].astype(dt)) \
                 * (y @ shared["wi"].astype(dt))
-            gate = jax.nn.sigmoid(jnp.einsum(
-                "th,h->t", y.astype(jnp.float32),
-                shared["gate"].astype(jnp.float32)))
-            total = total + gate[:, None] * (
-                hid @ shared["wo"].astype(dt)).astype(jnp.float32)
+            out = (hid @ shared["wo"].astype(dt)).astype(jnp.float32)
+            if "gate" in shared:
+                out = out * jax.nn.sigmoid(jnp.einsum(
+                    "th,h->t", y.astype(jnp.float32),
+                    shared["gate"].astype(jnp.float32)))[:, None]
+            total = total + out
     counts = {"pairs": jnp.sum(here).astype(jnp.int32),
               "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32),
               "work_items": gm.work_items(work)}

@@ -146,6 +146,42 @@ def test_hybrid_programs_hold_one_grouped_product_a_call_site(program):
     assert calls == 3 * len(cfg.stack_plan[1]) and under_cond == 0
 
 
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", ["mla/mla_project", "mla/mla_absorb", "mla/mla_attn",
+                "dense_ffn", "moe/moe_route", "moe/moe_shared"]),
+    ("prefill", ["mla/mla_project", "mla/mla_chunk", "dense_ffn",
+                 "moe/moe_experts"])])
+def test_latent_programs_hold_their_scopes_and_one_layer_body(program, scopes):
+    """The trace readers key on these paths (``mla_decode_ms`` on ``mla``,
+    ``mla_decode_roofline`` on ``mla_attn``). The dense prologue is a layer
+    of its own before the scan and the expert layers one scanned body: two
+    ``mla_decode`` kernels and three grouped products a token step, whatever
+    the depth."""
+    model = get_model("tiny-kimi", num_layers=5)
+    cfg = model.config
+    params = jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pools = {"kv": _sds((cfg.kv_layers, NB, BS, 256)),
+             "counters": _sds((len(COUNTERS),), I32)}
+    fns = engine_v2._shared_step_fns(cfg, None)
+    ids = lambda *shape: _sds(shape, I32)  # noqa: E731
+    if program == "prefill":
+        traced = fns["prefill"].trace(params, pools, ids(1, 16), ids(1),
+                                      ids(1), ids(1, BM))
+    else:
+        traced = fns["decode"].trace(params, pools, ids(S), ids(S),
+                                     ids(S, BM), ids(S))
+    text = traced.lower().as_text(debug_info=True)
+    for path in scopes:
+        assert path in text, path
+    assert _module(traced.lower()) == f"jit_dstpu_serve_{program}"
+    if program == "decode":
+        calls, under_cond = _grouped_matmul_calls(traced.jaxpr.jaxpr)
+        assert calls == 3 and under_cond == 0
+        assert len(re.findall(r"\bname=mla_decode\b",
+                              str(traced.jaxpr))) == 2
+
+
 # -- training programs -------------------------------------------------------
 
 TINY = TransformerConfig(
@@ -259,6 +295,11 @@ KERNELS = [
     ("flash_bwd_dq", jax.grad(_flash, argnums=(0, 1, 2)), _QKV),
     ("paged_decode", paged_attention.paged_decode_attention,
      (_sds((4, 4, 64)), _POOL, _sds((4, 4), I32), _sds((4,), I32))),
+    ("mla_decode",
+     lambda q, kv, bt, ctx: paged_attention.mla_decode_attention(
+         q, kv, bt, ctx, value_dim=128, scale=0.1, layer=1),
+     (_sds((4, 4, 256)), _sds((2, 16, 8, 256)), _sds((4, 4), I32),
+      _sds((4,), I32))),
     ("paged_prefill", paged_attention.paged_prefill_attention,
      (_sds((2, 8, 4, 64)), _POOL, _sds((2, 4), I32), _sds((2,), I32),
       _sds((2,), I32))),

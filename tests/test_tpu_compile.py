@@ -423,3 +423,68 @@ def test_sala_programs_keep_the_three_pools_in_place(chip, program):
     assert mem.temp_size_in_bytes < 2**30
     # 5.25 GiB of weights + 2.54 GiB of pools
     assert 7.7 < mem.argument_size_in_bytes / 2**30 < 7.9
+
+
+def _kimi_c1(chip):
+    """``kimi-k2.7-code-serve-c1`` as abstract arguments on the described
+    chip. Returns (step programs, serving params, pools, ids, the pool's
+    bytes)."""
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.inference.ragged.kv_cache import KVCacheConfig
+    from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.zoo import get_model
+
+    blocks, bs, pages = 14336, 64, 512
+    model = get_model("kimi-k2", num_layers=5, experts_held=12,
+                      vocab_size=20480, max_seq_len=pages * bs,
+                      param_dtype=BF16, remat=False)
+    cfg = model.config
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                       jax.eval_shape(model.init, jax.random.PRNGKey(0))))
+    kv = KVCacheConfig(num_layers=5, kv_heads=64, head_dim=192,
+                       block_size=bs, num_blocks=blocks, dtype=BF16,
+                       kind="latent", latent_dim=cfg.latent_dim)
+    pools = {"kv": sds(kv.pool_shape, BF16),
+             "counters": sds((len(COUNTERS),), jnp.int32)}
+    return (engine_v2._shared_step_fns(cfg, None), params, pools,
+            lambda *shape: sds(shape, jnp.int32),
+            kv.num_blocks * kv.bytes_per_block)
+
+
+@pytest.mark.parametrize("program", ["decode", "multi_decode", "prefill"])
+def test_kimi_programs_keep_the_latent_pool_in_place(chip, program):
+    """The latent pool (14,336 pages of 64 tokens of 640 bf16 lanes, five
+    layers: 5.47 GiB, the 576 values a token padded to whole lane tiles as
+    the device's layout pads them anyway) comes back in the buffer it came
+    in; the token step holds the ``mla_decode`` kernel and the grouped
+    product; a 2,048-token chunk of one sequence keeps its temporaries under
+    1 GiB (scores of 512 context tokens at a time, their keys and values
+    expanded for those 512 alone)."""
+    fns, params, pools, ids, held = _kimi_c1(chip)
+    S, pages = 48, 512
+    if program == "prefill":
+        lowered = fns["prefill"].lower(params, pools, ids(1, 2048), ids(1),
+                                       ids(1), ids(1, pages))
+    else:
+        steps = {"steps": 8} if program == "multi_decode" else {}
+        lowered = fns[program].lower(params, pools, ids(S), ids(S),
+                                     ids(S, pages), ids(S), **steps)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if program != "prefill":
+        assert "mla_decode" in text and "gmm" in text
+    mem = compiled.memory_analysis()
+    assert held == 5 * 14336 * 64 * 640 * 2
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 2**30
+    # 6.51 GiB of weights + 5.47 GiB of pool
+    assert 11.9 < mem.argument_size_in_bytes / 2**30 < 12.1
+    print(program, mem.argument_size_in_bytes / 2**30,
+          mem.temp_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30)
