@@ -352,7 +352,11 @@ class InferenceEngineV2:
                       "tokens_gather": 0, "tokens_prefill_kernel": 0,
                       "tokens_decode": 0, "tokens_multi_decode": 0,
                       "prefill_chunks": 0, "admission_wait_s": 0.0,
-                      "ttft_s": 0.0, "first_tokens": 0}
+                      "ttft_s": 0.0, "first_tokens": 0,
+                      # a step split by program (_split_by_program):
+                      # steps that made more than one program call, and
+                      # the calls of the prefill program made for chunks
+                      "split_steps": 0, "prefill_chunk_calls": 0}
         if self._hybrid:
             # the expert layers' routing, counted on the device by every
             # step program and fetched with the step's tokens: tokens x
@@ -1154,10 +1158,12 @@ class InferenceEngineV2:
                 self._preempt_starved()
                 return {}
         t0 = time.perf_counter()
-        # one program a part (one part, all of the step, but for a model
-        # that runs no gather program); the pools pass from one to the next
+        # one program a part (_split_by_program); the pools pass from one
+        # to the next
         runs = []
-        for part in self._split_by_program(scheduled):
+        parts = self._split_by_program(scheduled)
+        self.stats["split_steps"] += len(parts) > 1
+        for part in parts:
             mine = [scheduled[i] for i in part]
             with self.mesh:
                 with span("build_batch"):
@@ -1208,13 +1214,19 @@ class InferenceEngineV2:
                 # rows) are the step's only result it reads. Several
                 # programs: each one's sampled rows, put in the order of
                 # the schedule, so that the pick is one call whose row i
-                # belongs to the i-th scheduled sequence as ever
-                if len(runs) == 1:
+                # belongs to the i-th scheduled sequence as ever. (A
+                # chunk call's rows are taken this way even where it is the
+                # step's only call, and the decode program's logits are its
+                # slots' rows as they stand: so no shape of the take is one
+                # that only a step with token rows beside chunks compiles.)
+                if len(runs) == 1 and not (self._splits_steps
+                                           and last_program == "prefill"):
                     logits, idx_dev = runs[0][2], jnp.asarray(picks[0])
                 else:
                     logits = None
-                    for (part, _, lg, _), idx in zip(runs, picks):
-                        rows = self._take_rows(lg, jnp.asarray(idx))
+                    for (part, program, lg, _), idx in zip(runs, picks):
+                        rows = lg if program == "decode" else \
+                            self._take_rows(lg, jnp.asarray(idx))
                         at = np.full(self.max_seqs, self.max_seqs, np.int32)
                         at[:len(part)] = part
                         logits = dstpu_merge_rows(
@@ -1270,25 +1282,67 @@ class InferenceEngineV2:
             self._release_finished()
         return emitted
 
+    @property
+    def _splits_steps(self) -> bool:
+        """Whether a step is split by program: on the kernel path for a
+        dense model (``model_runner``), always for a model with block-sparse
+        or latent attention (no gather program is built for it). Not where
+        the gather program is the path (``_use_paged_kernel`` off), nor for
+        the hybrid runner of a model that still has a gather program: its
+        prefill program is the Pallas kernel over the whole step, or the
+        step is left to that program (_plan_prefill_segments)."""
+        return self._no_gather or (self._use_paged_kernel
+                                   and not self._hybrid)
+
     def _split_by_program(self, scheduled):
         """The step's work as the lists (of indices into ``scheduled``) one
-        program each takes: all of it, but for a model with block-sparse or
-        latent attention (long contexts: the gather program, a context a
-        token, is not built for it) the sequences that advance one token go
-        through the decode program and every chunk through the prefill
-        program, one sequence a call."""
-        if not self._no_gather:
+        program call each takes: all of it, but where steps are split
+        (_splits_steps) the sequences that advance one token go through the
+        decode program, one call, and the chunks through the prefill
+        program over their own sequences' pages. Chunks share a call, in
+        the order of the schedule, while its padded layout stays within
+        twice the step's budget (``S x tq <= 2 x max_tokens``, chunk rows
+        only: a call is bound by one read of the weights, so several short
+        chunks cost what one does); one sequence a call for a model with
+        block-sparse or latent attention."""
+        chunks = [i for i, s in enumerate(scheduled) if len(s[1]) > 1]
+        if not self._splits_steps or not chunks:
             return [list(range(len(scheduled)))]
+        calls = []
+        for i in chunks:
+            if calls and not self._no_gather and self._pads_within_budget(
+                    [len(scheduled[j][1]) for j in (*calls[-1], i)]):
+                calls[-1].append(i)
+            else:
+                calls.append([i])
         single = [i for i, s in enumerate(scheduled) if len(s[1]) == 1]
-        chunks = [[i] for i, s in enumerate(scheduled) if len(s[1]) > 1]
-        return ([single] if single else []) + chunks
+        return ([single] if single else []) + calls
+
+    def _segment_shape(self, lens):
+        """``(S, tq)`` of the prefill program's padded layout for chunks
+        of these lengths, each bucketed to a power of two so jit compiles
+        a handful of programs."""
+        tq = self._min_segment
+        while tq < max(lens):
+            tq *= 2
+        S = 1  # segment-count bucket: slots are ordered, so the forward
+        while S < len(lens):  # runs on the leading S rows only
+            S *= 2
+        return min(S, self.max_seqs), tq
+
+    def _pads_within_budget(self, lens) -> bool:
+        """The padded layout materializes S*tq token rows (incl. [S,tq,V]
+        fp32 logits): whether that blowup stays within twice the flat
+        token budget."""
+        S, tq = self._segment_shape(lens)
+        return S * tq <= 2 * self.max_tokens
 
     def _build_step_call(self, scheduled):
-        """Pick the program for this step's mix and assemble its host
+        """Pick the program for this part of a step and assemble its host
         arrays: ``(jitted fn, program name, arguments after params and
         KV, the ragged batch)``. ``decode`` when every sequence advances one token (tokens
         line up with slots, so the compact paged-kernel path applies),
-        ``prefill`` when the Pallas prefill kernel takes the chunks,
+        ``prefill`` when the prefill program takes the chunks,
         else the flat ``gather`` program."""
         batch = build_ragged_batch(scheduled, self.max_tokens,
                                    self.max_seqs, self.max_blocks_per_seq)
@@ -1311,6 +1365,8 @@ class InferenceEngineV2:
                     f"{self._last_fallback_reason}: paged prefill fell "
                     "back to the gather path — flat-layout serve step, "
                     "no Pallas kernel; see log_summary()")
+            elif self._splits_steps:
+                self.stats["prefill_chunk_calls"] += 1
             else:
                 self.stats["prefill_kernel_steps"] += 1
             # fraction of mixed prefill steps that lost the Pallas
@@ -1348,40 +1404,28 @@ class InferenceEngineV2:
             jnp.asarray(batch.num_tokens, jnp.int32), *slots_arg), batch
 
     def _plan_prefill_segments(self, scheduled):
-        """Per-slot padded chunk layout for the Pallas prefill kernel, or
-        None when per-segment padding would outweigh the flat layout
-        (then the gather path runs). Tq is bucketed to powers of two so
-        jit compiles a handful of programs."""
-        longest = max(len(nt) for _, nt, _ in scheduled)
-        tq = self._min_segment
-        while tq < longest:
-            tq *= 2
-        if self._no_gather:     # one sequence's chunk, no Pallas kernel
-            (_, nt, sp), = scheduled
-            toks = np.zeros((1, tq), np.int32)
-            toks[0, :len(nt)] = nt
-            return (jnp.asarray(toks), jnp.asarray([sp], np.int32),
-                    jnp.asarray([len(nt)], np.int32))
-        # kernel scratch is (Tq*num_heads) rows of (2*128 + head_dim) fp32
-        # VMEM; keep it well under the ~16MB/core budget or the Mosaic
-        # compile fails at serve time (gather path has no such limit)
-        # per-shard head count under the tp shard_map
-        scratch_bytes = (tq * (self.cfg.num_heads // self._tp)
-                         * (256 + self.cfg.head_dim) * 4)
-        if scratch_bytes > 4 * 1024 * 1024:
-            self.stats["fallback_reasons"]["vmem"] += 1
-            self._last_fallback_reason = "vmem"
-            return None
-        S = 1  # segment-count bucket: slots are ordered, so the forward
-        while S < len(scheduled):  # runs on the leading S rows only
-            S *= 2
-        S = min(S, self.max_seqs)
-        # the padded layout materializes S*tq token rows (incl. [S,tq,V]
-        # fp32 logits); cap the blowup over the flat token budget
-        if S * tq > 2 * self.max_tokens:
-            self.stats["fallback_reasons"]["padding"] += 1
-            self._last_fallback_reason = "padding"
-            return None
+        """Per-slot padded chunk layout for the prefill program
+        (_segment_shape). None only where steps are not split by program:
+        the prefill program is then the Pallas kernel over the whole step,
+        so its scratch has to fit and the per-segment padding must not
+        outweigh the flat layout (then the gather program runs)."""
+        lens = [len(nt) for _, nt, _ in scheduled]
+        S, tq = self._segment_shape(lens)
+        if not self._splits_steps:
+            # kernel scratch is (Tq*num_heads) rows of (2*128 + head_dim)
+            # fp32 VMEM; keep it well under the ~16MB/core budget or the
+            # Mosaic compile fails at serve time (gather path has no such
+            # limit); per-shard head count under the tp shard_map
+            scratch_bytes = (tq * (self.cfg.num_heads // self._tp)
+                             * (256 + self.cfg.head_dim) * 4)
+            if scratch_bytes > 4 * 1024 * 1024:
+                self.stats["fallback_reasons"]["vmem"] += 1
+                self._last_fallback_reason = "vmem"
+                return None
+            if not self._pads_within_budget(lens):
+                self.stats["fallback_reasons"]["padding"] += 1
+                self._last_fallback_reason = "padding"
+                return None
         toks = np.zeros((S, tq), np.int32)
         pos0 = np.zeros(S, np.int32)
         nreal = np.zeros(S, np.int32)

@@ -95,6 +95,22 @@ def _kv_dense(kv, kv_sc, layer, dt):
     return dense[None], 0
 
 
+def _gather_sequences(cfg, kv, kv_sc, layer, block_table, dt):
+    """The pages of each row of ``block_table`` [S, Bm] (and no others)
+    taken from layer ``layer`` of the pool, a quantized pool dequantized on
+    read (only the gathered pages, never the pool), split into keys and
+    values and laid out head-major: two of [S, nkv, Lmax, hd]."""
+    gathered = kv[layer, block_table]    # [S, Bm, bs, 2, nkv, hd(/2)]
+    if kv_sc is not None:
+        gathered = kv_dequantize(
+            kv_unpack(gathered, _kv_bits(kv)),
+            kv_sc[layer, block_table], dtype=dt)
+    gathered = gathered.reshape(block_table.shape[0], -1, 2, cfg.kv_heads,
+                                cfg.head_dim)
+    return (gathered[:, :, 0].transpose(0, 2, 1, 3),
+            gathered[:, :, 1].transpose(0, 2, 1, 3))
+
+
 def _scan_layers(layer_body, x, params, kv_data, kv_scales):
     """Run ``layer_body((x, kv, kv_sc), (layer_params, l))`` over the
     layers with the pool as the scan's *carry*: one buffer, scattered
@@ -276,7 +292,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     """
     kv_data, kv_scales = _kv_parts(kv_data)
     T = token_ids.shape[0]
-    Smax, Bm = block_table.shape
+    Bm = block_table.shape[1]
     bs = kv_data.shape[2]
     dt = effective_dtype(cfg.dtype)
     is_real = jnp.arange(T) < num_tokens  # [T]
@@ -315,16 +331,9 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
             # *token*, [T, nkv, Lmax, hd]: the layout the contractions
             # below read, so the take is the only pass that writes a
             # token's context
-            gathered = kv[l, block_table]  # [S, Bm, bs, 2, nkv, hd(/2)]
-            if kv_sc is not None:
-                # dequant-on-read: only the gathered pages, never the pool
-                gathered = kv_dequantize(
-                    kv_unpack(gathered, _kv_bits(kv)),
-                    kv_sc[l, block_table], dtype=dt)
-            gathered = gathered.reshape(Smax, max_ctx, 2, cfg.kv_heads,
-                                        cfg.head_dim)
-            k_seq = gathered[:, :, 0].transpose(0, 2, 1, 3)[token_seq]
-            v_seq = gathered[:, :, 1].transpose(0, 2, 1, 3)[token_seq]
+            k_seq, v_seq = _gather_sequences(cfg, kv, kv_sc, l, block_table,
+                                             dt)
+            k_seq, v_seq = k_seq[token_seq], v_seq[token_seq]
         with jax.named_scope("attn"):
             # grouped-query attention per KV head (k), its query heads as
             # a group axis (g): g is 1 for a multi-head model, k is 1 for
@@ -412,6 +421,30 @@ def _paged_prefill(mesh, q, kv, layer, block_table, seg_pos0, ctx_lens):
                        block_table, seg_pos0, ctx_lens)
 
 
+def _segment_attention(cfg: TransformerConfig, q, kv, kv_sc, layer,
+                       block_table, pos):
+    """Causal attention of each segment's chunk over that segment's own
+    pages, as plain products: q [S, Tq, nh, hd] at positions ``pos``
+    [S, Tq] against the pool rows of ``block_table`` [S, Bm]. The context
+    is read once a *sequence* ([S, nkv, Lmax, hd], a quantized pool
+    dequantized on read) and contracted per KV head (k) with its query
+    heads as a group axis (g); no per-token context exists. Rows past a
+    token's position are masked, so whatever the pages hold beyond the
+    chunk's end is never read into a result."""
+    S, Tq = pos.shape
+    dt = q.dtype
+    with jax.named_scope("kv_gather"):
+        k_seq, v_seq = _gather_sequences(cfg, kv, kv_sc, layer, block_table,
+                                         dt)
+    qg = q.reshape(S, Tq, cfg.kv_heads, -1, cfg.head_dim)
+    scores = jnp.einsum("stkgd,skmd->stkgm", qg, k_seq.astype(dt))
+    key_pos = jnp.arange(k_seq.shape[2])
+    mask = key_pos <= pos[:, :, None, None, None]
+    probs = _attention_probs(cfg, scores, mask)
+    return jnp.einsum("stkgm,skmd->stkgd", probs,
+                      v_seq.astype(dt)).reshape(q.shape)
+
+
 def ragged_prefill_forward(cfg: TransformerConfig, params,
                            kv_data: jax.Array, seg_tokens: jax.Array,
                            seg_pos0: jax.Array, seg_nreal: jax.Array,
@@ -423,20 +456,23 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
     over new chunks + paged history). Each segment s runs ``nreal[s]``
     new tokens at absolute positions pos0[s].. through the paged cache;
     padded rows (qi >= nreal) and dead segments (nreal == 0) write to the
-    scratch page and emit garbage logits the engine never reads.
+    scratch page and emit garbage logits the engine never reads. The
+    attention is plain products over each segment's own pages
+    (:func:`_segment_attention`): on the chip the Pallas prefill kernel
+    was never the faster of the two here (PERF.md, PR 38).
 
     seg_tokens [S, Tq] int32; seg_pos0/seg_nreal [S]; block_table [S, Bm]
-    Returns (logits [S, Tq, V] fp32, kv_data').
+    (``mesh`` is the step programs' common keyword: the plain products
+    need no ``shard_map``.) Returns (logits [S, Tq, V] fp32, kv_data').
     """
     kv_data, kv_scales = _kv_parts(kv_data)
-    S, Tq = seg_tokens.shape
+    Tq = seg_tokens.shape[1]
     bs = kv_data.shape[2]
     dt = effective_dtype(cfg.dtype)
 
     qi = jnp.arange(Tq)[None, :]                      # [1, Tq]
     pos = seg_pos0[:, None] + qi                      # [S, Tq]
     real = qi < seg_nreal[:, None]                    # [S, Tq]
-    ctx_lens = seg_pos0 + seg_nreal                   # [S]
 
     x = vocab_parallel_lookup(
         params["embed"]["tokens"].astype(dt), seg_tokens)  # [S, Tq, H]
@@ -456,9 +492,8 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
             q, k, v = _qkv(cfg, layer_params, y, pos)  # q [S,Tq,nh,hd]
         kv, kv_sc = _kv_write(kv, kv_sc, l, page, offset, k, v)
         with jax.named_scope("attn"):
-            attn = _paged_prefill(mesh, q.astype(dt),
-                                  *_kv_dense(kv, kv_sc, l, dt),
-                                  block_table, seg_pos0, ctx_lens)
+            attn = _segment_attention(cfg, q.astype(dt), kv, kv_sc, l,
+                                      block_table, pos)
             attn = jnp.einsum("stnd,ndh->sth", attn.astype(dt),
                               layer_params["attn"]["wo"].astype(dt))
             if cfg.use_biases:

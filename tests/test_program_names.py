@@ -66,7 +66,7 @@ def test_serving_program_module_name(serve_lowered, program):
 
 @pytest.mark.parametrize("program,scopes", [
     ("gather", ["kv_write", "kv_gather", "attn", "mlp", "head"]),
-    ("prefill", ["kv_write", "attn", "mlp", "head"]),
+    ("prefill", ["kv_write", "attn/kv_gather", "attn", "mlp", "head"]),
     ("decode", ["kv_write", "attn", "mlp", "head"]),
     ("multi_decode", ["kv_write", "attn", "mlp", "head"]),
 ])
@@ -74,7 +74,9 @@ def test_serving_program_scopes(serve_lowered, program, scopes):
     text = serve_lowered[program].as_text(debug_info=True)
     for scope in scopes:
         assert re.search(rf'"[^"]*\b{scope}/[^"]*"', text), (program, scope)
-    if program != "gather":     # the [T, max_ctx, ...] gather is that path's
+    # the [T, max_ctx, ...] gather is the gather program's, a sequence's
+    # pages read once a chunk the prefill program's; the kernels read pages
+    if program not in ("gather", "prefill"):
         assert "kv_gather/" not in text
 
 

@@ -137,15 +137,16 @@ def _prompt(n, seed):
 
 
 def _mixed_run(engine):
-    """Gather, kernel-prefill, burst and single-decode steps, by hand:
+    """Split, prefill, burst and single-decode steps, by hand:
 
     A. three prompts of 20, 5, 5 tokens, 10 new tokens each. One step
-       takes all three chunks; padded to 4 slots x 32 it is over twice
-       the step's 32-token budget, so the gather program runs it: 3
-       tokens. A burst of 8 follows (24 tokens); with one token left to
-       each a burst does not pay, and one decode step emits 3.
+       takes all three chunks, split by program: padded to 4 slots x 32
+       they are over twice the step's 32-token budget, so two calls of
+       the prefill program take them (2 x 32, then 1 x 8), 3 tokens. A
+       burst of 8 follows (24 tokens); with one token left to each a
+       burst does not pay, and one decode step emits 3.
     B. one prompt of 40, 2 new tokens: chunks of 32 (no token) and 8 (1
-       token) through the prefill kernel, then one decode step (1).
+       token) through the prefill program, then one decode step (1).
     """
     out = {}
     engine.put([1, 2, 3], [_prompt(20, 1), _prompt(5, 2), _prompt(5, 3)],
@@ -156,9 +157,11 @@ def _mixed_run(engine):
     return out
 
 
-EXPECTED = {"tokens_gather": 3, "tokens_multi_decode": 24,
-            "tokens_decode": 3 + 1, "tokens_prefill_kernel": 1,
-            "prefill_chunks": 3 + 2, "first_tokens": 4, "admitted": 4}
+EXPECTED = {"tokens_gather": 0, "tokens_multi_decode": 24,
+            "tokens_decode": 3 + 1, "tokens_prefill_kernel": 3 + 1,
+            "prefill_chunks": 3 + 2, "first_tokens": 4, "admitted": 4,
+            "split_steps": 1, "prefill_chunk_calls": 2 + 2,
+            "prefill_kernel_steps": 0, "prefill_gather_fallbacks": 0}
 
 
 def test_counters_against_a_hand_counted_schedule(devices):
@@ -213,12 +216,14 @@ def test_serve_spans_and_request_trace_share_step_ids(devices, tmp_path):
         names = [k["name"] for k in kids]
         assert set(names) <= SERVE_CHILDREN
         assert names[0] == "admit" and names[-1] == "journal"
-        assert names.count("dispatch") == 1
-        d = kids[names.index("dispatch")]
-        programs.append(d["ids"]["program"])
-        assert d["ids"]["seqs"] >= 1 and d["ids"]["tokens"] >= 1
+        # one dispatch a program call, each behind its own build_batch
+        calls = [k for k in kids if k["name"] == "dispatch"]
+        assert len(calls) == names.count("build_batch") >= 1
+        programs.append("+".join(d["ids"]["program"] for d in calls))
+        assert all(d["ids"]["seqs"] >= 1 and d["ids"]["tokens"] >= 1
+                   for d in calls)
         assert names.index("build_batch") < names.index("dispatch")
-    assert programs == ["gather", "multi_decode", "decode",
+    assert programs == ["prefill+prefill", "multi_decode", "decode",
                         "prefill", "prefill", "decode"]
     puts = [s for s in spans if s["name"] == "put"]
     assert [(p["ids"]["uid"], p["ids"]["requests"]) for p in puts] == \
@@ -237,7 +242,7 @@ def test_serve_spans_and_request_trace_share_step_ids(devices, tmp_path):
                     ("DECODE_EMIT", ids[4]), ("DECODE_EMIT", ids[5])]
     one = [s.fields["step_id"] for s in traces[1].spans
            if s.kind == "DECODE_EMIT"]
-    assert one == [by_step["gather"], by_step["multi_decode"], ids[2]]
+    assert one == [ids[0], by_step["multi_decode"], ids[2]]
     engine.close()
 
 

@@ -349,7 +349,9 @@ def test_a_step_with_a_chunk_and_decoding_sequences_runs_no_gather(served):
     eng, _, _ = served
     st = eng.stats
     assert st["tokens_gather"] == 0 and st["prefill_gather_fallbacks"] == 0
-    assert st["tokens_prefill_kernel"] == 3 and st["prefill_kernel_steps"] >= 8
+    assert st["tokens_prefill_kernel"] == 3 and st["prefill_chunk_calls"] >= 8
+    # its chunk attention is the block rule's plain product, never the kernel
+    assert st["prefill_kernel_steps"] == 0 and st["split_steps"] >= 1
     assert st["tokens_decode"] > 0 and st["tokens_multi_decode"] > 0
     # the rule ran: it read fewer blocks than it saw, and the short
     # positions read everything

@@ -1,0 +1,298 @@
+"""A dense model's step, split by program, against the gather program.
+
+On the kernel path ``InferenceEngineV2._split_by_program`` sends the rows
+that advance one token through the decode program and the chunks through
+the prefill program over their own sequences' pages (plain products a
+segment, ``model_runner._segment_attention``), several chunks a call while
+the call's padded layout stays within twice the step's budget. Here, on
+the CPU with an interpreter decode kernel, the same schedule runs both ways
+(``_use_paged_kernel`` flipped, as ``tests/test_kv_in_place.py`` does): the
+same tokens come out, and the logits rows they were picked from agree.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.runners.serve import LogitsTap
+from deepspeed_tpu.inference import engine_v2
+from deepspeed_tpu.models.zoo import get_model
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+MODELS = {
+    # multi-head, learned positions, LayerNorm, GELU (the preset as it is)
+    "mha": {},
+    # grouped queries, rotary, RMSNorm, SwiGLU, untied head (Mistral's form)
+    "gqa": dict(num_kv_heads=2, pos_emb="rope", norm="rmsnorm",
+                activation="swiglu", tie_embeddings=False),
+    # one KV head, biases, Falcon's parallel block
+    "mqa": dict(num_kv_heads=1, pos_emb="rope", use_biases=True,
+                parallel_block=True),
+}
+
+
+def _model(name, dtype=F32, seed=3):
+    model = get_model("tiny", dtype=dtype, param_dtype=dtype, **MODELS[name])
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
+def _engine(model, params, kernel, dtype=F32, **kw):
+    args = dict(kv_blocks=96, kv_block_size=8, max_tokens_per_step=32,
+                max_seqs_per_step=4, max_blocks_per_seq=8, decode_steps=1,
+                prefix_cache=False, dtype=dtype)
+    args.update(kw)
+    eng = engine_v2.InferenceEngineV2(model, params=params, **args)
+    eng._use_paged_kernel = kernel
+    return eng
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(1, 250, n).astype(np.int32)
+
+
+class Drive:
+    """One engine under a script; per step what was scheduled ((uid, new
+    tokens, start) a row), the tokens emitted, and the logits row behind
+    each."""
+
+    def __init__(self, eng):
+        self.eng, self.log = eng, []
+
+    def put(self, uids, prompts, max_new):
+        self.eng.put(list(uids), list(prompts), max_new_tokens=max_new)
+
+    def step(self, n=1):
+        for _ in range(n):
+            with LogitsTap(self.eng) as tap:
+                schedule = self.eng.scheduler.schedule
+
+                def scheduled():
+                    out = schedule()
+                    self._rows = [(s.uid, len(nt), sp) for s, nt, sp in out]
+                    return out
+
+                self.eng.scheduler.schedule = scheduled
+                emitted = self.eng.step()
+            rows = {uid: tap.rows[-1][tap.slots[-1].index(uid)]
+                    for uid in emitted}
+            self.log.append((self._rows, emitted, rows))
+
+    def finish(self, limit=64):
+        while self.eng.state.seqs or self.eng._queue:
+            self.step()
+            limit -= 1
+            assert limit > 0, "the engine does not finish"
+
+
+def _same(split: Drive, gather: Drive, atol, rel=None, rows_at_least=0):
+    """Step by step: one schedule, the same tokens, the rows they were
+    picked from equal to ``atol`` (float32) or within ``rel`` of the row's
+    norm (bf16). In bf16 the two programs round in another order and may
+    pick another token at a near-tie: the histories part there, so the
+    comparison ends, and ``rows_at_least`` rows must have been compared."""
+    compared = 0
+    for (rows_s, out_s, lg_s), (rows_g, out_g, lg_g) in zip(split.log,
+                                                           gather.log):
+        assert rows_s == rows_g                 # one schedule
+        for uid in out_g:
+            a, b = lg_s[uid], lg_g[uid]
+            if rel is None:
+                np.testing.assert_allclose(a, b, atol=atol, rtol=atol)
+            else:
+                bound = rel * np.linalg.norm(b)
+                assert np.linalg.norm(a - b) <= bound, uid
+                assert b.max() - b[out_s[uid]] <= bound, uid    # a near-tie
+            compared += 1
+        if rel is not None and out_s != out_g:
+            break
+        assert out_s == out_g
+    else:
+        assert len(split.log) == len(gather.log)
+    assert compared >= rows_at_least, compared
+    assert split.eng.stats["tokens_gather"] == 0
+    assert gather.eng.stats["tokens_gather"] > 0
+    split.eng.close(), gather.eng.close()
+
+
+def _chunk_script(d: Drive):
+    """Two and four chunks a step, three that do not pad into one call, a
+    chunk over a page border, one that ends its prompt and one that does
+    not, a chunk behind a prefix hit, and a dead slot between live ones.
+    Pages of 8, 32 tokens and 4 sequences a step (so a call's padded
+    layout may hold 64 rows)."""
+    # two chunks: 13 tokens cross the border between pages 0 and 1
+    d.put([1, 2], [_prompt(13, 1), _prompt(5, 2)], 3)
+    d.step(2)
+    # two token rows and two chunks, then four sequences in decode
+    d.put([3, 4], [_prompt(9, 3), _prompt(6, 4)], 4)
+    d.finish()
+    # four chunks in one step
+    d.put([5, 6, 7, 8], [_prompt(9, 5), _prompt(3, 6), _prompt(9, 7),
+                         _prompt(6, 8)], 2)
+    d.step()
+    assert sorted(n for _, n, _ in d.log[-1][0]) == [3, 6, 9, 9]
+    d.finish()
+    # three chunks, two calls: 20 and 9 pad to 2 x 32 rows, a third
+    # sequence would make that 4 x 32
+    d.put([30, 31, 32], [_prompt(20, 30), _prompt(9, 31), _prompt(3, 32)], 2)
+    d.step()
+    assert [n for _, n, _ in d.log[-1][0]] == [20, 9, 3]
+    d.finish()
+    # a prompt over the step's budget: 32 that do not end it, 13 that do
+    d.put([9], [_prompt(45, 9)], 2)
+    d.step(2)
+    assert [r[0] for r in d.log[-2:]] == [[(9, 32, 0)], [(9, 13, 32)]]
+    assert not d.log[-2][1] and list(d.log[-1][1]) == [9]
+    d.finish()
+    # behind a prefix hit: the second prompt shares two whole pages
+    if d.eng.kv_cache.prefix_cache is not None:
+        doc = _prompt(16, 10)
+        d.put([10], [np.concatenate([doc, _prompt(7, 11)])], 2)
+        d.finish()
+        d.put([11], [np.concatenate([doc, _prompt(5, 12)])], 2)
+        d.step()
+        assert d.log[-1][0] == [(11, 5, 16)]
+        d.finish()
+    # a dead slot between live ones: the middle sequence ends first, a
+    # prompt arrives, and the step holds two token rows and a chunk
+    d.put([20], [_prompt(4, 20)], 8)
+    d.put([21], [_prompt(4, 21)], 2)
+    d.put([22], [_prompt(4, 22)], 8)
+    d.step(3)
+    assert 21 not in d.eng.state.seqs
+    d.put([23], [_prompt(11, 23)], 2)
+    d.step()
+    assert d.log[-1][0] == [(20, 1, 6), (22, 1, 6), (23, 11, 0)]
+    d.finish()
+
+
+def _both(model, params, script, **kw):
+    split, gather = (Drive(_engine(model, params, kernel, **kw))
+                     for kernel in (True, False))
+    script(split), script(gather)
+    return split, gather
+
+
+@pytest.mark.parametrize("name", ["mha", "gqa", "mqa"])
+def test_split_step_matches_the_gather_program(name):
+    model, params = _model(name)
+    split, gather = _both(model, params, _chunk_script, prefix_cache=True)
+    st = split.eng.stats
+    assert st["prefix_hit_tokens"] == 16 == gather.eng.stats[
+        "prefix_hit_tokens"]
+    # every step's chunks were one call of the prefill program, but for
+    # the three that do not pad into one: two
+    steps_with_chunk = sum(any(n > 1 for _, n, _ in rows)
+                           for rows, _, _ in split.log)
+    assert steps_with_chunk == 10
+    assert st["prefill_chunk_calls"] == steps_with_chunk + 1
+    assert st["prefill_gather_fallbacks"] == 0 == st["prefill_kernel_steps"]
+    # more than one call: token rows beside a chunk (twice), and those two
+    assert st["split_steps"] == 3 == 1 + sum(
+        len({n == 1 for _, n, _ in rows}) == 2 for rows, _, _ in split.log)
+    assert gather.eng.stats["split_steps"] == 0 == gather.eng.stats[
+        "prefill_chunk_calls"]
+    _same(split, gather, 1e-5)
+
+
+@pytest.mark.parametrize("lens,calls", [
+    # one call while S x tq (each bucketed to a power of two, tq from 8)
+    # stays within 2 x 32 rows
+    ([9, 3, 9, 6], [[0, 1, 2, 3]]),               # 4 x 16
+    ([20, 9, 3], [[0, 1], [2]]),                  # 2 x 32, not 4 x 32
+    ([17, 2, 2, 2], [[0, 1], [2, 3]]),
+    ([30, 30], [[0, 1]]),
+    # token rows first, all of them one call of the decode program
+    ([1, 20, 1, 9], [[0, 2], [1, 3]]),
+    ([1, 1, 1], [[0, 1, 2]]),
+])
+def test_chunks_share_a_call_while_its_padding_stays_in_budget(lens, calls):
+    model, params = _model("mha")
+    scheduled = [(None, [0] * n, 0) for n in lens]
+    eng = _engine(model, params, True)
+    assert eng._split_by_program(scheduled) == calls
+    eng._use_paged_kernel = False           # the gather program: one part
+    assert eng._split_by_program(scheduled) == [list(range(len(lens)))]
+    eng.close()
+
+
+@pytest.mark.parametrize("chunks", [[20], [5, 6, 9]])
+def test_token_rows_beside_chunks(chunks):
+    """31 (29) sequences in decode and one prompt (three) arriving: one
+    call of the decode program, one of the prefill program."""
+    model, params = _model("gqa")
+    rows_before = 32 - len(chunks)
+
+    def script(d):
+        d.put(range(rows_before), [_prompt(1, i) for i in range(rows_before)],
+              6)
+        d.step(2)
+        d.put(range(90, 90 + len(chunks)),
+              [_prompt(n, 90 + i) for i, n in enumerate(chunks)], 3)
+        d.step()
+        rows = d.log[-1][0]
+        assert sorted(n for _, n, _ in rows) == [1] * rows_before + sorted(
+            chunks)
+        assert len(d.log[-1][1]) == 32            # every row emits
+        d.finish()
+
+    split, gather = _both(model, params, script, max_seqs_per_step=32,
+                          max_tokens_per_step=64, kv_blocks=160)
+    st = split.eng.stats
+    assert (st["split_steps"], st["prefill_chunk_calls"]) == (1, 1)
+    assert st["tokens_prefill_kernel"] == len(chunks)
+    assert st["tokens_decode"] == rows_before * 6 + len(chunks) * 2
+    _same(split, gather, 1e-5)
+
+
+@pytest.mark.parametrize("pool", ["int8", "bf16"])
+def test_split_step_on_an_int8_pool_and_in_bf16(pool):
+    """An int8 pool is dequantized on read by both programs, from the same
+    payload: float32 agreement. In bf16 the two programs round in another
+    order: the bf16 bound (2 % of the row's norm)."""
+    if pool == "int8":
+        model, params = _model("gqa")
+        split, gather = _both(model, params, _chunk_script, kv_quant_bits=8)
+        _same(split, gather, 1e-5)
+    else:
+        # a draw at which no pick of the script is a near-tie (of eight
+        # tried, three: a toy model's logits are flat)
+        model, params = _model("gqa", BF16, seed=0)
+        split, gather = _both(model, params, _chunk_script, dtype=BF16)
+        _same(split, gather, None, rel=2e-2, rows_at_least=44)
+
+
+@pytest.mark.parametrize("name", ["gqa", "mha"])
+def test_split_step_under_tp2(name, devices):
+    from deepspeed_tpu.parallel import topology as topo
+    from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
+
+    model, params = _model(name)
+    runs = []
+    for kernel in (True, False):
+        topo._GLOBAL_MESH = None
+        mesh = build_mesh(TopologyConfig(dp=1, tp=2), devices=devices[:2])
+        d = Drive(_engine(model, params, kernel, mesh=mesh))
+        assert d.eng._tp == 2
+        _chunk_script(d)
+        runs.append(d)
+    _same(*runs, 1e-5)
+
+
+def test_speculation_still_verifies_through_the_gather_program():
+    model, params = _model("mha")
+    prompt = np.tile(np.arange(6, dtype=np.int32), 4)   # lookup finds drafts
+    spec = _engine(model, params, True, spec_decode=True, spec_k=3,
+                   decode_steps=8)
+    plain = _engine(model, params, True, decode_steps=8)
+    for eng in (spec, plain):
+        eng.put([1], [prompt], max_new_tokens=12)
+    assert spec.generate_all() == plain.generate_all()
+    st = spec.stats
+    assert st["spec_steps"] > 0 and st["tokens_gather"] > 0
+    assert plain.stats["tokens_gather"] == 0
+    # the prompt itself went through the prefill program in both
+    assert st["prefill_chunk_calls"] == plain.stats["prefill_chunk_calls"] == 1
+    spec.close(), plain.close()

@@ -287,6 +287,88 @@ def test_gather_program_reads_each_kv_head_once(chip):
         assert "[" + ",".join(map(str, shape)) + "]" not in text, shape
 
 
+@pytest.mark.parametrize("segs,tq", [(1, TOKENS), (2, TOKENS),
+                                     (8, TOKENS // 4), (32, TOKENS // 16)])
+def test_chunk_program_reads_a_sequence_context_once(chip, segs, tq):
+    """The prefill program (``jit_dstpu_serve_prefill``) at the same shapes,
+    one sequence's chunk of 256 rows a call, and two, eight of 64 and 32 of
+    16 (padded layouts a call may have: ``S x tq <= 2 x max_tokens``): the
+    attention is the plain product over each sequence's own 64 pages, no
+    kernel. The pool is handed back in its buffer; no context a *token*
+    exists in the text (``[256,8,1024,128]``, with or without the segment
+    axis, in either order of heads and context); and the temporaries stay
+    under 256 MiB (a scratch compile of one chunk read 0.5 MiB beside the
+    31 MiB of float32 logits that the program hands out)."""
+    fns, params, kv, ids = _serve_c1_two_layers(chip)
+    compiled = fns["prefill"].lower(
+        params, kv, ids(segs, tq), ids(segs), ids(segs),
+        ids(segs, PAGES)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * kv.size
+    assert mem.temp_size_in_bytes < 2**28
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    ctx, (nkv, hd) = PAGES * kv.shape[2], kv.shape[-2:]
+    for shape in ((nkv, ctx, hd), (ctx, nkv, hd)):
+        for lead in ((segs * tq,), (segs, tq)):
+            assert "[" + ",".join(map(str, lead + shape)) + "]" not in text
+
+
+def test_warm_up_leaves_a_window_of_mixed_steps_nothing_to_compile(
+        monkeypatch):
+    """A rehearsal on the CPU, interpreter kernels, the tiny fixture
+    configuration: after the serving runner's ``warm_up`` a window in which
+    prompts of every chunk bucket arrive among decoding sequences, alone
+    and several a step, compiles nothing (the benchmark's own listener
+    counts), and those steps were split by program."""
+    monkeypatch.undo()          # this one runs: not the chip's kernels
+    import numpy as np
+
+    from benchmarks.generators.requests import Request, Served
+    from benchmarks.harness import compiles
+    from benchmarks.harness import manifest as mf
+    from benchmarks.runners import serve
+
+    _, bench_dir, _, cfg, _ = mf.resolve(
+        os.path.join(os.path.dirname(__file__), "benchmarks", "fixtures",
+                     "BENCHMARK.tiny.json"), "tiny-gen")
+    arch = mf.reference_of(cfg, bench_dir).Arch.from_model(cfg)
+    engine, _ = serve.build_engine(cfg, arch, 2147480011)
+    served = Served(engine)
+    compiles.install()
+    serve.warm_up(served, cfg, arch.vocab_size)
+    before, stats0 = compiles.count(), dict(engine.stats)
+    assert before > 0                       # the listener hears this process
+
+    rng = np.random.default_rng(5)
+    rid = iter(range(1, 1000))
+
+    def put(n, max_new):
+        served.put(Request(next(rid), rng.integers(0, arch.vocab_size, n)
+                           .astype(np.int32), max_new))
+
+    for _ in range(4):                      # decoding sequences, long answers
+        put(5, 120)
+    # (33, 9, 9 and 20, 3, 3, 3, 3 do not pad into one call: two each)
+    for arrivals in ([3], [9], [17], [33], [60], [40, 9], [12, 12, 12, 12],
+                     [64], [100], [1], [2, 30], [33, 9, 9], [20, 3, 3, 3, 3]):
+        for n in arrivals:
+            put(n, 3)
+        for _ in range(3):
+            served.step()
+    while served.outstanding:
+        served.step()
+    seen = compiles.count() - before
+    assert seen == 0, compiles.SEEN[-seen:]
+    new = {k: engine.stats[k] - stats0[k] for k in (
+        "split_steps", "prefill_chunk_calls", "tokens_gather",
+        "tokens_multi_decode", "prefill_gather_fallbacks")}
+    assert new["split_steps"] >= 8 and new["prefill_chunk_calls"] >= 14
+    assert new["tokens_gather"] == 0 == new["prefill_gather_fallbacks"]
+    assert new["tokens_multi_decode"] > 0
+    engine.close()
+
+
 def test_grouped_matmul_fwd_bwd(chip):
     """Mixtral-width expert matmul: M=8192 rows over E=8 experts."""
     M, K, N, E = 8192, 4096, 14336, 8
