@@ -40,7 +40,7 @@ def sample(traffic: Dict, seed: int, vocab: int, n: int):
 
 
 def drive(served: Served, traffic: Dict, seed: int, vocab: int,
-          seconds: float, on_window_open=None, while_open=None) -> Dict:
+          seconds: float, on_window_open=None) -> Dict:
     """Returns the window ``[0, seconds]`` on the served clock (rebased
     when the window opens), exactly: the ``serve_step`` that straddles the
     close counts for the share of its duration inside
@@ -69,6 +69,4 @@ def drive(served: Served, traffic: Dict, seed: int, vocab: int,
         on_window_open()
     while served.now() < seconds:
         pump()
-        if while_open:
-            while_open()
     return {"t0": 0.0, "t1": float(seconds), "t_end": served.now()}

@@ -1,7 +1,10 @@
 """Open loop with bursts: requests are sent on a schedule whether or not
 earlier ones have finished. A request is one document, a question and an
 answer; the requests of a burst are about one document and arrive within
-``burst_within_s``; half of the bursts ask about one of a few *hot*
+``burst_within_s`` (0: at one instant, all of them put before the engine's
+next step), no burst sooner than ``min_gap_s`` after the one before it
+(so each finds the engine idle, where the mix's rate allows);
+``hot_burst_share`` of the bursts ask about one of a few *hot*
 documents (in the prefix cache since set-up), the others each about a
 *fresh* document that nothing has seen.
 
@@ -36,6 +39,9 @@ def _skeleton(traffic: Dict, seconds: float) -> List[Dict]:
                          * traffic.get("arrival_span", 1.0))))
     fixed = np.random.default_rng(_TIMELINE)
     gaps = stats.exponential_gaps(1.0 / traffic["bursts_per_s"], n)
+    if traffic.get("min_gap_s"):
+        # a burst never arrives at a working engine (PERF.md section 4)
+        gaps = stats.floored_gaps(gaps, traffic["min_gap_s"])
     gaps = [gaps[i] for i in fixed.permutation(n)]
     sizes = traffic["burst_sizes"]
     sizes = [sizes[i % len(sizes)] for i in range(n)]
@@ -129,11 +135,7 @@ def prewarm(served: Served, traffic: Dict, seed: int, vocab: int) -> None:
 
 
 def drive(served: Served, traffic: Dict, seed: int, vocab: int,
-          seconds: float, on_window_open=None, while_open=None,
-          salt: int = 0) -> Dict:
-    """``while_open()`` runs between steps and before every sleep; it may
-    return a time (on the served clock) at which it wants to run again,
-    and an idle generator then sleeps no longer than that."""
+          seconds: float, on_window_open=None, salt: int = 0) -> Dict:
     reqs = plan(traffic, seed, vocab, seconds, salt)
     served.rebase()
     if on_window_open:
@@ -141,7 +143,6 @@ def drive(served: Served, traffic: Dict, seed: int, vocab: int,
     lag, i = [], 0
     sched = {r.rid: r.scheduled for r in reqs}
     while True:
-        wake = while_open() if while_open else None
         now = served.now()
         if now >= seconds:
             break
@@ -151,7 +152,7 @@ def drive(served: Served, traffic: Dict, seed: int, vocab: int,
             i += 1
         if served.outstanding == 0:
             nxt = min(reqs[i].scheduled if i < len(reqs) else seconds,
-                      seconds, wake if wake is not None else seconds)
+                      seconds)
             with trace.span("generator_sleep"):
                 time.sleep(max(0.0, nxt - served.now()))
             continue
@@ -162,4 +163,5 @@ def drive(served: Served, traffic: Dict, seed: int, vocab: int,
             # due in the window and never put: the engine sat in one step
             # from before their arrival to the close
             "unsent": [r for r in reqs[i:] if r.scheduled < t1],
-            "tags": {r.rid: r.tag for r in reqs}}
+            "tags": {r.rid: r.tag for r in reqs},
+            "groups": {r.rid: r.group for r in reqs}}

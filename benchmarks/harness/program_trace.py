@@ -35,6 +35,7 @@ from benchmarks.harness import trace as T
 
 SPAN_PREFIX = "dstpu/"
 TRAIN_STEP, SERVE_GATHER = "jit_dstpu_train_step", "jit_dstpu_serve_gather"
+SERVE_PREFILL = "jit_dstpu_serve_prefill"
 
 
 class Span(NamedTuple):

@@ -141,6 +141,29 @@ def exponential_gaps(mean: float, n: int) -> List[float]:
     return [mean * k * r for r in raw]
 
 
+def floored_gaps(gaps: Sequence[float], floor: float) -> List[float]:
+    """``gaps`` with none under ``floor``, their count and their sum as
+    they were: every shorter gap is raised to the floor, and what that
+    adds is taken off the longest gaps, which are lowered to one common
+    ceiling. The order of the gaps is kept."""
+    total, n = sum(gaps), len(gaps)
+    if floor * n > total:
+        raise ValueError(f"{n} gaps of at least {floor} s do not fit into "
+                         f"{total} s")
+    out = [max(g, floor) for g in gaps]
+    excess = sum(out) - total
+    # lower the longest to the ceiling c with sum(max(0, g - c)) = excess
+    desc = sorted(out, reverse=True)
+    taken, c = 0.0, desc[0]
+    for k, g in enumerate(desc[1:] + [floor], start=1):
+        step = (c - g) * k
+        if taken + step >= excess:
+            c -= (excess - taken) / k
+            break
+        taken, c = taken + step, g
+    return [min(g, c) for g in out]
+
+
 def spread(values: Sequence[float]) -> float:
     """The contract's spread: interquartile distance over the median, by
     ``statistics.quantiles(values, n=4)``."""

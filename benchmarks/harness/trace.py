@@ -215,6 +215,19 @@ class Trace:
     def window_s(self) -> float:
         return self.t1 - self.t0
 
+    def between(self, t0: float, t1: float) -> "Trace":
+        """The window ``[t0, t1]`` alone: the device events that lie whole
+        inside it and the host spans that reach into it. (A capture opened
+        from a thread begins and ends inside a step; its reader cuts it
+        back to the steps it holds whole.)"""
+        def inside(events):
+            return [e for e in events if e[1] >= t0 and e[1] + e[2] <= t1]
+
+        return Trace({c: inside(ev) for c, ev in self.device_ops.items()},
+                     [s for s in self.host_spans
+                      if s[1] < t1 and s[1] + s[2] > t0], t0, t1,
+                     {c: inside(ev) for c, ev in self.modules.items()})
+
     def busy_s(self) -> float:
         """Mean over the chips used."""
         per = [busy_seconds(ev, self.t0, self.t1)
@@ -279,10 +292,14 @@ def read_xplane(path: str) -> Trace:
 class Capture:
     """``with Capture(dir) as c: ...`` traces the block; ``c.trace`` is the
     reduced-ready Trace afterwards. The Python tracer is off: it slows the
-    host that the serving engine runs on."""
+    host that the serving engine runs on. Stopping collects and writes the
+    profile, seconds of work mostly outside the interpreter's lock, and
+    reading the file back holds the lock for seconds more: a caller that
+    opens the capture from a thread beside a loop it must not hold passes
+    ``read_on_exit=False`` and calls ``read()`` when the loop has ended."""
 
-    def __init__(self, out_dir: str):
-        self.out_dir = out_dir
+    def __init__(self, out_dir: str, read_on_exit: bool = True):
+        self.out_dir, self.read_on_exit = out_dir, read_on_exit
         self.trace: Optional[Trace] = None
         self.wall_s = 0.0
 
@@ -306,9 +323,13 @@ class Capture:
         self._window.__exit__(None, None, None)
         self.wall_s = time.perf_counter() - self._t
         jax.profiler.stop_trace()
-        if exc[0] is None:
-            files = glob.glob(os.path.join(self.out_dir, "plugins", "profile",
-                                           "*", "*.xplane.pb"))
-            if files:
-                self.trace = read_xplane(max(files, key=os.path.getmtime))
+        if exc[0] is None and self.read_on_exit:
+            self.read()
         return False
+
+    def read(self) -> Optional[Trace]:
+        files = glob.glob(os.path.join(self.out_dir, "plugins", "profile",
+                                       "*", "*.xplane.pb"))
+        if files:
+            self.trace = read_xplane(max(files, key=os.path.getmtime))
+        return self.trace

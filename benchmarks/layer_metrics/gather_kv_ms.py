@@ -5,8 +5,9 @@ every sequence taken from the pool, a quantized pool's dequantisation,
 and one row of the whole context per *token*), summed over the layers,
 over the program's executions in the traced window. It is the part of
 ``gather_step_ms`` that a step layout which reads the context per
-sequence (ROADMAP S8 b+c) would remove. The dotted names (``.gen``,
-``.burst``) are this reader; a program without the scope reads nothing."""
+sequence (ROADMAP S8 b+c) would remove, and did for the dense models in
+PR 38. The dotted name (``.gen``) is this reader; a program without the
+scope reads nothing."""
 
 from benchmarks.harness import program_trace as P
 from benchmarks.layer_metrics.gdn_decode_ms import scope_seconds

@@ -1,10 +1,9 @@
 """Mean device time of one execution of the gather program
-(``jit_dstpu_serve_gather``, today's mixed prefill step) in the traced
-window, by the program's name on the device's module line. The dotted
-name (``.burst``) is this reader: a cell that reports another end-to-end
-metric needs a name of its own (``serve-gen-closed`` would, but its traced
-slice, the window's first 4 s, holds decode bursts only: PERF.md section
-7)."""
+(``jit_dstpu_serve_gather``: the mixed prefill step of a model whose
+runner has no split by program, the hybrid runner's) in the traced window,
+by the program's name on the device's module line. The dotted name
+(``.gen``) is this reader. Since PR 38 no dense cell runs the program; a
+trace without it reads nothing."""
 
 from benchmarks.harness import program_trace as P
 

@@ -12,6 +12,7 @@ window; nothing may compile inside it.
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Dict, List
 
@@ -270,6 +271,21 @@ def where_the_close_fell(steps, t0: float, t1: float) -> Dict:
             "outside_serve_step_s": (t1 - t0) - inside}
 
 
+def slice_of_whole_steps(steps: List[Dict], lo: float, hi: float):
+    """A capture opened and closed from a thread begins and ends wherever
+    the generator's loop then is. A step that an end of ``[lo, hi]`` falls
+    into is left out and the slice cut back to that step's border, so that
+    the trace holds the work of whole steps, which the readers divide it
+    by; an end that falls between steps stays where it fell. Returns the
+    slice and the range of the steps inside it."""
+    lo = max([lo] + [s["t"] + s["dt"] for s in steps
+                     if s["t"] < lo < s["t"] + s["dt"]])
+    hi = min([hi] + [s["t"] for s in steps if s["t"] < hi < s["t"] + s["dt"]])
+    inside = [i for i, s in enumerate(steps)
+              if lo <= s["t"] and s["t"] + s["dt"] <= hi]
+    return lo, hi, (inside[0], inside[-1] + 1) if inside else (0, 0)
+
+
 def run(ctx) -> Dict:
     cfg, traffic = ctx.config, ctx.traffic
     ref = manifest.reference_of(cfg, ctx.bench_dir)
@@ -293,7 +309,7 @@ def run(ctx) -> Dict:
               "traffic": gen.describe(traffic, ctx.seconds)})
 
     counters0 = {}
-    cap = {"c": None, "open": False, "steps": (0, 0)}
+    cap = {"trace": None, "steps": (0, 0)}
     # the traced slice of the window, on the served clock: the mix's own
     # (``trace_window_s``, for a timeline whose start is not typical of
     # it), or the first ``trace_seconds``
@@ -312,30 +328,44 @@ def run(ctx) -> Dict:
         counters0.update(snapshot())
         ctx.compiles0 = compiles.count()
         ctx.mark_setup_done()
-        while_open()
+        if ctx.trace:
+            cap["thread"] = threading.Thread(target=capture_slice,
+                                             daemon=True)
+            cap["thread"].start()
 
-    def while_open():
-        """Opens and closes the capture; returns when it next has to."""
-        if not ctx.trace or (cap["c"] and not cap["open"]):
-            return None
-        if not cap["open"]:
-            if served.now() < t_from:
-                return t_from
-            cap["c"] = trace.Capture(ctx.trace_dir)
-            cap["c"].__enter__()
-            cap["open"], cap["steps"] = True, (len(served.steps), 0)
-        if served.now() < t_to:
-            return t_to
-        cap["c"].__exit__(None, None, None)
-        cap["open"] = False
-        cap["steps"] = (cap["steps"][0], len(served.steps))
-        return None
+    def capture_slice():
+        """The slice is opened and closed from a thread beside the
+        generator's loop: stopping the profiler takes 13 s on the chip
+        (starting, 0.05 s) and reading the file back seconds more, and a
+        loop that waited for them put the requests due meanwhile 2-3 s
+        late (``generator_lag_p90_ms`` 2,751-2,970 under the profiler,
+        ledger, PR 38 and 39; 0.6 from the thread, PERF.md section 6)."""
+        try:
+            time.sleep(max(0.0, t_from - served.now()))
+            c = trace.Capture(ctx.trace_dir, read_on_exit=False)
+            c.__enter__()
+            cap["at"] = [served.now(), None]
+            time.sleep(max(0.0, t_to - served.now()))
+            cap["at"][1] = served.now()
+            c.__exit__(None, None, None)
+            cap["c"], cap["stop_s"] = c, served.now() - cap["at"][1]
+        except BaseException as e:        # raised again where it is joined
+            cap["error"] = e
 
     window = gen.drive(served, traffic, ctx.seed, arch.vocab_size,
-                       ctx.seconds, on_open, while_open)
-    if cap["open"]:
-        cap["c"].__exit__(None, None, None)
-        cap["steps"] = (cap["steps"][0], len(served.steps))
+                       ctx.seconds, on_open)
+    if "thread" in cap:
+        cap["thread"].join()
+        if "error" in cap:
+            raise cap["error"]
+        whole = cap["c"].read()
+        lo, hi, cap["steps"] = slice_of_whole_steps(served.steps, *cap["at"])
+        if whole is not None:
+            on_trace_clock = whole.t0 - cap["at"][0]
+            cap["trace"] = whole.between(lo + on_trace_clock,
+                                         hi + on_trace_clock)
+        ctx.note({"traced_slice_s": [lo, hi], "profiler_open_s": cap["at"],
+                  "profiler_stop_s": cap["stop_s"]})
     in_window = compiles.count() - ctx.compiles0
     ctx.verdict.require("no_compile_in_window", in_window == 0,
                         f"{in_window} compilations inside the window: "
@@ -364,6 +394,20 @@ def run(ctx) -> Dict:
         attempted, late, failed = open_loop_outcome(served, window,
                                                     ctx.seconds)
         samples.update(ttft=len(tt), unfinished_at_close=late)
+        # in timeline order: [request, burst, due, put after due, first
+        # token after due, last token after due], so a run says which
+        # request moved and how long a burst kept the engine (PERF.md 2)
+        lag = dict(zip(window["scheduled"], window["generator_lag_s"]))
+        groups = window.get("groups", {})
+        due_in = [(r, d) for r, d in window["scheduled"].items()
+                  if t0 <= d < t1]                   # ``stats.ttfts``'s order
+        samples["ttft_by_request_ms"] = [
+            [rid, groups.get(rid), round(1e3 * due, 1),
+             round(1e3 * lag[rid], 2) if rid in lag else None,
+             round(1e3 * x, 2),
+             round(1e3 * (served.done_at[rid] - due), 1)
+             if rid in served.done_at else None]
+            for (rid, due), x in zip(due_in, tt)]
         if tt:
             e2e["ttft_p50_ms"] = 1e3 * stats.percentile(tt, 50)
             e2e["ttft_p90_ms"] = 1e3 * stats.percentile(tt, 90)
@@ -375,7 +419,7 @@ def run(ctx) -> Dict:
               "serve_step_share": busy_s / span,
               "end_to_end": e2e, "counters": delta})
     return {"attempted": attempted, "failed": failed,
-            "end_to_end": e2e, "trace": cap["c"].trace if cap["c"] else None,
+            "end_to_end": e2e, "trace": cap["trace"],
             "counters": delta, "served": served, "window": window,
             "facts": {"arch": arch, "traced_steps": cap["steps"],
                       "traffic": traffic, "samples": samples}}

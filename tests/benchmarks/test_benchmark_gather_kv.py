@@ -56,8 +56,7 @@ def trace(scopes):
                           scopes)
 
 
-@pytest.mark.parametrize("metric", ["gather_kv_ms", "gather_kv_ms.gen",
-                                    "gather_kv_ms.burst"])
+@pytest.mark.parametrize("metric", ["gather_kv_ms", "gather_kv_ms.gen"])
 def test_gather_kv_reads_the_scope_per_execution(monkeypatch, metric):
     reader = mf.load_module("layer_metrics", metric)
     monkeypatch.setattr(P, "open_run",
@@ -79,14 +78,24 @@ def test_gather_kv_reads_nothing_from_a_program_without_the_scope(
     assert reader.read(Ctx(), {}) is None
 
 
-def test_the_manifest_lists_gather_kv_in_the_cells_that_run_the_program():
+GONE = ["gather_step_ms.burst", "gather_kv_ms.burst", "prefill_kernel_share"]
+
+
+@pytest.mark.parametrize("name", ["gather_kv_ms.gen", "gather_step_ms.gen",
+                                  "gather_token_share"])
+def test_the_manifest_lists_the_gather_metrics_in_the_cell_that_runs_the_program(
+        name):
+    """Since PR 38 no step of a dense model runs the gather program: its
+    metrics list the one cell whose runner still does, and the entries
+    that had no such cell left (PR 40) are gone with their readers."""
     manifest = mf.load_manifest()
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    moved = {"gather_kv_ms.gen": "serve_tokens_per_s",
-             "gather_kv_ms.burst": "ttft_p50_ms"}
-    for name, metric in moved.items():
-        entry, step = by_name[name], by_name[name.replace("_kv_", "_step_")]
-        assert entry["moves"] == metric and entry["layer"] == step["layer"]
-        assert entry["workloads"] == step["workloads"]
-        assert os.path.isfile(os.path.join(mf.BENCH_DIR, "layer_metrics",
-                                           name + ".py"))
+    entry = by_name[name]
+    assert entry["layer"] == "serve step programs"
+    assert entry["workloads"] == ["serve-qnext-gen-closed"]
+    assert os.path.isfile(os.path.join(mf.BENCH_DIR, "layer_metrics",
+                                       name + ".py"))
+    for gone in GONE:
+        assert gone not in by_name
+        assert not os.path.exists(os.path.join(mf.BENCH_DIR, "layer_metrics",
+                                               gone + ".py"))

@@ -192,6 +192,46 @@ def test_no_runner_or_harness_file_imports_an_architecture(rel):
         assert word not in code, (rel, word)
 
 
+def test_no_metric_lists_a_reader_that_is_gone_and_no_reader_is_left_over(man):
+    """A removal takes the entry and the reader file together (PR 40: the
+    three metrics PR 38 silenced): every per-layer entry has its file, and
+    every file is an entry's or the one reader behind an entry's dotted
+    names."""
+    listed = {m["name"] for m in man["per_layer"]}
+    folder = os.path.join(mf.BENCH_DIR, "layer_metrics")
+    files = {f[:-3] for f in os.listdir(folder)
+             if f.endswith(".py") and f != "__init__.py"}
+    assert listed <= files, sorted(listed - files)
+    shared = {f for f in files - listed
+              if any(n.startswith(f + ".") for n in listed)}
+    assert files == listed | shared, sorted(files - listed - shared)
+    for f in shared:            # a dotted name is that reader and no other
+        for n in (n for n in listed if n.startswith(f + ".")):
+            assert mf.load_module("layer_metrics", n).read \
+                is mf.load_module("layer_metrics", f).read
+
+
+MISTRAL_SERVING = {
+    "serve-gen-closed": {"prefill_call_ms.gen", "prefill_calls_per_chunk.gen"},
+    "serve-rag-burst": {"prefill_call_ms.burst",
+                        "prefill_calls_per_chunk.burst"},
+    "serve-chat-steady": {"prefill_call_ms.burst",
+                          "prefill_calls_per_chunk.burst"}}
+
+
+@pytest.mark.parametrize("cell", sorted(MISTRAL_SERVING))
+def test_the_dense_serving_cells_list_the_prefill_program_and_no_gather_metric(
+        man, cell):
+    names = {m["name"] for m in mf.metrics_of(man, "per_layer", cell)}
+    assert MISTRAL_SERVING[cell] <= names
+    assert not {n for n in names if n.startswith("gather_")
+                or n == "prefill_kernel_share"}
+    by_name = {m["name"]: m for m in man["per_layer"]}
+    for n in MISTRAL_SERVING[cell]:
+        assert by_name[n]["moves"] == ("serve_tokens_per_s" if n.endswith(
+            ".gen") else "ttft_p50_ms")
+
+
 def test_cells_report_setup_one_more_metric_and_a_layer_metric(man):
     e2e_names = {m["name"] for m in man["end_to_end"]}
     assert "setup_s" in e2e_names

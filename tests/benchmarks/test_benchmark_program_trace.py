@@ -213,27 +213,47 @@ def test_trace_reader_on_the_hand_made_timeline(monkeypatch, metric, kind,
         assert "step_trace" in ctx.notes[0]["host_exposed"]["by_child"]
 
 
-def test_gather_step_reader_reads_the_module_line(monkeypatch):
-    mods = [("jit_dstpu_serve_gather(1)", 0.1, 0.43),
-            ("jit_dstpu_serve_decode(3)", 0.6, 0.04)]
-    pt = program_trace(ops=[], modules=mods, spans=[], t0=0.0, t1=1.0)
+MODULE_LINE = [("jit_dstpu_serve_gather(1)", 0.1, 0.43),
+               ("jit_dstpu_serve_prefill(7)", 0.55, 0.012),
+               ("jit_dstpu_serve_decode(3)", 0.6, 0.04),
+               ("jit_dstpu_serve_prefill(7)", 0.7, 0.016),
+               ("jit_dstpu_serve_prefill(7)", 0.99, 0.02)]    # leaves the window
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("gather_step_ms", 430.0), ("gather_step_ms.gen", 430.0),
+    ("prefill_call_ms", 14.0), ("prefill_call_ms.burst", 14.0),
+    ("prefill_call_ms.gen", 14.0)])
+def test_program_readers_read_the_module_line(monkeypatch, metric, want):
+    """Mean device time of one execution of the program, by its name on
+    the module line; an execution that leaves the traced window is not
+    counted, and a trace without the program reads nothing (never 0)."""
+    pt = program_trace(ops=[], modules=MODULE_LINE, spans=[], t0=0.0, t1=1.0)
     monkeypatch.setattr(P, "open_run", lambda ctx, result: pt)
     ctx = Ctx("serve")
-    reader = mf.load_module("layer_metrics", "gather_step_ms.burst")
-    assert reader.read(ctx, {}) == pytest.approx(430.0)
+    reader = mf.load_module("layer_metrics", metric)
+    assert reader.read(ctx, {}) == pytest.approx(want)
     assert ctx.notes[0]["programs_in_trace"] == {
-        "jit_dstpu_serve_gather": 1, "jit_dstpu_serve_decode": 1}
+        "jit_dstpu_serve_gather": 1, "jit_dstpu_serve_decode": 1,
+        "jit_dstpu_serve_prefill": 2}
+    other = [m for m in MODULE_LINE
+             if ("gather" in m[0]) != ("gather" in metric)
+             and "decode" not in m[0]]
+    pt = program_trace(ops=[], modules=other, spans=[], t0=0.0, t1=1.0)
+    assert reader.read(Ctx("serve"), {}) is None
 
 
 COUNTERS = {"tokens_gather": 13, "tokens_prefill_kernel": 0,
             "tokens_decode": 7, "tokens_multi_decode": 80,
             "admission_wait_s": 0.08, "admitted": 4, "prefill_chunks": 10,
-            "ttft_s": 2.0, "first_tokens": 4}
+            "prefill_chunk_calls": 9, "ttft_s": 2.0, "first_tokens": 4}
 
 
 @pytest.mark.parametrize("metric,want", [
     ("gather_token_share", 13.0), ("queue_wait_ms", 20.0),
-    ("prefill_chunks_per_req", 2.5), ("ttft_engine_ms", 500.0)])
+    ("prefill_chunks_per_req", 2.5), ("ttft_engine_ms", 500.0),
+    ("prefill_calls_per_chunk", 0.9), ("prefill_calls_per_chunk.burst", 0.9),
+    ("prefill_calls_per_chunk.gen", 0.9)])
 def test_counter_reader(metric, want):
     reader = mf.load_module("layer_metrics", metric)
     assert reader.read(Ctx("serve"), {"counters": {"engine": COUNTERS}}) \
@@ -358,7 +378,7 @@ ENV.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 COUNTED = {"tiny-train": set(), "tiny-gen": {"gather_token_share"},
            "tiny-burst": {"queue_wait_ms", "prefill_chunks_per_req",
-                          "ttft_engine_ms"}}
+                          "prefill_calls_per_chunk.burst", "ttft_engine_ms"}}
 
 
 @pytest.mark.parametrize("cell", sorted(COUNTED))

@@ -88,22 +88,42 @@ def test_weights_of_the_committed_configurations_are_what_they_were(path, layer)
                                                0.0091552734375]
 
 
-def test_the_three_committed_configurations_share_one_table_of_leaves():
+def _mistral_configurations():
+    """(name, configuration) of every committed configuration whose
+    ``reference`` is ``mistral``: the manifest holds other architectures
+    since PR 28, each with a table of leaves of its own."""
     import json
     import os
 
     from benchmarks.harness import manifest as mf
 
-    tables = set()
+    out = []
     for c in mf.load_manifest()["configs"]:
         with open(os.path.join(mf.ROOT, c["file"])) as f:
             cfg = json.load(f)
-        arch = mf.reference_of(cfg).Arch.from_model(cfg)
-        tables.add(arch.leaf_table())
-        assert [x.path for x in arch.leaf_table() if x.per_layer] == [
-            "attn.wq", "attn.wk", "attn.wv", "attn.wo", "mlp.wg", "mlp.wi",
-            "mlp.wo", "ln1.scale", "ln2.scale"]
-    assert len(tables) == 1          # depth is not in the table
+        if cfg["reference"] == "mistral":
+            out.append((c["name"], cfg))
+    return out
+
+
+MISTRAL_CONFIGURATIONS = _mistral_configurations()
+
+
+@pytest.mark.parametrize("cfg", [c for _, c in MISTRAL_CONFIGURATIONS],
+                         ids=[n for n, _ in MISTRAL_CONFIGURATIONS])
+def test_the_three_committed_configurations_share_one_table_of_leaves(cfg):
+    from benchmarks.harness import manifest as mf
+
+    def table(c):
+        return mf.reference_of(c).Arch.from_model(c).leaf_table()
+
+    others = MISTRAL_CONFIGURATIONS
+    assert len(others) == 3
+    assert [x.path for x in table(cfg) if x.per_layer] == [
+        "attn.wq", "attn.wk", "attn.wv", "attn.wo", "mlp.wg", "mlp.wi",
+        "mlp.wo", "ln1.scale", "ln2.scale"]
+    # depth is not in the table
+    assert all(table(c) == table(cfg) for _, c in others)
 
 
 def test_weights_differ_by_seed_and_serve_dtype_rounds_the_same_draws():

@@ -74,6 +74,37 @@ def test_trace_object_averages_chips_and_takes_the_worst_for_collectives():
     assert len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
 
 
+def test_a_trace_cut_to_a_window_keeps_whole_device_events_and_reaching_spans():
+    """``Trace.between``: the slice a capture's reader cuts back to whole
+    steps. A device event that the cut falls into goes (its step is not
+    counted either); a host span that reaches into the window stays, so an
+    idle gap at the window's start still finds the sleep that covers it."""
+    whole = T.Trace({0: OPS}, SPANS, 0.0, 6.0, {0: [("jit_a(1)", 1.0, 2.0),
+                                                    ("jit_a(1)", 4.0, 1.0)]})
+    cut = whole.between(3.5, 5.0)
+    assert (cut.t0, cut.t1, cut.window_s) == (3.5, 5.0, 1.5)
+    assert cut.device_ops == {0: [("fusion.1", 4.0, 0.5), ("all-reduce.7", 4.5, 0.5)]}
+    assert cut.modules == {0: [("jit_a(1)", 4.0, 1.0)]}
+    assert cut.host_spans == [("result_fetch", 3.1, 0.8)]     # 3.1-3.9 reaches in
+    assert cut.busy_s() == pytest.approx(1.0)
+    assert whole.between(1.2, 3.0).device_ops == {0: [
+        OPS[2], OPS[3], OPS[4]]}                   # the while and fusion.1 begin before
+    assert whole.busy_s() == pytest.approx(3.0)   # the whole is left as it was
+
+
+def test_a_capture_closed_without_reading_is_read_when_asked(tmp_path):
+    """``read_on_exit=False``: the thread that closes a capture beside the
+    generator's loop leaves the file for the main thread to read."""
+    import jax.numpy as jnp
+
+    with T.Capture(str(tmp_path / "tr"), read_on_exit=False) as cap:
+        with T.span("serve_step"):
+            jnp.ones((8, 8)).sum().block_until_ready()
+    assert cap.trace is None
+    got = cap.read()
+    assert got is cap.trace and "serve_step" in {s[0] for s in got.host_spans}
+
+
 def test_capture_on_the_cpu_reads_the_benchmarks_spans(tmp_path):
     import jax
     import jax.numpy as jnp
