@@ -104,6 +104,8 @@ def _shared_step_fns(cfg, kernel_mesh):
         "multi_decode": program(
             partial(runner.ragged_multi_decode, cfg, mesh=kernel_mesh),
             "dstpu_serve_multi_decode", static_argnames=("steps",)),
+        # no program: the rows the gather program computes for a call
+        "gather_rows": runner.gather_rows_computed,
     }
     _JIT_CACHE[key] = (cfg, fns)
     return fns
@@ -138,6 +140,10 @@ def dstpu_pick_greedy_all(lg):
 _TOKENS_OF = {"gather": "tokens_gather", "prefill": "tokens_prefill_kernel",
               "decode": "tokens_decode",
               "multi_decode": "tokens_multi_decode"}
+# what a ``dstpu/dispatch`` span can name as its ``program``: the four step
+# programs, and ``spec`` (a speculative round's verification, which runs
+# the gather program and is counted apart from a step's own gather calls)
+PROGRAMS = ("gather", "prefill", "decode", "multi_decode", "spec")
 
 _PICK_GREEDY = dstpu_pick_greedy
 _TAKE_ROWS = dstpu_take_rows
@@ -353,10 +359,24 @@ class InferenceEngineV2:
                       "tokens_decode": 0, "tokens_multi_decode": 0,
                       "prefill_chunks": 0, "admission_wait_s": 0.0,
                       "ttft_s": 0.0, "first_tokens": 0,
-                      # a step split by program (_split_by_program):
-                      # steps that made more than one program call, and
-                      # the calls of the prefill program made for chunks
-                      "split_steps": 0, "prefill_chunk_calls": 0}
+                      # the calls of the prefill program made for the
+                      # chunks of a step split by program
+                      # (_split_by_program)
+                      "prefill_chunk_calls": 0, "burst_steps": 0,
+                      # what each program was given, counted where a call
+                      # is issued (_dispatch): engine steps that made a
+                      # call at all, and below a set a program; a
+                      # request's wait for its first token in calls: all
+                      # those issued from its put() to that token, and
+                      # those of them that carried it
+                      "steps_dispatched": 0, "first_token_calls": 0,
+                      "first_token_own_calls": 0}
+        for program in PROGRAMS:
+            # calls, the token rows they really carried, the rows they
+            # computed (the program's padded layout) and the passes over
+            # the layers' weights they made one after another
+            self.stats.update({f"{k}_{program}": 0 for k in (
+                "calls", "rows", "padded_rows", "token_steps")})
         if self._hybrid:
             # the expert layers' routing, counted on the device by every
             # step program and fetched with the step's tokens: tokens x
@@ -382,6 +402,14 @@ class InferenceEngineV2:
         # span, of the spans nested in it, and of the request tracer's
         # PREFILL / DECODE_EMIT spans of that step
         self._step_id = 0
+        # program calls: those of the open step so far (a dispatch span's
+        # ``call``) and those of the engine's life, against which a request
+        # is stamped at put(); uid -> [that stamp, the calls since that
+        # carried it] while its first token is out (kept through a requeue,
+        # as ``_admit_time`` is)
+        self._step_calls = 0
+        self._calls_issued = 0
+        self._calls_at_put: Dict[int, List[int]] = {}
         self._closed = False
         # admission queue: put() never raises on a full KV pool — requests
         # wait FIFO here and admit as blocks free up; preemption victims
@@ -461,6 +489,10 @@ class InferenceEngineV2:
         self._step_fn = _fns["step"]
         self._decode_fn = _fns["decode"]
         self._prefill_fn = _fns["prefill"]
+        # the rows a call of the gather program computes: the flat budget,
+        # or what the runner lays it out as inside the program
+        self._gather_rows = int(_fns["gather_rows"](self.max_seqs,
+                                                    self.max_tokens))
         # device-side token pick: the step fetches only sampled ids (or
         # the consumed rows when temperature > 0), never the full [T, V]
         # logits buffer (see step())
@@ -563,6 +595,7 @@ class InferenceEngineV2:
             self._queue.append(_QueuedRequest(
                 uid=uid, tokens=toks, max_new_tokens=max_new_tokens,
                 enqueue_time=now, admit_time=now))
+            self._calls_at_put[uid] = [self._calls_issued, 0]
             if journal_ingress:
                 jr.admit(uid, toks.tolist(), int(max_new_tokens))
             self.stats["queued"] += 1
@@ -611,14 +644,18 @@ class InferenceEngineV2:
         self._hub.gauge("serve.queue_wait_depth", len(self._queue),
                         labels=self._metric_labels)
 
-    def _release_seq(self, uid: int) -> Optional[float]:
+    def _release_seq(self, uid: int, requeue: bool = False
+                     ) -> Optional[float]:
         """The ONE sequence-teardown path: frees state + KV and pops the
         latency maps (both the finished and the preempted path route
         here, so neither leaks ``_admit_time``/``_last_emit_time`` under
         sustained overload). Returns the pending admit time, if TTFT was
-        still unmeasured, for requeue to carry forward."""
+        still unmeasured, for requeue to carry forward; with ``requeue``
+        the request's stamp of calls stays too (``_calls_at_put``)."""
         self.state.release(uid)
         admit = self._admit_time.pop(uid, None)
+        if not requeue:
+            self._calls_at_put.pop(uid, None)
         self._last_emit_time.pop(uid, None)
         self._seq_accept_ewma.pop(uid, None)
         return admit
@@ -657,7 +694,7 @@ class InferenceEngineV2:
                         free_blocks=self.kv_cache.free_blocks,
                         queue_depth=len(self._queue))
         prior = seq.prior_generated + len(seq.generated)
-        admit = self._release_seq(seq.uid)
+        admit = self._release_seq(seq.uid, requeue=True)
         self._queue.appendleft(_QueuedRequest(
             uid=seq.uid, tokens=tokens, max_new_tokens=seq.max_new_tokens,
             enqueue_time=time.perf_counter(), prior_generated=prior,
@@ -726,7 +763,7 @@ class InferenceEngineV2:
             [np.asarray(seq.input_tokens, np.int32),
              np.asarray(seq.generated, np.int32)])
         prior = seq.prior_generated + len(seq.generated)
-        admit = self._release_seq(seq.uid)
+        admit = self._release_seq(seq.uid, requeue=True)
         self._queue.appendleft(_QueuedRequest(
             uid=seq.uid, tokens=tokens, max_new_tokens=seq.max_new_tokens,
             enqueue_time=time.perf_counter(), prior_generated=prior,
@@ -1100,7 +1137,54 @@ class InferenceEngineV2:
         timeline joins to its steps and through them to the device
         programs dispatched under them."""
         self._step_id += 1
+        self._step_calls = 0
         return span("serve_step", step_id=self._step_id)
+
+    def _dispatch(self, program: str, seqs, tokens: int,
+                  token_steps: int = 1, chunks: int = 0, **shape):
+        """The one place a program call is described: the
+        ``dstpu/dispatch`` span to issue it under (the jitted call goes
+        inside the ``with``), after counting it. ``program`` is one of
+        PROGRAMS; ``seqs`` the sequences the call carries; ``tokens`` the
+        token rows that are really theirs; ``token_steps`` the passes over
+        the model's layers it makes one after another (K for a burst);
+        ``chunks`` the prompt chunks in it; ``shape`` the prefill
+        program's padded layout, ``S`` and ``tq``. The span carries all of
+        it with the ``step_id`` of the enclosing ``serve_step``, the
+        call's place among the step's calls (``call``, from 0) and the
+        rows the program computes whatever it carries (``padded_rows``);
+        the counters (``calls_<program>`` ...) take the same numbers, and
+        each carried sequence whose first token is still out counts the
+        call as its own."""
+        if program == "prefill":
+            padded_rows = shape["S"] * shape["tq"]
+        elif program in ("decode", "multi_decode"):
+            padded_rows = token_steps * self.max_seqs
+        else:       # the gather program: its runner's layout of the budget
+            padded_rows = self._gather_rows
+        call = self._step_calls
+        self._step_calls += 1
+        self._calls_issued += 1
+        st = self.stats
+        st["steps_dispatched"] += call == 0
+        st["calls_" + program] += 1
+        st["rows_" + program] += tokens
+        st["padded_rows_" + program] += padded_rows
+        st["token_steps_" + program] += token_steps
+        if program == "prefill":
+            st["prefill_chunk_calls"] += self._splits_steps
+        elif program in ("decode", "multi_decode"):
+            st["decode_kernel_steps"] += token_steps
+            st["burst_steps"] += program == "multi_decode"
+        if self._calls_at_put:
+            for seq in seqs:
+                waiting = self._calls_at_put.get(seq.uid)
+                if waiting is not None:
+                    waiting[1] += 1
+        return span("dispatch", program=program, step_id=self._step_id,
+                    call=call, seqs=len(seqs), tokens=tokens,
+                    padded_rows=padded_rows, token_steps=token_steps,
+                    chunks=chunks, **shape)
 
     def step(self, temperature: float = 0.0, seed: int = 0,
              eos_token_id: Optional[int] = None) -> Dict[int, int]:
@@ -1161,15 +1245,20 @@ class InferenceEngineV2:
         # one program a part (_split_by_program); the pools pass from one
         # to the next
         runs = []
-        parts = self._split_by_program(scheduled)
-        self.stats["split_steps"] += len(parts) > 1
-        for part in parts:
+        call_of = {}        # index into ``scheduled`` -> its call of the step
+        for part in self._split_by_program(scheduled):
             mine = [scheduled[i] for i in part]
+            call_of.update(dict.fromkeys(part, self._step_calls))
             with self.mesh:
                 with span("build_batch"):
                     fn, program, args, batch = self._build_step_call(mine)
-                with span("dispatch", program=program, seqs=len(mine),
-                          tokens=int(batch.num_tokens)):
+                    shape = (dict(zip(("S", "tq"), args[0].shape))
+                             if program == "prefill" else {})
+                with self._dispatch(
+                        program, [seq for seq, _, _ in mine],
+                        int(batch.num_tokens),
+                        chunks=sum(sp < len(seq.input_tokens)
+                                   for seq, _, sp in mine), **shape):
                     logits, new_kv = fn(self.params, self.kv_cache.kv_state,
                                         *args)
             # the program consumed (donated) the handle it was given
@@ -1270,12 +1359,13 @@ class InferenceEngineV2:
                 # same clock domain as every other span (skew-aware wall
                 # time): a stamp from the raw clock would rebase acausally
                 t_start = wall_time() - (now - t0)
-                for seq, new_tokens, start_pos in scheduled:
+                for i, (seq, new_tokens, start_pos) in enumerate(scheduled):
                     if start_pos < len(seq.input_tokens):
                         self.tracer.on_prefill(seq.uid, t_start, wall_ms,
                                                tokens=len(new_tokens),
                                                start_pos=start_pos,
-                                               step_id=self._step_id)
+                                               step_id=self._step_id,
+                                               call=call_of[i])
             for uid in emitted:
                 self._note_emitted(uid, 1, now)
             self._update_serve_gauges()
@@ -1365,9 +1455,7 @@ class InferenceEngineV2:
                     f"{self._last_fallback_reason}: paged prefill fell "
                     "back to the gather path — flat-layout serve step, "
                     "no Pallas kernel; see log_summary()")
-            elif self._splits_steps:
-                self.stats["prefill_chunk_calls"] += 1
-            else:
+            elif not self._splits_steps:
                 self.stats["prefill_kernel_steps"] += 1
             # fraction of mixed prefill steps that lost the Pallas
             # kernel to the gather path — per-replica on the Prometheus
@@ -1378,8 +1466,6 @@ class InferenceEngineV2:
                 "serve.paged_fallback_ratio",
                 self.stats["prefill_gather_fallbacks"] / max(1, attempts),
                 labels=self._metric_labels)
-        elif decode_only:
-            self.stats["decode_kernel_steps"] += 1
         if seg_plan is not None:
             n_segs = seg_plan[0].shape[0]
             return self._prefill_fn, "prefill", (
@@ -1461,6 +1547,9 @@ class InferenceEngineV2:
             self._ttft_hist.observe(now - admit)
             self.stats["ttft_s"] += now - admit
             self.stats["first_tokens"] += 1
+            stamp, own = self._calls_at_put.pop(uid, (self._calls_issued, 0))
+            self.stats["first_token_calls"] += self._calls_issued - stamp
+            self.stats["first_token_own_calls"] += own
             n_tokens -= 1
             last = now
         if last is not None and n_tokens > 0:
@@ -1527,8 +1616,8 @@ class InferenceEngineV2:
                 args = (jnp.asarray(d_tok), jnp.asarray(d_pos),
                         jnp.asarray(bt), jnp.asarray(ctx),
                         *self._state_slots_arg(live))
-            with span("dispatch", program="multi_decode", seqs=len(live),
-                      tokens=K * len(live)):
+            with self._dispatch("multi_decode", live, K * len(live),
+                                token_steps=K):
                 toks, new_kv = self._multi_decode_fn(
                     self.params, self.kv_cache.kv_state, *args, steps=K)
             # at once: the handle the cache still holds is consumed
@@ -1537,8 +1626,6 @@ class InferenceEngineV2:
                 toks_np = np.asarray(toks)  # [K, S]: one fetch per K tokens
                 self._fetch_counters(True)
         with span("bookkeep"):
-            self.stats["decode_kernel_steps"] += K
-            self.stats["burst_steps"] = self.stats.get("burst_steps", 0) + 1
             emitted: Dict[int, List[int]] = {}
             for i, s in enumerate(live):
                 accepted = []
@@ -1655,8 +1742,8 @@ class InferenceEngineV2:
                         jnp.asarray(batch.token_pos),
                         jnp.asarray(batch.block_table),
                         jnp.asarray(batch.num_tokens, jnp.int32))
-            with span("dispatch", program="spec", seqs=len(sched),
-                      tokens=int(batch.num_tokens)):
+            with self._dispatch("spec", [seq for seq, _, _ in sched],
+                                int(batch.num_tokens)):
                 logits, new_kv = self._step_fn(
                     self.params, self.kv_cache.kv_state, *args)
             self.kv_cache.set_kv_state(new_kv)

@@ -407,6 +407,15 @@ def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens):
     return lax.map(segment, (q_n, q_r, block_table, pos, ctx_lens))
 
 
+def gather_rows_computed(max_seqs: int, max_tokens: int) -> int:
+    """The token rows one call of :func:`ragged_forward` computes, whatever
+    it carries (a ``dstpu/dispatch`` span's ``padded_rows``): it lays the
+    flat tokens out anew, sequence by token, for the chunked recurrence
+    (``seg_real`` [S, T]), so every recurrent layer runs ``max_seqs`` rows
+    of ``max_tokens`` each."""
+    return max_seqs * max_tokens
+
+
 def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
                    token_pos, block_table, num_tokens, state_slots=None
                    ) -> Tuple[jax.Array, Dict]:

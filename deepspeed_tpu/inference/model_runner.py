@@ -271,6 +271,13 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def gather_rows_computed(max_seqs: int, max_tokens: int) -> int:
+    """The token rows one call of :func:`ragged_forward` computes, whatever
+    it carries: the flat budget (what a ``dstpu/dispatch`` span gives as its
+    ``padded_rows``)."""
+    return max_tokens
+
+
 def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
                    token_ids: jax.Array, token_seq: jax.Array,
                    token_pos: jax.Array, block_table: jax.Array,

@@ -410,13 +410,18 @@ class RequestTracer:
 
     def on_prefill(self, uid: int, start: float, dur_ms: float,
                    tokens: int, start_pos: int,
-                   step_id: Optional[int] = None) -> None:
+                   step_id: Optional[int] = None,
+                   call: Optional[int] = None) -> None:
         """``step_id``: the engine step that computed the chunk, the id
-        of its ``dstpu/serve_step`` span on the profiler's clock."""
+        of its ``dstpu/serve_step`` span on the profiler's clock;
+        ``call``: which of the step's program calls carried it, the
+        ``call`` of that ``dstpu/dispatch`` span."""
         t = self._active.get(uid) if self.enabled else None
         if t is None:
             return
         ids = {} if step_id is None else {"step_id": int(step_id)}
+        if call is not None:
+            ids["call"] = int(call)
         t.add("PREFILL", start, dur_ms=dur_ms, tokens=int(tokens),
               start_pos=int(start_pos), **ids)
 

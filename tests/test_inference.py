@@ -234,7 +234,10 @@ class TestInferenceEngineV2:
             assert v2.stats["tokens_gather"] == 4
             summary = v2.log_summary()
             assert summary["prefill_gather_fallbacks"] >= 1
-            assert summary["split_steps"] == 0 == summary[
+            # one call a step, of the gather program
+            assert summary["calls_gather"] == 1 == summary[
+                "steps_dispatched"]
+            assert summary["calls_prefill"] == 0 == summary[
                 "prefill_chunk_calls"]
             # every prompt step so far lost the kernel: the gauge reads 1
             assert v2._hub.gauges["serve.paged_fallback_ratio"] == 1.0
@@ -256,7 +259,8 @@ class TestInferenceEngineV2:
                                for p in prompts.values()], max_new_tokens=2)
         v2.step()
         summary = v2.log_summary()
-        assert summary["split_steps"] == 1
+        assert summary["steps_dispatched"] == 1
+        assert (summary["calls_decode"], summary["calls_prefill"]) == (1, 1)
         assert summary["prefill_chunk_calls"] == 1
         assert summary["prefill_kernel_steps"] == 0
         assert summary["prefill_gather_fallbacks"] == 0

@@ -434,7 +434,11 @@ class TestServingSnapshot:
         e._admit_time = {1: 100.0}
         e._last_emit_time = {}
         e._step_id = 0                         # and counts its steps
-        e.stats = {"ttft_s": 0.0, "first_tokens": 0}
+        # ... and its program calls: 7 issued so far, this request put with
+        # 3 made and carried by 2 of the 4 since
+        e._calls_issued, e._calls_at_put = 7, {1: [3, 2]}
+        e.stats = {"ttft_s": 0.0, "first_tokens": 0,
+                   "first_token_calls": 0, "first_token_own_calls": 0}
         e._note_emitted(1, 1, now=100.5)       # first token: TTFT 0.5s
         e._note_emitted(1, 1, now=100.7)       # decode gap 0.2s
         e._note_emitted(1, 2, now=101.1)       # burst: 2 tokens over 0.4s
@@ -445,7 +449,10 @@ class TestServingSnapshot:
         assert d["count"] == 3
         assert d["max"] == pytest.approx(0.2, rel=0.02)
         # the flat counters hold the same observation as the histogram
-        assert e.stats == {"ttft_s": pytest.approx(0.5), "first_tokens": 1}
+        assert e.stats == {"ttft_s": pytest.approx(0.5), "first_tokens": 1,
+                           "first_token_calls": 4,
+                           "first_token_own_calls": 2}
+        assert e._calls_at_put == {}
 
 
 # ---------------------------------------------------------------------------

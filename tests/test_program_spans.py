@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as dstpu
-from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.inference.engine_v2 import PROGRAMS, InferenceEngineV2
 from deepspeed_tpu.models.zoo import get_model
 from deepspeed_tpu.utils.annotate import SPAN_PREFIX
 
@@ -160,8 +160,56 @@ def _mixed_run(engine):
 EXPECTED = {"tokens_gather": 0, "tokens_multi_decode": 24,
             "tokens_decode": 3 + 1, "tokens_prefill_kernel": 3 + 1,
             "prefill_chunks": 3 + 2, "first_tokens": 4, "admitted": 4,
-            "split_steps": 1, "prefill_chunk_calls": 2 + 2,
-            "prefill_kernel_steps": 0, "prefill_gather_fallbacks": 0}
+            "prefill_chunk_calls": 2 + 2,
+            "prefill_kernel_steps": 0, "prefill_gather_fallbacks": 0,
+            # what each program was given, a call at a time: A's two chunk
+            # calls carry 25 rows as 2 x 32 and 5 as 1 x 8, B's 32 as
+            # 1 x 32 and 8 as 1 x 8; the burst 8 x 3 rows as 8 x 4 slots;
+            # the decode steps 3 and 1 rows as 4 slots each
+            "steps_dispatched": 6,
+            "calls_prefill": 4, "rows_prefill": 25 + 5 + 32 + 8,
+            "padded_rows_prefill": 64 + 8 + 32 + 8, "token_steps_prefill": 4,
+            "calls_multi_decode": 1, "rows_multi_decode": 24,
+            "padded_rows_multi_decode": 32, "token_steps_multi_decode": 8,
+            "calls_decode": 2, "rows_decode": 3 + 1,
+            "padded_rows_decode": 4 + 4, "token_steps_decode": 2,
+            "calls_gather": 0, "rows_gather": 0, "calls_spec": 0,
+            "decode_kernel_steps": 8 + 2, "burst_steps": 1,
+            # A's requests are put before any call and get their first
+            # tokens once the step's two calls are out, each carried by
+            # one of them; B is put with 4 calls made and waits for its
+            # own two
+            "first_token_calls": 3 * 2 + 2, "first_token_own_calls": 3 + 2}
+# the ids of each ``dstpu/dispatch`` span of _mixed_run, in order
+MIXED_CALLS = [
+    dict(program="prefill", call=0, seqs=2, tokens=25, padded_rows=64,
+         token_steps=1, chunks=2, S=2, tq=32),
+    dict(program="prefill", call=1, seqs=1, tokens=5, padded_rows=8,
+         token_steps=1, chunks=1, S=1, tq=8),
+    dict(program="multi_decode", call=0, seqs=3, tokens=24, padded_rows=32,
+         token_steps=8, chunks=0),
+    dict(program="decode", call=0, seqs=3, tokens=3, padded_rows=4,
+         token_steps=1, chunks=0),
+    dict(program="prefill", call=0, seqs=1, tokens=32, padded_rows=32,
+         token_steps=1, chunks=1, S=1, tq=32),
+    dict(program="prefill", call=0, seqs=1, tokens=8, padded_rows=8,
+         token_steps=1, chunks=1, S=1, tq=8),
+    dict(program="decode", call=0, seqs=1, tokens=1, padded_rows=4,
+         token_steps=1, chunks=0)]
+
+
+def _dispatches(spans):
+    """The ``dstpu/dispatch`` spans' ids, in order, and for each the id of
+    the ``serve_step`` span it lies in (which its own ``step_id`` has to
+    name)."""
+    steps = [s for s in spans if s["name"] == "serve_step"]
+    out = []
+    for d in (s for s in spans if s["name"] == "dispatch"):
+        (step,) = [t for t in steps if t["start"] <= d["start"]
+                   and d["end"] <= t["end"]]
+        assert d["ids"]["step_id"] == step["ids"]["step_id"]
+        out.append({k: v for k, v in d["ids"].items() if k != "step_id"})
+    return out
 
 
 def test_counters_against_a_hand_counted_schedule(devices):
@@ -174,6 +222,12 @@ def test_counters_against_a_hand_counted_schedule(devices):
     assert got == EXPECTED
     tokens = sum(engine.stats[k] for k in EXPECTED if k.startswith("tokens_"))
     assert tokens == sum(len(t) for t in out.values()) == 32
+    st = engine.stats
+    assert all(st[f"rows_{p}"] <= st[f"padded_rows_{p}"]
+               and st[f"calls_{p}"] <= st[f"token_steps_{p}"]
+               for p in PROGRAMS)
+    assert engine._calls_issued == sum(st[f"calls_{p}"] for p in PROGRAMS)
+    assert engine._calls_at_put == {}
     # the time sums hold the histograms' own observations
     snap = engine.snapshot()
     assert engine.stats["ttft_s"] == pytest.approx(snap["ttft"]["sum"],
@@ -225,6 +279,9 @@ def test_serve_spans_and_request_trace_share_step_ids(devices, tmp_path):
         assert names.index("build_batch") < names.index("dispatch")
     assert programs == ["prefill+prefill", "multi_decode", "decode",
                         "prefill", "prefill", "decode"]
+    # every call says what it carried, where it stood in its step, and
+    # the step it belongs to
+    assert _dispatches(spans) == MIXED_CALLS
     puts = [s for s in spans if s["name"] == "put"]
     assert [(p["ids"]["uid"], p["ids"]["requests"]) for p in puts] == \
         [(1, 3), (4, 1)]
@@ -236,6 +293,10 @@ def test_serve_spans_and_request_trace_share_step_ids(devices, tmp_path):
     # the request tracer's spans name the step that made them
     traces = {t.uid: t for t in engine.request_traces()}
     by_step = dict(zip(programs, ids))
+    # ... and a PREFILL span the call of that step that carried the chunk
+    assert [[s.fields["call"] for s in traces[u].spans
+             if s.kind == "PREFILL"] for u in (1, 2, 3, 4)] == \
+        [[0], [0], [1], [0, 0]]
     four = [(s.kind, s.fields.get("step_id")) for s in traces[4].spans
             if s.kind in ("PREFILL", "DECODE_EMIT")]
     assert four == [("PREFILL", ids[3]), ("PREFILL", ids[4]),
@@ -257,22 +318,187 @@ def test_speculative_round_spans(devices, tmp_path):
     assert got == want
     spec = [s for s in spans if s["name"] == "dispatch"
             and s["ids"]["program"] == "spec"]
-    assert len(spec) == engine.stats["spec_steps"]
-    # a speculative round runs the gather program
+    assert len(spec) == engine.stats["spec_steps"] > 0
+    # a speculative round runs the gather program over the flat budget, one
+    # pass over the layers whatever it verifies, and is counted under its
+    # own name
     s = engine.stats
+    assert all(d["ids"]["padded_rows"] == 32 and d["ids"]["token_steps"] == 1
+               and d["ids"]["call"] == 0 and d["ids"]["chunks"] == 0
+               and d["ids"]["seqs"] == 1 for d in spec)
+    assert s["calls_spec"] == len(spec) == s["token_steps_spec"]
+    assert s["rows_spec"] == sum(d["ids"]["tokens"] for d in spec) \
+        == s["spec_proposed"] + len(spec)
+    assert s["padded_rows_spec"] == 32 * len(spec)
+    assert s["calls_gather"] == 0 == s["tokens_prefill_kernel"] - 1
     assert (s["tokens_gather"] + s["tokens_prefill_kernel"]
             + s["tokens_decode"] + s["tokens_multi_decode"]) == 12
     engine.close(), plain.close()
 
 
-def test_serve_tokens_do_not_depend_on_a_profiler_session(devices, tmp_path):
-    a, b = _serve_engine(), _serve_engine()
+def _hybrid_engine():
+    from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
+
+    model = get_model("tiny-hybrid", param_dtype=jnp.float32,
+                      dtype=jnp.float32)
+    return InferenceEngineV2(
+        model, mesh=build_mesh(TopologyConfig(), devices=jax.devices()[:1]),
+        params=model.init(jax.random.PRNGKey(0)), dtype=jnp.float32,
+        kv_blocks=64, kv_block_size=16, max_tokens_per_step=32,
+        max_seqs_per_step=4, max_blocks_per_seq=8, state_slots=4)
+
+
+@pytest.mark.parametrize("kind", ["dense", "hybrid", "speculative"])
+def test_serve_tokens_do_not_depend_on_a_profiler_session(devices, tmp_path,
+                                                          kind):
+    make = {"dense": _serve_engine, "hybrid": _hybrid_engine,
+            "speculative": lambda: _serve_engine(spec_decode=True, spec_k=3)
+            }[kind]
+    a, b = make(), make()
     plain = _mixed_run(a)
     traced, spans = capture(tmp_path, lambda: _mixed_run(b))
     assert plain == traced and spans
-    assert {k: a.stats[k] for k in EXPECTED} == \
-        {k: b.stats[k] for k in EXPECTED}
+    counted = [k for k, v in a.stats.items() if isinstance(v, int)]
+    assert {k: a.stats[k] for k in counted} == \
+        {k: b.stats[k] for k in counted}
+    assert set(EXPECTED) <= set(counted)
     a.close(), b.close()
+
+
+def test_the_calls_of_a_split_step_say_their_place_and_what_they_carry(
+        devices, tmp_path):
+    """One sequence in decode and three prompts of 5, 20 and 5 arriving.
+    The scheduler's scan of the waiting prompts starts one further with
+    every step that had one waiting (this engine's second), so the chunks
+    come as 20, 5, 5: the step is a call of the decode program (its row as
+    4 slots), then two of the prefill program: 20 and 5 as 2 x 32, and 5
+    as 1 x 8 (the three would pad to 4 x 32, over twice the budget of
+    32)."""
+    engine = _serve_engine(prefix_cache=False)
+
+    def run():
+        engine.put([7], [_prompt(5, 7)], max_new_tokens=10)
+        engine.serve_step()
+        stats0 = dict(engine.stats)
+        engine.put([1, 2, 3], [_prompt(5, 1), _prompt(20, 2), _prompt(5, 3)],
+                   max_new_tokens=10)
+        engine.serve_step()
+        return {k: v - stats0[k] for k, v in engine.stats.items()
+                if isinstance(v, int)}
+
+    new, spans = capture(tmp_path, run)
+    assert _dispatches(spans)[1:] == [
+        dict(program="decode", call=0, seqs=1, tokens=1, padded_rows=4,
+             token_steps=1, chunks=0),
+        dict(program="prefill", call=1, seqs=2, tokens=25, padded_rows=64,
+             token_steps=1, chunks=2, S=2, tq=32),
+        dict(program="prefill", call=2, seqs=1, tokens=5, padded_rows=8,
+             token_steps=1, chunks=1, S=1, tq=8)]
+    want = {"steps_dispatched": 1, "calls_decode": 1, "rows_decode": 1,
+            "padded_rows_decode": 4, "calls_prefill": 2, "rows_prefill": 30,
+            "padded_rows_prefill": 72, "token_steps_prefill": 2,
+            "prefill_chunk_calls": 2, "decode_kernel_steps": 1,
+            "calls_gather": 0, "calls_multi_decode": 0,
+            # the three wait for all three calls of their step, the
+            # decode program's among them, and ride in one each
+            "first_tokens": 3, "first_token_calls": 9,
+            "first_token_own_calls": 3}
+    assert {k: new[k] for k in want} == want
+    # the request tracer's PREFILL span names the call that carried it
+    step = engine._step_id
+    engine.generate_all()
+    traces = {t.uid: t for t in engine.request_traces()}
+    assert {u: [(s.fields["step_id"], s.fields["call"])
+                for s in traces[u].spans if s.kind == "PREFILL"]
+            for u in (1, 2, 3)} == {1: [(step, 2)], 2: [(step, 1)],
+                                    3: [(step, 1)]}
+    engine.close()
+
+
+def test_a_hybrid_gather_step_counts_the_runners_layout(devices, tmp_path):
+    """The hybrid runner's gather program lays the step's flat tokens out
+    anew, ``max_seqs`` rows of ``max_tokens``, for the chunked recurrence:
+    that, and not the flat budget, is what a call computes."""
+    from deepspeed_tpu.inference import hybrid_runner, model_runner
+
+    assert model_runner.gather_rows_computed(4, 32) == 32
+    assert hybrid_runner.gather_rows_computed(4, 32) == 4 * 32
+    engine = _hybrid_engine()
+
+    def run():
+        engine.put([1, 2, 3], [_prompt(3, i) for i in (1, 2, 3)],
+                   max_new_tokens=12)
+        engine.serve_step()
+        engine.put([4], [_prompt(20, 4)], max_new_tokens=3)
+        engine.serve_step()
+
+    _, spans = capture(tmp_path, run)
+    assert _dispatches(spans) == [
+        dict(program="gather", call=0, seqs=3, tokens=9, padded_rows=128,
+             token_steps=1, chunks=3),
+        dict(program="gather", call=0, seqs=4, tokens=23, padded_rows=128,
+             token_steps=1, chunks=1)]
+    st = engine.stats
+    assert (st["calls_gather"], st["rows_gather"], st["padded_rows_gather"],
+            st["token_steps_gather"]) == (2, 32, 256, 2)
+    assert st["prefill_gather_fallbacks"] == 2 == st["steps_dispatched"]
+    assert (st["first_tokens"], st["first_token_calls"],
+            st["first_token_own_calls"]) == (4, 4, 4)
+    engine.close()
+
+
+def test_first_token_calls_of_two_prompts_whose_chunks_alternate(devices):
+    """Two prompts of 40 tokens put together, 32 tokens a step: the
+    scheduler's rotating scan gives the first step to one, the second to
+    the other, and the third carries the 8 left of each in one call. Each
+    waited for three calls, and two of them were its own."""
+    engine = _serve_engine(prefix_cache=False)
+    engine.put([1, 2], [_prompt(40, 1), _prompt(40, 2)], max_new_tokens=2)
+    assert engine._calls_at_put == {1: [0, 0], 2: [0, 0]}
+    engine.serve_step(), engine.serve_step()
+    assert engine._calls_at_put == {1: [0, 1], 2: [0, 1]}
+    assert engine.serve_step().keys() == {1, 2}
+    st = engine.stats
+    assert (st["calls_prefill"], st["rows_prefill"],
+            st["padded_rows_prefill"]) == (3, 80, 32 + 32 + 2 * 8)
+    assert (st["first_tokens"], st["first_token_calls"],
+            st["first_token_own_calls"]) == (2, 6, 4)
+    assert engine._calls_at_put == {}
+    engine.generate_all()
+    # later tokens count nothing more
+    assert (st["first_token_calls"], st["first_token_own_calls"]) == (6, 4)
+    engine.close()
+
+
+def test_a_requeued_request_keeps_its_stamp_of_calls(devices):
+    """Preempted after its first chunk and requeued, a request still
+    counts from its put(): the chunk call before the preemption, and the
+    two that compute the prompt again, all its own; another request's
+    call in between is counted and foreign."""
+    engine = _serve_engine(prefix_cache=False)
+    engine.put([1], [_prompt(40, 1)], max_new_tokens=2)
+    engine.serve_step()
+    assert engine._calls_at_put == {1: [0, 1]}
+    engine._requeue(engine.state.seqs[1])
+    assert engine._calls_at_put == {1: [0, 1]} and engine._admit_time == {}
+    assert engine.stats["requeued"] == 1 and not engine.state.seqs
+    engine.put([2], [_prompt(5, 2)], max_new_tokens=1)
+    out = engine.generate_all()
+    assert {u: len(t) for u, t in out.items()} == {1: 2, 2: 1}
+    st = engine.stats
+    # 1: chunk, [requeue] chunk (with 2's prompt beside it in the call),
+    # chunk -> 3 calls, all its own; 2: put with one call made, its first
+    # token with two
+    assert st["calls_prefill"] == 3
+    assert (st["first_tokens"], st["first_token_calls"],
+            st["first_token_own_calls"]) == (2, 3 + 1, 3 + 1)
+    assert engine._calls_at_put == {}
+    # a request flushed before its first token leaves no stamp behind
+    engine.put([3], [_prompt(40, 3)], max_new_tokens=2)
+    engine.serve_step()
+    engine.flush([3])
+    assert engine._calls_at_put == {} and engine._admit_time == {}
+    engine.close()
 
 
 def test_close_detaches_the_request_tracer_from_the_flight_recorder(devices):

@@ -17,6 +17,7 @@ import pytest
 
 from benchmarks.runners.serve import LogitsTap
 from deepspeed_tpu.inference import engine_v2
+from deepspeed_tpu.inference.engine_v2 import PROGRAMS
 from deepspeed_tpu.models.zoo import get_model
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -168,6 +169,12 @@ def _chunk_script(d: Drive):
     d.finish()
 
 
+def _calls_beyond_one_a_step(stats):
+    """Program calls over one a step: what steps split by program add."""
+    return sum(stats[f"calls_{p}"] for p in PROGRAMS) - stats[
+        "steps_dispatched"]
+
+
 def _both(model, params, script, **kw):
     split, gather = (Drive(_engine(model, params, kernel, **kw))
                      for kernel in (True, False))
@@ -190,10 +197,14 @@ def test_split_step_matches_the_gather_program(name):
     assert st["prefill_chunk_calls"] == steps_with_chunk + 1
     assert st["prefill_gather_fallbacks"] == 0 == st["prefill_kernel_steps"]
     # more than one call: token rows beside a chunk (twice), and those two
-    assert st["split_steps"] == 3 == 1 + sum(
+    assert _calls_beyond_one_a_step(st) == 3 == 1 + sum(
         len({n == 1 for _, n, _ in rows}) == 2 for rows, _, _ in split.log)
-    assert gather.eng.stats["split_steps"] == 0 == gather.eng.stats[
-        "prefill_chunk_calls"]
+    assert st["calls_prefill"] == st["prefill_chunk_calls"]
+    assert st["calls_gather"] == 0 < st["calls_decode"]
+    got = gather.eng.stats
+    assert _calls_beyond_one_a_step(got) == 0 == got["prefill_chunk_calls"]
+    assert got["calls_prefill"] == 0 == got["calls_decode"]
+    assert got["calls_gather"] > 0
     _same(split, gather, 1e-5)
 
 
@@ -241,7 +252,12 @@ def test_token_rows_beside_chunks(chunks):
     split, gather = _both(model, params, script, max_seqs_per_step=32,
                           max_tokens_per_step=64, kv_blocks=160)
     st = split.eng.stats
-    assert (st["split_steps"], st["prefill_chunk_calls"]) == (1, 1)
+    assert (_calls_beyond_one_a_step(st), st["calls_prefill"]) == (1, 1)
+    assert st["prefill_chunk_calls"] == 1
+    # the call carried the chunks' rows, padded to S x tq
+    S = 1 if len(chunks) == 1 else 4
+    assert st["rows_prefill"] == sum(chunks)
+    assert st["padded_rows_prefill"] == S * (32 if max(chunks) > 16 else 16)
     assert st["tokens_prefill_kernel"] == len(chunks)
     assert st["tokens_decode"] == rows_before * 6 + len(chunks) * 2
     _same(split, gather, 1e-5)
