@@ -258,8 +258,7 @@ class InferenceEngineV2:
             latent_dim=getattr(self.cfg, "latent_dim", 0))
         self.kv_cache = BlockedKVCache(kv_cfg, mesh=self.mesh)
         if self._hybrid and not self._recurrent:
-            # no state pool carries the step programs' counters
-            self.kv_cache.counters = jnp.zeros((len(COUNTERS),), jnp.int32)
+            self.kv_cache.pools_as_dict = True     # no state pool says so
         # disagg handoff wire codec mode ("auto"/"raw"/"int8"/"int4");
         # consumed by serving/disagg.py serialize_prefix
         self._handoff_wire = handoff_wire
@@ -329,7 +328,6 @@ class InferenceEngineV2:
         # serve-path telemetry (VERDICT r2: the gather fallback is a perf
         # cliff users can't see — count it; reference analog: the comms
         # logger's op counts, utils/comms_logging.py)
-        self._last_fallback_reason = "unknown"
         self.stats = {"decode_kernel_steps": 0, "prefill_kernel_steps": 0,
                       "prefill_gather_fallbacks": 0,
                       "fallback_reasons": {"vmem": 0, "padding": 0},
@@ -1095,20 +1093,25 @@ class InferenceEngineV2:
             slots[i] = s.state_slot
         return (jnp.asarray(slots),)
 
-    def _fetch_counters(self, decode: bool) -> None:
-        """Add what the step program counted to ``stats`` (inside the
-        ``fetch`` span, after the step's tokens: the program is done)."""
-        pool = self.kv_cache.state_pool
+    def _fetch_counters(self, calls) -> None:
+        """Add what a step's program calls counted to ``stats``, each under
+        its own program: ``calls`` holds, a call, the pools it returned (for
+        their ``counters``: that call's own vector, which no later call
+        takes) and whether it was of a decode program. Once a step, inside
+        the ``fetch`` span, after the step's tokens: the programs are done,
+        and the host has waited for none of them between two calls."""
         if not self._hybrid:
             return
-        counters = self.kv_cache.counters if pool is None else pool.counters
-        counted = dict(zip(COUNTERS, (int(v) for v in np.asarray(counters))))
-        for name, n in counted.items():
-            self.stats[name] += n
-        if decode:
-            self.stats["moe_local_pairs_decode"] += counted["moe_local_pairs"]
-            self.stats["moe_experts_hit_decode"] += counted["moe_experts_hit"]
-            self.stats["moe_work_items_decode"] += counted["moe_work_items"]
+        for pools, decode in calls:
+            counted = dict(zip(COUNTERS, (int(v) for v in
+                                          np.asarray(pools["counters"]))))
+            for name, n in counted.items():
+                self.stats[name] += n
+            if decode:
+                for name in ("moe_local_pairs", "moe_experts_hit",
+                             "moe_work_items"):
+                    self.stats[name + "_decode"] += counted[name]
+        pool = self.kv_cache.state_pool
         self.stats["state_slots_in_use"] = pool.slots_in_use if pool else 0
         self.stats["compressed_keys_in_use"] = \
             self.kv_cache.compressed_keys_in_use
@@ -1172,7 +1175,7 @@ class InferenceEngineV2:
         st["padded_rows_" + program] += padded_rows
         st["token_steps_" + program] += token_steps
         if program == "prefill":
-            st["prefill_chunk_calls"] += self._splits_steps
+            st["prefill_chunk_calls"] += 1
         elif program in ("decode", "multi_decode"):
             st["decode_kernel_steps"] += token_steps
             st["burst_steps"] += program == "multi_decode"
@@ -1244,7 +1247,7 @@ class InferenceEngineV2:
         t0 = time.perf_counter()
         # one program a part (_split_by_program); the pools pass from one
         # to the next
-        runs = []
+        runs, counted = [], []
         call_of = {}        # index into ``scheduled`` -> its call of the step
         for part in self._split_by_program(scheduled):
             mine = [scheduled[i] for i in part]
@@ -1263,9 +1266,16 @@ class InferenceEngineV2:
                                         *args)
             # the program consumed (donated) the handle it was given
             self.kv_cache.set_kv_state(new_kv)
-            if runs and self._hybrid:       # before the next call overwrites
-                self._fetch_counters(runs[-1][1] == "decode")
+            counted.append((new_kv, program == "decode"))
             runs.append((part, program, logits, batch))
+        if any(program == "prefill" for _, program, _, _ in runs):
+            # the step's chunks went through the prefill program. No step
+            # of the kernel path is left to the gather program any more:
+            # the share of prompt steps that were reads 0, and goes with
+            # ``prefill_gather_fallbacks`` and that program (ROADMAP.md, D12)
+            self.stats["prefill_kernel_steps"] += 1
+            self._hub.gauge("serve.paged_fallback_ratio", 0.0,
+                            labels=self._metric_labels)
 
         # Sample ON DEVICE and fetch only token ids (greedy) or just the
         # consumed rows (stochastic). Materializing the full [T, V]
@@ -1308,8 +1318,7 @@ class InferenceEngineV2:
                 # step's only call, and the decode program's logits are its
                 # slots' rows as they stand: so no shape of the take is one
                 # that only a step with token rows beside chunks compiles.)
-                if len(runs) == 1 and not (self._splits_steps
-                                           and last_program == "prefill"):
+                if len(runs) == 1 and last_program != "prefill":
                     logits, idx_dev = runs[0][2], jnp.asarray(picks[0])
                 else:
                     logits = None
@@ -1327,10 +1336,10 @@ class InferenceEngineV2:
                     toks_np = np.asarray(self._pick_greedy(logits, idx_dev))
                 else:
                     rows_np = np.asarray(self._take_rows(logits, idx_dev))
-                self._fetch_counters(last_program == "decode")
+                self._fetch_counters(counted)
         elif self._hybrid:
             with span("fetch"):       # no token to read: the counters alone
-                self._fetch_counters(last_program == "decode")
+                self._fetch_counters(counted)
         with span("bookkeep"):
             for slot, seq, program in consumers:
                 if temperature == 0.0:
@@ -1374,15 +1383,11 @@ class InferenceEngineV2:
 
     @property
     def _splits_steps(self) -> bool:
-        """Whether a step is split by program: on the kernel path for a
-        dense model (``model_runner``), always for a model with block-sparse
+        """Whether a step is split by program: wherever the kernel path is
+        on, whatever the runner, and always for a model with block-sparse
         or latent attention (no gather program is built for it). Not where
-        the gather program is the path (``_use_paged_kernel`` off), nor for
-        the hybrid runner of a model that still has a gather program: its
-        prefill program is the Pallas kernel over the whole step, or the
-        step is left to that program (_plan_prefill_segments)."""
-        return self._no_gather or (self._use_paged_kernel
-                                   and not self._hybrid)
+        the gather program is the path (``_use_paged_kernel`` off)."""
+        return self._no_gather or self._use_paged_kernel
 
     def _split_by_program(self, scheduled):
         """The step's work as the lists (of indices into ``scheduled``) one
@@ -1430,48 +1435,19 @@ class InferenceEngineV2:
     def _build_step_call(self, scheduled):
         """Pick the program for this part of a step and assemble its host
         arrays: ``(jitted fn, program name, arguments after params and
-        KV, the ragged batch)``. ``decode`` when every sequence advances one token (tokens
-        line up with slots, so the compact paged-kernel path applies),
-        ``prefill`` when the prefill program takes the chunks,
-        else the flat ``gather`` program."""
+        KV, the ragged batch)``. On the kernel path ``decode`` when every
+        sequence advances one token (tokens line up with slots, so the
+        compact paged-kernel path applies), else ``prefill`` (the part is
+        chunks: _split_by_program); off it the flat ``gather`` program."""
         batch = build_ragged_batch(scheduled, self.max_tokens,
                                    self.max_seqs, self.max_blocks_per_seq)
         slots_arg = self._state_slots_arg([seq for seq, _, _ in scheduled])
-        decode_only = (self._use_paged_kernel
-                       and all(len(nt) == 1 for _, nt, _ in scheduled))
-        seg_plan = None
-        if self._use_paged_kernel and not decode_only:
-            seg_plan = self._plan_prefill_segments(scheduled)
-            if seg_plan is None:
-                self.stats["prefill_gather_fallbacks"] += 1
-                # warn ONCE per reason (vmem/padding), then count
-                # silently: the re-log-every-100 version flooded tier-1
-                # output on CPU runs. Counts stay queryable in
-                # log_summary() / telemetry.get().
-                from deepspeed_tpu.utils import telemetry
-
-                telemetry.count(
-                    "serve.prefill_gather_fallback",
-                    f"{self._last_fallback_reason}: paged prefill fell "
-                    "back to the gather path — flat-layout serve step, "
-                    "no Pallas kernel; see log_summary()")
-            elif not self._splits_steps:
-                self.stats["prefill_kernel_steps"] += 1
-            # fraction of mixed prefill steps that lost the Pallas
-            # kernel to the gather path — per-replica on the Prometheus
-            # page, so a fleet shows WHICH replica degraded, not a blur
-            attempts = (self.stats["prefill_gather_fallbacks"]
-                        + self.stats["prefill_kernel_steps"])
-            self._hub.gauge(
-                "serve.paged_fallback_ratio",
-                self.stats["prefill_gather_fallbacks"] / max(1, attempts),
-                labels=self._metric_labels)
-        if seg_plan is not None:
-            n_segs = seg_plan[0].shape[0]
-            return self._prefill_fn, "prefill", (
-                *seg_plan, jnp.asarray(batch.block_table[:n_segs]),
-                *slots_arg), batch
-        if decode_only:
+        if not self._use_paged_kernel:
+            return self._step_fn, "gather", (
+                jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
+                jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
+                jnp.asarray(batch.num_tokens, jnp.int32), *slots_arg), batch
+        if all(len(nt) == 1 for _, nt, _ in scheduled):
             # compact per-slot arrays: token i belongs to slot i; pad
             # out to max_seqs (token budget may be smaller than the
             # slot budget)
@@ -1484,34 +1460,16 @@ class InferenceEngineV2:
                 jnp.asarray(d_tok), jnp.asarray(d_pos),
                 jnp.asarray(batch.block_table),
                 jnp.asarray(batch.ctx_lens), *slots_arg), batch
-        return self._step_fn, "gather", (
-            jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
-            jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
-            jnp.asarray(batch.num_tokens, jnp.int32), *slots_arg), batch
+        seg_plan = self._plan_prefill_segments(scheduled)
+        n_segs = seg_plan[0].shape[0]
+        return self._prefill_fn, "prefill", (
+            *seg_plan, jnp.asarray(batch.block_table[:n_segs]),
+            *slots_arg), batch
 
     def _plan_prefill_segments(self, scheduled):
         """Per-slot padded chunk layout for the prefill program
-        (_segment_shape). None only where steps are not split by program:
-        the prefill program is then the Pallas kernel over the whole step,
-        so its scratch has to fit and the per-segment padding must not
-        outweigh the flat layout (then the gather program runs)."""
-        lens = [len(nt) for _, nt, _ in scheduled]
-        S, tq = self._segment_shape(lens)
-        if not self._splits_steps:
-            # kernel scratch is (Tq*num_heads) rows of (2*128 + head_dim)
-            # fp32 VMEM; keep it well under the ~16MB/core budget or the
-            # Mosaic compile fails at serve time (gather path has no such
-            # limit); per-shard head count under the tp shard_map
-            scratch_bytes = (tq * (self.cfg.num_heads // self._tp)
-                             * (256 + self.cfg.head_dim) * 4)
-            if scratch_bytes > 4 * 1024 * 1024:
-                self.stats["fallback_reasons"]["vmem"] += 1
-                self._last_fallback_reason = "vmem"
-                return None
-            if not self._pads_within_budget(lens):
-                self.stats["fallback_reasons"]["padding"] += 1
-                self._last_fallback_reason = "padding"
-                return None
+        (_segment_shape): the chunks of one call (_split_by_program)."""
+        S, tq = self._segment_shape([len(nt) for _, nt, _ in scheduled])
         toks = np.zeros((S, tq), np.int32)
         pos0 = np.zeros(S, np.int32)
         nreal = np.zeros(S, np.int32)
@@ -1624,7 +1582,7 @@ class InferenceEngineV2:
             self.kv_cache.set_kv_state(new_kv)
             with span("fetch"):
                 toks_np = np.asarray(toks)  # [K, S]: one fetch per K tokens
-                self._fetch_counters(True)
+                self._fetch_counters([(new_kv, True)])
         with span("bookkeep"):
             emitted: Dict[int, List[int]] = {}
             for i, s in enumerate(live):
@@ -1983,10 +1941,10 @@ class InferenceEngineV2:
 
     def log_summary(self) -> Dict[str, Any]:
         """Serve-path telemetry (the comms-logger log_summary analog):
-        kernel vs gather-fallback step counts, with fallback reasons.
-        A nonzero ``prefill_gather_fallbacks`` means prefill ran the
-        flat gather path — raise max_tokens_per_step or lower
-        max_seqs_per_step/prompt chunking to restore the kernel path."""
+        the calls and rows of each program, kernel-path step counts
+        (``prefill_kernel_steps``: steps whose chunks the prefill program
+        took) and the gather-fallback counts, which no step raises since
+        every runner splits its steps by program."""
         s = dict(self.stats)
         s["fallback_reasons"] = dict(self.stats["fallback_reasons"])
         s["preempt_reasons"] = dict(self.stats["preempt_reasons"])

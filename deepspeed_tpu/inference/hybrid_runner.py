@@ -12,9 +12,11 @@ do not tell the models apart. What differs:
   one layer a full layer), ``state`` and ``conv`` (the recurrent-state
   pool, ``inference/ragged/state_pool.py``) and, for a model with the
   block-sparse rule, ``ck`` (the compressed keys, page-addressed beside
-  ``kv``: ``inference/ragged/kv_cache.py``) — plus ``counters``; all are
-  carried through the layer loops and updated in place (the kernels read the
-  pools whole, by layer and page or slot);
+  ``kv``: ``inference/ragged/kv_cache.py``); all are carried through the
+  layer loops and updated in place (the kernels read the pools whole, by
+  layer and page or slot). The dict that comes back holds one key more,
+  ``counters``: the call's own vector, a new array each call and no
+  argument of the next, so the engine can keep it while the pools go on;
 * one more trailing argument, ``state_slots [S]``: the state-pool slot of the
   sequence in each batch slot (the scratch slot for an empty one);
 * the layer loop is a scan over the repeats of the layer pattern with a scan
@@ -26,7 +28,9 @@ do not tell the models apart. What differs:
 * with the block-sparse rule a full layer's token step selects pages
   (``sparse_select``) and runs the paged decode kernel over the chosen ones
   (``sparse_attn``); a chunk attends over its own sequence's pages under the
-  block mask; the gather program is not built;
+  block mask; the gather program is not built. Without the rule a chunk
+  attends over its own sequence's pages as plain products, the dense
+  runner's ``model_runner._segment_attention``;
 * with latent attention (``attention_kind`` "mla") the paged pool is a latent
   pool (one vector a token) and there is no recurrent layer, state pool or
   ``state_slots``: a token step writes the token's latent and runs the
@@ -54,8 +58,8 @@ from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.inference.model_runner import (_kv_write, _paged_decode,
-                                                  _paged_prefill)
-from deepspeed_tpu.inference.ragged.state_pool import (MOE_COUNTERS,
+                                                  _segment_attention)
+from deepspeed_tpu.inference.ragged.state_pool import (COUNTERS, MOE_COUNTERS,
                                                        SPARSE_COUNTERS)
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.hybrid import HybridConfig
@@ -68,6 +72,12 @@ from deepspeed_tpu.runtime.sharding import effective_dtype
 
 _MOE = len(MOE_COUNTERS)    # where the expert blocks' counters end
 _SPARSE = _MOE + len(SPARSE_COUNTERS)   # and the sparse rule's; then latent
+
+
+def _no_counts():
+    """A call's ``counters`` before it has counted: every program starts
+    from this and hands its own vector out, so none is taken in."""
+    return jnp.zeros((len(COUNTERS),), jnp.int32)
 
 
 def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
@@ -138,7 +148,7 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
             first[full] += n
         return carry, None
 
-    carry = (x, dict(pools, counters=jnp.zeros_like(pools["counters"])))
+    carry = (x, dict(pools, counters=_no_counts()))
     seen = {True: 0, False: 0}
     for l in range(K):      # the prologue: dense layers, outside the scan
         full = cfg.layer_kinds[l]
@@ -479,9 +489,11 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
 def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
                            seg_pos0, seg_nreal, block_table, state_slots=None,
                            *, mesh=None) -> Tuple[jax.Array, Dict]:
-    """Prefill chunks, one segment a sequence slot; attention through the
-    paged prefill kernel or, with the sparse rule, over each segment's own
-    pages under its block mask. Returns (logits [S, Tq, V] float32, pools')."""
+    """Prefill chunks, one segment a sequence slot; attention over each
+    segment's own pages: plain products a KV head
+    (``model_runner._segment_attention``) or, with the sparse rule, under
+    its block mask. (``mesh`` is the step programs' common keyword.)
+    Returns (logits [S, Tq, V] float32, pools')."""
     S, Tq = seg_tokens.shape
     bs = pools["kv"].shape[2]
     dt = effective_dtype(cfg.dtype)
@@ -516,8 +528,8 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
         kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
         pools = dict(pools, kv=kv)
         if sz is None:
-            a = _paged_prefill(mesh, q.astype(dt), kv, l_kv, block_table,
-                               seg_pos0, ctx_lens)
+            a = _segment_attention(cfg, q.astype(dt), kv, None, l_kv,
+                                   block_table, pos)
         else:
             ck = _compress_new(sz, kv, pools["ck"], l_kv, block_table,
                                seg_pos0, seg_nreal, Tq // sz.stride + 1)
@@ -618,6 +630,6 @@ def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
         return (pools, nxt, pos + 1, jnp.where(alive, ctx + 1, 0), counts), nxt
 
     (pools, _, _, _, counts), toks = lax.scan(
-        body, (pools, token_ids, token_pos, context_lens,
-               jnp.zeros_like(pools["counters"])), length=steps)
+        body, (dict(pools, counters=_no_counts()), token_ids, token_pos,
+               context_lens, _no_counts()), length=steps)
     return toks, dict(pools, counters=counts)

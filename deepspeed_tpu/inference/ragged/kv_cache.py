@@ -169,9 +169,10 @@ class BlockedKVCache:
                  config.compressed_per_block, config.kv_heads,
                  config.head_dim), config.dtype)
         shape = config.pool_shape
-        # what the last step program of a hybrid stack counted, where no
-        # recurrent-state pool carries it (a stack without recurrent layers)
-        self.counters = None
+        # a hybrid stack's step programs take the pools as a dict, also
+        # where no recurrent-state pool stands beside this one (a stack
+        # without recurrent layers): the engine says so
+        self.pools_as_dict = False
         quantized = config.quant_bits is not None
         # int4 packs nibbles into uint8 (the runner infers the width from
         # the pool dtype at trace time: int8 → 8, uint8 → 4, e4m3 → fp8)
@@ -207,16 +208,17 @@ class BlockedKVCache:
         (payload, fp32 scales) pair when ``quant_bits`` is set (int8
         payload, or packed-nibble uint8 for 4-bit storage). With a
         recurrent-state pool attached: the dict of both pools
-        (``inference/hybrid_runner.py``)."""
+        (``inference/hybrid_runner.py``). A hybrid stack's ``counters`` are
+        no part of it: a step program hands its own vector out and takes
+        none in, so the last call's is never donated to the next."""
         if self.state_pool is not None:
             sp = self.state_pool
-            state = {"kv": self.data, "state": sp.state, "conv": sp.conv,
-                     "counters": sp.counters}
+            state = {"kv": self.data, "state": sp.state, "conv": sp.conv}
             if self.compressed is not None:
                 state["ck"] = self.compressed
             return state
-        if self.counters is not None:
-            return {"kv": self.data, "counters": self.counters}
+        if self.pools_as_dict:
+            return {"kv": self.data}
         if self.scales is None:
             return self.data
         return (self.data, self.scales)
@@ -228,11 +230,11 @@ class BlockedKVCache:
         ``data`` / ``kv_state`` afresh, never keep one across a step."""
         if self.state_pool is not None:
             sp = self.state_pool
-            self.data, sp.state, sp.conv, sp.counters = (
-                state["kv"], state["state"], state["conv"], state["counters"])
+            self.data, sp.state, sp.conv = (
+                state["kv"], state["state"], state["conv"])
             self.compressed = state.get("ck")
-        elif self.counters is not None:
-            self.data, self.counters = state["kv"], state["counters"]
+        elif self.pools_as_dict:
+            self.data = state["kv"]
         elif self.scales is None:
             self.data = state
         else:

@@ -82,8 +82,6 @@ class RecurrentStatePool:
                                 c.value_dim), jnp.float32)
         self.conv = jnp.zeros((c.layers, c.slots + 1, c.conv_taps - 1,
                                c.conv_channels), c.dtype)
-        # what the last step program counted (hybrid_runner's ``counters``)
-        self.counters = jnp.zeros((len(COUNTERS),), jnp.int32)
         self._free: List[int] = list(range(c.slots - 1, -1, -1))
 
     @property

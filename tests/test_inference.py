@@ -205,11 +205,11 @@ class TestInferenceEngineV2:
         assert run(True) == run(False)
 
     def test_prefill_fallback_telemetry(self):
-        """When the padded-segment plan trips its blowup heuristic the
-        serve silently used to drop to the gather path; the stats counter
-        must record it (VERDICT r2 weak #6). The refusal is left to the
-        hybrid runner of a model that has a gather program: its prefill
-        program takes the step whole."""
+        """A step whose one padded layout would outweigh the flat one used
+        to be left to the gather program by the hybrid runner of a model
+        that has one, and the stats counter recorded it (VERDICT r2 weak
+        #6). That runner splits its steps by program like every other: no
+        step of the kernel path falls back, and the counters say so."""
         from deepspeed_tpu.inference import InferenceEngineV2
         from deepspeed_tpu.models.zoo import get_model
         model = get_model("tiny-hybrid", param_dtype=jnp.float32,
@@ -220,8 +220,9 @@ class TestInferenceEngineV2:
             max_blocks_per_seq=8, state_slots=4,
             max_tokens_per_step=24, max_seqs_per_step=4)
         tq = v2._min_segment
-        # 4 sequences, one long chunk: tq stays at the mixers' chunk, S
-        # buckets to 4 — S*tq > 2*max_tokens = 48 → padding-blowup fallback
+        # 4 sequences, one long chunk: as one plan tq stays at the mixers'
+        # chunk and S buckets to 4 — S*tq > 2*max_tokens = 48, the old
+        # padding refusal
         assert 4 * tq > 48
         prompts = {1: [2] * 9, 2: [3], 3: [4], 4: [5]}
         try:
@@ -229,21 +230,23 @@ class TestInferenceEngineV2:
                                    for p in prompts.values()],
                    max_new_tokens=2)
             v2.step()
-            assert v2.stats["prefill_gather_fallbacks"] >= 1
-            assert v2.stats["fallback_reasons"]["padding"] >= 1
-            assert v2.stats["tokens_gather"] == 4
             summary = v2.log_summary()
-            assert summary["prefill_gather_fallbacks"] >= 1
-            # one call a step, of the gather program
-            assert summary["calls_gather"] == 1 == summary[
-                "steps_dispatched"]
-            assert summary["calls_prefill"] == 0 == summary[
-                "prefill_chunk_calls"]
-            # every prompt step so far lost the kernel: the gauge reads 1
-            assert v2._hub.gauges["serve.paged_fallback_ratio"] == 1.0
-            # kernel-path steps still count once prefill is done
+            assert summary["prefill_gather_fallbacks"] == 0
+            assert summary["fallback_reasons"] == {"vmem": 0, "padding": 0}
+            assert summary["tokens_gather"] == 0 == summary["calls_gather"]
+            # the three token rows one call, the chunk another (1 x tq)
+            assert summary["steps_dispatched"] == 1
+            assert (summary["calls_decode"], summary["calls_prefill"],
+                    summary["prefill_chunk_calls"]) == (1, 1, 1)
+            assert summary["padded_rows_prefill"] == tq
+            assert (summary["tokens_decode"],
+                    summary["tokens_prefill_kernel"]) == (3, 1)
+            assert summary["prefill_kernel_steps"] == 1
+            # no prompt step lost the kernel path: the gauge reads 0
+            assert v2._hub.gauges["serve.paged_fallback_ratio"] == 0.0
             v2.generate_all()
             assert v2.stats["decode_kernel_steps"] >= 1
+            assert v2.stats["calls_gather"] == 0
         finally:
             v2.close()
 
@@ -262,7 +265,7 @@ class TestInferenceEngineV2:
         assert summary["steps_dispatched"] == 1
         assert (summary["calls_decode"], summary["calls_prefill"]) == (1, 1)
         assert summary["prefill_chunk_calls"] == 1
-        assert summary["prefill_kernel_steps"] == 0
+        assert summary["prefill_kernel_steps"] == 1
         assert summary["prefill_gather_fallbacks"] == 0
         assert summary["fallback_reasons"] == {"vmem": 0, "padding": 0}
         assert summary["tokens_decode"] == 3

@@ -406,7 +406,8 @@ def test_engine_serving_tree_and_donation():
     assert eng.params["experts"]["wi"].shape[0] == 2
     assert eng.params["mla"]["wo"].shape[0] == 3
     assert eng.kv_cache.state_pool is None
-    assert set(eng.kv_cache.kv_state) == {"kv", "counters"}
+    # (the programs hand their counters out and take none in)
+    assert set(eng.kv_cache.kv_state) == {"kv"}
     eng.put([1], [np.arange(20, dtype=np.int32)], max_new_tokens=3)
     toks = list(eng.generate_all()[1])
     assert len(toks) == 3 and toks == _greedy_by_apply(

@@ -117,12 +117,15 @@ def _grouped_matmul_calls(jaxpr, under_cond=False):
     return calls, conds
 
 
-@pytest.mark.parametrize("program", ["gather", "decode", "multi_decode"])
+@pytest.mark.parametrize("program", ["gather", "decode", "multi_decode",
+                                     "prefill"])
 def test_hybrid_programs_hold_one_grouped_product_a_call_site(program):
     """Three products an expert block, one block a run of one kind of layer
     (``stack_plan``): that many kernels in the program and no conditional
     around any. The tile is a function of the static shapes, so a program
-    holds no variant of the kernel to choose from while it runs."""
+    holds no variant of the kernel to choose from while it runs. The
+    prefill program's chunk attention is plain products over each
+    segment's own pages: no ``paged_prefill`` kernel in it."""
     model = get_model("tiny-hybrid")
     cfg = model.config
     params = jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
@@ -140,12 +143,58 @@ def test_hybrid_programs_hold_one_grouped_product_a_call_site(program):
     if program == "gather":
         traced = fns["step"].trace(params, pools, ids(T), ids(T), ids(T),
                                    ids(S, BM), ids(), ids(S))
+    elif program == "prefill":
+        traced = fns["prefill"].trace(params, pools, ids(2, 64), ids(2),
+                                      ids(2), ids(2, BM), ids(S))
+        assert _module(traced.lower()) == "jit_dstpu_serve_prefill"
+        kernels = set(re.findall(r"\bname=(\w+)", str(traced.jaxpr)))
+        assert "paged_prefill" not in kernels and "grouped_matmul" in kernels
     else:
         steps = {"steps": 3} if program == "multi_decode" else {}
         traced = fns[program].trace(params, pools, ids(S), ids(S), ids(S, BM),
                                     ids(S), ids(S), **steps)
     calls, under_cond = _grouped_matmul_calls(traced.jaxpr.jaxpr)
     assert calls == 3 * len(cfg.stack_plan[1]) and under_cond == 0
+
+
+def test_a_start_of_a_recurrent_model_builds_no_gather_program():
+    """A model with recurrent layers that has a gather program
+    (``tiny-hybrid``), through the benchmark's ``warm_up`` at a toy budget
+    (64 tokens and 4 sequences a step, bursts of 1 and 2): every step is
+    split by program, so the gather program is never traced, and the
+    prefill program is built for the ``(S, tq)`` layouts twice the budget
+    admits and no other (chunks bucket from the recurrence's 64)."""
+    from benchmarks.generators.requests import Served
+    from benchmarks.runners import serve
+
+    model = get_model("tiny-hybrid", param_dtype=F32, dtype=F32)
+    budget = {"kv_blocks": 64, "kv_block_size": 16, "max_tokens_per_step": 64,
+              "max_seqs_per_step": 4, "max_blocks_per_seq": 8,
+              "state_slots": 8}
+    eng = engine_v2.InferenceEngineV2(
+        model, params=model.init(jax.random.PRNGKey(0)), dtype=F32, **budget)
+    dispatch, shapes = eng._dispatch, set()
+
+    def recorded(program, *args, **shape):
+        if program == "prefill":
+            shapes.add((shape["S"], shape["tq"]))
+        return dispatch(program, *args, **shape)
+
+    eng._dispatch = recorded
+    serve.warm_up(Served(eng), {"engine": budget, "decode_steps": 2},
+                  model.config.vocab_size)
+    assert shapes == {(1, 64), (2, 64)}
+    assert all(S * tq <= 2 * 64 for S, tq in shapes)
+    assert eng._step_fn._cache_size() == 0          # jit_dstpu_serve_gather
+    # (one entry more for the engine's first call, which takes the pools
+    # as they were made: not yet committed to their device)
+    assert eng._prefill_fn._cache_size() == len(shapes) + 1
+    assert eng._decode_fn._cache_size() == 1
+    st = eng.stats
+    assert st["calls_gather"] == 0 == st["prefill_gather_fallbacks"]
+    assert st["calls_prefill"] == st["prefill_chunk_calls"] > 0
+    assert st["calls_multi_decode"] > 0
+    eng.close()
 
 
 @pytest.mark.parametrize("program,scopes", [

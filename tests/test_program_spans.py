@@ -161,7 +161,8 @@ EXPECTED = {"tokens_gather": 0, "tokens_multi_decode": 24,
             "tokens_decode": 3 + 1, "tokens_prefill_kernel": 3 + 1,
             "prefill_chunks": 3 + 2, "first_tokens": 4, "admitted": 4,
             "prefill_chunk_calls": 2 + 2,
-            "prefill_kernel_steps": 0, "prefill_gather_fallbacks": 0,
+            # steps whose chunks the prefill program took: A's one, B's two
+            "prefill_kernel_steps": 1 + 2, "prefill_gather_fallbacks": 0,
             # what each program was given, a call at a time: A's two chunk
             # calls carry 25 rows as 2 x 32 and 5 as 1 x 8, B's 32 as
             # 1 x 32 and 8 as 1 x 8; the burst 8 x 3 rows as 8 x 4 slots;
@@ -415,15 +416,21 @@ def test_the_calls_of_a_split_step_say_their_place_and_what_they_carry(
     engine.close()
 
 
-def test_a_hybrid_gather_step_counts_the_runners_layout(devices, tmp_path):
-    """The hybrid runner's gather program lays the step's flat tokens out
-    anew, ``max_seqs`` rows of ``max_tokens``, for the chunked recurrence:
-    that, and not the flat budget, is what a call computes."""
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "split"])
+def test_a_hybrid_step_counts_the_runners_layout(devices, tmp_path, kernel):
+    """The hybrid runner's gather program (the path with
+    ``_use_paged_kernel`` off) lays the step's flat tokens out anew,
+    ``max_seqs`` rows of ``max_tokens``, for the chunked recurrence: that,
+    and not the flat budget, is what a call computes. On the kernel path
+    the same steps are split by program: a chunk's call computes its own
+    rows, bucketed from the recurrence's 64 (one sequence a call here:
+    two of 64 rows would pass twice the budget of 32)."""
     from deepspeed_tpu.inference import hybrid_runner, model_runner
 
     assert model_runner.gather_rows_computed(4, 32) == 32
     assert hybrid_runner.gather_rows_computed(4, 32) == 4 * 32
     engine = _hybrid_engine()
+    engine._use_paged_kernel = kernel
 
     def run():
         engine.put([1, 2, 3], [_prompt(3, i) for i in (1, 2, 3)],
@@ -433,17 +440,36 @@ def test_a_hybrid_gather_step_counts_the_runners_layout(devices, tmp_path):
         engine.serve_step()
 
     _, spans = capture(tmp_path, run)
-    assert _dispatches(spans) == [
-        dict(program="gather", call=0, seqs=3, tokens=9, padded_rows=128,
-             token_steps=1, chunks=3),
-        dict(program="gather", call=0, seqs=4, tokens=23, padded_rows=128,
-             token_steps=1, chunks=1)]
     st = engine.stats
-    assert (st["calls_gather"], st["rows_gather"], st["padded_rows_gather"],
-            st["token_steps_gather"]) == (2, 32, 256, 2)
-    assert st["prefill_gather_fallbacks"] == 2 == st["steps_dispatched"]
-    assert (st["first_tokens"], st["first_token_calls"],
-            st["first_token_own_calls"]) == (4, 4, 4)
+    if kernel:
+        chunk = dict(program="prefill", seqs=1, padded_rows=64,
+                     token_steps=1, chunks=1, S=1, tq=64)
+        assert _dispatches(spans) == [
+            dict(chunk, call=0, tokens=3), dict(chunk, call=1, tokens=3),
+            dict(chunk, call=2, tokens=3),
+            dict(program="decode", call=0, seqs=3, tokens=3, padded_rows=4,
+                 token_steps=1, chunks=0),
+            dict(chunk, call=1, tokens=20)]
+        assert (st["calls_prefill"], st["rows_prefill"],
+                st["padded_rows_prefill"], st["prefill_chunk_calls"],
+                st["prefill_kernel_steps"]) == (4, 29, 256, 4, 2)
+        assert st["calls_gather"] == 0 == st["tokens_gather"]
+        assert (st["first_tokens"], st["first_token_calls"],
+                st["first_token_own_calls"]) == (4, 3 * 3 + 2, 4)
+    else:
+        assert _dispatches(spans) == [
+            dict(program="gather", call=0, seqs=3, tokens=9, padded_rows=128,
+                 token_steps=1, chunks=3),
+            dict(program="gather", call=0, seqs=4, tokens=23,
+                 padded_rows=128, token_steps=1, chunks=1)]
+        assert (st["calls_gather"], st["rows_gather"],
+                st["padded_rows_gather"], st["token_steps_gather"]) == (
+                    2, 32, 256, 2)
+        assert st["calls_prefill"] == 0 == st["prefill_kernel_steps"]
+        assert (st["first_tokens"], st["first_token_calls"],
+                st["first_token_own_calls"]) == (4, 4, 4)
+    assert st["prefill_gather_fallbacks"] == 0
+    assert st["steps_dispatched"] == 2
     engine.close()
 
 

@@ -1,14 +1,19 @@
-"""A dense model's step, split by program, against the gather program.
+"""A step split by program, against the gather program: a dense model's and
+that of a recurrent model that has a gather program (``tiny-hybrid``: gated
+DeltaNet, full attention and experts).
 
 On the kernel path ``InferenceEngineV2._split_by_program`` sends the rows
 that advance one token through the decode program and the chunks through
 the prefill program over their own sequences' pages (plain products a
-segment, ``model_runner._segment_attention``), several chunks a call while
-the call's padded layout stays within twice the step's budget. Here, on
-the CPU with an interpreter decode kernel, the same schedule runs both ways
-(``_use_paged_kernel`` flipped, as ``tests/test_kv_in_place.py`` does): the
-same tokens come out, and the logits rows they were picked from agree.
+segment, ``model_runner._segment_attention``, for both runners), several
+chunks a call while the call's padded layout stays within twice the step's
+budget. Here, on the CPU with interpreter kernels, the same schedule runs
+both ways (``_use_paged_kernel`` flipped, as ``tests/test_kv_in_place.py``
+does): the same tokens come out, and the logits rows they were picked from
+agree.
 """
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +23,7 @@ import pytest
 from benchmarks.runners.serve import LogitsTap
 from deepspeed_tpu.inference import engine_v2
 from deepspeed_tpu.inference.engine_v2 import PROGRAMS
+from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
 from deepspeed_tpu.models.zoo import get_model
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -195,7 +201,8 @@ def test_split_step_matches_the_gather_program(name):
                            for rows, _, _ in split.log)
     assert steps_with_chunk == 10
     assert st["prefill_chunk_calls"] == steps_with_chunk + 1
-    assert st["prefill_gather_fallbacks"] == 0 == st["prefill_kernel_steps"]
+    assert st["prefill_gather_fallbacks"] == 0
+    assert st["prefill_kernel_steps"] == steps_with_chunk
     # more than one call: token rows beside a chunk (twice), and those two
     assert _calls_beyond_one_a_step(st) == 3 == 1 + sum(
         len({n == 1 for _, n, _ in rows}) == 2 for rows, _, _ in split.log)
@@ -312,3 +319,188 @@ def test_speculation_still_verifies_through_the_gather_program():
     # the prompt itself went through the prefill program in both
     assert st["prefill_chunk_calls"] == plain.stats["prefill_chunk_calls"] == 1
     spec.close(), plain.close()
+
+
+# -- a recurrent model that has a gather program -----------------------------
+
+# chunks bucket from the recurrence's 64: a call may hold 4 x 64 or 2 x 128
+HYBRID = dict(kv_blocks=128, kv_block_size=16, max_tokens_per_step=128,
+              max_seqs_per_step=8, max_blocks_per_seq=16, state_slots=8)
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    model = get_model("tiny-hybrid", dtype=F32, param_dtype=F32)
+    return model, model.init(jax.random.PRNGKey(3))
+
+
+def _pools(eng):
+    """Each live sequence's recurrent state and convolution tail, by uid."""
+    pool = eng.kv_cache.state_pool
+    state, conv = np.array(pool.state), np.array(pool.conv)
+    return {uid: (state[:, s.state_slot], conv[:, s.state_slot])
+            for uid, s in eng.state.seqs.items()}
+
+
+def _hybrid_script(chunks):
+    """Three sequences decoding, then ``chunks`` arrive in one step beside
+    them; the pools are compared right after that step."""
+    def script(d):
+        d.put([1, 2, 3], [_prompt(n, n) for n in (5, 70, 17)], 12)
+        d.step(3)
+        d.put(range(10, 10 + len(chunks)),
+              [_prompt(n, 40 + i) for i, n in enumerate(chunks)], 4)
+        dispatch, d.shapes = d.eng._dispatch, []
+
+        def recorded(program, *args, **shape):
+            if program == "prefill":
+                d.shapes.append((shape["S"], shape["tq"]))
+            return dispatch(program, *args, **shape)
+
+        d.eng._dispatch = recorded
+        d.step()
+        d.eng._dispatch = dispatch
+        assert sorted(n for _, n, _ in d.log[-1][0]) == sorted(
+            [1, 1, 1] + chunks)
+        d.pools = _pools(d.eng)
+        d.finish()
+    return script
+
+
+@pytest.mark.parametrize("chunks,shapes", [
+    ([20], [(1, 64)]),                      # a chunk beside token rows
+    ([100], [(1, 128)]),
+    ([30, 64], [(2, 64)]),                  # two chunks a call
+    ([9, 33, 5, 64], [(4, 64)]),            # four
+    ([70, 30], [(2, 128)]),
+    # 4 x 128 rows would not fit (the scheduler's scan of the waiting
+    # prompts starts at the second arrival: 9, 9, 70)
+    ([70, 9, 9], [(2, 64), (1, 128)]),
+])
+def test_a_recurrent_models_step_is_split_like_a_dense_ones(hybrid, chunks,
+                                                            shapes):
+    """The decode program for the token rows, the prefill program for the
+    chunks over their own rows: the same tokens and rows as the gather
+    program gives, and after the mixed step every sequence's recurrent
+    state and convolution tail are the gather path's."""
+    split, gather = _both(*hybrid, _hybrid_script(chunks), **HYBRID)
+    for uid, (state, conv) in gather.pools.items():
+        np.testing.assert_allclose(split.pools[uid][0], state, atol=1e-4)
+        np.testing.assert_allclose(split.pools[uid][1], conv, atol=1e-4)
+    assert len(gather.pools) == 3 + len(chunks)
+    st = split.eng.stats
+    assert st["calls_gather"] == 0 == st["prefill_gather_fallbacks"]
+    assert st["calls_prefill"] == st["prefill_chunk_calls"]
+    # the first step's three prompts (5, 70, 17: 4 x 128 rows, two calls)
+    # and then the arrivals' calls, each within twice the step's budget
+    assert split.shapes == shapes and gather.shapes == []
+    assert all(S * tq <= 2 * HYBRID["max_tokens_per_step"]
+               for S, tq in shapes)
+    assert st["calls_prefill"] == 2 + len(shapes)
+    assert st["padded_rows_prefill"] == 2 * 128 + 64 + sum(
+        S * tq for S, tq in shapes)
+    assert st["rows_prefill"] == 5 + 70 + 17 + sum(chunks)
+    assert gather.eng.stats["calls_prefill"] == 0
+    _same(split, gather, 1e-4)
+
+
+def test_a_recurrent_models_split_streams_are_the_full_forwards(hybrid):
+    """Token streams of the split engine, a prompt over the step's budget
+    and several a step among them, against ``argmax`` of the model's own
+    forward over prompt and answer (one padded batch: the model is causal,
+    what follows a sequence's end does not reach back)."""
+    model, params = hybrid
+    eng = _engine(model, params, True, **HYBRID)
+    prompts = {1: _prompt(150, 1), 2: _prompt(64, 2), 3: _prompt(7, 3)}
+    eng.put(list(prompts), list(prompts.values()), max_new_tokens=6)
+    out = eng.generate_all()
+    seqs = np.zeros((len(prompts), 150 + 5), np.int32)
+    for row, (uid, prompt) in enumerate(prompts.items()):
+        seqs[row, :len(prompt) + 5] = np.concatenate([prompt, out[uid][:-1]])
+    logits = np.asarray(model.apply(params, jnp.asarray(seqs)))
+    for row, (uid, prompt) in enumerate(prompts.items()):
+        want = np.argmax(logits[row, len(prompt) - 1:len(prompt) + 5], -1)
+        assert out[uid] == want.tolist(), uid
+    st = eng.stats
+    assert st["calls_gather"] == 0 == st["tokens_gather"]
+    # 128 of the long prompt (with the 7: 2 x 128), then its 22 beside
+    # the 64 (2 x 64): two steps, a call each
+    assert (st["prefill_kernel_steps"], st["calls_prefill"]) == (2, 2)
+    eng.close()
+
+
+def test_each_calls_counters_are_booked_once_under_its_own_program(
+        hybrid, monkeypatch):
+    """Steps of one, two and three program calls: what each call counted
+    (read here as the call returns, which the engine must not do) is added
+    to ``stats`` once, a decode call's also under ``_decode``, and the
+    engine reads them once a step, inside ``fetch``, with no program call
+    after it in the step."""
+    model, params = hybrid
+    eng = _engine(model, params, True, **HYBRID)
+    open_spans, events, per_call = [], [], []
+
+    real_span = engine_v2.span
+
+    @contextlib.contextmanager
+    def span(name, **ids):
+        open_spans.append(name)
+        try:
+            with real_span(name, **ids):
+                yield
+        finally:
+            open_spans.pop()
+
+    monkeypatch.setattr(engine_v2, "span", span)
+
+    def counting(program):
+        fn = getattr(eng, f"_{program}_fn")
+
+        def call(*args, **kw):
+            out = fn(*args, **kw)
+            per_call.append((program, np.array(out[1]["counters"])))
+            events.append(program)
+            return out
+        setattr(eng, f"_{program}_fn", call)
+
+    counting("decode"), counting("prefill")
+    fetch = eng._fetch_counters
+
+    def fetching(calls):
+        events.append(("fetch", tuple(open_spans), len(calls)))
+        return fetch(calls)
+
+    eng._fetch_counters = fetching
+
+    def step():
+        events.clear()
+        eng.step()
+        return list(events)
+
+    eng.put([1, 2, 3], [_prompt(n, n) for n in (5, 30, 17)], 12)
+    one = step()                                    # three chunks, one call
+    assert one == ["prefill", ("fetch", ("serve_step", "fetch"), 1)]
+    assert step()[0] == "decode"
+    eng.put([4], [_prompt(40, 4)], 4)
+    two = step()                                    # token rows and a chunk
+    assert two == ["decode", "prefill",
+                   ("fetch", ("serve_step", "fetch"), 2)]
+    eng.put([5, 6, 7], [_prompt(n, n) for n in (70, 9, 9)], 4)
+    three = step()                                  # and chunks of two calls
+    assert three == ["decode", "prefill", "prefill",
+                     ("fetch", ("serve_step", "fetch"), 3)]
+    eng.generate_all()
+
+    names = list(COUNTERS)
+    total = sum(v for _, v in per_call)
+    of_decode = sum(v for program, v in per_call if program == "decode")
+    assert {n: eng.stats[n] for n in names} == dict(zip(names,
+                                                        total.tolist()))
+    for n in ("moe_local_pairs", "moe_experts_hit", "moe_work_items"):
+        assert eng.stats[n + "_decode"] == of_decode[names.index(n)] > 0
+        assert eng.stats[n] > eng.stats[n + "_decode"]
+    # a decode call's three rows hit fewer experts than a chunk's forty
+    hit = names.index("moe_experts_hit")
+    chunk_hits = [v[hit] for program, v in per_call if program == "prefill"]
+    assert max(v[hit] for p, v in per_call if p == "decode") < min(chunk_hits)
+    eng.close()

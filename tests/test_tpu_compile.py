@@ -431,6 +431,50 @@ def test_paged_decode_at_head_size_256_group_8(chip):
              ((32, 64), jnp.int32), ((32,), jnp.int32))
 
 
+@pytest.mark.parametrize("segs,tq", [(1, 256), (8, 64)])
+def test_qnext_chunk_program_keeps_both_pools_in_place(chip, segs, tq):
+    """``qwen3-next-80b-a3b-serve-c1``'s prefill program at the cell's
+    shapes (8 layers, 128 held experts, 2,080 pages of 16 tokens for the 2
+    full layers, 48 state slots and the scratch slot for the 6 recurrent
+    ones), a lone chunk of 256 rows and eight of 64, the two ends of what a
+    split step hands it (``S x tq <= 2 x 256``): the paged pool, the state
+    pool and the convolution tails come back in their buffers, the chunk
+    attention is plain products (no Mosaic call named ``paged_prefill``,
+    only the grouped products), and the temporaries stay under half a GiB
+    beside 7.5 GiB of arguments (a scratch compile read 0.11 and 0.26 GiB;
+    the gather program this replaces on the kernel path held 1.75)."""
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.zoo import get_model
+
+    model = get_model("qwen3-next-80b-a3b", num_layers=8, max_seq_len=1024,
+                      param_dtype=BF16, remat=False, vocab_size=37984,
+                      experts_held=128, expert_offset=0)
+    cfg = model.config
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                       jax.eval_shape(model.init, jax.random.PRNGKey(0))))
+    pools = {"kv": sds((2, 2080, 16, 2, cfg.kv_heads, cfg.head_dim), BF16),
+             "state": sds((6, 49, 32, 128, 128), jnp.float32),
+             "conv": sds((6, 49, 3, cfg.conv_channels), BF16)}
+    held = sum(x.size * x.dtype.itemsize for x in pools.values())
+    ids = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    compiled = engine_v2._shared_step_fns(cfg, None)["prefill"].lower(
+        params, pools, ids(segs, tq), ids(segs), ids(segs), ids(segs, 64),
+        ids(32)).compile()
+    text = compiled.as_text()
+    assert "grouped_matmul" in text and "paged_prefill" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 2**29
+    assert 7.4 < mem.argument_size_in_bytes / 2**30 < 7.7
+
+
 # the third architecture at its published widths and the cell's shapes
 # (MiniCPM-SALA, layers 9-16: 32 lightning heads of 128 x 128; 2 KV heads of
 # 128 in 64-token pages; 16,384 pages, 512 a sequence, 32 sequences)
