@@ -89,8 +89,16 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
     and, inside, each run of one kind scanned (a lone layer called), so a
     program holds one layer body a run whatever the depth. ``pools`` is the
     carry, ``pools["counters"]`` what this call counted. Returns (x, pools')."""
+    if any(cfg.layer_windows):
+        # (its pages would have to be given back as the window passes them)
+        raise NotImplementedError(
+            "windowed attention is not served yet: the paged cache has no "
+            "layer kind that frees pages behind a sliding window")
+    if cfg.post_norms:
+        raise NotImplementedError(
+            "post-branch norms are not served yet")
     reps, runs = cfg.stack_plan
-    K = cfg.first_k_dense if cfg.num_experts else 0
+    K = cfg.dense_layers
     per = (cfg.num_layers - K) // reps
     in_period = {True: sum(n for full, n in runs if full)}
     in_period[False] = per - in_period[True]

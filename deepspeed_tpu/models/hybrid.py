@@ -4,10 +4,15 @@
 layer: ``attention_kind`` "mla", no recurrent layer; a prologue of
 ``first_k_dense`` dense layers before the expert layers; sigmoid routing).
 
-The stack is not one scanned block. Each layer is **recurrent** or **full**
-(:attr:`HybridConfig.layer_kinds`: Qwen3-Next's "every
+The stack is not one scanned block. Each layer is **recurrent**, **full** or
+**windowed** (:attr:`HybridConfig.layer_kinds`: Qwen3-Next's "every
 ``full_attention_interval``-th layer is full", or a published list of mixers
-cut to the layers held here). A recurrent layer is gated DeltaNet or lightning
+cut to the layers held here). A windowed layer ("w") is the full layer's
+gated softmax attention under a ``sliding_window`` (key j visible to query i
+iff 0 <= i - j < window), with its own share of rotary dims
+(``window_rotary_factor``), so a stack can rotate its windowed layers and
+leave its full layers without positions. ``post_norms`` adds a norm after
+each branch (four a layer). A recurrent layer is gated DeltaNet or lightning
 attention (``recurrent_kind``; ``ops/pallas/gated_delta.py``); a full layer is
 gated softmax attention with QK-norm, rotary on a part of each head (none of
 it at ``partial_rotary_factor`` 0) and, with ``sparse_topk``, the block-sparse
@@ -35,11 +40,26 @@ first_k_dense, ...]``; the other per-layer leaves keep a slot a layer).
 
 ``experts_held`` / ``expert_offset`` give the chip's share of the routed
 experts (None: all of them); the router always has ``num_experts`` outputs.
+With ``experts_apart`` the stacked tree itself keeps ``experts`` at the top,
+over the expert layers alone (a training state then holds no dead expert
+slot for a dense layer).
+
+Training (``loss_fn`` through ``runtime/engine.py``): each layer under the
+engine's checkpoint policy, softmax attention through
+``ops/attention.py::multi_head_attention`` (the flash kernel on a chip, its
+band grid under a window), the experts through the differentiable grouped
+product on a row buffer of :func:`share_capacity` rows (a pair beyond it
+stops the engine's step: ``HybridLM.fatal_counters``), the loss in
+``tiled_logits`` tiles of the sequence. With ``bias_update_rate`` the loss's
+aux carries ``param_deltas``: what the step adds to ``router_bias`` from the
+tokens each output got (``parallel/moe.py::bias_update``), which the engine
+applies after the optimizer, no gradient involved.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -50,8 +70,10 @@ from jax import lax
 from deepspeed_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                               yarn_inv_freq, yarn_mscale)
 from deepspeed_tpu.ops import block_sparse
+from deepspeed_tpu.ops.attention import multi_head_attention
 from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, lightning_chunk
-from deepspeed_tpu.parallel.moe import GateConfig, moe_ffn_share
+from deepspeed_tpu.parallel.moe import (GateConfig, bias_update,
+                                        moe_ffn_share)
 from deepspeed_tpu.runtime.sharding import (effective_dtype,
                                             vocab_parallel_lookup)
 
@@ -76,11 +98,18 @@ class HybridConfig(TransformerConfig):
     shared_ffn_size: int = 512
     experts_held: Optional[int] = None  # None: all of them
     expert_offset: int = 0
-    # the published model's mixers, one letter a layer ("m": full, "l":
-    # recurrent), of which this stack holds ``num_layers`` from
-    # ``first_layer`` on; None: every ``full_attention_interval``-th is full
+    # the published model's mixers, one letter a layer ("m": full, "w":
+    # windowed softmax attention, "l": recurrent), of which this stack
+    # holds ``num_layers`` from ``first_layer`` on; None: every
+    # ``full_attention_interval``-th is full
     layer_pattern: Optional[str] = None
     first_layer: int = 0
+    # the "w" layers: their window, and the share of each head they rotate
+    # (``partial_rotary_factor`` is the full layers')
+    sliding_window: Optional[int] = None
+    window_rotary_factor: float = 1.0
+    # a norm after each branch too (``ln1_post``, ``ln2_post``)
+    post_norms: bool = False
     recurrent_kind: str = "gdn"         # gdn | lightning
     # muP: the embedding's scale, the scale of every residual branch, and
     # what the final hidden state is divided by before the head
@@ -114,9 +143,16 @@ class HybridConfig(TransformerConfig):
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_mscale_all_dim: float = 1.0
-    # the first layers' feed-forward is the dense SwiGLU of ``ffn_size``,
-    # the experts start after them
+    # the published model's first layers have the dense SwiGLU of
+    # ``ffn_size`` as their feed-forward, the experts start after them; a
+    # stack that starts at ``first_layer`` holds ``dense_layers`` of them
     first_k_dense: int = 0
+    # the stacked tree keeps ``experts`` at the top, over the expert
+    # layers alone (else under ``layers.moe``, a slot for every layer)
+    experts_apart: bool = False
+    # what a training step moves a sigmoid router's bias by, towards equal
+    # load (the published trainer's rule; 0: the bias is held)
+    bias_update_rate: float = 0.0
     # the router's rule (parallel/moe.py::route) and whether the shared
     # expert sits behind a sigmoid gate
     router_scoring: str = "softmax"
@@ -127,19 +163,22 @@ class HybridConfig(TransformerConfig):
         super().__post_init__()
         if self.attention_kind not in ("gated", "mla"):
             raise ValueError(f"attention_kind {self.attention_kind!r}")
-        if not 0 <= self.first_k_dense <= self.num_layers:
-            raise ValueError(f"first_k_dense={self.first_k_dense} of "
-                             f"{self.num_layers} layers")
+        if not 0 <= self.first_k_dense <= self.first_layer + self.num_layers:
+            raise ValueError(f"first_k_dense={self.first_k_dense} of layers "
+                             f"{self.first_layer}.."
+                             f"{self.first_layer + self.num_layers}")
         if self.layer_pattern is None:
             if self.num_layers % self.full_attention_interval:
                 raise ValueError(
                     f"num_layers={self.num_layers} is not a whole number of "
                     f"periods of {self.full_attention_interval} layers")
-        elif (set(self.layer_pattern) - set("ml") or self.first_layer
+        elif (set(self.layer_pattern) - set("mwl") or self.first_layer
               + self.num_layers > len(self.layer_pattern)):
             raise ValueError(
                 f"layers {self.first_layer}..{self.first_layer + self.num_layers}"
-                f" lie outside the pattern {self.layer_pattern!r} (m | l)")
+                f" lie outside the pattern {self.layer_pattern!r} (m | w | l)")
+        if any(self.layer_windows) and self.attention_kind != "gated":
+            raise ValueError("a windowed layer is gated softmax attention")
         if self.recurrent_kind not in ("gdn", "lightning"):
             raise ValueError(f"recurrent_kind {self.recurrent_kind!r}")
         self.sparse                     # the sizes check themselves
@@ -183,14 +222,36 @@ class HybridConfig(TransformerConfig):
             else self.experts_held
 
     @property
-    def layer_kinds(self) -> Tuple[bool, ...]:
-        """For each layer held here, whether it is full."""
+    def _held_pattern(self) -> str:
         if self.layer_pattern is None:
             per = self.full_attention_interval
-            return tuple((l + 1) % per == 0 for l in range(self.num_layers))
-        held = self.layer_pattern[self.first_layer:
+            return "".join("m" if (l + 1) % per == 0 else "l"
+                           for l in range(self.num_layers))
+        return self.layer_pattern[self.first_layer:
                                   self.first_layer + self.num_layers]
-        return tuple(c == "m" for c in held)
+
+    @property
+    def layer_kinds(self) -> Tuple[bool, ...]:
+        """For each layer held here, whether it is softmax attention over
+        keys and values (full or windowed), not recurrent."""
+        return tuple(c != "l" for c in self._held_pattern)
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        """For each layer held here, its sliding window (None: none)."""
+        if "w" in self._held_pattern and not self.sliding_window:
+            raise ValueError("a windowed layer needs sliding_window")
+        return tuple(self.sliding_window if c == "w" else None
+                     for c in self._held_pattern)
+
+    @property
+    def dense_layers(self) -> int:
+        """Layers held here whose feed-forward is the dense SwiGLU of the
+        published model's first ``first_k_dense``."""
+        if not self.num_experts:
+            return 0
+        return min(max(self.first_k_dense - self.first_layer, 0),
+                   self.num_layers)
 
     @property
     def stack_plan(self) -> Tuple[int, Tuple[Tuple[bool, int], ...]]:
@@ -200,7 +261,7 @@ class HybridConfig(TransformerConfig):
         them, the pattern as runs ``(full,
         layers)`` of one kind. The serving runner scans the repeats and,
         inside, each run: one layer body a run, whatever the depth."""
-        kinds = self.layer_kinds[self.first_k_dense:]   # after the prologue
+        kinds = self.layer_kinds[self.dense_layers:]    # after the prologue
         L = len(kinds)
         p = next(p for p in range(1, L + 1)
                  if L % p == 0 and kinds == kinds[:p] * (L // p))
@@ -263,7 +324,7 @@ class HybridConfig(TransformerConfig):
 
     def is_dense(self, layer: int) -> bool:
         """Whether the layer's feed-forward is the dense SwiGLU."""
-        return not self.num_experts or layer < self.first_k_dense
+        return not self.num_experts or layer < self.dense_layers
 
     def is_full(self, layer: int) -> bool:
         return self.layer_kinds[layer]
@@ -281,10 +342,17 @@ class HybridConfig(TransformerConfig):
         active = 3 * h * (self.top_k * self.moe_ffn_size + self.shared_ffn_size)
         held_all = 3 * h * self.moe_ffn_size * self.held
         # (the prologue's slots of the expert leaves are never read)
-        K = self.first_k_dense
-        return 6.0 * (self.num_params() - self.num_layers * held_all
+        K = self.dense_layers
+        slots = self.num_layers - K if self.experts_apart else self.num_layers
+        return 6.0 * (self.num_params() - slots * held_all
                       + (self.num_layers - K) * active
                       - K * 3 * h * self.shared_ffn_size)
+
+
+def _layer_norms(cfg: HybridConfig) -> Tuple[str, ...]:
+    """A layer's norms: before each branch and, with ``post_norms``, after."""
+    return ("ln1", "ln2") + (("ln1_post", "ln2_post") if cfg.post_norms
+                             else ())
 
 
 def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
@@ -308,18 +376,23 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
                "wo": (L, nv, dv, h)}
     top = {}
     if cfg.num_experts:
+        K, F = cfg.dense_layers, cfg.ffn_size
+        Le = L - K if cfg.experts_apart else L
+        experts = {"wg": (Le, e, h, f), "wi": (Le, e, h, f),
+                   "wo": (Le, e, f, h)}
         moe = {"router": (L, h, cfg.num_experts),
-               "experts": {"wg": (L, e, h, f), "wi": (L, e, h, f),
-                           "wo": (L, e, f, h)},
                "shared": {"wg": (L, h, fs), "wi": (L, h, fs),
                           "wo": (L, fs, h)}}
+        if cfg.experts_apart:
+            top["experts"] = experts
+        else:
+            moe["experts"] = experts
         if cfg.shared_gate:
             moe["shared_gate"] = (L, h)
         if cfg.router_scoring == "sigmoid":
             moe["router_bias"] = (L, cfg.num_experts)
         ffn = {"moe": moe}
-        if cfg.first_k_dense:
-            K, F = cfg.first_k_dense, cfg.ffn_size
+        if K:
             top["dense"] = {"wg": (K, h, F), "wi": (K, h, F), "wo": (K, F, h)}
     else:
         ffn = {"mlp": {"wg": (L, h, cfg.ffn_size), "wi": (L, h, cfg.ffn_size),
@@ -342,7 +415,7 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
         "final_norm": {"scale": (h,)},
         "unembed": {"kernel": (h, v)},
         **top,
-        "layers": {"ln1": {"scale": (L, h)}, "ln2": {"scale": (L, h)},
+        "layers": {**{n: {"scale": (L, h)} for n in _layer_norms(cfg)},
                    **mixers, **ffn},
     }
 
@@ -397,19 +470,23 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
         rec.update({"q_norm": (L, None), "k_norm": (L, None)})
     top = {}
     if cfg.num_experts:
+        experts = {"wg": (L, "expert", "embed", None),
+                   "wi": (L, "expert", "embed", None),
+                   "wo": (L, "expert", None, "embed")}
         moe = {"router": (L, "embed", None),
-               "experts": {"wg": (L, "expert", "embed", None),
-                           "wi": (L, "expert", "embed", None),
-                           "wo": (L, "expert", None, "embed")},
                "shared": {"wg": (L, "embed", None),
                           "wi": (L, "embed", None),
                           "wo": (L, None, "embed")}}
+        if cfg.experts_apart:
+            top["experts"] = experts
+        else:
+            moe["experts"] = experts
         if cfg.shared_gate:
             moe["shared_gate"] = (L, "embed")
         if cfg.router_scoring == "sigmoid":
             moe["router_bias"] = (L, None)
         ffn = {"moe": moe}
-        if cfg.first_k_dense:
+        if cfg.dense_layers:
             top["dense"] = {"wg": (L, "embed", "mlp"), "wi": (L, "embed", "mlp"),
                             "wo": (L, "mlp", "embed")}
     else:
@@ -438,8 +515,8 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
         "final_norm": {"scale": ("embed",)},
         "unembed": {"kernel": ("embed", "vocab")},
         **top,
-        "layers": {"ln1": {"scale": (L, "embed")},
-                   "ln2": {"scale": (L, "embed")}, **mixers, **ffn},
+        "layers": {**{n: {"scale": (L, "embed")} for n in _layer_norms(cfg)},
+                   **mixers, **ffn},
     }
 
 
@@ -453,7 +530,8 @@ def serving_params(cfg: HybridConfig, params: Dict[str, Any],
     tree up, and each stacked leaf that is cut is deleted as soon as its cut
     exists, so that at most one leaf is held twice (a chip that the cut
     tree nearly fills cannot hold both trees)."""
-    if "experts" in params:
+    mixer = "mla" if cfg.attention_kind == "mla" else "attn"
+    if "experts" in params and mixer in params:
         return params
 
     def cut(tree, keep):
@@ -473,15 +551,14 @@ def serving_params(cfg: HybridConfig, params: Dict[str, Any],
     full = [l for l in range(L) if cfg.is_full(l)]
     out = {"embed": params["embed"], "final_norm": params["final_norm"],
            "unembed": params["unembed"]}
-    mixer = "mla" if cfg.attention_kind == "mla" else "attn"
     out[mixer] = cut(layers.pop(mixer), full)
     if cfg.recurrent_layers:
         out[cfg.recurrent_kind] = cut(layers.pop(cfg.recurrent_kind),
                                       [l for l in range(L) if l not in full])
-    experts = {}
-    if cfg.num_experts:
+    experts = params.get("experts", {})     # (there with experts_apart)
+    if cfg.num_experts and not experts:
         moe = dict(layers["moe"])
-        experts = cut(moe.pop("experts"), list(range(cfg.first_k_dense, L)))
+        experts = cut(moe.pop("experts"), list(range(cfg.dense_layers, L)))
         layers["moe"] = moe
     if "dense" in params:
         out["dense"] = params["dense"]
@@ -503,10 +580,11 @@ def residual(cfg: "HybridConfig", x, out):
     return x + out if c == 1.0 else x + jnp.asarray(c, out.dtype) * out
 
 
-def attn_project(cfg: HybridConfig, ap, y, positions):
-    """Queries and keys normed and rotated, values, and the output gate.
-    y [..., H]; positions [...]. Returns q [..., nq, d], k, v [..., nkv, d],
-    gate [..., nq, d]."""
+def attn_project(cfg: HybridConfig, ap, y, positions, windowed: bool = False):
+    """Queries and keys normed and rotated (a full layer's share of each
+    head, or with ``windowed`` a windowed layer's), values, and the output
+    gate. y [..., H]; positions [...]. Returns q [..., nq, d], k, v
+    [..., nkv, d], gate [..., nq, d]."""
     dt, d = y.dtype, cfg.head_dim
     qg = jnp.einsum("...h,hnd->...nd", y, ap["wq"].astype(dt))
     q, gate = qg[..., :d], qg[..., d:]
@@ -514,7 +592,8 @@ def attn_project(cfg: HybridConfig, ap, y, positions):
     v = jnp.einsum("...h,hnd->...nd", y, ap["wv"].astype(dt))
     q = _rms(q, ap["q_norm"], cfg.norm_eps)
     k = _rms(k, ap["k_norm"], cfg.norm_eps)
-    rot = int(d * cfg.partial_rotary_factor)
+    rot = int(d * (cfg.window_rotary_factor if windowed
+                   else cfg.partial_rotary_factor))
 
     def rope(x):
         if not rot:
@@ -679,20 +758,30 @@ def _swiglu(mp, y):
     return a @ mp["wo"].astype(dt)
 
 
+def branch(cfg: HybridConfig, lp, post: str, x, out):
+    """``x + c * out`` (``c`` the residual scale), the branch's output
+    through its own norm first where the stack has ``post_norms``."""
+    if cfg.post_norms:
+        out = _rms(out, lp[post]["scale"], cfg.norm_eps)
+    return residual(cfg, x, out)
+
+
 def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None,
-                 dense=None):
+                 dense=None, capacity=None):
     """``x + c * ffn(norm(x))`` on flat tokens x [T, H] (``c`` the residual
     scale): the expert block, with its routing counts beside it, or the dense
     SwiGLU (no counts): for every layer of a stack without experts, and with
     ``dense`` (one prologue layer's ``wg``, ``wi``, ``wo``) for a layer of
-    the prologue. ``layer`` indexes ``experts`` (the expert layers alone)."""
+    the prologue. ``layer`` indexes ``experts`` (the expert layers alone);
+    with ``layer`` None ``experts`` is one layer's leaves, the products
+    differentiate, and ``capacity`` bounds their row buffer."""
     y = _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
     if dense is not None:
         with jax.named_scope("dense_ffn"):
-            return residual(cfg, x, _swiglu(dense, y)), None
+            return branch(cfg, lp, "ln2_post", x, _swiglu(dense, y)), None
     if not cfg.num_experts:
         with jax.named_scope("mlp"):
-            return residual(cfg, x, _swiglu(lp["mlp"], y)), None
+            return branch(cfg, lp, "ln2_post", x, _swiglu(lp["mlp"], y)), None
     moe = lp["moe"]
     shared = dict(moe["shared"])
     if cfg.shared_gate:
@@ -700,8 +789,8 @@ def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None,
     out, counts = moe_ffn_share(
         y, moe["router"], experts, cfg.gate, offset=cfg.expert_offset,
         shared=shared, valid=valid, layer=layer,
-        router_bias=moe.get("router_bias"))
-    return residual(cfg, x, out), counts
+        router_bias=moe.get("router_bias"), capacity=capacity)
+    return branch(cfg, lp, "ln2_post", x, out), counts
 
 
 def embed_tokens(cfg: HybridConfig, params, ids):
@@ -754,84 +843,192 @@ def full_attention(cfg: HybridConfig, q, k, v, positions):
 
 
 # ---------------------------------------------------------------------------
-# full forward (no cache): the v1 engine's ``forward``, and the tests
+# full forward (no cache): the training engine's path, and the tests
 # ---------------------------------------------------------------------------
 
+# what an expert layer of the full forward counts, summed over the layers:
+# (token, expert) pairs routed to held experts, held experts that got a row,
+# the fullest held expert's rows, tokens through an expert layer, and pairs
+# beyond the row buffer (``share_capacity``), which add nothing
+MOE_COUNTERS = ("moe_local_pairs", "moe_experts_hit", "moe_max_expert_rows",
+                "moe_token_layers", "moe_dropped_pairs")
 
-def apply(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
-          positions: Optional[jax.Array] = None) -> jax.Array:
-    """tokens [B, S] -> float32 logits [B, S, V]: every sequence from an
-    empty state, the recurrence in its chunked form."""
+
+def softmax_attention(cfg: HybridConfig, q, k, v, positions, window=None):
+    """Causal softmax attention of whole sequences, no cache: the kernel
+    dispatch of ``ops/attention.py`` (flash on a chip, its band grid under
+    ``window``), or with the sparse rule the masked-dense form."""
+    if cfg.sparse is not None:
+        return full_attention(cfg, q, k, v, positions)
+    return multi_head_attention(q, k, v, causal=True, impl=cfg.attn_impl,
+                                window=window)
+
+
+def share_capacity(cfg: HybridConfig, tokens: int) -> Optional[int]:
+    """Rows of the training path's expert buffer for ``tokens`` tokens. A
+    chip that holds every expert gives every pair a row (None). A share's
+    pairs are the first ``top_k * held / num_experts`` of the sorted rows in
+    expectation; the buffer is one and a half times that in whole 512-row
+    tiles, and never more than every pair. (A router balanced by its bias
+    sends a share within a few percent of its expectation; the slack is for
+    a router before it is balanced, where the fullest share seen on drawn
+    weights was 1.07 of it. A step that routes more stops:
+    ``HybridLM.fatal_counters``.)"""
+    if cfg.held == cfg.num_experts:
+        return None
+    expect = tokens * cfg.top_k * cfg.held / cfg.num_experts
+    rows = int(math.ceil(1.5 * expect / 512.0)) * 512
+    return min(rows, -(-tokens * cfg.top_k // 128) * 128)
+
+
+def _layer(cfg: HybridConfig, l: int, x, positions, lp, mp, experts, dense):
+    """Layer ``l`` of the full forward on x [B, S, H]: ``lp`` its per-layer
+    leaves, ``mp`` its mixer's, ``experts`` its routed experts' (or None),
+    ``dense`` its prologue feed-forward's (or None). Returns (x, counts
+    [len(MOE_COUNTERS)] int32, load [num_experts] int32: the tokens each of
+    the router's outputs got, zeros for a dense layer)."""
+    B, S, H = x.shape
+    dt = x.dtype
+    nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                  cfg.linear_value_head_dim)
+    state0 = jnp.zeros((B, nv, dk, dv), jnp.float32)    # (a recurrent layer's)
+    y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
+    if cfg.attention_kind == "mla":
+        q_n, q_r, latent = mla_project(cfg, mp, y, positions)
+        out = mla_output(mp, mla_attention(cfg, mp, q_n, q_r, latent))
+    elif cfg.is_full(l):
+        window = cfg.layer_windows[l]
+        with jax.named_scope("attn_window" if window else "attn_full"):
+            q, k, v, gate = attn_project(cfg, mp, y, positions,
+                                         windowed=window is not None)
+            a = softmax_attention(cfg, q, k, v, positions, window)
+            out = attn_output(mp, a, gate)
+    elif cfg.recurrent_kind == "gdn":
+        mixed, z, beta, g = gdn_project(cfg, mp, y)
+        tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1,
+                          cfg.conv_channels), dt)
+        conv, _ = causal_conv(mp["conv"], tail, mixed)
+        qf, kf, vf = gdn_heads(cfg, conv)
+        o, _ = gdn_chunk(qf, kf, vf, g, beta, state0)
+        out = gdn_output(cfg, mp, o, z)
+    else:
+        decay = cfg.lightning_decay()[sum(
+            not full for full in cfg.layer_kinds[:l])]
+        qf, kf, vf, z = lightning_project(cfg, mp, y, positions)
+        o, _ = lightning_chunk(qf, kf, vf, jnp.broadcast_to(decay, (B, S, nv)),
+                               state0)
+        out = lightning_output(cfg, mp, o, z)
+    x = branch(cfg, lp, "ln1_post", x, out)
+    x, c = expert_block(cfg, lp, experts, x.reshape(B * S, H), None,
+                        dense=dense, capacity=share_capacity(cfg, B * S))
+    if c is None:
+        counts = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
+        load = jnp.zeros((cfg.num_experts,), jnp.int32)
+    else:
+        counts = jnp.stack([c["pairs"], c["experts_hit"], c["max_rows"],
+                            jnp.int32(B * S), c["dropped"]])
+        load = c["load"]
+    return x.reshape(B, S, H), counts, load
+
+
+def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
+                  positions: Optional[jax.Array] = None):
+    """tokens [B, S] -> (the last layer's output [B, S, H] before the final
+    norm, the expert layers' counts ``{name: int32}`` over MOE_COUNTERS, the
+    router outputs' tokens a layer ``[num_layers, num_experts]`` int32):
+    every sequence from an empty state, the recurrence in its chunked form,
+    each layer under the checkpoint policy (``remat`` / ``remat_policy``:
+    the engine's ``activation_checkpointing`` where the model names none)."""
+    from deepspeed_tpu.runtime.activation_checkpointing import \
+        checkpoint_wrapper
+
     p = serving_params(cfg, params)
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x = embed_tokens(cfg, p, tokens)
-    dt = x.dtype
-    nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
-                  cfg.linear_value_head_dim)
-    state0 = jnp.zeros((B, nv, dk, dv), jnp.float32)
-    decay = cfg.lightning_decay() if cfg.recurrent_kind == "lightning" else None
+    K = cfg.dense_layers
+    mixer = "mla" if cfg.attention_kind == "mla" else "attn"
+    seen = {True: 0, False: 0}
+    counts, loads = jnp.zeros((len(MOE_COUNTERS),), jnp.int32), []
 
-    def layer_of(l):
-        return jax.tree.map(lambda a: a[l], p["layers"])
+    def at(tree, i):
+        return jax.tree.map(lambda a: a[i], tree)
 
-    K = cfg.first_k_dense if cfg.num_experts else 0
-
-    def ffn(x, l):
-        dense = jax.tree.map(lambda a: a[l], p["dense"]) if l < K else None
-        out, _ = expert_block(cfg, layer_of(l), p["experts"],
-                              x.reshape(B * S, -1), l - K, dense=dense)
-        return out.reshape(B, S, -1)
-
-    l_kv = l_rec = 0
     for l in range(cfg.num_layers):
-        lp = layer_of(l)
-        y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        if cfg.attention_kind == "mla":
-            mp = jax.tree.map(lambda a: a[l], p["mla"])
-            q_n, q_r, latent = mla_project(cfg, mp, y, positions)
-            a = mla_attention(cfg, mp, q_n, q_r, latent)
-            x = residual(cfg, x, mla_output(mp, a))
-        elif cfg.is_full(l):
-            ap = jax.tree.map(lambda a: a[l_kv], p["attn"])
-            q, k, v, gate = attn_project(cfg, ap, y, positions)
-            a = full_attention(cfg, q, k, v, positions)
-            x = residual(cfg, x, attn_output(ap, a, gate))
-            l_kv += 1
-        elif cfg.recurrent_kind == "gdn":
-            gp = jax.tree.map(lambda a: a[l_rec], p["gdn"])
-            mixed, z, beta, g = gdn_project(cfg, gp, y)
-            tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1,
-                              cfg.conv_channels), dt)
-            conv, _ = causal_conv(gp["conv"], tail, mixed)
-            qf, kf, vf = gdn_heads(cfg, conv)
-            o, _ = gdn_chunk(qf, kf, vf, g, beta, state0)
-            x = residual(cfg, x, gdn_output(cfg, gp, o, z))
-            l_rec += 1
-        else:
-            mp = jax.tree.map(lambda a: a[l_rec], p["lightning"])
-            qf, kf, vf, z = lightning_project(cfg, mp, y, positions)
-            g = jnp.broadcast_to(decay[l_rec], (B, S, nv))
-            o, _ = lightning_chunk(qf, kf, vf, g, state0)
-            x = residual(cfg, x, lightning_output(cfg, mp, o, z))
-            l_rec += 1
-        x = ffn(x, l)
-    return head_logits(cfg, p, x)
+        full = cfg.is_full(l)
+        fn = functools.partial(_layer, cfg, l)
+        if cfg.remat:
+            fn = checkpoint_wrapper(fn, policy=cfg.remat_policy)
+        x, c, load = fn(x, positions, at(p["layers"], l),
+                        at(p[mixer if full else cfg.recurrent_kind],
+                           seen[full]),
+                        at(p["experts"], l - K) if cfg.num_experts and l >= K
+                        else None,
+                        at(p["dense"], l) if l < K else None)
+        seen[full] += 1
+        counts = counts + c
+        loads.append(load)
+    # the fullest expert is a maximum a layer; summed like the others it
+    # stays "rows of the fullest expert, added over the expert layers"
+    return x, dict(zip(MOE_COUNTERS, counts)), jnp.stack(loads)
+
+
+def apply(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
+          positions: Optional[jax.Array] = None) -> jax.Array:
+    """tokens [B, S] -> float32 logits [B, S, V]."""
+    x, _, _ = hidden_states(cfg, params, tokens, positions)
+    return head_logits(cfg, serving_params(cfg, params), x)
 
 
 def loss_fn(cfg: HybridConfig, params, batch) -> Tuple[jax.Array, Dict]:
-    """Mean next-token cross-entropy over ``batch["input_ids"]`` [B, S+1]."""
+    """Mean next-token cross-entropy over ``batch["input_ids"]`` [B, S+1],
+    as ``models/transformer.py`` takes it: with ``tiled_logits`` the final
+    norm, the head and the loss a tile of the sequence at a time, so that no
+    [B, S, V] array exists. The aux carries ``ntokens`` and, of a stack with
+    experts, ``counters``: what its expert layers counted, and with
+    ``bias_update_rate`` ``param_deltas``: what the step adds to the
+    ``router_bias`` of its expert layers (a dense layer's slot gets zero)."""
     ids = batch["input_ids"]
-    logits = apply(cfg, params, ids[:, :-1])
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
-    return jnp.mean(nll), {}
+    inputs, labels = ids[:, :-1], ids[:, 1:]
+    x, counters, loads = hidden_states(cfg, params, inputs)
+    with jax.named_scope("head_loss"):
+        if cfg.tiled_logits > 1:
+            from deepspeed_tpu.parallel.tiled_compute import tiled_logits_loss
+
+            def fnorm_tile(h):
+                h = _rms(h, params["final_norm"]["scale"], cfg.norm_eps)
+                return h if cfg.logit_divisor == 1.0 else \
+                    h / jnp.asarray(cfg.logit_divisor, h.dtype)
+
+            nll_sum, total = tiled_logits_loss(
+                x, params["unembed"]["kernel"].astype(x.dtype), labels, None,
+                cfg.tiled_logits, tile_transform=fnorm_tile)
+        else:
+            logits = head_logits(cfg, params, x)
+            nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+                logits, labels[..., None], axis=-1)[..., 0]
+            nll_sum, total = jnp.sum(nll), jnp.float32(nll.size)
+        loss = nll_sum / total
+    aux = {"loss": loss, "ntokens": total}
+    if cfg.num_experts:
+        aux["counters"] = counters
+        if cfg.bias_update_rate and cfg.router_scoring == "sigmoid":
+            expert_layer = jnp.arange(cfg.num_layers) >= cfg.dense_layers
+            aux["param_deltas"] = {"layers": {"moe": {"router_bias": jnp.where(
+                expert_layer[:, None],
+                bias_update(loads, cfg.bias_update_rate), 0.0)}}}
+    return loss, aux
 
 
 class HybridLM:
     """(config, init, apply, loss, logical_axes): the model object the
     engines take, as ``TransformerLM`` is for the dense block."""
+
+    # a step that counted one of these above zero computed something else
+    # than the model (pairs beyond the experts' row buffer add nothing):
+    # the engine stops there
+    fatal_counters = ("moe_dropped_pairs",)
 
     def __init__(self, config: HybridConfig):
         self.config = config

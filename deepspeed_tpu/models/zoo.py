@@ -204,6 +204,44 @@ def _register_hybrid():
             first_k_dense=1, num_experts=16, top_k=4, moe_ffn_size=32,
             shared_ffn_size=32, experts_held=4, router_scoring="sigmoid",
             routed_scale=2.5, shared_gate=False, remat=False),
+        # Trinity-Mini (huggingface.co/arcee-ai/Trinity-Mini config.json,
+        # model_type afmoe) at the published values: gated softmax
+        # attention with QK-norm in all 32 layers, three under a 2048-token
+        # window (rotary over the whole head) to one full (no position
+        # encoding), a norm before and after each branch, two leading dense
+        # layers, then 128 routed experts (top 8 by sigmoid score with a
+        # bias that takes part in the choice, weights scaled by 2.826) and
+        # an ungated shared expert; the embedding times sqrt(hidden). The
+        # training layout: experts over the expert layers alone, the loss
+        # in tiles, the bias moved by load_balance_coeff a step.
+        "trinity-mini": HybridConfig(
+            vocab_size=200192, hidden_size=2048, num_layers=32, num_heads=32,
+            num_kv_heads=4, attn_head_dim=128, ffn_size=6144,
+            max_seq_len=131072, pos_emb="rope", norm="rmsnorm",
+            activation="swiglu", tie_embeddings=False, rope_theta=1e4,
+            norm_eps=1e-5, partial_rotary_factor=0.0,
+            window_rotary_factor=1.0, layer_pattern="wwwm" * 8,
+            sliding_window=2048, post_norms=True, first_k_dense=2,
+            num_experts=128, top_k=8, moe_ffn_size=1024,
+            shared_ffn_size=1024, router_scoring="sigmoid",
+            routed_scale=2.826, shared_gate=False, scale_emb=2048 ** 0.5,
+            experts_apart=True, bias_update_rate=0.001, tiled_logits=8),
+        # the same stack at a toy size, cut as the benchmark's cell is:
+        # from the second leading dense layer on, then one period of expert
+        # layers (three windowed, one full), a window shorter than a
+        # sequence, 4 of 16 experts held
+        "tiny-trinity": HybridConfig(
+            vocab_size=256, hidden_size=64, num_layers=5, num_heads=4,
+            num_kv_heads=2, attn_head_dim=32, ffn_size=128, max_seq_len=256,
+            pos_emb="rope", norm="rmsnorm", activation="swiglu",
+            tie_embeddings=False, rope_theta=1e4, norm_eps=1e-5,
+            partial_rotary_factor=0.0, window_rotary_factor=1.0,
+            layer_pattern="wwwm" * 2, first_layer=1, sliding_window=24,
+            post_norms=True, first_k_dense=2, num_experts=16, top_k=2,
+            moe_ffn_size=32, shared_ffn_size=32, experts_held=4,
+            router_scoring="sigmoid", routed_scale=2.5, shared_gate=False,
+            scale_emb=8.0, experts_apart=True, bias_update_rate=0.001,
+            tiled_logits=2),
     })
 
 

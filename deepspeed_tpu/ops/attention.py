@@ -43,7 +43,8 @@ def repeat_kv_heads(q, k, v):
 
 
 def xla_attention(q, k, v, causal: bool = True,
-                  segment_ids: Optional[jax.Array] = None) -> jax.Array:
+                  segment_ids: Optional[jax.Array] = None,
+                  window: Optional[int] = None) -> jax.Array:
     """Reference attention. q: [B, S, Nq, D]; k,v: [B, S, Nkv, D] with
     Nq a multiple of Nkv (GQA repeats kv heads here).
 
@@ -60,6 +61,8 @@ def xla_attention(q, k, v, causal: bool = True,
         qpos = jnp.arange(Sq)[:, None] + (Sk - Sq)
         kpos = jnp.arange(Sk)[None, :]
         scores = jnp.where(kpos <= qpos, scores, NEG_INF)
+        if window is not None:
+            scores = jnp.where(qpos - kpos < window, scores, NEG_INF)
     if segment_ids is not None:
         same = segment_ids[:, :, None] == segment_ids[:, None, :]
         scores = jnp.where(same[:, None, :, :], scores, NEG_INF)
@@ -131,7 +134,12 @@ def _auto_block(seq: int) -> int:
 
 def _pick_blocks(seq: int) -> tuple:
     """Flash block geometry: kernels.flash_block_q/_k where set (0 =
-    auto), else the seq-derived default."""
+    auto), else the seq-derived default. A sliding window does not move
+    it: at 8k under a window of 2k the 1024-wide blocks' band is 21 block
+    pairs against 70 of 512 (1.2x the products, 0.3x the grid steps), and
+    on the chip the steps decide: forward 6.9 ms against 11.1, forward and
+    backward 22.9 against 27.1 (2 x 8192, 32 heads over 4; my chip run,
+    PR 43; 256-wide: 21.2 and 52.3)."""
     bq = bk = _auto_block(seq)
     kcfg = _KERNEL_CONFIG
     if kcfg is not None:
@@ -158,7 +166,7 @@ def _export_dispatch(region: str, source: str, reason: str) -> None:
 
 
 def _flash_on_mesh(q, k, v, causal: bool, segment_ids, block_q: int,
-                   block_k: int) -> jax.Array:
+                   block_k: int, window: Optional[int] = None) -> jax.Array:
     """The flash kernel on whatever mesh is live.
 
     GSPMD cannot partition a Mosaic kernel ("wrap the call in a
@@ -178,7 +186,8 @@ def _flash_on_mesh(q, k, v, causal: bool, segment_ids, block_q: int,
     from deepspeed_tpu.runtime import sharding as shard_lib
 
     kernel = functools.partial(flash_attention, causal=causal,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               window=window)
     mesh = topology._GLOBAL_MESH
     manual = shard_lib._MANUAL_AXES
     auto = {a: n for a, n in (mesh.shape.items() if mesh is not None else ())
@@ -215,15 +224,21 @@ def _flash_on_mesh(q, k, v, causal: bool, segment_ids, block_q: int,
 
 
 def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
-                         segment_ids: Optional[jax.Array] = None) -> jax.Array:
+                         segment_ids: Optional[jax.Array] = None,
+                         window: Optional[int] = None) -> jax.Array:
     """Dispatching entry point used by the model zoo.
 
     ``impl='auto'`` runs the flash kernel on a TPU from FLASH_MIN_SEQ
     up and the XLA einsum elsewhere; ``impl='flash'``/``'xla'`` name
     one. Flash blocks are ``kernels.flash_block_q/_k`` where set, else
-    ``_auto_block(seq)``.
+    ``_auto_block(seq)``. ``window``: a sliding window under the causal
+    mask (key j visible to query i iff 0 <= i - j < window), in the flash
+    kernel's band grid or the XLA mask.
     """
     seq = q.shape[1]
+    if window is not None and (not causal or impl == "blocksparse"):
+        raise ValueError("a sliding window needs causal attention through "
+                         "the flash or XLA path")
     if impl == "blocksparse":
         if _SPARSE_CONFIG is None:
             raise ValueError(
@@ -248,5 +263,7 @@ def multi_head_attention(q, k, v, causal: bool = True, impl: str = "auto",
                          "FLASH_MIN_SEQ")
     if impl == "flash":
         bq, bk = _pick_blocks(seq)
-        return _flash_on_mesh(q, k, v, causal, segment_ids, bq, bk)
-    return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+        windowed = {} if window is None else {"window": window}
+        return _flash_on_mesh(q, k, v, causal, segment_ids, bq, bk, **windowed)
+    return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                         window=window)

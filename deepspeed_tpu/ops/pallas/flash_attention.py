@@ -34,6 +34,15 @@ list of live (q-block, k-block) pairs via scalar prefetch instead of a
 dense nq x nk grid with a skip gate. A skipped grid step still costs
 its K/V block DMA and grid overhead — at long context that is ~2x
 wasted HBM bandwidth, which is exactly what bounds the kernel at D=128.
+
+A sliding ``window`` (key j visible to query i iff 0 <= i - j < window)
+makes the live pairs a *band*: each q-block meets its diagonal block and
+the ``band`` blocks below it. The windowed kernels walk an nq x (band + 1)
+rectangle; the few steps that fall off the band's ragged end (before
+block 0 in a q-run, past the last q-block in a k-run) are clamped onto
+the neighbouring live step's blocks, so nothing is fetched for them, and
+skipped, so nothing is multiplied. They carry their own names
+(``flash_window_*``): a trace tells a windowed call from a full one.
 """
 
 from __future__ import annotations
@@ -65,7 +74,31 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _num_items(nq: int, nk: int, causal: bool) -> int:
+def _band_blocks(window: int, block: int, nq: int) -> int:
+    """Blocks below the diagonal that a ``window`` reaches: the first row
+    of q-block iq sees keys from iq*block - (window - 1) on."""
+    return min(-(-(window - 1) // block), nq - 1)
+
+
+def _band_q(t, band: int):
+    """Windowed q-major item t -> (iq, ik, d, live): step d of q-block
+    iq's run over k-blocks iq - band .. iq. A step before block 0 is not
+    live and sits on block 0, which the run's first live step reads."""
+    iq, d = t // (band + 1), t % (band + 1)
+    ik = iq - band + d
+    return iq, jnp.maximum(ik, 0), d, ik >= 0
+
+
+def _band_kv(t, band: int, nq: int):
+    """Windowed k-major twin -> (iq, ik, d, live): step d of k-block
+    ik's run over q-blocks ik .. ik + band. A step past the last q-block
+    is not live and sits on the last block, which the step before it read."""
+    ik, d = t // (band + 1), t % (band + 1)
+    iq = ik + d
+    return jnp.minimum(iq, nq - 1), ik, d, iq < nq
+
+
+def _num_items(nq: int, nk: int, causal: bool, band=None) -> int:
     """Work items in the (triangle-)packed grid. Causal requires
     block_q == block_k, giving the exact lower triangle nq*(nq+1)/2.
 
@@ -74,6 +107,8 @@ def _num_items(nq: int, nk: int, causal: bool) -> int:
     while the item count fits int32. nq = 2^15 (S = 32M at block 1024)
     is still ~5e8 items; anything larger must raise, not corrupt."""
     t_total = nq * (nq + 1) // 2 if causal else nq * nk
+    if band is not None:
+        t_total = nq * (band + 1)
     if t_total >= 2 ** 31:
         raise ValueError(
             f"flash grid item count {t_total} overflows the int32 packed "
@@ -125,6 +160,16 @@ def _decompose_kv(t, nq: int, nk: int, causal: bool):
     return iq, ik
 
 
+def _q_run(t, nq: int, nk: int, causal: bool, band):
+    """A q-major step -> (iq, ik, first of its q-block's run, last of it,
+    live); ``live`` is None where every step is (no window)."""
+    if band is not None:
+        iq, ik, d, live = _band_q(t, band)
+        return iq, ik, d == 0, d == band, live
+    iq, ik = _decompose_q(t, nq, nk, causal)
+    return iq, ik, ik == 0, (ik == iq) if causal else (ik == nk - 1), None
+
+
 def _kv_row(b, hq: int, hkv: int):
     """GQA index map: flattened q row b = batch*hq + h → kv row for
     kv head h // (hq // hkv). The load-bearing GQA invariant — forward
@@ -133,7 +178,7 @@ def _kv_row(b, hq: int, hkv: int):
 
 
 def _mask(s, *, iq, ik, causal: bool, seg_q, seg_k,
-          block_q: int, block_k: int):
+          block_q: int, block_k: int, window=None):
     """Apply causal and/or segment masks to a [BQ, BK] score block.
     ``seg_q`` is a [BQ, 1] column, ``seg_k`` a [1, BK] row."""
     if causal:
@@ -142,9 +187,17 @@ def _mask(s, *, iq, ik, causal: bool, seg_q, seg_k,
         kpos = ik * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         s = jnp.where(kpos <= qpos, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(qpos - kpos < window, s, NEG_INF)
     if seg_q is not None:
         s = jnp.where(seg_q == seg_k, s, NEG_INF)
     return s
+
+
+def _when(live):
+    """Decorator for a grid step's work: always (``live`` None: every
+    step of a packed grid is live) or only on a live step of a band."""
+    return (lambda step: step()) if live is None else pl.when(live)
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +207,7 @@ def _mask(s, *, iq, ik, causal: bool, seg_q, seg_k,
 
 def _fwd_kernel(*refs, scale: float, causal: bool,
                 has_segments: bool, block_q: int, block_k: int,
-                nq: int, nk: int):
+                nq: int, nk: int, window=None, band=None):
     if has_segments:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, \
             acc_sc, m_sc, l_sc = refs
@@ -164,9 +217,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
     t = pl.program_id(1)
     # triangle-packed grid: every step is live; q-major ordering means a
     # q-block's run starts at its first k-block and ends at the diagonal
-    iq, ik = _decompose_q(t, nq, nk, causal)
-    first = ik == 0
-    last = (ik == iq) if causal else (ik == nk - 1)
+    iq, ik, first, last, live = _q_run(t, nq, nk, causal, band)
 
     @pl.when(first)
     def _init():
@@ -174,29 +225,35 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    q = q_ref[0]  # [BQ, D]
-    k = k_ref[0]  # [BK, D]
-    v = v_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-    s = _mask(s, iq=iq, ik=ik, causal=causal,
-              seg_q=sq_ref[0][:, :1] if has_segments else None,
-              seg_k=sk_ref[0][:1, :] if has_segments else None,
-              block_q=block_q, block_k=block_k)
+    @_when(live)
+    def _step():
+        q = q_ref[0]  # [BQ, D]
+        k = k_ref[0]  # [BK, D]
+        v = v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [BQ, BK]
+        s = _mask(s, iq=iq, ik=ik, causal=causal,
+                  seg_q=sq_ref[0][:, :1] if has_segments else None,
+                  seg_k=sk_ref[0][:1, :] if has_segments else None,
+                  block_q=block_q, block_k=block_k, window=window)
 
-    m_prev = m_sc[:, :1]  # [BQ, 1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)  # [BQ, 1]
-    p = jnp.exp(s - m_new)  # [BQ, BK]
-    l_new = l_sc[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)  # [BQ, D]
-    acc_sc[:] = acc_sc[:] * alpha + pv
-    m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-    l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+        # a row whose keys in this block are all masked (the band's lower
+        # edge) adds exp(0) terms at m = NEG_INF; the first block with a
+        # visible key rescales them by exp(NEG_INF - m) = 0, and the
+        # diagonal block always has one
+        m_prev = m_sc[:, :1]  # [BQ, 1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)  # [BQ, 1]
+        p = jnp.exp(s - m_new)  # [BQ, BK]
+        l_new = l_sc[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [BQ, D]
+        acc_sc[:] = acc_sc[:] * alpha + pv
+        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
+        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
 
     @pl.when(last)
     def _finalize():
@@ -207,8 +264,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
 
 
 def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
-               hq: int, hkv: int,
-               block_q: int, block_k: int) -> Tuple[jax.Array, jax.Array]:
+               hq: int, hkv: int, block_q: int, block_k: int,
+               window=None) -> Tuple[jax.Array, jax.Array]:
     """q: [B*Hq, S, D]; k,v: [B*Hkv, S, D]; seg_q: [B, S, STAT_LANES],
     seg_k: [B, SEG_SUBLANES, S], or both None.
 
@@ -217,12 +274,13 @@ def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
     BHq, S, D = q.shape
     nq, nk = S // block_q, S // block_k
     has_segments = seg_q is not None
+    band = None if window is None else _band_blocks(window, block_q, nq)
 
     def kv_row(b):
         return _kv_row(b, hq, hkv)
 
     def d_q(t):
-        return _decompose_q(t, nq, nk, causal)
+        return _q_run(t, nq, nk, causal, band)[:2]
 
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, t: (b, d_q(t)[0], 0)),
@@ -242,11 +300,12 @@ def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
         args += [seg_q, seg_k]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_segments=has_segments,
-        block_q=block_q, block_k=block_k, nq=nq, nk=nk)
+        block_q=block_q, block_k=block_k, nq=nq, nk=nk, window=window,
+        band=band)
     o, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
-        grid=(BHq, _num_items(nq, nk, causal)),
+        name="flash_fwd" if window is None else "flash_window_fwd",
+        grid=(BHq, _num_items(nq, nk, causal, band)),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, t: (b, d_q(t)[0], 0)),
@@ -276,7 +335,7 @@ def _flash_fwd(q, k, v, seg_q, seg_k, scale: float, causal: bool,
 
 def _bwd_dkdv_kernel(*refs, scale: float, causal: bool,
                      has_segments: bool, block_q: int, block_k: int,
-                     nq: int, nk: int):
+                     nq: int, nk: int, window=None, band=None):
     if has_segments:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref, \
             dk_ref, dv_ref, dk_sc, dv_sc = refs
@@ -289,42 +348,50 @@ def _bwd_dkdv_kernel(*refs, scale: float, causal: bool,
     # (GQA group x live q-blocks) in scratch per k-block run.
     t, mem = pl.program_id(1), pl.program_id(2)
     g = pl.num_programs(2)
-    iq, ik = _decompose_kv(t, nq, nk, causal)
-    run_start = ik if causal else 0
-    first = jnp.logical_and(mem == 0, iq == run_start)
-    last = jnp.logical_and(mem == g - 1, iq == nq - 1)
+    if band is None:
+        iq, ik = _decompose_kv(t, nq, nk, causal)
+        run_start = ik if causal else 0
+        first = jnp.logical_and(mem == 0, iq == run_start)
+        last = jnp.logical_and(mem == g - 1, iq == nq - 1)
+        live = None
+    else:
+        iq, ik, d, live = _band_kv(t, band, nq)
+        first = jnp.logical_and(mem == 0, d == 0)
+        last = jnp.logical_and(mem == g - 1, d == band)
 
     @pl.when(first)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0][:, :1]  # [BQ, 1]
-    delta = delta_ref[0][:, :1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-    s = _mask(s, iq=iq, ik=ik, causal=causal,
-              seg_q=sq_ref[0][:, :1] if has_segments else None,
-              seg_k=sk_ref[0][:1, :] if has_segments else None,
-              block_q=block_q, block_k=block_k)
-    p = jnp.exp(s - lse)  # [BQ, BK]
-    # dv += p^T @ do
-    dv_sc[:] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # dp = do @ v^T ; ds = p * (dp - delta)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    dk_sc[:] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    @_when(live)
+    def _step():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0][:, :1]  # [BQ, 1]
+        delta = delta_ref[0][:, :1]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [BQ, BK]
+        s = _mask(s, iq=iq, ik=ik, causal=causal,
+                  seg_q=sq_ref[0][:, :1] if has_segments else None,
+                  seg_k=sk_ref[0][:1, :] if has_segments else None,
+                  block_q=block_q, block_k=block_k, window=window)
+        p = jnp.exp(s - lse)  # [BQ, BK]
+        # dv += p^T @ do
+        dv_sc[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # dp = do @ v^T ; ds = p * (dp - delta)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        dk_sc[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
 
     @pl.when(last)
     def _finalize():
@@ -334,7 +401,7 @@ def _bwd_dkdv_kernel(*refs, scale: float, causal: bool,
 
 def _bwd_dq_kernel(*refs, scale: float, causal: bool,
                    has_segments: bool, block_q: int, block_k: int,
-                   nq: int, nk: int):
+                   nq: int, nk: int, window=None, band=None):
     if has_segments:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref, \
             dq_ref, dq_sc = refs
@@ -343,35 +410,35 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool,
             dq_ref, dq_sc = refs
         sq_ref = sk_ref = None
     t = pl.program_id(1)
-    iq, ik = _decompose_q(t, nq, nk, causal)
-    first = ik == 0
-    last = (ik == iq) if causal else (ik == nk - 1)
+    iq, ik, first, last, live = _q_run(t, nq, nk, causal, band)
 
     @pl.when(first)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0][:, :1]
-    delta = delta_ref[0][:, :1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    s = _mask(s, iq=iq, ik=ik, causal=causal,
-              seg_q=sq_ref[0][:, :1] if has_segments else None,
-              seg_k=sk_ref[0][:1, :] if has_segments else None,
-              block_q=block_q, block_k=block_k)
-    p = jnp.exp(s - lse)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    dq_sc[:] += jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    @_when(live)
+    def _step():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0][:, :1]
+        delta = delta_ref[0][:, :1]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = _mask(s, iq=iq, ik=ik, causal=causal,
+                  seg_q=sq_ref[0][:, :1] if has_segments else None,
+                  seg_k=sk_ref[0][:1, :] if has_segments else None,
+                  block_q=block_q, block_k=block_k, window=window)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        dq_sc[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
 
     @pl.when(last)
     def _finalize():
@@ -379,7 +446,7 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool,
 
 
 def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
-               hq, hkv, block_q, block_k):
+               hq, hkv, block_q, block_k, window=None):
     BHq, S, D = q.shape
     BHkv = k.shape[0]
     g = hq // hkv
@@ -389,27 +456,34 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
 
     nq, nk = S // block_q, S // block_k
     has_segments = seg_q is not None
+    band = None if window is None else _band_blocks(window, block_q, nq)
 
     def d_kv(t):
-        return _decompose_kv(t, nq, nk, causal)
+        if band is None:
+            return _decompose_kv(t, nq, nk, causal)
+        return _band_kv(t, band, nq)[:2]
 
     # --- dk/dv: one pass per kv head, accumulating over its q-head group
-    def q_row(b, m):
+    def q_row(b, m, t=None):
+        if band is not None:
+            # a step off the band's end stays on the q head it follows
+            # (the group's last), so that its blocks are not fetched anew
+            m = jnp.where(_band_kv(t, band, nq)[3], m, g - 1)
         return (b // hkv) * hq + (b % hkv) * g + m
 
     dkdv_in_specs = [
         pl.BlockSpec((1, block_q, D),
-                     lambda b, t, m: (q_row(b, m), d_kv(t)[0], 0)),
+                     lambda b, t, m: (q_row(b, m, t), d_kv(t)[0], 0)),
         pl.BlockSpec((1, block_k, D),
                      lambda b, t, m: (b, d_kv(t)[1], 0)),  # k
         pl.BlockSpec((1, block_k, D),
                      lambda b, t, m: (b, d_kv(t)[1], 0)),  # v
         pl.BlockSpec((1, block_q, D),
-                     lambda b, t, m: (q_row(b, m), d_kv(t)[0], 0)),
+                     lambda b, t, m: (q_row(b, m, t), d_kv(t)[0], 0)),
         pl.BlockSpec((1, block_q, STAT_LANES),
-                     lambda b, t, m: (q_row(b, m), d_kv(t)[0], 0)),
+                     lambda b, t, m: (q_row(b, m, t), d_kv(t)[0], 0)),
         pl.BlockSpec((1, block_q, STAT_LANES),
-                     lambda b, t, m: (q_row(b, m), d_kv(t)[0], 0)),
+                     lambda b, t, m: (q_row(b, m, t), d_kv(t)[0], 0)),
     ]
     dkdv_args = [q, k, v, do, lse, delta]
     if has_segments:
@@ -423,9 +497,10 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
     dkdv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           has_segments=has_segments,
-                          block_q=block_q, block_k=block_k, nq=nq, nk=nk),
-        name="flash_bwd_dkdv",
-        grid=(BHkv, _num_items(nq, nk, causal), g),
+                          block_q=block_q, block_k=block_k, nq=nq, nk=nk,
+                          window=window, band=band),
+        name="flash_bwd_dkdv" if window is None else "flash_window_bwd_dkdv",
+        grid=(BHkv, _num_items(nq, nk, causal, band), g),
         in_specs=dkdv_in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, D),
@@ -452,7 +527,7 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
         return _kv_row(b, hq, hkv)
 
     def d_q(t):
-        return _decompose_q(t, nq, nk, causal)
+        return _q_run(t, nq, nk, causal, band)[:2]
 
     dq_in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, t: (b, d_q(t)[0], 0)),
@@ -478,9 +553,10 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           has_segments=has_segments,
-                          block_q=block_q, block_k=block_k, nq=nq, nk=nk),
-        name="flash_bwd_dq",
-        grid=(BHq, _num_items(nq, nk, causal)),
+                          block_q=block_q, block_k=block_k, nq=nq, nk=nk,
+                          window=window, band=band),
+        name="flash_bwd_dq" if window is None else "flash_window_bwd_dq",
+        grid=(BHq, _num_items(nq, nk, causal, band)),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, D),
                                lambda b, t: (b, d_q(t)[0], 0)),
@@ -498,28 +574,28 @@ def _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale, causal,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, seg_q, seg_k, causal: bool, hq: int, hkv: int,
-           block_q: int, block_k: int):
+           block_q: int, block_k: int, window=None):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     o, _ = _flash_fwd(q, k, v, seg_q, seg_k, scale, causal, hq, hkv,
-                      block_q, block_k)
+                      block_q, block_k, window)
     return o
 
 
 def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, hq, hkv,
-                   block_q, block_k):
+                   block_q, block_k, window):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     o, lse = _flash_fwd(q, k, v, seg_q, seg_k, scale, causal, hq, hkv,
-                        block_q, block_k)
+                        block_q, block_k, window)
     return o, (q, k, v, seg_q, seg_k, o, lse)
 
 
-def _flash_vjp_bwd(causal, hq, hkv, block_q, block_k, res, do):
+def _flash_vjp_bwd(causal, hq, hkv, block_q, block_k, window, res, do):
     q, k, v, seg_q, seg_k, o, lse = res
     scale = 1.0 / (q.shape[-1] ** 0.5)
     dq, dk, dv = _flash_bwd(q, k, v, seg_q, seg_k, o, lse, do, scale,
-                            causal, hq, hkv, block_q, block_k)
+                            causal, hq, hkv, block_q, block_k, window)
     return dq, dk, dv, None, None
 
 
@@ -529,13 +605,18 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q, k, v, causal: bool = True,
                     segment_ids: Optional[jax.Array] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K) -> jax.Array:
+                    block_k: int = DEFAULT_BLOCK_K,
+                    window: Optional[int] = None) -> jax.Array:
     """Public entry. q: [B, S, Nq, D]; k, v: [B, S, Nkv, D] (GQA-native —
     Nq must be a multiple of Nkv; no pre-repeat needed or wanted).
 
     ``segment_ids``: optional [B, S] int array; attention is masked to
     same-segment pairs (packed sequences). Causal and non-causal both
     run in the kernel.
+
+    ``window``: optional sliding window under the causal mask: key j is
+    visible to query i iff 0 <= i - j < window. Only the band of blocks
+    the window reaches is visited, forward and backward.
 
     Pads S up to a block multiple. Padding is always masked: under a
     causal mask padded queries only attend the real prefix and are
@@ -546,6 +627,9 @@ def flash_attention(q, k, v, causal: bool = True,
     Nkv = k.shape[2]
     if Nq % Nkv != 0:
         raise ValueError(f"q heads ({Nq}) not a multiple of kv heads ({Nkv})")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a sliding window ({window}) is a causal mask's: "
+                         f"causal={causal}")
     bq = min(block_q, _round_pow2(S))
     bk = min(block_k, _round_pow2(S))
     if causal and bq != bk:
@@ -575,7 +659,7 @@ def flash_attention(q, k, v, causal: bool = True,
         seg_k = jnp.broadcast_to(seg_k[:, None, :], (B, SEG_SUBLANES, Sp))
 
     o = _flash(prep(q), prep(k), prep(v), seg_q, seg_k,
-               causal, Nq, Nkv, bq, bk)
+               causal, Nq, Nkv, bq, bk, window)
     o = o[:, :S].reshape(B, Nq, S, D)
     return jnp.swapaxes(o, 1, 2)
 

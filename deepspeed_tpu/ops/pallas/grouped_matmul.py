@@ -180,7 +180,7 @@ def choose_tiles(m: int, kdim: int, n: int, num_groups: int, dtype,
     itemsize = jnp.dtype(dtype).itemsize
     bm = row_tile(m, num_groups, dtype)
     if bm == 512:
-        bn, bk = 1024, 512
+        bn, bk = 2048 // itemsize, 512      # (float32: what VMEM holds)
     else:
         bk = _pick_block(kdim, max(128, _RHS_BLOCK_BYTES // (128 * itemsize)))
         # (a power of two, so that the halving in _pick_block lands on a
@@ -340,14 +340,18 @@ def _tgmm_kernel(tile_ids, group_ids, row_start, row_end,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rows = tile * block_m + jax.lax.broadcasted_iota(
-        jnp.int32, (block_m, 1), 0)
-    mask = jnp.logical_and(rows >= row_start[t], rows < row_end[t])
-    lhs = jnp.where(mask, lhs_ref[...], 0)
-    acc_ref[...] += jax.lax.dot_general(
-        lhs, dout_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+    # a slot with no row (the work list's padding, and with rows beyond the
+    # groups' sum most of it) multiplies nothing
+    @pl.when(row_end[t] > row_start[t])
+    def _product():
+        rows = tile * block_m + jax.lax.broadcasted_iota(
+            jnp.int32, (block_m, 1), 0)
+        mask = jnp.logical_and(rows >= row_start[t], rows < row_end[t])
+        lhs = jnp.where(mask, lhs_ref[...], 0)
+        acc_ref[...] += jax.lax.dot_general(
+            lhs, dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
 def _tgmm_call(lhs: jax.Array, dout: jax.Array, group_sizes: jax.Array,
@@ -359,7 +363,14 @@ def _tgmm_call(lhs: jax.Array, dout: jax.Array, group_sizes: jax.Array,
     block_m = _pick_block(m, block_m)
     block_n = _pick_block(n, block_n)
     block_k = _pick_block(kdim, block_k)
-    meta = make_group_metadata(group_sizes, m, block_m)
+    tiles, gids, row_start, row_end = make_group_metadata(group_sizes, m,
+                                                          block_m)
+    # the forward's padding slots are aimed at the row tiles no group
+    # covers (it has to hand them back zero-filled); here they fetch
+    # nothing: they stay on the last tile a group covers
+    live = row_end > row_start
+    last = jnp.maximum(jnp.sum(live) - 1, 0)
+    meta = (jnp.where(live, tiles, tiles[last]), gids, row_start, row_end)
     t_total = _num_work_items(m, num_groups, block_m)
     grid = (kdim // block_k, n // block_n, t_total)
 
@@ -404,7 +415,10 @@ def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     """Grouped matmul: row m of ``lhs`` times ``rhs[group(m)]``.
 
     lhs [M, K] sorted by group, rhs [E, K, N], group_sizes [E] int32 with
-    sum == M. Returns [M, N] in lhs.dtype (fp32 MXU accumulation).
+    sum <= M. Returns [M, N] in lhs.dtype (fp32 MXU accumulation). Rows
+    beyond the groups' sum belong to no group: zeros come back for them,
+    the backward gives them zero gradients and the groups' gradients hold
+    nothing of them.
     A block size given as 0 is the kernel's choice from the shapes
     (:func:`choose_tiles`); a positive one is that tile, snapped to a
     divisor of its dim. The backward runs the forward's tiles.

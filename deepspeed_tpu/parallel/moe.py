@@ -288,12 +288,25 @@ def route(y: jax.Array, router_w: jax.Array, cfg: GateConfig,
     return w * cfg.routed_scale, idx.astype(jnp.int32)
 
 
+def bias_update(load: jax.Array, rate: float) -> jax.Array:
+    """What a step adds to the bias of :func:`route`'s choice, from the
+    tokens each output got in it (``load [..., E]``): ``rate`` up for an
+    output under the mean, down for one over it, the mean of the change
+    taken off (the bias balances; it does not drift). No gradient reaches the
+    bias: a trainer applies this between steps
+    (``runtime/engine.py``, a model's ``param_deltas``)."""
+    load = load.astype(jnp.float32)
+    d = rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+    return d - jnp.mean(d, axis=-1, keepdims=True)
+
+
 @jax.named_scope("moe")
 def moe_ffn_share(y: jax.Array, router_w: jax.Array,
                   expert_params: Dict[str, jax.Array], cfg: GateConfig, *,
                   offset: int = 0, shared: Optional[Dict] = None,
                   valid: Optional[jax.Array] = None, layer=None,
-                  router_bias: Optional[jax.Array] = None
+                  router_bias: Optional[jax.Array] = None,
+                  capacity: Optional[int] = None
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """An expert layer that holds a *share* of the experts: one chip's part
     of a layer whose ``cfg.num_experts`` experts are divided over the chips
@@ -316,18 +329,34 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
     y [T, H] (already normed); ``valid [T]`` marks real tokens (padding is
     routed nowhere). Returns (out [T, H] in y's type, counts): ``pairs``,
     the (token, expert) pairs routed here, ``experts_hit``, the held
-    experts that got a row, and ``work_items``, the (row tile, expert)
-    pairs each of the three grouped products multiplies — int32 scalars
-    for the serving counters.
+    experts that got a row, and — int32 scalars all — with ``layer`` (the
+    serving programs' counters) ``work_items``, the (row tile, expert) pairs
+    each of the three grouped products multiplies, without it (the training
+    step's) ``max_rows``, the fullest held expert's rows, ``dropped``, and
+    ``load [num_experts]``, the tokens that chose each of the router's
+    outputs.
 
     Rows sort by local expert; pairs routed elsewhere sort last and lie
-    beyond the groups' sum, where the grouped product yields zeros.
+    beyond the groups' sum, where the grouped product yields zeros (and its
+    backward zero row gradients, and nothing of them in the experts').
+
+    Without ``layer`` the experts are one layer's leaves and the products
+    differentiate (``gm.gmm``): the training path. The rows of all ``T *
+    top_k`` pairs are mostly rows routed elsewhere (seven eighths at a share
+    of an eighth), so that path may bound the buffer: ``capacity`` (a
+    multiple of 128) keeps the first rows of the sorted order, which are the
+    local ones; local pairs beyond it are ``dropped`` (counted, adding
+    nothing): a static row budget as :func:`_ep_capacity` is for the
+    exchange. ``router_bias`` takes part in the choice alone and gets no
+    gradient.
     """
     T, H = y.shape
     held = expert_params["wi"].shape[-3]
     k = cfg.top_k
-    w, idx = route(y, router_w, cfg, router_bias)
+    if router_bias is not None:
+        router_bias = lax.stop_gradient(router_bias)
     with jax.named_scope("moe_route"):
+        w, idx = route(y, router_w, cfg, router_bias)
         here = (idx >= offset) & (idx < offset + held)
         if valid is not None:
             here = here & valid[:, None]
@@ -345,9 +374,18 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
         row_token = token[order]
         group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
             jnp.int32)
+        dropped = jnp.int32(0)
+        if capacity is not None and capacity < m:
+            if capacity % 128 or layer is not None:
+                raise ValueError(f"capacity={capacity}: a multiple of 128, "
+                                 f"on the path without a layer index")
+            m, order, row_token = capacity, order[:capacity], \
+                row_token[:capacity]
+            dropped = jnp.maximum(jnp.sum(group_sizes) - m, 0)
         # one work list for the three products: they share the row tile
-        # (the tile the products will use: the largest that divides m)
-        work = gm.make_group_metadata(
+        # (the tile the products will use: the largest that divides m);
+        # the differentiable product makes its own
+        work = None if layer is None else gm.make_group_metadata(
             group_sizes, m, gm.choose_tiles(m, H, H, held, y.dtype)[0])
     with jax.named_scope("moe_experts"):
         out = _expert_ffn(y[row_token], group_sizes, expert_params, "swiglu",
@@ -366,8 +404,22 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
                     shared["gate"].astype(jnp.float32)))[:, None]
             total = total + out
     counts = {"pairs": jnp.sum(here).astype(jnp.int32),
-              "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32),
-              "work_items": gm.work_items(work)}
+              "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32)}
+    if layer is None:       # the training path's counters
+        counts["max_rows"] = jnp.max(group_sizes).astype(jnp.int32)
+        counts["dropped"] = dropped.astype(jnp.int32)
+        # every output's tokens, held here or not: what a balancing bias
+        # is updated from
+        chosen = idx if valid is None else jnp.where(
+            valid[:, None], idx, cfg.num_experts)
+        # (a compare and a sum: a bincount of T * top_k keys is a
+        # scatter-add, 1.1 ms a layer's forward at 131,072 keys on the chip)
+        counts["load"] = jnp.sum(
+            chosen[..., None] == jnp.arange(cfg.num_experts,
+                                            dtype=chosen.dtype),
+            axis=(0, 1), dtype=jnp.int32)
+    else:                   # the serving programs'
+        counts["work_items"] = gm.work_items(work)
     return total.astype(y.dtype), counts
 
 

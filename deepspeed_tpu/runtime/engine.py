@@ -880,6 +880,22 @@ class Engine:
             loss, aux = model_loss(params, batch)
             return loss * scale, (loss, aux)
 
+        def step_aux(aux):
+            """What of a model's ``aux`` the step carries out: the token
+            count; ``counters`` — int32 scalars the model counted in its
+            forward (an expert layer's routing), summed over the
+            microbatches into the step's metrics; and ``param_deltas`` —
+            state that no gradient moves (a router's balancing bias, moved
+            by what the step routed): a part of the parameter tree, float32,
+            its mean over the microbatches added to the master weights
+            after the optimizer's update."""
+            return (jnp.asarray(aux.get("ntokens", 0.0), jnp.float32),
+                    {k: jnp.asarray(v, jnp.int32)
+                     for k, v in aux.get("counters", {}).items()},
+                    jax.tree.map(lambda d: lax.stop_gradient(
+                        jnp.asarray(d, jnp.float32)),
+                        aux.get("param_deltas", {})))
+
         def fwd_bwd(params, batch, scale):
             """One microbatch: loss + fp32 grads (grad-sharding applied →
             stage-2 reduce-scatter happens here)."""
@@ -889,13 +905,15 @@ class Engine:
             grads = _constrain_tree(grads, grad_sh)
             return loss, grads
 
-        def apply_update(params, opt_state, ls_state, step, grads, ntokens):
+        def apply_update(params, opt_state, ls_state, step, grads, ntokens,
+                         deltas=None):
             overflow = (has_overflow(grads) if fp16
                         else jnp.asarray(False))
             scale = ls_state.scale if fp16 else None
             params, opt_state, gnorm = apply_mixed_precision_update(
                 opt_state, grads, self.tx, cdt, grad_clip=grad_clip,
-                grad_scale=scale, skip=overflow if fp16 else None)
+                grad_scale=scale, skip=overflow if fp16 else None,
+                param_deltas=deltas)
             params = _constrain_tree(params, param_sh)
             new_ls = (update_loss_scale(ls_state, overflow, cfg.fp16)
                       if fp16 else ls_state)
@@ -945,15 +963,17 @@ class Engine:
                     # compiler's int32 bounds check
                     mb = jax.tree.map(lambda b: b[0], batches)
                     scaled, (loss, aux) = loss_of(params, mb, scale)
-                    return scaled, (loss[None], jnp.asarray(
-                        aux.get("ntokens", 0.0), jnp.float32)[None])
+                    ntok, counted, deltas = step_aux(aux)
+                    return scaled, (loss[None], ntok[None], counted, deltas)
 
                 def body(carry, mb):
                     scaled, (loss, aux) = loss_of(params, mb, scale)
-                    return carry + scaled / gas, (loss, aux.get("ntokens", 0.0))
-                total, (losses, ntoks) = lax.scan(
+                    return carry + scaled / gas, (loss,) + step_aux(aux)
+                total, (losses, ntoks, counted, deltas) = lax.scan(
                     body, jnp.asarray(0.0, jnp.float32), batches)
-                return total, (losses, ntoks)
+                return total, (losses, ntoks, jax.tree.map(
+                    lambda c: jnp.sum(c, axis=0), counted), jax.tree.map(
+                    lambda d: jnp.mean(d, axis=0), deltas))
 
             if qgz:
                 # qgZ: one gradient per batch-shard group (no implicit
@@ -983,17 +1003,20 @@ class Engine:
                     grads = qgz_reduce_tree(g_groups, grad_sh, self.mesh)
                 losses = jnp.mean(losses_g, axis=0)
                 ntoks = jnp.sum(ntoks_g, axis=0)
+                counted = deltas = {}
             else:
                 with jax.named_scope("forward_backward"):
-                    (_, (losses, ntoks)), grads = jax.value_and_grad(
-                        total_loss, has_aux=True)(params)
+                    (_, (losses, ntoks, counted, deltas)), grads = \
+                        jax.value_and_grad(total_loss, has_aux=True)(params)
                     grads = jax.tree.map(
                         lambda g: g.astype(jnp.float32), grads)
                     grads = _constrain_tree(grads, grad_sh)
             with jax.named_scope("optimizer"):
                 params, opt_state, new_ls, new_step, metrics = apply_update(
-                    params, opt_state, ls_state, step, grads, ntoks)
+                    params, opt_state, ls_state, step, grads, ntoks, deltas)
             metrics["loss"] = jnp.mean(losses)
+            if counted:
+                metrics["model_counters"] = counted
             return params, opt_state, new_ls, new_step, metrics
 
         opt_sh = self._opt_shardings
@@ -1274,6 +1297,13 @@ class Engine:
             # below finds them resolved
             jax.block_until_ready(metrics)
         self._last_grad_norm = metrics.get("grad_norm")
+        for name in getattr(self.model, "fatal_counters", ()):
+            n = int(metrics.get("model_counters", {}).get(name, 0))
+            if n:
+                raise RuntimeError(
+                    f"step {entry.step}: the model counted {name} = {n}; "
+                    f"what it computed is not the model (its parameters "
+                    f"have taken that step's update)")
         if entry.sync:
             # blocking path: identical ordering to the classic loop
             with span("after_step_host"):
@@ -1822,6 +1852,13 @@ class Engine:
 
             comm_total, comm_delta = self.hub.comm_deltas()
             compile_d = self.hub.compile_delta()
+            # what the model counted in this step (int32 scalars of the
+            # step program, read with the loss): on the step's row and,
+            # summed, in the hub's counters
+            counted = {k: int(v) for k, v in
+                       (metrics.get("model_counters") or {}).items()}
+            for name, n in counted.items():
+                self.hub.counter_add(f"train.{name}", n)
             trace = StepTrace(
                 step=step_no, wall_ms=wall_ms, tokens=tokens,
                 tokens_per_sec=tps, tokens_per_sec_per_chip=tps_chip,
@@ -1837,7 +1874,7 @@ class Engine:
                 compile_secs=compile_d["secs"],
                 comm_bytes_total=comm_total or None,
                 comm_bytes_delta=comm_delta or None,
-                device_mem=device_memory_stats())
+                device_mem=device_memory_stats(), extras=counted)
             self.hub.record_step(trace)
             if self.monitor is not None and self.monitor.enabled and \
                     step_no % self.config.steps_per_print == 0:

@@ -120,6 +120,14 @@ def init_mixed_precision(params_fp32, tx: optax.GradientTransformation,
     return MixedPrecisionState(master=master, inner=inner)
 
 
+def _add_at(tree, deltas):
+    """``tree`` with ``deltas`` (nested dicts down to some of its leaves)
+    added to the leaves they name."""
+    if isinstance(deltas, dict):
+        return {**tree, **{k: _add_at(tree[k], d) for k, d in deltas.items()}}
+    return tree + deltas.astype(tree.dtype)
+
+
 def apply_mixed_precision_update(
     state: MixedPrecisionState,
     grads_fp32,
@@ -128,13 +136,16 @@ def apply_mixed_precision_update(
     grad_clip: float = 0.0,
     grad_scale: Optional[jax.Array] = None,
     skip: Optional[jax.Array] = None,
+    param_deltas: Optional[Dict[str, Any]] = None,
 ) -> Tuple[Any, MixedPrecisionState, jax.Array]:
     """One optimizer step (reference BF16_Optimizer.step bf16_optimizer.py:303).
 
     Returns (new compute-dtype params, new state, global grad norm).
     ``grad_scale`` divides grads (loss-scale unscaling); ``skip`` (bool
     scalar) makes the whole update a no-op (overflow step, reference
-    fp16/fused_optimizer.py overflow path).
+    fp16/fused_optimizer.py overflow path). ``param_deltas``: nested dicts
+    down to some of the master's leaves, added to them after the update
+    (state that no gradient moves; skipped with the step).
     """
     if grad_scale is not None:
         grads_fp32 = jax.tree.map(lambda g: g / grad_scale, grads_fp32)
@@ -146,6 +157,8 @@ def apply_mixed_precision_update(
 
     updates, new_inner = tx.update(grads_fp32, state.inner, state.master)
     new_master = optax.apply_updates(state.master, updates)
+    if param_deltas:
+        new_master = _add_at(new_master, param_deltas)
 
     if skip is not None:
         new_master = jax.tree.map(

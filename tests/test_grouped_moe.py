@@ -81,6 +81,39 @@ def test_gmm_grad():
                                    atol=1e-3, rtol=1e-3)
 
 
+@pytest.mark.parametrize("sizes", [
+    (100, 0, 37, 60),       # 197 of 512 rows: most row tiles belong to no group
+    (0, 0, 0, 0),           # nothing routed here
+    (128, 128, 128, 128)])  # every row in a group
+def test_gmm_backward_with_rows_beyond_the_groups_sum(sizes):
+    """``sum(group_sizes) < M`` (a share's row buffer: pairs routed to
+    absent chips sort last, and a bounded buffer is half empty): the rows
+    beyond the sum get zeros forward and zero gradients, and the groups'
+    gradients hold nothing of them."""
+    M, K, N, E = 512, 64, 128, 4
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    lhs = jax.random.normal(ks[0], (M, K))
+    rhs = jax.random.normal(ks[1], (E, K, N)) * 0.1
+    t = jax.random.normal(ks[2], (M, N))
+    gs = jnp.asarray(sizes, jnp.int32)
+    total = int(gs.sum())
+    gid = np.repeat(np.arange(E), sizes)
+
+    def dense(lhs, rhs):
+        out = jnp.einsum("mk,mkn->mn", lhs[:total], rhs[gid])
+        return jnp.concatenate([out, jnp.zeros((M - total, N))])
+
+    for bm in (32, 128):
+        out = gmm(lhs, rhs, gs, bm, 128, 64)
+        np.testing.assert_allclose(out, dense(lhs, rhs), atol=1e-4)
+        got = jax.grad(lambda a, b: jnp.sum(gmm(a, b, gs, bm, 128, 64) * t),
+                       (0, 1))(lhs, rhs)
+        want = jax.grad(lambda a, b: jnp.sum(dense(a, b) * t), (0, 1))(lhs, rhs)
+        np.testing.assert_allclose(got[0], want[0], atol=1e-4)
+        np.testing.assert_allclose(got[1], want[1], atol=1e-3)
+        assert not np.any(np.asarray(got[0][total:]))
+
+
 def test_metadata_covers_rows_exactly_once():
     """Every row of every nonempty group appears in exactly one work
     item's (tile ∩ [row_start, row_end)) range."""
@@ -186,6 +219,9 @@ def test_work_items_are_the_slots_that_hold_a_row(name):
     (32768, 8, 4096, 14336, BF16, {}, (512, 1024, 512)),   # Mixtral, training
     (32768, 8, 14336, 4096, BF16, {}, (512, 1024, 512)),
     (4096, 8, 4096, 14336, BF16, {}, (512, 1024, 512)),
+    (24576, 16, 2048, 1024, BF16, {}, (512, 1024, 512)),   # a share, training
+    # float32 (a check's witness): half the columns, what VMEM holds
+    (12288, 16, 1024, 2048, jnp.float32, {}, (512, 512, 512)),
     (1024, 8, 4096, 14336, BF16, {}, (128, 256, 4096)),    # 128 rows a group
     # kernels.gmm_block_*: upper bounds on the choice
     (32768, 8, 4096, 14336, BF16, {"block_m": 256, "block_n": 512},

@@ -385,6 +385,39 @@ def test_grouped_matmul_fwd_bwd(chip):
              ((M, K), BF16), ((E, K, N), BF16), ((E,), jnp.int32))
 
 
+@pytest.mark.parametrize("block", [512, 1024])
+def test_flash_under_a_window_fwd_bwd(chip, block):
+    """The band grid at the expert-training cell's shapes: 2 x 8192, 32
+    query heads over 4, a window of 2048; the three kernels under their own
+    names."""
+    def loss(q, k, v):
+        return jnp.sum(flash_attention.flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            window=2048).astype(jnp.float32))
+
+    text = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    ((2, 8192, 32, D), BF16), ((2, 8192, 4, D), BF16),
+                    ((2, 8192, 4, D), BF16))
+    for name in ("flash_window_fwd", "flash_window_bwd_dkdv",
+                 "flash_window_bwd_dq"):
+        assert name in text
+    assert "flash_bwd_dq" not in text and "flash_bwd_dkdv" not in text
+
+
+def test_grouped_matmul_bwd_on_a_half_empty_row_buffer(chip):
+    """The expert-training cell's products: 24,576 buffer rows over 16
+    experts of 2048 x 1024, forward and both gradients."""
+    for K, N in ((2048, 1024), (1024, 2048)):
+        def loss(lhs, rhs, sizes):
+            return jnp.sum(grouped_matmul.gmm(lhs, rhs, sizes)
+                           .astype(jnp.float32))
+
+        text = _compile(chip, jax.grad(loss, argnums=(0, 1)),
+                        ((24576, K), BF16), ((16, K, N), BF16),
+                        ((16,), jnp.int32))
+        assert "grouped_matmul_dw" in text
+
+
 def test_kv_quantize_int8(chip):
     """One step's new K/V rows, one fp32 scale per head vector."""
     def f(x):
