@@ -48,6 +48,7 @@ from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.optimizer import (
     MixedPrecisionState, apply_mixed_precision_update, get_base_optimizer,
     init_mixed_precision)
+from deepspeed_tpu.runtime.param_stream import export_layer_schedule
 from deepspeed_tpu.runtime.prefetch import PrefetchingIterator
 from deepspeed_tpu.utils import memspace
 from deepspeed_tpu.utils.annotate import named, span, step_span
@@ -99,6 +100,28 @@ def initialize(
         client_optimizer=optimizer,
     )
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
+
+
+def zero3_compiler_options(stage: int, mesh_shape: Dict[str, int],
+                           platform: str) -> Dict[str, Any]:
+    """Compiler options of the train step program that follow from what
+    the job is, not from a knob. ZeRO-3 on a TPU mesh whose only wide
+    axes are data axes with fsdp among them: the gradient reduce-scatter
+    leaves the windowed-einsum form. There the compiler turns each weight
+    gradient's product-and-reduce-scatter into a ring of
+    collective-permutes whose last hops have nothing left to run under at
+    the end of a layer's backward, and the first synchronous collective of
+    the next layer waits out the skew they leave: a sixth of
+    train-z3-fsdp4-2k's step. As one fused product-and-reduce-scatter the
+    same bytes leave while the product runs (PERF.md, PR 44). With a
+    tensor- or sequence-parallel axis the windowed form is how those axes'
+    products overlap their collectives, so such a mesh keeps the
+    compiler's default, and so does every other stage and backend."""
+    wide = [a for a, n in mesh_shape.items() if n > 1]
+    if (platform == "tpu" and stage == 3 and mesh_shape.get("fsdp", 1) > 1
+            and all(a in ("dp", "fsdp") for a in wide)):
+        return {"xla_tpu_enable_windowed_einsum_for_reduce_scatter": False}
+    return {}
 
 
 class _FnModel:
@@ -287,6 +310,10 @@ class Engine:
         else:
             self.sp_plan = None
 
+        # after the planner: a depth it put on the model is a named one
+        self.layer_gather_ahead = self._resolve_gather_ahead(model, config,
+                                                             mesh)
+
         self.micro_batch_size = config.train_micro_batch_size_per_chip
         self.gradient_accumulation_steps = config.gradient_accumulation_steps
         self.train_batch_size = config.train_batch_size
@@ -429,6 +456,7 @@ class Engine:
                                   else ("layers",))
         self._rng = jax.random.PRNGKey(seed if seed is not None else config.seed)
         self._axes = model.logical_axes()
+        self.train_step_compiler_options = self._train_step_compiler_options()
         self._build_state()
         self._build_step_fns()
 
@@ -620,6 +648,56 @@ class Engine:
         mem_util.see_memory_usage("engine init: ready")
 
     # ------------------------------------------------------------------
+    def _resolve_gather_ahead(self, model, config, mesh) -> Tuple[int, str]:
+        """``(depth, reason)`` of the carried gather-ahead path in force
+        for this job's layer stack (runtime/param_stream.py
+        resolve_gather_ahead): what ``train.layer_gather_ahead`` reads
+        when the step is traced. It mirrors, in order, the branches of
+        models/transformer.py apply_hidden."""
+        from deepspeed_tpu.models.transformer import TransformerLM
+        from deepspeed_tpu.runtime.param_stream import resolve_gather_ahead
+
+        mcfg = getattr(model, "config", None)
+        streams = (isinstance(model, TransformerLM)
+                   and not mcfg.fpdt_host_residual)
+        explicit = getattr(getattr(config, "performance", None),
+                           "overlap_depth", None)
+        if explicit is None and streams and (
+                mcfg.overlap_depth
+                or os.environ.get("DSTPU_OVERLAP_DEPTH", "") != ""):
+            explicit = mcfg.overlap_depth      # the model's field, the env
+        zo = config.zero_optimization
+        depth, reason = resolve_gather_ahead(
+            explicit, streams_layers=streams, mesh_shape=dict(mesh.shape),
+            param_offload=(zo.offload_param is not None
+                           and zo.offload_param.device != "none"))
+        log_dist(f"layer gather-ahead: depth {depth} ({reason})", ranks=[0])
+        return depth, reason
+
+    def _train_step_compiler_options(self) -> Dict[str, Any]:
+        """:func:`zero3_compiler_options` for this engine's job, or
+        nothing where the mesh's compiler does not know them: it refuses
+        an unknown option by name, so it is asked with a program of
+        nothing before the train step depends on it."""
+        device = self.mesh.devices.flat[0]
+        options = zero3_compiler_options(
+            self.config.zero_optimization.stage, dict(self.mesh.shape),
+            device.platform)
+        if not options:
+            return {}
+        try:
+            probe = jax.ShapeDtypeStruct(
+                (), jnp.float32,
+                sharding=jax.sharding.SingleDeviceSharding(device))
+            jax.jit(lambda a: a).lower(probe).compile(
+                compiler_options=options)
+        except Exception as e:
+            logger.warning(f"the train step compiles with the compiler's "
+                           f"defaults: it refused {options}: {e}")
+            return {}
+        log_dist(f"train step compiler options: {options}", ranks=[0])
+        return options
+
     def _config_lr(self) -> float:
         if self.config.optimizer and "lr" in (self.config.optimizer.params or {}):
             return self.config.optimizer.params["lr"]
@@ -873,6 +951,8 @@ class Engine:
                                                  cfg.pipeline.schedule)
 
         def model_loss(params, batch):
+            export_layer_schedule(*self.layer_gather_ahead,
+                                  self.train_step_compiler_options)
             with shard_lib.qwz_context(qwz_bits), pp_defaults:
                 return self.model.loss(params, batch)
 
@@ -1081,8 +1161,9 @@ class Engine:
         donate = (0, 1, 2, 3)
         # stable program names: the device trace's module line and the
         # HLO say jit_dstpu_train_step, not jit_train_step or _unknown
-        self._jit_train_step = jax.jit(named(train_step, "dstpu_train_step"),
-                                       donate_argnums=donate)
+        self._jit_train_step = jax.jit(
+            named(train_step, "dstpu_train_step"), donate_argnums=donate,
+            compiler_options=self.train_step_compiler_options or None)
         self._jit_grad_step = jax.jit(named(grad_step, "dstpu_grad_step"))
         if self._onebit:
             self._jit_onebit = jax.jit(

@@ -255,6 +255,58 @@ def streamed_layers_prefetch(layer_fn: Callable[..., Any],
     return run(stacked_tree, x, tuple(extra))
 
 
+def resolve_gather_ahead(explicit: Optional[int], *, streams_layers: bool,
+                         mesh_shape, param_offload: bool) -> Tuple[int, str]:
+    """``(depth, reason)``: how many layers ahead a step on an fsdp mesh
+    gathers the parameter slices in the layer scan's carry (0: the plain
+    scan), from what the engine can observe of the job. A job that names
+    no depth gets the plain scan: on the chip the carried gathers cost
+    more than they hide (each carried layer is copied once an iteration,
+    and the compiler already issues the plain scan's gathers beside the
+    layer's products: PERF.md, PR 44), so the depth is a user's to name
+    (``performance.overlap_depth``), never a default."""
+    if not streams_layers:
+        return 0, "the model's layer stack does not run through the streamer"
+    if mesh_shape.get("pp", 1) > 1:
+        return 0, "pipeline axis"
+    if param_offload:
+        return 0, "parameter host offload streams the layers itself"
+    if mesh_shape.get("fsdp", 1) <= 1:
+        return 0, "no fsdp axis"
+    if explicit:
+        return int(explicit), f"overlap_depth {int(explicit)} named by the job"
+    if explicit is not None:
+        return 0, "overlap_depth 0 named by the job"
+    return 0, ("no depth named: carried gathers measured slower than the "
+               "plain scan on the chip")
+
+
+def export_layer_schedule(depth: int, reason: str, compiler_options) -> None:
+    """Publish what the engine chose for the layer stack's collectives
+    to the observability hub, at trace time (once a compiled program), as
+    ops/attention.py does a kernel choice; never instantiates a hub of
+    its own. ``train.layer_gather_ahead``: the carried depth in force (0:
+    the plain scan), with the reason as an event;
+    ``train.reduce_scatter_windowed``: 0 where the gradient
+    reduce-scatter was taken out of the compiler's windowed form
+    (runtime/engine.py zero3_compiler_options), else 1."""
+    try:
+        from deepspeed_tpu.observability.hub import peek_hub
+
+        hub = peek_hub()
+    except Exception:
+        hub = None
+    if hub is None:
+        return
+    hub.gauge("train.layer_gather_ahead", float(depth))
+    hub.record_event("layer_gather_ahead", depth=int(depth), reason=reason)
+    windowed = compiler_options.get(
+        "xla_tpu_enable_windowed_einsum_for_reduce_scatter", True)
+    hub.gauge("train.reduce_scatter_windowed", float(bool(windowed)))
+    if compiler_options:
+        hub.record_event("train_step_compiler_options", **compiler_options)
+
+
 def pin_to_host(tree: Any) -> Any:
     """Place a parameter subtree in pinned host memory, staged fp32
     (sub-32-bit host→device streaming is unsupported on current TPU

@@ -285,3 +285,294 @@ def test_fsdp_stage3_overlap_parity(devices):
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# What a ZeRO-3 job on an fsdp mesh gets without a knob: the plain scan
+# (the carried gathers are a named depth only: they lose on the chip) and,
+# on a TPU, the gradient reduce-scatter out of its windowed form
+# (Engine._resolve_gather_ahead, Engine._train_step_compiler_options)
+# ---------------------------------------------------------------------------
+
+
+def _cell_model(**overrides):
+    """The four-chip cell's architecture at toy widths: RMSNorm, SwiGLU,
+    rotary positions, grouped-query attention, untied head, bf16."""
+    from deepspeed_tpu.models.transformer import (TransformerConfig,
+                                                  TransformerLM)
+
+    kw = dict(vocab_size=64, hidden_size=32, num_layers=3, num_heads=4,
+              num_kv_heads=2, ffn_size=64, max_seq_len=16, pos_emb="rope",
+              norm="rmsnorm", activation="swiglu", tie_embeddings=False,
+              use_biases=False, dtype=jnp.bfloat16)
+    kw.update(overrides)
+    return TransformerLM(TransformerConfig(**kw))
+
+
+def _cell_job(**extra):
+    job = {"train_micro_batch_size_per_chip": 1,
+           "optimizer": {"type": "adamw", "params": {"lr": 3e-4}},
+           "zero_optimization": {"stage": 3},
+           "bf16": {"enabled": True},
+           "activation_checkpointing": {"policy": "nothing_saveable"},
+           "steps_per_print": 10 ** 9}
+    for k, v in extra.items():
+        job[k] = dict(job.get(k, {}), **v) if isinstance(v, dict) else v
+    return job
+
+
+def _engine(job, topology=None, model=None):
+    """The job on four of the CPU's devices, or on one (no mesh)."""
+    import deepspeed_tpu as dstpu
+    from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
+
+    mesh = build_mesh(TopologyConfig(**(topology or {"dp": 1})),
+                      devices=jax.devices()[:4 if topology else 1])
+    engine, _, _, _ = dstpu.initialize(model=model or _cell_model(),
+                                       config=job, mesh=mesh)
+    return engine
+
+
+def _tokens(engine, seed=0):
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, 64, (engine.train_batch_size, 16)).astype(np.int32)
+
+
+def _sub_jaxprs(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _sub_jaxprs(inner)
+
+
+def _scans_carrying(engine, shape):
+    """How many scans of the traced train step carry a value of
+    ``shape`` (one gathered layer leaf riding ahead of the compute), and
+    the step's jaxpr as text."""
+    batches = engine._next_microbatches(
+        iter([{"input_ids": _tokens(engine)}]), 1)
+    closed = jax.make_jaxpr(engine._jit_train_step)(
+        engine.params, engine.opt_state, engine.loss_scale_state,
+        engine.step_count, batches)
+    n = 0
+    for eqn in _sub_jaxprs(closed.jaxpr):
+        if eqn.primitive.name == "scan":
+            nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+            n += any(tuple(v.aval.shape) == shape
+                     for v in eqn.invars[nc:nc + nk])
+    return n, str(closed)
+
+
+FSDP4 = {"dp": 1, "fsdp": 4}
+# id, job extras, mesh, model overrides, depth, what the reason names
+RESOLUTIONS = [
+    ("stage3_fsdp4", {}, FSDP4, {}, 0, "no depth named"),
+    ("named_zero", {"performance": {"overlap_depth": 0}}, FSDP4, {}, 0,
+     "overlap_depth 0 named by the job"),
+    ("named_two", {"performance": {"overlap_depth": 2}}, FSDP4, {}, 2,
+     "overlap_depth 2 named by the job"),
+    ("named_on_the_model", {}, FSDP4, {"overlap_depth": 1}, 1,
+     "overlap_depth 1 named by the job"),
+    ("stage2_named", {"zero_optimization": {"stage": 2},
+                      "performance": {"overlap_depth": 1}}, FSDP4, {}, 1,
+     "named by the job"),
+    ("dots_saveable", {"activation_checkpointing":
+                       {"policy": "dots_saveable"}}, FSDP4, {}, 0,
+     "no depth named"),
+    ("no_mesh", {}, None, {}, 0, "no fsdp axis"),
+    ("no_mesh_named", {"performance": {"overlap_depth": 2}}, None, {}, 0,
+     "no fsdp axis"),
+]
+
+
+@pytest.mark.parametrize("case", RESOLUTIONS, ids=[c[0] for c in RESOLUTIONS])
+def test_gather_ahead_resolution(case):
+    """What the engine says of the job's layer stack is what the traced
+    step does: at a named depth the forward scan and the backward scan
+    each carry the next layer's gathered leaves; with none named (the
+    chip's reading: the carried gathers lose) and wherever the staged
+    path cannot run, no scan carries a layer leaf. The gauge reads the
+    depth and the event the reason, published when the step is traced."""
+    from deepspeed_tpu.observability.hub import get_hub, reset_hub
+
+    _, extra, topology, overrides, depth, names = case
+    reset_hub()
+    engine = _engine(_cell_job(**extra), topology, _cell_model(**overrides))
+    hub, events = get_hub(), []
+    hub.record_event = lambda kind, **f: events.append((kind, f))
+    try:
+        assert engine.layer_gather_ahead[0] == depth
+        assert names in engine.layer_gather_ahead[1]
+        # wq of one layer, gathered: [hidden, heads, head_dim]
+        carried, _ = _scans_carrying(engine, (32, 4, 8))
+        assert carried == (2 if depth else 0)
+        gauges = hub.snapshot()["gauges"]
+        assert gauges["train.layer_gather_ahead"] == depth
+        assert gauges["train.reduce_scatter_windowed"] == 1.0   # the CPU's
+        assert ("layer_gather_ahead",
+                {"depth": depth, "reason": engine.layer_gather_ahead[1]}
+                ) in events
+    finally:
+        engine.close()
+        reset_hub()
+
+
+@pytest.mark.parametrize("case", [
+    ("pipeline", {"pipeline": {"microbatches": 2},
+                  "performance": {"overlap_depth": 1}},
+     {"pp": 2, "dp": 1, "fsdp": 2}, "pipeline axis"),
+    ("host_offload", {"zero_optimization": {
+        "offload_optimizer": {"device": "cpu"},
+        "offload_param": {"device": "cpu"}}}, FSDP4,
+     "parameter host offload"),
+], ids=lambda c: c[0])
+def test_gather_ahead_stands_aside(case):
+    """The paths that fetch a layer's parameters their own way keep it,
+    a named depth or not, and the gauge says 0 for them."""
+    _, extra, topology, names = case
+    engine = _engine(_cell_job(**extra), topology)
+    try:
+        assert engine.layer_gather_ahead[0] == 0
+        assert names in engine.layer_gather_ahead[1]
+    finally:
+        engine.close()
+
+
+def test_one_chip_step_is_the_step_without_the_resolution(monkeypatch):
+    """No mesh: the engine leaves the model's config as it was handed
+    in, passes the compiler nothing, and the train step it traces is,
+    equation for equation, the one an engine without the resolution
+    traces."""
+    from deepspeed_tpu.runtime.engine import Engine
+
+    model = _cell_model()
+    before = model.config
+    engine = _engine(_cell_job(), None, model)
+    try:
+        assert engine.module.config == before
+        _, with_resolution = _scans_carrying(engine, (32, 4, 8))
+    finally:
+        engine.close()
+    monkeypatch.setattr(Engine, "_resolve_gather_ahead",
+                        lambda self, *a: (0, "as the parent"))
+    parent = _engine(_cell_job(), None, _cell_model())
+    try:
+        _, without = _scans_carrying(parent, (32, 4, 8))
+    finally:
+        parent.close()
+    assert with_resolution == without
+    assert "optimization_barrier" not in with_resolution
+    assert "custom_vjp" not in with_resolution
+
+
+RS_UNWINDOWED = {"xla_tpu_enable_windowed_einsum_for_reduce_scatter": False}
+# id, stage, mesh, platform, the options
+COMPILER_OPTIONS = [
+    ("zero3_fsdp4_on_a_tpu", 3, {"dp": 1, "fsdp": 4, "tp": 1}, "tpu",
+     RS_UNWINDOWED),
+    ("zero3_dp2_fsdp4_on_a_tpu", 3, {"dp": 2, "fsdp": 4}, "tpu",
+     RS_UNWINDOWED),
+    ("on_the_cpu", 3, {"dp": 1, "fsdp": 4}, "cpu", {}),
+    ("stage2", 2, {"dp": 1, "fsdp": 4}, "tpu", {}),
+    ("stage1", 1, {"dp": 1, "fsdp": 4}, "tpu", {}),
+    ("tensor_parallel_axis", 3, {"fsdp": 2, "tp": 2}, "tpu", {}),
+    ("sequence_parallel_axis", 3, {"fsdp": 2, "sp": 2}, "tpu", {}),
+    ("pipeline_axis", 3, {"pp": 2, "fsdp": 2}, "tpu", {}),
+    ("no_fsdp_axis", 3, {"dp": 4, "fsdp": 1}, "tpu", {}),
+    ("one_chip", 3, {"dp": 1, "fsdp": 1}, "tpu", {}),
+]
+
+
+@pytest.mark.parametrize("case", COMPILER_OPTIONS,
+                         ids=[c[0] for c in COMPILER_OPTIONS])
+def test_train_step_compiler_options_follow_the_job(case):
+    """ZeRO-3 over data axes with fsdp among them, on a TPU: the
+    gradient reduce-scatter leaves the windowed form. Any other stage,
+    mesh or backend compiles as before."""
+    from deepspeed_tpu.runtime.engine import zero3_compiler_options
+
+    _, stage, shape, platform, want = case
+    assert zero3_compiler_options(stage, shape, platform) == want
+
+
+@pytest.mark.parametrize("options,windowed", [({}, 1.0),
+                                              (RS_UNWINDOWED, 0.0)],
+                         ids=["compiler_default", "taken_off"])
+def test_layer_schedule_says_what_the_reduce_scatter_is(options, windowed):
+    """``train.reduce_scatter_windowed`` reads 0 where the engine took
+    the gradient reduce-scatter out of the windowed form, with the
+    options as an event; 1 and no event where the compiler's default
+    stands."""
+    from deepspeed_tpu.observability.hub import get_hub, reset_hub
+    from deepspeed_tpu.runtime.param_stream import export_layer_schedule
+
+    reset_hub()
+    hub, events = get_hub(), []
+    hub.record_event = lambda kind, **f: events.append((kind, f))
+    try:
+        export_layer_schedule(0, "no depth named", options)
+        assert hub.snapshot()["gauges"][
+            "train.reduce_scatter_windowed"] == windowed
+        assert [f for k, f in events
+                if k == "train_step_compiler_options"] == (
+                    [options] if options else [])
+    finally:
+        reset_hub()
+
+
+def test_a_compiler_that_refuses_the_options_gets_none(monkeypatch):
+    """The CPU's compiler knows no ``xla_tpu_`` option: asked for the
+    TPU's, the engine finds that out with a program of nothing and
+    compiles the step with the compiler's defaults; the cell's job as it
+    is (on the CPU) passes none in the first place."""
+    from deepspeed_tpu.runtime import engine as engine_mod
+
+    engine = _engine(_cell_job(), FSDP4)
+    try:
+        assert engine._train_step_compiler_options() == {}
+        monkeypatch.setattr(engine_mod, "zero3_compiler_options",
+                            lambda *a: dict(RS_UNWINDOWED))
+        assert engine._train_step_compiler_options() == {}
+    finally:
+        engine.close()
+
+
+PARITY = [
+    ("cell", {}),
+    ("cell_float32", {"dtype": jnp.float32}),
+    ("cell_multi_head", {"num_kv_heads": 4}),
+]
+
+
+@pytest.mark.parametrize("case", PARITY, ids=[c[0] for c in PARITY])
+def test_fsdp_stage3_named_depth_parity(case):
+    """The cell's own settings through ``dstpu.initialize``: the job as
+    the cell writes it (no ``performance`` block: the plain
+    rematerialised scan) against the same job at a named depth of one
+    (the carried gathers, whose custom VJP recomputes a layer from its
+    saved input as ``nothing_saveable`` does): the loss is the same bits,
+    the gradients agree to the compute dtype's rounding."""
+    _, overrides = case
+    bf16 = overrides.get("dtype", jnp.bfloat16) == jnp.bfloat16
+    job = _cell_job(bf16={"enabled": bf16})
+
+    def run(extra):
+        engine = _engine(dict(job, **extra), FSDP4, _cell_model(**overrides))
+        try:
+            assert engine.layer_gather_ahead[0] == (1 if extra else 0)
+            batch = engine.shard_batch({"input_ids": _tokens(engine)})
+            loss, grads = engine._jit_fwd_bwd(
+                engine.params, batch, jnp.asarray(1.0, jnp.float32))
+            return float(loss), jax.tree.map(np.asarray, grads)
+        finally:
+            engine.close()
+
+    loss_plain, g_plain = run({})
+    loss_staged, g_staged = run({"performance": {"overlap_depth": 1}})
+    assert loss_staged == loss_plain
+    tol = dict(rtol=2e-2, atol=2e-3) if bf16 else dict(rtol=2e-5, atol=2e-6)
+    for a, b in zip(jax.tree.leaves(g_staged), jax.tree.leaves(g_plain)):
+        np.testing.assert_allclose(a, b, **tol)

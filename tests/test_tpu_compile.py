@@ -651,3 +651,103 @@ def test_kimi_programs_keep_the_latent_pool_in_place(chip, program):
     assert 11.9 < mem.argument_size_in_bytes / 2**30 < 12.1
     print(program, mem.argument_size_in_bytes / 2**30,
           mem.temp_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30)
+
+
+def _zero3_fsdp4_step(monkeypatch, layers, job_extra=None):
+    """The train step of ``mistral-7b-train-c4``'s job at ``layers`` of
+    its 12, lowered on abstract state for the described 2x2: the engine
+    as a job builds it, but for its state, which a described device
+    cannot hold. Returns (engine, lowered step)."""
+    import json
+
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.config.config import load_config
+    from deepspeed_tpu.models.zoo import get_model
+    from deepspeed_tpu.ops import attention as attn_ops
+    from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
+    from deepspeed_tpu.runtime import engine as engine_mod
+
+    monkeypatch.setattr(attn_ops, "_flash_available", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "mistral-7b-train-c4.json")) as f:
+        cell = json.load(f)
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    mesh = build_mesh(TopologyConfig(**cell["mesh"]), devices=devices)
+    whole = NamedSharding(mesh, P())
+
+    def on(shapes, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            shapes, shardings)
+
+    class AbstractState(engine_mod.Engine):
+        def _build_state(self):
+            param_sh = self.plan.param_shardings(self._axes)
+            opt_sh = self.plan.opt_shardings(self._axes)
+
+            def init_fn(rng):
+                p32 = engine_mod._constrain_tree(self.model.init(rng), opt_sh)
+                mp = engine_mod.init_mixed_precision(p32, self.tx,
+                                                     shardings=opt_sh)
+                params = jax.tree.map(
+                    lambda m: m.astype(self.compute_dtype), mp.master)
+                return engine_mod._constrain_tree(params, param_sh), mp
+
+            rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole)
+            with jax.set_mesh(mesh):
+                placed = jax.jit(init_fn).lower(rng).compile().output_shardings
+            self.params, self.opt_state = on(jax.eval_shape(init_fn, rng),
+                                             placed)
+            self._param_shardings, self._opt_shardings = param_sh, opt_sh
+            self._setup_param_host_offload()
+            scale = jax.eval_shape(
+                lambda: engine_mod.init_loss_scale(self.config.fp16))
+            self.loss_scale_state = on(scale, jax.tree.map(lambda _: whole,
+                                                           scale))
+            self.step_count = jax.ShapeDtypeStruct((), jnp.int32,
+                                                   sharding=whole)
+
+    model = get_model(cell["preset"], num_layers=layers,
+                      max_seq_len=cell["seq_len"])
+    engine = AbstractState(
+        model, load_config(dict(cell["job"], seed=1, **(job_extra or {}))),
+        mesh=mesh)
+    batches = {"input_ids": jax.ShapeDtypeStruct(
+        (1, engine.train_batch_size, cell["seq_len"]), jnp.int32,
+        sharding=engine._batch_sharding(2))}
+    with jax.set_mesh(mesh):
+        lowered = engine._jit_train_step.lower(
+            engine.params, engine.opt_state, engine.loss_scale_state,
+            engine.step_count, batches)
+    return engine, lowered
+
+
+def test_zero3_fsdp4_step_reduces_gradients_outside_the_rings(
+        chip, monkeypatch):
+    """The four-chip cell's job, two layers of it: the engine hands the
+    TPU's compiler the option its job calls for, the compiler knows it,
+    and the backward scan of the compiled step holds the layer's weight
+    gradients' reductions as products that reduce-scatter as they run,
+    not as rings of collective-permutes: what is left of those is the
+    windowed all-gather of a weight the forward scan reads once."""
+    import re
+
+    engine, lowered = _zero3_fsdp4_step(monkeypatch, layers=2)
+    assert engine.layer_gather_ahead[0] == 0
+    options = engine._train_step_compiler_options()
+    assert options == {
+        "xla_tpu_enable_windowed_einsum_for_reduce_scatter": False}
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce-scatter" in text
+    # a ring hop of a gradient carries a quarter of a leaf in its own
+    # layout: [1024, ...] of [4096, ...], or [..., 1024] of [..., 4096]
+    hops = re.findall(
+        r"= \(?(bf16\[[\d,]+\])[^=\n]* collective-permute-start\(", text)
+    assert hops and not [h for h in hops if h in (
+        "bf16[1024,14336]", "bf16[14336,1024]", "bf16[1024,32,128]",
+        "bf16[32,128,1024]", "bf16[1024,8,128]")], hops
