@@ -79,7 +79,9 @@ class InferenceEngine:
         self.max_seq_len = max_seq_len or self.cfg.max_seq_len
         self._dtype = dtype
 
-        axes = model.logical_axes()
+        # (a model may take its tree in more than one layout: models/hybrid.py)
+        axes = model.axes_for(params) if params is not None and hasattr(
+            model, "axes_for") else model.logical_axes()
         self._param_specs = jax.tree.map(
             lambda la: spec_from_logical(la, TP_PARAM_RULES), axes,
             is_leaf=lambda x: isinstance(x, tuple) and all(

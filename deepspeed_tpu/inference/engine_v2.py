@@ -219,6 +219,12 @@ class InferenceEngineV2:
         # runs the gather program, and what would read a K/V page by its
         # heads refuses by name (_refuse_for_latent_pool)
         self._latent = bool(getattr(self.cfg, "latent_dim", 0))
+        # windowed latent layers keep a pool of their own, a ring of pages a
+        # sequence (ragged/kv_cache.py, WindowedLatentPool): what would copy,
+        # share or restore a sequence's pages without knowing the ring
+        # refuses by name (_refuse_for_windowed_pool), and the prefix cache
+        # is off (a skipped prefix would leave the ring without its rows)
+        self._windowed = bool(getattr(self.cfg, "window_latent_dim", 0))
         # smallest chunk bucket of the prefill program (powers of two from
         # here: _plan_prefill_segments)
         self._min_segment = 8
@@ -226,6 +232,19 @@ class InferenceEngineV2:
             self._refuse_for_latent_pool(
                 "speculative decoding (spec_decode / drafter), which "
                 "verifies its drafts through the gather program,")
+        if self._windowed:
+            if host_kv_tier:
+                self._refuse_for_windowed_pool(
+                    "the host KV tier (host_kv_tier)")
+            if prefix_cache:
+                log_dist(
+                    "InferenceEngineV2: the model has windowed latent "
+                    "layers, whose pages are a ring a sequence writes over: "
+                    "prefix cache OFF (no hit is taken); host-tier parking, "
+                    "session migration, the disagg hand-off and speculative "
+                    "decoding are refused (WindowedPoolUnsupported)",
+                    ranks=[0])
+            prefix_cache = False
         if self._recurrent:
             self._init_recurrent(kv_quant_bits, host_kv_tier,
                                  spec_decode or drafter is not None)
@@ -255,10 +274,22 @@ class InferenceEngineV2:
             num_blocks=kv_blocks, dtype=dtype, quant_bits=kv_quant_bits,
             compressed_per_block=sparse.per_block if self._sparse else 0,
             kind="latent" if self._latent else "kv",
-            latent_dim=getattr(self.cfg, "latent_dim", 0))
+            latent_dim=getattr(self.cfg, "latent_dim", 0),
+            index_key_dim=getattr(self.cfg, "index_key_dim", 0))
         self.kv_cache = BlockedKVCache(kv_cfg, mesh=self.mesh)
         if self._hybrid and not self._recurrent:
             self.kv_cache.pools_as_dict = True     # no state pool says so
+        if self._windowed:
+            from deepspeed_tpu.inference.ragged import (WindowedLatentPool,
+                                                        WindowPoolConfig)
+
+            # every sequence of a step its whole ring, and the scratch page
+            self.kv_cache.window_pool = WindowedLatentPool(
+                WindowPoolConfig.for_sequences(
+                    max_seqs_per_step, layers=self.cfg.window_layers,
+                    window=self.cfg.sliding_window,
+                    row_dim=self.cfg.window_latent_dim,
+                    block_size=kv_block_size, dtype=dtype))
         # disagg handoff wire codec mode ("auto"/"raw"/"int8"/"int4");
         # consumed by serving/disagg.py serialize_prefix
         self._handoff_wire = handoff_wire
@@ -395,7 +426,8 @@ class InferenceEngineV2:
                               moe_experts_hit_decode=0,
                               moe_work_items_decode=0, state_slots_in_use=0,
                               state_slots=pool.total_slots if pool else 0,
-                              compressed_keys_in_use=0)
+                              compressed_keys_in_use=0,
+                              window_pages_in_use=0)
         # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
         # span, of the spans nested in it, and of the request tracer's
         # PREFILL / DECODE_EMIT spans of that step
@@ -530,11 +562,14 @@ class InferenceEngineV2:
         per-token steps for zero scheduling benefit."""
         blocks = self.kv_cache.blocks_needed(prompt_len + 1)
         pool = self.kv_cache.state_pool
+        wpool = self.kv_cache.window_pool
         if (blocks > self.max_blocks_per_seq
                 or len(self.state.seqs) >= self.max_seqs
                 or len(self.state.seqs)
                 >= self.state.max_tracked_sequences
-                or (pool is not None and pool.free_slots == 0)):
+                or (pool is not None and pool.free_slots == 0)
+                or (wpool is not None and wpool.free_blocks
+                    < wpool.config.ring_pages)):
             return False
         committed = 0
         for s in self.state.seqs.values():
@@ -845,6 +880,7 @@ class InferenceEngineV2:
         from host memory. Returns None when there is nothing warm to
         capture (unknown uid, mid-prefill, queued-but-never-admitted):
         the caller degrades to the legacy fold-and-resubmit path."""
+        self._refuse_for_windowed_pool("the session-migration wire")
         self._refuse_for_latent_pool("the session-migration wire")
         self._refuse_without_snapshot("session migration "
                                       "(migrate_out_session)")
@@ -914,6 +950,7 @@ class InferenceEngineV2:
           engine (per-seq cap): counted and closed, mirroring
           ``_requeue``'s cap-truncation contract.
         """
+        self._refuse_for_windowed_pool("the session-migration wire")
         self._refuse_for_latent_pool("the session-migration wire")
         self._refuse_without_snapshot("session migration "
                                       "(install_migrated_session)")
@@ -1082,16 +1119,39 @@ class InferenceEngineV2:
                 f"{what} is not built for a latent pool (one vector a "
                 "token, no K/V pair, no head axis: ragged/kv_cache.py)")
 
-    def _state_slots_arg(self, seqs) -> Tuple:
-        """The step programs' trailing argument for a model with recurrent
-        layers: each batch slot's state-pool slot (scratch where empty)."""
-        pool = self.kv_cache.state_pool
-        if pool is None:
-            return ()
-        slots = np.full(self.max_seqs, pool.scratch_slot, np.int32)
-        for i, s in enumerate(seqs):
-            slots[i] = s.state_slot
-        return (jnp.asarray(slots),)
+    def _refuse_for_windowed_pool(self, what: str) -> None:
+        """Raise the named error for an operation that would copy, share or
+        restore a sequence's pages without knowing its ring in the windowed
+        pool (no-op for other models)."""
+        if self._windowed:
+            from deepspeed_tpu.inference.ragged import WindowedPoolUnsupported
+
+            raise WindowedPoolUnsupported(
+                f"{what} is not built for a model with windowed latent "
+                "layers (a ring of pages a sequence writes over as its "
+                "window moves on: ragged/kv_cache.py)")
+
+    def _pool_args(self, seqs) -> Dict[str, Any]:
+        """The step programs' keyword arguments beside the block table:
+        ``state_slots`` for a model with recurrent layers (each batch slot's
+        state-pool slot, scratch where empty), ``window_table`` for one with
+        windowed latent layers (each batch slot's ring of pages in the
+        windowed pool, the scratch page where a slot or an entry is
+        empty)."""
+        out = {}
+        pool, wpool = self.kv_cache.state_pool, self.kv_cache.window_pool
+        if pool is not None:
+            slots = np.full(self.max_seqs, pool.scratch_slot, np.int32)
+            for i, s in enumerate(seqs):
+                slots[i] = s.state_slot
+            out["state_slots"] = jnp.asarray(slots)
+        if wpool is not None:
+            ring = np.full((self.max_seqs, wpool.config.ring_pages),
+                           wpool.scratch_block, np.int32)
+            for i, s in enumerate(seqs):
+                ring[i, :len(s.window_blocks)] = s.window_blocks
+            out["window_table"] = jnp.asarray(ring)
+        return out
 
     def _fetch_counters(self, calls) -> None:
         """Add what a step's program calls counted to ``stats``, each under
@@ -1115,6 +1175,8 @@ class InferenceEngineV2:
         self.stats["state_slots_in_use"] = pool.slots_in_use if pool else 0
         self.stats["compressed_keys_in_use"] = \
             self.kv_cache.compressed_keys_in_use
+        wpool = self.kv_cache.window_pool
+        self.stats["window_pages_in_use"] = wpool.pages_in_use if wpool else 0
 
     def holds_prefix_blocks(self, tokens) -> int:
         """How many full prefix blocks of ``tokens`` this engine can
@@ -1254,7 +1316,8 @@ class InferenceEngineV2:
             call_of.update(dict.fromkeys(part, self._step_calls))
             with self.mesh:
                 with span("build_batch"):
-                    fn, program, args, batch = self._build_step_call(mine)
+                    fn, program, args, pools, batch = \
+                        self._build_step_call(mine)
                     shape = (dict(zip(("S", "tq"), args[0].shape))
                              if program == "prefill" else {})
                 with self._dispatch(
@@ -1263,7 +1326,7 @@ class InferenceEngineV2:
                         chunks=sum(sp < len(seq.input_tokens)
                                    for seq, _, sp in mine), **shape):
                     logits, new_kv = fn(self.params, self.kv_cache.kv_state,
-                                        *args)
+                                        *args, **pools)
             # the program consumed (donated) the handle it was given
             self.kv_cache.set_kv_state(new_kv)
             counted.append((new_kv, program == "decode"))
@@ -1435,18 +1498,19 @@ class InferenceEngineV2:
     def _build_step_call(self, scheduled):
         """Pick the program for this part of a step and assemble its host
         arrays: ``(jitted fn, program name, arguments after params and
-        KV, the ragged batch)``. On the kernel path ``decode`` when every
+        KV, the pools' keyword arguments (_pool_args), the ragged
+        batch)``. On the kernel path ``decode`` when every
         sequence advances one token (tokens line up with slots, so the
         compact paged-kernel path applies), else ``prefill`` (the part is
         chunks: _split_by_program); off it the flat ``gather`` program."""
         batch = build_ragged_batch(scheduled, self.max_tokens,
                                    self.max_seqs, self.max_blocks_per_seq)
-        slots_arg = self._state_slots_arg([seq for seq, _, _ in scheduled])
+        pools = self._pool_args([seq for seq, _, _ in scheduled])
         if not self._use_paged_kernel:
             return self._step_fn, "gather", (
                 jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
                 jnp.asarray(batch.token_pos), jnp.asarray(batch.block_table),
-                jnp.asarray(batch.num_tokens, jnp.int32), *slots_arg), batch
+                jnp.asarray(batch.num_tokens, jnp.int32)), pools, batch
         if all(len(nt) == 1 for _, nt, _ in scheduled):
             # compact per-slot arrays: token i belongs to slot i; pad
             # out to max_seqs (token budget may be smaller than the
@@ -1459,12 +1523,11 @@ class InferenceEngineV2:
             return self._decode_fn, "decode", (
                 jnp.asarray(d_tok), jnp.asarray(d_pos),
                 jnp.asarray(batch.block_table),
-                jnp.asarray(batch.ctx_lens), *slots_arg), batch
+                jnp.asarray(batch.ctx_lens)), pools, batch
         seg_plan = self._plan_prefill_segments(scheduled)
         n_segs = seg_plan[0].shape[0]
         return self._prefill_fn, "prefill", (
-            *seg_plan, jnp.asarray(batch.block_table[:n_segs]),
-            *slots_arg), batch
+            *seg_plan, jnp.asarray(batch.block_table[:n_segs])), pools, batch
 
     def _plan_prefill_segments(self, scheduled):
         """Per-slot padded chunk layout for the prefill program
@@ -1572,12 +1635,13 @@ class InferenceEngineV2:
                     ctx[i] = s.seen_tokens + 1
                     bt[i, :len(s.kv_blocks)] = s.kv_blocks
                 args = (jnp.asarray(d_tok), jnp.asarray(d_pos),
-                        jnp.asarray(bt), jnp.asarray(ctx),
-                        *self._state_slots_arg(live))
+                        jnp.asarray(bt), jnp.asarray(ctx))
+                pools = self._pool_args(live)
             with self._dispatch("multi_decode", live, K * len(live),
                                 token_steps=K):
                 toks, new_kv = self._multi_decode_fn(
-                    self.params, self.kv_cache.kv_state, *args, steps=K)
+                    self.params, self.kv_cache.kv_state, *args, steps=K,
+                    **pools)
             # at once: the handle the cache still holds is consumed
             self.kv_cache.set_kv_state(new_kv)
             with span("fetch"):

@@ -38,12 +38,29 @@ do not tell the models apart. What differs:
   pages (``mla_absorb`` / ``mla_attn``), a chunk the expanded form over its
   sequence's cached latents, up-projected a block of context at a time
   (``mla_chunk``); the prologue's dense layers run before the scan
-  (``dense_ffn``); the gather program is not built.
+  (``dense_ffn``); the gather program is not built;
+* with the learned selector (``index_topk``) the pools hold ``ik`` too, the
+  selector's key a token, page-addressed beside ``kv``: a full layer's token
+  step scores every cached key (``dsa_index``: the kernel of that name),
+  keeps the ``index_topk`` highest exactly (``dsa_select``) and runs the
+  absorbed form over the sequence's pages with the choice as one more term
+  of the ``mla_decode`` kernel's mask (``dsa_attn``); a chunk gets the choice
+  as one more term of the expanded form's mask;
+* with windowed latent layers (``window_attention_kind`` "mla": the third
+  mixer kind of ``_run_stack``, scope ``wmla``) the pools hold ``wkv``, the
+  windowed pool (``ragged/kv_cache.py``), and one more trailing argument,
+  ``window_table [S, ring_pages]``: each sequence's ring of pages. A token
+  step writes the token's latent to ring entry ``(pos // page) % ring_pages``
+  and runs the absorbed form over the ring from the window's first page on
+  under a lower bound (``wmla_attn``); a chunk attends over the ``window -
+  1`` tokens before it (read from the ring first) and its own latents under
+  the band (``wmla_chunk``), then writes its last rows.
 
 ``counters`` comes back as this call's ``[moe_token_layers, moe_local_pairs,
 moe_experts_hit, moe_work_items, sparse_blocks_selected,
 sparse_blocks_visible, sparse_dense_tokens, mla_context_tokens,
-mla_pages_read]`` (``state_pool.COUNTERS``; summed over the steps of a
+mla_pages_read, dsa_rows_selected, dsa_rows_visible, window_rows_read,
+window_pages_recycled]`` (``state_pool.COUNTERS``; summed over the steps of a
 burst).
 """
 
@@ -59,7 +76,10 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.inference.model_runner import (_kv_write, _paged_decode,
                                                   _segment_attention)
-from deepspeed_tpu.inference.ragged.state_pool import (COUNTERS, MOE_COUNTERS,
+from deepspeed_tpu.inference.ragged.state_pool import (COUNTERS,
+                                                       DSA_COUNTERS,
+                                                       MLA_COUNTERS,
+                                                       MOE_COUNTERS,
                                                        SPARSE_COUNTERS)
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.hybrid import HybridConfig
@@ -72,6 +92,8 @@ from deepspeed_tpu.runtime.sharding import effective_dtype
 
 _MOE = len(MOE_COUNTERS)    # where the expert blocks' counters end
 _SPARSE = _MOE + len(SPARSE_COUNTERS)   # and the sparse rule's; then latent
+_MLA = _SPARSE + len(MLA_COUNTERS)      # attention's; then the selector's
+_WINDOW = _MLA + len(DSA_COUNTERS)      # and the windowed latent layers'
 
 
 def _no_counts():
@@ -80,33 +102,40 @@ def _no_counts():
     return jnp.zeros((len(COUNTERS),), jnp.int32)
 
 
-def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
+def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid,
+               win_fn=None):
     """The layer loop. ``x`` has any leading shape; ``rec_fn(y, mp, l_rec,
-    pools) -> (out, pools)`` and ``full_fn(y, ap, l_kv, pools) -> (out,
-    pools)`` are the two mixers on normed input; ``valid`` marks the real
+    pools) -> (out, pools)``, ``full_fn(y, ap, l_kv, pools) -> (out,
+    pools)`` and ``win_fn(y, wp, l_win, pools) -> (out, pools)`` are the
+    mixers on normed input (recurrent, full, windowed latent: each indexed
+    among its own layers); ``valid`` marks the real
     tokens (flat, for the experts' counters). The layers run as
     ``cfg.stack_plan`` lays them out: a scan over the repeats of the pattern
     and, inside, each run of one kind scanned (a lone layer called), so a
     program holds one layer body a run whatever the depth. ``pools`` is the
     carry, ``pools["counters"]`` what this call counted. Returns (x, pools')."""
-    if any(cfg.layer_windows):
-        # (its pages would have to be given back as the window passes them)
+    if any(cfg.layer_windows) and not cfg.window_layers:
+        # (latent windowed layers keep a ring of pages in a pool of their
+        # own; a window over the paged K/V pool has no such layer kind yet)
         raise NotImplementedError(
-            "windowed attention is not served yet: the paged cache has no "
-            "layer kind that frees pages behind a sliding window")
+            "windowed gated softmax attention is not served yet: only "
+            "latent windowed layers have a pool whose pages are reused "
+            "behind the window (ragged/kv_cache.py, WindowedLatentPool)")
     if cfg.post_norms:
         raise NotImplementedError(
             "post-branch norms are not served yet")
     reps, runs = cfg.stack_plan
     K = cfg.dense_layers
     per = (cfg.num_layers - K) // reps
-    in_period = {True: sum(n for full, n in runs if full)}
-    in_period[False] = per - in_period[True]
+    kinds = (True, False, "w")
+    in_period = {k: sum(n for kind, n in runs if kind == k) for k in kinds}
     lead, H = x.shape[:-1], x.shape[-1]
     experts = params["experts"]
     full_kind = "mla" if cfg.attention_kind == "mla" else "attn"
     mixers = {True: params[full_kind],
-              False: params.get(cfg.recurrent_kind)}
+              False: params.get(cfg.recurrent_kind), "w": params.get("wmla")}
+    fns = {True: (full_fn, full_kind), False: (rec_fn, cfg.recurrent_kind),
+           "w": (win_fn, "wmla")}
 
     def at(tree, i):
         # a layer's leaves read where they lie in the stack: a run's slice
@@ -116,8 +145,7 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
             lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
 
     def body(full, prologue=False):
-        mixer, scope = (full_fn, full_kind) if full else (rec_fn,
-                                                          cfg.recurrent_kind)
+        mixer, scope = fns[full]
 
         def layer(carry, where):
             x, pools = carry
@@ -139,11 +167,10 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
         return layer
 
     # mixer-layer index of the first layer after the prologue, by kind
-    base = {True: sum(cfg.layer_kinds[:K])}
-    base[False] = K - base[True]
+    base = {k: sum(kind == k for kind in cfg.mixer_kinds[:K]) for k in kinds}
 
     def period(carry, r):
-        first = {True: 0, False: 0, None: 0}
+        first = {True: 0, False: 0, "w": 0, None: 0}
         for full, n in runs:
             steps = jnp.arange(n, dtype=jnp.int32)
             where = (K + r * per + first[None] + steps,
@@ -157,9 +184,9 @@ def _run_stack(cfg: HybridConfig, params, x, pools, rec_fn, full_fn, valid):
         return carry, None
 
     carry = (x, dict(pools, counters=_no_counts()))
-    seen = {True: 0, False: 0}
+    seen = dict.fromkeys(kinds, 0)
     for l in range(K):      # the prologue: dense layers, outside the scan
-        full = cfg.layer_kinds[l]
+        full = cfg.mixer_kinds[l]
         carry, _ = body(full, prologue=True)(
             carry, (jnp.int32(l), jnp.int32(seen[full])))
         seen[full] += 1
@@ -335,42 +362,150 @@ def _no_gather():
         "the prefill and decode programs")
 
 
+def _padded(x, width: int):
+    """x [..., d] with zeros up to a pool's lane-padded row width."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def _mla_write(cfg, mp, y, pos, pools, l, page, offset):
     """The mixer's projections and the tokens' latents written to their rows
     of the latent pool (zeros up to the pool's lane-padded width). Returns
-    (q_n, q_r, pools')."""
+    (q_n, q_r, pools') and, of a mixer with the selector, the normed query
+    latent its queries read as a fourth value."""
     with jax.named_scope("mla_project"):
-        q_n, q_r, latent = hybrid.mla_project(cfg, mp, y, pos)
+        q_n, q_r, latent, *cq0 = hybrid.mla_project(
+            cfg, mp, y, pos, query_latent=bool(cfg.index_topk))
         kv = pools["kv"]
-        pad = kv.shape[-1] - latent.shape[-1]
-        latent = jnp.pad(latent, [(0, 0)] * (latent.ndim - 1) + [(0, pad)])
-        kv = kv.at[l, page, offset].set(latent.astype(kv.dtype))
-    return q_n, q_r, dict(pools, kv=kv)
+        kv = kv.at[l, page, offset].set(
+            _padded(latent, kv.shape[-1]).astype(kv.dtype))
+    return (q_n, q_r, dict(pools, kv=kv), *cq0)
+
+
+def _absorbed_decode(cfg, mp, q_n, q_r, kv, l, table, ctx, windowed=False,
+                     lower=None, chosen=None, scope="mla_attn"):
+    """A token step of latent attention in its absorbed form: each head's
+    query carried into the latent's coordinates (``mla_absorb``), the
+    ``mla_decode`` kernel over the pages ``table`` lists of ``kv[l]`` (keys
+    and values the same rows; under ``scope``; ``lower`` / ``chosen``: the
+    kernel's window bound and the selector's choice), the result carried
+    back to the head's values. Returns (o [S, n, v], pages fetched [S])."""
+    from deepspeed_tpu.ops.pallas.paged_attention import mla_decode_attention
+
+    z = cfg.mla_sizes(windowed)
+    W = kv.shape[3]
+    with jax.named_scope("mla_absorb"):
+        q = _padded(jnp.concatenate(
+            [hybrid.mla_absorb_q(cfg, mp, q_n), q_r], -1), W)
+    with jax.named_scope(scope):
+        o, fetched = mla_decode_attention(
+            q.astype(kv.dtype), kv, table, ctx, value_dim=z.kv_rank,
+            scale=z.scale, layer=l, lower=lower, chosen=chosen)
+    with jax.named_scope("mla_absorb"):
+        o = hybrid.mla_absorb_o(cfg, mp, o.astype(q_n.dtype), windowed)
+    return o, fetched
 
 
 def _mla_decode(cfg, mp, q_n, q_r, pools, l, block_table, context_lens):
-    """A token step of latent attention in its absorbed form: each head's
-    query carried into the latent's coordinates, the ``mla_decode`` kernel
-    over the sequence's own pages (keys and values the same rows), the
-    result carried back to the head's values. Returns (o [S, n, v],
-    pools')."""
-    from deepspeed_tpu.ops.pallas.paged_attention import mla_decode_attention
-
-    kv = pools["kv"]
-    W = kv.shape[3]
-    with jax.named_scope("mla_absorb"):
-        q = jnp.concatenate([hybrid.mla_absorb_q(cfg, mp, q_n), q_r], -1)
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, W - q.shape[-1])))
-    with jax.named_scope("mla_attn"):
-        o, fetched = mla_decode_attention(
-            q.astype(kv.dtype), kv, block_table, context_lens,
-            value_dim=cfg.kv_lora_rank, scale=cfg.mla_scale, layer=l)
-    with jax.named_scope("mla_absorb"):
-        o = hybrid.mla_absorb_o(cfg, mp, o.astype(q_n.dtype))
+    """A token step of dense latent attention over the sequence's own pages.
+    Returns (o [S, n, v], pools')."""
+    o, fetched = _absorbed_decode(cfg, mp, q_n, q_r, pools["kv"], l,
+                                  block_table, context_lens)
     # asked of the kernel, and what the kernel counted as it fetched
     counts = jnp.stack([jnp.sum(context_lens), jnp.sum(fetched)])
-    return o, dict(pools, counters=pools["counters"].at[_SPARSE:].add(
+    return o, dict(pools, counters=pools["counters"].at[_SPARSE:_MLA].add(
         counts.astype(jnp.int32)))
+
+
+def _index_write(cfg, mp, y, cq0, pos, pools, l, page, offset):
+    """The selector's projections, and the tokens' keys written beside their
+    latents (``ik``, page-addressed as ``kv``). Returns (qI, w, pools')."""
+    qi, ki, w = hybrid.index_project(cfg, mp, y, cq0, pos)
+    ik = pools["ik"]
+    return qi, w, dict(pools, ik=ik.at[l, page, offset].set(
+        ki.astype(ik.dtype)))
+
+
+def _dsa_decode(cfg, mp, y, cq0, q_n, q_r, pools, l, block_table, token_pos,
+                context_lens, page, offset):
+    """A token step of a full latent layer with the selector: score every
+    cached token's indexer key (``dsa_index``: the kernel of that name over
+    the sequence's own pages of keys), keep the ``index_topk`` highest,
+    exactly (``dsa_select``: the k-th score found by its bits, no sort;
+    every token of a shorter context), and run the absorbed form over the
+    sequence's own pages with the choice as one more term of the kernel's
+    mask (``dsa_attn``). The pages are walked whole: on this chip a gather
+    of 2,048 chosen rows a sequence costs more than reading the context
+    (1.5 ms a layer against 1.2 GB at the memory's rate; PERF.md section
+    6, PR 45). Returns (o [S, n, v], pools')."""
+    from deepspeed_tpu.ops.pallas.paged_attention import index_scores_decode
+
+    Bm, bs = block_table.shape[1], pools["kv"].shape[2]
+    with jax.named_scope("dsa_index"):
+        qi, w, pools = _index_write(cfg, mp, y, cq0, token_pos, pools, l,
+                                    page, offset)
+        scores = index_scores_decode(qi.astype(pools["ik"].dtype), w,
+                                     pools["ik"], block_table, context_lens,
+                                     layer=l)
+    with jax.named_scope("dsa_select"):
+        seen = jnp.arange(Bm * bs)[None, :] < context_lens[:, None]
+        chosen = hybrid.topk_mask(jnp.where(seen, scores, 0.0), seen,
+                                  cfg.index_topk)
+    o, _ = _absorbed_decode(cfg, mp, q_n, q_r, pools["kv"], l, block_table,
+                            context_lens, chosen=chosen, scope="dsa_attn")
+    counts = jnp.stack([jnp.sum(jnp.minimum(context_lens, cfg.index_topk)),
+                        jnp.sum(context_lens)])
+    return o, dict(pools, counters=pools["counters"].at[_MLA:_WINDOW].add(
+        counts.astype(jnp.int32)))
+
+
+def _ring(window_table, pos, bs: int):
+    """The ring page of each position: entry ``(pos // bs) % ring_pages`` of
+    the sequence's row of ``window_table`` [S, R]; pos [S] or [S, T]."""
+    entry = (pos // bs) % window_table.shape[1]
+    if pos.ndim == 1:
+        return jnp.take_along_axis(window_table, entry[:, None], axis=1)[:, 0]
+    return jnp.take_along_axis(window_table, entry, axis=1)
+
+
+def _window_counts(pools, rows_read, pos, real, bs: int, ring: int):
+    """``pools`` with a windowed layer's counters added: the rows its
+    queries read, and the ring pages a real token at ``pos`` began to write
+    over (the first row of a page whose entry held an older page)."""
+    recycled = real & (pos % bs == 0) & (pos // bs >= ring)
+    counts = jnp.stack([jnp.sum(rows_read), jnp.sum(recycled)])
+    return dict(pools, counters=pools["counters"].at[_WINDOW:].add(
+        counts.astype(jnp.int32)))
+
+
+def _wmla_decode(cfg, wp, y, pools, l, window_table, token_pos, context_lens):
+    """A token step of a windowed latent layer: the token's latent written
+    to its ring page, then the absorbed form over the ring's pages from the
+    window's first on (the table turned so that entry 0 is that page), with
+    the window's far edge as the kernel's lower bound. Returns (o [S, n, v],
+    pools')."""
+    wkv = pools["wkv"]
+    bs, R, W = wkv.shape[2], window_table.shape[1], cfg.sliding_window
+    alive = context_lens > 0
+    with jax.named_scope("mla_project"):
+        q_n, q_r, latent = hybrid.mla_project(cfg, wp, y, token_pos,
+                                              windowed=True)
+        page = jnp.where(alive, _ring(window_table, token_pos, bs),
+                         wkv.shape[1] - 1)
+        offset = jnp.where(alive, token_pos % bs, bs - 1)
+        wkv = wkv.at[l, page, offset].set(
+            _padded(latent, wkv.shape[-1]).astype(wkv.dtype))
+    lo = jnp.maximum(context_lens - W, 0)       # the first position seen
+    first = lo // bs                            # its page, along the context
+    turned = jnp.take_along_axis(
+        window_table, (first[:, None] + jnp.arange(R)[None, :]) % R, axis=1)
+    ctx = jnp.where(alive, context_lens - first * bs, 0)
+    o, _ = _absorbed_decode(cfg, wp, q_n, q_r, wkv, l, turned, ctx,
+                            windowed=True, lower=lo - first * bs,
+                            scope="wmla_attn")
+    pools = _window_counts(dict(pools, wkv=wkv),
+                           jnp.minimum(context_lens, W), token_pos, alive, bs,
+                           R)
+    return o, pools
 
 
 # context tokens whose keys and values the chunk path holds expanded at once
@@ -378,13 +513,16 @@ _MLA_CHUNK_KEYS = 512
 
 
 @jax.named_scope("mla_chunk")
-def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens):
+def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens,
+               selected=None):
     """Chunk attention in the expanded form, one segment after another:
     each attends over its own sequence's cached latents (this chunk's are
     written already), up-projected to keys and values ``_MLA_CHUNK_KEYS``
     context tokens at a time under a running softmax, and no further than
     the segment's context: nothing holds a sequence's expanded keys (0.75
-    GiB a layer at 24k tokens). q_n, q_r [S, Tq, n, .]; pos [S, Tq].
+    GiB a layer at 24k tokens). q_n, q_r [S, Tq, n, .]; pos [S, Tq];
+    ``selected`` bool [S, Tq, Bm * bs] or None: the context tokens the
+    selector kept for each query, one more term of the mask.
     Returns o [S, Tq, n, v] in q's type."""
     S, Tq = pos.shape
     bs, Bm = kv.shape[2], block_table.shape[1]
@@ -392,8 +530,11 @@ def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens):
     Tk = min(_MLA_CHUNK_KEYS, Bm * bs)
     scale = cfg.mla_scale
 
+    if selected is None:        # (a mask of nothing: one shape of the map)
+        selected = jnp.ones((S, 1, 1), bool)
+
     def segment(args):
-        qn, qr, table, ts, ctx = args
+        qn, qr, table, ts, ctx, sel = args
         rows = with_layout_constraint(
             kv[l, table], Layout(major_to_minor=(0, 1, 2))).reshape(
                 Bm * bs, -1)
@@ -406,6 +547,8 @@ def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens):
                   + jnp.einsum("qnd,kd->nqk", qr, k_r)).astype(
                       jnp.float32) * scale
             seen = (b * Tk + jnp.arange(Tk))[None, :] <= ts[:, None]
+            if sel.shape[-1] > 1:
+                seen = seen & lax.dynamic_slice_in_dim(sel, b * Tk, Tk, 1)
             sc = jnp.where(seen[None], sc, -1e30)
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
             alpha = jnp.exp(m - m_new)
@@ -422,7 +565,98 @@ def _mla_chunk(cfg, mp, q_n, q_r, kv, l, block_table, pos, ctx_lens):
         o = acc / jnp.where(den == 0.0, 1.0, den)[..., None]
         return jnp.swapaxes(o, 0, 1).astype(dt)
 
-    return lax.map(segment, (q_n, q_r, block_table, pos, ctx_lens))
+    return lax.map(segment, (q_n, q_r, block_table, pos, ctx_lens, selected))
+
+
+def _dsa_chunk_select(cfg, qi, w, ik, l, block_table, pos, ctx_lens):
+    """The selector on a chunk, one segment after another: the scores of
+    the segment's queries against its sequence's cached indexer keys (this
+    chunk's are written already), ``_MLA_CHUNK_KEYS`` context tokens at a
+    time and no further than the context, then for each query the
+    ``index_topk`` causally visible tokens that score highest, exactly, as a
+    mask. qi [S, Tq, ni, di]; w [S, Tq, ni]; pos [S, Tq]. Returns bool [S,
+    Tq, Bm * bs]."""
+    S, Tq = pos.shape
+    bs, Bm = ik.shape[2], block_table.shape[1]
+    N = Bm * bs
+    Tk = min(_MLA_CHUNK_KEYS, N)
+
+    def segment(args):
+        q, ws, table, ts, ctx = args
+        keys = ik[l, table].reshape(N, -1)
+
+        def block(b, sc):
+            part = hybrid.index_scores(
+                q, ws, lax.dynamic_slice_in_dim(keys, b * Tk, Tk))
+            return lax.dynamic_update_slice_in_dim(sc, part, b * Tk, 1)
+
+        sc = lax.fori_loop(0, (ctx + Tk - 1) // Tk, block,
+                           jnp.zeros((Tq, N), jnp.float32))
+        visible = jnp.arange(N)[None, :] <= ts[:, None]
+        return hybrid.topk_mask(sc, visible, cfg.index_topk)
+
+    return lax.map(segment, (qi, w, block_table, pos, ctx_lens))
+
+
+# queries of a windowed chunk whose scores are held at once
+_WINDOW_CHUNK_QUERIES = 512
+
+
+def _wmla_chunk(cfg, wp, y, pools, l, window_table, pos, real, seg_pos0,
+                seg_nreal):
+    """A chunk through a windowed latent layer, in the expanded form: its
+    keys are the ``window - 1`` tokens before it, read from the ring
+    *before* this chunk's rows are written (a chunk longer than the ring
+    writes over itself), and its own latents, which never leave the
+    program; a block of ``_WINDOW_CHUNK_QUERIES`` queries attends over the
+    keys it can see, under the band. Then the chunk's last rows, as many as
+    the ring holds without meeting itself, are written. y [S, Tq, H]; pos,
+    real [S, Tq]. Returns (o [S, Tq, n, v], pools')."""
+    S, Tq = pos.shape
+    wkv = pools["wkv"]
+    bs, R, W = wkv.shape[2], window_table.shape[1], cfg.sliding_window
+    z = cfg.mla_sizes(True)
+    dt = y.dtype
+    P = W - 1                       # earlier tokens a query can see
+    Bq = min(Tq, _WINDOW_CHUNK_QUERIES)
+    with jax.named_scope("mla_project"):
+        q_n, q_r, latent = hybrid.mla_project(cfg, wp, y, pos, windowed=True)
+    with jax.named_scope("wmla_chunk"):
+        tpos = seg_pos0[:, None] - P + jnp.arange(P)[None, :]       # [S, P]
+        at = jnp.maximum(tpos, 0)
+        tail = wkv[l, _ring(window_table, at, bs), at % bs]
+        keys = jnp.concatenate(
+            [tail[..., :z.latent_dim].astype(dt), latent], axis=1)
+        kpos = jnp.concatenate([tpos, pos], axis=1)
+        kreal = jnp.concatenate([tpos >= 0, real], axis=1)
+
+        def segment(args):
+            qn, qr, ks, kp, kr, ts = args
+
+            def block(i):
+                sl = lambda a, n: lax.dynamic_slice_in_dim(a, i * Bq, n)
+                k_n, v, k_r = hybrid.mla_expand(cfg, wp, sl(ks, P + Bq),
+                                                windowed=True)
+                sc = (jnp.einsum("qnd,knd->nqk", sl(qn, Bq), k_n)
+                      + jnp.einsum("qnd,kd->nqk", sl(qr, Bq), k_r)).astype(
+                          jnp.float32) * z.scale
+                back = sl(ts, Bq)[:, None] - sl(kp, P + Bq)[None, :]
+                ok = (back >= 0) & (back < W) & sl(kr, P + Bq)[None, :]
+                pr = jax.nn.softmax(jnp.where(ok[None], sc, -1e30), axis=-1)
+                return jnp.einsum("nqk,knd->qnd", pr.astype(dt), v)
+
+            return lax.map(block, jnp.arange(Tq // Bq)).reshape(
+                Tq, z.heads, z.v)
+
+        o = lax.map(segment, (q_n, q_r, keys, kpos, kreal, pos))
+        # of a chunk longer than the ring, the rows the next step can see
+        keep = real & (pos >= (seg_pos0 + seg_nreal)[:, None] - (R - 1) * bs)
+        page = jnp.where(keep, _ring(window_table, pos, bs), wkv.shape[1] - 1)
+        offset = jnp.where(keep, pos % bs, bs - 1)
+        wkv = wkv.at[l, page, offset].set(
+            _padded(latent, wkv.shape[-1]).astype(wkv.dtype))
+    rows = jnp.where(real, jnp.minimum(pos + 1, W), 0)
+    return o, _window_counts(dict(pools, wkv=wkv), rows, pos, keep, bs, R)
 
 
 def gather_rows_computed(max_seqs: int, max_tokens: int) -> int:
@@ -496,7 +730,8 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
 
 def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
                            seg_pos0, seg_nreal, block_table, state_slots=None,
-                           *, mesh=None) -> Tuple[jax.Array, Dict]:
+                           window_table=None, *, mesh=None
+                           ) -> Tuple[jax.Array, Dict]:
     """Prefill chunks, one segment a sequence slot; attention over each
     segment's own pages: plain products a KV head
     (``model_runner._segment_attention``) or, with the sparse rule, under
@@ -527,11 +762,23 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
 
     def full_fn(y, ap, l_kv, pools):
         if cfg.attention_kind == "mla":
-            q_n, q_r, pools = _mla_write(cfg, ap, y, pos, pools, l_kv, page,
-                                         offset)
+            q_n, q_r, pools, *cq0 = _mla_write(cfg, ap, y, pos, pools, l_kv,
+                                               page, offset)
+            sel = None
+            if cq0:
+                with jax.named_scope("dsa_index"):
+                    qi, w, pools = _index_write(cfg, ap, y, cq0[0], pos,
+                                                pools, l_kv, page, offset)
+                    sel = _dsa_chunk_select(cfg, qi, w, pools["ik"], l_kv,
+                                            block_table, pos, ctx_lens)
+                seen = jnp.where(real, pos + 1, 0)
+                pools = dict(pools, counters=pools["counters"].at[
+                    _MLA:_WINDOW].add(jnp.stack([
+                        jnp.sum(jnp.minimum(seen, cfg.index_topk)),
+                        jnp.sum(seen)]).astype(jnp.int32)))
             o = _mla_chunk(cfg, ap, q_n, q_r, pools["kv"], l_kv, block_table,
-                           pos, ctx_lens)
-            return hybrid.mla_output(ap, o), pools
+                           pos, ctx_lens, sel)
+            return hybrid.mla_output(ap, o, y), pools
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, pos)
         kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
         pools = dict(pools, kv=kv)
@@ -547,14 +794,19 @@ def ragged_prefill_forward(cfg: HybridConfig, params, pools: Dict, seg_tokens,
                          counters=pools["counters"].at[_MOE:_SPARSE].add(counts))
         return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
+    def win_fn(y, wp, l_win, pools):
+        o, pools = _wmla_chunk(cfg, wp, y, pools, l_win, window_table[:S],
+                               pos, real, seg_pos0, seg_nreal)
+        return hybrid.mla_output(wp, o, y), pools
+
     x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn,
-                          real.reshape(-1))
+                          real.reshape(-1), win_fn)
     return hybrid.head_logits(cfg, params, x), pools
 
 
 def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
                           token_pos, block_table, context_lens,
-                          state_slots=None, *, mesh=None
+                          state_slots=None, window_table=None, *, mesh=None
                           ) -> Tuple[jax.Array, Dict]:
     """One decode step: one new token for each live slot (``context_lens``
     0 marks a dead one). The recurrent layers run their decode kernel
@@ -596,11 +848,16 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
 
     def full_fn(y, ap, l_kv, pools):
         if cfg.attention_kind == "mla":
-            q_n, q_r, pools = _mla_write(cfg, ap, y, token_pos, pools, l_kv,
-                                         page, offset)
-            o, pools = _mla_decode(cfg, ap, q_n, q_r, pools, l_kv,
-                                   block_table, context_lens)
-            return hybrid.mla_output(ap, o), pools
+            q_n, q_r, pools, *cq0 = _mla_write(cfg, ap, y, token_pos, pools,
+                                               l_kv, page, offset)
+            if cq0:
+                o, pools = _dsa_decode(cfg, ap, y, cq0[0], q_n, q_r, pools,
+                                       l_kv, block_table, token_pos,
+                                       context_lens, page, offset)
+            else:
+                o, pools = _mla_decode(cfg, ap, q_n, q_r, pools, l_kv,
+                                       block_table, context_lens)
+            return hybrid.mla_output(ap, o, y), pools
         q, k, v, gate = hybrid.attn_project(cfg, ap, y, token_pos)
         kv, _ = _kv_write(pools["kv"], None, l_kv, page, offset, k, v)
         pools = dict(pools, kv=kv)
@@ -616,14 +873,20 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
                          counters=pools["counters"].at[_MOE:_SPARSE].add(counts))
         return hybrid.attn_output(ap, a.astype(dt), gate), pools
 
-    x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn, alive)
+    def win_fn(y, wp, l_win, pools):
+        o, pools = _wmla_decode(cfg, wp, y, pools, l_win, window_table,
+                                token_pos, context_lens)
+        return hybrid.mla_output(wp, o, y), pools
+
+    x, pools = _run_stack(cfg, params, x, pools, rec_fn, full_fn, alive,
+                          win_fn)
     return hybrid.head_logits(cfg, params, x), pools
 
 
 def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
                         token_pos, block_table, context_lens,
-                        state_slots=None, *, steps: int, mesh=None
-                        ) -> Tuple[jax.Array, Dict]:
+                        state_slots=None, window_table=None, *, steps: int,
+                        mesh=None) -> Tuple[jax.Array, Dict]:
     """``steps`` greedy decode steps in one program, the argmax fed back on
     the device (``model_runner.ragged_multi_decode``'s contract); the
     counters sum over the steps. Returns (tokens [steps, S] int32, pools')."""
@@ -631,7 +894,7 @@ def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
         pools, tok, pos, ctx, counts = carry
         logits, pools = ragged_decode_forward(
             cfg, params, pools, tok, pos, block_table, ctx, state_slots,
-            mesh=mesh)
+            window_table, mesh=mesh)
         alive = ctx > 0
         nxt = jnp.where(alive, jnp.argmax(logits, axis=-1).astype(jnp.int32), 0)
         counts = counts + pools["counters"]

@@ -2,7 +2,8 @@
 
 from deepspeed_tpu.inference.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.ragged.kv_cache import (
-    BlockedKVCache, KVCacheConfig, LatentPoolUnsupported)
+    BlockedKVCache, KVCacheConfig, LatentPoolUnsupported, WindowPoolConfig,
+    WindowedLatentPool, WindowedPoolUnsupported)
 from deepspeed_tpu.inference.ragged.kv_tier import HostKVTier, PagedSession
 from deepspeed_tpu.inference.ragged.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.ragged.sequence import (
@@ -25,4 +26,7 @@ __all__ = [
     "RecurrentStatePool",
     "StatePoolConfig",
     "StateSnapshotUnsupported",
+    "WindowPoolConfig",
+    "WindowedLatentPool",
+    "WindowedPoolUnsupported",
 ]

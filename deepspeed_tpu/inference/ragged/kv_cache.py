@@ -40,6 +40,32 @@ whatever the shape says, and the decode kernel's page fetch can slice a pool
 only at whole tiles. The block table, the allocator, the prefix chain and
 the host tier address pages and do not look inside one: a latent page is a
 page. The quantized rungs are not built for it and refuse by name.
+
+A latent pool whose model selects its context with a learned indexer keeps
+the **indexer keys** too (``KVCacheConfig.index_key_dim`` values a token),
+page-addressed beside the latents and kept for the whole context,
+
+    index_keys[L, num_blocks, block_size, index_key_dim]
+
+as the compressed keys above are: same block table, same allocator, a page
+that is freed takes them with it.
+
+A model with **windowed latent layers** keeps a third pool
+(:class:`WindowedLatentPool`, ``BlockedKVCache.window_pool``): rows of
+another width, for the last ``window`` tokens of a sequence only,
+
+    wkv[window layers, window_blocks, block_size, lanes(row_dim)]
+
+with an allocator of its own. A sequence holds a **ring** of at most
+``ring_pages = ceil((window + block_size - 1) / block_size) + 1`` pages of
+it, whatever its length: the token at position ``p`` lives in ring entry
+``(p // block_size) % ring_pages``, so a page the window has passed is
+written over by the tokens ``ring_pages`` pages later (the step programs
+count each such reuse: ``window_pages_recycled``). Pages are taken one at a
+time as a young sequence grows and all given back when it is released. What
+would have to copy, share or restore a sequence's pages without knowing the
+ring (the prefix cache, the host tier, migration, hand-off, speculation)
+refuses by name (:class:`WindowedPoolUnsupported`).
 """
 
 from __future__ import annotations
@@ -58,6 +84,81 @@ from deepspeed_tpu.inference.ragged.blocked_allocator import BlockedAllocator
 class LatentPoolUnsupported(NotImplementedError):
     """The operation is not built for a latent pool (one vector a token, no
     K/V pair, no head axis)."""
+
+
+class WindowedPoolUnsupported(NotImplementedError):
+    """The operation is not built for a model with a windowed pool (a ring of
+    pages a sequence writes over as its window moves on)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowPoolConfig:
+    layers: int
+    window: int             # tokens visible, the query's own counted
+    row_dim: int            # values a token keeps in one layer
+    block_size: int
+    num_blocks: int         # the last one is scratch
+    dtype: object = jnp.bfloat16
+
+    @classmethod
+    def for_sequences(cls, seqs: int, **sizes) -> "WindowPoolConfig":
+        """A pool that gives each of ``seqs`` sequences its whole ring, and
+        the scratch page."""
+        ring = cls(num_blocks=1, **sizes).ring_pages
+        return cls(num_blocks=seqs * ring + 1, **sizes)
+
+    @property
+    def ring_pages(self) -> int:
+        """Pages a sequence holds at most: the window's span, a partial page
+        at either end, and one page of room for a burst's new tokens."""
+        bs = self.block_size
+        return -(-(self.window + bs - 1) // bs) + 1
+
+    @property
+    def pool_shape(self):
+        return (self.layers, self.num_blocks, self.block_size,
+                -(-self.row_dim // 128) * 128)
+
+
+class WindowedLatentPool:
+    """The windowed latent layers' pool: the device array and an allocator
+    of its own (the last block is scratch and never handed out)."""
+
+    def __init__(self, config: WindowPoolConfig):
+        self.config = config
+        self.allocator = BlockedAllocator(config.num_blocks - 1)
+        self.data = jnp.zeros(config.pool_shape, config.dtype)
+
+    @property
+    def scratch_block(self) -> int:
+        return self.config.num_blocks - 1
+
+    @property
+    def free_blocks(self) -> int:
+        return self.allocator.free_blocks
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.allocator.total_blocks - self.allocator.free_blocks
+
+    def pages_for(self, num_tokens: int) -> int:
+        """Ring pages a sequence of ``num_tokens`` holds."""
+        c = self.config
+        return min(-(-num_tokens // c.block_size), c.ring_pages)
+
+    def grow(self, blocks: np.ndarray, num_tokens: int):
+        """``blocks`` grown to what ``num_tokens`` tokens hold, or None
+        where the pool has no page left."""
+        need = self.pages_for(num_tokens) - len(blocks)
+        if need <= 0:
+            return blocks
+        if need > self.allocator.free_blocks:
+            return None
+        return np.concatenate([blocks, self.allocator.allocate(need)])
+
+    def free(self, blocks) -> None:
+        if len(blocks):
+            self.allocator.free(blocks)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +181,8 @@ class KVCacheConfig:
     # values a token (multi-head latent attention)
     kind: str = "kv"
     latent_dim: int = 0
+    # the selector's key a token, beside a latent pool (0: no selector)
+    index_key_dim: int = 0
 
     def __post_init__(self):
         if self.kind not in ("kv", "latent"):
@@ -94,6 +197,8 @@ class KVCacheConfig:
                     f"quant_bits={self.quant_bits!r} and compressed keys "
                     f"are not built for it (quantized latent pages: "
                     f"ROADMAP.md)")
+        elif self.index_key_dim:
+            raise ValueError("indexer keys live beside a latent pool")
         if self.quant_bits not in (None, 4, 8, "fp8"):
             raise ValueError(f"kv quant_bits must be None, 4, 8 or 'fp8', "
                              f"got {self.quant_bits}")
@@ -119,7 +224,8 @@ class KVCacheConfig:
     @property
     def bytes_per_block(self) -> int:
         if self.kind == "latent":
-            return (self.num_layers * self.block_size * self.payload_width
+            return (self.num_layers * self.block_size
+                    * (self.payload_width + self.index_key_dim)
                     * jnp.dtype(self.dtype).itemsize)
         vecs = self.num_layers * self.block_size * 2 * self.kv_heads
         if self.quant_bits is not None:
@@ -168,6 +274,14 @@ class BlockedKVCache:
                 (config.num_layers, config.num_blocks,
                  config.compressed_per_block, config.kv_heads,
                  config.head_dim), config.dtype)
+        # the selector's keys of a latent pool (None: no selector), and the
+        # windowed latent layers' pool (None: no such layer; attached by the
+        # owner); both handed out and taken back with the pool
+        self.index_keys = None
+        if config.index_key_dim:
+            self.index_keys = jnp.zeros(
+                config.pool_shape[:3] + (config.index_key_dim,), config.dtype)
+        self.window_pool = None
         shape = config.pool_shape
         # a hybrid stack's step programs take the pools as a dict, also
         # where no recurrent-state pool stands beside this one (a stack
@@ -218,7 +332,12 @@ class BlockedKVCache:
                 state["ck"] = self.compressed
             return state
         if self.pools_as_dict:
-            return {"kv": self.data}
+            state = {"kv": self.data}
+            if self.index_keys is not None:
+                state["ik"] = self.index_keys
+            if self.window_pool is not None:
+                state["wkv"] = self.window_pool.data
+            return state
         if self.scales is None:
             return self.data
         return (self.data, self.scales)
@@ -235,6 +354,9 @@ class BlockedKVCache:
             self.compressed = state.get("ck")
         elif self.pools_as_dict:
             self.data = state["kv"]
+            self.index_keys = state.get("ik")
+            if self.window_pool is not None:
+                self.window_pool.data = state["wkv"]
         elif self.scales is None:
             self.data = state
         else:

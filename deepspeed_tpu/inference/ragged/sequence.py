@@ -45,6 +45,11 @@ class SequenceDescriptor:
     # slot of the recurrent-state pool (ragged/state_pool.py) for a model
     # with recurrent layers; -1: the model has none
     state_slot: int = -1
+    # the sequence's ring of pages in the windowed latent layers' pool
+    # (ragged/kv_cache.py, WindowedLatentPool): entry ``(p // block) %
+    # ring_pages`` holds position ``p``; empty: the model has no such layer
+    window_blocks: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.empty(0, dtype=np.int64))
 
     @property
     def total_tokens(self) -> int:
@@ -102,6 +107,13 @@ class StateManager:
         batch metadata (build_ragged_batch bucket bound)."""
         total_needed = self.kv_cache.blocks_needed(new_total)
         need = total_needed - len(seq.kv_blocks)
+        wpool = getattr(self.kv_cache, "window_pool", None)
+        if wpool is not None:
+            # the ring first: it takes nothing once it is whole
+            ring = wpool.grow(seq.window_blocks, new_total)
+            if ring is None:
+                return False
+            seq.window_blocks = ring
         if need <= 0:
             return True
         if (self.max_blocks_per_seq is not None
@@ -129,6 +141,15 @@ class StateManager:
         without re-prefilling what the tier kept. Returns the number of
         prefill tokens skipped."""
         cache = self.kv_cache.prefix_cache
+        if cache is not None and getattr(self.kv_cache, "window_pool",
+                                         None) is not None:
+            from deepspeed_tpu.inference.ragged.kv_cache import \
+                WindowedPoolUnsupported
+
+            raise WindowedPoolUnsupported(
+                "the prefix cache is not built for a model with a windowed "
+                "pool: a skipped prefix would leave the sequence's ring "
+                "without the rows its window still sees")
         if (cache is None or seq.seen_tokens or len(seq.kv_blocks)
                 or len(seq.input_tokens) <= cache.block_size):
             return 0
@@ -231,6 +252,9 @@ class StateManager:
         if seq.state_slot >= 0:
             self.kv_cache.state_pool.free(seq.state_slot)
             seq.state_slot = -1
+        if len(seq.window_blocks):
+            self.kv_cache.window_pool.free(seq.window_blocks)
+            seq.window_blocks = np.empty(0, dtype=np.int64)
 
     def live_uids(self) -> List[int]:
         return list(self.seqs)

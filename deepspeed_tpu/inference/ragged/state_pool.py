@@ -36,13 +36,19 @@ import jax.numpy as jnp
 # expert blocks' part, then the block-selecting attention's, then latent
 # attention's decode kernel's (the context tokens it was asked to read, and
 # the page copies it started, which the kernel counts itself; summed over
-# sequences, layers and steps)
+# sequences, layers and steps), then the selector's and the windowed latent
+# layers' (the cached rows a full layer's queries attended over and could
+# see; the rows a windowed layer's queries read, and the ring pages a token
+# began to write over: summed likewise)
 MOE_COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
                 "moe_work_items")
 SPARSE_COUNTERS = ("sparse_blocks_selected", "sparse_blocks_visible",
                    "sparse_dense_tokens")
 MLA_COUNTERS = ("mla_context_tokens", "mla_pages_read")
-COUNTERS = MOE_COUNTERS + SPARSE_COUNTERS + MLA_COUNTERS
+DSA_COUNTERS = ("dsa_rows_selected", "dsa_rows_visible")
+WINDOW_COUNTERS = ("window_rows_read", "window_pages_recycled")
+COUNTERS = (MOE_COUNTERS + SPARSE_COUNTERS + MLA_COUNTERS + DSA_COUNTERS
+            + WINDOW_COUNTERS)
 
 
 class StateSnapshotUnsupported(NotImplementedError):

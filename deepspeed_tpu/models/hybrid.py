@@ -11,7 +11,14 @@ cut to the layers held here). A windowed layer ("w") is the full layer's
 gated softmax attention under a ``sliding_window`` (key j visible to query i
 iff 0 <= i - j < window), with its own share of rotary dims
 (``window_rotary_factor``), so a stack can rotate its windowed layers and
-leave its full layers without positions. ``post_norms`` adds a norm after
+leave its full layers without positions; or, in a latent stack
+(``window_attention_kind`` "mla": the ``dots3_note`` family), a latent mixer
+of its own (``wmla``: its own ranks, head count, key width and rotary base)
+under the window, beside full latent layers whose context a learned selector
+picks (``index_topk``: ``index_project``, ``index_scores``, ``topk_mask``),
+both with a sigmoid gate a head (``mla_head_gate``) and rescaled latents
+(``mla_lora_rescale``). :attr:`HybridConfig.mixer_kinds` says which mixer's
+leaves each layer reads. ``post_norms`` adds a norm after
 each branch (four a layer). A recurrent layer is gated DeltaNet or lightning
 attention (``recurrent_kind``; ``ops/pallas/gated_delta.py``); a full layer is
 gated softmax attention with QK-norm, rotary on a part of each head (none of
@@ -33,6 +40,7 @@ passes and the serving runner (``inference/hybrid_runner.py``) take:
     experts wg, wi, wo (none with a dense feed-forward)    [L, E_held, ...]
     gdn | lightning  the recurrent layers' mixer           [recurrent, ...]
     attn | mla       the full layers' mixer                [full, ...]
+    wmla             the windowed latent layers' mixer     [windowed, ...]
     dense   wg, wi, wo: the prologue's feed-forward        [first_k_dense, ...]
 
 (with a prologue ``experts`` holds the expert layers alone, ``[L -
@@ -61,7 +69,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +84,29 @@ from deepspeed_tpu.parallel.moe import (GateConfig, bias_update,
                                         moe_ffn_share)
 from deepspeed_tpu.runtime.sharding import (effective_dtype,
                                             vocab_parallel_lookup)
+
+
+class MlaSizes(NamedTuple):
+    """One latent mixer's sizes (``HybridConfig.mla_sizes``): heads, the
+    two ranks, a head's ``nope`` / ``rope`` / value widths, the rotary base
+    (``inv_freq``: YaRN's frequencies in its place), the softmax scale and
+    what the normed latents are multiplied by."""
+
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    inv_freq: Any
+    scale: float
+    q_rescale: float
+    kv_rescale: float
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_rank + self.rope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,6 +167,30 @@ class HybridConfig(TransformerConfig):
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # the "w" layers' mixer: "gated" (the full layers' gated softmax
+    # attention under the window) or "mla": a latent mixer of its own, with
+    # its own ranks, head count, key width and rotary base, over the last
+    # ``sliding_window`` tokens (the query's own position counted)
+    window_attention_kind: str = "gated"
+    window_num_heads: int = 64
+    window_q_lora_rank: int = 1024
+    window_kv_lora_rank: int = 1024
+    window_qk_nope_head_dim: int = 192
+    window_qk_rope_head_dim: int = 64
+    window_v_head_dim: int = 128
+    window_rope_theta: float = 50000.0
+    # a latent mixer's extras: the normed latents times ``sqrt(hidden /
+    # rank)`` (queries' and keys' alike), and one sigmoid gate a head on the
+    # attention's output, read from the layer's normed input
+    mla_lora_rescale: bool = False
+    mla_head_gate: bool = False
+    # the full latent layers' learned selector (0: none, dense attention):
+    # ``index_n_heads`` query heads of ``index_head_dim`` against one key a
+    # token, cached beside the latent; a query attends over the
+    # ``index_topk`` tokens that score highest (all of them below that)
+    index_topk: int = 0
+    index_n_heads: int = 64
+    index_head_dim: int = 128
     # YaRN (factor 1: plain rotary): models/transformer.py::yarn_inv_freq,
     # and ``yarn_mscale(factor, mscale_all_dim)**2`` on the softmax scale
     rope_yarn_factor: float = 1.0
@@ -177,8 +232,19 @@ class HybridConfig(TransformerConfig):
             raise ValueError(
                 f"layers {self.first_layer}..{self.first_layer + self.num_layers}"
                 f" lie outside the pattern {self.layer_pattern!r} (m | w | l)")
-        if any(self.layer_windows) and self.attention_kind != "gated":
-            raise ValueError("a windowed layer is gated softmax attention")
+        if self.window_attention_kind not in ("gated", "mla"):
+            raise ValueError(
+                f"window_attention_kind {self.window_attention_kind!r}")
+        if any(self.layer_windows) and (self.attention_kind == "mla") != (
+                self.window_attention_kind == "mla"):
+            raise ValueError(
+                "a windowed layer is of the full layers' kind: gated softmax "
+                "attention beside gated, a latent mixer beside latent "
+                f"(attention_kind {self.attention_kind!r}, "
+                f"window_attention_kind {self.window_attention_kind!r})")
+        if self.index_topk and self.attention_kind != "mla":
+            raise ValueError("the selector (index_topk) is the latent "
+                             "layers'")
         if self.recurrent_kind not in ("gdn", "lightning"):
             raise ValueError(f"recurrent_kind {self.recurrent_kind!r}")
         self.sparse                     # the sizes check themselves
@@ -204,9 +270,44 @@ class HybridConfig(TransformerConfig):
     def mla_scale(self) -> float:
         """The softmax scale of latent attention: ``(nope + rope)^-1/2``
         times YaRN's ``m**2``."""
+        return self.mla_sizes().scale
+
+    def mla_sizes(self, windowed: bool = False) -> "MlaSizes":
+        """The sizes of a latent mixer: the full layers', or with
+        ``windowed`` the "w" layers' own."""
+        h = self.hidden_size
+
+        def rescale(rank):
+            return math.sqrt(h / rank) if self.mla_lora_rescale else 1.0
+
+        if windowed:
+            dn, r = self.window_qk_nope_head_dim, self.window_qk_rope_head_dim
+            return MlaSizes(
+                self.window_num_heads, self.window_q_lora_rank,
+                self.window_kv_lora_rank, dn, r, self.window_v_head_dim,
+                self.window_rope_theta, None, 1.0 / math.sqrt(dn + r),
+                rescale(self.window_q_lora_rank),
+                rescale(self.window_kv_lora_rank))
         m = yarn_mscale(self.rope_yarn_factor, self.rope_mscale_all_dim)
-        return m * m / math.sqrt(self.qk_nope_head_dim
-                                 + self.qk_rope_head_dim)
+        dn, r = self.qk_nope_head_dim, self.qk_rope_head_dim
+        return MlaSizes(self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+                        dn, r, self.v_head_dim, self.rope_theta,
+                        self.mla_inv_freq(), m * m / math.sqrt(dn + r),
+                        rescale(self.q_lora_rank), rescale(self.kv_lora_rank))
+
+    @property
+    def window_latent_dim(self) -> int:
+        """Values a token keeps in a windowed latent layer's cache (0: the
+        stack has no such layer)."""
+        if self.window_attention_kind != "mla" or "w" not in self._held_pattern:
+            return 0
+        return self.window_kv_lora_rank + self.window_qk_rope_head_dim
+
+    @property
+    def index_key_dim(self) -> int:
+        """Values a token keeps for the selector beside its latent (0: no
+        selector)."""
+        return self.index_head_dim if self.index_topk else 0
 
     def mla_inv_freq(self):
         """The rotary part's inverse frequencies (None: plain rotary)."""
@@ -245,6 +346,20 @@ class HybridConfig(TransformerConfig):
                      for c in self._held_pattern)
 
     @property
+    def mixer_kinds(self) -> Tuple[Any, ...]:
+        """For each layer held here, which mixer's leaves it reads: True the
+        full layers' (a gated windowed layer shares them), False the
+        recurrent one's, "w" the windowed latent mixer's own."""
+        own = self.window_attention_kind == "mla"
+        return tuple("w" if c == "w" and own else c != "l"
+                     for c in self._held_pattern)
+
+    @property
+    def window_layers(self) -> int:
+        """Layers with a mixer and a cache of their own behind a window."""
+        return sum(k == "w" for k in self.mixer_kinds)
+
+    @property
     def dense_layers(self) -> int:
         """Layers held here whose feed-forward is the dense SwiGLU of the
         published model's first ``first_k_dense``."""
@@ -254,14 +369,14 @@ class HybridConfig(TransformerConfig):
                    self.num_layers)
 
     @property
-    def stack_plan(self) -> Tuple[int, Tuple[Tuple[bool, int], ...]]:
-        """``(repeats, runs)``: the layer kinds (after the prologue of
-        ``first_k_dense`` layers, which the runner calls one by one before
-        the scan) as ``repeats`` copies of the shortest pattern that tiles
-        them, the pattern as runs ``(full,
-        layers)`` of one kind. The serving runner scans the repeats and,
-        inside, each run: one layer body a run, whatever the depth."""
-        kinds = self.layer_kinds[self.dense_layers:]    # after the prologue
+    def stack_plan(self) -> Tuple[int, Tuple[Tuple[Any, int], ...]]:
+        """``(repeats, runs)``: the layers' mixers (``mixer_kinds``; after
+        the prologue of ``first_k_dense`` layers, which the runner calls one
+        by one before the scan) as ``repeats`` copies of the shortest
+        pattern that tiles them, the pattern as runs ``(kind, layers)`` of
+        one mixer. The serving runner scans the repeats and, inside, each
+        run: one layer body a run, whatever the depth."""
+        kinds = self.mixer_kinds[self.dense_layers:]    # after the prologue
         L = len(kinds)
         p = next(p for p in range(1, L + 1)
                  if L % p == 0 and kinds == kinds[:p] * (L // p))
@@ -275,14 +390,15 @@ class HybridConfig(TransformerConfig):
 
     @property
     def kv_layers(self) -> int:
-        """Layers that hold keys and values: the full ones."""
-        return sum(self.layer_kinds)
+        """Layers that hold keys and values (or latents) in the paged pool:
+        the full ones, and the windowed ones that share their mixer."""
+        return sum(k is True for k in self.mixer_kinds)
 
     periods = kv_layers                 # Qwen3-Next: one full layer a period
 
     @property
     def recurrent_layers(self) -> int:
-        return self.num_layers - self.kv_layers
+        return sum(k is False for k in self.mixer_kinds)
 
     @property
     def conv_channels(self) -> int:
@@ -398,12 +514,9 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
         ffn = {"mlp": {"wg": (L, h, cfg.ffn_size), "wi": (L, h, cfg.ffn_size),
                        "wo": (L, cfg.ffn_size, h)}}
     if cfg.attention_kind == "mla":
-        c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        dn, dv, ql = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.q_lora_rank
-        mixers = {"mla": {"wqa": (L, h, ql), "q_norm": (L, ql),
-                          "wqb": (L, ql, nq, dn + r), "wkva": (L, h, c + r),
-                          "kv_norm": (L, c), "wkvb": (L, c, nq, dn + dv),
-                          "wo": (L, nq, dv, h)}}
+        mixers = {"mla": _mla_shapes(cfg, False)}
+        if cfg.window_layers:
+            mixers["wmla"] = _mla_shapes(cfg, True)
     else:
         mixers = {"attn": {"wq": (L, h, nq, 2 * d), "wk": (L, h, nkv, d),
                            "wv": (L, h, nkv, d), "wo": (L, nq, d, h),
@@ -420,8 +533,29 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
     }
 
 
-_GAINS = ("scale", "q_norm", "k_norm", "kv_norm", "norm")
-_MIXERS = ("attn", "mla", "gdn", "lightning")
+def _mla_shapes(cfg: HybridConfig, windowed: bool) -> Dict[str, Tuple]:
+    """A latent mixer's leaves, a slot a layer: the full layers' (with the
+    selector's three projections and its key norm) or the windowed ones'."""
+    h, L, z = cfg.hidden_size, cfg.num_layers, cfg.mla_sizes(windowed)
+    out = {"wqa": (L, h, z.q_rank), "q_norm": (L, z.q_rank),
+           "wqb": (L, z.q_rank, z.heads, z.nope + z.rope),
+           "wkva": (L, h, z.kv_rank + z.rope), "kv_norm": (L, z.kv_rank),
+           "wkvb": (L, z.kv_rank, z.heads, z.nope + z.v),
+           "wo": (L, z.heads, z.v, h)}
+    if cfg.mla_head_gate:
+        out["wgate"] = (L, h, z.heads)
+    if cfg.index_topk and not windowed:
+        ni, di = cfg.index_n_heads, cfg.index_head_dim
+        out.update(wiq=(L, z.q_rank, ni, di), wik=(L, h, di),
+                   ik_norm=(L, di), ik_bias=(L, di), wiw=(L, h, ni))
+    return out
+
+
+# the selector's key norm is a LayerNorm with bias at its own eps
+INDEX_NORM_EPS = 1e-6
+
+_GAINS = ("scale", "q_norm", "k_norm", "kv_norm", "norm", "ik_norm")
+_MIXERS = ("attn", "mla", "wmla", "gdn", "lightning")
 
 
 def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
@@ -436,7 +570,7 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
         group = path[-2].key if len(path) > 1 else ""
         if name in _GAINS:
             x = jnp.ones(shape, cfg.param_dtype)
-        elif name == "A_log":
+        elif name in ("A_log", "ik_bias"):
             x = jnp.zeros(shape, cfg.param_dtype)
         elif name == "dt_bias":
             x = jax.random.normal(key, shape, cfg.param_dtype) * 2.0
@@ -448,8 +582,11 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
             if name == "wo":   # contracts everything but the last axis
                 fan = math.prod(shape[2:-1]) if group in _MIXERS \
                     else shape[-2]
-            elif name in ("wqb", "wkvb"):   # [L, rank, heads, d]
-                fan = shape[1]
+            elif name in ("wqb", "wkvb", "wiq"):   # [L, rank, heads, d]
+                # (of the input as it arrives: the rescaled latent's)
+                z = cfg.mla_sizes(group == "wmla")
+                fan = shape[1] * {"wqb": z.q_rescale, "wkvb": z.kv_rescale,
+                                  "wiq": 1.0}[name] ** 2
             else:              # [L, (E,) h, ...]: contracts h
                 fan = cfg.hidden_size
             x = jax.random.normal(key, shape, cfg.param_dtype) / math.sqrt(fan)
@@ -494,11 +631,16 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
                        "wo": (L, "mlp", "embed")}}
     if cfg.attention_kind == "mla":
         # replicated: a latent cache has no head axis to shard
-        mixers = {"mla": {"wqa": (L, "embed", None), "q_norm": (L, None),
-                          "wqb": (L, None, None, None),
-                          "wkva": (L, "embed", None), "kv_norm": (L, None),
-                          "wkvb": (L, None, None, None),
-                          "wo": (L, None, None, "embed")}}
+        def latent(windowed):
+            return {name: (L, "embed") + (None,) * (len(shape) - 2)
+                    if name in ("wqa", "wkva", "wgate", "wik", "wiw")
+                    else (L,) + (None,) * (len(shape) - 2) + ("embed",)
+                    if name == "wo" else (L,) + (None,) * (len(shape) - 1)
+                    for name, shape in _mla_shapes(cfg, windowed).items()}
+
+        mixers = {"mla": latent(False)}
+        if cfg.window_layers:
+            mixers["wmla"] = latent(True)
     else:
         mixers = {"attn": {"wq": (L, "embed", "heads", "head_dim"),
                            "wk": (L, "embed", "kv_heads", "head_dim"),
@@ -520,6 +662,16 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
     }
 
 
+def axes_for(cfg: HybridConfig, params: Dict[str, Any]) -> Dict[str, Any]:
+    """:func:`logical_axes` in the layout of ``params``: a mixer handed over
+    at the top of the tree (over its own layers, as :func:`serving_params`
+    takes it) has its axes there."""
+    axes = logical_axes(cfg)
+    layers = dict(axes["layers"])
+    top = {m: layers.pop(m) for m in _MIXERS if m in params and m in layers}
+    return dict(axes, layers=layers, **top)
+
+
 def serving_params(cfg: HybridConfig, params: Dict[str, Any],
                    donate: bool = False) -> Dict[str, Any]:
     """The tree the forward passes take: of each mixer only the layers that
@@ -531,7 +683,8 @@ def serving_params(cfg: HybridConfig, params: Dict[str, Any],
     exists, so that at most one leaf is held twice (a chip that the cut
     tree nearly fills cannot hold both trees)."""
     mixer = "mla" if cfg.attention_kind == "mla" else "attn"
-    if "experts" in params and mixer in params:
+    if "experts" in params and "layers" in params and not any(
+            m in params["layers"] for m in _MIXERS):
         return params
 
     def cut(tree, keep):
@@ -548,13 +701,16 @@ def serving_params(cfg: HybridConfig, params: Dict[str, Any],
 
     L = cfg.num_layers
     layers = dict(params["layers"])
-    full = [l for l in range(L) if cfg.is_full(l)]
     out = {"embed": params["embed"], "final_norm": params["final_norm"],
            "unembed": params["unembed"]}
-    out[mixer] = cut(layers.pop(mixer), full)
-    if cfg.recurrent_layers:
-        out[cfg.recurrent_kind] = cut(layers.pop(cfg.recurrent_kind),
-                                      [l for l in range(L) if l not in full])
+    # (a mixer handed over at the top is over its own layers already: the
+    # benchmark's weight table draws it so, and holds no dead slot)
+    for name, kind in ((mixer, True), (cfg.recurrent_kind, False),
+                       ("wmla", "w")):
+        uses = [l for l in range(L) if cfg.mixer_kinds[l] == kind]
+        if uses:
+            out[name] = params[name] if name in params else \
+                cut(layers.pop(name), uses)
     experts = params.get("experts", {})     # (there with experts_apart)
     if cfg.num_experts and not experts:
         moe = dict(layers["moe"])
@@ -604,63 +760,154 @@ def attn_project(cfg: HybridConfig, ap, y, positions, windowed: bool = False):
     return rope(q), rope(k), v, gate
 
 
-def mla_project(cfg: HybridConfig, mp, y, positions):
+def mla_project(cfg: HybridConfig, mp, y, positions, windowed: bool = False,
+                query_latent: bool = False):
     """Latent attention's projections of y [..., H] at positions [...]:
     queries through their bottleneck, ``q_n [..., n, nope]`` and ``q_r
     [..., n, rope]`` (rotated), and the token's latent ``[..., c + rope]``:
     the normed compressed vector ``c_kv`` and the one rotated key ``k_r``
-    all heads share. That vector is what the cache holds."""
-    dt, c, dn = y.dtype, cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    inv = cfg.mla_inv_freq()
-    cq = _rms(y @ mp["wqa"].astype(dt), mp["q_norm"], cfg.norm_eps)
+    all heads share. That vector is what the cache holds. ``windowed``: the
+    "w" layers' mixer, with its own sizes and rotary base. With
+    ``mla_lora_rescale`` both normed latents are scaled (``sqrt(hidden /
+    rank)``). ``query_latent``: a fourth value, the normed query latent
+    *before* that scale, which the selector's queries read."""
+    z = cfg.mla_sizes(windowed)
+    dt, c, dn = y.dtype, z.kv_rank, z.nope
+    cq0 = _rms(y @ mp["wqa"].astype(dt), mp["q_norm"], cfg.norm_eps)
+    cq = cq0 if z.q_rescale == 1.0 else cq0 * jnp.asarray(z.q_rescale, dt)
     q = jnp.einsum("...q,qnd->...nd", cq, mp["wqb"].astype(dt))
     kva = y @ mp["wkva"].astype(dt)
     c_kv = _rms(kva[..., :c], mp["kv_norm"], cfg.norm_eps)
-    q_r = _rope(q[..., dn:], positions, cfg.rope_theta, inv)
-    k_r = _rope(kva[..., None, c:], positions, cfg.rope_theta, inv)[..., 0, :]
-    return q[..., :dn], q_r, jnp.concatenate([c_kv, k_r], axis=-1)
+    if z.kv_rescale != 1.0:
+        c_kv = c_kv * jnp.asarray(z.kv_rescale, dt)
+    q_r = _rope(q[..., dn:], positions, z.theta, z.inv_freq)
+    k_r = _rope(kva[..., None, c:], positions, z.theta, z.inv_freq)[..., 0, :]
+    out = q[..., :dn], q_r, jnp.concatenate([c_kv, k_r], axis=-1)
+    return out + (cq0,) if query_latent else out
 
 
 def mla_absorb_q(cfg: HybridConfig, mp, q_n):
     """The absorbed query ``q_n W_kvb^K`` [..., n, c]: a head's query in the
     latent's own coordinates, so that its score against a cached token is a
-    product with the latent itself."""
-    wk = mp["wkvb"][..., :cfg.qk_nope_head_dim].astype(q_n.dtype)
+    product with the latent itself. (``nope`` is read off ``q_n``: either
+    latent mixer's.)"""
+    wk = mp["wkvb"][..., :q_n.shape[-1]].astype(q_n.dtype)
     return jnp.einsum("...nd,cnd->...nc", q_n, wk)
 
 
-def mla_absorb_o(cfg: HybridConfig, mp, o):
+def mla_absorb_o(cfg: HybridConfig, mp, o, windowed: bool = False):
     """``o W_kvb^V``: attention's output over latents [..., n, c] to a
     head's values [..., n, v]."""
-    wv = mp["wkvb"][..., cfg.qk_nope_head_dim:].astype(o.dtype)
+    wv = mp["wkvb"][..., cfg.mla_sizes(windowed).nope:].astype(o.dtype)
     return jnp.einsum("...nc,cnd->...nd", o, wv)
 
 
-def mla_expand(cfg: HybridConfig, mp, latent):
+def mla_expand(cfg: HybridConfig, mp, latent, windowed: bool = False):
     """The expanded form's keys and values of cached latents [..., c +
     rope]: ``k_n [..., n, nope]``, ``v [..., n, v]`` and the shared rotary
     key ``k_r [..., rope]``."""
-    c, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    kv = jnp.einsum("...c,cnd->...nd", latent[..., :c],
+    z = cfg.mla_sizes(windowed)
+    kv = jnp.einsum("...c,cnd->...nd", latent[..., :z.kv_rank],
                     mp["wkvb"].astype(latent.dtype))
-    return kv[..., :dn], kv[..., dn:], latent[..., c:c + cfg.qk_rope_head_dim]
+    return (kv[..., :z.nope], kv[..., z.nope:],
+            latent[..., z.kv_rank:z.kv_rank + z.rope])
 
 
-def mla_output(mp, o):
-    """``concat(o) W_o``; o [..., n, v]."""
+def mla_output(mp, o, y=None):
+    """``concat(o) W_o``; o [..., n, v]. With the head-wise gate (``wgate``;
+    ``y`` the layer's normed input) each head's output times ``sigmoid(y
+    W_g)`` first."""
+    if "wgate" in mp:
+        with jax.named_scope("attn_gate"):
+            g = jax.nn.sigmoid((y @ mp["wgate"].astype(y.dtype)).astype(
+                jnp.float32))
+            o = o * g[..., None].astype(o.dtype)
     return jnp.einsum("...nd,ndh->...h", o, mp["wo"].astype(o.dtype))
 
 
-def mla_attention(cfg: HybridConfig, mp, q_n, q_r, latent):
+def index_project(cfg: HybridConfig, mp, y, cq0, positions):
+    """The selector's projections of the normed input y [..., H] and the
+    normed query latent cq0 [..., q_rank]: queries ``qI [..., ni, di]`` and
+    the token's key ``kI [..., di]`` (LayerNorm with bias), both with their
+    last ``qk_rope_head_dim`` dims rotated at the full layers' base, and the
+    heads' weights ``[..., ni]`` float32 with the score's constant ``ni^-1/2
+    di^-1/2`` folded in."""
+    dt, r = y.dtype, cfg.qk_rope_head_dim
+    ni, di = cfg.index_n_heads, cfg.index_head_dim
+    q = jnp.einsum("...q,qnd->...nd", cq0, mp["wiq"].astype(dt))
+    k = _norm(y @ mp["wik"].astype(dt),
+              {"scale": mp["ik_norm"], "bias": mp["ik_bias"]}, "layernorm",
+              INDEX_NORM_EPS)
+
+    def rope(x):
+        return jnp.concatenate(
+            [x[..., :di - r], _rope(x[..., di - r:], positions,
+                                    cfg.rope_theta, cfg.mla_inv_freq())], -1)
+
+    w = (y @ mp["wiw"].astype(dt)).astype(jnp.float32) / math.sqrt(ni * di)
+    return rope(q), rope(k[..., None, :])[..., 0, :], w
+
+
+def index_scores(q, w, keys):
+    """``I(t, s) = sum_h w[t, h] relu(q[t, h] . keys[s])``: q [..., T, ni,
+    di], w [..., T, ni] float32, keys [..., N, di]; float32 [..., T, N]."""
+    s = jnp.einsum("...tnd,...sd->...tns", q, keys.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("...tns,...tn->...ts", jax.nn.relu(s), w)
+
+
+def topk_mask(scores, visible, k: int):
+    """Which of each row's entries are among its ``k`` largest *visible*
+    ones, exactly (every visible one where fewer than ``k`` are); a tie goes
+    to the earlier entry. scores float32 [..., N], visible bool [..., N].
+    The ``k``-th largest value is found by its bits, most significant
+    first (32 counts over the row: no sort), then the ties at it are taken
+    from the front."""
+    bits = lax.bitcast_convert_type(scores.astype(jnp.float32) + 0.0,
+                                    jnp.uint32)
+    # an order-preserving map of floats to unsigned ints; 0 lies below all
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    key = jnp.where(visible, key, jnp.uint32(0))
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(key >= cand[..., None], axis=-1)
+        return jnp.where(n >= k, cand, prefix)
+
+    thr = lax.fori_loop(0, 32, bit, jnp.zeros(key.shape[:-1], jnp.uint32))
+    above = key > thr[..., None]
+    tie = key == thr[..., None]
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return visible & (above | (tie & (jnp.cumsum(tie, axis=-1) <= room)))
+
+
+def mla_attention(cfg: HybridConfig, mp, q_n, q_r, latent, windowed=False,
+                  selected=None):
     """Causal latent attention of whole sequences in the expanded form, no
-    cache: q_n, q_r [B, S, n, .]; latent [B, S, c + rope]."""
+    cache: q_n, q_r [B, S, n, .]; latent [B, S, c + rope]. ``windowed``: the
+    "w" layers' mixer under its window; ``selected`` bool [B, S, S]: the
+    keys the selector kept for each query."""
     S, dt = q_n.shape[1], q_n.dtype
-    k_n, v, k_r = mla_expand(cfg, mp, latent)
+    k_n, v, k_r = mla_expand(cfg, mp, latent, windowed)
     s = (jnp.einsum("bsnd,btnd->bnst", q_n, k_n)
          + jnp.einsum("bsnd,btd->bnst", q_r, k_r)).astype(jnp.float32)
-    ok = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    pr = jax.nn.softmax(jnp.where(ok, s * cfg.mla_scale, -1e30), axis=-1)
+    back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    ok = back >= 0
+    if windowed:
+        ok = ok & (back < cfg.sliding_window)
+    ok = ok[None, None] if selected is None else (ok & selected)[:, None]
+    pr = jax.nn.softmax(jnp.where(ok, s * cfg.mla_sizes(windowed).scale,
+                                  -1e30), axis=-1)
     return jnp.einsum("bnst,btnd->bsnd", pr.astype(dt), v)
+
+
+def select_tokens(cfg: HybridConfig, mp, y, cq0, positions):
+    """The selector on whole sequences, no cache: bool [B, S, S], for each
+    query the ``index_topk`` causally visible keys that score highest."""
+    S = y.shape[1]
+    q, k, w = index_project(cfg, mp, y, cq0, positions)
+    visible = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+    return topk_mask(index_scores(q, w, k), visible[None], cfg.index_topk)
 
 
 @jax.named_scope("attn_gate")
@@ -894,8 +1141,14 @@ def _layer(cfg: HybridConfig, l: int, x, positions, lp, mp, experts, dense):
     state0 = jnp.zeros((B, nv, dk, dv), jnp.float32)    # (a recurrent layer's)
     y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
     if cfg.attention_kind == "mla":
-        q_n, q_r, latent = mla_project(cfg, mp, y, positions)
-        out = mla_output(mp, mla_attention(cfg, mp, q_n, q_r, latent))
+        windowed = cfg.mixer_kinds[l] == "w"
+        selects = bool(cfg.index_topk) and not windowed
+        q_n, q_r, latent, *cq0 = mla_project(cfg, mp, y, positions, windowed,
+                                             query_latent=selects)
+        sel = select_tokens(cfg, mp, y, cq0[0], positions) if selects \
+            else None
+        out = mla_output(mp, mla_attention(cfg, mp, q_n, q_r, latent,
+                                           windowed, sel), y)
     elif cfg.is_full(l):
         window = cfg.layer_windows[l]
         with jax.named_scope("attn_window" if window else "attn_full"):
@@ -948,21 +1201,21 @@ def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x = embed_tokens(cfg, p, tokens)
     K = cfg.dense_layers
-    mixer = "mla" if cfg.attention_kind == "mla" else "attn"
-    seen = {True: 0, False: 0}
+    names = {True: "mla" if cfg.attention_kind == "mla" else "attn",
+             False: cfg.recurrent_kind, "w": "wmla"}
+    seen = {True: 0, False: 0, "w": 0}
     counts, loads = jnp.zeros((len(MOE_COUNTERS),), jnp.int32), []
 
     def at(tree, i):
         return jax.tree.map(lambda a: a[i], tree)
 
     for l in range(cfg.num_layers):
-        full = cfg.is_full(l)
+        full = cfg.mixer_kinds[l]
         fn = functools.partial(_layer, cfg, l)
         if cfg.remat:
             fn = checkpoint_wrapper(fn, policy=cfg.remat_policy)
         x, c, load = fn(x, positions, at(p["layers"], l),
-                        at(p[mixer if full else cfg.recurrent_kind],
-                           seen[full]),
+                        at(p[names[full]], seen[full]),
                         at(p["experts"], l - K) if cfg.num_experts and l >= K
                         else None,
                         at(p["dense"], l) if l < K else None)
@@ -1038,6 +1291,9 @@ class HybridLM:
 
     def logical_axes(self) -> Dict[str, Any]:
         return logical_axes(self.config)
+
+    def axes_for(self, params) -> Dict[str, Any]:
+        return axes_for(self.config, params)
 
     def apply(self, params, tokens, positions=None):
         return apply(self.config, params, tokens, positions)

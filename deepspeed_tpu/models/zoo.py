@@ -204,6 +204,56 @@ def _register_hybrid():
             first_k_dense=1, num_experts=16, top_k=4, moe_ffn_size=32,
             shared_ffn_size=32, experts_held=4, router_scoring="sigmoid",
             routed_scale=2.5, shared_gate=False, remat=False),
+        # dots3-note-prev (huggingface.co/dots-studio/dots3-note-prev
+        # config.json, model_type dots3_note) at the published values: 46
+        # layers, a full latent layer then periods of one full and three
+        # sliding ones. Full: latent attention (128 heads of 128 + 64 over a
+        # 512 + 64 latent) whose context a learned selector picks (64 heads
+        # of 128 score every cached token's 128-value key, the top 2,048
+        # are attended); sliding: a latent mixer of its own (64 heads of
+        # 192 + 64 over a 1024 + 64 latent) over the last 513 tokens, its
+        # own rotary base; a sigmoid gate a head on both; both normed
+        # latents rescaled. One leading dense layer, then 256 routed
+        # experts (top 8 by sigmoid score, a bias in the choice, weights
+        # renormalised) and an ungated shared expert.
+        "dots3-note": HybridConfig(
+            vocab_size=152064, hidden_size=5120, num_layers=46,
+            num_heads=128, num_kv_heads=128, attn_head_dim=192,
+            ffn_size=13824, max_seq_len=524288, pos_emb="rope",
+            norm="rmsnorm", activation="swiglu", tie_embeddings=False,
+            rope_theta=8e7, norm_eps=1e-5, attention_kind="mla",
+            q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128,
+            layer_pattern="m" + "mwww" * 11 + "m", sliding_window=513,
+            window_attention_kind="mla", window_num_heads=64,
+            window_q_lora_rank=1024, window_kv_lora_rank=1024,
+            window_qk_nope_head_dim=192, window_qk_rope_head_dim=64,
+            window_v_head_dim=128, window_rope_theta=50000.0,
+            mla_lora_rescale=True, mla_head_gate=True, index_topk=2048,
+            index_n_heads=64, index_head_dim=128, first_k_dense=1,
+            num_experts=256, top_k=8, moe_ffn_size=1536,
+            shared_ffn_size=1536, router_scoring="sigmoid",
+            routed_scale=1.0, shared_gate=False),
+        # the same stack at a toy size, cut as the benchmark's cell is (the
+        # leading dense layer and two periods), a window and a selection
+        # shorter than a test's sequences, 4 of 16 experts held
+        "tiny-dots3": HybridConfig(
+            vocab_size=256, hidden_size=64, num_layers=9, num_heads=4,
+            num_kv_heads=4, attn_head_dim=48, ffn_size=128, max_seq_len=256,
+            pos_emb="rope", norm="rmsnorm", activation="swiglu",
+            tie_embeddings=False, rope_theta=8e7, norm_eps=1e-5,
+            attention_kind="mla", q_lora_rank=48, kv_lora_rank=128,
+            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+            layer_pattern="m" + "mwww" * 3, sliding_window=13,
+            window_attention_kind="mla", window_num_heads=2,
+            window_q_lora_rank=32, window_kv_lora_rank=128,
+            window_qk_nope_head_dim=48, window_qk_rope_head_dim=16,
+            window_v_head_dim=32, window_rope_theta=5e4,
+            mla_lora_rescale=True, mla_head_gate=True, index_topk=16,
+            index_n_heads=4, index_head_dim=32, first_k_dense=1,
+            num_experts=16, top_k=4, moe_ffn_size=32, shared_ffn_size=32,
+            experts_held=4, router_scoring="sigmoid", routed_scale=1.0,
+            shared_gate=False, remat=False),
         # Trinity-Mini (huggingface.co/arcee-ai/Trinity-Mini config.json,
         # model_type afmoe) at the published values: gated softmax
         # attention with QK-norm in all 32 layers, three under a 2048-token

@@ -575,15 +575,24 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array,
 _MLA_BLOCK_TOKENS = 512
 
 
-def _mla_decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
-                       started_ref, buf, sem, slot_ref, acc_ref, *, bs: int,
-                       nb: int,
-                       pages: int, vd: int, scale: float):
+def _mla_decode_kernel(bt_ref, ctx_ref, layer_ref, *refs, bs: int, nb: int,
+                       pages: int, vd: int, scale: float, windowed: bool,
+                       chosen: bool):
     """One sequence a grid step over its own latent pages
     (:func:`_walk_pages`), every head at once: the heads' absorbed queries
     ``[heads, W]`` against a block ``[pages * bs, W]``, the values the first
     ``vd`` columns of the same rows already in VMEM. There is no KV head, so
-    no head mask."""
+    no head mask. ``windowed``: one more scalar operand, each sequence's
+    lower bound, and one more term of the mask (a token below it is not
+    seen). ``chosen``: one more blocked operand, ``[blocks, tokens]`` of the
+    sequence's context, above zero where the token is attended over (a
+    selector's choice): one more term of the mask too."""
+    lo, keep_ref = 0, None
+    if windowed:
+        lo, refs = refs[0][pl.program_id(0)], refs[1:]
+    if chosen:
+        keep_ref, refs = refs[1], refs[:1] + refs[2:]
+    (q_ref, kv_hbm, out_ref, started_ref, buf, sem, slot_ref, acc_ref) = refs
     T = pages * bs
     W = buf.shape[-1]
     rp = q_ref.shape[1]
@@ -598,10 +607,17 @@ def _mla_decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
         k = buf[slot].reshape(T, W).astype(dt)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        sc = jnp.where(tok < ctx - blk * T, sc, NEG_INF)
+        seen = tok < ctx - blk * T
+        if windowed:
+            seen = jnp.logical_and(seen, tok >= lo - blk * T)
+        if chosen:
+            seen = jnp.logical_and(seen, keep_ref[0, pl.ds(blk, 1), :] > 0.0)
+        sc = jnp.where(seen, sc, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(sc - m_new)
+        if windowed or chosen:  # a block with no key seen: exp(0) of none
+            p = jnp.where(seen, p, 0.0)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(dt), k[:, :vd], (((1,), (0,)), ((), ())),
@@ -620,7 +636,8 @@ def _mla_decode_kernel(bt_ref, ctx_ref, layer_ref, q_ref, kv_hbm, out_ref,
 def mla_decode_attention(q: jax.Array, kv: jax.Array, block_table: jax.Array,
                          context_lens: jax.Array, *, value_dim: int,
                          scale: float, layer,
-                         pages_per_compute_block: int = None
+                         pages_per_compute_block: int = None,
+                         lower: jax.Array = None, chosen: jax.Array = None
                          ) -> Tuple[jax.Array, jax.Array]:
     """Decode attention of multi-head latent attention in its absorbed form,
     over a latent pool (``inference/ragged/kv_cache.py``, kind "latent").
@@ -634,6 +651,13 @@ def mla_decode_attention(q: jax.Array, kv: jax.Array, block_table: jax.Array,
     block_table  [S, max_pages]; context_lens [S] (0: a dead slot, output 0)
     value_dim    the leading columns of a row that are also its value (the
                  compressed vector's width, a multiple of 128)
+    lower        [S] or None: a sequence's tokens below it are not seen (a
+                 sliding window's far edge, counted along the block table;
+                 the pages below it are still walked, so hand over a table
+                 that starts at the window's first page)
+    chosen       [S, max_pages * block_size] bool or None: the context tokens
+                 a sequence attends over (a selector's choice; the others
+                 are fetched with their pages and masked)
 
     A score is ``q . row * scale``; the output ``[S, heads, value_dim]`` is
     the softmax-weighted sum of the rows' leading ``value_dim`` columns: keys
@@ -654,16 +678,26 @@ def mla_decode_attention(q: jax.Array, kv: jax.Array, block_table: jax.Array,
     rp = -(-nh // 8) * 8
     if rp != nh:
         q = jnp.pad(q, ((0, 0), (0, rp - nh), (0, 0)))
+    windowed = lower is not None
     kernel = functools.partial(_mla_decode_kernel, bs=bs, nb=nb, pages=P,
-                               vd=value_dim, scale=float(scale))
+                               vd=value_dim, scale=float(scale),
+                               windowed=windowed, chosen=chosen is not None)
+    bounds = (lower.astype(jnp.int32),) if windowed else ()
+    keep, keep_spec = (), []
+    if chosen is not None:      # a row a block of the walk, whole blocks
+        nblk = -(-Bm // P)
+        keep = (jnp.pad(chosen.astype(jnp.float32),
+                        ((0, 0), (0, nblk * P * bs - Bm * bs))).reshape(
+                            S, nblk, P * bs),)
+        keep_spec = [pl.BlockSpec((1, nblk, P * bs), lambda s, *_: (s, 0, 0))]
     out, started = pl.pallas_call(
         kernel,
         name="mla_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + windowed,
             grid=(S,),
             in_specs=[pl.BlockSpec((1, rp, W), lambda s, *_: (s, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      *keep_spec, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((1, rp, value_dim),
                                     lambda s, *_: (s, 0, 0)),
                        pl.BlockSpec(memory_space=pltpu.SMEM)],
@@ -680,5 +714,99 @@ def mla_decode_attention(q: jax.Array, kv: jax.Array, block_table: jax.Array,
         interpret=_interpret(),
     )(block_table.astype(jnp.int32),
       jnp.minimum(context_lens.astype(jnp.int32), Bm * bs),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, kv)
+      jnp.asarray(layer, jnp.int32).reshape(1), *bounds, q, *keep, kv)
     return out[:, :nh], started
+
+
+# ---------------------------------------------------------------------------
+# the learned selector's scores over a sequence's cached indexer keys
+# ---------------------------------------------------------------------------
+
+# tokens of context a block of the indexer's walk holds: 16 pages of 64, 256
+# KiB a buffer at 128 bf16 lanes a key; the score tile is [heads, tokens]
+_INDEX_BLOCK_TOKENS = 1024
+
+
+def _index_scores_kernel(bt_ref, ctx_ref, layer_ref, q_ref, w_ref, ik_hbm,
+                         out_ref, buf, sem, slot_ref, *, bs: int, nb: int,
+                         pages: int):
+    """One sequence a grid step over its own pages of indexer keys
+    (:func:`_walk_pages`): the heads' queries ``[heads, d]`` against a block
+    of keys ``[pages * bs, d]``, ReLU, the heads' weights as one more product
+    ``[8, heads] x [heads, tokens]`` (row 0 is the sequence's), written to
+    the block's row of the output. Rows of blocks past the context are not
+    written."""
+    T = pages * bs
+    dt = jnp.promote_types(q_ref.dtype, buf.dtype)
+    q = q_ref[0].astype(dt)
+    w = w_ref[0]
+
+    def multiply(blk, slot, carry):
+        k = buf[slot].reshape(T, -1).astype(dt)
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        tot = jax.lax.dot_general(w, jnp.maximum(sc, 0.0),
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST)
+        out_ref[0, pl.ds(blk, 1), :] = tot[:1]
+        return carry
+
+    _walk_pages(bt_ref, ctx_ref, layer_ref, ik_hbm, buf, sem, slot_ref, bs=bs,
+                nb=nb, pages=pages, multiply=multiply, init=0)
+
+
+def index_scores_decode(q: jax.Array, w: jax.Array, ik: jax.Array,
+                        block_table: jax.Array, context_lens: jax.Array, *,
+                        layer) -> jax.Array:
+    """The learned selector's scores of one query a sequence against every
+    indexer key the sequence has cached: ``I[s, t] = sum_h w[s, h] relu(q[s,
+    h] . ik[layer, page(t), t % bs])``.
+
+    q            [S, heads, d] (on a chip d fills whole 128-lane tiles: the
+                 page fetch slices the pool at tiles); w [S, heads] float32
+    ik           [L, num_blocks, block_size, d]: the keys, page-addressed as
+                 the latent pool beside them
+    block_table  [S, max_pages]; context_lens [S]
+
+    Returns float32 ``[S, max_pages * block_size]``; an entry at or past the
+    sequence's context (rounded up to whole blocks of the walk) is not
+    written and holds anything: mask by ``context_lens``. Each key is read
+    once, nothing past the context is fetched; the heads' scores never leave
+    VMEM."""
+    S, nh, d = q.shape
+    _, nb, bs, dk = ik.shape
+    if d != dk:
+        raise ValueError(f"queries {q.shape} against indexer keys {ik.shape}: "
+                         f"the widths must agree")
+    Bm = block_table.shape[1]
+    P = max(1, min(_INDEX_BLOCK_TOKENS // bs, Bm))
+    nblk = -(-Bm // P)
+    T = P * bs
+    if nblk * P != Bm:      # whole blocks of the walk
+        block_table = jnp.pad(block_table, ((0, 0), (0, nblk * P - Bm)))
+    w8 = jnp.pad(w.astype(jnp.float32)[:, None, :], ((0, 0), (0, 7), (0, 0)))
+    kernel = functools.partial(_index_scores_kernel, bs=bs, nb=nb, pages=P)
+    out = pl.pallas_call(
+        kernel,
+        name="dsa_index",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, nh, d), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec((1, 8, nh), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, nblk, T), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, P, bs, d), ik.dtype),       # two blocks
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, nblk, T), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(block_table.astype(jnp.int32),
+      jnp.minimum(context_lens.astype(jnp.int32), Bm * bs),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, w8, ik)
+    return out.reshape(S, nblk * T)[:, :Bm * bs]

@@ -240,6 +240,10 @@ def _refuse_for_recurrent(engine) -> None:
     refuse = getattr(engine, "_refuse_without_snapshot", None)
     if refuse is not None:
         refuse("the disagg prefill->decode hand-off")
+    # a windowed pool's ring of pages is no chain of prefix blocks
+    refuse = getattr(engine, "_refuse_for_windowed_pool", None)
+    if refuse is not None:
+        refuse("the disagg hand-off wire")
     # the wire's codec is laid out by K/V head: a latent pool has none
     refuse = getattr(engine, "_refuse_for_latent_pool", None)
     if refuse is not None:

@@ -233,6 +233,52 @@ def test_latent_programs_hold_their_scopes_and_one_layer_body(program, scopes):
                               str(traced.jaxpr))) == 2
 
 
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", ["mla/mla_project", "mla/dsa_index", "mla/dsa_select",
+                "mla/dsa_attn", "mla/mla_absorb", "mla/attn_gate",
+                "wmla/mla_project", "wmla/wmla_attn", "wmla/attn_gate",
+                "dense_ffn", "moe/moe_route"]),
+    ("prefill", ["mla/mla_project", "mla/dsa_index", "mla/mla_chunk",
+                 "wmla/wmla_chunk", "wmla/attn_gate", "dense_ffn",
+                 "moe/moe_experts"])])
+def test_selected_and_windowed_latent_programs_hold_their_scopes(program,
+                                                                  scopes):
+    """The trace readers key on these paths (``dsa_index_ms`` on
+    ``dsa_index`` and ``dsa_select``, ``dsa_attn_ms`` on ``dsa_attn``,
+    ``window_mla_decode_ms`` on ``wmla``, its roofline on ``wmla_attn``).
+    The dense prologue (a full layer) runs before the scan; the two periods
+    are one scanned body of a full layer and a scanned run of three sliding
+    ones: in a token step two ``dsa_index`` kernels and three ``mla_decode``
+    (two over chosen tokens, one over a ring), whatever the depth."""
+    model = get_model("tiny-dots3")
+    cfg = model.config
+    params = jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    ring = 4
+    pools = {"kv": _sds((cfg.kv_layers, NB, BS, 256)),
+             "ik": _sds((cfg.kv_layers, NB, BS, cfg.index_key_dim)),
+             "wkv": _sds((cfg.window_layers, S * ring + 1, BS, 256)),
+             "counters": _sds((len(COUNTERS),), I32)}
+    fns = engine_v2._shared_step_fns(cfg, None)
+    ids = lambda *shape: _sds(shape, I32)  # noqa: E731
+    if program == "prefill":
+        traced = fns["prefill"].trace(params, pools, ids(1, 16), ids(1),
+                                      ids(1), ids(1, BM),
+                                      window_table=ids(S, ring))
+    else:
+        traced = fns["decode"].trace(params, pools, ids(S), ids(S),
+                                     ids(S, BM), ids(S),
+                                     window_table=ids(S, ring))
+    text = traced.lower().as_text(debug_info=True)
+    for path in scopes:
+        assert path in text, path
+    assert _module(traced.lower()) == f"jit_dstpu_serve_{program}"
+    if program == "decode":
+        jaxpr = str(traced.jaxpr)
+        assert len(re.findall(r"\bname=dsa_index\b", jaxpr)) == 2
+        assert len(re.findall(r"\bname=mla_decode\b", jaxpr)) == 3
+
+
 # -- training programs -------------------------------------------------------
 
 TINY = TransformerConfig(

@@ -11,6 +11,7 @@ no chip time — and assert the Mosaic custom call is in the program.
 Nothing runs: a pass here is not a chip run.
 """
 
+import math
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
@@ -651,6 +652,85 @@ def test_kimi_programs_keep_the_latent_pool_in_place(chip, program):
     assert 11.9 < mem.argument_size_in_bytes / 2**30 < 12.1
     print(program, mem.argument_size_in_bytes / 2**30,
           mem.temp_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30)
+
+
+def _dots3_c1(chip):
+    """``dots3-note-prev-serve-c1`` as abstract arguments on the described
+    chip. Returns (step programs, serving params, pools, ids, the bytes of
+    the three pools)."""
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.inference.ragged.kv_cache import (KVCacheConfig,
+                                                         WindowPoolConfig)
+    from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.zoo import get_model
+
+    blocks, bs, pages, seqs = 16384, 64, 512, 48
+    model = get_model("dots3-note", num_layers=9, experts_held=8,
+                      vocab_size=19072, max_seq_len=pages * bs,
+                      param_dtype=BF16, remat=False)
+    cfg = model.config
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
+                       jax.eval_shape(model.init, jax.random.PRNGKey(0))))
+    kv = KVCacheConfig(num_layers=cfg.kv_layers, kv_heads=128, head_dim=192,
+                       block_size=bs, num_blocks=blocks, dtype=BF16,
+                       kind="latent", latent_dim=cfg.latent_dim,
+                       index_key_dim=cfg.index_key_dim)
+    win = WindowPoolConfig.for_sequences(
+        seqs, layers=cfg.window_layers, window=513,
+        row_dim=cfg.window_latent_dim, block_size=bs)
+    ring = win.ring_pages
+    pools = {"kv": sds(kv.pool_shape, BF16),
+             "ik": sds(kv.pool_shape[:3] + (cfg.index_key_dim,), BF16),
+             "wkv": sds(win.pool_shape, BF16),
+             "counters": sds((len(COUNTERS),), jnp.int32)}
+    held = kv.num_blocks * kv.bytes_per_block + 2 * math.prod(win.pool_shape)
+    return (engine_v2._shared_step_fns(cfg, None), params, pools,
+            lambda *shape: sds(shape, jnp.int32), held, ring)
+
+
+@pytest.mark.parametrize("program", ["decode", "multi_decode", "prefill"])
+def test_dots3_programs_keep_the_three_pools_in_place(chip, program):
+    """The latent pool with the selector's keys beside it (16,384 pages of
+    64 tokens, 640 + 128 bf16 lanes, three full layers: 4.5 GiB) and the
+    windowed pool (48 rings of 10 pages and a scratch page, 1,152 lanes, six
+    sliding layers: 0.4 GiB) come back in the buffers they came in; the
+    token step holds the ``dsa_index`` and ``mla_decode`` kernels (the
+    second over the chosen tokens and over the rings) and the grouped
+    product; arguments are two thirds of the chip; temporaries: a token step
+    0.26 GiB, an 8-step burst 0.57, a 2,048-token chunk of one sequence 1.40
+    (the selector's scores and their bit keys ``[2048, 32768]`` twice 0.25,
+    the expanded form's scores as Kimi's)."""
+    fns, params, pools, ids, held, ring = _dots3_c1(chip)
+    S, pages = 48, 512
+    if program == "prefill":
+        lowered = fns["prefill"].lower(params, pools, ids(1, 2048), ids(1),
+                                       ids(1), ids(1, pages),
+                                       window_table=ids(S, ring))
+    else:
+        steps = {"steps": 8} if program == "multi_decode" else {}
+        lowered = fns[program].lower(params, pools, ids(S), ids(S),
+                                     ids(S, pages), ids(S),
+                                     window_table=ids(S, ring), **steps)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if program != "prefill":
+        assert "mla_decode" in text and "gmm" in text and "dsa_index" in text
+    mem = compiled.memory_analysis()
+    assert ring == 10
+    assert held == 3 * 16384 * 64 * 768 * 2 + 6 * 481 * 64 * 1152 * 2
+    assert mem.alias_size_in_bytes >= held
+    print(program, mem.argument_size_in_bytes / 2**30,
+          mem.temp_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30)
+    assert mem.temp_size_in_bytes < 1.5 * 2**30
+    # 5.77 GiB of weights + 4.9 GiB of pools: 67% of the chip's 16
+    assert 10.5 < mem.argument_size_in_bytes / 2**30 < 10.9
 
 
 def _zero3_fsdp4_step(monkeypatch, layers, job_extra=None):

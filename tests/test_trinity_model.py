@@ -134,7 +134,8 @@ def test_the_serving_runner_refuses_a_windowed_stack_by_name():
 
     c = get_model("tiny-trinity").config
     with pytest.raises(NotImplementedError,
-                       match="windowed attention is not served yet"):
+                       match="windowed gated softmax attention is not "
+                             "served yet"):
         hybrid_runner._run_stack(c, {}, jnp.zeros((4, 64)), {}, None, None,
                                  None)
 
