@@ -25,7 +25,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deepspeed_tpu.inference import model_runner
 from deepspeed_tpu.inference.ragged import (
@@ -63,6 +63,19 @@ class _QueuedRequest:
     # re-prefilling; ``tokens`` carries the folded history anyway as
     # the fallback if the tier spills the session before readmission
     paged: bool = False
+
+
+@dataclasses.dataclass
+class _BurstInFlight:
+    """A decode burst the device has been handed and the host has not read
+    yet: what ``_issue_burst`` leaves for ``_collect_burst``."""
+    live: List[Any]                 # its sequences, in slot order
+    steps: int                      # K
+    toks: Any                       # [K, S] on the device
+    last: Any                       # [S], row K - 1: the next call's ids
+    pools: Any                      # what the call returned (its counters)
+    counts: Dict[str, int]          # the call's counters, for ``stats``
+    eos_token_id: Optional[int]     # of the step that issued it
 
 
 # Process-level jit cache shared by every engine instance. A fleet of
@@ -392,6 +405,11 @@ class InferenceEngineV2:
                       # chunks of a step split by program
                       # (_split_by_program)
                       "prefill_chunk_calls": 0, "burst_steps": 0,
+                      # decode bursts dispatched while the one before was
+                      # still unread (_burst_step), and the rows of such a
+                      # call thrown away because their sequence had ended
+                      # in the call before it
+                      "calls_issued_ahead": 0, "ahead_rows_discarded": 0,
                       # what each program was given, counted where a call
                       # is issued (_dispatch): engine steps that made a
                       # call at all, and below a set a program; a
@@ -441,6 +459,16 @@ class InferenceEngineV2:
         self._calls_issued = 0
         self._calls_at_put: Dict[int, List[int]] = {}
         self._closed = False
+        # the decode burst in flight (issued by the last ``serve_step``,
+        # read by the next), and tokens a drain read outside a step, which
+        # the next ``serve_step`` returns with its own (_drain)
+        self._inflight: Optional[_BurstInFlight] = None
+        self._undelivered: Dict[int, List[int]] = {}
+        # where a burst's ``token_ids`` live: what a step program's own
+        # outputs carry, so that the ids built on the host and the last row
+        # of the call before are one kind of argument to one compiled
+        # program
+        self._replicated = NamedSharding(self.mesh, PartitionSpec())
         # admission queue: put() never raises on a full KV pool — requests
         # wait FIFO here and admit as blocks free up; preemption victims
         # requeue at the FRONT with their generated tokens preserved
@@ -861,6 +889,7 @@ class InferenceEngineV2:
         allows. False when paging doesn't apply — the sequence stays
         live."""
         self._refuse_without_snapshot("host-tier parking (page_out)")
+        self._drain()
         seq = self.state.seqs.get(uid)
         if seq is None or seq.done:
             return False
@@ -884,6 +913,7 @@ class InferenceEngineV2:
         self._refuse_for_latent_pool("the session-migration wire")
         self._refuse_without_snapshot("session migration "
                                       "(migrate_out_session)")
+        self._drain()
         tier = getattr(self.kv_cache, "host_tier", None)
         seq = self.state.seqs.get(uid)
         if seq is None or seq.done:
@@ -954,6 +984,7 @@ class InferenceEngineV2:
         self._refuse_for_latent_pool("the session-migration wire")
         self._refuse_without_snapshot("session migration "
                                       "(install_migrated_session)")
+        self._drain()
         uid = int(sess.uid)
         if uid in self.state.seqs or any(r.uid == uid for r in self._queue):
             return "duplicate"
@@ -1050,6 +1081,7 @@ class InferenceEngineV2:
         must not read ``params`` afterwards."""
         from deepspeed_tpu.inference.engine import InferenceEngine
 
+        self._drain()       # the call in flight reads the weights it was given
         if params is None:
             params = self.model.init(
                 jax.random.PRNGKey(int(seed or 0)))
@@ -1206,7 +1238,8 @@ class InferenceEngineV2:
         return span("serve_step", step_id=self._step_id)
 
     def _dispatch(self, program: str, seqs, tokens: int,
-                  token_steps: int = 1, chunks: int = 0, **shape):
+                  token_steps: int = 1, chunks: int = 0,
+                  counts: Optional[Dict[str, int]] = None, **shape):
         """The one place a program call is described: the
         ``dstpu/dispatch`` span to issue it under (the jitted call goes
         inside the ``with``), after counting it. ``program`` is one of
@@ -1220,7 +1253,10 @@ class InferenceEngineV2:
         rows the program computes whatever it carries (``padded_rows``);
         the counters (``calls_<program>`` ...) take the same numbers, and
         each carried sequence whose first token is still out counts the
-        call as its own."""
+        call as its own. ``counts``: a dict to take the call's counters in
+        place of ``stats``, for a burst, whose counters move with its
+        tokens (_collect_burst); a call dispatched while the one before it
+        is unread says ``ahead=1`` in ``shape``."""
         if program == "prefill":
             padded_rows = shape["S"] * shape["tq"]
         elif program in ("decode", "multi_decode"):
@@ -1230,17 +1266,19 @@ class InferenceEngineV2:
         call = self._step_calls
         self._step_calls += 1
         self._calls_issued += 1
-        st = self.stats
-        st["steps_dispatched"] += call == 0
-        st["calls_" + program] += 1
-        st["rows_" + program] += tokens
-        st["padded_rows_" + program] += padded_rows
-        st["token_steps_" + program] += token_steps
+        self.stats["steps_dispatched"] += call == 0
+        mine = {"calls_" + program: 1, "rows_" + program: tokens,
+                "padded_rows_" + program: padded_rows,
+                "token_steps_" + program: token_steps}
         if program == "prefill":
-            st["prefill_chunk_calls"] += 1
+            mine["prefill_chunk_calls"] = 1
         elif program in ("decode", "multi_decode"):
-            st["decode_kernel_steps"] += token_steps
-            st["burst_steps"] += program == "multi_decode"
+            mine["decode_kernel_steps"] = token_steps
+            mine["burst_steps"] = int(program == "multi_decode")
+        if counts is None:
+            self._count(mine)
+        else:
+            counts.update(mine)
         if self._calls_at_put:
             for seq in seqs:
                 waiting = self._calls_at_put.get(seq.uid)
@@ -1251,10 +1289,17 @@ class InferenceEngineV2:
                     padded_rows=padded_rows, token_steps=token_steps,
                     chunks=chunks, **shape)
 
+    def _count(self, counts: Dict[str, int]) -> None:
+        for name, n in counts.items():
+            self.stats[name] += n
+
     def step(self, temperature: float = 0.0, seed: int = 0,
              eos_token_id: Optional[int] = None) -> Dict[int, int]:
         """Run one SplitFuse step. Returns {uid: new_token} for sequences
-        that produced a token this step."""
+        that produced a token this step. (A burst still in flight from a
+        ``serve_step`` is read first; the next ``serve_step`` returns its
+        tokens.)"""
+        self._drain()
         with self._open_step():
             with span("admit"):
                 self._admit_from_queue()
@@ -1606,50 +1651,109 @@ class InferenceEngineV2:
             self._hub.gauge("serve.burst_efficiency",
                             self._burst_tokens / self._burst_capacity,
                             labels=self._metric_labels)
+            self._hub.gauge("serve.issued_ahead_share",
+                            self.stats["calls_issued_ahead"]
+                            / max(1, self.stats["calls_multi_decode"]),
+                            labels=self._metric_labels)
 
-    def _try_decode_burst(self, eos_token_id: Optional[int]
-                          ) -> Optional[Dict[int, List[int]]]:
-        """Run ``decode_steps`` greedy tokens in one device round trip.
+    def _burst_step(self, eos_token_id: Optional[int]
+                    ) -> Optional[Dict[int, List[int]]]:
+        """Hand out ``decode_steps`` greedy tokens a sequence from one
+        device program, and keep the device one call ahead where that costs
+        nobody anything.
 
-        Applies only in steady state: every live sequence mid-decode, no
-        prefill pending, and KV capacity for the whole burst (the block
-        tables are frozen for its duration). Returns None when a single
-        SplitFuse step should run instead."""
+        A burst applies only in steady state: every live sequence
+        mid-decode, no prefill pending, and KV capacity for the whole burst
+        (the block tables are frozen for its duration). It has two halves:
+        *issue* (plan, build, dispatch: _issue_burst) and *collect* (fetch,
+        accept, bookkeeping: _collect_burst). This step collects the call
+        the last step left in flight, or where there is none issues one and
+        collects it; before it collects, it issues the call after that if
+        the rule of _plan_decode_burst allows. The follow-on needs nothing
+        the host learns from the call before it: its ids are that call's
+        last row, on the device already. So the host's work between two
+        calls (fetch, bookkeeping, the caller's own, the next plan and
+        build) runs under a call and not between two. Returns None when a
+        single SplitFuse step should run instead."""
+        t0 = time.perf_counter()
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            flight = self._issue_burst(eos_token_id)
+            if flight is None:
+                return None
+        self._inflight = self._issue_burst(eos_token_id, after=flight)
+        return self._collect_burst(flight, t0)
+
+    def _issue_burst(self, eos_token_id: Optional[int],
+                     after: Optional[_BurstInFlight] = None
+                     ) -> Optional[_BurstInFlight]:
+        """Plan, build and dispatch one burst; None where none applies.
+        ``after``: the call in flight that this one follows. Its sequences
+        stand ``after.steps`` tokens past what the host has accounted, and
+        its ``last`` row is this call's ``token_ids``."""
         with span("schedule"):
-            K = self._plan_decode_burst()
+            K = self._plan_decode_burst(after)
         if K is None:
             return None
-        live = [s for s in self.state.seqs.values() if not s.done]
-        t0 = time.perf_counter()
+        if after is None:
+            live, unread = [s for s in self.state.seqs.values()
+                            if not s.done], 0
+        else:
+            live, unread = after.live, after.steps
         with self.mesh:
             with span("build_batch"):
                 S = self.max_seqs
-                d_tok = np.zeros(S, np.int32)
                 d_pos = np.zeros(S, np.int32)
                 ctx = np.zeros(S, np.int32)
                 bt = np.zeros((S, self.max_blocks_per_seq), np.int32)
                 for i, s in enumerate(live):
-                    d_tok[i] = (s.generated[-1] if s.generated
-                                else int(s.input_tokens[-1]))
-                    d_pos[i] = s.seen_tokens
-                    ctx[i] = s.seen_tokens + 1
+                    d_pos[i] = s.seen_tokens + unread
+                    ctx[i] = s.seen_tokens + unread + 1
                     bt[i, :len(s.kv_blocks)] = s.kv_blocks
-                args = (jnp.asarray(d_tok), jnp.asarray(d_pos),
-                        jnp.asarray(bt), jnp.asarray(ctx))
+                if after is None:
+                    d_tok = np.zeros(S, np.int32)
+                    for i, s in enumerate(live):
+                        d_tok[i] = (s.generated[-1] if s.generated
+                                    else int(s.input_tokens[-1]))
+                    ids = jax.device_put(d_tok, self._replicated)
+                else:
+                    ids = after.last
+                args = (ids, jnp.asarray(d_pos), jnp.asarray(bt),
+                        jnp.asarray(ctx))
                 pools = self._pool_args(live)
+            counts: Dict[str, int] = {}
+            ahead = {} if after is None else {"ahead": 1}
             with self._dispatch("multi_decode", live, K * len(live),
-                                token_steps=K):
-                toks, new_kv = self._multi_decode_fn(
+                                token_steps=K, counts=counts, **ahead):
+                toks, new_kv, last = self._multi_decode_fn(
                     self.params, self.kv_cache.kv_state, *args, steps=K,
                     **pools)
             # at once: the handle the cache still holds is consumed
             self.kv_cache.set_kv_state(new_kv)
-            with span("fetch"):
-                toks_np = np.asarray(toks)  # [K, S]: one fetch per K tokens
-                self._fetch_counters([(new_kv, True)])
+        self.stats["calls_issued_ahead"] += after is not None
+        return _BurstInFlight(live, K, toks, last, new_kv, counts,
+                              eos_token_id)
+
+    def _collect_burst(self, flight: _BurstInFlight, t0: float
+                       ) -> Dict[int, List[int]]:
+        """Read a burst's tokens and account for them: the call's counters
+        move here, with the tokens the step returns. A sequence that ended
+        in the call before this one (an end-of-sequence token, found when
+        this call was already issued) takes none of its rows; it is
+        released once no call that writes its pages or its state is in
+        flight."""
+        K = flight.steps
+        with span("fetch"):
+            toks_np = np.asarray(flight.toks)  # [K, S]: one fetch per K tokens
+            self._fetch_counters([(flight.pools, True)])
         with span("bookkeep"):
+            self._count(flight.counts)
+            eos_token_id = flight.eos_token_id
             emitted: Dict[int, List[int]] = {}
-            for i, s in enumerate(live):
+            for i, s in enumerate(flight.live):
+                if s.done:
+                    self.stats["ahead_rows_discarded"] += K
+                    continue
                 accepted = []
                 budget_left = s.gen_budget_left
                 for k in range(K):
@@ -1672,27 +1776,65 @@ class InferenceEngineV2:
             n_emitted = sum(len(v) for v in emitted.values())
             self.stats["tokens_multi_decode"] += n_emitted
             self._burst_tokens += n_emitted
-            self._burst_capacity += K * len(live)
+            self._burst_capacity += K * len(flight.live)
             for uid, toks in emitted.items():
                 if toks:
                     self._note_emitted(uid, len(toks), now)
             self._update_serve_gauges()
-            self._release_finished()
+            if self._inflight is None:
+                self._release_finished()
         return emitted
 
-    def _plan_decode_burst(self) -> Optional[int]:
+    def _drain(self) -> None:
+        """Read the burst in flight, if any, outside the step that would
+        have: whatever reads or moves engine state from outside a step does
+        this first. The tokens wait in ``_undelivered`` for the next
+        ``serve_step`` (or ``take_undelivered``)."""
+        flight, self._inflight = self._inflight, None
+        if flight is not None:
+            for uid, toks in self._collect_burst(
+                    flight, time.perf_counter()).items():
+                self._undelivered.setdefault(uid, []).extend(toks)
+
+    def take_undelivered(self) -> Dict[int, List[int]]:
+        """Tokens read while no step was open (_drain), handed over once:
+        for a caller that moves a session elsewhere and may not step this
+        engine again before the tokens are due."""
+        out, self._undelivered = self._undelivered, {}
+        return out
+
+    def _plan_decode_burst(self, after: Optional[_BurstInFlight] = None
+                           ) -> Optional[int]:
         """The burst length K this round can run, with KV capacity for
-        the whole burst allocated, or None."""
+        the whole burst allocated, or None.
+
+        With ``after``, the call in flight: whether the engine runs a call
+        ahead, from what it can see. The batch is full (an arrival could
+        not be admitted before a slot frees, so a second queued call keeps
+        nobody from a first token; with a free slot the engine stays one
+        call deep), nothing waits in the admission queue, no drafter (a
+        speculative round is planned from tokens the host has not read),
+        the sequences are the call's own with none ended, none reaches its
+        budget inside the call in flight (a finish is an admission: the
+        blocking path's), and there is capacity for both calls."""
         live = [s for s in self.state.seqs.values() if not s.done]
         if (self.decode_steps <= 1 or not live or len(live) > self.max_seqs
                 or any((not s.in_decode) or s.pending_prefill for s in live)):
             return None
+        unread = 0
+        if after is not None:
+            unread = after.steps
+            if (self._drafter is not None or self._queue
+                    or len(live) != self.max_seqs
+                    or len(after.live) != len(live)
+                    or any(a is not b for a, b in zip(after.live, live))):
+                return None
         # clamp the burst to the shortest remaining budget: probing
         # capacity K tokens past a sequence that only needs 1 more would
         # trip ensure_capacity's per-seq-cap kill and truncate output
         # that per-token stepping would have finished
         K = min(self.decode_steps,
-                max(1, min(s.gen_budget_left for s in live)))
+                max(1, min(s.gen_budget_left for s in live) - unread))
         if K <= 1:
             return None
         # side-effect-free capacity probe first: per-seq cap, then total
@@ -1700,7 +1842,7 @@ class InferenceEngineV2:
         # and push the fallback step into victim preemption)
         need_total = 0
         for s in live:
-            blocks = self.kv_cache.blocks_needed(s.seen_tokens + K)
+            blocks = self.kv_cache.blocks_needed(s.seen_tokens + unread + K)
             if (self.state.max_blocks_per_seq is not None
                     and blocks > self.state.max_blocks_per_seq):
                 return None  # near the per-seq cap: per-token tail
@@ -1708,7 +1850,7 @@ class InferenceEngineV2:
         if need_total > self.kv_cache.free_blocks:
             return None
         for s in live:
-            ok = self.state.ensure_capacity(s, s.seen_tokens + K)
+            ok = self.state.ensure_capacity(s, s.seen_tokens + unread + K)
             assert ok, "capacity probe said yes but allocation failed"
         return K
 
@@ -1936,19 +2078,34 @@ class InferenceEngineV2:
         best step for the current mix — speculative decode (drafts
         available), multi-token burst (steady greedy decode), or a plain
         SplitFuse step. Returns {uid: tokens emitted this round}. The
-        open-loop SLO harness (tools/serve_bench.py) drives this."""
+        open-loop SLO harness (tools/serve_bench.py) drives this.
+
+        With every sequence slot taken and nothing queued, a burst step
+        returns the tokens of the call the step before dispatched and
+        leaves the next call running (_burst_step): the caller reads call
+        n's tokens while call n+1 runs. The tokens, their order and each
+        sequence's end are those of an engine that reads every call before
+        it issues the next."""
         with self._open_step():
             with span("admit"):
                 self._admit_from_queue()
             out: Optional[Dict[int, List[int]]] = None
             if temperature == 0.0:
-                out = self._try_spec_step(eos_token_id)
+                if self._inflight is None:
+                    out = self._try_spec_step(eos_token_id)
                 if out is None:
-                    out = self._try_decode_burst(eos_token_id)
+                    out = self._burst_step(eos_token_id)
+            else:
+                self._drain()
             if out is None:
                 emitted = self._splitfuse_step(temperature, seed,
                                                eos_token_id)
                 out = {uid: [tok] for uid, tok in emitted.items()}
+            if self._undelivered:
+                # what a drain read between two steps, before this step's
+                for uid, toks in out.items():
+                    self._undelivered.setdefault(uid, []).extend(toks)
+                out = self.take_undelivered()
             with span("journal"):
                 jr = get_journal()
                 if jr is not None and out and jr.claim_ingress(
@@ -1968,7 +2125,7 @@ class InferenceEngineV2:
         device round trip."""
         results: Dict[int, List[int]] = {}
         for _ in range(max_steps):
-            if not self.state.seqs and not self._queue:
+            if not (self.state.seqs or self._queue or self._undelivered):
                 break
             # every round makes progress: emits tokens, advances a
             # prefill, admits from the queue, or preempts a starved
@@ -1981,8 +2138,10 @@ class InferenceEngineV2:
     def flush(self, uids: List[int]) -> None:
         """Drop sequences + free KV (reference engine_v2.py flush);
         covers queued-but-unadmitted requests too."""
+        self._drain()
         tier = getattr(self.kv_cache, "host_tier", None)
         for uid in uids:
+            self._undelivered.pop(uid, None)
             self.tracer.on_finish(uid, "flushed")
             self._release_seq(uid)
             if tier is not None and tier.has_session(uid):
@@ -1996,9 +2155,12 @@ class InferenceEngineV2:
         tracer's crash-dump context leaves the flight recorder and the
         hub's Prometheus page is written once more. Idempotent. Device
         state (weights, KV pool) goes with the object; the process-wide
-        hub, flight recorder and crash handlers stay for other engines."""
+        hub, flight recorder and crash handlers stay for other engines. A
+        burst in flight is read first (``take_undelivered`` has its
+        tokens)."""
         if self._closed:
             return
+        self._drain()
         self._closed = True
         self.tracer.detach_flight()
         self._hub.write_prometheus()
@@ -2024,7 +2186,9 @@ class InferenceEngineV2:
         """Serving observability snapshot: request-latency percentiles
         (TTFT + per-decode-token, p50/p95/p99), queue/occupancy gauges
         and the kernel/fallback counters. The same histograms render on
-        the hub's Prometheus page (docs/observability.md)."""
+        the hub's Prometheus page (docs/observability.md). A burst in
+        flight is read first, so the counters and the tokens agree."""
+        self._drain()
         live = [s for s in self.state.seqs.values() if not s.done]
         out: Dict[str, Any] = {
             "ttft": self._ttft_hist.snapshot(),

@@ -886,10 +886,14 @@ def ragged_decode_forward(cfg: HybridConfig, params, pools: Dict, token_ids,
 def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
                         token_pos, block_table, context_lens,
                         state_slots=None, window_table=None, *, steps: int,
-                        mesh=None) -> Tuple[jax.Array, Dict]:
+                        mesh=None) -> Tuple[jax.Array, Dict, jax.Array]:
     """``steps`` greedy decode steps in one program, the argmax fed back on
-    the device (``model_runner.ragged_multi_decode``'s contract); the
-    counters sum over the steps. Returns (tokens [steps, S] int32, pools')."""
+    the device (``model_runner.ragged_multi_decode``'s contract, the last
+    row handed out once more for the burst that follows included: a caller
+    with a full batch issues call n+1 from it before it reads call n); the
+    counters sum over the steps and are this call's own, so a caller holds
+    them until it reads the call's tokens. Returns (tokens [steps, S] int32,
+    pools', tokens[steps - 1])."""
     def body(carry, _):
         pools, tok, pos, ctx, counts = carry
         logits, pools = ragged_decode_forward(
@@ -900,7 +904,7 @@ def ragged_multi_decode(cfg: HybridConfig, params, pools: Dict, token_ids,
         counts = counts + pools["counters"]
         return (pools, nxt, pos + 1, jnp.where(alive, ctx + 1, 0), counts), nxt
 
-    (pools, _, _, _, counts), toks = lax.scan(
+    (pools, last, _, _, counts), toks = lax.scan(
         body, (dict(pools, counters=_no_counts()), token_ids, token_pos,
                context_lens, _no_counts()), length=steps)
-    return toks, dict(pools, counters=counts)
+    return toks, dict(pools, counters=counts), last

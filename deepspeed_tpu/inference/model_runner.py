@@ -584,7 +584,7 @@ def ragged_multi_decode(cfg: TransformerConfig, params, kv_data: jax.Array,
                         token_ids: jax.Array, token_pos: jax.Array,
                         block_table: jax.Array, context_lens: jax.Array,
                         *, steps: int, mesh=None
-                        ) -> Tuple[jax.Array, jax.Array]:
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``steps`` greedy decode steps in ONE device program.
 
     The autoregressive loop runs as a ``lax.scan`` over
@@ -602,7 +602,16 @@ def ragged_multi_decode(cfg: TransformerConfig, params, kv_data: jax.Array,
     (context_lens == 0) stay dead, their writes going to the scratch
     page inside :func:`ragged_decode_forward`.
 
-    Returns (tokens [steps, S] int32, kv_data').
+    The last row comes back once more as an array of its own: it is the
+    ``token_ids`` of the burst that follows this one, so a caller whose
+    batch is full can hand the device call n+1 (positions and context
+    lengths plus ``steps``, block tables grown for both) before it has
+    read call n, and reads call n's tokens while n+1 runs
+    (``InferenceEngineV2._burst_step``; docs/serving.md, "A burst in
+    flight"). The two calls run in order on one device stream, so the
+    tokens are those of reading each call before issuing the next.
+
+    Returns (tokens [steps, S] int32, kv_data', tokens[steps - 1]).
     """
     def body(carry, _):
         kv, tok, pos, ctx = carry
@@ -613,6 +622,6 @@ def ragged_multi_decode(cfg: TransformerConfig, params, kv_data: jax.Array,
         nxt = jnp.where(alive, nxt, 0)
         return (kv, nxt, pos + 1, jnp.where(alive, ctx + 1, 0)), nxt
 
-    (kv_data, *_), toks = lax.scan(
+    (kv_data, last, *_), toks = lax.scan(
         body, (kv_data, token_ids, token_pos, context_lens), length=steps)
-    return toks, kv_data
+    return toks, kv_data, last

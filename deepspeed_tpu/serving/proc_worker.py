@@ -242,6 +242,11 @@ class WorkerLoop:
                                      wire=msg.get("wire"))
         except Exception:
             sess = None  # degrade to recompute, never wedge the worker
+        # the capture read the engine's burst in flight first: those
+        # tokens are emissions too, and go before the reply
+        unread = self.replica.engine.take_undelivered()
+        if unread:
+            self._send_emit(unread)
         self.channel.send({"type": "session_payload",
                            "req": msg["req"],
                            "session": encode_session(sess)})

@@ -175,7 +175,7 @@ class ServingReplica:
                 self._apply(sub)
         busy = bool(self.engine.state.seqs) or bool(self.engine._queue)
         emitted = self.engine.serve_step(eos_token_id=eos_token_id) \
-            if busy else {}
+            if busy else self.engine.take_undelivered()
         self.steps += 1
         now = wall_time()
         self.last_heartbeat = now
@@ -265,6 +265,12 @@ class ServingReplica:
             sess = serialize_session(self.engine, mo.uid, wire=mo.wire)
         except Exception:
             sess = None  # degrade, never wedge the pump
+        # the capture read the engine's burst in flight first: its tokens
+        # reach the router before the session changes hands (a later
+        # emission of this replica's would be dropped as stale)
+        unread = self.engine.take_undelivered()
+        if unread and self.emit_callback is not None:
+            self.emit_callback(self, unread)
         mo.cb(sess)
 
     # -- load report ---------------------------------------------------

@@ -49,6 +49,13 @@ os.environ["JAX_PLATFORMS"] = "cpu"  # force: tests never touch the real TPU
 # spawns neither read one run's executables in the next nor fill the
 # tree with CPU entries
 os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+# the suite's programs are toy-sized and most run once, so most of a whole
+# run is the CPU compiler's time and not the programs': JAX's own switch
+# (backend optimization level 0, no expensive LLVM passes) takes a fifth
+# off it, and the workers and rehearsals a test spawns inherit it. What
+# tests/test_tpu_compile.py compiles is read as the chip's compiler leaves
+# it: that module switches it back (its ``_compiled_as_for_the_chip``)
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 
 # flight-recorder dumps (e.g. a deliberately-fired stall watchdog in the
 # engine tests) default to ./dstpu_flight — point them at a temp dir so

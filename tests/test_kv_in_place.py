@@ -262,6 +262,10 @@ def test_pool_after_steps_is_a_plain_scatter_of_the_served_rows(
     assert eng.stats["burst_steps"] == 2 and eng.stats["tokens_decode"] > 0
     assert (eng.stats["tokens_prefill_kernel"] + eng.stats["tokens_gather"]
             >= 4)
+    # the batch is full, so a third burst is in flight and writing rows:
+    # whatever looks at the engine from outside a step reads it first
+    eng.snapshot()
+    assert eng.stats["burst_steps"] == 3 and eng._inflight is None
 
     def dense(state):
         if quant_bits is None:

@@ -179,6 +179,32 @@ class TestWarmMigration:
         assert fleet.stats["migrations"] == 1
         assert fleet.stats["migrate_wire_bytes"] > 0
 
+    def test_a_burst_in_flight_is_read_before_the_session_leaves(self, tiny):
+        """One slot a replica, so a lone session is a full batch and its
+        replica keeps a decode burst in flight between steps: the capture
+        reads it first, those tokens reach the router before the session
+        changes hands, and the stream is the unmigrated one."""
+        gen = 40
+        ref = reference_stream(tiny, PROMPT, gen)
+        fleet = make_fleet(tiny, n=2, max_seqs_per_step=1)
+        fleet.submit(1, PROMPT, max_new_tokens=gen)
+        for _ in range(3):
+            fleet.step()
+        rec = fleet._requests[1]
+        src_rid = rec.replica_id
+        src = fleet.replicas[src_rid].engine
+        assert src._inflight is not None and src.stats["calls_issued_ahead"]
+        had = len(rec.emitted)
+        fleet.remove_replica(src_rid)
+        assert fleet.migrate_sessions(src_rid)["requested"] == 1
+        fleet.step()
+        assert src._inflight is None and not src._undelivered
+        assert len(rec.emitted) >= had + src.decode_steps
+        assert rec.replica_id != src_rid
+        fleet.run_until_complete()
+        assert list(fleet.results()[1]) == ref
+        assert fleet.stats["migrations"] == 1
+
     def test_transport_death_degrades_to_recompute(self, tiny):
         """A capture that never lands (the RPC path hands the callback
         None) folds emitted tokens and recomputes — bit-identical, the

@@ -124,12 +124,23 @@ def test_trace_capture_keeps_the_last_step_span(devices, tmp_path,
 
 # -- serving -----------------------------------------------------------------
 
+_MODELS = {}
+
+
+def _model(name, **kw):
+    """One model a preset for the file: engines over one config object
+    share their compiled step programs (``engine_v2._shared_step_fns``)."""
+    if name not in _MODELS:
+        _MODELS[name] = get_model(name, **kw)
+    return _MODELS[name]
+
+
 def _serve_engine(**kw):
     kw = dict(dict(kv_blocks=64, kv_block_size=8, max_tokens_per_step=32,
                    max_seqs_per_step=4, max_blocks_per_seq=16,
                    dtype=jnp.float32, request_trace={"sample_rate": 1.0}),
               **kw)
-    return InferenceEngineV2(get_model("tiny"), **kw)
+    return InferenceEngineV2(_model("tiny"), **kw)
 
 
 def _prompt(n, seed):
@@ -199,8 +210,8 @@ MIXED_CALLS = [
          token_steps=1, chunks=0)]
 
 
-def _dispatches(spans):
-    """The ``dstpu/dispatch`` spans' ids, in order, and for each the id of
+def _dispatches(spans, keep_step_id=False):
+    """The ``dstpu/dispatch`` spans' ids, in order, each checked against
     the ``serve_step`` span it lies in (which its own ``step_id`` has to
     name)."""
     steps = [s for s in spans if s["name"] == "serve_step"]
@@ -209,7 +220,8 @@ def _dispatches(spans):
         (step,) = [t for t in steps if t["start"] <= d["start"]
                    and d["end"] <= t["end"]]
         assert d["ids"]["step_id"] == step["ids"]["step_id"]
-        out.append({k: v for k, v in d["ids"].items() if k != "step_id"})
+        out.append({k: v for k, v in d["ids"].items()
+                    if keep_step_id or k != "step_id"})
     return out
 
 
@@ -308,6 +320,61 @@ def test_serve_spans_and_request_trace_share_step_ids(devices, tmp_path):
     engine.close()
 
 
+@pytest.mark.parametrize("kind", ["dense", "hybrid"])
+def test_a_call_issued_ahead_says_so_and_names_the_step_that_issued_it(
+        devices, tmp_path, kind):
+    """A full batch (four slots, four requests, 25 tokens each: one from
+    the prompts' step, three bursts of eight): the first burst step issues
+    two calls and returns the first one's tokens, the next issues the third
+    and returns the second's, the last issues none and returns the
+    third's. A call issued while the one before it is unread says
+    ``ahead=1`` and the ``step_id`` of the step that issued it; a
+    request's ``DECODE_EMIT`` spans name the step that returned the
+    tokens."""
+    make = {"dense": _serve_engine, "hybrid": _hybrid_engine}[kind]
+    engine = make(prefix_cache=False, request_trace={"sample_rate": 1.0})
+    n, K = engine.max_seqs, engine.decode_steps
+    uids = list(range(1, n + 1))
+    assert (n, K) == (4, 8)
+
+    def run():
+        engine.put(uids, [_prompt(5, u) for u in uids],
+                   max_new_tokens=1 + 3 * K)
+        return engine.generate_all()
+
+    run()                                         # compile outside
+    first = engine._step_id
+    ahead0 = engine.stats["calls_issued_ahead"]
+    out, spans = capture(tmp_path, run)
+    assert {u: len(t) for u, t in out.items()} == \
+        dict.fromkeys(uids, 1 + 3 * K)
+    assert engine.stats["calls_issued_ahead"] - ahead0 == 2
+    steps = [s for s in spans if s["name"] == "serve_step"]
+    ids = [s["ids"]["step_id"] for s in steps]
+    assert ids == list(range(first + 1, first + 1 + len(steps)))
+    bursts = [d for d in _dispatches(spans, keep_step_id=True)
+              if d["program"] == "multi_decode"]
+    # (the step's id, the call's place in it, ahead): two calls in the
+    # first burst step, one in the next, none in the last
+    b0 = bursts[0]["step_id"]
+    assert [(d["step_id"], d["call"], d.get("ahead", 0)) for d in bursts] \
+        == [(b0, 0, 0), (b0, 1, 1), (b0 + 1, 0, 1)]
+    assert all(d["tokens"] == K * n and d["token_steps"] == K
+               for d in bursts)
+    kids = [[k["name"] for k in inside(step, spans)] for step in steps]
+    by_id = dict(zip(ids, kids))
+    assert by_id[b0].count("dispatch") == 2 == by_id[b0].count("build_batch")
+    assert by_id[b0 + 2].count("dispatch") == 0       # it only reads
+    assert "fetch" in by_id[b0 + 2] and "bookkeep" in by_id[b0 + 2]
+    assert all(set(names) <= SERVE_CHILDREN for names in kids)
+    for uid in uids:
+        trace = [t for t in engine.request_traces() if t.uid == uid][-1]
+        emits = [(s.fields["step_id"], s.fields["n"])
+                 for s in trace.spans if s.kind == "DECODE_EMIT"]
+        assert emits[-3:] == [(b0, K), (b0 + 1, K), (b0 + 2, K)]
+    engine.close()
+
+
 def test_speculative_round_spans(devices, tmp_path):
     engine = _serve_engine(spec_decode=True, spec_k=3)
     prompt = np.tile(np.arange(6, dtype=np.int32), 4)   # lookup finds drafts
@@ -337,16 +404,17 @@ def test_speculative_round_spans(devices, tmp_path):
     engine.close(), plain.close()
 
 
-def _hybrid_engine():
+def _hybrid_engine(**kw):
     from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
 
-    model = get_model("tiny-hybrid", param_dtype=jnp.float32,
-                      dtype=jnp.float32)
+    model = _model("tiny-hybrid", param_dtype=jnp.float32,
+                   dtype=jnp.float32)
+    kw = dict(dict(kv_blocks=64, kv_block_size=16, max_tokens_per_step=32,
+                   max_seqs_per_step=4, max_blocks_per_seq=8, state_slots=4),
+              **kw)
     return InferenceEngineV2(
         model, mesh=build_mesh(TopologyConfig(), devices=jax.devices()[:1]),
-        params=model.init(jax.random.PRNGKey(0)), dtype=jnp.float32,
-        kv_blocks=64, kv_block_size=16, max_tokens_per_step=32,
-        max_seqs_per_step=4, max_blocks_per_seq=8, state_slots=4)
+        params=model.init(jax.random.PRNGKey(0)), dtype=jnp.float32, **kw)
 
 
 @pytest.mark.parametrize("kind", ["dense", "hybrid", "speculative"])

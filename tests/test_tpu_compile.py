@@ -51,6 +51,17 @@ def chip():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _compiled_as_for_the_chip():
+    """tests/conftest.py has the suite's CPU programs compiled with most
+    optimization off; what is compiled here is read (custom calls, copies,
+    bytes) as the compiler leaves it for a chip."""
+    prev = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", prev)
+
+
 @pytest.fixture(autouse=True)
 def _device_kernels(monkeypatch):
     """``jax.default_backend()`` is still the CPU here, so each module's
