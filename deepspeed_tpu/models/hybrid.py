@@ -80,8 +80,8 @@ from deepspeed_tpu.models.transformer import (TransformerConfig, _norm, _rope,
 from deepspeed_tpu.ops import block_sparse
 from deepspeed_tpu.ops.attention import multi_head_attention
 from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, lightning_chunk
-from deepspeed_tpu.parallel.moe import (GateConfig, bias_update,
-                                        moe_ffn_share)
+from deepspeed_tpu.parallel.moe import (ROUTING_NAME, GateConfig,
+                                        bias_update, moe_ffn_share)
 from deepspeed_tpu.runtime.sharding import (effective_dtype,
                                             vocab_parallel_lookup)
 
@@ -1191,7 +1191,9 @@ def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
     router outputs' tokens a layer ``[num_layers, num_experts]`` int32):
     every sequence from an empty state, the recurrence in its chunked form,
     each layer under the checkpoint policy (``remat`` / ``remat_policy``:
-    the engine's ``activation_checkpointing`` where the model names none)."""
+    the engine's ``activation_checkpointing`` where the model names none),
+    which decides about activations; an expert layer's routing integers
+    (``parallel/moe.py::ROUTING_NAME``) cross the checkpoint under any."""
     from deepspeed_tpu.runtime.activation_checkpointing import \
         checkpoint_wrapper
 
@@ -1213,7 +1215,8 @@ def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
         full = cfg.mixer_kinds[l]
         fn = functools.partial(_layer, cfg, l)
         if cfg.remat:
-            fn = checkpoint_wrapper(fn, policy=cfg.remat_policy)
+            fn = checkpoint_wrapper(fn, policy=cfg.remat_policy,
+                                    kept_names=(ROUTING_NAME,))
         x, c, load = fn(x, positions, at(p["layers"], l),
                         at(p[names[full]], seen[full]),
                         at(p["experts"], l - K) if cfg.num_experts and l >= K

@@ -269,23 +269,25 @@ def route_top_k(y: jax.Array, router_w: jax.Array, top_k: int):
 
 
 def route(y: jax.Array, router_w: jax.Array, cfg: GateConfig,
-          bias: Optional[jax.Array] = None):
+          bias: Optional[jax.Array] = None, keep=lambda idx: idx):
     """The router's rule as ``cfg`` states it, in float32 at ``HIGHEST``:
     :func:`route_top_k`, or with ``scoring`` "sigmoid" the scores ``sigmoid(y
     W_r)``, the ``top_k`` of ``score + bias`` chosen (``bias [E]``: the
     correction that balances load takes part in the choice and never in a
     weight), the chosen *scores* renormalised to sum to one and multiplied by
-    ``routed_scale``. Returns (weights [T, k] float32, experts [T, k])."""
+    ``routed_scale``. Returns (weights [T, k] float32, experts [T, k]). The
+    sigmoid rule reads its weights at ``keep(experts)``: where ``keep`` names
+    the choice for a checkpoint, the backward pass does not choose again."""
     if cfg.scoring == "softmax":
         return route_top_k(y, router_w, cfg.top_k)
     score = jax.nn.sigmoid(jnp.einsum(
         "th,he->te", y.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
     choose = score if bias is None else score + bias.astype(jnp.float32)
-    _, idx = lax.top_k(choose, cfg.top_k)
+    idx = keep(lax.top_k(choose, cfg.top_k)[1].astype(jnp.int32))
     top = jnp.take_along_axis(score, idx, axis=-1)
     w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
-    return w * cfg.routed_scale, idx.astype(jnp.int32)
+    return w * cfg.routed_scale, idx
 
 
 def bias_update(load: jax.Array, rate: float) -> jax.Array:
@@ -314,49 +316,46 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
 
     The router runs over all ``cfg.num_experts`` outputs by ``cfg``'s rule
     (:func:`route`; ``router_bias`` is the sigmoid rule's per-expert
-    correction); the experts held here are
-    ``offset .. offset + E_held`` (``expert_params`` leaves ``[E_held, ...]``,
-    or ``[L, E_held, ...]`` with ``layer``). The result is what *these*
-    experts add for the tokens routed to them, plus — given ``shared``
-    (``wg``, ``wi``, ``wo`` and, for a model that has one, the gate vector
-    ``gate``) — the shared expert, behind its sigmoid gate or added as it is,
-    which every chip computes alike. A token
+    correction); the experts held here are ``offset .. offset + E_held``
+    (``expert_params`` leaves ``[E_held, ...]``, or ``[L, E_held, ...]`` with
+    ``layer``). The result is what *these* experts add for the tokens routed
+    to them, plus — given ``shared`` (``wg``, ``wi``, ``wo`` and, for a model
+    that has one, the gate vector ``gate``) — the shared expert, behind its
+    sigmoid gate or added as it is, which every chip computes alike. A token
     none of whose experts live here gets the shared expert alone. Nothing
     stands in for the absent chips: on one chip there is no exchange; on a
     mesh with an ``ep`` axis the same layer is :func:`moe_ffn_dropless`,
     whose shards each hold ``E / ep`` and exchange rows.
 
     y [T, H] (already normed); ``valid [T]`` marks real tokens (padding is
-    routed nowhere). Returns (out [T, H] in y's type, counts): ``pairs``,
-    the (token, expert) pairs routed here, ``experts_hit``, the held
-    experts that got a row, and — int32 scalars all — with ``layer`` (the
-    serving programs' counters) ``work_items``, the (row tile, expert) pairs
-    each of the three grouped products multiplies, without it (the training
-    step's) ``max_rows``, the fullest held expert's rows, ``dropped``, and
-    ``load [num_experts]``, the tokens that chose each of the router's
-    outputs.
+    routed nowhere). Returns (out [T, H] in y's type, counts): ``pairs``, the
+    (token, expert) pairs routed here, ``experts_hit``, the held experts that
+    got a row, and — int32 scalars all — with ``layer`` (the serving
+    programs' counters) ``work_items``, the (row tile, expert) pairs each of
+    the three grouped products multiplies, without it (the training step's)
+    ``max_rows``, the fullest held expert's rows, ``dropped``, and ``load
+    [num_experts]``, the tokens that chose each of the router's outputs.
 
-    Rows sort by local expert; pairs routed elsewhere sort last and lie
-    beyond the groups' sum, where the grouped product yields zeros (and its
-    backward zero row gradients, and nothing of them in the experts').
-
-    Without ``layer`` the experts are one layer's leaves and the products
-    differentiate (``gm.gmm``): the training path. The rows of all ``T *
-    top_k`` pairs are mostly rows routed elsewhere (seven eighths at a share
-    of an eighth), so that path may bound the buffer: ``capacity`` (a
-    multiple of 128) keeps the first rows of the sorted order, which are the
-    local ones; local pairs beyond it are ``dropped`` (counted, adding
-    nothing): a static row budget as :func:`_ep_capacity` is for the
+    Rows sort by local expert; pairs routed elsewhere sort last and lie beyond
+    the groups' sum, where the grouped product yields zeros (and its backward
+    zero row gradients, and nothing of them in the experts'). Without ``layer``
+    the experts are one layer's leaves and the products differentiate
+    (``gm.gmm``): the training path. Most of the ``T * top_k`` rows are routed
+    elsewhere (seven eighths at a share of an eighth), so it may bound the
+    buffer: ``capacity`` (a multiple of 128) keeps the first rows of the sorted
+    order, the local ones; local pairs beyond it are ``dropped`` (counted,
+    adding nothing): a static row budget as :func:`_ep_capacity` is for the
     exchange. ``router_bias`` takes part in the choice alone and gets no
-    gradient.
-    """
+    gradient. That path names its routing's integers (:func:`_keep_routing`): a
+    checkpoint told of ``ROUTING_NAME`` keeps them, and sorts once."""
     T, H = y.shape
     held = expert_params["wi"].shape[-3]
     k = cfg.top_k
     if router_bias is not None:
         router_bias = lax.stop_gradient(router_bias)
     with jax.named_scope("moe_route"):
-        w, idx = route(y, router_w, cfg, router_bias)
+        keep = _keep_routing if layer is None else (lambda ints: ints)
+        w, idx = route(y, router_w, cfg, router_bias, keep)
         here = (idx >= offset) & (idx < offset + held)
         if valid is not None:
             here = here & valid[:, None]
@@ -382,9 +381,10 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
             m, order, row_token = capacity, order[:capacity], \
                 row_token[:capacity]
             dropped = jnp.maximum(jnp.sum(group_sizes) - m, 0)
-        # one work list for the three products: they share the row tile
-        # (the tile the products will use: the largest that divides m);
-        # the differentiable product makes its own
+        order, row_token, group_sizes = map(
+            keep, (order, row_token, group_sizes))
+        # one work list for the three products, which share the row tile (the
+        # largest that divides m); the differentiable product makes its own
         work = None if layer is None else gm.make_group_metadata(
             group_sizes, m, gm.choose_tiles(m, H, H, held, y.dtype)[0])
     with jax.named_scope("moe_experts"):
@@ -753,3 +753,29 @@ def moe_ffn_dropless(x: jax.Array, router_w: jax.Array,
     stats = jax.tree.map(lambda s: jnp.mean(s, axis=0), stats_sh)
     out = constrain_activation(out, ("batch", "seq", "embed"))
     return out, _aux_from_stats(stats, cfg)
+
+
+# The name under which the training path of :func:`moe_ffn_share` tags its
+# routing's integers for ``jax.checkpoint``. A model that wraps an expert
+# layer hands it to ``checkpoint_wrapper(..., kept_names=(ROUTING_NAME,))``
+# (``models/hybrid.py::hidden_states``), and the layer's backward pass gets
+# the integers back instead of running ``lax.top_k``, the stable sort, the
+# gather of the sorted rows' tokens and the bincount a second time: 9.5 ms of
+# a 571 ms step of `train-trinity-ep8share-8k` (PERF.md section 6, PR 47).
+ROUTING_NAME = "moe_routing"
+
+
+def _keep_routing(ints: jax.Array) -> jax.Array:
+    """Tag one of an expert layer's routing results as worth keeping across
+    a checkpoint under every policy: the experts chosen ``idx [T, top_k]``
+    and, after the cut to ``capacity``, the sorted order's ``order`` and
+    ``row_token [M]`` and the held experts' ``group_sizes``; int32 all, 4
+    bytes a pair and 8 a row of the buffer (524 KB + 2 x 98 KB + 64 B a layer
+    at 16,384 tokens, top-8 and 24,576 rows). No gradient flows through an
+    integer, so the kept ones are the ones a second run would make; the
+    scores, the weights and every product stay the policy's to decide. An
+    identity where nothing wraps the layer. (Down here so that no line above
+    a grouped product's call site moves: ROADMAP.md S10.)"""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(ints, ROUTING_NAME)

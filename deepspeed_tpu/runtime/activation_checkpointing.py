@@ -28,7 +28,7 @@ TPU mapping (each reference knob → an XLA-native mechanism):
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -137,14 +137,39 @@ def _partition_constraint(x, mesh):
         x, NamedSharding(mesh, P(*spec)))
 
 
+def _and_these_names(policy, names: Tuple[str, ...]):
+    """``policy`` (None: save nothing) and, saved on the device, the values
+    named ``names``. (``save_from_both_policies`` takes two policies that
+    answer True or False; an offloading policy answers with a place.)"""
+    named = jax.checkpoint_policies.save_only_these_names(*names)
+    if policy is None:
+        return named
+
+    def either(prim, *args, **params):
+        return named(prim, *args, **params) or policy(prim, *args, **params)
+
+    return either
+
+
 def checkpoint_wrapper(function: Callable,
                        policy: Optional[str] = None,
                        partition_activations: Optional[bool] = None,
-                       cpu_checkpointing: bool = False) -> Callable:
-    """Wrap ``function`` with the configured remat behavior."""
+                       cpu_checkpointing: bool = False,
+                       kept_names: Tuple[str, ...] = ()) -> Callable:
+    """Wrap ``function`` with the configured remat behavior.
+
+    ``kept_names`` are ``checkpoint_name`` tags the *model* keeps across
+    the checkpoint under every policy, on the device, beside whatever the
+    policy saves: results that carry no gradient and cost more to make
+    again than to hold (an expert layer's routing integers,
+    ``parallel/moe.py::ROUTING_NAME``: kilobytes against a sort). The
+    policy keeps deciding about activations; the empty tuple wraps as
+    before."""
     resolved = resolve_policy(policy, cpu_checkpointing)
     part = (_GLOBAL_CONFIG.get("partition_activations", False)
             if partition_activations is None else partition_activations)
+    if kept_names and resolved != "everything":
+        resolved = _and_these_names(resolved, kept_names)
 
     if resolved == "everything":
         inner = function

@@ -587,3 +587,139 @@ def test_moe_model_trains_through_grouped_path():
     # the tiny preset is large enough that nothing drops at S=64)
     np.testing.assert_allclose(cfgs["grouped"][0], cfgs["einsum"][0],
                                rtol=5e-3)
+
+
+# -- an expert layer under a checkpoint routes once ---------------------------
+
+def _primitives(jaxpr, names=("sort", "top_k")):
+    """How often each of ``names`` stands in a jaxpr and in every jaxpr
+    nested in it."""
+    found = dict.fromkeys(names, 0)
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in found:
+            found[eqn.primitive.name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            for name, n in _primitives(sub, names).items():
+                found[name] += n
+    return found
+
+
+def _share_layer(capacity):
+    """A sigmoid-routed share (4 of 16 experts, top-2, 128 tokens: 256
+    pairs, some 64 of them local) as a function of what a model's layer is
+    handed, and its arguments."""
+    from deepspeed_tpu.parallel.moe import moe_ffn_share
+
+    T, H, F, E, held = 128, 64, 32, 16, 4
+    cfg = GateConfig(num_experts=E, top_k=2, drop_tokens=False,
+                     scoring="sigmoid", routed_scale=2.5)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    args = (jax.random.normal(ks[0], (T, H)),
+            jax.random.normal(ks[1], (H, E)) * 0.3,
+            {"wg": jax.random.normal(ks[2], (held, H, F)) * 0.1,
+             "wi": jax.random.normal(ks[3], (held, H, F)) * 0.1,
+             "wo": jax.random.normal(ks[4], (held, F, H)) * 0.1},
+            jax.random.normal(ks[5], (E,)) * 0.1)
+
+    def layer(y, router_w, experts, bias, valid):
+        out, counts = moe_ffn_share(y, router_w, experts, cfg, offset=4,
+                                    valid=valid, router_bias=bias,
+                                    capacity=capacity)
+        return out, counts["dropped"]
+
+    return layer, args
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "valid"])
+@pytest.mark.parametrize("capacity", [None, 128], ids=["every_pair", "cut"])
+def test_a_checkpointed_share_keeps_its_routing_and_nothing_else(
+        capacity, masked):
+    """Under ``nothing_saveable`` and the layer's own name, the backward pass
+    of a share holds no second ``top_k`` and no second sort; what crosses
+    the checkpoint besides the layer's arguments is the four named integer
+    arrays (``idx``, and ``order``, ``row_token`` after the cut to
+    ``capacity``, ``group_sizes``) and no floating-point value; the
+    gradients are those of the same layer under a plain ``jax.checkpoint``,
+    to the bit."""
+    # (what ``jax.ad_checkpoint.print_saved_residuals`` prints, as a list)
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from deepspeed_tpu.parallel.moe import ROUTING_NAME
+    from deepspeed_tpu.runtime.activation_checkpointing import \
+        checkpoint_wrapper
+
+    layer, args = _share_layer(capacity)
+    valid = jnp.arange(128) < 100 if masked else None
+    kept = checkpoint_wrapper(layer, policy="nothing_saveable",
+                              kept_names=(ROUTING_NAME,))
+    plain = jax.checkpoint(layer)
+
+    def loss(fn):
+        def f(y, router_w, experts, bias):
+            out, dropped = fn(y, router_w, experts, bias, valid)
+            return jnp.sum(out * out), dropped
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    assert _primitives(jax.make_jaxpr(loss(kept))(*args).jaxpr) == {
+        "sort": 1, "top_k": 1}
+    assert _primitives(jax.make_jaxpr(loss(plain))(*args).jaxpr) == {
+        "sort": 2, "top_k": 2}
+
+    named, rows = [], capacity or 256
+    for aval, why in saved_residuals(kept, *args, valid):
+        if f"named '{ROUTING_NAME}'" in why:
+            named.append((aval.shape, aval.dtype))
+        else:   # an argument, or an integer a jitted function passed on
+            assert why.startswith("from the argument") \
+                or aval.dtype == jnp.int32, (aval, why)
+    assert sorted(named) == sorted(
+        (shape, jnp.int32) for shape in [(128, 2), (rows,), (rows,), (4,)])
+
+    (got, dropped), (want, _) = jax.jit(loss(kept))(*args), \
+        jax.jit(loss(plain))(*args)
+    assert int(dropped) == 0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.any(np.asarray(w)) and np.array_equal(g, w)
+
+
+@pytest.mark.parametrize("cut", [False, True], ids=["every_pair", "cut"])
+def test_the_backward_pass_of_the_stack_routes_nothing_again(cut, monkeypatch):
+    """Every layer runs under the job's ``nothing_saveable``, and an expert
+    layer's routing integers cross its checkpoint all the same
+    (``parallel/moe.py::ROUTING_NAME``): the gradient's program holds one
+    ``top_k`` and one sort an expert layer where a plain ``jax.checkpoint``
+    of the same layers holds two, with or without a row buffer that cuts the
+    sorted order, and the gradients are the same to the bit."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.zoo import get_model
+    from deepspeed_tpu.runtime import activation_checkpointing as ac
+
+    if cut:     # 256 tokens, top-2, a quarter of the experts: some 128 rows
+        monkeypatch.setattr(hybrid, "share_capacity", lambda cfg, tokens: 384)
+    m = get_model("tiny-trinity", dtype="float32", num_layers=3, remat=True,
+                  remat_policy="nothing_saveable")
+    c = m.config
+    assert [c.is_dense(l) for l in range(3)] == [True, False, False]
+    p = m.init(jax.random.PRNGKey(0))
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 128)))
+
+    def grad():     # a new function a call: nothing traced is found again
+        def loss(p):
+            x, counts, _ = hybrid.hidden_states(c, p, ids)
+            return jnp.sum(x * x), counts["moe_dropped_pairs"]
+        return jax.grad(loss, has_aux=True)
+
+    assert _primitives(jax.make_jaxpr(grad())(p).jaxpr) == {
+        "sort": 2, "top_k": 2}
+    got, dropped = jax.jit(grad())(p)
+    assert int(dropped) == 0
+    monkeypatch.setattr(ac, "checkpoint_wrapper",
+                        lambda fn, **kw: jax.checkpoint(fn))
+    assert _primitives(jax.make_jaxpr(grad())(p).jaxpr) == {
+        "sort": 4, "top_k": 4}
+    want, _ = jax.jit(grad())(p)
+    moved = 0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(g, w)
+        moved += bool(np.any(np.asarray(w)))
+    assert moved > 10
