@@ -29,15 +29,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deepspeed_tpu.inference import model_runner
 from deepspeed_tpu.inference.ragged import (
-    BlockedKVCache, KVCacheConfig, PrefixCache, RaggedBatch, StateManager)
+    BlockedAllocator, BlockedKVCache, PrefixCache, StateManager)
 from deepspeed_tpu.inference.ragged.ragged_batch import build_ragged_batch
-from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
 from deepspeed_tpu.inference.scheduler import SplitFuseScheduler
 from deepspeed_tpu.inference.spec_decode import PromptLookupDrafter
 from deepspeed_tpu.models.transformer import TransformerLM
 from deepspeed_tpu.observability.clocksync import wall_time
 from deepspeed_tpu.observability.journal import get_journal
-from deepspeed_tpu.parallel import topology as topo
 from deepspeed_tpu.utils.annotate import named, span
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -88,17 +86,27 @@ class _BurstInFlight:
 _JIT_CACHE: Dict[Any, Tuple[Any, Dict[str, Any]]] = {}
 
 
+def runner_for(cfg):
+    """The runner of a configuration: the module that has its four step
+    programs and says what they need of the engine (``store_specs``,
+    ``serving_params``, ``COUNTERS`` ..., the same names in each). A hybrid
+    stack (models/hybrid.py: recurrent layers, block-sparse or latent
+    attention) has its own (inference/hybrid_runner.py)."""
+    from deepspeed_tpu.models.hybrid import HybridConfig
+
+    if isinstance(cfg, HybridConfig):
+        from deepspeed_tpu.inference import hybrid_runner
+
+        return hybrid_runner
+    return model_runner
+
+
 def _shared_step_fns(cfg, kernel_mesh):
     key = (id(cfg), kernel_mesh)
     hit = _JIT_CACHE.get(key)
     if hit is not None and hit[0] is cfg:
         return hit[1]
-    # a hybrid stack (models/hybrid.py: recurrent layers, block-sparse or
-    # latent attention) has its own four programs under the same names and
-    # leading arguments (inference/hybrid_runner.py)
-    runner = model_runner
-    if hasattr(cfg, "stack_plan"):
-        from deepspeed_tpu.inference import hybrid_runner as runner
+    runner = runner_for(cfg)
     # each program under a stable name: the device trace's module line
     # says jit_dstpu_serve_gather, ... ("step" is the gather program).
     # Every program donates the KV pool (argument 1): the pool is one
@@ -117,8 +125,6 @@ def _shared_step_fns(cfg, kernel_mesh):
         "multi_decode": program(
             partial(runner.ragged_multi_decode, cfg, mesh=kernel_mesh),
             "dstpu_serve_multi_decode", static_argnames=("steps",)),
-        # no program: the rows the gather program computes for a call
-        "gather_rows": runner.gather_rows_computed,
     }
     _JIT_CACHE[key] = (cfg, fns)
     return fns
@@ -211,120 +217,53 @@ class InferenceEngineV2:
                                    quantize_weights=quantize_weights)
         self.model, self.cfg = model, model.config
         self.mesh, self.params = self._v1.mesh, self._v1.params
-        # a model with recurrent layers (models/hybrid.py) keeps a second,
-        # slot-addressed kind of per-sequence state beside the KV blocks
-        # (ragged/state_pool.py). It has no snapshot yet, so whatever would
-        # copy or roll back a sequence's state is switched off (the prefix
-        # cache: a skipped prefix without the state at its end is a wrong
-        # answer) or refused by name (host tier, migration, hand-off,
-        # speculation): _refuse_without_snapshot
-        self._recurrent = bool(getattr(self.cfg, "recurrent_layers", 0))
-        # any stack of models/hybrid.py: its programs take the pools as one
-        # dict with their counters, and its parameters as the serving tree
-        self._hybrid = hasattr(self.cfg, "stack_plan")
+        # what the model keeps per sequence and what its programs take is
+        # the runner's to say; the cache holds it (ragged/store.py)
+        self._runner = runner_for(self.cfg)
         # ``donate_params``: the caller gives the stacked tree up, and the
         # engine deletes each stacked leaf it cuts (hybrid.serving_params):
         # the arrays handed in, here and to every later reload_params, are
         # gone afterwards
         self._donate_params = bool(donate_params)
-        # multi-head latent attention: the paged pool is a latent pool
-        # (ragged/kv_cache.py, kind "latent": one vector a token), no step
-        # runs the gather program, and what would read a K/V page by its
-        # heads refuses by name (_refuse_for_latent_pool)
-        self._latent = bool(getattr(self.cfg, "latent_dim", 0))
-        # windowed latent layers keep a pool of their own, a ring of pages a
-        # sequence (ragged/kv_cache.py, WindowedLatentPool): what would copy,
-        # share or restore a sequence's pages without knowing the ring
-        # refuses by name (_refuse_for_windowed_pool), and the prefix cache
-        # is off (a skipped prefix would leave the ring without its rows)
-        self._windowed = bool(getattr(self.cfg, "window_latent_dim", 0))
         # smallest chunk bucket of the prefill program (powers of two from
         # here: _plan_prefill_segments)
-        self._min_segment = 8
-        if self._latent and (spec_decode or drafter is not None):
-            self._refuse_for_latent_pool(
-                "speculative decoding (spec_decode / drafter), which "
-                "verifies its drafts through the gather program,")
-        if self._windowed:
-            if host_kv_tier:
-                self._refuse_for_windowed_pool(
-                    "the host KV tier (host_kv_tier)")
-            if prefix_cache:
-                log_dist(
-                    "InferenceEngineV2: the model has windowed latent "
-                    "layers, whose pages are a ring a sequence writes over: "
-                    "prefix cache OFF (no hit is taken); host-tier parking, "
-                    "session migration, the disagg hand-off and speculative "
-                    "decoding are refused (WindowedPoolUnsupported)",
-                    ranks=[0])
-            prefix_cache = False
-        if self._recurrent:
-            self._init_recurrent(kv_quant_bits, host_kv_tier,
-                                 spec_decode or drafter is not None)
-            prefix_cache = False
-        elif self._hybrid:
-            self._keep_serving_params()
+        self._min_segment = self._runner.min_segment(self.cfg)
+        # whether a step may use the gather program (_split_by_program)
+        self._has_gather = self._runner.has_gather(self.cfg)
         # kept for reload_params: a hot-swap routes replacement weights
         # through the same v1 placement/quantization path as boot
         self._param_dtype = dtype
         self._quantize_weights = quantize_weights
 
-        # a model whose attention chooses the pages it reads
-        # (ops/block_sparse.py): one page is one block of the rule, its
-        # compressed keys live beside the pool (ragged/kv_cache.py), and no
-        # step of it runs the gather program (_split_by_program)
-        sparse = getattr(self.cfg, "sparse", None)
-        self._sparse = sparse is not None
-        self._no_gather = self._sparse or self._latent
-        if self._sparse and kv_block_size != sparse.block:
-            raise ValueError(
-                f"kv_block_size={kv_block_size}: a model with block-sparse "
-                f"attention needs pages of its block size, {sparse.block}")
-        kv_cfg = KVCacheConfig(
-            num_layers=getattr(self.cfg, "kv_layers", self.cfg.num_layers),
-            kv_heads=self.cfg.kv_heads,
-            head_dim=self.cfg.head_dim, block_size=kv_block_size,
-            num_blocks=kv_blocks, dtype=dtype, quant_bits=kv_quant_bits,
-            compressed_per_block=sparse.per_block if self._sparse else 0,
-            kind="latent" if self._latent else "kv",
-            latent_dim=getattr(self.cfg, "latent_dim", 0),
-            index_key_dim=getattr(self.cfg, "index_key_dim", 0))
-        self.kv_cache = BlockedKVCache(kv_cfg, mesh=self.mesh)
-        if self._hybrid and not self._recurrent:
-            self.kv_cache.pools_as_dict = True     # no state pool says so
-        if self._windowed:
-            from deepspeed_tpu.inference.ragged import (WindowedLatentPool,
-                                                        WindowPoolConfig)
-
-            # every sequence of a step its whole ring, and the scratch page
-            self.kv_cache.window_pool = WindowedLatentPool(
-                WindowPoolConfig.for_sequences(
-                    max_seqs_per_step, layers=self.cfg.window_layers,
-                    window=self.cfg.sliding_window,
-                    row_dim=self.cfg.window_latent_dim,
-                    block_size=kv_block_size, dtype=dtype))
+        paged, beside = self._runner.store_specs(
+            self.cfg, kv_blocks=kv_blocks, kv_block_size=kv_block_size,
+            max_seqs=max_seqs_per_step, state_slots=state_slots,
+            dtype=dtype, quant_bits=kv_quant_bits)
+        # the tree is cut before the pools are made: a chip that the cut
+        # tree and the pools nearly fill cannot hold the stacked one too
+        self._keep_serving_params()
+        self.kv_cache = BlockedKVCache(
+            paged, mesh=self.mesh, stores=[spec.build() for spec in beside])
+        if spec_decode or drafter is not None:
+            self.kv_cache.require(
+                "speculation", "speculative decoding (spec_decode / drafter)")
+        if host_kv_tier:
+            self.kv_cache.require("host_tier",
+                                  "the host KV tier (host_kv_tier)")
+        if prefix_cache and not self.kv_cache.supports("prefix_cache"):
+            log_dist(
+                "InferenceEngineV2: a store of this model has no snapshot a "
+                "skipped prefix could start from: prefix cache OFF (no hit "
+                "is taken)", ranks=[0])
+            prefix_cache = False
         # disagg handoff wire codec mode ("auto"/"raw"/"int8"/"int4");
         # consumed by serving/disagg.py serialize_prefix
         self._handoff_wire = handoff_wire
         # the last block is the padding-token scratch target
         # (model_runner.ragged_forward routes padded writes there): shrink
         # the allocator so it is never handed out
-        from deepspeed_tpu.inference.ragged import BlockedAllocator
-
         self.kv_cache.allocator = BlockedAllocator(kv_blocks - 1)
         self._scratch_block = kv_blocks - 1
-        if self._recurrent:
-            from deepspeed_tpu.inference.ragged import (RecurrentStatePool,
-                                                        StatePoolConfig)
-
-            c = self.cfg
-            self.kv_cache.state_pool = RecurrentStatePool(StatePoolConfig(
-                layers=c.recurrent_layers,
-                slots=int(state_slots or max_seqs_per_step),
-                heads=c.linear_num_value_heads, key_dim=c.linear_key_head_dim,
-                value_dim=c.linear_value_head_dim,
-                conv_taps=c.conv_taps,
-                conv_channels=c.conv_channels, dtype=dtype))
         # shared-prefix KV reuse: full blocks whose content-hash chain
         # matches a cached prefix are shared by reference and skip
         # prefill (ragged/prefix_cache.py; docs/serving.md)
@@ -424,28 +363,15 @@ class InferenceEngineV2:
             # the layers' weights they made one after another
             self.stats.update({f"{k}_{program}": 0 for k in (
                 "calls", "rows", "padded_rows", "token_steps")})
-        if self._hybrid:
-            # the expert layers' routing, counted on the device by every
-            # step program and fetched with the step's tokens: tokens x
-            # expert layers, (token, expert) pairs routed to experts held
-            # here, held experts that got a row, work items of a grouped
-            # product that did a product (summed over calls; the
-            # ``_decode`` three count the two decode programs alone); and
-            # the state pool's occupancy; the sparse rule's choices likewise
-            # ((query, KV head) pairs' chosen and visible blocks over the
-            # queries past dense_len, and the queries below it: the
-            # programs' vector, state_pool.COUNTERS) and the compressed
-            # keys' occupancy (window slots of the held pages); latent
-            # attention's decode kernel likewise (context tokens asked for,
-            # pages fetched)
-            pool = self.kv_cache.state_pool
-            self.stats.update(dict.fromkeys(COUNTERS, 0),
-                              moe_local_pairs_decode=0,
-                              moe_experts_hit_decode=0,
-                              moe_work_items_decode=0, state_slots_in_use=0,
-                              state_slots=pool.total_slots if pool else 0,
-                              compressed_keys_in_use=0,
-                              window_pages_in_use=0)
+        # what the runner's programs count on the device (their ``counters``
+        # vector, fetched with the step's tokens and summed over calls:
+        # hybrid_runner.COUNTERS), those of them kept for the two decode
+        # programs alone (``<name>_decode``), and the stores' occupancy
+        self._counters = self._runner.COUNTERS
+        self.stats.update(
+            dict.fromkeys(self._counters + self._runner.OCCUPANCY, 0),
+            **{name + "_decode": 0 for name in self._runner.DECODE_COUNTERS})
+        self.stats.update(self.kv_cache.occupancy())
         # engine steps so far: the ``step_id`` of each ``dstpu/serve_step``
         # span, of the spans nested in it, and of the request tracer's
         # PREFILL / DECODE_EMIT spans of that step
@@ -549,8 +475,8 @@ class InferenceEngineV2:
         self._prefill_fn = _fns["prefill"]
         # the rows a call of the gather program computes: the flat budget,
         # or what the runner lays it out as inside the program
-        self._gather_rows = int(_fns["gather_rows"](self.max_seqs,
-                                                    self.max_tokens))
+        self._gather_rows = int(self._runner.gather_rows_computed(
+            self.max_seqs, self.max_tokens))
         # device-side token pick: the step fetches only sampled ids (or
         # the consumed rows when temperature > 0), never the full [T, V]
         # logits buffer (see step())
@@ -589,15 +515,11 @@ class InferenceEngineV2:
         over-admitting past the slots would silently degrade them to
         per-token steps for zero scheduling benefit."""
         blocks = self.kv_cache.blocks_needed(prompt_len + 1)
-        pool = self.kv_cache.state_pool
-        wpool = self.kv_cache.window_pool
         if (blocks > self.max_blocks_per_seq
                 or len(self.state.seqs) >= self.max_seqs
                 or len(self.state.seqs)
                 >= self.state.max_tracked_sequences
-                or (pool is not None and pool.free_slots == 0)
-                or (wpool is not None and wpool.free_blocks
-                    < wpool.config.ring_pages)):
+                or not self.kv_cache.admissible()):
             return False
         committed = 0
         for s in self.state.seqs.values():
@@ -730,10 +652,7 @@ class InferenceEngineV2:
         ``reason`` tags the preemption (today only pool_exhausted; the
         disaggregated-router follow-ups add more) on the counter, the
         stats dict, and the victim's trace."""
-        tokens = np.concatenate(
-            [np.asarray(seq.input_tokens, np.int32),
-             np.asarray(seq.generated, np.int32)])
-        if (self.kv_cache.blocks_needed(len(tokens) + 1)
+        if (self.kv_cache.blocks_needed(seq.total_tokens + 1)
                 > self.max_blocks_per_seq):
             # grown to the per-seq block cap: readmission could never
             # fit, so end it (the pre-existing cap-truncation contract)
@@ -754,16 +673,25 @@ class InferenceEngineV2:
                         generated=len(seq.generated),
                         free_blocks=self.kv_cache.free_blocks,
                         queue_depth=len(self._queue))
+        self._to_queue_front(seq, reason)
+
+    def _to_queue_front(self, seq, reason: str, paged: bool = False) -> None:
+        """Release a preempted ``seq`` and queue it at the FRONT, its history
+        folded into the prompt (what readmission recomputes; the fallback of
+        a ``paged`` one whose session the tier spills before then)."""
+        tokens = np.concatenate(
+            [np.asarray(seq.input_tokens, np.int32),
+             np.asarray(seq.generated, np.int32)])
         prior = seq.prior_generated + len(seq.generated)
         admit = self._release_seq(seq.uid, requeue=True)
         self._queue.appendleft(_QueuedRequest(
             uid=seq.uid, tokens=tokens, max_new_tokens=seq.max_new_tokens,
             enqueue_time=time.perf_counter(), prior_generated=prior,
-            admit_time=admit, requeued=True))
+            admit_time=admit, requeued=True, paged=paged))
         self.stats["preempted"] += 1
         self.stats["preempt_reasons"][reason] = \
             self.stats["preempt_reasons"].get(reason, 0) + 1
-        self.stats["requeued"] += 1
+        self.stats["paged_out" if paged else "requeued"] += 1
         self._hub.counter_add("serve.preempted", labels=self._metric_labels)
         self._hub.counter_add(f"serve.preempted_reason.{reason}",
                               labels=self._metric_labels)
@@ -817,27 +745,7 @@ class InferenceEngineV2:
                         n_blocks=int(keep),
                         free_blocks=self.kv_cache.free_blocks,
                         queue_depth=len(self._queue))
-        # folded history rides in the queued request as the fallback:
-        # if the tier spills the session before readmission, admission
-        # degrades to the ordinary prefix-recompute path
-        tokens = np.concatenate(
-            [np.asarray(seq.input_tokens, np.int32),
-             np.asarray(seq.generated, np.int32)])
-        prior = seq.prior_generated + len(seq.generated)
-        admit = self._release_seq(seq.uid, requeue=True)
-        self._queue.appendleft(_QueuedRequest(
-            uid=seq.uid, tokens=tokens, max_new_tokens=seq.max_new_tokens,
-            enqueue_time=time.perf_counter(), prior_generated=prior,
-            admit_time=admit, requeued=True, paged=True))
-        self.stats["preempted"] += 1
-        self.stats["preempt_reasons"][reason] = \
-            self.stats["preempt_reasons"].get(reason, 0) + 1
-        self.stats["paged_out"] += 1
-        self._hub.counter_add("serve.preempted", labels=self._metric_labels)
-        self._hub.counter_add(f"serve.preempted_reason.{reason}",
-                              labels=self._metric_labels)
-        self._hub.gauge("serve.queue_wait_depth", len(self._queue),
-                        labels=self._metric_labels)
+        self._to_queue_front(seq, reason, paged=True)
         return True
 
     def _try_page_in(self, req: _QueuedRequest, now: float) -> str:
@@ -855,18 +763,7 @@ class InferenceEngineV2:
             self.kv_cache.reclaim(keep - self.kv_cache.free_blocks)
         if keep > self.kv_cache.free_blocks:
             return "stall"
-        sess = tier.pop_session(req.uid)
-        seq = self.state.get_or_create(sess.uid, sess.input_tokens,
-                                       sess.max_new_tokens)
-        seq.generated = list(sess.generated)
-        seq.prior_generated = sess.prior_generated
-        seq.seen_tokens = sess.seen_tokens
-        blocks = self.kv_cache.allocator.allocate(keep)
-        seq.kv_blocks = np.asarray(blocks, np.int64)
-        self.kv_cache.write_blocks(blocks, sess.payload, sess.scales)
-        seq.resumed_from_tier = keep
-        if sess.spec_accept_ewma is not None:
-            self._seq_accept_ewma[sess.uid] = float(sess.spec_accept_ewma)
+        self._restore(tier.pop_session(req.uid), keep)
         self.stats["paged_in"] += 1
         self.stats["admitted"] += 1
         self.stats["warm_resume_tokens"] += sess.seen_tokens
@@ -882,13 +779,30 @@ class InferenceEngineV2:
         self.stats["admission_wait_s"] += now - req.enqueue_time
         return "resumed"
 
+    def _restore(self, sess, n: int) -> None:
+        """A parked or migrated session as a live sequence again: its ``n``
+        blocks (pool-native) written into blocks of this pool, its
+        descriptor as it stood. The caller has seen to the room."""
+        seq = self.state.get_or_create(
+            int(sess.uid), np.asarray(sess.input_tokens, np.int32),
+            sess.max_new_tokens)
+        seq.generated = list(sess.generated)
+        seq.prior_generated = int(sess.prior_generated)
+        seq.seen_tokens = int(sess.seen_tokens)
+        blocks = self.kv_cache.allocator.allocate(n)
+        seq.kv_blocks = np.asarray(blocks, np.int64)
+        self.kv_cache.write_blocks(blocks, sess.payload, sess.scales)
+        seq.resumed_from_tier = n
+        if sess.spec_accept_ewma is not None:
+            self._seq_accept_ewma[seq.uid] = float(sess.spec_accept_ewma)
+
     def page_out(self, uid: int) -> bool:
         """Explicitly park a live sequence's KV in the host tier (e.g. a
         session going idle between turns). The request re-enters the
         admission queue flagged ``paged`` and warm-resumes when capacity
         allows. False when paging doesn't apply — the sequence stays
         live."""
-        self._refuse_without_snapshot("host-tier parking (page_out)")
+        self.kv_cache.require("host_tier", "host-tier parking (page_out)")
         self._drain()
         seq = self.state.seqs.get(uid)
         if seq is None or seq.done:
@@ -909,53 +823,44 @@ class InferenceEngineV2:
         from host memory. Returns None when there is nothing warm to
         capture (unknown uid, mid-prefill, queued-but-never-admitted):
         the caller degrades to the legacy fold-and-resubmit path."""
-        self._refuse_for_windowed_pool("the session-migration wire")
-        self._refuse_for_latent_pool("the session-migration wire")
-        self._refuse_without_snapshot("session migration "
-                                      "(migrate_out_session)")
+        self.kv_cache.require("migration",
+                              "session migration (migrate_out_session)")
         self._drain()
         tier = getattr(self.kv_cache, "host_tier", None)
         seq = self.state.seqs.get(uid)
         if seq is None or seq.done:
-            sess = tier.pop_session(uid) if tier is not None else None
-            if sess is None:
+            src = tier.pop_session(uid) if tier is not None else None
+            if src is None:
                 return None
             # drop the paged queue entry: ownership moves with the bytes
             if any(r.uid == uid for r in self._queue):
                 self._queue = deque(r for r in self._queue
                                     if r.uid != uid)
             self._seq_accept_ewma.pop(uid, None)
-            self.tracer.on_finish(uid, "migrated")
-            self.stats["migrated_out"] += 1
-            self._hub.counter_add("serve.migrated_out",
-                                  labels=self._metric_labels)
-            return {"uid": int(uid),
-                    "input_tokens": np.asarray(sess.input_tokens, np.int32),
-                    "generated": list(sess.generated),
-                    "seen_tokens": int(sess.seen_tokens),
-                    "max_new_tokens": int(sess.max_new_tokens),
-                    "prior_generated": int(sess.prior_generated),
-                    "payload": sess.payload, "scales": sess.scales,
-                    "spec_accept_ewma": sess.spec_accept_ewma}
-        if seq.pending_prefill or seq.seen_tokens <= 0:
-            return None
-        # trim to the blocks holding real KV (same rule as _page_out):
-        # rejected speculative drafts leave garbage past the frontier
-        keep = self.kv_cache.blocks_needed(seq.seen_tokens)
-        if keep <= 0 or keep > len(seq.kv_blocks):
-            return None
-        payload, scales = self.kv_cache.read_blocks_host(
-            np.asarray(seq.kv_blocks[:keep], np.int64))
+            payload, scales, ewma = (src.payload, src.scales,
+                                     src.spec_accept_ewma)
+        else:
+            if seq.pending_prefill or seq.seen_tokens <= 0:
+                return None
+            # trim to the blocks holding real KV (same rule as _page_out):
+            # rejected speculative drafts leave garbage past the frontier
+            keep = self.kv_cache.blocks_needed(seq.seen_tokens)
+            if keep <= 0 or keep > len(seq.kv_blocks):
+                return None
+            payload, scales = self.kv_cache.read_blocks_host(
+                np.asarray(seq.kv_blocks[:keep], np.int64))
+            src, ewma = seq, self._seq_accept_ewma.get(uid)
         cap = {"uid": int(uid),
-               "input_tokens": np.asarray(seq.input_tokens, np.int32),
-               "generated": list(seq.generated),
-               "seen_tokens": int(seq.seen_tokens),
-               "max_new_tokens": int(seq.max_new_tokens),
-               "prior_generated": int(seq.prior_generated),
+               "input_tokens": np.asarray(src.input_tokens, np.int32),
+               "generated": list(src.generated),
+               "seen_tokens": int(src.seen_tokens),
+               "max_new_tokens": int(src.max_new_tokens),
+               "prior_generated": int(src.prior_generated),
                "payload": payload, "scales": scales,
-               "spec_accept_ewma": self._seq_accept_ewma.get(uid)}
+               "spec_accept_ewma": ewma}
         self.tracer.on_finish(uid, "migrated")
-        self._release_seq(uid)
+        if src is seq:
+            self._release_seq(uid)
         self.stats["migrated_out"] += 1
         self._hub.counter_add("serve.migrated_out",
                               labels=self._metric_labels)
@@ -980,10 +885,8 @@ class InferenceEngineV2:
           engine (per-seq cap): counted and closed, mirroring
           ``_requeue``'s cap-truncation contract.
         """
-        self._refuse_for_windowed_pool("the session-migration wire")
-        self._refuse_for_latent_pool("the session-migration wire")
-        self._refuse_without_snapshot("session migration "
-                                      "(install_migrated_session)")
+        self.kv_cache.require("migration",
+                              "session migration (install_migrated_session)")
         self._drain()
         uid = int(sess.uid)
         if uid in self.state.seqs or any(r.uid == uid for r in self._queue):
@@ -1001,20 +904,7 @@ class InferenceEngineV2:
             if n > self.kv_cache.free_blocks:
                 self.kv_cache.reclaim(n - self.kv_cache.free_blocks)
             if n <= self.kv_cache.free_blocks:
-                seq = self.state.get_or_create(
-                    uid, np.asarray(sess.input_tokens, np.int32),
-                    sess.max_new_tokens)
-                seq.generated = list(sess.generated)
-                seq.prior_generated = int(sess.prior_generated)
-                seq.seen_tokens = int(sess.seen_tokens)
-                blocks = self.kv_cache.allocator.allocate(n)
-                seq.kv_blocks = np.asarray(blocks, np.int64)
-                self.kv_cache.write_blocks(blocks, sess.payload,
-                                           sess.scales)
-                seq.resumed_from_tier = n
-                if sess.spec_accept_ewma is not None:
-                    self._seq_accept_ewma[uid] = float(
-                        sess.spec_accept_ewma)
+                self._restore(sess, n)
                 self.tracer.on_enqueue(uid, len(fold),
                                        queue_depth=len(self._queue))
                 self.tracer.on_admit(uid, wait_s=0.0, requeued=True)
@@ -1028,25 +918,14 @@ class InferenceEngineV2:
                                       int(sess.seen_tokens),
                                       labels=self._metric_labels)
                 return "resumed"
-        if (n > 0 and tier is not None and n <= self.max_blocks_per_seq
-                and tier.put_session(sess)):
-            # target HBM is full RIGHT NOW: park the warm bytes in the
-            # host tier — readmission warm-resumes with zero re-prefill
-            self._queue.append(_QueuedRequest(
-                uid=uid, tokens=fold,
-                max_new_tokens=int(sess.max_new_tokens),
-                enqueue_time=now, prior_generated=prior,
-                requeued=True, paged=True))
-            self.tracer.on_enqueue(uid, len(fold),
-                                   queue_depth=len(self._queue))
-            self.stats["migrate_paged"] += 1
-            self.stats["queued"] += 1
-            self._hub.counter_add("serve.migrate_paged",
-                                  labels=self._metric_labels)
-            self._admit_from_queue()
-            return "paged"
+        # target HBM is full RIGHT NOW: park the warm bytes in the host
+        # tier — readmission warm-resumes with zero re-prefill
+        paged = bool(n > 0 and tier is not None
+                     and n <= self.max_blocks_per_seq
+                     and tier.put_session(sess))
         blocks_needed = self.kv_cache.blocks_needed(len(fold) + 1)
-        if (blocks_needed > self.max_blocks_per_seq
+        if not paged and (
+                blocks_needed > self.max_blocks_per_seq
                 or blocks_needed > self.kv_cache.allocator.total_blocks):
             # can never fit this engine: close it loudly (the same
             # contract as _requeue's per-seq-cap truncation) instead of
@@ -1054,17 +933,19 @@ class InferenceEngineV2:
             self.stats["truncated"] += 1
             self.tracer.on_finish(uid, "truncated")
             return "truncated"
+        rung = "paged" if paged else "recompute"
         self._queue.append(_QueuedRequest(
             uid=uid, tokens=fold, max_new_tokens=int(sess.max_new_tokens),
-            enqueue_time=now, prior_generated=prior, requeued=True))
+            enqueue_time=now, prior_generated=prior, requeued=True,
+            paged=paged))
         self.tracer.on_enqueue(uid, len(fold),
                                queue_depth=len(self._queue))
-        self.stats["migrate_recompute"] += 1
+        self.stats["migrate_" + rung] += 1
         self.stats["queued"] += 1
-        self._hub.counter_add("serve.migrate_recompute",
+        self._hub.counter_add("serve.migrate_" + rung,
                               labels=self._metric_labels)
         self._admit_from_queue()
-        return "recompute"
+        return rung
 
     def reload_params(self, params: Optional[Dict[str, Any]] = None,
                       seed: Optional[int] = None) -> None:
@@ -1090,100 +971,13 @@ class InferenceEngineV2:
             dtype=self._param_dtype,
             quantize_weights=self._quantize_weights)
         self.params = self._v1.params
-        if self._hybrid:
-            self._keep_serving_params()
-
-    def _init_recurrent(self, kv_quant_bits, host_kv_tier, speculate) -> None:
-        """Construction-time part of serving a model with recurrent layers:
-        refuse what needs a state snapshot, say once what was switched off,
-        and keep of the stacked tree only what the programs read."""
-        if kv_quant_bits is not None:
-            raise ValueError(
-                "a quantized KV pool is not wired into the hybrid step "
-                "programs (inference/hybrid_runner.py): serve with "
-                "kv_quant_bits=None")
-        if host_kv_tier:
-            self._refuse_without_snapshot("the host KV tier (host_kv_tier)")
-        if speculate:
-            self._refuse_without_snapshot("speculative decoding "
-                                          "(spec_decode / drafter)")
-        log_dist(
-            "InferenceEngineV2: the model has recurrent layers, whose "
-            "per-sequence state has no snapshot yet: prefix cache OFF (no "
-            "hit is taken); host-tier parking, session migration, the "
-            "disagg hand-off and speculative decoding are refused "
-            "(StateSnapshotUnsupported)", ranks=[0])
-        # the chunked recurrence pads every row of a segment batch to a
-        # whole chunk, so a smaller bucket would compile one more prefill
-        # program for the same work
-        from deepspeed_tpu.ops.pallas.gated_delta import CHUNK
-
-        sparse = self.cfg.sparse        # its chunks also hold whole blocks
-        self._min_segment = max(CHUNK, sparse.block if sparse else 0)
         self._keep_serving_params()
 
     def _keep_serving_params(self) -> None:
-        from deepspeed_tpu.models.hybrid import serving_params
-
-        self.params = serving_params(self.cfg, self.params,
-                                     donate=self._donate_params)
+        """Keep of the tree handed in what the runner's programs read."""
+        self.params = self._runner.serving_params(
+            self.cfg, self.params, donate=self._donate_params)
         self._v1.params = self.params     # drop the stacked tree's last ref
-
-    def _refuse_without_snapshot(self, what: str) -> None:
-        """Raise the named error for an operation that would copy or roll
-        back a sequence's recurrent state (no-op for other models)."""
-        if self._recurrent:
-            from deepspeed_tpu.inference.ragged import \
-                StateSnapshotUnsupported
-
-            raise StateSnapshotUnsupported(
-                f"{what} needs a snapshot of each sequence's recurrent "
-                "state (ragged/state_pool.py), which does not exist yet: "
-                "not available for a model with recurrent layers")
-
-    def _refuse_for_latent_pool(self, what: str) -> None:
-        """Raise the named error for an operation that would read or write
-        a page by its K/V heads (no-op for other models)."""
-        if self._latent:
-            from deepspeed_tpu.inference.ragged import LatentPoolUnsupported
-
-            raise LatentPoolUnsupported(
-                f"{what} is not built for a latent pool (one vector a "
-                "token, no K/V pair, no head axis: ragged/kv_cache.py)")
-
-    def _refuse_for_windowed_pool(self, what: str) -> None:
-        """Raise the named error for an operation that would copy, share or
-        restore a sequence's pages without knowing its ring in the windowed
-        pool (no-op for other models)."""
-        if self._windowed:
-            from deepspeed_tpu.inference.ragged import WindowedPoolUnsupported
-
-            raise WindowedPoolUnsupported(
-                f"{what} is not built for a model with windowed latent "
-                "layers (a ring of pages a sequence writes over as its "
-                "window moves on: ragged/kv_cache.py)")
-
-    def _pool_args(self, seqs) -> Dict[str, Any]:
-        """The step programs' keyword arguments beside the block table:
-        ``state_slots`` for a model with recurrent layers (each batch slot's
-        state-pool slot, scratch where empty), ``window_table`` for one with
-        windowed latent layers (each batch slot's ring of pages in the
-        windowed pool, the scratch page where a slot or an entry is
-        empty)."""
-        out = {}
-        pool, wpool = self.kv_cache.state_pool, self.kv_cache.window_pool
-        if pool is not None:
-            slots = np.full(self.max_seqs, pool.scratch_slot, np.int32)
-            for i, s in enumerate(seqs):
-                slots[i] = s.state_slot
-            out["state_slots"] = jnp.asarray(slots)
-        if wpool is not None:
-            ring = np.full((self.max_seqs, wpool.config.ring_pages),
-                           wpool.scratch_block, np.int32)
-            for i, s in enumerate(seqs):
-                ring[i, :len(s.window_blocks)] = s.window_blocks
-            out["window_table"] = jnp.asarray(ring)
-        return out
 
     def _fetch_counters(self, calls) -> None:
         """Add what a step's program calls counted to ``stats``, each under
@@ -1192,23 +986,17 @@ class InferenceEngineV2:
         takes) and whether it was of a decode program. Once a step, inside
         the ``fetch`` span, after the step's tokens: the programs are done,
         and the host has waited for none of them between two calls."""
-        if not self._hybrid:
+        if not self._counters:
             return
         for pools, decode in calls:
-            counted = dict(zip(COUNTERS, (int(v) for v in
-                                          np.asarray(pools["counters"]))))
+            counted = dict(zip(self._counters, (
+                int(v) for v in np.asarray(pools["counters"]))))
             for name, n in counted.items():
                 self.stats[name] += n
             if decode:
-                for name in ("moe_local_pairs", "moe_experts_hit",
-                             "moe_work_items"):
+                for name in self._runner.DECODE_COUNTERS:
                     self.stats[name + "_decode"] += counted[name]
-        pool = self.kv_cache.state_pool
-        self.stats["state_slots_in_use"] = pool.slots_in_use if pool else 0
-        self.stats["compressed_keys_in_use"] = \
-            self.kv_cache.compressed_keys_in_use
-        wpool = self.kv_cache.window_pool
-        self.stats["window_pages_in_use"] = wpool.pages_in_use if wpool else 0
+        self.stats.update(self.kv_cache.occupancy())
 
     def holds_prefix_blocks(self, tokens) -> int:
         """How many full prefix blocks of ``tokens`` this engine can
@@ -1445,7 +1233,7 @@ class InferenceEngineV2:
                 else:
                     rows_np = np.asarray(self._take_rows(logits, idx_dev))
                 self._fetch_counters(counted)
-        elif self._hybrid:
+        elif self._counters:
             with span("fetch"):       # no token to read: the counters alone
                 self._fetch_counters(counted)
         with span("bookkeep"):
@@ -1495,7 +1283,7 @@ class InferenceEngineV2:
         on, whatever the runner, and always for a model with block-sparse
         or latent attention (no gather program is built for it). Not where
         the gather program is the path (``_use_paged_kernel`` off)."""
-        return self._no_gather or self._use_paged_kernel
+        return not self._has_gather or self._use_paged_kernel
 
     def _split_by_program(self, scheduled):
         """The step's work as the lists (of indices into ``scheduled``) one
@@ -1513,7 +1301,7 @@ class InferenceEngineV2:
             return [list(range(len(scheduled)))]
         calls = []
         for i in chunks:
-            if calls and not self._no_gather and self._pads_within_budget(
+            if calls and self._has_gather and self._pads_within_budget(
                     [len(scheduled[j][1]) for j in (*calls[-1], i)]):
                 calls[-1].append(i)
             else:
@@ -1543,14 +1331,15 @@ class InferenceEngineV2:
     def _build_step_call(self, scheduled):
         """Pick the program for this part of a step and assemble its host
         arrays: ``(jitted fn, program name, arguments after params and
-        KV, the pools' keyword arguments (_pool_args), the ragged
-        batch)``. On the kernel path ``decode`` when every
+        KV, the stores' keyword arguments (``kv_cache.step_args``), the
+        ragged batch)``. On the kernel path ``decode`` when every
         sequence advances one token (tokens line up with slots, so the
         compact paged-kernel path applies), else ``prefill`` (the part is
         chunks: _split_by_program); off it the flat ``gather`` program."""
         batch = build_ragged_batch(scheduled, self.max_tokens,
                                    self.max_seqs, self.max_blocks_per_seq)
-        pools = self._pool_args([seq for seq, _, _ in scheduled])
+        pools = self.kv_cache.step_args([seq for seq, _, _ in scheduled],
+                                        self.max_seqs)
         if not self._use_paged_kernel:
             return self._step_fn, "gather", (
                 jnp.asarray(batch.token_ids), jnp.asarray(batch.token_seq),
@@ -1720,7 +1509,7 @@ class InferenceEngineV2:
                     ids = after.last
                 args = (ids, jnp.asarray(d_pos), jnp.asarray(bt),
                         jnp.asarray(ctx))
-                pools = self._pool_args(live)
+                pools = self.kv_cache.step_args(live, self.max_seqs)
             counts: Dict[str, int] = {}
             ahead = {} if after is None else {"ahead": 1}
             with self._dispatch("multi_decode", live, K * len(live),

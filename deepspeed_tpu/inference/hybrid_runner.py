@@ -56,18 +56,14 @@ do not tell the models apart. What differs:
   1`` tokens before it (read from the ring first) and its own latents under
   the band (``wmla_chunk``), then writes its last rows.
 
-``counters`` comes back as this call's ``[moe_token_layers, moe_local_pairs,
-moe_experts_hit, moe_work_items, sparse_blocks_selected,
-sparse_blocks_visible, sparse_dense_tokens, mla_context_tokens,
-mla_pages_read, dsa_rows_selected, dsa_rows_visible, window_rows_read,
-window_pages_recycled]`` (``state_pool.COUNTERS``; summed over the steps of a
-burst).
+``counters`` comes back as this call's vector, in the order of ``COUNTERS``
+below (summed over the steps of a burst).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,20 +72,43 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.inference.model_runner import (_kv_write, _paged_decode,
                                                   _segment_attention)
-from deepspeed_tpu.inference.ragged.state_pool import (COUNTERS,
-                                                       DSA_COUNTERS,
-                                                       MLA_COUNTERS,
-                                                       MOE_COUNTERS,
-                                                       SPARSE_COUNTERS)
+from deepspeed_tpu.inference.ragged.kv_cache import (KVCacheConfig,
+                                                     WindowPoolConfig)
+from deepspeed_tpu.inference.ragged.state_pool import StatePoolConfig
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.hybrid import HybridConfig
 from deepspeed_tpu.ops import block_sparse
-from deepspeed_tpu.ops.pallas.gated_delta import (gdn_chunk, gdn_decode,
+from deepspeed_tpu.ops.pallas.gated_delta import (CHUNK, gdn_chunk,
+                                                  gdn_decode,
                                                   lightning_chunk,
                                                   lightning_decode)
 from deepspeed_tpu.runtime.sharding import effective_dtype
 
 
+# what a step program counts, in the order of its ``counters`` vector: the
+# expert blocks' part, then the block-selecting attention's, then latent
+# attention's decode kernel's (the context tokens it was asked to read, and
+# the page copies it started, which the kernel counts itself; summed over
+# sequences, layers and steps), then the selector's and the windowed latent
+# layers' (the cached rows a full layer's queries attended over and could
+# see; the rows a windowed layer's queries read, and the ring pages a token
+# began to write over: summed likewise)
+MOE_COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
+                "moe_work_items")
+SPARSE_COUNTERS = ("sparse_blocks_selected", "sparse_blocks_visible",
+                   "sparse_dense_tokens")
+MLA_COUNTERS = ("mla_context_tokens", "mla_pages_read")
+DSA_COUNTERS = ("dsa_rows_selected", "dsa_rows_visible")
+WINDOW_COUNTERS = ("window_rows_read", "window_pages_recycled")
+COUNTERS = (MOE_COUNTERS + SPARSE_COUNTERS + MLA_COUNTERS + DSA_COUNTERS
+            + WINDOW_COUNTERS)
+# those ``stats`` also keeps for the two decode programs alone (``_decode``),
+# and the occupancy keys it has for every model (0 for a store it has not)
+DECODE_COUNTERS = ("moe_local_pairs", "moe_experts_hit", "moe_work_items")
+OCCUPANCY = ("state_slots", "state_slots_in_use", "compressed_keys_in_use",
+             "window_pages_in_use")
+
+serving_params = hybrid.serving_params  # the tree these programs take
 _MOE = len(MOE_COUNTERS)    # where the expert blocks' counters end
 _SPARSE = _MOE + len(SPARSE_COUNTERS)   # and the sparse rule's; then latent
 _MLA = _SPARSE + len(MLA_COUNTERS)      # attention's; then the selector's
@@ -353,13 +372,6 @@ def _scratch(pools, alive, state_slots):
     if "state" not in pools:
         return None
     return jnp.where(alive, state_slots, pools["state"].shape[1] - 1)
-
-
-def _no_gather():
-    raise NotImplementedError(
-        "the gather program holds a context per token and is not built "
-        "for a model with block-sparse or latent attention: its steps run "
-        "the prefill and decode programs")
 
 
 def _padded(x, width: int):
@@ -659,6 +671,63 @@ def _wmla_chunk(cfg, wp, y, pools, l, window_table, pos, real, seg_pos0,
     return o, _window_counts(dict(pools, wkv=wkv), rows, pos, keep, bs, R)
 
 
+def store_specs(cfg: HybridConfig, *, kv_blocks: int, kv_block_size: int,
+                max_seqs: int, state_slots: Optional[int], dtype, quant_bits):
+    """``model_runner.store_specs``'s contract, and the one place a
+    configuration is read for what it keeps per sequence: pages of keys and
+    values a full layer (with the sparse rule's compressed keys: a page is
+    a block of the rule) or of latents (with the selector's keys); a slot
+    of recurrent state; a ring of windowed latent pages, every sequence of
+    a step its whole ring."""
+    sparse = cfg.sparse
+    if sparse is not None and kv_block_size != sparse.block:
+        raise ValueError(
+            f"kv_block_size={kv_block_size}: a model with block-sparse "
+            f"attention needs pages of its block size, {sparse.block}")
+    paged = KVCacheConfig(
+        num_layers=cfg.kv_layers, kv_heads=cfg.kv_heads,
+        head_dim=cfg.head_dim, block_size=kv_block_size,
+        num_blocks=kv_blocks, dtype=dtype, quant_bits=quant_bits,
+        compressed_per_block=sparse.per_block if sparse else 0,
+        kind="latent" if cfg.latent_dim else "kv",
+        latent_dim=cfg.latent_dim, index_key_dim=cfg.index_key_dim)
+    if quant_bits is not None:      # (a latent pool's own refusal came first)
+        raise ValueError(
+            "a quantized KV pool is not wired into the hybrid step "
+            "programs (inference/hybrid_runner.py): serve with "
+            "kv_quant_bits=None")
+    beside = []
+    if cfg.recurrent_layers:
+        beside.append(StatePoolConfig(
+            layers=cfg.recurrent_layers, slots=int(state_slots or max_seqs),
+            heads=cfg.linear_num_value_heads, key_dim=cfg.linear_key_head_dim,
+            value_dim=cfg.linear_value_head_dim, conv_taps=cfg.conv_taps,
+            conv_channels=cfg.conv_channels, dtype=dtype))
+    if cfg.window_latent_dim:
+        beside.append(WindowPoolConfig.for_sequences(
+            max_seqs, layers=cfg.window_layers, window=cfg.sliding_window,
+            row_dim=cfg.window_latent_dim, block_size=kv_block_size,
+            dtype=dtype))
+    return paged, beside
+
+
+def min_segment(cfg: HybridConfig) -> int:
+    """The prefill program's smallest chunk bucket: the chunked recurrence
+    pads every row of a segment batch to a whole chunk (which also holds
+    whole blocks of the sparse rule), so a smaller bucket would compile one
+    more prefill program for the same work."""
+    if not cfg.recurrent_layers:
+        return 8
+    return max(CHUNK, cfg.sparse.block if cfg.sparse else 0)
+
+
+def has_gather(cfg: HybridConfig) -> bool:
+    """No step of a model with block-sparse or latent attention runs the
+    gather program (:func:`ragged_forward` refuses): its chunks go one
+    sequence a call through the prefill program."""
+    return cfg.sparse is None and cfg.attention_kind != "mla"
+
+
 def gather_rows_computed(max_seqs: int, max_tokens: int) -> int:
     """The token rows one call of :func:`ragged_forward` computes, whatever
     it carries (a ``dstpu/dispatch`` span's ``padded_rows``): it lays the
@@ -677,8 +746,11 @@ def ragged_forward(cfg: HybridConfig, params, pools: Dict, token_ids, token_seq,
     rule: it lays out one whole context per *token*, and such a model's
     contexts are long; the engine runs its chunks through the prefill
     program and its single tokens through the decode program."""
-    if cfg.sparse is not None or cfg.attention_kind == "mla":
-        _no_gather()
+    if not has_gather(cfg):
+        raise NotImplementedError(
+            "the gather program holds a context per token and is not built "
+            "for a model with block-sparse or latent attention: its steps "
+            "run the prefill and decode programs")
     T = token_ids.shape[0]
     S, Bm = block_table.shape
     bs = pools["kv"].shape[2]

@@ -18,12 +18,13 @@ jitted once per shape bucket (the CUDA-graph analog, engine.py:497).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.inference.ragged.kv_cache import KVCacheConfig
 from deepspeed_tpu.models.transformer import (
     TransformerConfig, _norm, _rope, act_fn)
 from deepspeed_tpu.ops.pallas.quantization import (kv_dequantize,
@@ -34,13 +35,12 @@ from deepspeed_tpu.runtime.sharding import (effective_dtype,
 
 
 def _kv_parts(kv_state):
-    """Split the ragged KV pool pytree: a bare array (bf16 pool — today's
-    program, traced verbatim) yields (data, None); a (payload, fp32
-    scales) pair yields both. The quantized branch is chosen at trace
-    time, so the unquantized lowering carries no quant ops at all."""
-    if isinstance(kv_state, (tuple, list)):
-        return kv_state[0], kv_state[1]
-    return kv_state, None
+    """Split the ragged KV pool pytree (``BlockedKVCache.kv_state``): ``kv``
+    alone (bf16 pool) yields (data, None); with the fp32 ``scales`` of a
+    quantized pool, both. The quantized branch is chosen at trace time, so
+    the unquantized lowering carries no quant ops at all (and the dict
+    itself never reaches the lowered program)."""
+    return kv_state["kv"], kv_state.get("scales")
 
 
 def _kv_bits(kv_layer):
@@ -119,7 +119,8 @@ def _scan_layers(layer_body, x, params, kv_data, kv_scales):
     (x, kv_data, kv_scales), _ = lax.scan(
         layer_body, (x, kv_data, kv_scales),
         (params["layers"], jnp.arange(kv_data.shape[0], dtype=jnp.int32)))
-    return x, (kv_data if kv_scales is None else (kv_data, kv_scales))
+    return x, ({"kv": kv_data} if kv_scales is None
+               else {"kv": kv_data, "scales": kv_scales})
 
 
 def _qkv(cfg: TransformerConfig, layer_params, y, positions):
@@ -271,6 +272,39 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+# What the serving engine asks of a runner beside its four programs (the same
+# names in ``hybrid_runner``): the stores a configuration keeps per sequence,
+# the tree its programs take, the smallest chunk bucket of the prefill
+# program, whether a step may use the gather program, and what the programs
+# count (these return the pools alone: no ``counters`` vector, none of them
+# kept apart for the decode programs, no occupancy key ``stats`` always has)
+COUNTERS = DECODE_COUNTERS = OCCUPANCY = ()
+
+
+def store_specs(cfg: TransformerConfig, *, kv_blocks: int,
+                kv_block_size: int, max_seqs: int,
+                state_slots: Optional[int], dtype, quant_bits):
+    """``(the paged pool's spec, the specs of the stores beside it)`` for
+    an engine of these sizes: keys and values a layer, nothing beside."""
+    return KVCacheConfig(
+        num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
+        head_dim=cfg.head_dim, block_size=kv_block_size,
+        num_blocks=kv_blocks, dtype=dtype, quant_bits=quant_bits), []
+
+
+def serving_params(cfg: TransformerConfig, params, donate: bool = False):
+    """The tree the programs take: the model's own."""
+    return params
+
+
+def min_segment(cfg: TransformerConfig) -> int:
+    return 8
+
+
+def has_gather(cfg: TransformerConfig) -> bool:
+    return True
+
+
 def gather_rows_computed(max_seqs: int, max_tokens: int) -> int:
     """The token rows one call of :func:`ragged_forward` computes, whatever
     it carries: the flat budget (what a ``dstpu/dispatch`` span gives as its
@@ -278,15 +312,15 @@ def gather_rows_computed(max_seqs: int, max_tokens: int) -> int:
     return max_tokens
 
 
-def ragged_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
+def ragged_forward(cfg: TransformerConfig, params, kv_data: Dict,
                    token_ids: jax.Array, token_seq: jax.Array,
                    token_pos: jax.Array, block_table: jax.Array,
                    num_tokens) -> Tuple[jax.Array, jax.Array]:
     """One ragged step over flat tokens.
 
-    kv_data     [L, num_blocks, bs, 2, nkv, hd] — or, for a quantized
-                pool, the (int8 payload, fp32 scales [L, nb, bs, 2, nkv])
-                pair from ``BlockedKVCache.kv_state``
+    kv_data     ``BlockedKVCache.kv_state``: ``kv`` [L, num_blocks, bs, 2,
+                nkv, hd] and, for a quantized pool (then the int8
+                payload), its fp32 ``scales`` [L, nb, bs, 2, nkv]
     token_ids   [T] int32 (padded); token_seq [T] slot ids; token_pos [T]
     block_table [S, Bm]; num_tokens scalar (true T, rest is padding)
 
@@ -453,7 +487,7 @@ def _segment_attention(cfg: TransformerConfig, q, kv, kv_sc, layer,
 
 
 def ragged_prefill_forward(cfg: TransformerConfig, params,
-                           kv_data: jax.Array, seg_tokens: jax.Array,
+                           kv_data: Dict, seg_tokens: jax.Array,
                            seg_pos0: jax.Array, seg_nreal: jax.Array,
                            block_table: jax.Array, *, mesh=None
                            ) -> Tuple[jax.Array, jax.Array]:
@@ -520,7 +554,7 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
 # ---------------------------------------------------------------------------
 
 
-def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
+def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: Dict,
                           token_ids: jax.Array, token_pos: jax.Array,
                           block_table: jax.Array, context_lens: jax.Array,
                           *, mesh=None) -> Tuple[jax.Array, jax.Array]:
@@ -580,7 +614,7 @@ def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: jax.Array,
     return _unembed(cfg, params, x), new_kv
 
 
-def ragged_multi_decode(cfg: TransformerConfig, params, kv_data: jax.Array,
+def ragged_multi_decode(cfg: TransformerConfig, params, kv_data: Dict,
                         token_ids: jax.Array, token_pos: jax.Array,
                         block_table: jax.Array, context_lens: jax.Array,
                         *, steps: int, mesh=None

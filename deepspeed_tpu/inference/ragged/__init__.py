@@ -11,6 +11,7 @@ from deepspeed_tpu.inference.ragged.sequence import (
 from deepspeed_tpu.inference.ragged.ragged_batch import RaggedBatch
 from deepspeed_tpu.inference.ragged.state_pool import (
     RecurrentStatePool, StatePoolConfig, StateSnapshotUnsupported)
+from deepspeed_tpu.inference.ragged.store import OPS, Store
 
 __all__ = [
     "BlockedAllocator",
@@ -26,6 +27,8 @@ __all__ = [
     "RecurrentStatePool",
     "StatePoolConfig",
     "StateSnapshotUnsupported",
+    "Store",
+    "OPS",
     "WindowPoolConfig",
     "WindowedLatentPool",
     "WindowedPoolUnsupported",

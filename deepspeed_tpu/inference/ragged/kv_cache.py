@@ -51,12 +51,13 @@ as the compressed keys above are: same block table, same allocator, a page
 that is freed takes them with it.
 
 A model with **windowed latent layers** keeps a third pool
-(:class:`WindowedLatentPool`, ``BlockedKVCache.window_pool``): rows of
+(:class:`WindowedLatentPool`, the store named ``"wkv"``): rows of
 another width, for the last ``window`` tokens of a sequence only,
 
     wkv[window layers, window_blocks, block_size, lanes(row_dim)]
 
-with an allocator of its own. A sequence holds a **ring** of at most
+with an allocator of its own. A sequence holds a **ring**
+(``seq.held["wkv"]``) of at most
 ``ring_pages = ceil((window + block_size - 1) / block_size) + 1`` pages of
 it, whatever its length: the token at position ``p`` lives in ring entry
 ``(p // block_size) % ring_pages``, so a page the window has passed is
@@ -64,21 +65,32 @@ written over by the tokens ``ring_pages`` pages later (the step programs
 count each such reuse: ``window_pages_recycled``). Pages are taken one at a
 time as a young sequence grows and all given back when it is released. What
 would have to copy, share or restore a sequence's pages without knowing the
-ring (the prefix cache, the host tier, migration, hand-off, speculation)
-refuses by name (:class:`WindowedPoolUnsupported`).
+ring (the prefix cache, the host tier, migration, hand-off) refuses by name
+(:class:`WindowedPoolUnsupported`). Speculation is not on its list: every
+model with a ring has a latent pool, whose refusal is the one pinned to win
+(``tests/test_dots3_model.py``) though the pages are asked last; whether a
+rejected draft's rows would cost a ring rows its window still sees is not
+established, so a ring beside a pool that speculates lists it first.
+
+:class:`BlockedKVCache` is the paged pool and the list of the stores beside
+it (``ragged/store.py``): what the engine asks of a model's per-sequence
+state it asks here, of all of them.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.ragged.blocked_allocator import BlockedAllocator
+from deepspeed_tpu.inference.ragged.store import OPS, Store
+
+_NO_BLOCKS = np.empty(0, dtype=np.int64)
 
 
 class LatentPoolUnsupported(NotImplementedError):
@@ -119,10 +131,17 @@ class WindowPoolConfig:
         return (self.layers, self.num_blocks, self.block_size,
                 -(-self.row_dim // 128) * 128)
 
+    def build(self) -> "WindowedLatentPool":
+        return WindowedLatentPool(self)
 
-class WindowedLatentPool:
+
+class WindowedLatentPool(Store):
     """The windowed latent layers' pool: the device array and an allocator
     of its own (the last block is scratch and never handed out)."""
+
+    name = "wkv"
+    unsupported = frozenset({"prefix_cache", "host_tier", "migration",
+                             "handoff"})
 
     def __init__(self, config: WindowPoolConfig):
         self.config = config
@@ -141,24 +160,57 @@ class WindowedLatentPool:
     def pages_in_use(self) -> int:
         return self.allocator.total_blocks - self.allocator.free_blocks
 
-    def pages_for(self, num_tokens: int) -> int:
-        """Ring pages a sequence of ``num_tokens`` holds."""
-        c = self.config
-        return min(-(-num_tokens // c.block_size), c.ring_pages)
-
-    def grow(self, blocks: np.ndarray, num_tokens: int):
-        """``blocks`` grown to what ``num_tokens`` tokens hold, or None
-        where the pool has no page left."""
-        need = self.pages_for(num_tokens) - len(blocks)
-        if need <= 0:
-            return blocks
-        if need > self.allocator.free_blocks:
-            return None
-        return np.concatenate([blocks, self.allocator.allocate(need)])
-
     def free(self, blocks) -> None:
         if len(blocks):
             self.allocator.free(blocks)
+
+    # -- the store interface (ragged/store.py) ---------------------------
+
+    def error(self, what: str) -> WindowedPoolUnsupported:
+        return WindowedPoolUnsupported(
+            f"{what} is not built for a model with windowed latent "
+            "layers (a ring of pages a sequence writes over as its "
+            "window moves on: ragged/kv_cache.py)")
+
+    def arrays(self):
+        return {"wkv": self.data}
+
+    def set_arrays(self, state) -> None:
+        self.data = state["wkv"]
+
+    def can_take(self) -> bool:
+        """A sequence is admitted where its whole ring has room."""
+        return self.free_blocks >= self.config.ring_pages
+
+    def take(self, seq) -> None:
+        seq.held["wkv"] = _NO_BLOCKS
+
+    def grow(self, seq, num_tokens: int) -> bool:
+        """The ring grown to what ``num_tokens`` tokens hold (nothing once
+        it is whole); False where the pool has no page left."""
+        c, ring = self.config, seq.held["wkv"]
+        need = min(-(-num_tokens // c.block_size), c.ring_pages) - len(ring)
+        if need > self.allocator.free_blocks:
+            return False
+        if need > 0:
+            seq.held["wkv"] = np.concatenate(
+                [ring, self.allocator.allocate(need)])
+        return True
+
+    def give_back(self, seq) -> None:
+        self.free(seq.held.pop("wkv"))
+
+    def host_args(self, seqs, rows: int):
+        """``window_table``: each batch slot's ring of pages, the scratch
+        page where a slot or an entry is empty."""
+        table = np.full((rows, self.config.ring_pages), self.scratch_block,
+                        np.int32)
+        for i, s in enumerate(seqs):
+            table[i, :len(s.held["wkv"])] = s.held["wkv"]
+        return {"window_table": table}
+
+    def in_use(self):
+        return {"window_pages_in_use": self.pages_in_use}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,9 +295,11 @@ def dstpu_kv_write_blocks(pool, idx, rows):
     return pool.at[:, idx].set(rows.astype(pool.dtype))
 
 
-class BlockedKVCache:
+class BlockedKVCache(Store):
     """Device pool + host allocator (reference kv_cache.py:40 contract:
-    reserve/free by block count; here also owns the device buffer).
+    reserve/free by block count; here also owns the device buffer), and
+    the list of the model's stores (``stores``: those handed in, then the
+    pages themselves).
 
     When a :class:`~deepspeed_tpu.inference.ragged.prefix_cache.PrefixCache`
     is attached (``prefix_cache`` attr), idle cached blocks are parked
@@ -253,15 +307,18 @@ class BlockedKVCache:
     under memory pressure, so shared-prefix reuse never shrinks the pool
     a live sequence can reach."""
 
-    def __init__(self, config: KVCacheConfig, mesh=None, tp_axis: str = "tp"):
+    name = "kv"
+
+    def __init__(self, config: KVCacheConfig, mesh=None, tp_axis: str = "tp",
+                 stores: Sequence[Store] = ()):
         self.config = config
         self.allocator = BlockedAllocator(config.num_blocks)
         self.prefix_cache = None  # Optional[PrefixCache], attached by owner
         self.host_tier = None     # Optional[HostKVTier], attached by owner
-        # Optional[RecurrentStatePool] (ragged/state_pool.py), attached by
-        # the owner for a model with recurrent layers: slot-addressed state
-        # beside the blocks, handed to the step programs in one pytree
-        self.state_pool = None
+        # the stores beside the pages first: where two refuse one operation,
+        # the error is the one that names what is particular to the model;
+        # and a sequence grows there first (a ring takes nothing once whole)
+        self.stores = [*stores, self]
         # the compressed keys of a model that chooses its pages (None: no
         # such model); one dtype with the pool, handed out and taken back
         # with it (``kv_state``)
@@ -274,19 +331,13 @@ class BlockedKVCache:
                 (config.num_layers, config.num_blocks,
                  config.compressed_per_block, config.kv_heads,
                  config.head_dim), config.dtype)
-        # the selector's keys of a latent pool (None: no selector), and the
-        # windowed latent layers' pool (None: no such layer; attached by the
-        # owner); both handed out and taken back with the pool
+        # the selector's keys of a latent pool (None: no selector), handed
+        # out and taken back with the pool
         self.index_keys = None
         if config.index_key_dim:
             self.index_keys = jnp.zeros(
                 config.pool_shape[:3] + (config.index_key_dim,), config.dtype)
-        self.window_pool = None
         shape = config.pool_shape
-        # a hybrid stack's step programs take the pools as a dict, also
-        # where no recurrent-state pool stands beside this one (a stack
-        # without recurrent layers): the engine says so
-        self.pools_as_dict = False
         quantized = config.quant_bits is not None
         # int4 packs nibbles into uint8 (the runner infers the width from
         # the pool dtype at trace time: int8 → 8, uint8 → 4, e4m3 → fp8)
@@ -315,52 +366,118 @@ class BlockedKVCache:
     def quant_bits(self) -> Optional[int]:
         return self.config.quant_bits
 
-    @property
-    def kv_state(self):
-        """Device pool as the pytree the ragged forwards consume: the bare
-        bf16 array when unquantized (today's program, verbatim), or a
-        (payload, fp32 scales) pair when ``quant_bits`` is set (int8
-        payload, or packed-nibble uint8 for 4-bit storage). With a
-        recurrent-state pool attached: the dict of both pools
-        (``inference/hybrid_runner.py``). A hybrid stack's ``counters`` are
-        no part of it: a step program hands its own vector out and takes
-        none in, so the last call's is never donated to the next."""
-        if self.state_pool is not None:
-            sp = self.state_pool
-            state = {"kv": self.data, "state": sp.state, "conv": sp.conv}
-            if self.compressed is not None:
-                state["ck"] = self.compressed
-            return state
-        if self.pools_as_dict:
-            state = {"kv": self.data}
-            if self.index_keys is not None:
-                state["ik"] = self.index_keys
-            if self.window_pool is not None:
-                state["wkv"] = self.window_pool.data
-            return state
-        if self.scales is None:
-            return self.data
-        return (self.data, self.scales)
+    # -- all the stores together -----------------------------------------
 
-    def set_kv_state(self, state) -> None:
-        """Store the pool returned by a compiled step (inverse of
-        :attr:`kv_state`). The step programs donate the pool they are
-        handed, so this is the only live handle afterwards: read
-        ``data`` / ``kv_state`` afresh, never keep one across a step."""
-        if self.state_pool is not None:
-            sp = self.state_pool
-            self.data, sp.state, sp.conv = (
-                state["kv"], state["state"], state["conv"])
-            self.compressed = state.get("ck")
-        elif self.pools_as_dict:
-            self.data = state["kv"]
-            self.index_keys = state.get("ik")
-            if self.window_pool is not None:
-                self.window_pool.data = state["wkv"]
-        elif self.scales is None:
-            self.data = state
-        else:
-            self.data, self.scales = state
+    @property
+    def kv_state(self) -> Dict[str, Any]:
+        """Every store's device arrays as the one pytree the step programs
+        take, donate and hand back: ``kv`` (bf16, or a quantized pool's
+        int8 / packed-nibble uint8 / e4m3 payload with its fp32 ``scales``),
+        ``ck`` and ``ik`` where the pool has them, and the other stores'
+        (``state``, ``conv``, ``wkv``). A hybrid stack's ``counters`` are no
+        part of it: a step program hands its own vector out and takes none
+        in, so the last call's is never donated to the next."""
+        return {k: v for store in self.stores
+                for k, v in store.arrays().items()}
+
+    def set_kv_state(self, state: Dict[str, Any]) -> None:
+        """Inverse of :attr:`kv_state`, for what a compiled step returned.
+        The step programs donate what they are handed, so these are the only
+        live handles afterwards: never keep one across a step."""
+        for store in self.stores:
+            store.set_arrays(state)
+
+    def store(self, name: str) -> Optional[Store]:
+        return next((s for s in self.stores if s.name == name), None)
+
+    @property
+    def state_pool(self):
+        """The recurrent-state store (None: the model has none)."""
+        return self.store("state")
+
+    def supports(self, op: str) -> bool:
+        return all(op not in store.unsupported for store in self.stores)
+
+    def require(self, op: str, what: str) -> None:
+        """Raise the named error of the first store that cannot do ``op``
+        (of ``store.OPS``)."""
+        assert op in OPS, op
+        for store in self.stores:
+            if op in store.unsupported:
+                raise store.error(what)
+
+    def admissible(self) -> bool:
+        """Whether every store has room for one more sequence (the pages
+        are the engine's own count: ``InferenceEngineV2.can_schedule``)."""
+        return all(store.can_take() for store in self.stores)
+
+    def step_args(self, seqs, rows: int) -> Dict[str, jax.Array]:
+        """The step programs' keyword arguments beside the block table."""
+        return {k: jnp.asarray(v) for store in self.stores
+                for k, v in store.host_args(seqs, rows).items()}
+
+    def occupancy(self) -> Dict[str, int]:
+        return {k: v for store in self.stores
+                for k, v in store.in_use().items()}
+
+    # -- the pages as a store (ragged/store.py) --------------------------
+
+    @property
+    def unsupported(self):
+        """What reads or writes a page by its K/V heads (the wires' codecs)
+        is not built for a latent page, nor is speculation, which verifies
+        through the gather program."""
+        if self.config.kind == "latent":
+            return frozenset({"migration", "handoff", "speculation"})
+        return frozenset()
+
+    def error(self, what: str) -> LatentPoolUnsupported:
+        return LatentPoolUnsupported(
+            f"{what} is not built for a latent pool (one vector a "
+            "token, no K/V pair, no head axis: ragged/kv_cache.py)")
+
+    def arrays(self):
+        named = {"kv": self.data, "scales": self.scales,
+                 "ck": self.compressed, "ik": self.index_keys}
+        return {k: v for k, v in named.items() if v is not None}
+
+    def set_arrays(self, state) -> None:
+        self.data = state["kv"]
+        self.scales = state.get("scales")
+        self.compressed = state.get("ck")
+        self.index_keys = state.get("ik")
+
+    def grow(self, seq, num_tokens: int) -> bool:
+        """After reclaiming idle prefix-cached blocks, if need be."""
+        need = self.blocks_needed(num_tokens) - len(seq.kv_blocks)
+        if need <= 0:
+            return True
+        if need > self.free_blocks:
+            self.reclaim(need - self.free_blocks)
+        if need > self.free_blocks:
+            return False
+        seq.kv_blocks = np.concatenate([seq.kv_blocks,
+                                        self.allocator.allocate(need)])
+        return True
+
+    def give_back(self, seq) -> None:
+        """The cache-managed head run is unref'd, the rest freed."""
+        n_shared = len(seq.prefix_keys)
+        if n_shared:
+            self.prefix_cache.unref(seq.prefix_keys)
+            seq.prefix_keys = []
+        if len(seq.kv_blocks) > n_shared:
+            self.free(seq.kv_blocks[n_shared:])
+        seq.kv_blocks = _NO_BLOCKS
+
+    def in_use(self):
+        """The compressed keys' window slots (a layer) of the pages that
+        sequences hold: taken and given back with their pages."""
+        if self.compressed is None:
+            return {}
+        held = self.allocator.total_blocks - self.allocator.free_blocks
+        return {"compressed_keys_in_use":
+                held * self.config.compressed_per_block}
 
     def blocks_needed(self, num_tokens: int) -> int:
         bs = self.config.block_size
@@ -400,13 +517,6 @@ class BlockedKVCache:
     @property
     def free_blocks(self) -> int:
         return self.allocator.free_blocks
-
-    @property
-    def compressed_keys_in_use(self) -> int:
-        """Window slots of the pages that sequences hold (a layer): they are
-        taken and given back with their pages."""
-        held = self.allocator.total_blocks - self.allocator.free_blocks
-        return held * self.config.compressed_per_block
 
     @property
     def available_blocks(self) -> int:

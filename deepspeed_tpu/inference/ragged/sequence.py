@@ -9,7 +9,7 @@ side — the compiled step only sees the dense metadata RaggedBatch builds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -42,14 +42,9 @@ class SequenceDescriptor:
     # this sequence's KV from the host tier — blocks paged in instead
     # of prefilled (the scheduler reports resumed decode separately)
     resumed_from_tier: int = 0
-    # slot of the recurrent-state pool (ragged/state_pool.py) for a model
-    # with recurrent layers; -1: the model has none
-    state_slot: int = -1
-    # the sequence's ring of pages in the windowed latent layers' pool
-    # (ragged/kv_cache.py, WindowedLatentPool): entry ``(p // block) %
-    # ring_pages`` holds position ``p``; empty: the model has no such layer
-    window_blocks: np.ndarray = dataclasses.field(
-        default_factory=lambda: np.empty(0, dtype=np.int64))
+    # what the sequence holds in each store beside the pages, keyed by the
+    # store's name (ragged/store.py): a slot, a ring of pages, ...
+    held: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     @property
     def total_tokens(self) -> int:
@@ -92,42 +87,25 @@ class StateManager:
         seq = SequenceDescriptor(uid=uid,
                                  input_tokens=np.asarray(tokens, np.int32),
                                  max_new_tokens=max_new_tokens)
-        pool = getattr(self.kv_cache, "state_pool", None)
-        if pool is not None:
-            # a zeroed slot for the sequence's whole life
-            seq.state_slot = pool.allocate()
+        for store in self.kv_cache.stores:
+            store.take(seq)
         self.seqs[uid] = seq
         return seq
 
     def ensure_capacity(self, seq: SequenceDescriptor, new_total: int) -> bool:
-        """Grow seq's block list to fit new_total tokens. False if the pool
-        is exhausted (after reclaiming idle prefix-cached blocks). A
-        sequence that hits the per-seq block cap is ENDED (truncated)
-        rather than grown — growing past the cap would crash the dense
-        batch metadata (build_ragged_batch bucket bound)."""
-        total_needed = self.kv_cache.blocks_needed(new_total)
-        need = total_needed - len(seq.kv_blocks)
-        wpool = getattr(self.kv_cache, "window_pool", None)
-        if wpool is not None:
-            # the ring first: it takes nothing once it is whole
-            ring = wpool.grow(seq.window_blocks, new_total)
-            if ring is None:
-                return False
-            seq.window_blocks = ring
-        if need <= 0:
-            return True
+        """Grow what ``seq`` holds in every store to fit new_total tokens.
+        False if one is exhausted (the pages: after reclaiming idle
+        prefix-cached blocks). A sequence that hits the per-seq block cap
+        is ENDED (truncated) rather than grown — growing past the cap would
+        crash the dense batch metadata (build_ragged_batch bucket bound)."""
         if (self.max_blocks_per_seq is not None
-                and total_needed > self.max_blocks_per_seq):
+                and self.kv_cache.blocks_needed(new_total)
+                > self.max_blocks_per_seq):
             seq.done = True
             seq.truncated = True
             return False
-        if need > self.kv_cache.free_blocks:
-            self.kv_cache.reclaim(need - self.kv_cache.free_blocks)
-        if need > self.kv_cache.free_blocks:
-            return False
-        new_blocks = self.kv_cache.allocator.allocate(need)
-        seq.kv_blocks = np.concatenate([seq.kv_blocks, new_blocks])
-        return True
+        return all(store.grow(seq, new_total)
+                   for store in self.kv_cache.stores)
 
     def attach_prefix(self, seq: SequenceDescriptor) -> int:
         """Seed a freshly-created sequence's block list from the prefix
@@ -141,15 +119,10 @@ class StateManager:
         without re-prefilling what the tier kept. Returns the number of
         prefill tokens skipped."""
         cache = self.kv_cache.prefix_cache
-        if cache is not None and getattr(self.kv_cache, "window_pool",
-                                         None) is not None:
-            from deepspeed_tpu.inference.ragged.kv_cache import \
-                WindowedPoolUnsupported
-
-            raise WindowedPoolUnsupported(
-                "the prefix cache is not built for a model with a windowed "
-                "pool: a skipped prefix would leave the sequence's ring "
-                "without the rows its window still sees")
+        if cache is not None:
+            # a skipped prefix without what a store keeps at its end (a
+            # recurrent state, a ring's rows) is a wrong answer
+            self.kv_cache.require("prefix_cache", "the prefix cache")
         if (cache is None or seq.seen_tokens or len(seq.kv_blocks)
                 or len(seq.input_tokens) <= cache.block_size):
             return 0
@@ -242,19 +215,6 @@ class StateManager:
         seq = self.seqs.pop(uid, None)
         if seq is None:
             return
-        n_shared = len(seq.prefix_keys)
-        if n_shared:
-            self.kv_cache.prefix_cache.unref(seq.prefix_keys)
-            seq.prefix_keys = []
-        if len(seq.kv_blocks) > n_shared:
-            self.kv_cache.free(seq.kv_blocks[n_shared:])
-        seq.kv_blocks = np.empty(0, dtype=np.int64)
-        if seq.state_slot >= 0:
-            self.kv_cache.state_pool.free(seq.state_slot)
-            seq.state_slot = -1
-        if len(seq.window_blocks):
-            self.kv_cache.window_pool.free(seq.window_blocks)
-            seq.window_blocks = np.empty(0, dtype=np.int64)
+        for store in self.kv_cache.stores:
+            store.give_back(seq)
 
-    def live_uids(self) -> List[int]:
-        return list(self.seqs)

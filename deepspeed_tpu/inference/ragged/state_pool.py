@@ -5,21 +5,23 @@ sequence and every recurrent layer, a state that is *replaced* at each token
 and does not grow: the recurrence's matrix for each head (float32; the delta
 rule's or lightning attention's) and the last ``K - 1`` inputs of the causal
 convolution (none for a mixer without one: ``conv_taps`` 1, an empty array). It is not block-addressed:
-a sequence owns one **slot** for its whole life,
+a sequence owns one **slot** for its whole life (``seq.held["state"]``),
 
     state[layers, slots + 1, heads, key_dim, value_dim]   float32
     conv [layers, slots + 1, K - 1, channels]             the serving dtype
 
 The last slot is scratch (rows of a step that hold no sequence read and write
-it), as the paged pool's last block is. The pool belongs to the object that
-owns the paged pool (``BlockedKVCache.state_pool``) and travels with it: the
-step programs take both in one donated pytree and update them in place.
+it), as the paged pool's last block is. The pool is one of the stores
+(``ragged/store.py``) of the object that owns the paged pool
+(``BlockedKVCache.stores``) and travels with it: the step programs take both
+in one donated pytree and update them in place.
 
 A slot is taken and **zeroed** at admission and given back when the sequence
 is released (finished, flushed, or preempted for recompute). There is no
 snapshot of a slot yet: whatever would have to copy a sequence's state
 (prefix cache, host tier, migration, hand-off, speculation's roll-back) is
-switched off or refused for such a model (:class:`StateSnapshotUnsupported`).
+switched off or refused for such a model (:class:`StateSnapshotUnsupported`:
+every one of ``store.OPS``).
 """
 
 from __future__ import annotations
@@ -30,25 +32,9 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-
-# what a step program counts, in the order of its ``counters`` vector: the
-# expert blocks' part, then the block-selecting attention's, then latent
-# attention's decode kernel's (the context tokens it was asked to read, and
-# the page copies it started, which the kernel counts itself; summed over
-# sequences, layers and steps), then the selector's and the windowed latent
-# layers' (the cached rows a full layer's queries attended over and could
-# see; the rows a windowed layer's queries read, and the ring pages a token
-# began to write over: summed likewise)
-MOE_COUNTERS = ("moe_token_layers", "moe_local_pairs", "moe_experts_hit",
-                "moe_work_items")
-SPARSE_COUNTERS = ("sparse_blocks_selected", "sparse_blocks_visible",
-                   "sparse_dense_tokens")
-MLA_COUNTERS = ("mla_context_tokens", "mla_pages_read")
-DSA_COUNTERS = ("dsa_rows_selected", "dsa_rows_visible")
-WINDOW_COUNTERS = ("window_rows_read", "window_pages_recycled")
-COUNTERS = (MOE_COUNTERS + SPARSE_COUNTERS + MLA_COUNTERS + DSA_COUNTERS
-            + WINDOW_COUNTERS)
+from deepspeed_tpu.inference.ragged.store import OPS, Store
 
 
 class StateSnapshotUnsupported(NotImplementedError):
@@ -74,6 +60,9 @@ class StatePoolConfig:
             + (self.conv_taps - 1) * self.conv_channels
             * jnp.dtype(self.dtype).itemsize)
 
+    def build(self) -> "RecurrentStatePool":
+        return RecurrentStatePool(self)
+
 
 @partial(jax.jit, donate_argnums=(0, 1))
 def dstpu_state_zero_slot(state, conv, slot):
@@ -81,7 +70,10 @@ def dstpu_state_zero_slot(state, conv, slot):
     return (state.at[:, slot].set(0.0), conv.at[:, slot].set(0))
 
 
-class RecurrentStatePool:
+class RecurrentStatePool(Store):
+    name = "state"
+    unsupported = frozenset(OPS)
+
     def __init__(self, config: StatePoolConfig):
         c = self.config = config
         self.state = jnp.zeros((c.layers, c.slots + 1, c.heads, c.key_dim,
@@ -120,3 +112,40 @@ class RecurrentStatePool:
         if not 0 <= slot < self.config.slots or slot in self._free:
             raise ValueError(f"slot {slot} is not in use")
         self._free.append(slot)
+
+    # -- the store interface (ragged/store.py) ---------------------------
+
+    def error(self, what: str) -> StateSnapshotUnsupported:
+        return StateSnapshotUnsupported(
+            f"{what} needs a snapshot of each sequence's recurrent "
+            "state (ragged/state_pool.py), which does not exist yet: "
+            "not available for a model with recurrent layers")
+
+    def arrays(self):
+        return {"state": self.state, "conv": self.conv}
+
+    def set_arrays(self, state) -> None:
+        self.state, self.conv = state["state"], state["conv"]
+
+    def can_take(self) -> bool:
+        return bool(self._free)
+
+    def take(self, seq) -> None:
+        seq.held[self.name] = self.allocate()
+
+    def give_back(self, seq) -> None:
+        slot = seq.held.pop(self.name, None)
+        if slot is not None:
+            self.free(slot)
+
+    def host_args(self, seqs, rows: int):
+        """``state_slots``: each batch slot's slot here, scratch where
+        empty."""
+        slots = np.full(rows, self.scratch_slot, np.int32)
+        for i, s in enumerate(seqs):
+            slots[i] = s.held[self.name]
+        return {"state_slots": slots}
+
+    def in_use(self):
+        return {"state_slots": self.total_slots,
+                "state_slots_in_use": self.slots_in_use}

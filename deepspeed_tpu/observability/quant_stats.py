@@ -739,10 +739,10 @@ def kv_off_switch_structural(cfg=None, params=None) -> bool:
             num_kv_heads=2, ffn_size=64, max_seq_len=32)
         params = init_params(cfg, jax.random.PRNGKey(0))
     L, nb, bs = cfg.num_layers, 4, 4
-    kv = jnp.zeros((L, nb, bs, 2, cfg.kv_heads, cfg.head_dim),
-                   jnp.bfloat16)
-    kvq = (jnp.zeros(kv.shape, jnp.int8),
-           jnp.ones(kv.shape[:-1], jnp.float32))
+    kv = {"kv": jnp.zeros((L, nb, bs, 2, cfg.kv_heads, cfg.head_dim),
+                          jnp.bfloat16)}
+    kvq = {"kv": jnp.zeros(kv["kv"].shape, jnp.int8),
+           "scales": jnp.ones(kv["kv"].shape[:-1], jnp.float32)}
     T = 4
     a = (jnp.zeros(T, jnp.int32), jnp.zeros(T, jnp.int32),
          jnp.arange(T, dtype=jnp.int32),

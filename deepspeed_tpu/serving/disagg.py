@@ -233,21 +233,10 @@ def _pool_convert(kvc, payload, ssel, wire_bits, packed: bool):
     return kv_dequantize(payload, ssel, dtype=kvc.data.dtype), None
 
 
-def _refuse_for_recurrent(engine) -> None:
-    """A prefix's KV blocks without the recurrent state at the prefix's end
-    are a wrong answer on the target: a model with recurrent layers has no
-    hand-off until its state pool has snapshots (ragged/state_pool.py)."""
-    refuse = getattr(engine, "_refuse_without_snapshot", None)
-    if refuse is not None:
-        refuse("the disagg prefill->decode hand-off")
-    # a windowed pool's ring of pages is no chain of prefix blocks
-    refuse = getattr(engine, "_refuse_for_windowed_pool", None)
-    if refuse is not None:
-        refuse("the disagg hand-off wire")
-    # the wire's codec is laid out by K/V head: a latent pool has none
-    refuse = getattr(engine, "_refuse_for_latent_pool", None)
-    if refuse is not None:
-        refuse("the disagg hand-off wire")
+# what the hand-off is called where a store refuses it (``kv_cache.require``:
+# prefix blocks without a recurrent state or a ring's rows are a wrong answer
+# on the target, and the codec is laid out by K/V head: a latent pool has none)
+_HANDOFF = "the disagg prefill->decode hand-off wire"
 
 
 def serialize_prefix(engine, tokens,
@@ -267,7 +256,7 @@ def serialize_prefix(engine, tokens,
     The chain is ref'd for the duration of the device→host copy so KV
     pressure on the source replica cannot evict-and-recycle a block
     mid-serialization."""
-    _refuse_for_recurrent(engine)
+    engine.kv_cache.require("handoff", _HANDOFF)
     cache = getattr(engine.kv_cache, "prefix_cache", None)
     if cache is None:
         return None
@@ -287,10 +276,7 @@ def serialize_prefix(engine, tokens,
     src_bits = getattr(kvc, "quant_bits", None)
     cache.ref(keys)
     try:
-        idx = np.asarray(blocks)
-        data = np.asarray(kvc.data[:, idx])
-        scales = (np.asarray(kvc.scales[:, idx])
-                  if getattr(kvc, "scales", None) is not None else None)
+        data, scales = kvc.read_blocks_host(blocks)
     finally:
         cache.unref(keys)
     data, scales, wire_bits, packed, wire_snr = _wire_quantize(
@@ -315,7 +301,7 @@ def install_prefix(engine, handoff: Optional[KVHandoff]
 
     Must run on the thread that owns ``engine`` (the replica pump): it
     mutates the pool array and the cache registry."""
-    _refuse_for_recurrent(engine)
+    engine.kv_cache.require("handoff", _HANDOFF)
     cache = getattr(engine.kv_cache, "prefix_cache", None)
     if cache is None or handoff is None or not handoff.keys:
         return (0, 0)

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
-from deepspeed_tpu.inference.ragged import (KVCacheConfig, StateManager,
+from deepspeed_tpu.inference.ragged import (KVCacheConfig,
+                                            SequenceDescriptor, StateManager,
                                             WindowedLatentPool,
                                             WindowedPoolUnsupported,
                                             WindowPoolConfig)
@@ -304,7 +305,7 @@ def test_engine_logits_match_the_reference_full_forward():
     prompts = {1: rng.integers(0, 256, 45).astype(np.int32),
                2: rng.integers(0, 256, 7).astype(np.int32)}
     eng = _engine(model, p, decode_steps=1)
-    assert eng.kv_cache.window_pool.config.ring_pages == 4
+    assert eng.kv_cache.store("wkv").config.ring_pages == 4
     rows, slots = [], []
     pick, schedule = eng._pick_greedy, eng.scheduler.schedule
 
@@ -333,7 +334,7 @@ def test_engine_logits_match_the_reference_full_forward():
                                  rows[-1][slots[-1].index(uid)]))
             toks[uid].extend(new)
     stats = dict(eng.stats)
-    assert eng.kv_cache.window_pool.pages_in_use == 0      # all given back
+    assert eng.kv_cache.store("wkv").pages_in_use == 0     # all given back
     assert eng.kv_cache.free_blocks == 63
     eng.close()
     assert all(len(t) == 12 for t in toks.values())
@@ -434,15 +435,20 @@ def test_windowed_pool_page_bound_and_free(window, block, ring):
     assert cfg.pool_shape == (2, 2 * ring + 1, block, 1152)
     pool = WindowedLatentPool(cfg)
     assert pool.scratch_block == 2 * ring and pool.free_blocks == 2 * ring
-    blocks = np.empty(0, np.int64)
+    one, two, late = (SequenceDescriptor(uid, np.empty(0, np.int32))
+                      for uid in range(3))
+    for seq in (one, two, late):
+        pool.take(seq)
     for tokens in (1, block, block + 1, 3 * block, 50 * block, 24000):
-        blocks = pool.grow(blocks, tokens)
+        assert pool.grow(one, tokens)
+        blocks = one.held["wkv"]
         assert len(blocks) == min(-(-tokens // block), ring) <= ring
         assert pool.pages_in_use == len(blocks)
-    other = pool.grow(np.empty(0, np.int64), 10 ** 6)
+    assert pool.grow(two, 10 ** 6)
+    other = two.held["wkv"]
     assert len(other) == ring and pool.free_blocks == 0
     assert not set(other) & set(blocks)
-    assert pool.grow(np.empty(0, np.int64), 1) is None      # none left
+    assert not pool.grow(late, 1) and not len(late.held["wkv"])  # none left
     pool.free(blocks)
     pool.free(other)
     assert pool.free_blocks == 2 * ring and pool.pages_in_use == 0
@@ -455,28 +461,28 @@ def test_state_manager_grows_a_ring_and_gives_it_back():
 
     kv = BlockedKVCache(KVCacheConfig(
         num_layers=3, kv_heads=4, head_dim=48, block_size=8, num_blocks=32,
-        dtype=F32, kind="latent", latent_dim=144, index_key_dim=32))
-    kv.pools_as_dict = True
-    kv.window_pool = WindowedLatentPool(WindowPoolConfig(
-        layers=6, window=13, row_dim=144, block_size=8, num_blocks=6,
-        dtype=F32))
+        dtype=F32, kind="latent", latent_dim=144, index_key_dim=32),
+        stores=[WindowedLatentPool(WindowPoolConfig(
+            layers=6, window=13, row_dim=144, block_size=8, num_blocks=6,
+            dtype=F32))])
+    ring = kv.store("wkv")
     assert set(kv.kv_state) == {"kv", "ik", "wkv"}
     assert kv.kv_state["ik"].shape == (3, 32, 8, 32)
     assert kv.config.bytes_per_block == 3 * 8 * (256 + 32) * 4
     state = StateManager(kv, max_blocks_per_seq=16)
     seq = state.get_or_create(1, np.arange(100, dtype=np.int32))
     assert state.ensure_capacity(seq, 20)
-    assert (len(seq.kv_blocks), len(seq.window_blocks)) == (3, 3)
+    assert (len(seq.kv_blocks), len(seq.held["wkv"])) == (3, 3)
     assert state.ensure_capacity(seq, 100)
-    assert (len(seq.kv_blocks), len(seq.window_blocks)) == (13, 4)
+    assert (len(seq.kv_blocks), len(seq.held["wkv"])) == (13, 4)
     other = state.get_or_create(2, np.arange(9, dtype=np.int32))
     assert state.ensure_capacity(other, 8)
     assert not state.ensure_capacity(other, 9)     # the windowed pool is out
     state.release(1)
-    assert kv.window_pool.pages_in_use == 1 and kv.free_blocks == 31
+    assert ring.pages_in_use == 1 and kv.free_blocks == 31
     assert state.ensure_capacity(other, 9)
     state.release(2)
-    assert kv.window_pool.pages_in_use == 0 and kv.free_blocks == 32
+    assert ring.pages_in_use == 0 and kv.free_blocks == 32
     with pytest.raises(ValueError, match="beside a latent pool"):
         KVCacheConfig(num_layers=1, kv_heads=1, head_dim=8, index_key_dim=8)
 

@@ -107,10 +107,12 @@ def seeded_pool(cache, dtype):
     data, scales = mr._kv_parts(cache.kv_state)
     key = jax.random.PRNGKey(7)
     if scales is None:
-        return jax.random.normal(key, data.shape, jnp.float32).astype(dtype)
+        return {"kv": jax.random.normal(key, data.shape,
+                                        jnp.float32).astype(dtype)}
     payload = jax.random.randint(key, data.shape, -127, 128, jnp.int32)
-    return (payload.astype(data.dtype),
-            jax.random.uniform(key, scales.shape, jnp.float32, 1e-3, 2e-2))
+    return {"kv": payload.astype(data.dtype),
+            "scales": jax.random.uniform(key, scales.shape, jnp.float32,
+                                         1e-3, 2e-2)}
 
 
 def dense(kv_state):
@@ -159,10 +161,10 @@ def test_float32_agrees_to_rounding_and_falcon_style_blocks_too(heads):
     want, want_kv = jax.jit(repeat_forward, static_argnums=0)(
         cfg, params, kv0, *step)
     got, got_kv = engine_v2._shared_step_fns(cfg, None)["step"](
-        params, jnp.copy(kv0), *step)
+        params, jax.tree.map(jnp.copy, kv0), *step)
     agree(got, want, step[-1], 1e-5)
-    np.testing.assert_allclose(np.asarray(got_kv)[:, :NB - 1],
-                               np.asarray(want_kv)[:, :NB - 1], atol=1e-5)
+    np.testing.assert_allclose(dense(got_kv)[:, :NB - 1],
+                               dense(want_kv)[:, :NB - 1], atol=1e-5)
 
 
 def test_grouped_heads_under_a_tp_mesh(devices):
@@ -185,12 +187,12 @@ def test_grouped_heads_under_a_tp_mesh(devices):
             kv_blocks=NB, kv_block_size=BS, max_tokens_per_step=T,
             max_seqs_per_step=S, max_blocks_per_seq=BM)
         kv = eng.kv_cache.kv_state
-        assert kv.shape[1] == NB
+        assert kv["kv"].shape[1] == NB
         with eng.mesh:
             logits, kv = eng._step_fn(eng.params, kv, *step)
         logits = np.asarray(logits)
         eng.close()
-        return logits, np.asarray(kv)
+        return logits, np.asarray(kv["kv"])
 
     one, one_kv = run(None)
     two, two_kv = run(build_mesh(TopologyConfig(dp=4, tp=2)))
@@ -198,7 +200,8 @@ def test_grouped_heads_under_a_tp_mesh(devices):
     np.testing.assert_allclose(two_kv[:, :NB - 1], one_kv[:, :NB - 1],
                                atol=1e-5)
     want, _ = jax.jit(repeat_forward, static_argnums=0)(
-        model.config, params, jnp.zeros(one_kv.shape, jnp.float32), *step)
+        model.config, params, {"kv": jnp.zeros(one_kv.shape, jnp.float32)},
+        *step)
     agree(one, want, step[-1], 1e-5)
 
 

@@ -106,7 +106,7 @@ def test_preemption_by_recompute_frees_the_slot_and_restarts_from_zero(model):
         for uid, toks in eng.serve_step().items():
             got[uid] += toks
     victim = eng.state.seqs[2]
-    slot = victim.state_slot
+    slot = victim.held["state"]
     eng._requeue(victim)                      # what a starved pool does
     assert 2 not in eng.state.seqs
     assert eng.kv_cache.state_pool.free_slots == 3 and slot >= 0

@@ -269,10 +269,10 @@ def test_pool_after_steps_is_a_plain_scatter_of_the_served_rows(
 
     def dense(state):
         if quant_bits is None:
-            return np.array(state, np.float32)
-        return np.array(kv_dequantize(kv_unpack(jnp.asarray(state[0]),
+            return np.array(state["kv"], np.float32)
+        return np.array(kv_dequantize(kv_unpack(jnp.asarray(state["kv"]),
                                                   quant_bits),
-                                        jnp.asarray(state[1]),
+                                        jnp.asarray(state["scales"]),
                                         dtype=jnp.float32))
 
     got = jax.tree.map(np.array, kvc.kv_state)

@@ -13,7 +13,7 @@ import pytest
 
 import deepspeed_tpu as dstpu
 from deepspeed_tpu.inference import engine_v2
-from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+from deepspeed_tpu.inference.hybrid_runner import COUNTERS
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.models.zoo import get_model
@@ -43,7 +43,8 @@ def serve_lowered():
     model = get_model("tiny")
     cfg = model.config
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    kv = _sds((cfg.num_layers, NB, BS, 2, cfg.kv_heads, cfg.head_dim))
+    kv = {"kv": _sds((cfg.num_layers, NB, BS, 2, cfg.kv_heads,
+                      cfg.head_dim))}
     fns = engine_v2._shared_step_fns(cfg, None)
     ids = lambda *shape: _sds(shape, I32)  # noqa: E731
     return {

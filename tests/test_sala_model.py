@@ -329,7 +329,7 @@ def served(tiny):
             out.setdefault(uid, []).extend(toks)
         kc = eng.kv_cache
         during.append((kc.allocator.total_blocks - kc.free_blocks,
-                       kc.compressed_keys_in_use))
+                       kc.in_use()["compressed_keys_in_use"]))
     return eng, out, during
 
 
@@ -370,7 +370,7 @@ def test_compressed_keys_are_held_and_freed_with_their_pages(served):
     assert max(c for _, c in during) > 0
     assert all(c == held * 4 for held, c in during)
     kc = eng.kv_cache
-    assert kc.compressed_keys_in_use == 0
+    assert kc.in_use()["compressed_keys_in_use"] == 0
     assert kc.free_blocks == kc.allocator.total_blocks
     assert eng.kv_cache.state_pool.slots_in_use == 0
     assert eng.stats["compressed_keys_in_use"] == during[-2][1]

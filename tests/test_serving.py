@@ -20,7 +20,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.ragged import BlockedAllocator, PrefixCache
+from deepspeed_tpu.inference.ragged import (BlockedAllocator, BlockedKVCache,
+                                            PrefixCache)
 from deepspeed_tpu.inference.ragged.sequence import StateManager
 from deepspeed_tpu.inference.scheduler import SplitFuseScheduler
 from deepspeed_tpu.inference.spec_decode import Drafter, PromptLookupDrafter
@@ -104,26 +105,23 @@ class TestPrefixCache:
 # -- scheduler fairness / starvation grid --------------------------------
 
 
-class _FakeKV:
-    """StateManager's kv_cache surface without device memory."""
+class _FakeKV(BlockedKVCache):
+    """StateManager's kv_cache surface without device memory: the pages'
+    host side alone."""
+
+    unsupported = frozenset()
 
     def __init__(self, blocks, block_size=8):
         self.allocator = BlockedAllocator(blocks)
         self.block_size = block_size
-        self.prefix_cache = None
+        self.prefix_cache = self.host_tier = None
+        self.stores = [self]
 
     def blocks_needed(self, n):
         return -(-n // self.block_size)
 
-    @property
-    def free_blocks(self):
-        return self.allocator.free_blocks
-
     def reclaim(self, n):
         return 0
-
-    def free(self, blocks):
-        self.allocator.free(blocks)
 
 
 class TestSchedulerFairness:

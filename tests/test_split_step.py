@@ -23,7 +23,7 @@ import pytest
 from benchmarks.runners.serve import LogitsTap
 from deepspeed_tpu.inference import engine_v2
 from deepspeed_tpu.inference.engine_v2 import PROGRAMS
-from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+from deepspeed_tpu.inference.hybrid_runner import COUNTERS
 from deepspeed_tpu.models.zoo import get_model
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -338,7 +338,7 @@ def _pools(eng):
     """Each live sequence's recurrent state and convolution tail, by uid."""
     pool = eng.kv_cache.state_pool
     state, conv = np.array(pool.state), np.array(pool.conv)
-    return {uid: (state[:, s.state_slot], conv[:, s.state_slot])
+    return {uid: (state[:, s.held["state"]], conv[:, s.held["state"]])
             for uid, s in eng.state.seqs.items()}
 
 

@@ -261,8 +261,8 @@ def test_decode_programs_keep_the_pool_in_place(chip, program):
     pool_bytes = 2 * kv.size
     steps = {"steps": 8} if program == "multi_decode" else {}
     compiled = fns[program].lower(
-        params, kv, ids(SEQS), ids(SEQS), ids(SEQS, PAGES), ids(SEQS),
-        **steps).compile()
+        params, {"kv": kv}, ids(SEQS), ids(SEQS), ids(SEQS, PAGES),
+        ids(SEQS), **steps).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -286,8 +286,8 @@ def test_gather_program_reads_each_kv_head_once(chip):
     times.)"""
     fns, params, kv, ids = _serve_c1_two_layers(chip)
     compiled = fns["step"].lower(
-        params, kv, ids(TOKENS), ids(TOKENS), ids(TOKENS), ids(SEQS, PAGES),
-        ids()).compile()
+        params, {"kv": kv}, ids(TOKENS), ids(TOKENS), ids(TOKENS),
+        ids(SEQS, PAGES), ids()).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * kv.size
     assert mem.temp_size_in_bytes < 2**30
@@ -313,7 +313,7 @@ def test_chunk_program_reads_a_sequence_context_once(chip, segs, tq):
     31 MiB of float32 logits that the program hands out)."""
     fns, params, kv, ids = _serve_c1_two_layers(chip)
     compiled = fns["prefill"].lower(
-        params, kv, ids(segs, tq), ids(segs), ids(segs),
+        params, {"kv": kv}, ids(segs, tq), ids(segs), ids(segs),
         ids(segs, PAGES)).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * kv.size
@@ -543,7 +543,7 @@ def _sala_c1(chip):
     """``minicpm-sala-serve-c1`` as abstract arguments on the described chip.
     Returns (step programs, serving params, pools, ids, bytes of the pools)."""
     from deepspeed_tpu.inference import engine_v2
-    from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+    from deepspeed_tpu.inference.hybrid_runner import COUNTERS
     from deepspeed_tpu.models import hybrid
     from deepspeed_tpu.models.zoo import get_model
 
@@ -606,7 +606,7 @@ def _kimi_c1(chip):
     bytes)."""
     from deepspeed_tpu.inference import engine_v2
     from deepspeed_tpu.inference.ragged.kv_cache import KVCacheConfig
-    from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+    from deepspeed_tpu.inference.hybrid_runner import COUNTERS
     from deepspeed_tpu.models import hybrid
     from deepspeed_tpu.models.zoo import get_model
 
@@ -672,7 +672,7 @@ def _dots3_c1(chip):
     from deepspeed_tpu.inference import engine_v2
     from deepspeed_tpu.inference.ragged.kv_cache import (KVCacheConfig,
                                                          WindowPoolConfig)
-    from deepspeed_tpu.inference.ragged.state_pool import COUNTERS
+    from deepspeed_tpu.inference.hybrid_runner import COUNTERS
     from deepspeed_tpu.models import hybrid
     from deepspeed_tpu.models.zoo import get_model
 
