@@ -37,9 +37,10 @@ do not tell the models apart. What differs:
   queries and keys, folds the new keys into their pages' rows
   (``msa_pool_write``), scores the sequence's pooled keys and chooses
   (``msa_index``), then attends (``msa_attn``): a token step through the paged
-  decode kernel over the pages each (sequence, KV head) chose, a chunk over
-  its own sequence's pages under the block mask, as the first rule's; the
-  gather program is not built;
+  decode kernel over the pages each (sequence, KV head) chose, a chunk through
+  the block-masked chunk kernel (``paged_block_prefill``: a tile of queries
+  over the pool's pages that one of its queries chose); the gather program
+  is not built;
 * with latent attention (``attention_kind`` "mla") the paged pool is a latent
   pool (one vector a token) and there is no recurrent layer, state pool or
   ``state_slots``: a token step writes the token's latent and runs the
@@ -111,9 +112,12 @@ DSA_COUNTERS = ("dsa_rows_selected", "dsa_rows_visible")
 WINDOW_COUNTERS = ("window_rows_read", "window_pages_recycled")
 # the learned block selector's: over the real (query, KV head) pairs the
 # blocks chosen and the blocks visible, and the queries whose context is no
-# more than the rule reads anyway
+# more than the rule reads anyway; then, over a chunk's (tile of queries, KV
+# head) pairs, the blocks the chunk kernel visited (one of the tile's queries
+# chose them) and the blocks the tile's last query sees
 MSA_COUNTERS = ("msa_blocks_chosen", "msa_blocks_visible",
-                "msa_dense_queries")
+                "msa_dense_queries", "msa_tile_blocks_visited",
+                "msa_tile_blocks_visible")
 COUNTERS = (MOE_COUNTERS + SPARSE_COUNTERS + MLA_COUNTERS + DSA_COUNTERS
             + WINDOW_COUNTERS + MSA_COUNTERS)
 # those ``stats`` also keeps for the two decode programs alone (``_decode``),
@@ -395,15 +399,18 @@ def _sparse_prefill(cfg, q, kv, ck, l_kv, block_table, pos, real, ctx_lens):
     return a.reshape(q.shape), jnp.sum(counts, axis=0)
 
 
-def _msa_counts(pools, sz, mask, visible, real):
+def _msa_counts(pools, sz, mask, visible, real, tiles=None):
     """``pools`` with the learned selector's counters added: mask [..., nkv,
     B] the blocks each real query's KV heads read, visible [...] the blocks
-    it sees."""
+    it sees; ``tiles`` (a chunk's) the lists the kernel walked: the blocks
+    each (KV head, tile) visited and the blocks each tile's last query sees."""
     nkv = mask.shape[-2]
+    walked, in_sight = (0, 0) if tiles is None else tiles
     counts = jnp.stack([
         jnp.sum(jnp.where(real[..., None, None], mask, False)),
         jnp.sum(jnp.where(real, visible, 0)) * nkv,
-        jnp.sum(real & (visible <= sz.width))])
+        jnp.sum(real & (visible <= sz.width)),
+        jnp.sum(walked), jnp.sum(in_sight) * nkv])
     return dict(pools, counters=pools["counters"].at[_MSA:].add(
         counts.astype(jnp.int32)))
 
@@ -469,16 +476,30 @@ def _msa_pool_chunk(sz, pk, l, ki, block_table, pos, real, seg_pos0):
     return pk.at[l, page].set(new)
 
 
+@jax.jit
+def _msa_chunk_attention(q, kv, block_table, mask, seg_pos0, ctx_lens, l):
+    """The block-masked chunk kernel over the pool, as one function of the
+    step program: the dense layer's call and the scanned layers' share one
+    trace and one lowering of the kernel (1.6 s each on a serving host, at
+    every start of every prefill program). Its operations carry ``msa_attn``
+    in their own name stack, which a called function does not inherit."""
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        block_prefill_attention
+
+    with jax.named_scope("msa_attn"):
+        return block_prefill_attention(q, kv, block_table, mask, seg_pos0,
+                                       ctx_lens, layer=l)
+
+
 def _msa_prefill(cfg, ap, y, q, pools, l, block_table, pos, real, seg_pos0,
                  ctx_lens):
-    """Chunk attention under the learned selector's block mask, one segment
-    after another, each over its own sequence's pages and pooled keys (this
-    chunk's keys, values and pooled keys are written already). q [S, Tq,
-    nq, d]; pos, real [S, Tq]. Returns (attention, pools')."""
+    """Chunk attention under the learned selector's block mask: the pooled
+    keys of each segment's own sequence scored and the blocks chosen, then
+    the chunk kernel over the pool's pages, a tile of queries over the
+    blocks one of them chose (this chunk's keys, values and pooled keys are
+    written already). q [S, Tq, nq, d]; pos, real [S, Tq]. Returns
+    (attention, pools')."""
     sz = cfg.msa
-    S, Tq = pos.shape
-    nkv, d = cfg.kv_heads, cfg.head_dim
-    kv = pools["kv"]
     with jax.named_scope("msa_index"):
         qi, ki = hybrid.msa_project(ap, y)
         with jax.named_scope("msa_pool_write"):
@@ -487,18 +508,10 @@ def _msa_prefill(cfg, ap, y, q, pools, l, block_table, pos, real, seg_pos0,
         mask, visible = jax.vmap(
             lambda qs, p, t: block_sparse.msa_select(sz, qs, p, t))(
                 qi, pk[l, block_table], pos)            # [S, Tq, nkv, Bm]
-
-    def segment(args):
-        qs, table, ts, n, mk = args
-        keys, values = _sequence_pages(kv, l, table, nkv, d)
-        return block_sparse.blocked_attention(
-            qs, keys, values, mk, ts, n, 1.0 / math.sqrt(d), sz.block)
-
     with jax.named_scope("msa_attn"):
-        a = lax.map(segment, (q.reshape(S, Tq, nkv, -1, d), block_table, pos,
-                              ctx_lens, mask))
-    return a.reshape(q.shape), _msa_counts(dict(pools, pk=pk), sz, mask,
-                                           visible, real)
+        a, *tiles = _msa_chunk_attention(q, pools["kv"], block_table, mask,
+                                         seg_pos0, ctx_lens, l)
+    return a, _msa_counts(dict(pools, pk=pk), sz, mask, visible, real, tiles)
 
 
 def _scratch(pools, alive, state_slots):

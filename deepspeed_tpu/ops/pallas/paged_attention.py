@@ -810,3 +810,324 @@ def index_scores_decode(q: jax.Array, w: jax.Array, ik: jax.Array,
       jnp.minimum(context_lens.astype(jnp.int32), Bm * bs),
       jnp.asarray(layer, jnp.int32).reshape(1), q, w8, ik)
     return out.reshape(S, nblk * T)[:, :Bm * bs]
+
+
+# ---------------------------------------------------------------------------
+# chunked prefill under a block mask: a tile of queries over the pages it chose
+# ---------------------------------------------------------------------------
+
+# The block-masked chunk kernel's tile and fold, measured on a v5e at the
+# learned selector's shapes (16 heads a KV group of 128, pages of 128 tokens
+# and 4 KV heads, 256 KB; PERF.md section 6, PR 50). A tile holds
+# ``_TILE_ROWS`` rows (queries x the heads of a KV group): its score tile
+# fills the MXU's rows whatever the page. A fold of the running softmax takes
+# ``_FOLD_KEYS`` keys (whole pages): every per-row term of the softmax
+# (maximum, rescale, denominator) costs a pass as wide as 128 keys' scores,
+# so a fold of one page read 3.8 us a page, of four 1.9, of eight 1.33 and of
+# sixteen no less (and a short list pays for the pages its last fold lacks).
+# One product takes ``_FOLD_ROWS`` of the tile's rows (512 reads 3% slower).
+# Two folds of pages, the head's keys and values, the score tile and the
+# softmax state want more than the 16 MiB a kernel is given unasked.
+_TILE_ROWS = 2048
+_FOLD_ROWS = 1024
+_FOLD_KEYS = 1024
+_CHUNK_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def chunk_tile(tq_all: int, g: int, bs: int) -> int:
+    """Queries a tile of the block-masked chunk kernel holds, from the
+    shapes alone: no more than a page (a tile that starts on a page's border
+    shares the blocks every query reads: the first, its own, the one before),
+    no more than ``_TILE_ROWS`` rows with the ``g`` heads of a KV group, no
+    more than the chunk."""
+    return max(1, min(bs, _TILE_ROWS // g, tq_all))
+
+
+def tile_visits(mask: jax.Array, seg_pos0: jax.Array, context_lens: jax.Array,
+                tq: int, bs: int):
+    """The blocks each tile of ``tq`` queries visits under ``mask`` [S, Tq,
+    nkv, Bm] (``Tq`` whole tiles): a block some real query of the tile chose
+    and that holds a key (it starts below the context).
+
+    Returns ``(live, blocks, count, visible)``: ``live`` the mask held to the
+    real queries and the blocks that exist; ``blocks`` [S, nkv, tiles, Bm]
+    int32 ascending, the first ``count`` [S, nkv, tiles] of them real (the
+    rest ``Bm``); ``visible`` [S, tiles] the blocks the tile's last real query
+    sees (0 for a tile with none)."""
+    S, Tq, nkv, Bm = mask.shape
+    nt = Tq // tq
+    ctx = context_lens.astype(jnp.int32)[:, None]
+    pos = seg_pos0.astype(jnp.int32)[:, None] + jnp.arange(Tq)[None, :]
+    blk = jnp.arange(Bm, dtype=jnp.int32)
+    live = (mask & (pos < ctx)[:, :, None, None]
+            & (blk[None, :] * bs < ctx)[:, None, None, :])
+    hit = jnp.any(live.reshape(S, nt, tq, nkv, Bm), axis=2).transpose(
+        0, 2, 1, 3)                                         # [S, nkv, nt, Bm]
+    blocks = jnp.sort(jnp.where(hit, blk, Bm), axis=-1)
+    count = jnp.sum(hit, axis=-1).astype(jnp.int32)
+    first = pos[:, ::tq]                                           # [S, nt]
+    last = jnp.minimum(first + tq, ctx) - 1
+    visible = jnp.where(first < ctx, last // bs + 1, 0)
+    return live, blocks, count, visible
+
+
+def _block_prefill_kernel(blocks_ref, count_ref, bt_ref, pos0_ref, layer_ref,
+                          q_ref, mask_ref, kv_hbm, out_ref, buf, sem,
+                          slot_ref, ks_ref, vs_ref, bias_ref, m_ref, l_ref,
+                          acc_ref, *, bs: int, nb: int, nkv: int, tq: int,
+                          g: int, gc: int, pages: int, scale: float):
+    """One tile of ``tq`` queries of one KV head a grid step, over the pages
+    its visit list names (``blocks_ref``, ``count_ref``: flat, a grid step's
+    entries after the one before it), ``pages`` of them a fold. Pages come
+    from the pool in HBM through the block table, as they lie (``kv_hbm[layer,
+    page]`` is ``[bs * 2 * nkv, d]``: a row a (token, plane, head)), the next
+    fold's (or the next grid step's first) in flight while this one is
+    multiplied. The KV head's rows are cut out of a page by strided loads of
+    32-bit words (a 16-bit pool packs two rows a word) into ``ks_ref`` /
+    ``vs_ref``. Rows are group-major, query-minor (``[g, tq, d]``), so the
+    mask's term -- the query chose the block, and the key is not after it --
+    is one additive ``[tq, keys]`` tile for all ``g`` heads (``bias_ref``). A
+    fold's scores are computed once and folded into the running softmax in
+    VMEM, ``gc`` heads a product."""
+    s, h, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nt = pl.num_programs(2)
+    Bm = bt_ref.shape[1]
+    width = -(-Bm // pages) * pages     # a grid step's entries: whole folds
+    step = (s * nkv + h) * nt + t
+    nsteps = pl.num_programs(0) * nkv * nt
+    layer = layer_ref[0]
+    d = q_ref.shape[-1]
+    dt = jnp.promote_types(q_ref.dtype, buf.dtype)     # what the MXU takes
+    pack = 4 // buf.dtype.itemsize      # rows of the pool a 32-bit word holds
+    words = buf.bitcast(jnp.uint32) if pack > 1 else buf
+
+    def copies(stp, fold, slot, fn):
+        """Apply ``fn`` to the copy of every page of fold ``fold`` of grid
+        step ``stp`` (``count_ref[stp]`` pages in all) into ``buf[slot]``."""
+        live = jnp.clip(count_ref[stp] - fold * pages, 0, pages)
+
+        def page_copy(i, carry):
+            blk = jax.lax.min(blocks_ref[stp * width + fold * pages + i],
+                              Bm - 1)
+            page = bt_ref[stp // (nkv * nt), blk]
+            page = jax.lax.min(jax.lax.max(page, 0), nb - 1)
+            fn(pltpu.make_async_copy(kv_hbm.at[layer, page], buf.at[slot, i],
+                                     sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, live, page_copy, 0)
+
+    def start(stp, fold, slot):
+        copies(stp, fold, slot, lambda dma: dma.start())
+
+    n = count_ref[step]
+    nfolds = (n + pages - 1) // pages
+    nxt = jax.lax.min(step + 1, nsteps - 1)
+    follows = step + 1 < nsteps      # (a last or empty one's copies: none)
+
+    @pl.when(step == 0)
+    def _first():
+        # rows a fetch never lands on still meet a zero weight in the value
+        # product: they have to be finite
+        ks_ref[...] = jnp.zeros_like(ks_ref)
+        vs_ref[...] = jnp.zeros_like(vs_ref)
+        slot_ref[0] = 0
+        start(step, 0, 0)
+
+    slot0 = slot_ref[0]
+    slot_ref[0] = (slot0 + nfolds) % 2  # where the next grid step starts
+
+    @pl.when(jnp.logical_and(nfolds == 0, follows))
+    def _dead():
+        start(nxt, 0, slot0)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    qpos = (pos0_ref[s] + t * tq
+            + jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 0))
+    key = jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tq, 128), 1)
+
+    def head_rows(slot, i, plane, head):
+        """``[bs, d]``: the rows of (plane, head) of page ``i`` of
+        ``buf[slot]``, every token's: row ``plane * nkv + head`` of the ``2 *
+        nkv`` a token has, ``pack`` of them a word. (The head is traced: a
+        strided load takes a traced start, where an index on the pool's
+        tiled head dim would have to be static.)"""
+        e = plane * nkv + head
+        x = words[slot, i, pl.ds(e // pack, bs, stride=2 * nkv // pack), :]
+        if pack == 1:
+            return x.astype(dt)
+        # the word's low half is the even row; a bfloat16 is the high half of
+        # the float32 of the same value
+        x = (x >> (16 * (e % pack)).astype(jnp.uint32)) << 16
+        return jax.lax.bitcast_convert_type(x, jnp.float32).astype(dt)
+
+    def fold(f, carry):
+        slot = (slot0 + f) % 2
+        last = f + 1 == nfolds
+
+        # the next fold, or the next grid step's first, flies meanwhile
+        @pl.when(jnp.logical_or(jnp.logical_not(last), follows))
+        def _ahead():
+            start(jnp.where(last, nxt, step), jnp.where(last, 0, f + 1),
+                  1 - slot)
+
+        copies(step, f, slot, lambda dma: dma.wait())
+        for i in range(pages):                              # static
+            j = f * pages + i
+            blk = jax.lax.min(blocks_ref[step * width + j], Bm - 1)
+            # the tile's column of the mask: who chose this block
+            chose = jnp.max(jnp.where(lane == blk % 128,
+                                      mask_ref[0, 0, 0, blk // 128], 0),
+                            axis=1, keepdims=True) > 0            # [tq, 1]
+            seen = jnp.logical_and(
+                jnp.logical_and(chose, j < n), blk * bs + key <= qpos)
+            bias_ref[:, i * bs:(i + 1) * bs] = jnp.where(seen, 0.0, NEG_INF)
+
+            # (each page's cut beside its column of the mask: all pages'
+            # cuts in one block read a fifth slower)
+            @pl.when(j < n)
+            def _cut(i=i):
+                ks_ref[i * bs:(i + 1) * bs] = head_rows(slot, i, 0, h)
+                vs_ref[i * bs:(i + 1) * bs] = head_rows(slot, i, 1, h)
+
+        k, v, bias = ks_ref[...], vs_ref[...], bias_ref[...][None]
+
+        # static: gc heads a product, the products side by side in one block
+        # so that one's softmax runs under the other's products (looped, the
+        # kernel read a quarter slower)
+        for g0 in range(0, g, gc):
+            heads = slice(g0, g0 + gc)
+            q = q_ref[0, 0, heads].reshape(gc * tq, d).astype(dt)
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            sc = sc.reshape(gc, tq, pages * bs) + bias
+            m_prev = m_ref[heads]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a masked score is NEG_INF to float32 rounding; against a
+            # maximum held above NEG_INF / 2 its weight is an exact 0, also
+            # in a row that has seen no key yet
+            p = jnp.exp(sc - jnp.maximum(m_new, 0.5 * NEG_INF))
+            l_ref[heads] = alpha * l_ref[heads] + jnp.sum(p, axis=-1,
+                                                          keepdims=True)
+            pv = jax.lax.dot_general(
+                p.reshape(gc * tq, pages * bs).astype(dt), v,
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            acc_ref[heads] = acc_ref[heads] * alpha + pv.reshape(gc, tq, d)
+            m_ref[heads] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, nfolds, fold, 0)
+    l = l_ref[...]
+    l = jnp.where(l == 0.0, 1.0, l)       # dead and padded rows, empty tiles
+    out_ref[0, 0] = (acc_ref[...] / l).astype(out_ref.dtype)
+
+
+def block_prefill_attention(q: jax.Array, kv: jax.Array,
+                            block_table: jax.Array, mask: jax.Array,
+                            seg_pos0: jax.Array, context_lens: jax.Array, *,
+                            layer=None, scale: float = None):
+    """Chunked-prefill attention over paged KV under a block mask: a query
+    attends, causally, over the keys of the blocks it chose. A block is a
+    page of the pool.
+
+    q            [S, Tq, num_heads, head_dim]: each segment one sequence's
+                 chunk, queries at positions ``seg_pos0 ..``, already written
+                 to the pool
+    kv           [L, num_blocks, block_size, 2, kv_heads, head_dim], float32
+                 or bfloat16, read at ``layer``; or one layer's 5-D pool with
+                 ``layer`` left out
+    block_table  [S, max_pages]
+    mask         [S, Tq, kv_heads, max_pages] bool: the blocks each (query,
+                 KV head) reads (a selector's choice; all its group's heads
+                 read the same)
+    seg_pos0     [S]; context_lens [S] = pos0 + real tokens, 0: a dead segment
+
+    A tile of queries (:func:`chunk_tile`) visits the blocks one of its real
+    queries chose (:func:`tile_visits`) and no other; inside a visited block
+    a query that did not choose it is masked, so the result is each query's
+    own. Operands enter the MXU in the pool's type; scores, running maximum,
+    denominator and accumulator are float32. Rows at or past the context
+    (padding, dead segments) give zeros.
+
+    Returns ``(out [S, Tq, num_heads, head_dim] in q.dtype, visited [S,
+    kv_heads, tiles] int32, visible [S, tiles] int32)``: the blocks each
+    tile visited and the blocks its last real query sees."""
+    S, Tq, nh, d = q.shape
+    kv, layer = _pool_and_layer(kv, layer)
+    L, nb, bs, _, nkv, _ = kv.shape
+    Bm = block_table.shape[1]
+    if nh % nkv:
+        raise ValueError(f"num_heads {nh} not a multiple of kv_heads {nkv}")
+    if mask.shape != (S, Tq, nkv, Bm):
+        raise ValueError(f"mask {mask.shape} for queries {q.shape} over "
+                         f"{nkv} KV heads and a table of {Bm} pages")
+    if kv.dtype not in (jnp.float32, jnp.bfloat16):
+        raise NotImplementedError(
+            f"the chunk kernel cuts a KV head's rows out of a page of "
+            f"float32 or bfloat16, not {kv.dtype}")
+    g = nh // nkv
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    tq = chunk_tile(Tq, g, bs)
+    nt = -(-Tq // tq)
+    if nt * tq != Tq:                   # whole tiles: padded rows choose none
+        q = jnp.pad(q, ((0, 0), (0, nt * tq - Tq), (0, 0), (0, 0)))
+        mask = jnp.pad(mask, ((0, 0), (0, nt * tq - Tq), (0, 0), (0, 0)))
+    live, blocks, count, visible = tile_visits(mask, seg_pos0, context_lens,
+                                               tq, bs)
+    P = max(1, min(_FOLD_KEYS // bs, Bm))       # pages a fold
+    blocks = jnp.pad(blocks, ((0, 0),) * 3 + ((0, -Bm % P),),
+                     constant_values=Bm)        # whole folds
+    C = -(-Bm // 128)
+    # the mask a tile at a time, a block a lane: [S, nkv, nt, C, tq, 128]
+    tiles = jnp.pad(live, ((0, 0),) * 3 + ((0, C * 128 - Bm),)).reshape(
+        S, nt, tq, nkv, C, 128).transpose(0, 3, 1, 4, 2, 5).astype(jnp.int32)
+    # rows group-major, query-minor: [S, nkv, g, Tq, d]
+    qg = q.reshape(S, nt * tq, nkv, g, d).transpose(0, 2, 3, 1, 4)
+    gc = max(1, min(g, _FOLD_ROWS // tq))
+    while g % gc:
+        gc -= 1
+
+    def rows_of(s, h, t, *_):
+        return (s, h, 0, t, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_block_prefill_kernel, bs=bs, nb=nb, nkv=nkv, tq=tq,
+                          g=g, gc=gc, pages=P, scale=float(scale)),
+        name="paged_block_prefill",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(S, nkv, nt),
+            in_specs=[pl.BlockSpec((1, 1, g, tq, d), rows_of),
+                      pl.BlockSpec((1, 1, 1, C, tq, 128),
+                                   lambda s, h, t, *_: (s, h, t, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, g, tq, d), rows_of),
+            scratch_shapes=[
+                pltpu.VMEM((2, P, bs * 2 * nkv, d), kv.dtype),  # two folds
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),    # buffer of the next fold
+                pltpu.VMEM((P * bs, d), kv.dtype),      # the head's keys
+                pltpu.VMEM((P * bs, d), kv.dtype),      # and values
+                pltpu.VMEM((tq, P * bs), jnp.float32),  # the mask, additive
+                pltpu.VMEM((g, tq, 1), jnp.float32),    # running max
+                pltpu.VMEM((g, tq, 1), jnp.float32),    # running denom
+                pltpu.VMEM((g, tq, d), jnp.float32),    # weighted values
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, nkv, g, nt * tq, d), q.dtype),
+        # a grid step starts the next one's first fetch: in order, one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_CHUNK_VMEM_BYTES),
+        interpret=_interpret(),
+    )(blocks.reshape(-1), count.reshape(-1), block_table.astype(jnp.int32),
+      seg_pos0.astype(jnp.int32), layer, qg, tiles,
+      # a page as it lies: the pool's bytes, a (token, plane, head) a row
+      kv.reshape(L, nb, bs * 2 * nkv, d))
+    out = out.transpose(0, 3, 1, 2, 4).reshape(S, nt * tq, nh, d)
+    return out[:, :Tq], count, visible

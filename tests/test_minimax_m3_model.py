@@ -260,14 +260,15 @@ def test_full_forward_selects_the_references_blocks_and_gives_its_logits():
 def served():
     """Two prompts through the engine: chunks of at most 20 tokens (they end
     inside blocks of 8), single token steps, then bursts; the rows the engine
-    sampled from, and the counters after the prompts and at the end."""
+    sampled from, the counters after the prompts and at the end, and the
+    chunks as they were scheduled, ``(uid, first position, tokens)``."""
     model = _model()
     p = _params(model)
     rng = np.random.default_rng(2)
     prompts = {1: rng.integers(0, 256, 77).astype(np.int32),
                2: rng.integers(0, 256, 13).astype(np.int32)}
     eng = _engine(model, p, decode_steps=1)
-    rows, slots = [], []
+    rows, slots, chunks = [], [], []
     pick, schedule = eng._pick_greedy, eng.scheduler.schedule
 
     def tap(lg, idx):
@@ -277,6 +278,8 @@ def served():
     def scheduled():
         out = schedule()
         slots.append([seq.uid for seq, _, _ in out])
+        chunks.extend((seq.uid, int(start), len(new))
+                      for seq, new, start in out if len(new) > 1)
         return out
 
     eng._pick_greedy, eng.scheduler.schedule = tap, scheduled
@@ -300,11 +303,11 @@ def served():
     stats = dict(eng.stats)
     free = eng.kv_cache.available_blocks    # (whole pages stay cached)
     eng.close()
-    return model, p, prompts, toks, got, after_prompts, stats, free
+    return model, p, prompts, toks, got, after_prompts, stats, free, chunks
 
 
 def test_engine_logits_match_the_reference_full_forward(served):
-    model, p, prompts, toks, got, _, stats, free = served
+    model, p, prompts, toks, got, _, stats, free, _ = served
     ref = _reference()
     a = _arch(ref, model.config)
     assert free == 63 and all(len(t) == 12 for t in toks.values())
@@ -335,7 +338,7 @@ def test_engine_counts_the_references_choice_in_chunk_and_token_steps(served):
     steps after them (blocks chosen and visible, queries that read
     everything) is what the reference chooses for the same queries: every
     position of both sequences but the last token's, in every layer."""
-    model, p, prompts, toks, _, after_prompts, stats, _ = served
+    model, p, prompts, toks, _, after_prompts, stats, _, _ = served
     ref = _reference()
     cfg = model.config
     a = _arch(ref, cfg)
@@ -360,6 +363,48 @@ def test_engine_counts_the_references_choice_in_chunk_and_token_steps(served):
     # both kinds of step chose: the prompts' chunks, then the token steps
     assert 0 < after_prompts["msa_blocks_chosen"] < stats["msa_blocks_chosen"]
     assert stats["msa_blocks_chosen"] < stats["msa_blocks_visible"]
+
+
+def test_engine_counts_the_blocks_a_tile_visits_as_the_reference_folds_them(
+        served):
+    """The chunk kernel's two counters over the prompts' chunks are the
+    reference's choice folded by tile: a tile is ``chunk_tile`` queries of a
+    chunk from its first position on (8 here, a block; chunks start and end
+    inside blocks, so tiles straddle them), it visits the union of what its
+    real queries chose, a KV head, and sees what its last real query sees.
+    Token steps count neither."""
+    from deepspeed_tpu.ops.pallas.paged_attention import chunk_tile
+
+    model, p, prompts, _, _, after_prompts, stats, _, chunks = served
+    ref = _reference()
+    cfg = model.config
+    a = _arch(ref, cfg)
+    bs, nkv = cfg.msa.block, cfg.kv_heads
+    assert max(s + n for u, s, n in chunks if u == 1) == 77
+    assert any(s % bs and (s + n) % bs for _, s, n in chunks)
+    want = np.zeros(2, int)
+    for uid, ids in prompts.items():
+        n = len(ids) + (-len(ids)) % 8
+        pos = jnp.arange(n)
+        xs, _ = _streams(ref, a, p, ids)
+        for l, x in enumerate(xs):
+            w = _layer_weights(p, l)
+            y = ref.rms_norm(x, w["input_layernorm"], a.rms_norm_eps)
+            _, _, ki = ref.leaves_behind(a, "float32", x, w, pos)
+            chosen = np.asarray(ref.chosen_blocks(a, ref.block_scores(
+                a, "float32", y, ref.pooled_keys(a, ki), w), pos))
+            for _, start, length in (c for c in chunks if c[0] == uid):
+                # (a chunk's bucket is a block or more: hybrid_runner.
+                # min_segment)
+                tq = chunk_tile(max(bs, length), cfg.num_heads // nkv, bs)
+                for first in range(start, start + length, tq):
+                    last = min(first + tq, start + length)
+                    want += [chosen[first:last].any(axis=0).sum(),
+                             ((last - 1) // bs + 1) * nkv]
+    names = ("msa_tile_blocks_visited", "msa_tile_blocks_visible")
+    assert [stats[k] for k in names] == list(want)
+    assert [after_prompts[k] for k in names] == list(want)
+    assert 0 < stats[names[0]] < stats[names[1]]
 
 
 def test_pooled_keys_are_the_maximum_from_scratch_after_every_step():

@@ -285,15 +285,15 @@ def test_selected_and_windowed_latent_programs_hold_their_scopes(program,
                 "attn/msa_attn", "dense_ffn", "moe/moe_route",
                 "moe/moe_shared"]),
     ("prefill", ["attn/msa_index", "attn/msa_index/msa_pool_write",
-                 "attn/msa_attn", "sparse_attn", "dense_ffn",
-                 "moe/moe_experts"])])
+                 "attn/msa_attn", "dense_ffn", "moe/moe_experts"])])
 def test_block_selecting_programs_hold_their_scopes(program, scopes):
     """The trace readers key on these paths (``msa_index_ms`` on
     ``msa_index``, the pooled keys' write inside it; ``msa_chunk_attn_ms``
     on ``msa_attn``; ``moe_chunk_ms`` on ``moe``). The dense layer runs
     before the scan and the expert layers are one scanned body: two paged
     decode kernels in a token step (over the pages each (sequence, KV head)
-    chose), whatever the depth, and no attention gate."""
+    chose) and two block-masked chunk kernels in a chunk call, each inside
+    ``msa_attn``, whatever the depth, and no attention gate."""
     model = get_model("tiny-m3")
     cfg = model.config
     params = jax.eval_shape(lambda p: hybrid.serving_params(cfg, p),
@@ -317,9 +317,19 @@ def test_block_selecting_programs_hold_their_scopes(program, scopes):
         assert path in text, path
     assert "attn_gate" not in text
     assert _module(traced.lower()) == f"jit_dstpu_serve_{program}"
+    jaxpr = str(traced.jaxpr)
     if program == "decode":
-        assert len(re.findall(r"\bname=paged_decode\b",
-                              str(traced.jaxpr))) == 2
+        assert len(re.findall(r"\bname=paged_decode\b", jaxpr)) == 2
+    else:
+        # one function of the program holds the chunk kernel, traced and
+        # lowered once and called by the dense layer and the scanned ones;
+        # its operations name the scope the trace readers sum themselves
+        assert len(re.findall(r"\bname=_msa_chunk_attention\b", jaxpr)) == 2
+        assert len(re.findall(r"\bname=paged_block_prefill\b", jaxpr)) == 1
+        assert len(re.findall(r"func.func private @_msa_chunk_attention",
+                              text)) == 1
+        assert re.search(r'"msa_attn/(\w+/)*paged_block_prefill', text)
+        assert "sparse_attn" not in text    # the plain form: the first rule's
 
 
 # -- training programs -------------------------------------------------------
@@ -443,6 +453,11 @@ KERNELS = [
     ("paged_prefill", paged_attention.paged_prefill_attention,
      (_sds((2, 8, 4, 64)), _POOL, _sds((2, 4), I32), _sds((2,), I32),
       _sds((2,), I32))),
+    ("paged_block_prefill",
+     lambda q, kv, bt, mask, pos0, ctx: paged_attention.block_prefill_attention(
+         q, kv, bt, mask, pos0, ctx)[0],
+     (_sds((2, 8, 4, 64)), _POOL, _sds((2, 4), I32),
+      _sds((2, 8, 2, 4), jnp.bool_), _sds((2,), I32), _sds((2,), I32))),
     ("grouped_matmul", _gmm,
      (_sds((256, 128)), _sds((2, 128, 128)), _sds((2,), I32))),
     ("grouped_matmul_dw", jax.grad(_gmm, argnums=(0, 1)),
