@@ -314,7 +314,7 @@ def route(y: jax.Array, router_w: jax.Array, cfg: GateConfig,
         precision=lax.Precision.HIGHEST))
     choose = score if bias is None else score + bias.astype(jnp.float32)
     idx = keep(lax.top_k(choose, cfg.top_k)[1].astype(jnp.int32))
-    top = jnp.take_along_axis(score, idx, axis=-1)
+    top = _chosen(score, idx)
     w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     return w * cfg.routed_scale, idx
 
@@ -393,26 +393,26 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
         m = ((m0 + 127) // 128) * 128
         local = jnp.where(here, idx - offset, held).reshape(-1)
         flat_w = jnp.where(here, w, 0.0).reshape(-1)
-        token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
         if m > m0:
             local = jnp.concatenate(
                 [local, jnp.full((m - m0,), held, local.dtype)])
             flat_w = jnp.concatenate([flat_w, jnp.zeros((m - m0,), w.dtype)])
-            token = jnp.concatenate([token, jnp.zeros((m - m0,), token.dtype)])
         order = jnp.argsort(local, stable=True)
-        row_token = token[order]
-        group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
-            jnp.int32)
+        # (a compare and a sum, and below a division: a bincount is a
+        # scatter-add and ``token[order]`` a gather of scalars, :func:`_chosen`)
+        group_sizes = jnp.sum(
+            local[:, None] == jnp.arange(held, dtype=local.dtype),
+            axis=0, dtype=jnp.int32)
         dropped = jnp.int32(0)
         if capacity is not None and capacity < m:
             if capacity % 128 or layer is not None:
                 raise ValueError(f"capacity={capacity}: a multiple of 128, "
                                  f"on the path without a layer index")
-            m, order, row_token = capacity, order[:capacity], \
-                row_token[:capacity]
+            m, order = capacity, order[:capacity]
             dropped = jnp.maximum(jnp.sum(group_sizes) - m, 0)
-        order, row_token, group_sizes = map(
-            keep, (order, row_token, group_sizes))
+        order, group_sizes = map(keep, (order, group_sizes))
+        # pair p is token p // k's, the padded tail token 0's
+        row_token = jnp.where(order < m0, order // k, 0)
         # one work list for the three products, which share the row tile (the
         # largest that divides m); the differentiable product makes its own
         work = None if layer is None else gm.make_group_metadata(
@@ -788,23 +788,45 @@ def moe_ffn_dropless(x: jax.Array, router_w: jax.Array,
 # routing's integers for ``jax.checkpoint``. A model that wraps an expert
 # layer hands it to ``checkpoint_wrapper(..., kept_names=(ROUTING_NAME,))``
 # (``models/hybrid.py::hidden_states``), and the layer's backward pass gets
-# the integers back instead of running ``lax.top_k``, the stable sort, the
-# gather of the sorted rows' tokens and the bincount a second time: 9.5 ms of
-# a 571 ms step of `train-trinity-ep8share-8k` (PERF.md section 6, PR 47).
+# the integers back instead of running ``lax.top_k``, the stable sort and the
+# count of the groups a second time (PERF.md section 6, PR 47).
 ROUTING_NAME = "moe_routing"
 
 
 def _keep_routing(ints: jax.Array) -> jax.Array:
     """Tag one of an expert layer's routing results as worth keeping across
     a checkpoint under every policy: the experts chosen ``idx [T, top_k]``
-    and, after the cut to ``capacity``, the sorted order's ``order`` and
-    ``row_token [M]`` and the held experts' ``group_sizes``; int32 all, 4
-    bytes a pair and 8 a row of the buffer (524 KB + 2 x 98 KB + 64 B a layer
-    at 16,384 tokens, top-8 and 24,576 rows). No gradient flows through an
-    integer, so the kept ones are the ones a second run would make; the
-    scores, the weights and every product stay the policy's to decide. An
-    identity where nothing wraps the layer. (Down here so that no line above
-    a grouped product's call site moves: ROADMAP.md S10.)"""
+    and, after the cut to ``capacity``, the sorted order ``order [M]`` and
+    the held experts' ``group_sizes``; int32 all, 4 bytes a pair and 4 a row
+    of the buffer (524 KB + 98 KB + 64 B a layer at 16,384 tokens, top-8 and
+    24,576 rows). No gradient flows through an integer, so the kept ones are
+    the ones a second run would make; the scores, the weights, every product
+    and what is arithmetic on a kept integer (a row's token) stay the
+    policy's to decide. An identity where nothing wraps the layer. (Down here
+    so that no line above a grouped product's call site moves: ROADMAP.md
+    S10.)"""
     from jax.ad_checkpoint import checkpoint_name
 
     return checkpoint_name(ints, ROUTING_NAME)
+
+
+def _chosen(score: jax.Array, idx: jax.Array) -> jax.Array:
+    """``jnp.take_along_axis(score, idx, axis=-1)`` (score [T, E], idx [T, k])
+    with no indexed access: a compare, a select and a sum over the outputs,
+    which XLA fuses into one pass and never writes out as ``[k, E, T]``. One
+    term of each sum is not zero, so the value is the gather's to the bit;
+    the transpose is a select and a sum over ``k``, and a token's ``idx`` are
+    distinct, so each score gets at most one cotangent: the scatter-add's to
+    the bit. On the chip a gather or a scatter of scalars runs at 7-9 ns an
+    element (1.0 ms a layer's forward at 16,384 tokens' 8 of 128, again in
+    the recomputation, 1.5 ms a step in the transpose); the same rule makes
+    :func:`moe_ffn_share`'s group sizes and each sorted row's token. The
+    tokens lie along the lanes (``[k, E, T]``): summed over the lanes
+    (``[T, k, E]``) the same pass is 0.42 ms and not 0.02. The barrier keeps
+    XLA from folding the caller's sum over ``k`` into this one, which would
+    add a token's ``k`` scores in another order than the gather's were
+    (PERF.md section 6, PR 51)."""
+    outputs = jnp.arange(score.shape[-1], dtype=idx.dtype)
+    top = jnp.sum(jnp.where(idx.T[:, None, :] == outputs[None, :, None],
+                            score.T[None, :, :], 0.0), axis=1)
+    return lax.optimization_barrier(top.T)

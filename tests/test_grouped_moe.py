@@ -636,11 +636,11 @@ def test_a_checkpointed_share_keeps_its_routing_and_nothing_else(
         capacity, masked):
     """Under ``nothing_saveable`` and the layer's own name, the backward pass
     of a share holds no second ``top_k`` and no second sort; what crosses
-    the checkpoint besides the layer's arguments is the four named integer
-    arrays (``idx``, and ``order``, ``row_token`` after the cut to
-    ``capacity``, ``group_sizes``) and no floating-point value; the
-    gradients are those of the same layer under a plain ``jax.checkpoint``,
-    to the bit."""
+    the checkpoint besides the layer's arguments is the three named integer
+    arrays (``idx``, and ``order`` after the cut to ``capacity``,
+    ``group_sizes``; a row's token is a division of its kept ``order``) and
+    no floating-point value; the gradients are those of the same layer under
+    a plain ``jax.checkpoint``, to the bit."""
     # (what ``jax.ad_checkpoint.print_saved_residuals`` prints, as a list)
     from jax._src.ad_checkpoint import saved_residuals
 
@@ -673,7 +673,7 @@ def test_a_checkpointed_share_keeps_its_routing_and_nothing_else(
             assert why.startswith("from the argument") \
                 or aval.dtype == jnp.int32, (aval, why)
     assert sorted(named) == sorted(
-        (shape, jnp.int32) for shape in [(128, 2), (rows,), (rows,), (4,)])
+        (shape, jnp.int32) for shape in [(128, 2), (rows,), (4,)])
 
     (got, dropped), (want, _) = jax.jit(loss(kept))(*args), \
         jax.jit(loss(plain))(*args)
@@ -723,3 +723,180 @@ def test_the_backward_pass_of_the_stack_routes_nothing_again(cut, monkeypatch):
         assert np.array_equal(g, w)
         moved += bool(np.any(np.asarray(w)))
     assert moved > 10
+
+
+# -- an expert layer's bookkeeping gathers and scatters no scalars ------------
+
+def _share_as_it_stood(y, router_w, experts, cfg, *, offset, valid, layer,
+                       bias, capacity):
+    """``route`` and ``moe_ffn_share`` with the bookkeeping of PR 47's tree:
+    ``take_along_axis`` for the chosen scores, ``bincount`` for the group
+    sizes and ``token[order]`` for a sorted row's token. Returns (out, pairs,
+    experts_hit, the training path's max_rows or None)."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    from deepspeed_tpu.parallel.moe import SWIGLU, _expert_ffn, route_top_k
+
+    T, H = y.shape
+    held, k = experts["wi"].shape[-3], cfg.top_k
+    if cfg.scoring == "softmax":
+        w, idx = route_top_k(y, router_w, k)
+    else:
+        score = jax.nn.sigmoid(jnp.einsum(
+            "th,he->te", y.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        choose = score if bias is None else \
+            score + jax.lax.stop_gradient(bias).astype(jnp.float32)
+        idx = jax.lax.top_k(choose, k)[1].astype(jnp.int32)
+        top = jnp.take_along_axis(score, idx, axis=-1)
+        w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        w = w * cfg.routed_scale
+    here = (idx >= offset) & (idx < offset + held)
+    if valid is not None:
+        here = here & valid[:, None]
+    m0 = T * k
+    m = ((m0 + 127) // 128) * 128
+    local = jnp.where(here, idx - offset, held).reshape(-1)
+    flat_w = jnp.where(here, w, 0.0).reshape(-1)
+    token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    if m > m0:
+        local = jnp.concatenate([local, jnp.full((m - m0,), held, local.dtype)])
+        flat_w = jnp.concatenate([flat_w, jnp.zeros((m - m0,), w.dtype)])
+        token = jnp.concatenate([token, jnp.zeros((m - m0,), token.dtype)])
+    order = jnp.argsort(local, stable=True)
+    row_token = token[order]
+    group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    if capacity is not None and capacity < m:
+        m, order, row_token = capacity, order[:capacity], row_token[:capacity]
+    work = None if layer is None else gm.make_group_metadata(
+        group_sizes, m, gm.choose_tiles(m, H, H, held, y.dtype)[0])
+    out = _expert_ffn(y[row_token], group_sizes, experts, SWIGLU, y.dtype,
+                      layer=layer, metadata=work)
+    contrib = out.astype(jnp.float32) * flat_w[order][:, None]
+    total = jnp.zeros((T, H), jnp.float32).at[row_token].add(contrib)
+    return (total.astype(y.dtype), jnp.sum(here).astype(jnp.int32),
+            jnp.sum(group_sizes > 0).astype(jnp.int32),
+            jnp.max(group_sizes) if layer is None else None)
+
+
+# name: (scoring, bias, valid tokens of T or None, T, capacity, layer)
+BOOKKEEPING = {
+    "sigmoid": ("sigmoid", False, None, 128, None, None),
+    "bias": ("sigmoid", True, None, 128, None, None),
+    "valid": ("sigmoid", False, 100, 128, None, None),
+    "padded_tail": ("sigmoid", True, None, 100, None, None),
+    "cut": ("sigmoid", True, None, 128, 256, None),
+    "cut_valid_tail": ("sigmoid", True, 90, 100, 256, None),
+    "softmax": ("softmax", False, 100, 100, None, None),
+    "layer": ("sigmoid", True, None, 128, None, 1),
+    "layer_valid_tail": ("sigmoid", False, 40, 50, None, 0),
+    "layer_softmax": ("softmax", False, 40, 50, None, 1),
+}
+
+
+@pytest.fixture(params=[False, True], ids=["plain", "optimized"])
+def optimized(request):
+    """tests/conftest.py compiles the suite's programs with most optimization
+    off; with it on, XLA may fold one sum into another and add in another
+    order (``moe._chosen`` holds a barrier against that)."""
+    prev = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", not request.param)
+    yield request.param
+    jax.config.update("jax_disable_most_optimizations", prev)
+
+
+@pytest.mark.parametrize("name", list(BOOKKEEPING))
+def test_the_bookkeeping_is_the_gathers_and_the_bincounts_to_the_bit(
+        name, optimized):
+    """The chosen scores by a compare and a sum, the group sizes by a compare
+    and a sum and a sorted row's token by a division are what
+    ``take_along_axis``, ``bincount`` and ``token[order]`` gave: the outputs,
+    the counts and, on the training path, the gradients of ``y``, the router
+    and the experts are equal to the bit, as the suite compiles and as a
+    user's program is compiled."""
+    from deepspeed_tpu.parallel.moe import moe_ffn_share
+
+    scoring, biased, n_valid, T, capacity, layer = BOOKKEEPING[name]
+    H, F, E, held, offset = 64, 32, 16, 4, 4
+    cfg = GateConfig(num_experts=E, top_k=4, drop_tokens=False,
+                     scoring=scoring, routed_scale=2.5)
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    stack = () if layer is None else (2,)
+    y = jax.random.normal(ks[0], (T, H))
+    router_w = jax.random.normal(ks[1], (H, E)) * 0.3
+    experts = {"wg": jax.random.normal(ks[2], stack + (held, H, F)) * 0.1,
+               "wi": jax.random.normal(ks[3], stack + (held, H, F)) * 0.1,
+               "wo": jax.random.normal(ks[4], stack + (held, F, H)) * 0.1}
+    bias = jax.random.normal(ks[5], (E,)) * 0.1 if biased else None
+    valid = None if n_valid is None else jnp.arange(T) < n_valid
+    kw = dict(offset=offset, valid=valid, layer=layer, capacity=capacity)
+
+    def now(y, router_w, experts):
+        out, c = moe_ffn_share(y, router_w, experts, cfg, router_bias=bias,
+                               **kw)
+        return out, c["pairs"], c["experts_hit"], c.get("max_rows")
+
+    def then(y, router_w, experts):
+        return _share_as_it_stood(y, router_w, experts, cfg, bias=bias, **kw)
+
+    got, want = jax.jit(now)(y, router_w, experts), \
+        jax.jit(then)(y, router_w, experts)
+    assert int(want[1]) > 20 and np.any(np.asarray(want[0]))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(g, w)
+    if layer is not None:       # the serving path's products: forward only
+        return
+
+    def grads(fn):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a)[0] ** 2),
+                                argnums=(0, 1, 2)))(y, router_w, experts)
+
+    for g, w in zip(jax.tree.leaves(grads(now)), jax.tree.leaves(grads(then))):
+        assert np.any(np.asarray(w)) and np.array_equal(g, w)
+
+
+def _indexed(jaxpr):
+    """Every ``gather`` and ``scatter-add`` of a jaxpr and of the jaxprs nested
+    in it as (primitive, the elements one index moves, the indices)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            moved = int(np.prod(eqn.params["slice_sizes"]))
+            found.append(("gather", moved,
+                          int(np.prod(eqn.outvars[0].aval.shape)) // moved))
+        elif eqn.primitive.name.startswith("scatter"):
+            n = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+            found.append((eqn.primitive.name,
+                          int(np.prod(eqn.invars[2].aval.shape)) // n, n))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _indexed(sub)
+    return found
+
+
+@pytest.mark.parametrize("capacity", [None, 128], ids=["every_pair", "cut"])
+def test_the_gradient_of_a_share_moves_rows_and_no_scalars_of_the_pairs(
+        capacity):
+    """What the gradient's program of a checkpointed share still reads or
+    writes by index, at the size of the row buffer or above (the grouped
+    products' work lists are a few entries): ``y[row_token]`` (forward,
+    recomputation; its transpose a scatter-add of rows), the combine (a
+    scatter-add of rows; its transpose a gather), and the row buffer's
+    weights ``flat_w[order]`` (forward, recomputation, a transposed
+    scatter-add), the one scalar a row that is left and over the buffer's
+    rows, not the pairs. No chosen score, group size or row's token is
+    gathered or scattered; beside it PR 47's pin: one sort, one ``top_k``."""
+    from deepspeed_tpu.parallel.moe import ROUTING_NAME
+    from deepspeed_tpu.runtime.activation_checkpointing import \
+        checkpoint_wrapper
+
+    layer, args = _share_layer(capacity)
+    kept = checkpoint_wrapper(layer, policy="nothing_saveable",
+                              kept_names=(ROUTING_NAME,))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kept(*a, None)[0] ** 2), argnums=(0, 1, 2)))(
+            *args).jaxpr
+    assert _primitives(jaxpr) == {"sort": 1, "top_k": 1}
+    rows, H = capacity or 256, 64
+    at_scale = sorted(op for op in _indexed(jaxpr) if op[2] >= rows)
+    assert at_scale == sorted(
+        3 * [("gather", H, rows)] + 2 * [("scatter-add", H, rows)]    # rows
+        + 2 * [("gather", 1, rows)] + [("scatter-add", 1, rows)])  # flat_w
