@@ -230,6 +230,9 @@ class InferenceEngineV2:
         self._min_segment = self._runner.min_segment(self.cfg)
         # whether a step may use the gather program (_split_by_program)
         self._has_gather = self._runner.has_gather(self.cfg)
+        # how often a token step runs the stack over each of its rows (the
+        # passes of a looped stack; 1 for every other)
+        self._ut_steps = int(self._runner.passes_per_token(self.cfg))
         # kept for reload_params: a hot-swap routes replacement weights
         # through the same v1 placement/quantization path as boot
         self._param_dtype = dtype
@@ -359,10 +362,15 @@ class InferenceEngineV2:
                       "first_token_own_calls": 0}
         for program in PROGRAMS:
             # calls, the token rows they really carried, the rows they
-            # computed (the program's padded layout) and the passes over
-            # the layers' weights they made one after another
+            # computed (the program's padded layout), the passes over
+            # the layers' weights they made one after another, and the
+            # rows times the passes of the stack the program ran over each
+            # (``rows`` for a stack run once a token)
             self.stats.update({f"{k}_{program}": 0 for k in (
-                "calls", "rows", "padded_rows", "token_steps")})
+                "calls", "rows", "padded_rows", "token_steps", "ut_passes")})
+        # the paged pool's layer slots: a layer's K/V, once a pass of a
+        # looped stack
+        self.stats["kv_slots"] = paged.num_layers
         # what the runner's programs count on the device (their ``counters``
         # vector, fetched with the step's tokens and summed over calls:
         # hybrid_runner.COUNTERS), those of them kept for the two decode
@@ -1036,7 +1044,8 @@ class InferenceEngineV2:
         the model's layers it makes one after another (K for a burst);
         ``chunks`` the prompt chunks in it; ``shape`` the prefill
         program's padded layout, ``S`` and ``tq``. The span carries all of
-        it with the ``step_id`` of the enclosing ``serve_step``, the
+        it with the passes of the stack a token step makes (``ut_steps``),
+        the ``step_id`` of the enclosing ``serve_step``, the
         call's place among the step's calls (``call``, from 0) and the
         rows the program computes whatever it carries (``padded_rows``);
         the counters (``calls_<program>`` ...) take the same numbers, and
@@ -1057,7 +1066,8 @@ class InferenceEngineV2:
         self.stats["steps_dispatched"] += call == 0
         mine = {"calls_" + program: 1, "rows_" + program: tokens,
                 "padded_rows_" + program: padded_rows,
-                "token_steps_" + program: token_steps}
+                "token_steps_" + program: token_steps,
+                "ut_passes_" + program: tokens * self._ut_steps}
         if program == "prefill":
             mine["prefill_chunk_calls"] = 1
         elif program in ("decode", "multi_decode"):
@@ -1075,7 +1085,7 @@ class InferenceEngineV2:
         return span("dispatch", program=program, step_id=self._step_id,
                     call=call, seqs=len(seqs), tokens=tokens,
                     padded_rows=padded_rows, token_steps=token_steps,
-                    chunks=chunks, **shape)
+                    ut_steps=self._ut_steps, chunks=chunks, **shape)
 
     def _count(self, counts: Dict[str, int]) -> None:
         for name, n in counts.items():

@@ -861,6 +861,12 @@ def store_specs(cfg: HybridConfig, *, kv_blocks: int, kv_block_size: int,
     return paged, beside
 
 
+def passes_per_token(cfg: HybridConfig) -> int:
+    """``model_runner.passes_per_token``'s contract: a hybrid stack runs
+    each layer once a token."""
+    return 1
+
+
 def min_segment(cfg: HybridConfig) -> int:
     """The prefill program's smallest chunk bucket: the chunked recurrence
     pads every row of a segment batch to a whole chunk (which also holds

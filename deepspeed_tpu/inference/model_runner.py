@@ -26,7 +26,7 @@ from jax import lax
 
 from deepspeed_tpu.inference.ragged.kv_cache import KVCacheConfig
 from deepspeed_tpu.models.transformer import (
-    TransformerConfig, _norm, _rope, act_fn)
+    LoopedStackUnsupported, TransformerConfig, _norm, _rope, act_fn)
 from deepspeed_tpu.ops.pallas.quantization import (kv_dequantize,
                                                    kv_pack, kv_quantize,
                                                    kv_unpack)
@@ -111,16 +111,58 @@ def _gather_sequences(cfg, kv, kv_sc, layer, block_table, dt):
             gathered[:, :, 1].transpose(0, 2, 1, 3))
 
 
-def _scan_layers(layer_body, x, params, kv_data, kv_scales):
+def _scan_layers(cfg, layer_body, x, params, kv_data, kv_scales):
     """Run ``layer_body((x, kv, kv_sc), (layer_params, l))`` over the
     layers with the pool as the scan's *carry*: one buffer, scattered
     into at ``[l, ...]`` and read at ``l``, never sliced per layer into
-    ``xs`` nor restacked from ``ys``. Returns (x, kv_state')."""
-    (x, kv_data, kv_scales), _ = lax.scan(
-        layer_body, (x, kv_data, kv_scales),
-        (params["layers"], jnp.arange(kv_data.shape[0], dtype=jnp.int32)))
+    ``xs`` nor restacked from ``ys``. Returns (x, kv_state').
+
+    A looped stack (``cfg.ut_steps`` passes over ``L`` layers) is ONE scan
+    over the pool's ``ut_steps * L`` slots: step ``s`` is pass ``s // L``
+    of layer ``s % L``, takes that layer's weights from the stack, writes
+    and reads the pool's slot ``s`` (:func:`store_specs`), and where it ends
+    a pass but the last the model's final norm follows it (the last pass's
+    is the head's, :func:`_unembed`, as for a stack run once). One loop and not a loop of passes around a
+    loop of layers: XLA hoists a relayout of the whole ``wq``/``wk``/``wv``
+    stacks out of an inner loop, 1.1 GiB of temporaries a call at the
+    published widths."""
+    if cfg.ut_steps > 1:
+        slots = kv_data.shape[0]
+        L = slots // cfg.ut_steps
+
+        def slot_body(carry, slot):
+            l = slot % L
+            layer_params = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, l, keepdims=False),
+                params["layers"])
+            with jax.named_scope("ut_pass"):
+                (x, kv, kv_sc), _ = layer_body(carry, (layer_params, slot))
+            with jax.named_scope("pass_norm"):
+                normed = _norm(x, params["final_norm"], cfg.norm,
+                               cfg.norm_eps)
+            between = (l == L - 1) & (slot < slots - 1)
+            return (jnp.where(between, normed, x), kv, kv_sc), None
+
+        (x, kv_data, kv_scales), _ = lax.scan(
+            slot_body, (x, kv_data, kv_scales),
+            jnp.arange(slots, dtype=jnp.int32))
+    else:
+        (x, kv_data, kv_scales), _ = lax.scan(
+            layer_body, (x, kv_data, kv_scales),
+            (params["layers"], jnp.arange(kv_data.shape[0], dtype=jnp.int32)))
     return x, ({"kv": kv_data} if kv_scales is None
                else {"kv": kv_data, "scales": kv_scales})
+
+
+def _attn_out(cfg: TransformerConfig, layer_params, attn, spec: str):
+    """The attention branch's output: heads back to hidden (``spec``), its
+    bias, and with ``post_norms`` the branch's own norm."""
+    attn = jnp.einsum(spec, attn, layer_params["attn"]["wo"].astype(attn.dtype))
+    if cfg.use_biases:
+        attn = attn + layer_params["attn"]["bo"].astype(attn.dtype)
+    if cfg.post_norms:
+        attn = _norm(attn, layer_params["ln1_post"], cfg.norm, cfg.norm_eps)
+    return attn
 
 
 def _qkv(cfg: TransformerConfig, layer_params, y, positions):
@@ -171,6 +213,8 @@ def _mlp(cfg: TransformerConfig, layer_params, x):
     out = jnp.einsum("...f,fh->...h", z, mp["wo"].astype(dt))
     if cfg.use_biases:
         out = out + mp["bo"].astype(dt)
+    if cfg.post_norms:
+        out = _norm(out, layer_params["ln2_post"], cfg.norm, cfg.norm_eps)
     return x + out
 
 
@@ -225,6 +269,11 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
     """tokens [B, S] starting at absolute position start_pos (scalar);
     returns (logits [B, S, V] fp32, updated cache). Works for prefill
     (S = prompt len, start_pos = 0) and decode (S = 1)."""
+    if cfg.ut_steps > 1:
+        raise LoopedStackUnsupported(
+            "forward_with_cache (the v1 engine's dense cache) keeps one K/V "
+            "slot a layer; a looped stack needs one a pass and layer: serve "
+            "it through InferenceEngineV2")
     B, S = tokens.shape
     dt = effective_dtype(cfg.dtype)
     max_len = cache.shape[2]
@@ -254,10 +303,7 @@ def forward_with_cache(cfg: TransformerConfig, params, tokens: jax.Array,
         probs = _attention_probs(cfg, scores, mask)
         attn = jnp.einsum("bkgsm,bmkd->bskgd", probs,
                           kv_layer[:, :, 1].astype(dt)).reshape(q.shape)
-        attn = jnp.einsum("bsnd,ndh->bsh", attn,
-                          layer_params["attn"]["wo"].astype(dt))
-        if cfg.use_biases:
-            attn = attn + layer_params["attn"]["bo"].astype(dt)
+        attn = _attn_out(cfg, layer_params, attn, "bsnd,ndh->bsh")
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             return _mlp(cfg, layer_params, x) + attn, kv_layer
         x = x + attn
@@ -285,9 +331,11 @@ def store_specs(cfg: TransformerConfig, *, kv_blocks: int,
                 kv_block_size: int, max_seqs: int,
                 state_slots: Optional[int], dtype, quant_bits):
     """``(the paged pool's spec, the specs of the stores beside it)`` for
-    an engine of these sizes: keys and values a layer, nothing beside."""
+    an engine of these sizes: keys and values a layer and pass (a looped
+    stack keeps each pass's own: ``ut_steps * num_layers`` slots from
+    ``num_layers`` layers of weights), nothing beside."""
     return KVCacheConfig(
-        num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
+        num_layers=cfg.ut_steps * cfg.num_layers, kv_heads=cfg.kv_heads,
         head_dim=cfg.head_dim, block_size=kv_block_size,
         num_blocks=kv_blocks, dtype=dtype, quant_bits=quant_bits), []
 
@@ -295,6 +343,12 @@ def store_specs(cfg: TransformerConfig, *, kv_blocks: int,
 def serving_params(cfg: TransformerConfig, params, donate: bool = False):
     """The tree the programs take: the model's own."""
     return params
+
+
+def passes_per_token(cfg: TransformerConfig) -> int:
+    """How often a token row runs the stack in one token step of any of the
+    four programs: every pass of a looped stack (no row leaves early)."""
+    return cfg.ut_steps
 
 
 def min_segment(cfg: TransformerConfig) -> int:
@@ -386,17 +440,14 @@ def ragged_forward(cfg: TransformerConfig, params, kv_data: Dict,
             probs = _attention_probs(cfg, scores, mask)
             attn = jnp.einsum("tkgm,tkmd->tkgd", probs,
                               v_seq.astype(dt)).reshape(q.shape)
-            attn = jnp.einsum("tnd,ndh->th", attn,
-                              layer_params["attn"]["wo"].astype(dt))
-            if cfg.use_biases:
-                attn = attn + layer_params["attn"]["bo"].astype(dt)
+            attn = _attn_out(cfg, layer_params, attn, "tnd,ndh->th")
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             x = _mlp(cfg, layer_params, x) + attn
         else:
             x = _mlp(cfg, layer_params, x + attn)
         return (x, kv, kv_sc), None
 
-    x, new_kv = _scan_layers(layer_body, x, params, kv_data, kv_scales)
+    x, new_kv = _scan_layers(cfg, layer_body, x, params, kv_data, kv_scales)
     return _unembed(cfg, params, x), new_kv
 
 
@@ -535,17 +586,15 @@ def ragged_prefill_forward(cfg: TransformerConfig, params,
         with jax.named_scope("attn"):
             attn = _segment_attention(cfg, q.astype(dt), kv, kv_sc, l,
                                       block_table, pos)
-            attn = jnp.einsum("stnd,ndh->sth", attn.astype(dt),
-                              layer_params["attn"]["wo"].astype(dt))
-            if cfg.use_biases:
-                attn = attn + layer_params["attn"]["bo"].astype(dt)
+            attn = _attn_out(cfg, layer_params, attn.astype(dt),
+                             "stnd,ndh->sth")
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             x = _mlp(cfg, layer_params, x) + attn
         else:
             x = _mlp(cfg, layer_params, x + attn)
         return (x, kv, kv_sc), None
 
-    x, new_kv = _scan_layers(layer_body, x, params, kv_data, kv_scales)
+    x, new_kv = _scan_layers(cfg, layer_body, x, params, kv_data, kv_scales)
     return _unembed(cfg, params, x), new_kv
 
 
@@ -600,17 +649,15 @@ def ragged_decode_forward(cfg: TransformerConfig, params, kv_data: Dict,
             attn = _paged_decode(mesh, q.astype(dt),
                                  *_kv_dense(kv, kv_sc, l, dt),
                                  block_table, context_lens)
-            attn = jnp.einsum("snd,ndh->sh", attn.astype(dt),
-                              layer_params["attn"]["wo"].astype(dt))
-            if cfg.use_biases:
-                attn = attn + layer_params["attn"]["bo"].astype(dt)
+            attn = _attn_out(cfg, layer_params, attn.astype(dt),
+                             "snd,ndh->sh")
         if cfg.parallel_block:  # Falcon: both branches read pre-attn x
             x = _mlp(cfg, layer_params, x) + attn
         else:
             x = _mlp(cfg, layer_params, x + attn)
         return (x, kv, kv_sc), None
 
-    x, new_kv = _scan_layers(layer_body, x, params, kv_data, kv_scales)
+    x, new_kv = _scan_layers(cfg, layer_body, x, params, kv_data, kv_scales)
     return _unembed(cfg, params, x), new_kv
 
 

@@ -38,6 +38,14 @@ class MoETransformerConfig(tfm.TransformerConfig):
     # "auto" | "grouped" (dropless grouped-GEMM) | "einsum" (capacity pad)
     moe_impl: str = "auto"
 
+    def __post_init__(self):
+        super().__post_init__()
+        if self.ut_steps > 1 or self.post_norms:
+            raise tfm.LoopedStackUnsupported(
+                "the expert stack runs each layer once a token and has no "
+                "post-branch norms (ut_steps, post_norms: models/"
+                "transformer.py's dense block alone)")
+
     @property
     def gate(self) -> GateConfig:
         return GateConfig(

@@ -39,6 +39,17 @@ from deepspeed_tpu.runtime.sharding import (constrain_activation,
                                             vocab_parallel_lookup)
 
 
+class LoopedStackUnsupported(NotImplementedError):
+    """A path that runs each layer once a token was asked to run a looped
+    stack (``TransformerConfig.ut_steps`` > 1) or one with post-branch
+    norms: it refuses rather than run another model."""
+
+
+class EarlyExitUnsupported(ValueError):
+    """``early_exit_threshold`` below 1: a token would leave the loop before
+    the last pass, which no path here does."""
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     """Architecture switches covering the GPT-2/Llama families."""
@@ -125,6 +136,21 @@ class TransformerConfig:
     # gradients. Opt-in — off keeps exact bf16/fp32 parity. Set by the
     # engine from config.performance.fp8_mlp.
     fp8_mlp: bool = False
+    # a looped ("universal") stack: the SAME ``num_layers`` layers run
+    # ``ut_steps`` times a token, the model's final norm after every pass
+    # (its output enters the next), and pass t of layer l keeps keys and
+    # values of its own (K/V slot ``t * num_layers + l``). An exit gate
+    # (``exit_gate``: Linear(hidden -> 1) with a bias) reads each pass's
+    # normed output; the no-cache forward returns the distribution over
+    # passes it implies (:func:`apply_with_exit`). At the threshold 1 no
+    # cumulative probability reaches it before the last pass, so every
+    # pass runs and the last one's output is the model's; a threshold
+    # below 1 is refused (:class:`EarlyExitUnsupported`).
+    ut_steps: int = 1
+    early_exit_threshold: float = 1.0
+    # a norm after each branch too, before its residual add (``ln1_post``,
+    # ``ln2_post``; ``models/hybrid.py`` has the same switch)
+    post_norms: bool = False
 
     def __post_init__(self):
         import os as _os
@@ -151,6 +177,20 @@ class TransformerConfig:
         # sp-all-gathered host KV stacks through its local q chunks
         # (parallel/fpdt.py sp_axis mode) — the former hard error here
         # is lifted (ROADMAP item 4 planner composition).
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps must be >= 1, got {self.ut_steps}")
+        if self.early_exit_threshold < 1.0:
+            raise EarlyExitUnsupported(
+                f"early_exit_threshold {self.early_exit_threshold} < 1: "
+                "leaving the loop before the last pass is not implemented "
+                "(a token that exits has no keys and values in the later "
+                "passes' slots); every pass runs, so only the threshold 1 "
+                "is served")
+        if (self.ut_steps > 1 or self.post_norms) and (
+                self.fpdt_host_kv or self.parallel_block):
+            raise LoopedStackUnsupported(
+                "a looped stack (ut_steps > 1) or post_norms runs the plain "
+                "sequential block only: not fpdt_host_kv, not parallel_block")
         if self.fpdt_host_residual:
             if not self.fpdt_host_kv:
                 raise ValueError(
@@ -190,18 +230,25 @@ class TransformerConfig:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6·N params + attn)."""
         n = self.num_params()
         attn = 12 * self.num_layers * self.hidden_size * self.max_seq_len
-        return 6 * n + attn
+        if self.ut_steps > 1:    # the layers' weights multiply every pass
+            n += (self.ut_steps - 1) * self.num_layers * self._layer_params()
+        return 6 * n + self.ut_steps * attn
 
-    def num_params(self) -> int:
-        h, L, f, v = self.hidden_size, self.num_layers, self.ffn, self.vocab_size
+    def _layer_params(self) -> int:
+        h, f = self.hidden_size, self.ffn
         hd, nh, nkv = self.head_dim, self.num_heads, self.kv_heads
         attn = h * nh * hd + 2 * h * nkv * hd + nh * hd * h
         mlp = (3 if self.activation == "swiglu" else 2) * h * f
         norm_width = 2 * h if self.norm == "layernorm" else h  # scale(+bias)
-        per_layer = attn + mlp + 2 * norm_width
+        return attn + mlp + (4 if self.post_norms else 2) * norm_width
+
+    def num_params(self) -> int:
+        h, L, v = self.hidden_size, self.num_layers, self.vocab_size
+        norm_width = 2 * h if self.norm == "layernorm" else h  # scale(+bias)
         emb = v * h + (0 if self.tie_embeddings else v * h)
         pos = self.max_seq_len * h if self.pos_emb == "learned" else 0
-        return L * per_layer + emb + pos + norm_width
+        gate = h + 1 if self.ut_steps > 1 else 0
+        return L * self._layer_params() + emb + pos + norm_width + gate
 
 
 # ---------------------------------------------------------------------------
@@ -260,10 +307,18 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         },
         "final_norm": {"scale": jnp.ones((h,), pd)},
     }
+    norms = ("ln1", "ln2")
+    if cfg.post_norms:
+        norms += ("ln1_post", "ln2_post")
+        for name in norms[2:]:
+            params["layers"][name] = {"scale": jnp.ones((L, h), pd)}
     if cfg.norm == "layernorm":
-        params["layers"]["ln1"]["bias"] = jnp.zeros((L, h), pd)
-        params["layers"]["ln2"]["bias"] = jnp.zeros((L, h), pd)
+        for name in norms:
+            params["layers"][name]["bias"] = jnp.zeros((L, h), pd)
         params["final_norm"]["bias"] = jnp.zeros((h,), pd)
+    if cfg.ut_steps > 1:
+        params["exit_gate"] = {"kernel": _dense_init(keys[8], (h, 1), dtype=pd),
+                               "bias": jnp.zeros((1,), pd)}
     if cfg.pos_emb == "learned":
         params["embed"]["positions"] = _dense_init(
             keys[6], (cfg.max_seq_len, h), 0.01, pd
@@ -314,10 +369,17 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         },
         "final_norm": {"scale": ("embed",)},
     }
+    norms = ("ln1", "ln2")
+    if cfg.post_norms:
+        norms += ("ln1_post", "ln2_post")
+        for name in norms[2:]:
+            axes["layers"][name] = {"scale": ("layers", "embed")}
     if cfg.norm == "layernorm":
-        axes["layers"]["ln1"]["bias"] = ("layers", "embed")
-        axes["layers"]["ln2"]["bias"] = ("layers", "embed")
+        for name in norms:
+            axes["layers"][name]["bias"] = ("layers", "embed")
         axes["final_norm"]["bias"] = ("embed",)
+    if cfg.ut_steps > 1:
+        axes["exit_gate"] = {"kernel": ("embed", None), "bias": (None,)}
     if cfg.use_biases:
         axes["layers"]["attn"]["bq"] = ("layers", "heads", "head_dim")
         axes["layers"]["attn"]["bk"] = ("layers", "kv_heads", "head_dim")
@@ -675,6 +737,9 @@ def _layer(cfg: TransformerConfig, x, layer_params, positions,
         attn = jnp.einsum("bsnd,ndh->bsh", attn, ap["wo"].astype(dt))
         if cfg.use_biases:
             attn = attn + ap["bo"].astype(dt)
+        if cfg.post_norms:
+            attn = _norm(attn, layer_params["ln1_post"], cfg.norm,
+                         cfg.norm_eps)
         attn = constrain_activation(
             checkpoint_name(attn, "attn_out"), ("batch", "seq", "embed"))
     return _layer_mlp(cfg, x, attn, layer_params)
@@ -743,6 +808,8 @@ def _layer_mlp(cfg: TransformerConfig, x, attn, layer_params):
     else:
         y = _norm(x, layer_params["ln2"], cfg.norm, cfg.norm_eps)
         z = mlp_fn(y)
+    if cfg.post_norms:
+        z = _norm(z, layer_params["ln2_post"], cfg.norm, cfg.norm_eps)
     z = constrain_activation(z, ("batch", "seq", "embed"))
     if cfg.parallel_block:
         return x + attn + z
@@ -757,9 +824,12 @@ def _layer_mlp(cfg: TransformerConfig, x, attn, layer_params):
 def apply_hidden(cfg: TransformerConfig, params: Dict[str, Any],
                  tokens: jax.Array,
                  positions: Optional[jax.Array] = None,
-                 final_norm: bool = True) -> jax.Array:
+                 final_norm: bool = True,
+                 pass_outputs: bool = False) -> jax.Array:
     """Forward pass up to (and including, unless ``final_norm=False``)
-    the final norm: tokens [B,S] → hidden [B,S,H].
+    the final norm: tokens [B,S] → hidden [B,S,H]. For a looped stack
+    ``pass_outputs`` returns every pass's normed output instead,
+    [ut_steps, B, S, H] (the last is what the head reads).
 
     ``final_norm=False`` lets the tiled-logits path fuse the norm into
     its per-tile pass — at long context the full-sequence norm's fp32
@@ -789,6 +859,15 @@ def apply_hidden(cfg: TransformerConfig, params: Dict[str, Any],
     from deepspeed_tpu.parallel import topology as _topo
     from deepspeed_tpu.parallel.pipeline import pipeline_enabled, pipelined_layers
 
+    looped = cfg.ut_steps > 1
+    if looped and (pipeline_enabled(_topo._GLOBAL_MESH)
+                   or cfg.param_host_offload
+                   or (cfg.overlap_depth and _topo._GLOBAL_MESH is not None
+                       and _topo._GLOBAL_MESH.shape.get("fsdp", 1) > 1)):
+        raise LoopedStackUnsupported(
+            "a looped stack (ut_steps > 1) runs as the plain scan of layers "
+            "only: not under pipeline stages, param_host_offload or the "
+            "stage-3 overlap streamer")
     if pipeline_enabled(_topo._GLOBAL_MESH):
         # pp > 1: run the layer stack as a microbatched stage pipeline
         # (remat is applied per stage inside pipelined_layers)
@@ -893,7 +972,25 @@ def apply_hidden(cfg: TransformerConfig, params: Dict[str, Any],
         def scan_body(carry, layer_params):
             return layer_fn(carry, layer_params, positions), None
 
-        x, _ = lax.scan(scan_body, x, params["layers"])
+        if looped:
+            # the same layers again in every pass, the model's final norm
+            # behind each: what comes out is normed, and each pass's output
+            # is what the exit gate reads
+            if not final_norm:
+                raise LoopedStackUnsupported(
+                    "apply_hidden(final_norm=False): the final norm is part "
+                    "of a looped stack's every pass")
+
+            def pass_body(x, _):
+                with jax.named_scope("ut_pass"):
+                    x, _ = lax.scan(scan_body, x, params["layers"])
+                x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+                return x, x
+
+            x, passes = lax.scan(pass_body, x, None, length=cfg.ut_steps)
+            return passes if pass_outputs else x   # [ut_steps, B, S, H] | x
+        else:
+            x, _ = lax.scan(scan_body, x, params["layers"])
 
     if not final_norm:
         return x
@@ -1037,6 +1134,32 @@ def apply(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
     return _unembed_logits(cfg, params, x)
 
 
+def apply_with_exit(cfg: TransformerConfig, params: Dict[str, Any],
+                    tokens: jax.Array, positions: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """The no-cache forward of a looped stack: ``(logits [B, S, V] float32,
+    exit distribution [B, S, ut_steps] float32)``. The gate reads each
+    pass's normed output, ``lam_t = sigmoid(h_t . w + b)``; pass t takes
+    ``lam_t * prod_{s<t} (1 - lam_s)`` and the last pass the remainder. At
+    the threshold 1 (the only one served) the logits are the last pass's
+    whatever the distribution says."""
+    if cfg.ut_steps < 2:
+        raise LoopedStackUnsupported(
+            "apply_with_exit: the stack runs once a token (ut_steps == 1) "
+            "and has no exit gate")
+    h = apply_hidden(cfg, params, tokens, positions, pass_outputs=True)
+    with jax.named_scope("exit_gate"):
+        gate = params["exit_gate"]
+        z = jnp.einsum("tbsh,ho->tbs", h.astype(jnp.float32),
+                       gate["kernel"].astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST)
+        lam = jax.nn.sigmoid(z + gate["bias"].astype(jnp.float32))[:-1]
+        stay = jnp.cumprod(1.0 - lam, axis=0)          # prod_{s<=t}(1 - lam_s)
+        before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+        probs = jnp.concatenate([lam * before, stay[-1:]])
+    return _unembed_logits(cfg, params, h[-1]), jnp.moveaxis(probs, 0, -1)
+
+
 @jax.named_scope("head_loss")
 def _unembed_logits(cfg: TransformerConfig, params, x) -> jax.Array:
     dt = cfg.dtype
@@ -1057,6 +1180,12 @@ def _unembed_logits(cfg: TransformerConfig, params, x) -> jax.Array:
 def loss_fn(cfg: TransformerConfig, params, batch) -> Tuple[jax.Array, Dict]:
     """Causal-LM cross-entropy. batch: {input_ids [B,S]} or
     {input_ids, labels, loss_mask}."""
+    if cfg.ut_steps > 1:
+        raise LoopedStackUnsupported(
+            "loss: the published objective of a looped stack (the expected "
+            "loss over exit passes, entropy-regularised) is not "
+            "implemented; the cross-entropy of the last pass alone would "
+            "train another model")
     tokens = batch["input_ids"]
     if "labels" in batch:
         inputs, labels = tokens, batch["labels"]
@@ -1148,6 +1277,11 @@ class TransformerLM:
 
     def apply(self, params, tokens, positions=None):
         return apply(self.config, params, tokens, positions)
+
+    def apply_with_exit(self, params, tokens, positions=None):
+        """``(logits, exit distribution over the passes)`` of a looped stack
+        (:func:`apply_with_exit`)."""
+        return apply_with_exit(self.config, params, tokens, positions)
 
     def loss(self, params, batch):
         return loss_fn(self.config, params, batch)

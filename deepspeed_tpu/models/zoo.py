@@ -5,7 +5,8 @@ Named configurations, by the class that runs them:
 * ``TransformerLM`` (models/transformer.py), one dense block: GPT-2 sizes,
   Llama-2/3, Mistral, Qwen2, Phi-3, OPT, Falcon — the families the reference
   ships policies for (module_inject/containers/*,
-  inference/v2/model_implementations/*);
+  inference/v2/model_implementations/*) — and Ouro, the same block with
+  post-branch norms run several times a token (``ut_steps``);
 * ``MoETransformerLM`` (models/moe_transformer.py), the same attention with
   a plain softmax top-k expert layer: Mixtral, Qwen2-MoE;
 * ``HybridLM`` (models/hybrid.py), recurrent layers among full-attention
@@ -84,6 +85,26 @@ CONFIGS = {
     # tiny debug config (reference tests/unit/simple_model.py role)
     "tiny": TransformerConfig(vocab_size=256, hidden_size=64, num_layers=2,
                               num_heads=4, max_seq_len=128, remat=False),
+    # Ouro-2.6B (ByteDance/Ouro-2.6B config.json, modeling_ouro.py): a
+    # looped stack. 48 Llama-style layers (multi-head attention, 16 of 16
+    # heads of 128, rotary on the whole head; SwiGLU) with a norm after each
+    # branch as well as before it, run four times a token: the model's one
+    # final norm after every pass, each pass's keys and values kept apart
+    # (192 K/V slots a token from 48 layers of weights), and an exit gate
+    # whose threshold 1 lets no token leave before the last pass.
+    "ouro-2.6b": TransformerConfig(
+        vocab_size=49152, hidden_size=2048, num_layers=48, num_heads=16,
+        num_kv_heads=16, ffn_size=5632, max_seq_len=65536, pos_emb="rope",
+        norm="rmsnorm", activation="swiglu", tie_embeddings=False,
+        rope_theta=1000000.0, norm_eps=1e-6, post_norms=True, ut_steps=4,
+        early_exit_threshold=1.0),
+    # the same stack at a toy size: three layers, four passes
+    "tiny-ouro": TransformerConfig(
+        vocab_size=256, hidden_size=64, num_layers=3, num_heads=4,
+        num_kv_heads=4, ffn_size=128, max_seq_len=256, pos_emb="rope",
+        norm="rmsnorm", activation="swiglu", tie_embeddings=False,
+        rope_theta=1000000.0, norm_eps=1e-6, post_norms=True, ut_steps=4,
+        early_exit_threshold=1.0, remat=False),
 }
 
 

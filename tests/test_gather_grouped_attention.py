@@ -73,7 +73,8 @@ def repeat_forward(cfg, params, kv_data, token_ids, token_seq, token_pos,
             return (mr._mlp(cfg, layer_params, x) + attn, kv, kv_sc), None
         return (mr._mlp(cfg, layer_params, x + attn), kv, kv_sc), None
 
-    x, new_kv = mr._scan_layers(layer_body, x, params, kv_data, kv_scales)
+    x, new_kv = mr._scan_layers(cfg, layer_body, x, params, kv_data,
+                                  kv_scales)
     return mr._unembed(cfg, params, x), new_kv
 
 

@@ -37,13 +37,13 @@ def _module(lowered) -> str:
 T, S, BM, NB, BS = 16, 4, 8, 32, 8      # tokens, slots, pages/seq, pool, page
 
 
-@pytest.fixture(scope="module")
-def serve_lowered():
-    """Each serving program lowered on abstract arguments of a tiny model."""
-    model = get_model("tiny")
+def _serve_programs(preset):
+    """Each serving program of the dense runner lowered on abstract
+    arguments of a tiny model (a pool of ``ut_steps * num_layers`` slots)."""
+    model = get_model(preset)
     cfg = model.config
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    kv = {"kv": _sds((cfg.num_layers, NB, BS, 2, cfg.kv_heads,
+    kv = {"kv": _sds((cfg.ut_steps * cfg.num_layers, NB, BS, 2, cfg.kv_heads,
                       cfg.head_dim))}
     fns = engine_v2._shared_step_fns(cfg, None)
     ids = lambda *shape: _sds(shape, I32)  # noqa: E731
@@ -57,6 +57,16 @@ def serve_lowered():
         "multi_decode": fns["multi_decode"].lower(
             params, kv, ids(S), ids(S), ids(S, BM), ids(S), steps=3),
     }
+
+
+@pytest.fixture(scope="module")
+def serve_lowered():
+    return _serve_programs("tiny")
+
+
+@pytest.fixture(scope="module")
+def looped_lowered():
+    return _serve_programs("tiny-ouro")
 
 
 @pytest.mark.parametrize("program", ["gather", "prefill", "decode",
@@ -79,6 +89,23 @@ def test_serving_program_scopes(serve_lowered, program, scopes):
     # pages read once a chunk the prefill program's; the kernels read pages
     if program not in ("gather", "prefill"):
         assert "kv_gather/" not in text
+
+
+@pytest.mark.parametrize("program", ["gather", "prefill", "decode",
+                                     "multi_decode"])
+def test_looped_programs_hold_the_loops_scopes(looped_lowered, program):
+    """A looped stack (``tiny-ouro``: three layers, four passes, a pool of
+    twelve slots) in the dense runner's four programs: ``loop_pass_ms`` keys
+    on ``ut_pass``, under which the layer's own scopes lie; the norm between
+    passes is ``pass_norm``; the exit gate is in no step program (threshold
+    1: nothing reads it)."""
+    lowered = looped_lowered[program]
+    assert _module(lowered) == f"jit_dstpu_serve_{program}"
+    text = lowered.as_text(debug_info=True)
+    for scope in ("ut_pass/attn", "ut_pass/kv_write", "ut_pass/mlp",
+                  "pass_norm", "head"):
+        assert scope + "/" in text, scope
+    assert "exit_gate" not in text
 
 
 @pytest.mark.parametrize("fn,args,name", [

@@ -210,18 +210,20 @@ MIXED_CALLS = [
          token_steps=1, chunks=0)]
 
 
-def _dispatches(spans, keep_step_id=False):
+def _dispatches(spans, keep_step_id=False, ut_steps=1):
     """The ``dstpu/dispatch`` spans' ids, in order, each checked against
     the ``serve_step`` span it lies in (which its own ``step_id`` has to
-    name)."""
+    name) and for the passes of the stack its model makes a token step
+    (``ut_steps``: 1 but for a looped stack)."""
     steps = [s for s in spans if s["name"] == "serve_step"]
     out = []
     for d in (s for s in spans if s["name"] == "dispatch"):
         (step,) = [t for t in steps if t["start"] <= d["start"]
                    and d["end"] <= t["end"]]
         assert d["ids"]["step_id"] == step["ids"]["step_id"]
-        out.append({k: v for k, v in d["ids"].items()
-                    if keep_step_id or k != "step_id"})
+        assert d["ids"]["ut_steps"] == ut_steps
+        out.append({k: v for k, v in d["ids"].items() if k != "ut_steps"
+                    and (keep_step_id or k != "step_id")})
     return out
 
 
@@ -432,6 +434,32 @@ def test_serve_tokens_do_not_depend_on_a_profiler_session(devices, tmp_path,
         {k: b.stats[k] for k in counted}
     assert set(EXPECTED) <= set(counted)
     a.close(), b.close()
+
+
+def test_a_looped_stack_says_its_passes_on_every_call(devices, tmp_path):
+    """``tiny-ouro`` (three layers, four passes): every ``dstpu/dispatch``
+    span says ``ut_steps`` 4, ``ut_passes_<program>`` counts four passes a
+    row of every program, and ``kv_slots`` the pool's twelve."""
+    engine = InferenceEngineV2(
+        _model("tiny-ouro"), kv_blocks=64, kv_block_size=8,
+        max_tokens_per_step=32, max_seqs_per_step=4, max_blocks_per_seq=16,
+        dtype=jnp.float32, decode_steps=4, prefix_cache=False)
+
+    def run():
+        engine.put([1, 2], [_prompt(20, 1), _prompt(5, 2)], max_new_tokens=6)
+        return engine.generate_all()
+
+    out, spans = capture(tmp_path, run)
+    calls = _dispatches(spans, ut_steps=4)
+    assert {c["program"] for c in calls} == {"prefill", "multi_decode",
+                                             "decode"}
+    st = engine.stats
+    assert st["kv_slots"] == 12 and all(len(t) == 6 for t in out.values())
+    for program in ("prefill", "multi_decode", "decode"):
+        assert st[f"ut_passes_{program}"] == 4 * st[f"rows_{program}"] \
+            == 4 * sum(c["tokens"] for c in calls if c["program"] == program)
+    assert st["ut_passes_gather"] == st["ut_passes_spec"] == 0
+    engine.close()
 
 
 def test_the_calls_of_a_split_step_say_their_place_and_what_they_carry(

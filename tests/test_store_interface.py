@@ -194,6 +194,12 @@ MATRIX = {
     "tiny": dict(host_tier=None, page_out=None, migrate_out=None,
                  migrate_in=None, handoff_serialize=None,
                  handoff_install=None, speculation=None, prefix_cache=None),
+    # (a looped stack's pool has a slot a pass and layer: the stores move
+    # ``[slots, blocks, ...]`` and do not ask what a slot is)
+    "tiny-ouro": dict(host_tier=None, page_out=None, migrate_out=None,
+                      migrate_in=None, handoff_serialize=None,
+                      handoff_install=None, speculation=None,
+                      prefix_cache=None),
     "tiny-hybrid": dict(host_tier=S, page_out=S, migrate_out=S, migrate_in=S,
                         handoff_serialize=S, handoff_install=S, speculation=S,
                         prefix_cache=S),

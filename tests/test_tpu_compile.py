@@ -837,6 +837,75 @@ def test_m3_programs_keep_the_pool_and_the_pooled_keys_in_place(chip, program):
     assert 7.8 < mem.argument_size_in_bytes / 2**30 < 8.2
 
 
+@pytest.mark.parametrize("program", ["decode", "multi_decode", "prefill"])
+def test_ouro_programs_hold_one_pool_of_192_slots(chip, program):
+    """``ouro-2.6b-serve-c1`` whole, as its configuration file sizes it (48
+    layers at the published widths, 337 blocks of 16 tokens, 16 sequences of
+    28 pages): the pool has 4 x 48 = 192 layer slots (7.90 GiB) and comes
+    back in the buffer it came in; arguments are the weights (4.97 GiB) and
+    the pool and nothing of size beside; no instruction puts out a second
+    pool. One loop over the slots keeps the token step's and the chunk
+    call's temporaries under 2 MB; the 8-step burst holds a relayout of the
+    ``wq``/``wk``/``wv`` stacks that XLA hoists out of its loop over the
+    steps, 1.13 GiB (``deepspeed_tpu/inference/model_runner.py::
+    _scan_layers``). The fullest program fits 15.75 GiB with 1.7 to
+    spare."""
+    import json
+
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.models.zoo import get_model
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "ouro-2.6b-serve-c1.json")) as f:
+        cell = json.load(f)
+    e = cell["engine"]
+    blocks, bs, pages, seqs = (e["kv_blocks"], e["kv_block_size"],
+                               e["max_blocks_per_seq"], e["max_seqs_per_step"])
+    model = get_model(cell["preset"], num_layers=cell["num_hidden_layers"],
+                      max_seq_len=pages * bs, param_dtype=BF16, remat=False)
+    cfg = model.config
+
+    def ids(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    slots = cell["total_ut_steps"] * cell["num_hidden_layers"]
+    kv = jax.ShapeDtypeStruct(
+        (slots, blocks, bs, 2, cfg.kv_heads, cfg.head_dim), BF16,
+        sharding=chip)
+    fns = engine_v2._shared_step_fns(cfg, None)
+    if program == "prefill":
+        lowered = fns["prefill"].lower(params, {"kv": kv}, ids(1, 256),
+                                       ids(1), ids(1), ids(1, pages))
+    else:
+        steps = {"steps": 8} if program == "multi_decode" else {}
+        lowered = fns[program].lower(params, {"kv": kv}, ids(seqs), ids(seqs),
+                                     ids(seqs, pages), ids(seqs), **steps)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert ("paged_decode" in text) == (program != "prefill")
+    mem = compiled.memory_analysis()
+    pool_bytes, gib = 2 * kv.size, 2**30
+    assert slots == 192 and round(pool_bytes / gib, 2) == 7.9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert 12.86 < mem.argument_size_in_bytes / gib < 12.88
+    shape = f"bf16[{slots},{blocks},{bs},2,{cfg.kv_heads},{cfg.head_dim}]"
+    assert not [line for line in text.splitlines()
+                if shape in line.split("=")[0] and " copy(" in line]
+    temp = mem.temp_size_in_bytes
+    print(program, mem.argument_size_in_bytes / gib, temp / gib)
+    if program == "multi_decode":
+        assert 1.0 * gib < temp < 1.2 * gib
+    else:
+        assert temp < 2 * 2**20
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + temp)
+    assert total < 14.1 * gib
+
+
 def _zero3_fsdp4_step(monkeypatch, layers, job_extra=None):
     """The train step of ``mistral-7b-train-c4``'s job at ``layers`` of
     its 12, lowered on abstract state for the described 2x2: the engine
