@@ -164,6 +164,104 @@ _TOKENS_OF = {"gather": "tokens_gather", "prefill": "tokens_prefill_kernel",
 # the gather program and is counted apart from a step's own gather calls)
 PROGRAMS = ("gather", "prefill", "decode", "multi_decode", "spec")
 
+# the phases of a step: every ``dstpu/<phase>`` span that lies inside a
+# ``dstpu/serve_step`` (InferenceEngineV2._phase opens them all)
+PHASES = ("admit", "schedule", "build_batch", "dispatch", "fetch",
+          "bookkeep", "journal")
+# what a step can be, by the program calls whose results it read
+STEP_KINDS = ("mixed", "prefill", "lone", "burst", "empty")
+_CHUNK_PROGRAMS = ("prefill", "gather")
+# why ``_plan_decode_burst`` planned no burst (``burst_refused_<reason>``)
+# and why it issued no call ahead of the one in flight
+# (``ahead_refused_<reason>``): one counter a return
+BURST_REFUSALS = ("prefill_pending", "budget", "seq_cap", "pool")
+AHEAD_REFUSALS = ("drafter", "queue", "free_slot", "batch_changed",
+                  "budget", "seq_cap", "pool")
+
+
+def step_kind(calls) -> str:
+    """The kind of a step, one of STEP_KINDS, from the ``(program,
+    token_steps)`` of the program calls whose results it read: those it
+    issued, but for a burst it left in flight (``ahead=1`` on its dispatch
+    span), which belongs to the step that collects it. ``mixed``: a chunk
+    call (``prefill`` or ``gather``) and token rows in one step, the step
+    whose one token waits for a prompt beside it; ``prefill``: chunk calls
+    alone (a ``gather`` call does not say whether it carried token rows
+    too, and counts as chunks); ``burst``: a call that makes several token
+    steps (``multi_decode``); ``lone``: one token step (``decode``, or a
+    speculative round's ``spec``); ``empty``: no call. The engine's counters
+    and the benchmark's readers of a profile both come through here."""
+    chunks = rows = burst = False
+    for program, token_steps in calls:
+        if program in _CHUNK_PROGRAMS:
+            chunks = True
+        else:
+            rows = True
+            burst = burst or token_steps > 1
+    if chunks:
+        return "mixed" if rows else "prefill"
+    if burst:
+        return "burst"
+    return "lone" if rows else "empty"
+
+
+class _StepRecord:
+    """What a step books of itself: its wall clock by phase, the calls whose
+    results it read, the tokens it returns. One an engine, the context
+    manager of its ``dstpu/serve_step`` span and of each phase's span
+    (phases are siblings: one is open at a time), folded into ``stats`` and
+    the flight recorder when the step closes (_close_step). Calls and tokens
+    read outside a step (_drain) wait here for the step that delivers them;
+    a phase outside a step books no time."""
+
+    __slots__ = ("engine", "t0", "phases", "calls", "rows", "tokens",
+                 "_step_span", "_open", "_phase_span", "_phase_t0")
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.t0: Optional[float] = None        # None: no step is open
+        self.phases = dict.fromkeys(PHASES, 0.0)
+        self._open: Optional[str] = None       # the phase that is open
+        self.clear()
+
+    def clear(self) -> None:
+        for name in PHASES:
+            self.phases[name] = 0.0
+        self.calls: List[Tuple[str, int]] = []
+        self.rows = self.tokens = 0
+
+    def step(self, step_span) -> "_StepRecord":
+        self._step_span = step_span
+        return self
+
+    def phase(self, name: str, phase_span) -> "_StepRecord":
+        if self._open is not None:
+            raise RuntimeError(f"phase {name!r} inside phase {self._open!r}: "
+                               "the phases of a step do not nest")
+        self._open, self._phase_span = name, phase_span
+        return self
+
+    def __enter__(self):
+        if self._open is None:
+            self._step_span.__enter__()
+            self.t0 = time.perf_counter()
+        else:
+            self._phase_span.__enter__()
+            self._phase_t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        now = time.perf_counter()
+        if self._open is None:
+            self.engine._close_step(now - self.t0)
+            self.t0 = None
+            return self._step_span.__exit__(*exc)
+        if self.t0 is not None:
+            self.phases[self._open] += now - self._phase_t0
+        self._open = None
+        return self._phase_span.__exit__(*exc)
+
+
 _PICK_GREEDY = dstpu_pick_greedy
 _TAKE_ROWS = dstpu_take_rows
 _PICK_GREEDY_ALL = dstpu_pick_greedy_all
@@ -359,7 +457,24 @@ class InferenceEngineV2:
                       # those issued from its put() to that token, and
                       # those of them that carried it
                       "steps_dispatched": 0, "first_token_calls": 0,
-                      "first_token_own_calls": 0}
+                      "first_token_own_calls": 0,
+                      # what _plan_decode_burst decided, one counter a
+                      # return (the refusals are added below), and the
+                      # token steps the planned bursts stood under
+                      # ``decode_steps``
+                      "bursts_planned": 0, "burst_steps_clamped": 0}
+        self.stats.update(
+            dict.fromkeys(["burst_refused_" + r for r in BURST_REFUSALS]
+                          + ["ahead_refused_" + r for r in AHEAD_REFUSALS], 0))
+        # a step's own record, folded by its kind when it closes
+        # (_close_step): steps, their wall seconds, the seconds of them the
+        # host blocked on the device (the ``fetch`` phases) and the tokens
+        # they returned
+        for kind in STEP_KINDS:
+            self.stats.update({f"steps_{kind}": 0, f"step_s_{kind}": 0.0,
+                               f"step_wait_s_{kind}": 0.0,
+                               f"step_tokens_{kind}": 0})
+        self._rec = _StepRecord(self)
         for program in PROGRAMS:
             # calls, the token rows they really carried, the rows they
             # computed (the program's padded layout), the passes over
@@ -453,6 +568,8 @@ class InferenceEngineV2:
             "serve.admission_wait_seconds", labels=lbl)
         self._spec_hist = self._hub.histogram("serve.spec_accepted_len",
                                               labels=lbl)
+        # the gauges are read, not pushed: the hub asks when somebody looks
+        self._hub.add_provider(self._serve_gauges, labels=lbl)
         # serving shares the crash flight recorder: a wedged serve step
         # dumps the last admits/steps the same way a training hang does
         self._flight = get_flight_recorder()
@@ -594,8 +711,6 @@ class InferenceEngineV2:
             self.tracer.on_enqueue(uid, len(toks),
                                    queue_depth=len(self._queue))
         self._admit_from_queue()
-        self._hub.gauge("serve.queue_wait_depth", len(self._queue),
-                        labels=self._metric_labels)
 
     def _admit_from_queue(self) -> None:
         """Admit waiting requests strictly FIFO while capacity lasts.
@@ -632,8 +747,6 @@ class InferenceEngineV2:
             self._admission_hist.observe(now - req.enqueue_time)
             self.stats["admission_wait_s"] += now - req.enqueue_time
             self.stats["admitted"] += 1
-        self._hub.gauge("serve.queue_wait_depth", len(self._queue),
-                        labels=self._metric_labels)
 
     def _release_seq(self, uid: int, requeue: bool = False
                      ) -> Optional[float]:
@@ -703,8 +816,6 @@ class InferenceEngineV2:
         self._hub.counter_add("serve.preempted", labels=self._metric_labels)
         self._hub.counter_add(f"serve.preempted_reason.{reason}",
                               labels=self._metric_labels)
-        self._hub.gauge("serve.queue_wait_depth", len(self._queue),
-                        labels=self._metric_labels)
 
     def _page_out(self, seq, reason: str = "paged_out") -> bool:
         """Preempt ``seq`` by PAGING its KV to the host tier instead of
@@ -1031,7 +1142,43 @@ class InferenceEngineV2:
         programs dispatched under them."""
         self._step_id += 1
         self._step_calls = 0
-        return span("serve_step", step_id=self._step_id)
+        return self._rec.step(span("serve_step", step_id=self._step_id))
+
+    def _phase(self, name: str, **ids):
+        """The one place a phase of a step opens: the ``dstpu/<name>``
+        profiler span with its ``ids``, and the phase's seconds on the
+        step's record (one of PHASES; they are siblings, none inside
+        another)."""
+        return self._rec.phase(name, span(name, **ids))
+
+    def _close_step(self, wall: float) -> None:
+        """Fold the closing step's record into ``stats`` under the step's
+        kind (step_kind), once a step: ``steps_<kind>``, ``step_s_<kind>``
+        (``wall``), ``step_wait_s_<kind>`` (its ``fetch`` phases: the host
+        blocked on the device) and ``step_tokens_<kind>``; the hub's token
+        counter once a step; and, for a step that read a call, its row in
+        the flight recorder: the operator's per-step ledger."""
+        rec = self._rec
+        kind = step_kind(rec.calls)
+        wait = rec.phases["fetch"]
+        self.stats["steps_" + kind] += 1
+        self.stats["step_s_" + kind] += wall
+        self.stats["step_wait_s_" + kind] += wait
+        self.stats["step_tokens_" + kind] += rec.tokens
+        if rec.tokens:
+            self._hub.counter_add("serve.tokens_emitted", rec.tokens,
+                                  labels=self._metric_labels)
+        if rec.calls and self._flight.enabled:
+            row = {name + "_ms": round(1e3 * s, 3)
+                   for name, s in rec.phases.items()}
+            if any(program == "spec" for program, _ in rec.calls):
+                row["spec"] = True
+            # ``step_kind``: an event's own ``kind`` is "serve_step"
+            self._flight.record("serve_step", step_kind=kind, tokens=rec.rows,
+                                emitted=rec.tokens,
+                                wall_ms=round(1e3 * wall, 3),
+                                wait_ms=round(1e3 * wait, 3), **row)
+        rec.clear()
 
     def _dispatch(self, program: str, seqs, tokens: int,
                   token_steps: int = 1, chunks: int = 0,
@@ -1053,7 +1200,8 @@ class InferenceEngineV2:
         call as its own. ``counts``: a dict to take the call's counters in
         place of ``stats``, for a burst, whose counters move with its
         tokens (_collect_burst); a call dispatched while the one before it
-        is unread says ``ahead=1`` in ``shape``."""
+        is unread says ``ahead=1`` in ``shape``. The step's record takes the
+        call where its counters move: here, or where a burst is collected."""
         if program == "prefill":
             padded_rows = shape["S"] * shape["tq"]
         elif program in ("decode", "multi_decode"):
@@ -1075,6 +1223,8 @@ class InferenceEngineV2:
             mine["burst_steps"] = int(program == "multi_decode")
         if counts is None:
             self._count(mine)
+            self._rec.calls.append((program, token_steps))
+            self._rec.rows += tokens
         else:
             counts.update(mine)
         if self._calls_at_put:
@@ -1082,10 +1232,11 @@ class InferenceEngineV2:
                 waiting = self._calls_at_put.get(seq.uid)
                 if waiting is not None:
                     waiting[1] += 1
-        return span("dispatch", program=program, step_id=self._step_id,
-                    call=call, seqs=len(seqs), tokens=tokens,
-                    padded_rows=padded_rows, token_steps=token_steps,
-                    ut_steps=self._ut_steps, chunks=chunks, **shape)
+        return self._phase("dispatch", program=program,
+                           step_id=self._step_id, call=call, seqs=len(seqs),
+                           tokens=tokens, padded_rows=padded_rows,
+                           token_steps=token_steps, ut_steps=self._ut_steps,
+                           chunks=chunks, **shape)
 
     def _count(self, counts: Dict[str, int]) -> None:
         for name, n in counts.items():
@@ -1099,7 +1250,7 @@ class InferenceEngineV2:
         tokens.)"""
         self._drain()
         with self._open_step():
-            with span("admit"):
+            with self._phase("admit"):
                 self._admit_from_queue()
             return self._splitfuse_step(temperature, seed, eos_token_id)
 
@@ -1143,7 +1294,7 @@ class InferenceEngineV2:
 
     def _splitfuse_step(self, temperature: float, seed: int,
                         eos_token_id: Optional[int]) -> Dict[int, int]:
-        with span("schedule"):
+        with self._phase("schedule"):
             scheduled = self.scheduler.schedule()
             self._release_finished()
             if not scheduled:
@@ -1158,7 +1309,7 @@ class InferenceEngineV2:
             mine = [scheduled[i] for i in part]
             call_of.update(dict.fromkeys(part, self._step_calls))
             with self.mesh:
-                with span("build_batch"):
+                with self._phase("build_batch"):
                     fn, program, args, pools, batch = \
                         self._build_step_call(mine)
                     shape = (dict(zip(("S", "tq"), args[0].shape))
@@ -1180,14 +1331,12 @@ class InferenceEngineV2:
             # the share of prompt steps that were reads 0, and goes with
             # ``prefill_gather_fallbacks`` and that program (ROADMAP.md, D12)
             self.stats["prefill_kernel_steps"] += 1
-            self._hub.gauge("serve.paged_fallback_ratio", 0.0,
-                            labels=self._metric_labels)
 
         # Sample ON DEVICE and fetch only token ids (greedy) or just the
         # consumed rows (stochastic). Materializing the full [T, V]
         # logits host-side is 131 MB/step at a 256-token budget x 128k
         # vocab; the ids are 4 bytes/sequence.
-        with span("bookkeep"):
+        with self._phase("bookkeep"):
             consumers, picks = [], []
             for part, program, logits, batch in runs:
                 stride = logits.shape[1] if logits.ndim == 3 else 1
@@ -1214,7 +1363,7 @@ class InferenceEngineV2:
         emitted: Dict[int, int] = {}
         last_program = runs[-1][1]
         if consumers:
-            with span("fetch"), self.mesh:
+            with self._phase("fetch"), self.mesh:
                 # the host blocks on the device here: the picked ids (or
                 # rows) are the step's only result it reads. Several
                 # programs: each one's sampled rows, put in the order of
@@ -1244,9 +1393,9 @@ class InferenceEngineV2:
                     rows_np = np.asarray(self._take_rows(logits, idx_dev))
                 self._fetch_counters(counted)
         elif self._counters:
-            with span("fetch"):       # no token to read: the counters alone
+            with self._phase("fetch"):  # no token to read: the counters alone
                 self._fetch_counters(counted)
-        with span("bookkeep"):
+        with self._phase("bookkeep"):
             for slot, seq, program in consumers:
                 if temperature == 0.0:
                     tok = int(toks_np[slot])
@@ -1262,10 +1411,7 @@ class InferenceEngineV2:
                     seq.done = True
             now = time.perf_counter()
             self._step_hist.observe(now - t0)
-            tokens = sum(int(r[3].num_tokens) for r in runs)
-            self._flight.record("serve_step", tokens=tokens,
-                                emitted=len(emitted),
-                                wall_ms=round((now - t0) * 1000.0, 3))
+            self._rec.tokens += len(emitted)
             if self.tracer.enabled:
                 # one PREFILL span per prompt chunk this step advanced;
                 # the span start backdates by the step wall so prefill
@@ -1283,7 +1429,6 @@ class InferenceEngineV2:
                                                call=call_of[i])
             for uid in emitted:
                 self._note_emitted(uid, 1, now)
-            self._update_serve_gauges()
             self._release_finished()
         return emitted
 
@@ -1404,8 +1549,6 @@ class InferenceEngineV2:
         self.tracer.on_emit(uid, n_tokens,
                             spec_overhead_ms=spec_overhead_ms,
                             step_id=self._step_id)
-        self._hub.counter_add("serve.tokens_emitted", n_tokens,
-                              labels=self._metric_labels)
         admit = self._admit_time.pop(uid, None)
         last = self._last_emit_time.get(uid)
         if admit is not None:
@@ -1423,37 +1566,42 @@ class InferenceEngineV2:
                 self._decode_hist.observe(per_tok)
         self._last_emit_time[uid] = now
 
-    def _update_serve_gauges(self) -> None:
-        live = [s for s in self.state.seqs.values() if not s.done]
-        self._hub.gauge("serve.queue_depth", len(live),
-                        labels=self._metric_labels)
-        self._hub.gauge("serve.queue_wait_depth", len(self._queue),
-                        labels=self._metric_labels)
-        self._hub.gauge("serve.pending_prefill_tokens",
-                        sum(s.pending_prefill for s in live),
-                        labels=self._metric_labels)
-        self._hub.gauge("serve.kv_free_blocks", self.kv_cache.free_blocks,
-                        labels=self._metric_labels)
+    def _serve_gauges(self) -> Dict[str, float]:
+        """The engine's gauges as they read now: the hub's provider
+        (``MetricsHub.add_provider``), called on the thread of whoever
+        reads the hub and never by a step. ``serve.paged_fallback_ratio``
+        appears with the first step whose chunks went through the kernel
+        path or fell back (no step of it is left to the gather program any
+        more: it reads 0 and goes with ``prefill_gather_fallbacks`` and
+        that program, ROADMAP.md D12), the burst's two with the first burst
+        read."""
+        st = self.stats
+        live = [s for s in list(self.state.seqs.values()) if not s.done]
+        out = {"serve.queue_depth": len(live),
+               "serve.queue_wait_depth": len(self._queue),
+               "serve.pending_prefill_tokens":
+                   sum(s.pending_prefill for s in live),
+               "serve.kv_free_blocks": self.kv_cache.free_blocks,
+               "serve.batch_seq_occupancy":
+                   self.scheduler.last_scheduled_seqs / max(1, self.max_seqs),
+               "serve.batch_token_occupancy":
+                   self.scheduler.last_scheduled_tokens
+                   / max(1, self.max_tokens)}
         if self.kv_cache.prefix_cache is not None:
-            self._hub.gauge("serve.prefix_cached_blocks",
-                            self.kv_cache.prefix_cache.cached_blocks,
-                            labels=self._metric_labels)
-        self._hub.gauge("serve.batch_seq_occupancy",
-                        self.scheduler.last_scheduled_seqs
-                        / max(1, self.max_seqs),
-                        labels=self._metric_labels)
-        self._hub.gauge("serve.batch_token_occupancy",
-                        self.scheduler.last_scheduled_tokens
-                        / max(1, self.max_tokens),
-                        labels=self._metric_labels)
+            out["serve.prefix_cached_blocks"] = \
+                self.kv_cache.prefix_cache.cached_blocks
+        tried = st["prefill_gather_fallbacks"] + st["prefill_kernel_steps"]
+        if tried:
+            out["serve.paged_fallback_ratio"] = \
+                st["prefill_gather_fallbacks"] / tried
         if self._burst_capacity > 0:
-            self._hub.gauge("serve.burst_efficiency",
-                            self._burst_tokens / self._burst_capacity,
-                            labels=self._metric_labels)
-            self._hub.gauge("serve.issued_ahead_share",
-                            self.stats["calls_issued_ahead"]
-                            / max(1, self.stats["calls_multi_decode"]),
-                            labels=self._metric_labels)
+            out["serve.burst_efficiency"] = \
+                self._burst_tokens / self._burst_capacity
+            out["serve.issued_ahead_share"] = \
+                st["calls_issued_ahead"] / max(1, st["calls_multi_decode"])
+        if self._spec_accept_ewma is not None:
+            out["serve.spec_accept_ewma"] = self._spec_accept_ewma
+        return out
 
     def _burst_step(self, eos_token_id: Optional[int]
                     ) -> Optional[Dict[int, List[int]]]:
@@ -1490,7 +1638,7 @@ class InferenceEngineV2:
         ``after``: the call in flight that this one follows. Its sequences
         stand ``after.steps`` tokens past what the host has accounted, and
         its ``last`` row is this call's ``token_ids``."""
-        with span("schedule"):
+        with self._phase("schedule"):
             K = self._plan_decode_burst(after)
         if K is None:
             return None
@@ -1500,7 +1648,7 @@ class InferenceEngineV2:
         else:
             live, unread = after.live, after.steps
         with self.mesh:
-            with span("build_batch"):
+            with self._phase("build_batch"):
                 S = self.max_seqs
                 d_pos = np.zeros(S, np.int32)
                 ctx = np.zeros(S, np.int32)
@@ -1542,11 +1690,13 @@ class InferenceEngineV2:
         released once no call that writes its pages or its state is in
         flight."""
         K = flight.steps
-        with span("fetch"):
+        with self._phase("fetch"):
             toks_np = np.asarray(flight.toks)  # [K, S]: one fetch per K tokens
             self._fetch_counters([(flight.pools, True)])
-        with span("bookkeep"):
+        with self._phase("bookkeep"):
             self._count(flight.counts)
+            self._rec.calls.append(("multi_decode", K))
+            self._rec.rows += K * len(flight.live)
             eos_token_id = flight.eos_token_id
             emitted: Dict[int, List[int]] = {}
             for i, s in enumerate(flight.live):
@@ -1574,12 +1724,12 @@ class InferenceEngineV2:
             # the tail)
             n_emitted = sum(len(v) for v in emitted.values())
             self.stats["tokens_multi_decode"] += n_emitted
+            self._rec.tokens += n_emitted
             self._burst_tokens += n_emitted
             self._burst_capacity += K * len(flight.live)
             for uid, toks in emitted.items():
                 if toks:
                     self._note_emitted(uid, len(toks), now)
-            self._update_serve_gauges()
             if self._inflight is None:
                 self._release_finished()
         return emitted
@@ -1605,7 +1755,31 @@ class InferenceEngineV2:
     def _plan_decode_burst(self, after: Optional[_BurstInFlight] = None
                            ) -> Optional[int]:
         """The burst length K this round can run, with KV capacity for
-        the whole burst allocated, or None.
+        the whole burst allocated, or None; and the one counter that says
+        which: ``bursts_planned`` (with ``burst_steps_clamped``, the token
+        steps K stood under ``decode_steps``), or the reason it was refused
+        (_burst_verdict): ``burst_refused_<reason>``, and with ``after``
+        ``ahead_refused_<reason>``."""
+        live = [s for s in self.state.seqs.values() if not s.done]
+        K, refused = self._burst_verdict(live, after)
+        if refused is not None:
+            self.stats[("burst_refused_" if after is None
+                        else "ahead_refused_") + refused] += 1
+        elif K is not None:
+            self.stats["bursts_planned"] += 1
+            self.stats["burst_steps_clamped"] += self.decode_steps - K
+            unread = 0 if after is None else after.steps
+            for s in live:
+                ok = self.state.ensure_capacity(s, s.seen_tokens + unread + K)
+                assert ok, "capacity probe said yes but allocation failed"
+        return K
+
+    def _burst_verdict(self, live, after: Optional[_BurstInFlight]
+                       ) -> Tuple[Optional[int], Optional[str]]:
+        """For the ``live`` sequences: ``(K, None)``, ``(None, why not)``
+        with one of BURST_REFUSALS (AHEAD_REFUSALS with ``after``), or
+        ``(None, None)`` where the question does not arise (bursts off,
+        nothing live). No side effect.
 
         With ``after``, the call in flight: whether the engine runs a call
         ahead, from what it can see. The batch is full (an arrival could
@@ -1616,18 +1790,26 @@ class InferenceEngineV2:
         the sequences are the call's own with none ended, none reaches its
         budget inside the call in flight (a finish is an admission: the
         blocking path's), and there is capacity for both calls."""
-        live = [s for s in self.state.seqs.values() if not s.done]
-        if (self.decode_steps <= 1 or not live or len(live) > self.max_seqs
-                or any((not s.in_decode) or s.pending_prefill for s in live)):
-            return None
+        if self.decode_steps <= 1 or not live:
+            return None, None
         unread = 0
         if after is not None:
             unread = after.steps
-            if (self._drafter is not None or self._queue
-                    or len(live) != self.max_seqs
-                    or len(after.live) != len(live)
+            if self._drafter is not None:
+                return None, "drafter"
+            if self._queue:
+                return None, "queue"
+            if len(live) != self.max_seqs:
+                return None, "free_slot"
+            if (len(after.live) != len(live)
                     or any(a is not b for a, b in zip(after.live, live))):
-                return None
+                return None, "batch_changed"
+        if (len(live) > self.max_seqs
+                or any((not s.in_decode) or s.pending_prefill for s in live)):
+            # a prompt still has a chunk to run (with a call in flight: a
+            # sequence that call does not know)
+            return None, ("prefill_pending" if after is None
+                          else "batch_changed")
         # clamp the burst to the shortest remaining budget: probing
         # capacity K tokens past a sequence that only needs 1 more would
         # trip ensure_capacity's per-seq-cap kill and truncate output
@@ -1635,7 +1817,7 @@ class InferenceEngineV2:
         K = min(self.decode_steps,
                 max(1, min(s.gen_budget_left for s in live) - unread))
         if K <= 1:
-            return None
+            return None, "budget"
         # side-effect-free capacity probe first: per-seq cap, then total
         # pool demand (a partial speculative grab would strand blocks
         # and push the fallback step into victim preemption)
@@ -1644,14 +1826,11 @@ class InferenceEngineV2:
             blocks = self.kv_cache.blocks_needed(s.seen_tokens + unread + K)
             if (self.state.max_blocks_per_seq is not None
                     and blocks > self.state.max_blocks_per_seq):
-                return None  # near the per-seq cap: per-token tail
+                return None, "seq_cap"  # near the per-seq cap: per-token tail
             need_total += max(0, blocks - len(s.kv_blocks))
         if need_total > self.kv_cache.free_blocks:
-            return None
-        for s in live:
-            ok = self.state.ensure_capacity(s, s.seen_tokens + unread + K)
-            assert ok, "capacity probe said yes but allocation failed"
-        return K
+            return None, "pool"
+        return K, None
 
     def _spec_round_k(self, seq, occ: float) -> int:
         """Draft length for ``seq`` this spec round. Fixed ``spec_k``
@@ -1690,13 +1869,13 @@ class InferenceEngineV2:
         run instead (prefill pending, no drafts, or KV-starved)."""
         if self._drafter is None:
             return None
-        with span("schedule"):
+        with self._phase("schedule"):
             sched = self._plan_spec_round()
         if sched is None:
             return None
         t_start = time.perf_counter()
         with self.mesh:
-            with span("build_batch"):
+            with self._phase("build_batch"):
                 batch = build_ragged_batch(sched, self.max_tokens,
                                            self.max_seqs,
                                            self.max_blocks_per_seq)
@@ -1710,9 +1889,9 @@ class InferenceEngineV2:
                 logits, new_kv = self._step_fn(
                     self.params, self.kv_cache.kv_state, *args)
             self.kv_cache.set_kv_state(new_kv)
-            with span("fetch"):
+            with self._phase("fetch"):
                 greedy = np.asarray(self._pick_greedy_all(logits))
-        with span("bookkeep"):
+        with self._phase("bookkeep"):
             return self._accept_spec_round(sched, batch, greedy, t_start,
                                            eos_token_id)
 
@@ -1845,18 +2024,13 @@ class InferenceEngineV2:
             emitted[s.uid] = final
             wasted_rows[s.uid] = n - len(final)
         self.stats["spec_steps"] += 1
-        if self._spec_accept_ewma is not None:
-            self._hub.gauge("serve.spec_accept_ewma", self._spec_accept_ewma,
-                            labels=self._metric_labels)
         now = time.perf_counter()
         self._step_hist.observe(now - t_start)
         round_wall_ms = (now - t_start) * 1e3
-        self._flight.record("serve_step", tokens=batch.num_tokens,
-                            emitted=sum(len(v) for v in emitted.values()),
-                            spec=True,
-                            wall_ms=round(round_wall_ms, 3))
         # a speculative round runs the gather program
-        self.stats["tokens_gather"] += sum(len(v) for v in emitted.values())
+        n_emitted = sum(len(v) for v in emitted.values())
+        self.stats["tokens_gather"] += n_emitted
+        self._rec.tokens += n_emitted
         for uid, toks in emitted.items():
             if toks:
                 # this request's share of the verify round spent on
@@ -1866,7 +2040,6 @@ class InferenceEngineV2:
                     uid, len(toks), now,
                     spec_overhead_ms=round_wall_ms * wasted_rows[uid]
                     / max(1, batch.num_tokens))
-        self._update_serve_gauges()
         self._release_finished()
         return emitted
 
@@ -1886,7 +2059,7 @@ class InferenceEngineV2:
         sequence's end are those of an engine that reads every call before
         it issues the next."""
         with self._open_step():
-            with span("admit"):
+            with self._phase("admit"):
                 self._admit_from_queue()
             out: Optional[Dict[int, List[int]]] = None
             if temperature == 0.0:
@@ -1905,7 +2078,7 @@ class InferenceEngineV2:
                 for uid, toks in out.items():
                     self._undelivered.setdefault(uid, []).extend(toks)
                 out = self.take_undelivered()
-            with span("journal"):
+            with self._phase("journal"):
                 jr = get_journal()
                 if jr is not None and out and jr.claim_ingress(
                         self._journal_owner) == self._journal_owner:
@@ -1960,6 +2133,10 @@ class InferenceEngineV2:
         if self._closed:
             return
         self._drain()
+        if self._rec.tokens:    # read by that drain: no step delivers them
+            self._hub.counter_add("serve.tokens_emitted", self._rec.tokens,
+                                  labels=self._metric_labels)
+            self._rec.tokens = 0
         self._closed = True
         self.tracer.detach_flight()
         self._hub.write_prometheus()
@@ -1988,22 +2165,14 @@ class InferenceEngineV2:
         the hub's Prometheus page (docs/observability.md). A burst in
         flight is read first, so the counters and the tokens agree."""
         self._drain()
-        live = [s for s in self.state.seqs.values() if not s.done]
+        gauges = self._serve_gauges()
         out: Dict[str, Any] = {
             "ttft": self._ttft_hist.snapshot(),
             "decode_token_latency": self._decode_hist.snapshot(),
             "step_latency": self._step_hist.snapshot(),
             "admission_wait": self._admission_hist.snapshot(),
-            "queue_depth": len(live),
-            "queue_wait_depth": len(self._queue),
-            "pending_prefill_tokens": sum(s.pending_prefill for s in live),
-            "kv_free_blocks": self.kv_cache.free_blocks,
             "kv_quant_bits": self.kv_cache.quant_bits,
             "handoff_wire": self._handoff_wire,
-            "batch_seq_occupancy": (self.scheduler.last_scheduled_seqs
-                                    / max(1, self.max_seqs)),
-            "batch_token_occupancy": (self.scheduler.last_scheduled_tokens
-                                      / max(1, self.max_tokens)),
             "scheduler": dict(self.scheduler.stats),
             "stats": dict(self.stats,
                           fallback_reasons=dict(
@@ -2012,9 +2181,13 @@ class InferenceEngineV2:
                               self.stats["preempt_reasons"])),
             "request_trace": self.tracer.snapshot(),
         }
-        if self._burst_capacity > 0:
-            out["burst_efficiency"] = (self._burst_tokens
-                                       / self._burst_capacity)
+        # the gauges the hub is given, under their bare names
+        for name in ("queue_depth", "queue_wait_depth",
+                     "pending_prefill_tokens", "kv_free_blocks",
+                     "batch_seq_occupancy", "batch_token_occupancy",
+                     "burst_efficiency"):
+            if "serve." + name in gauges:
+                out[name] = gauges["serve." + name]
         if self.kv_cache.prefix_cache is not None:
             out["prefix_cache"] = self.kv_cache.prefix_cache.snapshot()
         if self.stats["spec_proposed"] > 0:

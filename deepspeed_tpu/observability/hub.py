@@ -20,14 +20,20 @@ Compile/retrace visibility: jax.monitoring event listeners (registered
 once) count XLA compilations
 and their wall time; ``StepTrace.compile_events`` > 0 on a mid-run step
 is the classic silent-retrace regression signature.
+
+Gauges come two ways: pushed (``gauge``), and *provided*: an engine
+registers one method (``add_provider``) that says what its gauges read,
+and the hub calls it when somebody reads (``snapshot``, ``to_prometheus``,
+the Prometheus sink, ``gauges``), not when the engine steps.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+import weakref
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from deepspeed_tpu.observability.histogram import Histogram
 from deepspeed_tpu.observability.sinks import (JSONLSink, PrometheusTextSink,
@@ -43,11 +49,15 @@ _COMPILE_LOCK = threading.Lock()
 _COMPILE_EVENTS = 0
 _COMPILE_SECS = 0.0
 _LISTENERS_REGISTERED = False
+# the one event a compilation raises once: tracing, lowering and the
+# persistent cache's lookups raise others with "compile" in their names
+# (and ``compile_time_saved_sec`` is time that was not spent)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _on_compile_duration(event: str, duration: float, **kw) -> None:
     global _COMPILE_EVENTS, _COMPILE_SECS
-    if "compil" not in event:
+    if event != _COMPILE_EVENT:
         return
     with _COMPILE_LOCK:
         _COMPILE_EVENTS += 1
@@ -72,7 +82,9 @@ def compile_stats() -> Dict[str, float]:
 class MetricsHub:
     def __init__(self, step_history: int = 512):
         self._lock = threading.Lock()
-        self.gauges: Dict[str, float] = {}
+        self._gauges: Dict[str, float] = {}      # pushed (``gauge``)
+        # (weak reference to a bound method, its labels): see add_provider
+        self._providers: List[Tuple[Any, Optional[Dict[str, str]]]] = []
         self.counters: Dict[str, float] = {}
         self.histograms: Dict[str, Histogram] = {}
         self.step_history: deque = deque(maxlen=step_history)
@@ -133,7 +145,52 @@ class MetricsHub:
         if labels:
             name = labeled_name(name, labels)
         with self._lock:
-            self.gauges[name] = float(value)
+            self._gauges[name] = float(value)
+
+    def add_provider(self, method: Callable[[], Dict[str, float]],
+                     labels: Optional[Dict[str, str]] = None) -> None:
+        """Gauges computed when somebody reads them: ``method`` (a bound
+        method of the object the gauges describe) returns ``{name: value}``
+        as it stands now, and every read of the hub's gauges calls it and
+        files the values under ``labels``. The object is held weakly: a
+        provider whose object is gone is dropped at the next read, and its
+        series with it. It runs on the reader's thread, so it reads state a
+        step may be changing and must not iterate over it in place."""
+        with self._lock:
+            self._providers.append((weakref.WeakMethod(method), labels))
+
+    def _provided(self) -> Dict[str, float]:
+        """What the providers read now, by labeled name. Outside the lock:
+        a provider may ask the hub for something itself."""
+        with self._lock:
+            providers = list(self._providers)
+        out: Dict[str, float] = {}
+        dead = False
+        for ref, labels in providers:
+            method = ref()
+            if method is None:
+                dead = True
+                continue
+            try:
+                values = method()
+            except Exception:     # a reader's snapshot must not fail for it
+                logger.warning("metrics hub: a gauge provider failed",
+                               exc_info=True)
+                continue
+            for name, value in values.items():
+                out[labeled_name(name, labels)] = float(value)
+        if dead:
+            with self._lock:
+                self._providers = [e for e in self._providers
+                                   if e[0]() is not None]
+        return out
+
+    @property
+    def gauges(self) -> Dict[str, float]:
+        """Every gauge as it reads now, pushed and provided (a copy)."""
+        provided = self._provided()
+        with self._lock:
+            return {**self._gauges, **provided}
 
     def counter_add(self, name: str, n: float = 1.0,
                     labels: Optional[Dict[str, str]] = None) -> None:
@@ -195,8 +252,8 @@ class MetricsHub:
     def record_step(self, trace: StepTrace) -> None:
         with self._lock:
             self.step_history.append(trace)
-            self.gauges["train.step"] = trace.step
-            self.gauges["train.step_seconds"] = trace.wall_ms / 1000.0
+            self._gauges["train.step"] = trace.step
+            self._gauges["train.step_seconds"] = trace.wall_ms / 1000.0
             for name, val in (("train.loss", trace.loss),
                               ("train.grad_norm", trace.grad_norm),
                               ("train.lr", trace.lr),
@@ -206,7 +263,7 @@ class MetricsHub:
                               ("train.mfu", trace.mfu),
                               ("train.host_gap_ms", trace.host_gap_ms)):
                 if val is not None:
-                    self.gauges[name] = float(val)
+                    self._gauges[name] = float(val)
             self.counters["train.steps"] = \
                 self.counters.get("train.steps", 0.0) + 1.0
             if trace.tokens:
@@ -294,9 +351,10 @@ class MetricsHub:
     def snapshot(self) -> Dict[str, Any]:
         from deepspeed_tpu.utils import telemetry
 
+        gauges = self.gauges
         with self._lock:
             out: Dict[str, Any] = {
-                "gauges": dict(self.gauges),
+                "gauges": gauges,
                 "counters": dict(self.counters),
                 "histograms": {n: h.snapshot()
                                for n, h in self.histograms.items()},
@@ -310,8 +368,8 @@ class MetricsHub:
     def to_prometheus(self) -> str:
         from deepspeed_tpu.utils import telemetry
 
+        gauges = self.gauges
         with self._lock:
-            gauges = dict(self.gauges)
             counters = dict(self.counters)
             hists = dict(self.histograms)
         return render_prometheus(
