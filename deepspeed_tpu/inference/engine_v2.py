@@ -462,7 +462,10 @@ class InferenceEngineV2:
                       # return (the refusals are added below), and the
                       # token steps the planned bursts stood under
                       # ``decode_steps``
-                      "bursts_planned": 0, "burst_steps_clamped": 0}
+                      "bursts_planned": 0, "burst_steps_clamped": 0,
+                      # the blocks the planned bursts took from the prefix
+                      # cache's idle entries, and the plans that took any
+                      "burst_blocks_reclaimed": 0, "bursts_reclaiming": 0}
         self.stats.update(
             dict.fromkeys(["burst_refused_" + r for r in BURST_REFUSALS]
                           + ["ahead_refused_" + r for r in AHEAD_REFUSALS], 0))
@@ -1611,7 +1614,9 @@ class InferenceEngineV2:
 
         A burst applies only in steady state: every live sequence
         mid-decode, no prefill pending, and KV capacity for the whole burst
-        (the block tables are frozen for its duration). It has two halves:
+        among the pool's free blocks and the prefix cache's idle entries,
+        taken at the plan (the block tables are frozen for the burst's
+        duration). It has two halves:
         *issue* (plan, build, dispatch: _issue_burst) and *collect* (fetch,
         accept, bookkeeping: _collect_burst). This step collects the call
         the last step left in flight, or where there is none issues one and
@@ -1759,15 +1764,27 @@ class InferenceEngineV2:
         which: ``bursts_planned`` (with ``burst_steps_clamped``, the token
         steps K stood under ``decode_steps``), or the reason it was refused
         (_burst_verdict): ``burst_refused_<reason>``, and with ``after``
-        ``ahead_refused_<reason>``."""
+        ``ahead_refused_<reason>``.
+
+        What the free list lacks of the burst's blocks is reclaimed from
+        the prefix cache's idle entries at once, before any sequence grows
+        (``burst_blocks_reclaimed``, ``bursts_reclaiming``). With a call in
+        flight that is safe: an idle entry is a block no live sequence
+        holds, so no program in flight reads it, and the call that writes
+        it takes the pool's handle from the call in flight and so runs
+        behind it."""
         live = [s for s in self.state.seqs.values() if not s.done]
-        K, refused = self._burst_verdict(live, after)
+        K, refused, short = self._burst_verdict(live, after)
         if refused is not None:
             self.stats[("burst_refused_" if after is None
                         else "ahead_refused_") + refused] += 1
         elif K is not None:
             self.stats["bursts_planned"] += 1
             self.stats["burst_steps_clamped"] += self.decode_steps - K
+            if short > 0:
+                self.stats["burst_blocks_reclaimed"] += \
+                    self.kv_cache.reclaim(short)
+                self.stats["bursts_reclaiming"] += 1
             unread = 0 if after is None else after.steps
             for s in live:
                 ok = self.state.ensure_capacity(s, s.seen_tokens + unread + K)
@@ -1775,11 +1792,23 @@ class InferenceEngineV2:
         return K
 
     def _burst_verdict(self, live, after: Optional[_BurstInFlight]
-                       ) -> Tuple[Optional[int], Optional[str]]:
-        """For the ``live`` sequences: ``(K, None)``, ``(None, why not)``
-        with one of BURST_REFUSALS (AHEAD_REFUSALS with ``after``), or
-        ``(None, None)`` where the question does not arise (bursts off,
-        nothing live). No side effect.
+                       ) -> Tuple[Optional[int], Optional[str], int]:
+        """For the ``live`` sequences: ``(K, None, short)``, ``(None, why
+        not, 0)`` with one of BURST_REFUSALS (AHEAD_REFUSALS with
+        ``after``), or ``(None, None, 0)`` where the question does not arise
+        (bursts off, nothing live). ``short``: the blocks of the burst that
+        the free list lacks and the prefix cache's idle entries have. No
+        side effect.
+
+        Capacity is what the pool can give (``kv_cache.available_blocks``:
+        the free list and every idle prefix entry, which ``reclaim`` hands
+        over), as for a speculative round: a finished request's prompt
+        blocks stay in the prefix cache until something asks for them, so
+        the free list alone stands near empty in steady state. ``pool``
+        says that the pool, with everything idle counted, cannot hold the
+        burst. Under a host tier ``reclaim`` pages a block out by a read of
+        the pool, which waits for the call in flight: there the plan ahead
+        of a call keeps to the free list.
 
         With ``after``, the call in flight: whether the engine runs a call
         ahead, from what it can see. The batch is full (an arrival could
@@ -1791,25 +1820,25 @@ class InferenceEngineV2:
         budget inside the call in flight (a finish is an admission: the
         blocking path's), and there is capacity for both calls."""
         if self.decode_steps <= 1 or not live:
-            return None, None
+            return None, None, 0
         unread = 0
         if after is not None:
             unread = after.steps
             if self._drafter is not None:
-                return None, "drafter"
+                return None, "drafter", 0
             if self._queue:
-                return None, "queue"
+                return None, "queue", 0
             if len(live) != self.max_seqs:
-                return None, "free_slot"
+                return None, "free_slot", 0
             if (len(after.live) != len(live)
                     or any(a is not b for a, b in zip(after.live, live))):
-                return None, "batch_changed"
+                return None, "batch_changed", 0
         if (len(live) > self.max_seqs
                 or any((not s.in_decode) or s.pending_prefill for s in live)):
             # a prompt still has a chunk to run (with a call in flight: a
             # sequence that call does not know)
             return None, ("prefill_pending" if after is None
-                          else "batch_changed")
+                          else "batch_changed"), 0
         # clamp the burst to the shortest remaining budget: probing
         # capacity K tokens past a sequence that only needs 1 more would
         # trip ensure_capacity's per-seq-cap kill and truncate output
@@ -1817,7 +1846,7 @@ class InferenceEngineV2:
         K = min(self.decode_steps,
                 max(1, min(s.gen_budget_left for s in live) - unread))
         if K <= 1:
-            return None, "budget"
+            return None, "budget", 0
         # side-effect-free capacity probe first: per-seq cap, then total
         # pool demand (a partial speculative grab would strand blocks
         # and push the fallback step into victim preemption)
@@ -1826,11 +1855,16 @@ class InferenceEngineV2:
             blocks = self.kv_cache.blocks_needed(s.seen_tokens + unread + K)
             if (self.state.max_blocks_per_seq is not None
                     and blocks > self.state.max_blocks_per_seq):
-                return None, "seq_cap"  # near the per-seq cap: per-token tail
+                # near the per-seq cap: per-token tail
+                return None, "seq_cap", 0
             need_total += max(0, blocks - len(s.kv_blocks))
-        if need_total > self.kv_cache.free_blocks:
-            return None, "pool"
-        return K, None
+        free = self.kv_cache.free_blocks
+        ahead_of_a_page_out = (after is not None
+                               and self.kv_cache.host_tier is not None)
+        if need_total > (free if ahead_of_a_page_out
+                         else self.kv_cache.available_blocks):
+            return None, "pool", 0
+        return K, None, max(0, need_total - free)
 
     def _spec_round_k(self, seq, occ: float) -> int:
         """Draft length for ``seq`` this spec round. Fixed ``spec_k``

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2, _BurstInFlight
 from deepspeed_tpu.models.zoo import get_model
 from deepspeed_tpu.parallel.topology import TopologyConfig, build_mesh
 
@@ -244,3 +244,203 @@ def test_what_touches_the_engine_between_steps_reads_the_call_in_flight(
     if op == "migrate_out_session":     # what it had emitted, it delivered
         assert got[1] == base[1][:1 + 3 * K]
     assert not engine.state.seqs and not engine._undelivered
+
+
+# -- a burst is planned against what the pool can give ---------------------------
+#
+# Engines of 8-token blocks with the prefix cache on. A finished request leaves
+# its full prompt blocks idle in the cache, so the allocator's free list drains
+# while the pool does not: the burst plan counts the idle entries
+# (``kv_cache.available_blocks``) and takes what the free list lacks at once.
+
+PLAN = ("bursts_planned", "bursts_reclaiming", "burst_blocks_reclaimed",
+        "burst_refused_pool", "ahead_refused_pool")
+
+
+def _pooled(**kw):
+    args = dict(kv_block_size=8, prefix_cache=True, max_blocks_per_seq=8)
+    args.update(kw)
+    return _engine("tiny", **args)
+
+
+def _doc(seed, n=17):
+    """A prompt of two full blocks and a token: two idle entries once its
+    request has finished."""
+    return np.random.default_rng(100 + seed).integers(0, 200, n) \
+        .astype(np.int32)
+
+
+def _finish(engine, uid, prompt, max_new=2):
+    engine.put([uid], [prompt], max_new_tokens=max_new)
+    return engine.generate_all()[uid]
+
+
+def _drained(engine):
+    """Seven finished requests' prompt blocks idle in the cache; the free
+    list holds what is left."""
+    for i in range(7):
+        _finish(engine, 100 + i, _doc(i))
+    cache = engine.kv_cache.prefix_cache
+    assert cache.evictable_blocks == 14 == cache.cached_blocks
+
+
+@pytest.mark.parametrize("path", ["blocking", "ahead"])
+def test_a_free_list_drained_by_idle_prompt_blocks_still_plans_full_bursts(
+        devices, path):
+    """Sixteen usable blocks: fourteen idle entries of seven finished
+    requests and two for the prompts of two answers of 22 tokens, which end
+    in their fourth blocks. The bursts take six idle entries; the tokens are
+    those of single steps."""
+    def serve(**kw):
+        engine = _pooled(kv_blocks=17, **kw)
+        if path == "blocking":
+            _one_call_deep(engine)
+        _drained(engine)
+        assert engine.kv_cache.free_blocks == 2
+        return engine, _serve(engine, 22)
+
+    engine, got = serve()
+    single, want = serve(decode_steps=1)
+    assert got == want
+    assert {u: len(t) for u, t in got.items()} == {1: 22, 2: 22}
+    st = engine.stats
+    # five bursts of K, each a full one, none refused for the pool
+    assert st["bursts_planned"] == st["calls_multi_decode"] == 5
+    assert st["burst_steps_clamped"] == 0
+    assert st["burst_refused_pool"] == st["ahead_refused_pool"] == 0
+    assert st["calls_issued_ahead"] == (4 if path == "ahead" else 0)
+    # every block past the prompts' own comes from the idle entries, a
+    # burst's at once: a border a sequence every other burst
+    assert st["burst_blocks_reclaimed"] == 6
+    assert st["bursts_reclaiming"] == 3
+    # ... and the second prompt is a full block, cached in its turn
+    assert engine.kv_cache.prefix_cache.cached_blocks == 14 - 6 + 1
+    assert st["preempted"] == single.stats["preempted"] == 0
+    assert not any(single.stats[k] for k in PLAN)
+    engine.close(), single.close()
+
+
+def _two_decoding(engine, max_new=12):
+    """A full batch in decode, one token into its answers, a block border
+    inside the next burst and none in the next token; and the call that
+    would be in flight."""
+    engine.put([1, 2], [_doc(9, 7), _doc(8, 7)], max_new_tokens=max_new)
+    engine.step()
+    live = list(engine.state.seqs.values())
+    assert [s.seen_tokens for s in live] == [7, 7]
+    return _BurstInFlight(live, K, None, None, None, {}, None)
+
+
+@pytest.mark.parametrize("ahead", [False, True], ids=["blocking", "ahead"])
+@pytest.mark.parametrize("idle,free,planned", [
+    (2, 0, True),       # the free list empty, the idle entries are enough
+    (1, 1, True),       # one block of each
+    (0, 0, False),      # nothing anywhere (``_no_pool`` of test_step_record)
+    (1, 0, False),      # one short with every idle entry counted
+])
+def test_the_pool_refuses_only_what_free_and_idle_blocks_cannot_hold(
+        devices, ahead, idle, free, planned):
+    engine = _pooled(kv_blocks=33)
+    if idle:
+        _finish(engine, 100, _doc(0, 1 + 8 * idle))
+    flight = _two_decoding(engine)
+    # a burst of K, on top of the call in flight too, crosses one border a
+    # sequence: 7 + K and 7 + 2 K tokens end in the second block
+    need = 2
+    cache, pool = engine.kv_cache.prefix_cache, engine.kv_cache
+    pool.allocator.allocate(pool.free_blocks - free)
+    assert (pool.free_blocks, cache.evictable_blocks) == (free, idle)
+    assert pool.available_blocks == free + idle
+    before = {k: engine.stats[k] for k in PLAN}
+    got = engine._plan_decode_burst(flight if ahead else None)
+    moved = {k: engine.stats[k] - before[k] for k in PLAN
+             if engine.stats[k] != before[k]}
+    if planned:
+        assert got == K
+        assert moved == {"bursts_planned": 1, "bursts_reclaiming": 1,
+                         "burst_blocks_reclaimed": need - free}
+        assert (pool.free_blocks, cache.evictable_blocks) == \
+            (0, idle - (need - free))
+    else:
+        assert got is None
+        assert moved == {("ahead" if ahead else "burst") + "_refused_pool": 1}
+        # the refusal took nothing, and the single step it leaves to run
+        # needs no block: nobody is preempted
+        assert (pool.free_blocks, cache.evictable_blocks) == (free, idle)
+        engine.step()
+        assert engine.stats["preempted"] == 0 and len(engine.state.seqs) == 2
+    engine.close()
+
+
+def test_a_burst_plan_never_takes_a_prefix_a_live_sequence_holds(devices):
+    """Two requests on one document, one finished; beside them the idle
+    entries of two other documents. The bursts of the one that goes on take
+    the idle entries and leave the document it reads; a later request on an
+    evicted document computes it again. Every answer is that of an engine
+    without the cache."""
+    doc, old = _doc(0, 16), [_doc(1), _doc(2)]
+    asks = [np.concatenate([doc, _doc(10 + i, 3)]) for i in range(3)]
+
+    def serve(**kw):
+        engine = _one_call_deep(_pooled(**kw))
+        out = [_finish(engine, 100 + i, p) for i, p in enumerate(old)]
+        out.append(_finish(engine, 1, asks[0]))
+        engine.put([2], [asks[1]], max_new_tokens=2)
+        engine.put([3], [asks[2]], max_new_tokens=36)
+        got = engine.generate_all()
+        out += [got[2], got[3]]
+        held = [engine.holds_prefix_blocks(p) for p in asks[:1] + old]
+        out.append(_finish(engine, 4, old[0], 6))
+        return engine, out, held
+
+    # 9 usable blocks: the document's 2, the others' 4, and the 5 the long
+    # answer ends with beside its document: its bursts have to evict 2
+    engine, got, held = serve(kv_blocks=10)
+    plain, want, _ = serve(kv_blocks=64, prefix_cache=False)
+    assert got == want
+    st = engine.stats
+    assert st["prefix_hit_tokens"] >= 3 * 16 - 16     # both later asks hit
+    assert st["bursts_reclaiming"] >= 1 and st["burst_refused_pool"] == 0
+    assert st["burst_blocks_reclaimed"] == 2
+    # the least recently idle went, the live document never
+    assert held == [2, 0, 2]
+    assert st["preempted"] == 0
+    engine.close(), plain.close()
+
+
+def test_under_a_host_tier_only_the_blocking_plan_pages_out(devices):
+    """``reclaim`` under a host tier reads the pool, which would wait for
+    the call in flight: the plan ahead keeps to the free list, the blocking
+    plan pages the idle chain out, and a request that returns to it pages
+    it back in, with the tokens of an engine that never evicted."""
+    def fresh():
+        return _pooled(kv_blocks=33, host_kv_tier=True, host_tier_mb=8)
+
+    engine, roomy = fresh(), fresh()
+    pool, tier = engine.kv_cache, engine.kv_cache.host_tier
+    first = _finish(engine, 100, _doc(0), 4)
+    flight = _two_decoding(engine, max_new=22)
+    held = pool.allocator.allocate(pool.free_blocks)
+    assert pool.available_blocks == pool.prefix_cache.evictable_blocks == 2
+    assert engine._plan_decode_burst(flight) is None
+    assert engine.stats["ahead_refused_pool"] == 1
+    assert tier.stats["chain_blocks_out"] == 0
+    assert pool.prefix_cache.evictable_blocks == 2
+    got = engine.serve_step()                   # a blocking plan: pages out
+    assert engine.stats["burst_blocks_reclaimed"] == 2
+    assert engine.stats["burst_refused_pool"] == 0
+    assert tier.stats["chain_blocks_out"] == 2
+    assert pool.prefix_cache.cached_blocks == 0
+    assert engine.holds_prefix_blocks(_doc(0)) == 2      # in the tier
+    pool.free(held)
+    for uid, toks in engine.generate_all().items():
+        got[uid] = got[uid] + toks
+    again = _finish(engine, 101, _doc(0), 4)
+    assert tier.stats["chain_blocks_in"] == 2
+    assert first == again == _finish(roomy, 100, _doc(0), 4)
+    _two_decoding(roomy, max_new=22)
+    want = roomy.generate_all()
+    assert want == got
+    assert roomy.kv_cache.host_tier.stats["chain_blocks_out"] == 0
+    assert engine.stats["preempted"] == roomy.stats["preempted"] == 0
+    engine.close(), roomy.close()
