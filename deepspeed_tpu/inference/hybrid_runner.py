@@ -819,6 +819,22 @@ def _wmla_chunk(cfg, wp, y, pools, l, window_table, pos, real, seg_pos0,
     return o, _window_counts(dict(pools, wkv=wkv), rows, pos, keep, bs, R)
 
 
+def refuse_unserved(cfg: HybridConfig) -> None:
+    """The stacks the training path runs and no step program here does, each
+    by its own exception (raised where an engine is built: ``store_specs``)."""
+    if cfg.one_mixer:
+        raise hybrid.OneMixerStackUnsupported(
+            "a stack of one-mixer blocks is not served yet: the step "
+            "programs run a mixer and a feed-forward a layer, and "
+            "stack_plan and the KV / state pools count a slot a layer")
+    if cfg.recurrent_kind == "mamba2" and cfg.recurrent_layers:
+        raise hybrid.StateSpaceUnsupported(
+            "the Mamba-2 mixer is not served yet: no decode rule, no state "
+            "slot (heads x head x state float32 and a convolution tail) in "
+            "the pool, no prefill that carries the scan's state across "
+            "chunks")
+
+
 def store_specs(cfg: HybridConfig, *, kv_blocks: int, kv_block_size: int,
                 max_seqs: int, state_slots: Optional[int], dtype, quant_bits):
     """``model_runner.store_specs``'s contract, and the one place a
@@ -827,6 +843,7 @@ def store_specs(cfg: HybridConfig, *, kv_blocks: int, kv_block_size: int,
     a block of the rule) or of latents (with the selector's keys); a slot
     of recurrent state; a ring of windowed latent pages, every sequence of
     a step its whole ring."""
+    refuse_unserved(cfg)
     sparse = cfg.sparse
     rule = sparse or cfg.msa
     if rule is not None and kv_block_size != rule.block:

@@ -52,6 +52,16 @@ passes and the serving runner (``inference/hybrid_runner.py``) take:
 (with a prologue ``experts`` holds the expert layers alone, ``[L -
 first_k_dense, ...]``; the other per-layer leaves keep a slot a layer).
 
+A stack of **one-mixer blocks** (the ``nemotron_h`` family: ``layer_pattern``
+over "M" | "E" | "*", :attr:`HybridConfig.one_mixer`) is *norm -> one mixer ->
+residual* a block, the mixer a Mamba-2 state-space mixer (``recurrent_kind``
+"mamba2": ``mamba2_mixer``, ``ops/pallas/mamba2.py``), the expert feed-forward
+(here with ungated squared-ReLU experts, ``activation`` "relu2") or full
+attention (here without positions, QK-norm or gate). Its tree holds no leaf
+for a part a block lacks: ``layers`` keeps the one norm a block, and
+``mamba2``, ``attn``, ``moe`` and ``experts`` lie at the top, each over the
+blocks of its own kind. It trains; the serving runner refuses it.
+
 ``experts_held`` / ``expert_offset`` give the chip's share of the routed
 experts (None: all of them); the router always has ``num_experts`` outputs.
 With ``experts_apart`` the stacked tree itself keeps ``experts`` at the top,
@@ -86,10 +96,23 @@ from deepspeed_tpu.models.transformer import (TransformerConfig, _norm, _rope,
 from deepspeed_tpu.ops import block_sparse
 from deepspeed_tpu.ops.attention import multi_head_attention
 from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, lightning_chunk
-from deepspeed_tpu.parallel.moe import (ROUTING_NAME, GateConfig, Glu,
-                                        bias_update, moe_ffn_share)
+from deepspeed_tpu.ops.pallas.mamba2 import ssd_chunk
+from deepspeed_tpu.parallel.moe import (ROUTING_NAME, UNGATED, GateConfig,
+                                        Glu, bias_update, moe_ffn_share)
 from deepspeed_tpu.runtime.sharding import (effective_dtype,
                                             vocab_parallel_lookup)
+
+
+class StateSpaceUnsupported(NotImplementedError):
+    """A path with no decode rule, state slot or carried prefill for the
+    scalar-decay state-space mixer (``recurrent_kind`` "mamba2") was asked to
+    run one: it refuses rather than run another model."""
+
+
+class OneMixerStackUnsupported(NotImplementedError):
+    """A path that counts a mixer and a feed-forward a layer (a KV or state
+    slot a layer) was asked to run a stack of one-mixer blocks
+    (``layer_pattern`` over "M" | "E" | "*")."""
 
 
 class MlaSizes(NamedTuple):
@@ -235,6 +258,20 @@ class HybridConfig(TransformerConfig):
     router_scoring: str = "softmax"
     routed_scale: float = 1.0
     shared_gate: bool = True
+    # QK-norm in the "gated" full mixer (False: no ``q_norm`` / ``k_norm``)
+    qk_norm: bool = True
+    # Mamba-2 (``recurrent_kind`` "mamba2"; ops/pallas/mamba2.py): heads of
+    # ``mamba_head_dim`` (the inner width is their product, whatever the
+    # hidden size), ``B`` and ``C`` of ``mamba_state_size`` shared by the
+    # heads of each of ``mamba_n_groups`` groups, a causal convolution with
+    # a bias over ``mamba_conv_kernel`` taps, the scan in chunks of
+    # ``mamba_chunk`` tokens
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    mamba_state_size: int = 128
+    mamba_conv_kernel: int = 4
+    mamba_chunk: int = 128
 
     def __post_init__(self):
         super().__post_init__()
@@ -249,11 +286,27 @@ class HybridConfig(TransformerConfig):
                 raise ValueError(
                     f"num_layers={self.num_layers} is not a whole number of "
                     f"periods of {self.full_attention_interval} layers")
-        elif (set(self.layer_pattern) - set("mwl") or self.first_layer
+        elif (set(self.layer_pattern) - set("mwlME*") or self.first_layer
               + self.num_layers > len(self.layer_pattern)):
             raise ValueError(
                 f"layers {self.first_layer}..{self.first_layer + self.num_layers}"
-                f" lie outside the pattern {self.layer_pattern!r} (m | w | l)")
+                f" lie outside the pattern {self.layer_pattern!r} (m | w | l, "
+                f"or M | E | * for blocks of one mixer)")
+        elif set(self.layer_pattern) & set("ME*"):
+            if set(self.layer_pattern) & set("mwl"):
+                raise ValueError(
+                    f"the pattern {self.layer_pattern!r} mixes layers of a "
+                    f"mixer and a feed-forward (m | w | l) with blocks of "
+                    f"one mixer (M | E | *)")
+            if (self.attention_kind != "gated" or self.post_norms
+                    or self.first_k_dense or self.sparse_topk
+                    or self.msa_topk):
+                raise ValueError(
+                    "a block of one mixer is norm -> mixer -> residual: no "
+                    "post-branch norm, no dense prologue, and its attention "
+                    "the gated kind without a block-selecting rule")
+            if "E" in self.layer_pattern and not self.num_experts:
+                raise ValueError("an expert block (E) needs num_experts")
         if self.window_attention_kind not in ("gated", "mla"):
             raise ValueError(
                 f"window_attention_kind {self.window_attention_kind!r}")
@@ -267,8 +320,16 @@ class HybridConfig(TransformerConfig):
         if self.index_topk and self.attention_kind != "mla":
             raise ValueError("the selector (index_topk) is the latent "
                              "layers'")
-        if self.recurrent_kind not in ("gdn", "lightning"):
-            raise ValueError(f"recurrent_kind {self.recurrent_kind!r}")
+        if self.recurrent_kind not in ("gdn", "lightning", "mamba2"):
+            raise ValueError(f"recurrent_kind {self.recurrent_kind!r} "
+                             f"(gdn | lightning | mamba2)")
+        if self.mamba_num_heads % self.mamba_n_groups:
+            raise ValueError("mamba heads must be a multiple of the groups")
+        if self.activation in UNGATED and (not self.num_experts
+                                           or self.first_k_dense):
+            raise ValueError(
+                f"activation {self.activation!r} is the experts' own: the "
+                f"dense feed-forwards are gated")
         self.sparse                     # the sizes check themselves
         self.glu                        # and the activation
         if self.msa_topk and (self.sparse_topk
@@ -359,10 +420,18 @@ class HybridConfig(TransformerConfig):
                                   self.first_layer + self.num_layers]
 
     @property
+    def one_mixer(self) -> bool:
+        """Whether a block is *norm -> one mixer -> residual* (the pattern's
+        "M": the recurrent mixer, "E": the expert feed-forward, "*": full
+        attention), not a mixer and a feed-forward with a norm each."""
+        return bool(set(self.layer_pattern or "") & set("ME*"))
+
+    @property
     def layer_kinds(self) -> Tuple[bool, ...]:
         """For each layer held here, whether it is softmax attention over
-        keys and values (full or windowed), not recurrent."""
-        return tuple(c != "l" for c in self._held_pattern)
+        keys and values (full or windowed), not recurrent (nor, a block of
+        one mixer, the experts alone)."""
+        return tuple(c in "mw*" for c in self._held_pattern)
 
     @property
     def layer_windows(self) -> Tuple[Optional[int], ...]:
@@ -376,10 +445,11 @@ class HybridConfig(TransformerConfig):
     def mixer_kinds(self) -> Tuple[Any, ...]:
         """For each layer held here, which mixer's leaves it reads: True the
         full layers' (a gated windowed layer shares them), False the
-        recurrent one's, "w" the windowed latent mixer's own."""
+        recurrent one's, "w" the windowed latent mixer's own, None an expert
+        block's (a block of one mixer that is the feed-forward: no mixer)."""
         own = self.window_attention_kind == "mla"
-        return tuple("w" if c == "w" and own else c != "l"
-                     for c in self._held_pattern)
+        return tuple(None if c == "E" else "w" if c == "w" and own
+                     else c in "mw*" for c in self._held_pattern)
 
     @property
     def window_layers(self) -> int:
@@ -396,13 +466,24 @@ class HybridConfig(TransformerConfig):
                    self.num_layers)
 
     @property
+    def expert_layers(self) -> int:
+        """Layers held here that end in (or, blocks of one mixer, are) the
+        expert feed-forward."""
+        if not self.num_experts:
+            return 0
+        if self.one_mixer:
+            return sum(k is None for k in self.mixer_kinds)
+        return self.num_layers - self.dense_layers
+
+    @property
     def stack_plan(self) -> Tuple[int, Tuple[Tuple[Any, int], ...]]:
         """``(repeats, runs)``: the layers' mixers (``mixer_kinds``; after
         the prologue of ``first_k_dense`` layers, which the runner calls one
         by one before the scan) as ``repeats`` copies of the shortest
         pattern that tiles them, the pattern as runs ``(kind, layers)`` of
-        one mixer. The serving runner scans the repeats and, inside, each
-        run: one layer body a run, whatever the depth."""
+        one mixer (of blocks of one mixer, ``kind`` None: expert blocks).
+        The serving runner scans the repeats and, inside, each run: one
+        layer body a run, whatever the depth."""
         kinds = self.mixer_kinds[self.dense_layers:]    # after the prologue
         L = len(kinds)
         p = next(p for p in range(1, L + 1)
@@ -428,14 +509,23 @@ class HybridConfig(TransformerConfig):
         return sum(k is False for k in self.mixer_kinds)
 
     @property
+    def mamba_inner(self) -> int:
+        """The Mamba-2 mixer's inner width: heads x head size."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
     def conv_channels(self) -> int:
+        if self.recurrent_kind == "mamba2":     # x | B | C
+            return self.mamba_inner + 2 * self.mamba_n_groups \
+                * self.mamba_state_size
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
     def conv_taps(self) -> int:
         """Taps of the recurrent mixer's convolution (lightning: none)."""
-        return self.linear_conv_kernel_dim if self.recurrent_kind == "gdn" else 1
+        return {"gdn": self.linear_conv_kernel_dim,
+                "mamba2": self.mamba_conv_kernel}.get(self.recurrent_kind, 1)
 
     @property
     def sparse(self) -> Optional[block_sparse.SparseSizes]:
@@ -463,6 +553,13 @@ class HybridConfig(TransformerConfig):
             return Glu("swigluoai", self.swiglu_alpha, self.swiglu_limit)
         return Glu()
 
+    @property
+    def expert_activation(self):
+        """What the routed and the shared experts apply: :attr:`glu`, or the
+        name of an ungated activation (``parallel/moe.py::UNGATED``: two
+        matrices an expert, no ``wg``)."""
+        return self.activation if self.activation in UNGATED else self.glu
+
     def lightning_decay(self) -> jax.Array:
         """``log a`` [recurrent layers, heads] float32, a constant of the
         head and of the layer's *published* index ``l``: ``-s_j (1 - l /
@@ -471,8 +568,9 @@ class HybridConfig(TransformerConfig):
         n = self.linear_num_value_heads
         total = len(self.layer_pattern or "") or self.num_layers
         slope = 2.0 ** (-8.0 * (jnp.arange(n, dtype=jnp.float32) + 1.0) / n)
-        ls = jnp.asarray([self.first_layer + l for l, full in
-                          enumerate(self.layer_kinds) if not full], jnp.float32)
+        ls = jnp.asarray([self.first_layer + l for l, kind in
+                          enumerate(self.mixer_kinds) if kind is False],
+                         jnp.float32)
         return -slope[None, :] * (1.0 - ls[:, None] / max(total - 1, 1) + 1e-5)
 
     @property
@@ -494,12 +592,17 @@ class HybridConfig(TransformerConfig):
 
     def flops_per_token(self) -> float:
         """Forward and backward, 6 a weight a token touches (the experts:
-        ``top_k`` and the shared one)."""
+        ``top_k`` and the shared one, three matrices each or, ungated, two)."""
         h = self.hidden_size
         if not self.num_experts:
             return 6.0 * self.num_params()
-        active = 3 * h * (self.top_k * self.moe_ffn_size + self.shared_ffn_size)
-        held_all = 3 * h * self.moe_ffn_size * self.held
+        mats = 2 if self.activation in UNGATED else 3
+        active = mats * h * (self.top_k * self.moe_ffn_size
+                             + self.shared_ffn_size)
+        held_all = mats * h * self.moe_ffn_size * self.held
+        if self.one_mixer:      # every leaf is read by a block of its kind
+            return 6.0 * (self.num_params() + self.expert_layers * (
+                active - held_all - mats * h * self.shared_ffn_size))
         # (the prologue's slots of the expert leaves are never read)
         K = self.dense_layers
         slots = self.num_layers - K if self.experts_apart else self.num_layers
@@ -509,7 +612,10 @@ class HybridConfig(TransformerConfig):
 
 
 def _layer_norms(cfg: HybridConfig) -> Tuple[str, ...]:
-    """A layer's norms: before each branch and, with ``post_norms``, after."""
+    """A layer's norms: before each branch and, with ``post_norms``, after;
+    of a block of one mixer, the one before it."""
+    if cfg.one_mixer:
+        return ("ln1",)
     return ("ln1", "ln2") + (("ln1_post", "ln2_post") if cfg.post_norms
                              else ())
 
@@ -521,35 +627,48 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     e, f, fs = cfg.held, cfg.moe_ffn_size, cfg.shared_ffn_size
+    # the slots of each group of leaves: one a layer (``serving_params`` cuts
+    # a mixer to the layers that use it) or, of a stack of one-mixer blocks,
+    # one a block of the group's kind, the group at the top of the tree
+    one = cfg.one_mixer
+    La, Lr, Lm = (cfg.kv_layers, cfg.recurrent_layers, cfg.expert_layers) \
+        if one else (L, L, L)
     if cfg.recurrent_kind == "gdn":
-        rec = {"wq": (L, h, nk, dk), "wk": (L, h, nk, dk),
-               "wv": (L, h, nv, dv), "wz": (L, h, nv, dv),
-               "wb": (L, h, nv), "wa": (L, h, nv),
-               "conv": (L, cfg.linear_conv_kernel_dim, cfg.conv_channels),
-               "A_log": (L, nv), "dt_bias": (L, nv), "norm": (L, dv),
-               "wo": (L, nv, dv, h)}
+        rec = {"wq": (Lr, h, nk, dk), "wk": (Lr, h, nk, dk),
+               "wv": (Lr, h, nv, dv), "wz": (Lr, h, nv, dv),
+               "wb": (Lr, h, nv), "wa": (Lr, h, nv),
+               "conv": (Lr, cfg.linear_conv_kernel_dim, cfg.conv_channels),
+               "A_log": (Lr, nv), "dt_bias": (Lr, nv), "norm": (Lr, dv),
+               "wo": (Lr, nv, dv, h)}
+    elif cfg.recurrent_kind == "mamba2":
+        n, di, cc = cfg.mamba_num_heads, cfg.mamba_inner, cfg.conv_channels
+        rec = {"w_in": (Lr, h, di + cc + n),            # z | x B C | dt
+               "conv": (Lr, cfg.mamba_conv_kernel, cc), "conv_bias": (Lr, cc),
+               "A_log": (Lr, n), "dt_bias": (Lr, n), "D": (Lr, n),
+               "norm": (Lr, di), "w_out": (Lr, di, h)}
     else:
-        rec = {"wq": (L, h, nv, dk), "wk": (L, h, nv, dk),
-               "wv": (L, h, nv, dv), "wz": (L, h, nv, dv),
-               "q_norm": (L, dk), "k_norm": (L, dk), "norm": (L, dv),
-               "wo": (L, nv, dv, h)}
+        rec = {"wq": (Lr, h, nv, dk), "wk": (Lr, h, nv, dk),
+               "wv": (Lr, h, nv, dv), "wz": (Lr, h, nv, dv),
+               "q_norm": (Lr, dk), "k_norm": (Lr, dk), "norm": (Lr, dv),
+               "wo": (Lr, nv, dv, h)}
     top = {}
     if cfg.num_experts:
         K, F = cfg.dense_layers, cfg.ffn_size
-        Le = L - K if cfg.experts_apart else L
-        experts = {"wg": (Le, e, h, f), "wi": (Le, e, h, f),
-                   "wo": (Le, e, f, h)}
-        moe = {"router": (L, h, cfg.num_experts),
-               "shared": {"wg": (L, h, fs), "wi": (L, h, fs),
-                          "wo": (L, fs, h)}}
-        if cfg.experts_apart:
+        Le = cfg.expert_layers if cfg.experts_apart or one else L
+        gated = cfg.activation not in UNGATED
+        experts = {"wi": (Le, e, h, f), "wo": (Le, e, f, h)}
+        moe = {"router": (Lm, h, cfg.num_experts),
+               "shared": {"wi": (Lm, h, fs), "wo": (Lm, fs, h)}}
+        if gated:
+            experts["wg"], moe["shared"]["wg"] = (Le, e, h, f), (Lm, h, fs)
+        if cfg.experts_apart or one:
             top["experts"] = experts
         else:
             moe["experts"] = experts
         if cfg.shared_gate:
-            moe["shared_gate"] = (L, h)
+            moe["shared_gate"] = (Lm, h)
         if cfg.router_scoring == "sigmoid":
-            moe["router_bias"] = (L, cfg.num_experts)
+            moe["router_bias"] = (Lm, cfg.num_experts)
         ffn = {"moe": moe}
         if K:
             top["dense"] = {"wg": (K, h, F), "wi": (K, h, F), "wo": (K, F, h)}
@@ -562,25 +681,32 @@ def _shapes(cfg: HybridConfig) -> Dict[str, Any]:
             mixers["wmla"] = _mla_shapes(cfg, True)
     else:
         per_head = cfg.qk_norm_per_head
-        attn = {"wq": (L, h, nq, (2 if cfg.attn_output_gate else 1) * d),
-                "wk": (L, h, nkv, d), "wv": (L, h, nkv, d),
-                "wo": (L, nq, d, h),
-                "q_norm": (L, nq, d) if per_head else (L, d),
-                "k_norm": (L, nkv, d) if per_head else (L, d)}
+        attn = {"wq": (La, h, nq, (2 if cfg.attn_output_gate else 1) * d),
+                "wk": (La, h, nkv, d), "wv": (La, h, nkv, d),
+                "wo": (La, nq, d, h)}
+        if cfg.qk_norm:
+            attn.update(q_norm=(La, nq, d) if per_head else (La, d),
+                        k_norm=(La, nkv, d) if per_head else (La, d))
         if cfg.msa:
             hi, di = cfg.msa.index_heads, cfg.msa.index_dim
             attn.update(wsq=(L, h, nkv, hi, di), wsk=(L, h, nkv, di))
-        mixers = {"attn": attn}
+        mixers = {"attn": attn} if La else {}
     if cfg.recurrent_layers:
         mixers[cfg.recurrent_kind] = rec
-    return {
-        "embed": {"tokens": (v, h)},
-        "final_norm": {"scale": (h,)},
-        "unembed": {"kernel": (h, v)},
-        **top,
-        "layers": {**{n: {"scale": (L, h)} for n in _layer_norms(cfg)},
-                   **mixers, **ffn},
-    }
+    return _placed(cfg, {"embed": {"tokens": (v, h)},
+                         "final_norm": {"scale": (h,)},
+                         "unembed": {"kernel": (h, v)}, **top},
+                   {n: {"scale": (L, h)} for n in _layer_norms(cfg)},
+                   mixers, ffn)
+
+
+def _placed(cfg: HybridConfig, top, norms, mixers, ffn) -> Dict[str, Any]:
+    """The tree's nesting: the norms under ``layers``; the mixers and the
+    feed-forward's leaves there too, a slot a layer, or of a stack of
+    one-mixer blocks at the top, each over its own blocks."""
+    if cfg.one_mixer:
+        return {**top, **mixers, **ffn, "layers": norms}
+    return {**top, "layers": {**norms, **mixers, **ffn}}
 
 
 def _mla_shapes(cfg: HybridConfig, windowed: bool) -> Dict[str, Tuple]:
@@ -605,11 +731,12 @@ def _mla_shapes(cfg: HybridConfig, windowed: bool) -> Dict[str, Tuple]:
 INDEX_NORM_EPS = 1e-6
 
 _GAINS = ("scale", "q_norm", "k_norm", "kv_norm", "norm", "ik_norm")
-_MIXERS = ("attn", "mla", "wmla", "gdn", "lightning")
+_MIXERS = ("attn", "mla", "wmla", "gdn", "lightning", "mamba2")
 
 
 def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
-    """Fan-in normal draws, gains one, ``A_log`` zero, ``dt_bias`` spread."""
+    """Fan-in normal draws, gains (and Mamba-2's skip ``D``) one, ``A_log``
+    and the biases zero, ``dt_bias`` spread."""
     shapes = _shapes(cfg)
     leaves, treedef = jax.tree.flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
@@ -618,9 +745,9 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
     for key, (path, shape) in zip(keys, leaves):
         name = path[-1].key
         group = path[-2].key if len(path) > 1 else ""
-        if name in _GAINS:
+        if name in _GAINS or name == "D":
             x = jnp.ones(shape, cfg.param_dtype)
-        elif name in ("A_log", "ik_bias"):
+        elif name in ("A_log", "ik_bias", "conv_bias"):
             x = jnp.zeros(shape, cfg.param_dtype)
         elif name == "dt_bias":
             x = jax.random.normal(key, shape, cfg.param_dtype) * 2.0
@@ -632,6 +759,8 @@ def init_params(cfg: HybridConfig, rng: jax.Array) -> Dict[str, Any]:
             if name == "wo":   # contracts everything but the last axis
                 fan = math.prod(shape[2:-1]) if group in _MIXERS \
                     else shape[-2]
+            elif name == "w_out":
+                fan = shape[-2]
             elif name in ("wqb", "wkvb", "wiq"):   # [L, rank, heads, d]
                 # (of the input as it arrives: the rescaled latent's)
                 z = cfg.mla_sizes(group == "wmla")
@@ -653,18 +782,24 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
         rec.update({"wb": (L, "embed", None), "wa": (L, "embed", None),
                     "conv": (L, None, None), "A_log": (L, None),
                     "dt_bias": (L, None)})
+    elif cfg.recurrent_kind == "mamba2":
+        rec = {"w_in": (L, "embed", None), "conv": (L, None, None),
+               "conv_bias": (L, None), "A_log": (L, None),
+               "dt_bias": (L, None), "D": (L, None), "norm": (L, None),
+               "w_out": (L, None, "embed")}
     else:
         rec.update({"q_norm": (L, None), "k_norm": (L, None)})
     top = {}
     if cfg.num_experts:
-        experts = {"wg": (L, "expert", "embed", None),
-                   "wi": (L, "expert", "embed", None),
+        experts = {"wi": (L, "expert", "embed", None),
                    "wo": (L, "expert", None, "embed")}
         moe = {"router": (L, "embed", None),
-               "shared": {"wg": (L, "embed", None),
-                          "wi": (L, "embed", None),
+               "shared": {"wi": (L, "embed", None),
                           "wo": (L, None, "embed")}}
-        if cfg.experts_apart:
+        if cfg.activation not in UNGATED:
+            experts["wg"] = (L, "expert", "embed", None)
+            moe["shared"]["wg"] = (L, "embed", None)
+        if cfg.experts_apart or cfg.one_mixer:
             top["experts"] = experts
         else:
             moe["experts"] = experts
@@ -696,27 +831,25 @@ def logical_axes(cfg: HybridConfig) -> Dict[str, Any]:
         attn = {"wq": (L, "embed", "heads", "head_dim"),
                 "wk": (L, "embed", "kv_heads", "head_dim"),
                 "wv": (L, "embed", "kv_heads", "head_dim"),
-                "wo": (L, "heads", "head_dim", "embed"),
-                "q_norm": (L, "heads", "head_dim") if per_head
-                else (L, "head_dim"),
-                "k_norm": (L, "kv_heads", "head_dim") if per_head
-                else (L, "head_dim")}
+                "wo": (L, "heads", "head_dim", "embed")}
+        if cfg.qk_norm:
+            attn.update(q_norm=(L, "heads", "head_dim") if per_head
+                        else (L, "head_dim"),
+                        k_norm=(L, "kv_heads", "head_dim") if per_head
+                        else (L, "head_dim"))
         if cfg.msa:
             attn.update(wsq=(L, "embed", "kv_heads", None, None),
                         wsk=(L, "embed", "kv_heads", None))
-        mixers = {"attn": attn}
+        mixers = {"attn": attn} if cfg.kv_layers or not cfg.one_mixer else {}
     if cfg.recurrent_layers:
         # the recurrent mixer is replicated: its state pool is per
         # sequence, not per head shard
         mixers[cfg.recurrent_kind] = rec
-    return {
-        "embed": {"tokens": ("vocab", "embed")},
-        "final_norm": {"scale": ("embed",)},
-        "unembed": {"kernel": ("embed", "vocab")},
-        **top,
-        "layers": {**{n: {"scale": (L, "embed")} for n in _layer_norms(cfg)},
-                   **mixers, **ffn},
-    }
+    return _placed(cfg, {"embed": {"tokens": ("vocab", "embed")},
+                         "final_norm": {"scale": ("embed",)},
+                         "unembed": {"kernel": ("embed", "vocab")}, **top},
+                   {n: {"scale": (L, "embed")} for n in _layer_norms(cfg)},
+                   mixers, ffn)
 
 
 def axes_for(cfg: HybridConfig, params: Dict[str, Any]) -> Dict[str, Any]:
@@ -804,8 +937,9 @@ def attn_project(cfg: HybridConfig, ap, y, positions, windowed: bool = False):
         else (qg, None)
     k = jnp.einsum("...h,hnd->...nd", y, ap["wk"].astype(dt))
     v = jnp.einsum("...h,hnd->...nd", y, ap["wv"].astype(dt))
-    q = _rms(q, ap["q_norm"], cfg.norm_eps)
-    k = _rms(k, ap["k_norm"], cfg.norm_eps)
+    if cfg.qk_norm:
+        q = _rms(q, ap["q_norm"], cfg.norm_eps)
+        k = _rms(k, ap["k_norm"], cfg.norm_eps)
     rot = int(d * (cfg.window_rotary_factor if windowed
                    else cfg.partial_rotary_factor))
 
@@ -1049,6 +1183,75 @@ def lightning_output(cfg: HybridConfig, mp, o, z):
     return jnp.einsum("...nd,ndh->...h", o, mp["wo"].astype(dt))
 
 
+def mamba2_project(cfg: HybridConfig, mp, y):
+    """The Mamba-2 mixer's input projection of y [..., H], split: the gate
+    ``z`` [..., inner], the convolution's input ``x|B|C`` [..., C] and the
+    step sizes' ``dt`` [..., heads] before their bias."""
+    di, cc = cfg.mamba_inner, cfg.conv_channels
+    with jax.named_scope("mamba2_proj"):
+        zxbcdt = y @ mp["w_in"].astype(y.dtype)
+    return zxbcdt[..., :di], zxbcdt[..., di:di + cc], zxbcdt[..., di + cc:]
+
+
+@jax.named_scope("mamba2_conv")
+def mamba2_conv(mp, xbc):
+    """``silu(conv1d(x|B|C) + bias)`` of whole sequences [B, T, C] from a
+    zero tail: causal, depthwise, ``taps [K, C]``; float32 inside."""
+    taps, T = mp["conv"].astype(jnp.float32), xbc.shape[1]
+    K = taps.shape[0]
+    # (the window stays in its own type: widened tap by tap inside the sum)
+    window = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+    out = sum(taps[i] * window[:, i:i + T] for i in range(K))
+    return jax.nn.silu(out + mp["conv_bias"].astype(jnp.float32)).astype(
+        xbc.dtype)
+
+
+def mamba2_heads(cfg: HybridConfig, mp, conv_out, dt):
+    """After the convolution: ``x`` [..., heads, head], ``B``, ``C`` [...,
+    groups, state] (head ``h`` reads group ``h // (heads / groups)``), and
+    float32 the step sizes ``softplus(dt + dt_bias)`` [..., heads] (no clamp:
+    the family's ``time_step_limit`` is ``(0, inf)``) and ``A = -exp(A_log)``
+    [heads]."""
+    n, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N, di = cfg.mamba_n_groups, cfg.mamba_state_size, cfg.mamba_inner
+    lead = conv_out.shape[:-1]
+    x = conv_out[..., :di].reshape(lead + (n, P))
+    B = conv_out[..., di:di + G * N].reshape(lead + (G, N))
+    C = conv_out[..., di + G * N:].reshape(lead + (G, N))
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + mp["dt_bias"].astype(jnp.float32))
+    return x, B, C, dt, -jnp.exp(mp["A_log"].astype(jnp.float32))
+
+
+def mamba2_output(cfg: HybridConfig, mp, o, z):
+    """``out_proj(norm(o * silu(z)))``: the gate first, then an RMSNorm over
+    each of the ``mamba_n_groups`` groups of channels, one gain a channel; o
+    float32 [..., heads, head], z [..., inner]."""
+    G, di = cfg.mamba_n_groups, cfg.mamba_inner
+    lead = z.shape[:-1]
+    with jax.named_scope("mamba2_norm"):
+        g = o.reshape(lead + (di,)) * jax.nn.silu(z.astype(jnp.float32))
+        g = g.reshape(lead + (G, di // G))
+        g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+        g = (g.reshape(lead + (di,))
+             * mp["norm"].astype(jnp.float32)).astype(z.dtype)
+    with jax.named_scope("mamba2_proj"):
+        return g @ mp["w_out"].astype(z.dtype)
+
+
+@jax.named_scope("mamba2")
+def mamba2_mixer(cfg: HybridConfig, mp, y):
+    """The Mamba-2 mixer of whole sequences y [B, T, H] (normed), every
+    sequence from an empty state: projection, convolution, the chunked scan
+    (``ops/pallas/mamba2.py::ssd_chunk``), the gated grouped norm, the
+    output projection."""
+    z, xbc, dt = mamba2_project(cfg, mp, y)
+    x, B, C, dt, A = mamba2_heads(cfg, mp, mamba2_conv(mp, xbc), dt)
+    o, _ = ssd_chunk(x, dt, A, B, C, mp["D"], chunk=cfg.mamba_chunk)
+    return mamba2_output(cfg, mp, o, z)
+
+
 @jax.named_scope("gdn_conv")
 def causal_conv(taps, tail, x):
     """Depthwise causal convolution over the token axis. taps [K, C]; x
@@ -1076,6 +1279,22 @@ def branch(cfg: HybridConfig, lp, post: str, x, out):
     return residual(cfg, x, out)
 
 
+def expert_ffn(cfg: HybridConfig, moe, experts, y, layer, valid=None,
+               capacity=None):
+    """The expert feed-forward of normed flat tokens y [T, H] with its
+    routing counts (``parallel/moe.py::moe_ffn_share``): ``moe`` the layer's
+    router, bias and shared expert, ``experts`` and ``layer`` as
+    :func:`expert_block` takes them."""
+    shared = dict(moe["shared"])
+    if cfg.shared_gate:
+        shared["gate"] = moe["shared_gate"]
+    return moe_ffn_share(
+        y, moe["router"], experts, cfg.gate, offset=cfg.expert_offset,
+        shared=shared, valid=valid, layer=layer,
+        router_bias=moe.get("router_bias"), capacity=capacity,
+        glu=cfg.expert_activation)
+
+
 def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None,
                  dense=None, capacity=None):
     """``x + c * ffn(norm(x))`` on flat tokens x [T, H] (``c`` the residual
@@ -1094,14 +1313,8 @@ def expert_block(cfg: HybridConfig, lp, experts, x, layer, valid=None,
         with jax.named_scope("mlp"):
             return branch(cfg, lp, "ln2_post", x,
                           _glu_ffn(cfg, lp["mlp"], y)), None
-    moe = lp["moe"]
-    shared = dict(moe["shared"])
-    if cfg.shared_gate:
-        shared["gate"] = moe["shared_gate"]
-    out, counts = moe_ffn_share(
-        y, moe["router"], experts, cfg.gate, offset=cfg.expert_offset,
-        shared=shared, valid=valid, layer=layer,
-        router_bias=moe.get("router_bias"), capacity=capacity, glu=cfg.glu)
+    out, counts = expert_ffn(cfg, lp["moe"], experts, y, layer, valid,
+                             capacity)
     return branch(cfg, lp, "ln2_post", x, out), counts
 
 
@@ -1201,16 +1414,46 @@ def share_capacity(cfg: HybridConfig, tokens: int) -> Optional[int]:
 
 def _layer(cfg: HybridConfig, l: int, x, positions, lp, mp, experts, dense):
     """Layer ``l`` of the full forward on x [B, S, H]: ``lp`` its per-layer
-    leaves, ``mp`` its mixer's, ``experts`` its routed experts' (or None),
-    ``dense`` its prologue feed-forward's (or None). Returns (x, counts
+    leaves, ``mp`` its mixer's (an expert block's: its router, bias and
+    shared expert), ``experts`` its routed experts' (or None), ``dense`` its
+    prologue feed-forward's (or None). Returns (x, counts
     [len(MOE_COUNTERS)] int32, load [num_experts] int32: the tokens each of
-    the router's outputs got, zeros for a dense layer)."""
+    the router's outputs got, zeros for a layer without experts)."""
     B, S, H = x.shape
-    dt = x.dtype
+    y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
+    if cfg.one_mixer:           # norm -> one mixer -> residual
+        if cfg.mixer_kinds[l] is None:
+            out, c = expert_ffn(cfg, mp, experts, y.reshape(B * S, H), None,
+                                capacity=share_capacity(cfg, B * S))
+            out = out.reshape(B, S, H)
+        else:
+            out, c = _mixer(cfg, l, y, positions, mp), None
+        return (residual(cfg, x, out), *_counted(cfg, c, B * S))
+    x = branch(cfg, lp, "ln1_post", x, _mixer(cfg, l, y, positions, mp))
+    x, c = expert_block(cfg, lp, experts, x.reshape(B * S, H), None,
+                        dense=dense, capacity=share_capacity(cfg, B * S))
+    counts, load = _counted(cfg, c, B * S)
+    return x.reshape(B, S, H), counts, load
+
+
+def _counted(cfg: HybridConfig, c, tokens: int):
+    """An expert layer's counts in ``MOE_COUNTERS``' order and its load
+    (``c`` None, no experts: zeros)."""
+    if c is None:
+        return (jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
+                jnp.zeros((cfg.num_experts,), jnp.int32))
+    return (jnp.stack([c["pairs"], c["experts_hit"], c["max_rows"],
+                       jnp.int32(tokens), c["dropped"]]), c["load"])
+
+
+def _mixer(cfg: HybridConfig, l: int, y, positions, mp):
+    """Layer ``l``'s mixer of the full forward on its normed input y [B, S,
+    H], ``mp`` its leaves, every sequence from an empty state."""
+    B, S, _ = y.shape
+    dt = y.dtype
     nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                   cfg.linear_value_head_dim)
     state0 = jnp.zeros((B, nv, dk, dv), jnp.float32)    # (a recurrent layer's)
-    y = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
     if cfg.attention_kind == "mla":
         windowed = cfg.mixer_kinds[l] == "w"
         selects = bool(cfg.index_topk) and not windowed
@@ -1228,6 +1471,8 @@ def _layer(cfg: HybridConfig, l: int, x, positions, lp, mp, experts, dense):
             blocks = msa_mask(cfg, mp, y, positions) if cfg.msa else None
             a = softmax_attention(cfg, q, k, v, positions, window, blocks)
             out = attn_output(mp, a, gate)
+    elif cfg.recurrent_kind == "mamba2":
+        out = mamba2_mixer(cfg, mp, y)
     elif cfg.recurrent_kind == "gdn":
         mixed, z, beta, g = gdn_project(cfg, mp, y)
         tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1,
@@ -1238,22 +1483,12 @@ def _layer(cfg: HybridConfig, l: int, x, positions, lp, mp, experts, dense):
         out = gdn_output(cfg, mp, o, z)
     else:
         decay = cfg.lightning_decay()[sum(
-            not full for full in cfg.layer_kinds[:l])]
+            kind is False for kind in cfg.mixer_kinds[:l])]
         qf, kf, vf, z = lightning_project(cfg, mp, y, positions)
         o, _ = lightning_chunk(qf, kf, vf, jnp.broadcast_to(decay, (B, S, nv)),
                                state0)
         out = lightning_output(cfg, mp, o, z)
-    x = branch(cfg, lp, "ln1_post", x, out)
-    x, c = expert_block(cfg, lp, experts, x.reshape(B * S, H), None,
-                        dense=dense, capacity=share_capacity(cfg, B * S))
-    if c is None:
-        counts = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
-        load = jnp.zeros((cfg.num_experts,), jnp.int32)
-    else:
-        counts = jnp.stack([c["pairs"], c["experts_hit"], c["max_rows"],
-                            jnp.int32(B * S), c["dropped"]])
-        load = c["load"]
-    return x.reshape(B, S, H), counts, load
+    return out
 
 
 def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
@@ -1275,9 +1510,10 @@ def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x = embed_tokens(cfg, p, tokens)
     K = cfg.dense_layers
+    # (an expert block of a one-mixer stack reads ``moe`` as its mixer)
     names = {True: "mla" if cfg.attention_kind == "mla" else "attn",
-             False: cfg.recurrent_kind, "w": "wmla"}
-    seen = {True: 0, False: 0, "w": 0}
+             False: cfg.recurrent_kind, "w": "wmla", None: "moe"}
+    seen = dict.fromkeys(names, 0)
     counts, loads = jnp.zeros((len(MOE_COUNTERS),), jnp.int32), []
 
     def at(tree, i):
@@ -1289,10 +1525,13 @@ def hidden_states(cfg: HybridConfig, params: Dict[str, Any], tokens: jax.Array,
         if cfg.remat:
             fn = checkpoint_wrapper(fn, policy=cfg.remat_policy,
                                     kept_names=(ROUTING_NAME,))
-        x, c, load = fn(x, positions, at(p["layers"], l),
-                        at(p[names[full]], seen[full]),
-                        at(p["experts"], l - K) if cfg.num_experts and l >= K
-                        else None,
+        lp, mp = at(p["layers"], l), at(p[names[full]], seen[full])
+        if cfg.one_mixer:
+            experts = at(p["experts"], seen[None]) if full is None else None
+        else:
+            experts = at(p["experts"], l - K) if cfg.num_experts and l >= K \
+                else None
+        x, c, load = fn(x, positions, lp, mp, experts,
                         at(p["dense"], l) if l < K else None)
         seen[full] += 1
         counts = counts + c
@@ -1342,10 +1581,17 @@ def loss_fn(cfg: HybridConfig, params, batch) -> Tuple[jax.Array, Dict]:
     if cfg.num_experts:
         aux["counters"] = counters
         if cfg.bias_update_rate and cfg.router_scoring == "sigmoid":
-            expert_layer = jnp.arange(cfg.num_layers) >= cfg.dense_layers
-            aux["param_deltas"] = {"layers": {"moe": {"router_bias": jnp.where(
-                expert_layer[:, None],
-                bias_update(loads, cfg.bias_update_rate), 0.0)}}}
+            if cfg.one_mixer:   # the bias lies over the expert blocks alone
+                blocks = jnp.asarray([l for l, kind in enumerate(
+                    cfg.mixer_kinds) if kind is None])
+                aux["param_deltas"] = {"moe": {"router_bias": bias_update(
+                    loads[blocks], cfg.bias_update_rate)}}
+            else:
+                expert_layer = jnp.arange(cfg.num_layers) >= cfg.dense_layers
+                aux["param_deltas"] = {"layers": {"moe": {
+                    "router_bias": jnp.where(
+                        expert_layer[:, None],
+                        bias_update(loads, cfg.bias_update_rate), 0.0)}}}
     return loss, aux
 
 

@@ -353,6 +353,48 @@ def _register_hybrid():
             moe_ffn_size=32, shared_ffn_size=32, experts_held=2,
             router_scoring="sigmoid", routed_scale=2.0, shared_gate=False,
             remat=False),
+        # NVIDIA-Nemotron-3-Nano-30B-A3B (huggingface.co/nvidia/
+        # NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 config.json, model_type
+        # nemotron_h) at the published values: 52 blocks of one mixer each
+        # by ``hybrid_override_pattern`` (23 Mamba-2 "M": 64 heads of 64,
+        # 8 groups of B / C, state 128, a biased convolution of 4 taps,
+        # chunks of 128, a gated norm over groups of 512 channels; 23
+        # expert blocks "E": top 6 of 128 by sigmoid score with a bias in
+        # the choice, weights renormalised and scaled by 2.5, ungated
+        # squared-ReLU experts 1,856 wide and a shared one 3,712 wide; 6
+        # attention blocks "*": 32 query and 2 KV heads of 128 with no
+        # position encoding, no QK-norm and no gate). The training layout:
+        # the loss in tiles, the bias moved 0.001 a step.
+        "nemotron3-nano": HybridConfig(
+            vocab_size=131072, hidden_size=2688, num_layers=52, num_heads=32,
+            num_kv_heads=2, attn_head_dim=128, ffn_size=1856,
+            max_seq_len=262144, pos_emb="rope", norm="rmsnorm",
+            activation="relu2", tie_embeddings=False, rope_theta=1e4,
+            norm_eps=1e-5, partial_rotary_factor=0.0,
+            layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
+                          "EMEMEMEME",
+            attn_output_gate=False, qk_norm=False, recurrent_kind="mamba2",
+            mamba_num_heads=64, mamba_head_dim=64, mamba_n_groups=8,
+            mamba_state_size=128, mamba_conv_kernel=4, mamba_chunk=128,
+            num_experts=128, top_k=6, moe_ffn_size=1856,
+            shared_ffn_size=3712, router_scoring="sigmoid", routed_scale=2.5,
+            shared_gate=False, bias_update_rate=0.001, tiled_logits=8),
+        # the same stack at a toy size: the pattern's three kinds of block
+        # (an "EMEM*" of the toy pattern from block 1 on), 2 groups, 16
+        # router outputs of which 4 experts are held, chunks of 16
+        "tiny-nemotron": HybridConfig(
+            vocab_size=256, hidden_size=64, num_layers=5, num_heads=4,
+            num_kv_heads=2, attn_head_dim=32, ffn_size=32, max_seq_len=256,
+            pos_emb="rope", norm="rmsnorm", activation="relu2",
+            tie_embeddings=False, rope_theta=1e4, norm_eps=1e-5,
+            partial_rotary_factor=0.0, layer_pattern="MEMEM*EME",
+            first_layer=1, attn_output_gate=False, qk_norm=False,
+            recurrent_kind="mamba2", mamba_num_heads=4, mamba_head_dim=16,
+            mamba_n_groups=2, mamba_state_size=16, mamba_conv_kernel=4,
+            mamba_chunk=16, num_experts=16, top_k=2, moe_ffn_size=32,
+            shared_ffn_size=64, experts_held=4, router_scoring="sigmoid",
+            routed_scale=2.5, shared_gate=False, bias_update_rate=0.001,
+            tiled_logits=2),
     })
 
 

@@ -147,9 +147,27 @@ def _pick_block(dim: int, want: int) -> int:
     return max(b, 1)
 
 
+def _pick_lane_block(dim: int, want: int) -> int:
+    """A tile of a dim that lies on lanes (``n``, the contraction):
+    :func:`_pick_block`'s where that fills more than one lane tile or is all
+    that was asked for. A width that is 128 times an odd number (2688 = 128
+    x 21) halves down to 128 and one that is no multiple of 128 (1856 = 64
+    x 29) below it, which Mosaic refuses for a block that is not the whole
+    dim: there, the largest multiple of 128 within ``want`` that divides the
+    dim, or with none the whole dim."""
+    b = _pick_block(dim, want)
+    if b > 128 or b >= want:
+        return b
+    fit = [x for x in range(128, min(want, dim) + 1, 128) if dim % x == 0]
+    return max(fit) if fit else dim
+
+
 # a right-hand block of a memory-bound call: double-buffered, two of them
 # stay well inside the 16 MiB of VMEM a v5e kernel gets by default
 _RHS_BLOCK_BYTES = 2 << 20
+# all the blocks of one call, of the 16 MiB a v5e kernel gets by default
+# (Mosaic keeps some for itself: 13.8 MiB of blocks did not compile)
+_VMEM_BLOCKS_BYTES = 12 << 20
 
 
 def row_tile(m: int, num_groups: int, dtype) -> int:
@@ -190,7 +208,25 @@ def choose_tiles(m: int, kdim: int, n: int, num_groups: int, dtype,
             _RHS_BLOCK_BYTES // (bk * itemsize) + 1) // 2)
     bm, bn, bk = (min(b, cap) if cap > 0 else b
                   for b, cap in ((bm, block_m), (bn, block_n), (bk, block_k)))
-    return _pick_block(m, bm), _pick_block(n, bn), _pick_block(kdim, bk)
+    whole_k = _pick_lane_block(kdim, bk)
+    if whole_k > bk:
+        # no tile of the contraction fills lanes and it came back whole: of
+        # ``n``, what the right-hand block's bytes then hold
+        bn = min(bn, pl.next_power_of_2(
+            _RHS_BLOCK_BYTES // (whole_k * itemsize) + 1) // 2)
+    bm, whole_n = _pick_block(m, bm), _pick_lane_block(n, bn)
+    if whole_k > bk or whole_n > bn:
+        # a dim that came back whole is wider than was asked for: fewer rows,
+        # until the call's blocks (both operands and the result twice, for the
+        # pipeline, and a float32 accumulator as large as the largest of
+        # them: the backward's calls hold the same three blocks in other
+        # roles) fit a kernel's VMEM
+        while bm > 8 and (2 * itemsize * (bm * whole_k + whole_k * whole_n
+                                          + bm * whole_n)
+                          + 4 * max(bm * whole_n, bm * whole_k,
+                                    whole_k * whole_n)) > _VMEM_BLOCKS_BYTES:
+            bm //= 2
+    return bm, whole_n, whole_k
 
 
 def _gmm_kernel(tile_ids, group_ids, row_start, row_end, *refs,

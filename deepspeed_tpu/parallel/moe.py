@@ -255,6 +255,26 @@ class Glu:
 
 SWIGLU = Glu()
 
+# the ungated feed-forwards' activations, ``hidden = act(x W_u)``, by name:
+# the exact GELU, and the squared ReLU of the ``nemotron_h`` family
+UNGATED = {"gelu": jax.nn.gelu,
+           "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+
+def ffn_hidden(activation, product) -> jax.Array:
+    """A feed-forward's hidden rows: ``product(name)`` is its input times the
+    leaf ``name``. ``activation``: a :class:`Glu` ("swiglu": the plain one)
+    reads ``wg`` and ``wi``, one of :data:`UNGATED` by name ``wi`` alone;
+    anything else is refused."""
+    if activation == "swiglu":
+        activation = SWIGLU
+    if isinstance(activation, Glu):
+        return activation(product("wg"), product("wi"))
+    if activation not in UNGATED:
+        raise ValueError(f"feed-forward activation {activation!r} (a Glu | "
+                         f"{' | '.join(sorted(UNGATED))})")
+    return UNGATED[activation](product("wi"))
+
 
 def _expert_ffn(sorted_x: jax.Array, group_sizes: jax.Array,
                 expert_params: Dict[str, jax.Array], activation,
@@ -262,28 +282,24 @@ def _expert_ffn(sorted_x: jax.Array, group_sizes: jax.Array,
                 ) -> jax.Array:
     """Grouped-GEMM expert FFN over rows sorted by (local) expert. With
     ``layer`` the expert leaves are stacks ``[L, E, ...]`` read at that
-    (traced) layer inside the kernel, and the three products share the
+    (traced) layer inside the kernel, and the products share the
     work list ``metadata`` (their row tile is one: it follows from the
     rows and the experts alone). The kernel chooses each product's tiles
     from its shapes; ``tile_limits`` (``block_m`` / ``block_n`` /
-    ``block_k``) are upper bounds on that choice. ``activation``: "swiglu",
-    a :class:`Glu`, or anything else for the ungated GELU."""
+    ``block_k``) are upper bounds on that choice. ``activation``: what
+    :func:`ffn_hidden` takes (a gated one is three products, an ungated
+    one two)."""
     def gmm(lhs, rhs, sizes):
         tiles = gm.choose_tiles(lhs.shape[0], *rhs.shape[-2:], rhs.shape[-3],
                                 lhs.dtype, **(tile_limits or {}))
         if layer is None:
             return gm.gmm(lhs, rhs, sizes, *tiles)
         return gm.gmm_layer(lhs, rhs, sizes, layer, *tiles, metadata=metadata)
-    wi, wo = expert_params["wi"].astype(dt), expert_params["wo"].astype(dt)
-    if activation == "swiglu":
-        activation = SWIGLU
-    if isinstance(activation, Glu):
-        wg = expert_params["wg"].astype(dt)
-        hidden = activation(gmm(sorted_x, wg, group_sizes),
-                            gmm(sorted_x, wi, group_sizes))
-    else:
-        hidden = jax.nn.gelu(gmm(sorted_x, wi, group_sizes))
-    return gmm(hidden, wo, group_sizes)                     # [M, H-or-H_tp]
+    w = {name: expert_params[name].astype(dt) for name in ("wi", "wo", "wg")
+         if name in expert_params}
+    hidden = ffn_hidden(activation,
+                        lambda name: gmm(sorted_x, w[name], group_sizes))
+    return gmm(hidden, w["wo"], group_sizes)                # [M, H-or-H_tp]
 
 
 def route_top_k(y: jax.Array, router_w: jax.Array, top_k: int):
@@ -337,7 +353,7 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
                   offset: int = 0, shared: Optional[Dict] = None,
                   valid: Optional[jax.Array] = None, layer=None,
                   router_bias: Optional[jax.Array] = None,
-                  capacity: Optional[int] = None, glu: Glu = SWIGLU
+                  capacity: Optional[int] = None, glu=SWIGLU
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """An expert layer that holds a *share* of the experts: one chip's part
     of a layer whose ``cfg.num_experts`` experts are divided over the chips
@@ -351,7 +367,9 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
     to them, plus — given ``shared`` (``wg``, ``wi``, ``wo`` and, for a model
     that has one, the gate vector ``gate``) — the shared expert, behind its
     sigmoid gate or added as it is, which every chip computes alike. ``glu``
-    is the activation of the routed and the shared experts alike. A token
+    is the activation of the routed and the shared experts alike: a
+    :class:`Glu`, or the name of an ungated one (:func:`ffn_hidden`; no leaf
+    ``wg`` then). A token
     none of whose experts live here gets the shared expert alone. Nothing
     stands in for the absent chips: on one chip there is no exchange; on a
     mesh with an ``ep`` axis the same layer is :func:`moe_ffn_dropless`,
@@ -426,7 +444,7 @@ def moe_ffn_share(y: jax.Array, router_w: jax.Array,
     if shared is not None:
         with jax.named_scope("moe_shared"):
             dt = y.dtype
-            hid = glu(y @ shared["wg"].astype(dt), y @ shared["wi"].astype(dt))
+            hid = ffn_hidden(glu, lambda name: y @ shared[name].astype(dt))
             out = (hid @ shared["wo"].astype(dt)).astype(jnp.float32)
             if "gate" in shared:
                 out = out * jax.nn.sigmoid(jnp.einsum(

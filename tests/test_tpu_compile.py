@@ -1004,3 +1004,53 @@ def test_zero3_fsdp4_step_reduces_gradients_outside_the_rings(
     assert hops and not [h for h in hops if h in (
         "bf16[1024,14336]", "bf16[14336,1024]", "bf16[1024,32,128]",
         "bf16[32,128,1024]", "bf16[1024,8,128]")], hops
+
+
+# the ninth architecture at its published widths and the cell's shapes
+# (Nemotron 3 Nano, blocks 34-42: 8 held experts of 2688 x 1856 over a row
+# buffer of 9,216; Mamba-2 of 64 heads of 64 in 8 groups, state 128, two
+# sequences of 8,192 in chunks of 128)
+
+
+def test_grouped_matmul_at_widths_that_are_no_power_of_two(chip):
+    """``nemotron3-nano-train-c1``'s expert products, forward and backward:
+    the expert width 1856 = 64 x 29 is no multiple of a lane tile and the
+    hidden size 2688 = 128 x 21 halves down to one, so the tiles are the
+    whole width and 384 (``_pick_lane_block``); Mosaic refuses 64-lane
+    blocks, and 16 MiB of VMEM a block of 1856 x 896."""
+    def f(x, wi, wo, sizes):
+        def loss(x, wi, wo):
+            hid = grouped_matmul.gmm(x, wi, sizes)
+            out = grouped_matmul.gmm(jnp.square(jax.nn.relu(hid)), wo, sizes)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(x, wi, wo)
+
+    text = _compile(chip, f, ((9216, 2688), BF16), ((8, 2688, 1856), BF16),
+                    ((8, 1856, 2688), BF16), ((8,), jnp.int32))
+    assert text.count("grouped_matmul_dw") >= 2
+
+
+def test_mamba2_mixer_gradient_fits_beside_the_training_state(chip):
+    """The Mamba-2 mixer of ``nemotron3-nano-train-c1`` (the chunked scan in
+    plain ``jax.numpy``), value and gradient at two sequences of 8,192: XLA
+    keeps no [chunk, chunk] decay matrix a head in float32 beside its masked
+    product, and the whole backward's temporaries stay under 3.25 GiB (2.80
+    here; the step program has some 5.6 beside 7.5 of state: AOT, PR 57)."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.zoo import get_model
+
+    cfg = get_model("nemotron3-nano", num_layers=9, first_layer=34,
+                    experts_held=8, vocab_size=16384).config
+    shapes = hybrid._shapes(cfg)["mamba2"]
+    mp = {k: jax.ShapeDtypeStruct(s[1:], BF16, sharding=chip)
+          for k, s in shapes.items()}
+
+    def f(mp, y):
+        return jax.grad(lambda mp, y: jnp.sum(hybrid.mamba2_mixer(
+            cfg, mp, y).astype(jnp.float32)), argnums=(0, 1))(mp, y)
+
+    compiled = jax.jit(f).lower(mp, jax.ShapeDtypeStruct(
+        (2, 8192, 2688), BF16, sharding=chip)).compile()
+    mem = compiled.memory_analysis()
+    print("mamba2 mixer grad: temp GiB", mem.temp_size_in_bytes / 2**30)
+    assert mem.temp_size_in_bytes < 3.25 * 2**30
